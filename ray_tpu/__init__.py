@@ -21,6 +21,7 @@ from typing import Any, Dict, List, Optional, Sequence, Union
 from . import exceptions  # noqa: F401
 from ._private import context as _ctx
 from ._private import protocol as _P
+from ._private.accelerators import detect_tpus as _detect_tpus
 from ._private.client import CoreClient
 from ._private.config import CONFIG
 from ._private.gcs import GlobalControlPlane, JobRecord
@@ -110,16 +111,19 @@ def init(address: Optional[Any] = None,
         res = dict(resources or {})
         res.setdefault("CPU", float(num_cpus if num_cpus is not None
                                     else os.cpu_count() or 4))
+        tpus_detected = False
         if num_tpus is not None:
             res.setdefault("TPU", float(num_tpus))
         elif "TPU" not in res:
             detected = _detect_tpus()
             if detected:
                 res["TPU"] = float(detected)
+                tpus_detected = True
         if object_store_memory:
             CONFIG._values["object_store_memory_mb"] = (
                 object_store_memory // (1 << 20))
-        _global_node = NodeService(_global_gcs, _session_dir, res)
+        _global_node = NodeService(_global_gcs, _session_dir, res,
+                                   tpus_detected=tpus_detected)
         _global_node.start()
         _owns_cluster = True
 
@@ -205,20 +209,6 @@ def _install_driver_failure_hook() -> None:
     _sys.excepthook = _hook
 
 
-def _detect_tpus() -> int:
-    """TPU autodetection as a first-class resource (north-star requirement;
-    reference analogue: ``_private/accelerator.py:38-45``)."""
-    chips = os.environ.get("TPU_CHIPS")
-    if chips:
-        return int(chips)
-    # visible TPU chips via /dev (TPU VMs expose accel devices)
-    count = 0
-    for i in range(8):
-        if os.path.exists(f"/dev/accel{i}") or os.path.exists(f"/dev/vfio/{i}"):
-            count += 1
-    return count
-
-
 def is_initialized() -> bool:
     return _ctx.current_client is not None
 
@@ -232,6 +222,18 @@ def shutdown() -> None:
         from .util import tracing as _tracing
         _tracing.flush()          # ship driver-side spans before detach
     _ctx.current_client = None
+    if _owns_cluster and _global_node is not None:
+        # Worker output is forwarded onto this process's stdout by the
+        # node's log tailer and the client's reader thread. Drain it in
+        # order — one tail pass, then a round trip behind the frames that
+        # pass queued — so what workers wrote before shutdown() is
+        # printed before it returns, and nothing is printed after:
+        # close() below joins the reader thread.
+        try:
+            _global_node.drain_logs()
+            client.barrier()
+        except Exception:   # noqa: BLE001 — a wedged node must not block exit
+            pass
     try:
         client.close()
     except Exception:

@@ -368,7 +368,8 @@ class CoreClient:
                 pass
         elif op == P.EVENT:
             channel, data = payload
-            if channel == "LOG" and self.kind == P.KIND_DRIVER:
+            if (channel == "LOG" and self.kind == P.KIND_DRIVER
+                    and not self._closed.is_set()):
                 self._print_remote_logs(data)
         elif op == P.SHUTDOWN:
             self._fail_all(ConnectionError("node shutting down"))
@@ -417,6 +418,13 @@ class CoreClient:
         self._closed.set()
         self.reader.close()
         self.conn.close()
+        # the reader thread is what writes forwarded worker output to
+        # this process's stdout. _closed stops it printing what it has
+        # yet to handle; the join waits out a write already under way:
+        # once close() returns, nothing more is written
+        t = self._reader_thread
+        if t is not None and t is not threading.current_thread():
+            t.join(timeout=5.0)
 
     # ------------------------------------------------------------- plumbing
     def _on_send_error(self, msg, exc: BaseException) -> None:
@@ -433,6 +441,12 @@ class CoreClient:
         self.flush_submissions()
         self.conn.send((op, make_payload(req_id)))
         return fut
+
+    def barrier(self, timeout: float = 2.0) -> None:
+        """One round trip on the node connection: every frame the node
+        had queued for this client before the call (forwarded worker
+        output included) has been handled when it returns."""
+        self._request(P.KV_GET, lambda rid: (rid, b"")).result(timeout)
 
     def _send(self, op: int, payload: Any) -> None:
         self.flush_submissions()

@@ -59,7 +59,10 @@ def main(argv=None) -> int:
     if bool(args.head) == bool(args.address):
         ap.error("exactly one of --head / --address is required")
 
-    # node processes never own the TPU; the driver/trainer does
+    # A node process never opens the TPU backend. Workers start from the
+    # environment this process was started with, so that one granted TPU
+    # slots inherits its platform and not this pin (accelerators.worker_env).
+    started_env = dict(os.environ)
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
     from .gcs import GlobalControlPlane
@@ -88,7 +91,8 @@ def main(argv=None) -> int:
         gcs = RemoteControlPlane(args.address)
         gcs_port = int(args.address.rsplit(":", 1)[1])
 
-    node = NodeService(gcs, session_dir, resources)
+    node = NodeService(gcs, session_dir, resources,
+                       worker_base_env=started_env)
     node.start(labels=json.loads(args.labels), tcp_port=args.node_port,
                advertise_host=args.advertise_host)
     job_rest = None

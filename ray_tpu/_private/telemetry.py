@@ -37,6 +37,7 @@ import warnings
 from bisect import bisect_left
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+from . import accelerators
 from . import fieldsan
 from . import locksan
 from .config import CONFIG
@@ -633,7 +634,9 @@ def _ensure_flusher() -> None:
 def _flush_loop() -> None:
     while True:
         time.sleep(max(CONFIG.metrics_report_interval_ms, 250) / 1000.0)
-        _install_jax_compile_listener()
+        # every process that records telemetry runs this loop, so this
+        # is where a worker that opened the TPU backend reports its HBM
+        sample_devices()
         try:
             flush()
         except Exception:   # noqa: BLE001
@@ -855,8 +858,9 @@ def _sample_loop() -> None:
 
 
 def sample_once() -> None:
-    """One host + store + device sampling pass (called by the sampler
-    thread; separately callable for tests)."""
+    """One host + store sampling pass (called by the sampler thread;
+    separately callable for tests). Devices are sampled by the flusher
+    loop, which also runs in workers."""
     with _runtime_lock:
         nodes = [n for n in _nodes if not getattr(n, "dead", False)]
     for node in nodes:
@@ -890,7 +894,6 @@ def sample_once() -> None:
         except Exception:   # noqa: BLE001
             pass
         _sample_host(tags)
-    sample_devices()
 
 
 def _sample_host(tags: tuple) -> None:
@@ -910,13 +913,18 @@ def _sample_host(tags: tuple) -> None:
 def sample_devices() -> int:
     """Record per-device HBM gauges via ``device.memory_stats()``.
     Returns the number of devices that reported stats; 0 (and records
-    nothing) on CPU-only JAX or when jax was never imported. Never
-    raises."""
+    nothing) on CPU-only JAX, when jax was never imported, and in a
+    process whose user code has not opened a backend yet: sampling never
+    opens one, because that would take the chip in whichever process
+    happened to import jax (the driver, say) away from the worker that
+    was granted it. Never raises."""
     if "jax" not in sys.modules:
         return 0
     _install_jax_compile_listener()
     reported = 0
     try:
+        if not accelerators.jax_backend_initialized():
+            return 0
         jax = sys.modules["jax"]
         for dev in jax.local_devices():
             try:

@@ -258,6 +258,10 @@ class WorkerRuntime:
         context.current_task_id = spec.task_id
         context.current_task_name = spec.name
         context.current_accel_ids = spec.accel_ids
+        if spec.accel_ids:
+            # a process granted chips reports their HBM: the flusher loop
+            # samples devices once user code has opened the backend
+            telemetry._ensure_flusher()
         # inherit the submitting job's namespace so nested named-actor
         # lookups/creations resolve where the driver's would (ContextVar:
         # concurrent calls on a threaded actor don't race each other)

@@ -99,19 +99,19 @@ def build_mesh(spec: Optional[MeshSpec] = None,
                devices: Optional[Sequence[jax.Device]] = None) -> Mesh:
     """Build a `jax.sharding.Mesh` with the canonical axis names.
 
-    Uses `jax.experimental.mesh_utils.create_device_mesh` when the device
-    count allows so physical ICI adjacency is respected on real TPU
-    topologies; falls back to a plain reshape (CPU / virtual devices).
+    TPU devices go through `jax.experimental.mesh_utils.create_device_mesh`
+    so that physical ICI adjacency is respected, and an error from it
+    surfaces; CPU / virtual devices have no topology and are reshaped.
     """
     if devices is None:
         devices = jax.devices()
     devices = list(devices)
     spec = (spec or MeshSpec()).resolve(len(devices))
     shape = spec.axis_sizes()
-    try:
+    if devices[0].platform == "tpu":
         from jax.experimental import mesh_utils
         dev_array = mesh_utils.create_device_mesh(
             shape, devices=devices, allow_split_physical_axes=True)
-    except Exception:
+    else:
         dev_array = np.asarray(devices).reshape(shape)
     return Mesh(dev_array, AXIS_ORDER)

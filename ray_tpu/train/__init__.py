@@ -3,14 +3,14 @@
 Reference: Ray Train (``python/ray/train/``, SURVEY §2.3/§3.4). The
 reference spawns N single-GPU worker processes and wires them into a
 torch NCCL process group; TPU-native the unit of placement is the *host*
-(4 chips each) and the unit of computation is ONE jitted SPMD program
-over a `jax.sharding.Mesh` covering the slice — so `JaxTrainer` gangs
-one worker actor per host, assembles a global mesh (jax.distributed on
-real pods, local devices in tests), and runs the user's
-``train_loop_per_worker`` in lockstep on every host.
+and the unit of computation is ONE jitted SPMD program over a
+`jax.sharding.Mesh` — so `JaxTrainer` gangs one worker actor per host,
+grants its process the host's chips (``ScalingConfig(use_tpu=True)``) and
+runs the user's ``train_loop_per_worker`` in it; the loop builds its mesh
+over the host's devices.
 
-Parallelism (dp/fsdp/tp/sp/pp/ep) is a `MeshSpec` in ScalingConfig, not
-a wrapper class — see ``ray_tpu.parallel``.
+Parallelism (dp/fsdp/tp/sp/pp/ep) is a `MeshSpec`, not a wrapper class —
+see ``ray_tpu.parallel``.
 """
 
 from .checkpoint import Checkpoint  # noqa: F401

@@ -18,9 +18,12 @@ from ..parallel.mesh import MeshSpec
 class ScalingConfig:
     """How many host workers, what resources each, what device mesh.
 
-    num_workers: one per TPU host (4 chips/host on v5e); CPU-only
-    training uses plain actors. ``use_tpu`` adds the TPU resource to each
-    bundle so gang placement lands on TPU hosts.
+    num_workers: one per TPU host; CPU-only training uses plain actors.
+    ``use_tpu`` gives each worker a whole host's chips — as many ``TPU``
+    as the cluster's TPU nodes advertise (1 on a one-chip machine, 4 on
+    a v5e 2x2 host) — so gang placement lands on TPU hosts and each
+    worker's process owns its host's chips. ``resources_per_worker``
+    overrides the amount.
     """
 
     num_workers: int = 1
@@ -32,9 +35,20 @@ class ScalingConfig:
     def bundle(self) -> Dict[str, float]:
         res = dict(self.resources_per_worker or {})
         res.setdefault("CPU", 1.0)
-        if self.use_tpu:
-            res.setdefault("TPU", 4.0)     # chips per host
+        if self.use_tpu and "TPU" not in res:
+            res["TPU"] = _chips_per_host()
         return res
+
+
+def _chips_per_host() -> float:
+    """``TPU`` on the cluster's TPU nodes (the smallest, so that every
+    worker fits one). With none alive yet — an autoscaled cluster that
+    adds hosts on demand — a v5e host's four, which the placement group
+    then waits for."""
+    from .. import nodes
+    counts = [n["resources"]["TPU"] for n in nodes()
+              if n["alive"] and n["resources"].get("TPU", 0) >= 1]
+    return float(min(counts)) if counts else 4.0
 
 
 @dataclasses.dataclass

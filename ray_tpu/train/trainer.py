@@ -8,12 +8,13 @@ placement-group gang, sets up a torch process group, runs
 ``train_loop_per_worker``, streams ``session.report`` results back.
 
 TPU-native differences:
-  * one worker per *host*, not per chip; inside each worker the user
-    builds (or receives) a `jax.sharding.Mesh` over the host's devices —
-    on a real pod `jax.distributed.initialize` stitches hosts into one
-    global mesh (multi-controller SPMD); no NCCL/TCPStore rendezvous.
-  * parallelism comes from `ScalingConfig.mesh` (a MeshSpec), not from
-    DDP/FSDP wrapper classes.
+  * one worker per *host*, not per chip: the worker's process is granted
+    the host's chips (`ScalingConfig(use_tpu=True)`) and is the only one
+    that may open the TPU backend; inside it the user loop builds a
+    `jax.sharding.Mesh` over the host's devices (`build_mesh`). Hosts are
+    not stitched into one global mesh yet; no NCCL/TCPStore rendezvous.
+  * parallelism is a mesh (a MeshSpec) built in the loop, not DDP/FSDP
+    wrapper classes.
   * failure handling is checkpoint-based elastic restart: on worker
     death the whole gang restarts from the last reported checkpoint
     (SPMD programs can't lose a single participant).

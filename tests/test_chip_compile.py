@@ -1,0 +1,141 @@
+"""Compile the chip path's programs for a described TPU v5e, without one.
+
+The TPU compiler is installed with jax and compiles for a topology that is
+described and not attached (`on-chip-measurement` guide, section 2). This
+catches what interpret mode cannot — a kernel the Mosaic compiler refuses,
+a step that does not fit 16 GB of HBM, a shard_map that cannot be
+partitioned — at no chip time. Nothing runs: these tests say nothing about
+results or speed, and a pass here is not a chip run (`chip_smoke.py` is).
+
+Skipped where the topology cannot be described. The persistent compile
+cache is off around them: such a compile can be written to it but not read
+back without a chip.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+HBM_BYTES = 15.75 * 2 ** 30     # what the v5e compiler reports as capacity
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _on(sharding, tree):
+    """ShapeDtypeStructs of `tree`, every leaf placed by `sharding`."""
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+def test_flash_attention_fwd_bwd_compiles_at_bench_width(v5e):
+    """The three flash kernels at the width every gpt2_medium step runs:
+    [batch 12, 16 heads, seq 1024, head_dim 64] bf16, blocks 512."""
+    from ray_tpu.ops.attention import flash_attention
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, True, None, 512, 512, False)
+        return out.astype(jnp.float32).sum()
+
+    x = jax.ShapeDtypeStruct((12, 16, 1024, 64), jnp.bfloat16,
+                             sharding=SingleDeviceSharding(v5e.devices[0]))
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def _gpt2_medium_step(mesh, batch):
+    from ray_tpu.models import (GPT, gpt2_medium, init_train_state,
+                                make_optimizer, make_train_step)
+
+    # "auto" asks jax.default_backend(), which is the CPU here: name the
+    # kernel, as the step on the chip resolves it
+    cfg = gpt2_medium(max_seq_len=1024, remat_policy="dots",
+                      attention_impl="pallas")
+    model = GPT(cfg, mesh=mesh) if mesh is not None else GPT(cfg)
+    opt = make_optimizer()
+    state = jax.eval_shape(
+        lambda: init_train_state(model, opt, jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((batch, 1024), jnp.int32)
+    return model, opt, make_train_step(model, opt, mesh=mesh), state, tokens
+
+
+def test_gpt2_medium_train_step_fits_one_chip(v5e):
+    """chip_smoke.py's and bench.py's step: batch 12, "dots" remat. The
+    compiler refuses a program that exceeds HBM (batch 16 does)."""
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    _, _, step, state, tokens = _gpt2_medium_step(None, batch=12)
+    compiled = step.lower(_on(one_chip, state),
+                          {"tokens": _on(one_chip, tokens)}).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # compiling at all means it fits; the donated state is aliased to the
+    # new one, so what must fit beside it is the temporaries
+    assert compiled.memory_analysis().temp_size_in_bytes < HBM_BYTES
+
+
+@pytest.mark.slow   # 12 s here, and tier-1 runs close to its time limit
+def test_gpt2_medium_fsdp4_train_step_compiles_for_the_host(v5e):
+    """chip_smoke.py --chips 4: the same model on an fsdp=4 mesh built by
+    build_mesh from the four described chips, global batch 48; each chip
+    holds about a quarter of the state."""
+    from ray_tpu.models.training import batch_shardings
+    from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+
+    mesh = build_mesh(MeshSpec(fsdp=4), devices=v5e.devices)
+    _, _, step, state, tokens = _gpt2_medium_step(mesh, batch=48)
+    # the step's own in_shardings place the state
+    compiled = step.lower(
+        state, {"tokens": _on(batch_shardings(mesh), tokens)}).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "all-gather" in text
+    total = sum(np.prod(x.shape) * x.dtype.itemsize
+                for x in jax.tree_util.tree_leaves(state))
+    per_chip = compiled.memory_analysis().argument_size_in_bytes
+    assert 0.2 * total < per_chip < 0.4 * total
+
+
+def test_ring_attention_fwd_bwd_compiles_over_four_chips(v5e):
+    """Ring attention under shard_map, sequence split four ways:
+    [2, 16, 4096, 64] bf16, blocks 512. It has never run on a chip; the
+    long-context work (ROADMAP R4) builds on it."""
+    from ray_tpu.ops.ring_attention import ring_attention
+
+    mesh = Mesh(np.array(v5e.devices), ("sp",))
+    spec = P(None, None, "sp", None)
+
+    def local(q, k, v):
+        return ring_attention(q, k, v, "sp", True, None, "pallas", 512, 512)
+
+    def loss(q, k, v):
+        out = jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                            out_specs=spec, check_vma=False)(q, k, v)
+        return out.astype(jnp.float32).sum()
+
+    x = jax.ShapeDtypeStruct((2, 16, 4096, 64), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, spec))
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "collective-permute" in text
