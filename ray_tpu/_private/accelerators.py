@@ -115,8 +115,17 @@ def enable_compile_cache() -> str:
 
 def jax_backend_initialized() -> bool:
     """True once this process has opened a JAX backend. Never opens one,
-    never imports jax."""
+    never imports jax, and never queues behind jax's backend lock, which
+    is held for as long as a backend takes to open (the TPU runtime: 8 to
+    16 s): a backend that another thread is opening this instant is not
+    open yet. The table is read under that lock, so a True means every
+    backend is up and `jax.local_devices()` will not block."""
     if "jax" not in sys.modules:
         return False
     from jax._src import xla_bridge
-    return xla_bridge.backends_are_initialized()
+    if not xla_bridge._backend_lock.acquire(blocking=False):
+        return False
+    try:
+        return bool(xla_bridge._backends)
+    finally:
+        xla_bridge._backend_lock.release()

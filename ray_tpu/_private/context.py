@@ -17,6 +17,9 @@ current_task_name = None
 current_actor_id = None
 current_accel_ids = None        # TPU slot indices assigned at dispatch
 in_worker: bool = False
+# time.time() at which a worker process entered its main(), after the
+# interpreter's start and the runtime's imports
+worker_started_wall: Optional[float] = None
 
 # Set by the worker runtime once it hosts an actor instance: the
 # callable behind ray_tpu.actor_checkpoint() (captures + persists the

@@ -45,7 +45,7 @@ from .ids import ActorID, NodeID, ObjectID, TaskID, WorkerID
 from . import object_store
 from .object_store import ObjectMeta, ObjectStore
 from .rpc import RpcChannel
-from .serialization import to_bytes
+from .serialization import from_bytes, to_bytes
 
 _WORKER_STATES = ("STARTING", "IDLE", "BUSY", "ACTOR", "DEAD")
 
@@ -3838,9 +3838,13 @@ class NodeService:
             self.gcs.set_actor_state(spec.actor_id, ACTOR_DEAD,
                                      reason="creation task failed")
             # method calls queued while the actor was PENDING would hang
-            # forever otherwise
+            # forever otherwise; they carry what __init__ died of
+            try:
+                cause = f": {from_bytes(error)!r}"[:500]
+            except Exception:   # noqa: BLE001 — the verdict still stands
+                cause = ""
             self._fail_queued_actor_tasks(spec.actor_id,
-                                          "actor creation failed")
+                                          "actor creation failed" + cause)
             w = self._workers.get(rec.worker_id)
             if w is not None:
                 w.actor_id = None

@@ -45,6 +45,10 @@ from .config import CONFIG
 DEFAULT_BUCKETS = (0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5,
                    5.0, 10.0)
 
+# for what takes seconds, not milliseconds: a checkpoint, a gang's start
+LONG_BUCKETS = (0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 20.0, 40.0,
+                80.0)
+
 _N_SHARDS = 8
 
 # ------------------------------------------------------- quantile digest
@@ -632,15 +636,30 @@ def _ensure_flusher() -> None:
 
 
 def _flush_loop() -> None:
+    from . import context as _ctx
+    from ..util import tracing
     while True:
         time.sleep(max(CONFIG.metrics_report_interval_ms, 250) / 1000.0)
+        # This thread wakes beside user code — in a granted worker beside
+        # the loop that feeds the chip — so each activation measures
+        # itself: what it holds the GIL for is what the loop can lose.
+        # ``chips`` (the slots the running task holds) tells the
+        # chip-holding worker's series apart. Nothing here may touch jax
+        # before `sample_devices` has: the main thread may be importing it.
+        chips = str(len(_ctx.current_accel_ids or ()))
         # every process that records telemetry runs this loop, so this
         # is where a worker that opened the TPU backend reports its HBM
-        sample_devices()
-        try:
+        with tracing.timed_span(
+                "worker::sample_devices", M_WORKER_BACKGROUND,
+                (("thread", "sample_devices"), ("chips", chips))):
+            sample_devices()
+        with tracing.timed_span(
+                "worker::telemetry_flush", M_WORKER_BACKGROUND,
+                (("thread", "telemetry_flush"), ("chips", chips))):
             flush()
-        except Exception:   # noqa: BLE001
-            pass
+            # a long actor call (a training loop) has no task boundary
+            # to ship its spans at
+            tracing.flush()
 
 
 # ------------------------------------------------------------- snapshots
@@ -798,6 +817,13 @@ M_HBM_LIMIT = define(
 M_JAX_COMPILES = define(
     "counter", "rtpu_jax_compiles_total",
     "JAX compilation events observed in this process")
+M_WORKER_BACKGROUND = define(
+    "histogram", "rtpu_worker_background_seconds",
+    "Seconds one activation of a process's periodic background thread "
+    "ran (thread=sample_devices|telemetry_flush; chips=the accelerator "
+    "slots the process's running task holds, 0 in a driver)",
+    buckets=(0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
+             0.5, 1.0, 2.5, 10.0))
 M_DROPPED_SERIES = define(
     "counter", "rtpu_telemetry_dropped_series_total",
     "Metric series dropped by the control plane (cardinality cap or "
@@ -918,14 +944,17 @@ def sample_devices() -> int:
     opens one, because that would take the chip in whichever process
     happened to import jax (the driver, say) away from the worker that
     was granted it. Never raises."""
-    if "jax" not in sys.modules:
+    jax = sys.modules.get("jax")
+    # a jax that another thread is still importing counts as absent: to
+    # touch it now would block this thread on the import lock for seconds
+    # (or, through a submodule, break that import with a half-made module)
+    if jax is None or getattr(jax.__spec__, "_initializing", False):
         return 0
     _install_jax_compile_listener()
     reported = 0
     try:
         if not accelerators.jax_backend_initialized():
             return 0
-        jax = sys.modules["jax"]
         for dev in jax.local_devices():
             try:
                 stats = dev.memory_stats()

@@ -652,6 +652,7 @@ class WorkerRuntime:
 
 
 def main() -> None:
+    context.worker_started_wall = time.time()
     socket_path, node_hex, worker_hex = sys.argv[1], sys.argv[2], sys.argv[3]
     rt = WorkerRuntime(socket_path, NodeID.from_hex(node_hex),
                        WorkerID.from_hex(worker_hex))

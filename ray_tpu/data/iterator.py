@@ -14,7 +14,26 @@ from typing import Any, Dict, Iterator, List, Optional
 import numpy as np
 
 from .. import get
+from .._private import telemetry
+from ..util import tracing
 from .block import Block, block_concat, block_num_rows, block_slice
+
+# Spans sit around the statements that wait or copy, never across a
+# ``yield``: a generator's frame is suspended inside the consumer's time.
+M_FEED_WAIT = telemetry.define(
+    "histogram", "rtpu_data_feed_wait_seconds",
+    "Seconds a consumer of a streaming shard waited for one block: the "
+    "shard queue's get (stage=queue) and the object's fetch (stage=fetch)")
+M_FEED_TO_DEVICE = telemetry.define(
+    "histogram", "rtpu_data_feed_to_device_seconds",
+    "Seconds iter_device_batches spent turning one batch into jax Arrays "
+    "(asarray and device_put)")
+M_FEED_BATCHES = telemetry.define(
+    "counter", "rtpu_data_feed_batches_total",
+    "Device batches yielded by iter_device_batches")
+M_FEED_BYTES = telemetry.define(
+    "counter", "rtpu_data_feed_bytes_total",
+    "Bytes of the device batches yielded by iter_device_batches")
 
 
 class DataIterator:
@@ -28,7 +47,9 @@ class DataIterator:
     # ------------------------------------------------------------ blocks
     def iter_block_refs(self) -> Iterator[Any]:
         while True:
-            item = self._queue.get(block=True, timeout=None)
+            with tracing.timed_span("data::block_wait", M_FEED_WAIT,
+                                    (("stage", "queue"),)):
+                item = self._queue.get(block=True, timeout=None)
             if item is None:
                 return
             if isinstance(item, tuple) and item[0] == "__stream_error__":
@@ -51,7 +72,10 @@ class DataIterator:
 
     def iter_blocks(self) -> Iterator[Block]:
         for ref in self.iter_block_refs():
-            yield get(ref)
+            with tracing.timed_span("data::block_get", M_FEED_WAIT,
+                                    (("stage", "fetch"),)):
+                block = get(ref)
+            yield block
 
     # ----------------------------------------------------------- batches
     def iter_batches(self, *, batch_size: int = 256,
@@ -87,12 +111,16 @@ class DataIterator:
         for batch in self.iter_batches(batch_size=batch_size,
                                        drop_last=True):
             out = {}
-            for k, v in batch.items():
-                arr = jnp.asarray(v, dtype=dtype) if dtype is not None \
-                    else jnp.asarray(v)
-                if sharding is not None:
-                    arr = jax.device_put(arr, sharding)
-                out[k] = arr
+            with tracing.timed_span("data::to_device", M_FEED_TO_DEVICE):
+                for k, v in batch.items():
+                    arr = jnp.asarray(v, dtype=dtype) if dtype is not None \
+                        else jnp.asarray(v)
+                    if sharding is not None:
+                        arr = jax.device_put(arr, sharding)
+                    out[k] = arr
+            telemetry.counter_inc(M_FEED_BATCHES)
+            telemetry.counter_inc(M_FEED_BYTES, float(sum(
+                a.nbytes for a in out.values())))
             yield out
 
     def __reduce__(self):

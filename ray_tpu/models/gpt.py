@@ -387,41 +387,51 @@ class GPT:
         c = self.config
         dt = c.dtype
 
-        h = self._norm(x, w["norm1"], w.get("bias1"))
-        q = jnp.einsum("bsd,dhk->bshk", h, w["wq"].astype(dt))
-        k = jnp.einsum("bsd,dhk->bshk", h, w["wk"].astype(dt))
-        v = jnp.einsum("bsd,dhk->bshk", h, w["wv"].astype(dt))
-        if c.positions == "rope":
-            q = self._rope(q, positions)
-            k = self._rope(k, positions)
-        q = self._constrain(q, "act_batch", "act_seq", "act_heads",
-                            "head_dim")
-        k = self._constrain(k, "act_batch", "act_seq", "act_kv_heads",
-                            "head_dim")
-        attn = self._attention(q, k, v)
-        attn = jnp.einsum("bshk,hkd->bsd", attn, w["wo"].astype(dt))
-        x = x + self._constrain(attn, "act_batch", "act_seq", "act_embed")
+        # the scopes are metadata on the ops (the profiler's trace and the
+        # HLO carry them), the program is the same with or without
+        with jax.named_scope("attn_qkv"):
+            h = self._norm(x, w["norm1"], w.get("bias1"))
+            q = jnp.einsum("bsd,dhk->bshk", h, w["wq"].astype(dt))
+            k = jnp.einsum("bsd,dhk->bshk", h, w["wk"].astype(dt))
+            v = jnp.einsum("bsd,dhk->bshk", h, w["wv"].astype(dt))
+            if c.positions == "rope":
+                q = self._rope(q, positions)
+                k = self._rope(k, positions)
+            q = self._constrain(q, "act_batch", "act_seq", "act_heads",
+                                "head_dim")
+            k = self._constrain(k, "act_batch", "act_seq", "act_kv_heads",
+                                "head_dim")
+        with jax.named_scope("attn_kernel"):
+            attn = self._attention(q, k, v)
+        with jax.named_scope("attn_out"):
+            attn = jnp.einsum("bshk,hkd->bsd", attn, w["wo"].astype(dt))
+            x = x + self._constrain(attn, "act_batch", "act_seq",
+                                    "act_embed")
 
-        h = self._norm(x, w["norm2"], w.get("bias2"))
-        aux = jnp.zeros((), jnp.float32)
-        if c.n_experts > 0:
-            from .moe import moe_ffn
-            down, moe_metrics = moe_ffn(
-                h, w["router"], w["w_up"], w["w_gate"], w["w_down"],
-                top_k=c.moe_top_k,
-                capacity_factor=c.moe_capacity_factor, dtype=dt)
-            aux = moe_metrics["moe_aux_loss"]
-        else:
-            up = jnp.einsum("bsd,df->bsf", h, w["w_up"].astype(dt))
-            if c.activation == "swiglu":
-                gate = jnp.einsum("bsd,df->bsf", h,
-                                  w["w_gate"].astype(dt))
-                act = jax.nn.silu(gate) * up
+        with jax.named_scope("mlp"):
+            h = self._norm(x, w["norm2"], w.get("bias2"))
+            aux = jnp.zeros((), jnp.float32)
+            if c.n_experts > 0:
+                from .moe import moe_ffn
+                down, moe_metrics = moe_ffn(
+                    h, w["router"], w["w_up"], w["w_gate"], w["w_down"],
+                    top_k=c.moe_top_k,
+                    capacity_factor=c.moe_capacity_factor, dtype=dt)
+                aux = moe_metrics["moe_aux_loss"]
             else:
-                act = jax.nn.gelu(up, approximate=True)
-            act = self._constrain(act, "act_batch", "act_seq", "act_mlp")
-            down = jnp.einsum("bsf,fd->bsd", act, w["w_down"].astype(dt))
-        x = x + self._constrain(down, "act_batch", "act_seq", "act_embed")
+                up = jnp.einsum("bsd,df->bsf", h, w["w_up"].astype(dt))
+                if c.activation == "swiglu":
+                    gate = jnp.einsum("bsd,df->bsf", h,
+                                      w["w_gate"].astype(dt))
+                    act = jax.nn.silu(gate) * up
+                else:
+                    act = jax.nn.gelu(up, approximate=True)
+                act = self._constrain(act, "act_batch", "act_seq",
+                                      "act_mlp")
+                down = jnp.einsum("bsf,fd->bsd", act,
+                                  w["w_down"].astype(dt))
+            x = x + self._constrain(down, "act_batch", "act_seq",
+                                    "act_embed")
         return x, aux
 
     # -- forward -----------------------------------------------------------
@@ -447,15 +457,16 @@ class GPT:
         # spmd_partitioner.cc warning in MULTICHIP_r03. Replicated
         # operand + sharded indices computes the gather directly in the
         # activation sharding.
-        tbl = self._constrain(params["tok_embed"].astype(c.dtype),
-                              None, None)
-        tokens = self._constrain(tokens, "act_batch", "act_seq")
-        x = tbl[tokens]
-        if c.positions == "learned":
-            pos_tbl = self._constrain(params["pos_embed"].astype(c.dtype),
-                                      None, None)
-            x = x + pos_tbl[positions]
-        x = self._constrain(x, "act_batch", "act_seq", "act_embed")
+        with jax.named_scope("embed"):
+            tbl = self._constrain(params["tok_embed"].astype(c.dtype),
+                                  None, None)
+            tokens = self._constrain(tokens, "act_batch", "act_seq")
+            x = tbl[tokens]
+            if c.positions == "learned":
+                pos_tbl = self._constrain(
+                    params["pos_embed"].astype(c.dtype), None, None)
+                x = x + pos_tbl[positions]
+            x = self._constrain(x, "act_batch", "act_seq", "act_embed")
 
         block_fn = self._block
         if c.remat:
@@ -482,16 +493,20 @@ class GPT:
                 return x, aux
 
             x, aux_per_layer = lax.scan(scan_body, x, params["blocks"])
-        x = self._norm(x, params["norm_f"], params.get("bias_f"))
-        if c.tie_embeddings:
-            logits = jnp.einsum("bsd,vd->bsv", x,
-                                params["tok_embed"].astype(c.dtype))
-        else:
-            logits = jnp.einsum("bsd,dv->bsv", x,
-                                params["lm_head"].astype(c.dtype))
-        logits = self._constrain(logits, "act_batch", "act_seq", "act_vocab")
-        return logits.astype(jnp.float32), {
-            "moe_aux_loss": aux_per_layer.mean()}
+        # one scope, `head_loss`, for the final norm and the logits here
+        # and for the cross-entropy in `loss`
+        with jax.named_scope("head_loss"):
+            x = self._norm(x, params["norm_f"], params.get("bias_f"))
+            if c.tie_embeddings:
+                logits = jnp.einsum("bsd,vd->bsv", x,
+                                    params["tok_embed"].astype(c.dtype))
+            else:
+                logits = jnp.einsum("bsd,dv->bsv", x,
+                                    params["lm_head"].astype(c.dtype))
+            logits = self._constrain(logits, "act_batch", "act_seq",
+                                     "act_vocab")
+            logits = logits.astype(jnp.float32)
+        return logits, {"moe_aux_loss": aux_per_layer.mean()}
 
     def _pipeline_blocks(self, block_fn, blocks: Params, x: jax.Array,
                          positions: jax.Array) -> jax.Array:
@@ -566,22 +581,23 @@ class GPT:
         c = self.config
         tokens = batch["tokens"]
         logits, aux = self.forward_with_aux(params, tokens)  # [B,S,V] f32
-        targets = jnp.concatenate(
-            [tokens[:, 1:], jnp.zeros_like(tokens[:, :1])], axis=1)
-        mask = jnp.concatenate(
-            [jnp.ones_like(tokens[:, 1:], jnp.float32),
-             jnp.zeros_like(tokens[:, :1], jnp.float32)], axis=1)
-        if "loss_mask" in batch:
-            mask = mask * batch["loss_mask"].astype(jnp.float32)
+        with jax.named_scope("head_loss"):
+            targets = jnp.concatenate(
+                [tokens[:, 1:], jnp.zeros_like(tokens[:, :1])], axis=1)
+            mask = jnp.concatenate(
+                [jnp.ones_like(tokens[:, 1:], jnp.float32),
+                 jnp.zeros_like(tokens[:, :1], jnp.float32)], axis=1)
+            if "loss_mask" in batch:
+                mask = mask * batch["loss_mask"].astype(jnp.float32)
 
-        lse = jax.nn.logsumexp(logits, axis=-1)            # [B, S]
-        true_logit = jnp.take_along_axis(
-            logits, targets[..., None], axis=-1)[..., 0]   # [B, S]
-        nll = lse - true_logit
-        total = jnp.maximum(mask.sum(), 1.0)
-        loss = (nll * mask).sum() / total
-        if c.z_loss:
-            loss = loss + c.z_loss * (lse ** 2 * mask).sum() / total
+            lse = jax.nn.logsumexp(logits, axis=-1)            # [B, S]
+            true_logit = jnp.take_along_axis(
+                logits, targets[..., None], axis=-1)[..., 0]   # [B, S]
+            nll = lse - true_logit
+            total = jnp.maximum(mask.sum(), 1.0)
+            loss = (nll * mask).sum() / total
+            if c.z_loss:
+                loss = loss + c.z_loss * (lse ** 2 * mask).sum() / total
         metrics = {
             "loss": loss,
             "ppl_log": (nll * mask).sum() / total,

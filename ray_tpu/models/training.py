@@ -141,11 +141,14 @@ def make_train_step(model: GPT, optimizer: optax.GradientTransformation,
     def train_step(state: TrainState, batch: Dict[str, jax.Array]):
         grad_fn = jax.value_and_grad(model.loss, has_aux=True)
         (loss, metrics), grads = grad_fn(state.params, batch)
-        updates, opt_state = optimizer.update(grads, state.opt_state,
-                                              state.params)
-        params = optax.apply_updates(state.params, updates)
+        # scope names are metadata: the trace's device ops carry them
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, state.opt_state,
+                                                  state.params)
+            params = optax.apply_updates(state.params, updates)
+            grad_norm = optax.global_norm(grads)
         metrics = dict(metrics)
-        metrics["grad_norm"] = optax.global_norm(grads)
+        metrics["grad_norm"] = grad_norm
         new_state = TrainState(step=state.step + 1, params=params,
                                opt_state=opt_state)
         return new_state, metrics

@@ -179,6 +179,7 @@ def _fwd_pallas(q, k, v, *, scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return out, lse
 
@@ -317,6 +318,7 @@ def _bwd_pallas(q, k, v, out, lse, do, *, scale, causal, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct(q.shape, dq_dtype),
         scratch_shapes=[pltpu.VMEM((block_q, head_dim), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
 
     # dk/dv: kv block is the outer grid axis, q blocks stream innermost.
@@ -346,6 +348,7 @@ def _bwd_pallas(q, k, v, out, lse, do, *, scale, causal, block_q, block_k,
         scratch_shapes=[pltpu.VMEM((block_k, head_dim), jnp.float32),
                         pltpu.VMEM((block_k, head_dim), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
 
     if group > 1:

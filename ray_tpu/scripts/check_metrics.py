@@ -28,7 +28,7 @@ _README_NAME_RE = re.compile(r"`(rtpu_[A-Za-z0-9_:]+)`")
 # "Cluster event & span registry" section only — scanning the whole
 # README would catch unrelated backticked identifiers.
 _LABEL_RE = re.compile(r"^[A-Z][A-Z0-9_]*$")
-_SPAN_PREFIX_RE = re.compile(r"^[a-z][a-z0-9_]*::$")
+_SPAN_PREFIX_RE = re.compile(r"^[a-z][a-z0-9_]*::")
 _README_LABEL_RE = re.compile(r"`([A-Z][A-Z0-9_]+)`")
 _README_SPAN_RE = re.compile(r"`([a-z][a-z0-9_]*::)")
 _REGISTRY_HEADING = "### Cluster event & span registry"
@@ -259,8 +259,9 @@ def collect_event_labels(pkg_dir: str, files=None) -> Dict[str, str]:
 
 
 def collect_span_prefixes(pkg_dir: str, files=None) -> Dict[str, str]:
-    """Span-name prefixes (``xxx::``) appearing as string constants in
-    the name argument of ``start_span``/``begin_span`` calls."""
+    """Span-name prefixes (``xxx::``) that open a string constant in the
+    name argument of ``start_span``/``begin_span``/``timed_span`` calls
+    (``"task::" + name`` and ``"train::report"`` alike)."""
     out: Dict[str, str] = {}
     for rel, tree in (files if files is not None
                       else _walk_files(pkg_dir)):
@@ -270,13 +271,14 @@ def collect_span_prefixes(pkg_dir: str, files=None) -> Dict[str, str]:
             fn = node.func
             name = (fn.attr if isinstance(fn, ast.Attribute)
                     else fn.id if isinstance(fn, ast.Name) else None)
-            if name not in ("start_span", "begin_span"):
+            if name not in ("start_span", "begin_span", "timed_span"):
                 continue
             for sub in ast.walk(node.args[0]):
-                if (isinstance(sub, ast.Constant)
-                        and isinstance(sub.value, str)
-                        and _SPAN_PREFIX_RE.match(sub.value)):
-                    out[sub.value] = rel
+                found = (_SPAN_PREFIX_RE.match(sub.value)
+                         if isinstance(sub, ast.Constant)
+                         and isinstance(sub.value, str) else None)
+                if found:
+                    out[found.group(0)] = rel
     return out
 
 

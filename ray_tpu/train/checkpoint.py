@@ -16,6 +16,19 @@ import shutil
 import tempfile
 from typing import Any, Dict, Optional
 
+from .._private import telemetry
+from ..util import tracing
+
+M_SAVE_SECONDS = telemetry.define(
+    "histogram", "rtpu_checkpoint_save_seconds",
+    "Seconds inside Checkpoint.from_pytree (device-to-host copy and the "
+    "orbax write)",
+    buckets=telemetry.LONG_BUCKETS)
+M_SAVE_BYTES = telemetry.define(
+    "counter", "rtpu_checkpoint_save_bytes_total",
+    "Bytes of pytree leaves handed to Checkpoint.from_pytree (the sum "
+    "of the leaves' nbytes)")
+
 _METRICS_FILE = ".rtpu_metrics.json"
 _DICT_FILE = "data.pkl"
 _PYTREE_DIR = "pytree"
@@ -43,17 +56,24 @@ class Checkpoint:
     def from_pytree(cls, tree: Any, dir: Optional[str] = None,
                     extra: Optional[Dict[str, Any]] = None) -> "Checkpoint":
         """Save a JAX pytree (params / TrainState) via orbax."""
-        path = dir or tempfile.mkdtemp(prefix="rtpu_ckpt_")
-        os.makedirs(path, exist_ok=True)
-        import orbax.checkpoint as ocp
-        ckptr = ocp.PyTreeCheckpointer()
-        target = os.path.join(path, _PYTREE_DIR)
-        if os.path.exists(target):
-            shutil.rmtree(target)
-        ckptr.save(target, tree)
-        if extra:
-            with open(os.path.join(path, _DICT_FILE), "wb") as f:
-                pickle.dump(extra, f)
+        import jax
+        with tracing.timed_span("checkpoint::save", M_SAVE_SECONDS):
+            path = dir or tempfile.mkdtemp(prefix="rtpu_ckpt_")
+            os.makedirs(path, exist_ok=True)
+            import orbax.checkpoint as ocp
+            ckptr = ocp.PyTreeCheckpointer()
+            target = os.path.join(path, _PYTREE_DIR)
+            with tracing.start_span("checkpoint::clear_target"):
+                if os.path.exists(target):
+                    shutil.rmtree(target)
+            with tracing.start_span("checkpoint::orbax_save"):
+                ckptr.save(target, tree)
+            if extra:
+                with open(os.path.join(path, _DICT_FILE), "wb") as f:
+                    pickle.dump(extra, f)
+        telemetry.counter_inc(M_SAVE_BYTES, float(sum(
+            getattr(leaf, "nbytes", 0)
+            for leaf in jax.tree_util.tree_leaves(tree))))
         return cls(path)
 
     # ------------------------------------------------------------- reading
@@ -73,11 +93,12 @@ class Checkpoint:
         """Restore a pytree; pass abstract arrays / shardings as
         ``template`` to restore sharded on-device (orbax restore_args)."""
         import orbax.checkpoint as ocp
-        ckptr = ocp.PyTreeCheckpointer()
-        target = os.path.join(self.path, _PYTREE_DIR)
-        if template is None:
-            return ckptr.restore(target)
-        return ckptr.restore(target, item=template)
+        with tracing.start_span("checkpoint::restore"):
+            ckptr = ocp.PyTreeCheckpointer()
+            target = os.path.join(self.path, _PYTREE_DIR)
+            if template is None:
+                return ckptr.restore(target)
+            return ckptr.restore(target, item=template)
 
     # ------------------------------------------------------------ metadata
     def set_metrics(self, metrics: Dict[str, Any]) -> None:

@@ -9,9 +9,17 @@ results stream back to the trainer through a driver-owned results queue.
 from __future__ import annotations
 
 import threading
+import time
 from typing import Any, Dict, Optional
 
+from .._private import telemetry
+from ..util import tracing
 from .checkpoint import Checkpoint
+
+M_REPORT_SECONDS = telemetry.define(
+    "histogram", "rtpu_train_report_seconds",
+    "Seconds a train worker spent inside train.report (checkpoint "
+    "metadata and the put into the results queue)")
 
 _session_local = threading.local()
 
@@ -81,17 +89,23 @@ def report(metrics: Dict[str, Any],
     """
     ctx = get_context()
     ctx.iteration += 1
-    payload = {
-        "rank": ctx.world_rank,
-        "iteration": ctx.iteration,
-        "metrics": dict(metrics),
-        "checkpoint_path": None,
-    }
-    if checkpoint is not None and ctx.world_rank == 0:
-        checkpoint.set_metrics(metrics)
-        payload["checkpoint_path"] = checkpoint.path
-        ctx.latest_checkpoint = checkpoint
-    ctx.results_queue.put(payload)
+    with tracing.timed_span("train::report", M_REPORT_SECONDS):
+        payload = {
+            "rank": ctx.world_rank,
+            "iteration": ctx.iteration,
+            "metrics": dict(metrics),
+            "checkpoint_path": None,
+            # the driver reads how long the report waited for it
+            # (`trainer._drain`; one host, so one clock)
+            "reported_wall": time.time(),
+        }
+        if checkpoint is not None and ctx.world_rank == 0:
+            with tracing.start_span("train::report_checkpoint"):
+                checkpoint.set_metrics(metrics)
+            payload["checkpoint_path"] = checkpoint.path
+            ctx.latest_checkpoint = checkpoint
+        with tracing.start_span("train::report_put"):
+            ctx.results_queue.put(payload)
 
 
 def get_checkpoint() -> Optional[Checkpoint]:

@@ -28,9 +28,11 @@ import time
 from typing import Any, Callable, Dict, List, Optional
 
 from .. import get, kill, wait
+from .._private import context, telemetry
 from ..api import remote
 from ..exceptions import TaskError, WorkerCrashedError
 from ..util.placement_group import placement_group, remove_placement_group
+from ..util import tracing
 from ..util.scheduling_strategies import PlacementGroupSchedulingStrategy
 from .checkpoint import Checkpoint
 from .config import (CheckpointConfig, FailureConfig, RunConfig,
@@ -38,21 +40,50 @@ from .config import (CheckpointConfig, FailureConfig, RunConfig,
 from .result import Result
 from .session import TrainContext, _set_session
 
+M_GANG_START = telemetry.define(
+    "histogram", "rtpu_train_gang_start_seconds",
+    "Seconds of one phase of a train worker's start, observed in the "
+    "worker, in order and disjoint (one host: one clock): phase=spawn "
+    "(the driver's first _TrainWorker.remote() until the worker process "
+    "enters its main(): scheduling, process start, the runtime's imports; "
+    "0 in a process that was already up), load (until __init__ is "
+    "entered: registration, fetching and unpickling the actor class, which "
+    "imports ray_tpu.train and jax), run_wait (until run() is entered: the "
+    "driver splitting its datasets, the loop shipped and unpickled)",
+    buckets=telemetry.LONG_BUCKETS)
+M_REPORT_LAG = telemetry.define(
+    "histogram", "rtpu_train_report_lag_seconds",
+    "Seconds from a worker's train.report to the driver taking the "
+    "report into the run's history (one host: one clock)",
+    buckets=telemetry.LONG_BUCKETS)
+M_CHECKPOINT_PERSIST = telemetry.define(
+    "histogram", "rtpu_train_checkpoint_persist_seconds",
+    "Seconds the driver spent copying one reported checkpoint into the "
+    "experiment directory",
+    buckets=telemetry.LONG_BUCKETS)
+
 
 @remote
 class _TrainWorker:
     """One gang member; executes the user loop under a session."""
 
     def __init__(self, rank: int, world_size: int, storage_path: str,
-                 experiment_name: str):
+                 experiment_name: str, created_wall: float):
+        self._entered_wall = time.time()
         self.rank = rank
         self.world_size = world_size
         self.storage_path = storage_path
         self.experiment_name = experiment_name
+        # `created_wall`: the driver's clock before its first `.remote()`
+        # (one host: one clock); a process that was up before it spawned in 0
+        up = max(created_wall, context.worker_started_wall or 0.0)
+        _observe_phase("spawn", up - created_wall)
+        _observe_phase("load", self._entered_wall - up)
 
     def run(self, loop_fn: Callable, config: Dict[str, Any],
             results_queue, resume_ckpt_path: Optional[str],
             dataset_shards: Optional[Dict[str, Any]] = None):
+        _observe_phase("run_wait", time.time() - self._entered_wall)
         resume = (Checkpoint(resume_ckpt_path)
                   if resume_ckpt_path else None)
         ctx = TrainContext(self.rank, self.world_size, results_queue,
@@ -68,7 +99,15 @@ class _TrainWorker:
                 loop_fn()
         finally:
             _set_session(None)
+            # before the call returns: the flush that follows TASK_DONE
+            # races the gang's kill
+            tracing.flush()
+            telemetry.flush()
         return self.rank
+
+
+def _observe_phase(phase: str, seconds: float) -> None:
+    telemetry.hist_observe(M_GANG_START, seconds, (("phase", phase),))
 
 
 def _loop_takes_config(fn: Callable) -> bool:
@@ -128,13 +167,15 @@ class JaxTrainer:
         error: Optional[Exception] = None
 
         while True:
-            queue = Queue()
+            with tracing.start_span("train::results_queue"):
+                queue = Queue()
             gang = self._spawn_gang(name, storage)
             # fresh streaming shards per attempt: the pipeline re-executes
             # from the start on an elastic restart
-            shard_sets = {
-                ds_name: ds.streaming_split(self._scaling.num_workers)
-                for ds_name, ds in self._datasets.items()}
+            with tracing.start_span("train::streaming_split"):
+                shard_sets = {
+                    ds_name: ds.streaming_split(self._scaling.num_workers)
+                    for ds_name, ds in self._datasets.items()}
             try:
                 refs = [w.run.remote(self._loop, self._loop_config, queue,
                                      latest_ckpt.path if latest_ckpt
@@ -201,31 +242,37 @@ class JaxTrainer:
     def _spawn_gang(self, name: str, storage: str) -> dict:
         sc = self._scaling
         bundle = sc.bundle()
-        pg = placement_group([bundle] * sc.num_workers,
-                             strategy=sc.placement_strategy)
-        try:
-            pg.ready(timeout=60.0)
-        except TimeoutError:
-            if sc.placement_strategy == "STRICT_SPREAD":
-                # dev fallback: fewer nodes than workers — pack instead
-                remove_placement_group(pg)
-                pg = placement_group([bundle] * sc.num_workers,
-                                     strategy="PACK")
+        with tracing.start_span("train::pg_ready"):
+            pg = placement_group([bundle] * sc.num_workers,
+                                 strategy=sc.placement_strategy)
+            try:
                 pg.ready(timeout=60.0)
-            else:
-                raise
+            except TimeoutError:
+                if sc.placement_strategy == "STRICT_SPREAD":
+                    # dev fallback: fewer nodes than workers — pack instead
+                    remove_placement_group(pg)
+                    pg = placement_group([bundle] * sc.num_workers,
+                                         strategy="PACK")
+                    pg.ready(timeout=60.0)
+                else:
+                    raise
         workers = []
         try:
-            for rank in range(sc.num_workers):
-                strat = PlacementGroupSchedulingStrategy(
-                    placement_group=pg, placement_group_bundle_index=rank)
-                opts = {"scheduling_strategy": strat,
-                        "num_cpus": bundle.get("CPU", 1.0)}
-                extra = {k: v for k, v in bundle.items() if k != "CPU"}
-                if extra:
-                    opts["resources"] = extra
-                workers.append(_TrainWorker.options(**opts).remote(
-                    rank, sc.num_workers, storage, name))
+            # the phases of `rtpu_train_gang_start_seconds` start here,
+            # at the first `.remote()`: the worker observes them
+            created_wall = time.time()
+            with tracing.start_span("train::create_workers"):
+                for rank in range(sc.num_workers):
+                    strat = PlacementGroupSchedulingStrategy(
+                        placement_group=pg,
+                        placement_group_bundle_index=rank)
+                    opts = {"scheduling_strategy": strat,
+                            "num_cpus": bundle.get("CPU", 1.0)}
+                    extra = {k: v for k, v in bundle.items() if k != "CPU"}
+                    if extra:
+                        opts["resources"] = extra
+                    workers.append(_TrainWorker.options(**opts).remote(
+                        rank, sc.num_workers, storage, name, created_wall))
             return {"pg": pg, "workers": workers}
         except Exception:
             for w in workers:
@@ -259,29 +306,48 @@ def _latest(history, latest_ckpt, last_metrics):
     return latest_ckpt, last_metrics
 
 
+def _next_report(queue) -> Optional[Dict[str, Any]]:
+    from ..util.queue import Empty
+    try:
+        return queue.get_nowait()
+    except Empty:
+        return None
+
+
 def _drain(queue, exp_dir: str, saved: List[str],
            ckpt_config: CheckpointConfig,
            history: List[Dict[str, Any]]) -> None:
     """Pull all pending reports; persist rank-0 checkpoints into the
     experiment dir (checkpoint_000N) honoring num_to_keep."""
-    from ..util.queue import Empty
-    while True:
-        try:
-            payload = queue.get_nowait()
-        except Empty:
-            break
-        history.append(payload)
-        src = payload.get("checkpoint_path")
-        if src and os.path.isdir(src):
-            dst = os.path.join(exp_dir,
-                               f"checkpoint_{len(saved):06d}")
+    payload = _next_report(queue)
+    if payload is None:
+        return      # the poll found nothing: no span for an empty turn
+    with tracing.start_span("train::drain"):
+        while payload is not None:
+            _take_report(payload, exp_dir, saved, ckpt_config, history)
+            payload = _next_report(queue)
+
+
+def _take_report(payload: Dict[str, Any], exp_dir: str, saved: List[str],
+                 ckpt_config: CheckpointConfig,
+                 history: List[Dict[str, Any]]) -> None:
+    history.append(payload)
+    if "reported_wall" in payload:
+        telemetry.hist_observe(M_REPORT_LAG,
+                               time.time() - payload["reported_wall"])
+    src = payload.get("checkpoint_path")
+    if src and os.path.isdir(src):
+        dst = os.path.join(exp_dir, f"checkpoint_{len(saved):06d}")
+        with tracing.timed_span("train::persist_checkpoint",
+                                M_CHECKPOINT_PERSIST):
             if os.path.exists(dst):
                 shutil.rmtree(dst)
             shutil.copytree(src, dst)
-            payload["checkpoint_path"] = dst
-            saved.append(dst)
-            keep = ckpt_config.num_to_keep
-            if keep and len(saved) > keep:
+        payload["checkpoint_path"] = dst
+        saved.append(dst)
+        keep = ckpt_config.num_to_keep
+        if keep and len(saved) > keep:
+            with tracing.start_span("train::prune_checkpoints"):
                 for old in saved[:-keep]:
                     shutil.rmtree(old, ignore_errors=True)
-                del saved[:-keep]
+            del saved[:-keep]

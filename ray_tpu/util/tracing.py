@@ -10,23 +10,33 @@ remote execution, and export to the control plane where
 
 Enable with ``init(_system_config={"tracing_enabled": True})`` (or
 ``RTPU_TRACING_ENABLED=1``). Disabled, every hook is a no-op.
+
+One span, two records: in a process that has already imported jax,
+``start_span(name)`` also enters ``jax.profiler.TraceAnnotation("rtpu:" +
+name)``. A TraceMe is inert unless a profiler session is open in that
+process, so whenever an operator has opened a trace of a worker the span
+sits on the profiler's clock, on its own thread's line, beside the
+device's operations — whether or not ``tracing_enabled`` buffers a row.
+Tracing never imports jax itself (the driver stays off the chip).
 """
 
 from __future__ import annotations
 
 import contextlib
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
 
-from .._private import locksan
+from .._private import locksan, telemetry
 from .._private.config import CONFIG
 
 _local = threading.local()
 _buffer: List[dict] = []
 _buffer_lock = locksan.lock("tracing.buffer")
 _MAX_BUFFER = 10_000
+ANNOTATION_PREFIX = "rtpu:"     # a span's name on the profiler's timeline
 
 
 def enabled() -> bool:
@@ -89,15 +99,33 @@ def _new_span(name: str, parent: Optional[Dict[str, str]],
     }
 
 
+def _annotate(name: str):
+    """The span's twin on the profiler's clock, entered; None in a process
+    that has not imported jax."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    if profiler is None:
+        return None
+    note = profiler.TraceAnnotation(ANNOTATION_PREFIX + name)
+    note.__enter__()
+    return note
+
+
 @contextlib.contextmanager
 def start_span(name: str, attributes: Optional[Dict[str, Any]] = None,
                force: bool = False):
     """Open a span as a child of the current context. Yields the span
     dict (mutable: add attributes mid-flight). ``force`` traces even
     when local config has tracing off (used when the caller's spec says
-    the submitting process is tracing)."""
+    the submitting process is tracing). With or without a buffered row,
+    the span is a profiler annotation where jax is loaded."""
+    note = _annotate(name)
     if not (enabled() or force):
-        yield None
+        try:
+            yield None
+        finally:
+            if note is not None:
+                note.__exit__(None, None, None)
         return
     span = _new_span(name, get_current_context(), attributes)
     stack = getattr(_local, "stack", None)
@@ -113,6 +141,23 @@ def start_span(name: str, attributes: Optional[Dict[str, Any]] = None,
         span["end_time"] = time.time()
         stack.pop()
         _record(span)
+        if note is not None:
+            note.__exit__(None, None, None)
+
+
+@contextlib.contextmanager
+def timed_span(name: str, metric: str, tags: tuple = (),
+               attributes: Optional[Dict[str, Any]] = None):
+    """A span that on exit also observes its duration, in seconds, into
+    the telemetry histogram ``metric``: a layer boundary states itself
+    once, and its activations (``_count``) and busy seconds (``_sum``)
+    are counted whether or not tracing is on."""
+    t0 = time.perf_counter()
+    try:
+        with start_span(name, attributes) as span:
+            yield span
+    finally:
+        telemetry.hist_observe(metric, time.perf_counter() - t0, tags)
 
 
 def begin_span(name: str, parent: Optional[Dict[str, str]],
