@@ -76,8 +76,6 @@ class GPTConfig:
     z_loss: float = 1e-4
     # attention kernel: "auto" | "pallas" | "pallas_interpret" | "reference"
     attention_impl: str = "auto"
-    attn_block_q: int = 512
-    attn_block_k: int = 512
 
     @property
     def kv_heads(self) -> int:
@@ -352,8 +350,7 @@ class GPT:
 
             def local(qb, kb, vb):
                 return ring_attention(qb, kb, vb, seq_axis, True, None,
-                                      c.attention_impl, c.attn_block_q,
-                                      c.attn_block_k)
+                                      c.attention_impl)
 
             ot = jax.shard_map(local, mesh=self.mesh,
                                in_specs=(spec_q, spec_kv, spec_kv),
@@ -365,17 +362,14 @@ class GPT:
 
             def local(qb, kb, vb):
                 return dot_product_attention(
-                    qb, kb, vb, causal=True, impl=c.attention_impl,
-                    block_q=c.attn_block_q, block_k=c.attn_block_k)
+                    qb, kb, vb, causal=True, impl=c.attention_impl)
 
             ot = jax.shard_map(local, mesh=self.mesh,
                                in_specs=(spec_q, spec_kv, spec_kv),
                                out_specs=spec_q, check_vma=False)(qt, kt, vt)
         else:
             ot = dot_product_attention(qt, kt, vt, causal=True,
-                                       impl=c.attention_impl,
-                                       block_q=c.attn_block_q,
-                                       block_k=c.attn_block_k)
+                                       impl=c.attention_impl)
         return jnp.transpose(ot, (0, 2, 1, 3))
 
     def _constrain(self, x, *logical):

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .attention import (DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q, NEG_INF, _LANES,
+from .attention import (DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q, NEG_INF,
                         _bwd_pallas, _fwd_pallas)
 
 _FULL = 0   # attend to every key in the block
@@ -82,16 +82,15 @@ def _partial_bwd_reference(q, k, v, do, lse, delta, scale, diag):
 
 
 def _partial_fwd_pallas(q, k, v, scale, diag, block_q, block_k, interpret):
-    out, lse_rep = _fwd_pallas(q, k, v, scale=scale, causal=diag,
-                               block_q=block_q, block_k=block_k,
-                               interpret=interpret)
-    return out.astype(jnp.float32), lse_rep[..., 0]
+    out, lse = _fwd_pallas(q, k, v, scale=scale, causal=diag,
+                           block_q=block_q, block_k=block_k,
+                           interpret=interpret)
+    return out.astype(jnp.float32), lse
 
 
 def _partial_bwd_pallas(q, k, v, do, lse, delta, scale, diag, block_q,
                         block_k, interpret):
-    lse_rep = jnp.broadcast_to(lse[..., None], (*lse.shape, _LANES))
-    return _bwd_pallas(q, k, v, None, lse_rep, do, scale=scale, causal=diag,
+    return _bwd_pallas(q, k, v, None, lse, do, scale=scale, causal=diag,
                        block_q=block_q, block_k=block_k, interpret=interpret,
                        delta=delta, keep_f32=True)
 

@@ -50,16 +50,18 @@ def _on(sharding, tree):
         tree)
 
 
-def test_flash_attention_fwd_bwd_compiles_at_bench_width(v5e):
-    """The three flash kernels at the width every gpt2_medium step runs:
-    [batch 12, 16 heads, seq 1024, head_dim 64] bf16, blocks 512."""
+@pytest.mark.parametrize("shape", [(12, 16, 1024, 64), (16, 25, 1024, 64)],
+                         ids=["gpt2_medium", "gpt2_xl"])
+def test_flash_attention_fwd_bwd_compiles_at_bench_width(v5e, shape):
+    """The three flash kernels at the widths the benchmark's steps run,
+    bf16, at the shipped tile: [batch 12, 16 heads, seq 1024, head_dim 64]
+    (gpt2_medium) and [16, 25, 1024, 64] (gpt2_xl, a chip's share)."""
     from ray_tpu.ops.attention import flash_attention
 
     def loss(q, k, v):
-        out = flash_attention(q, k, v, True, None, 512, 512, False)
-        return out.astype(jnp.float32).sum()
+        return flash_attention(q, k, v).astype(jnp.float32).sum()
 
-    x = jax.ShapeDtypeStruct((12, 16, 1024, 64), jnp.bfloat16,
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
                              sharding=SingleDeviceSharding(v5e.devices[0]))
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         x, x, x).compile()

@@ -12,8 +12,9 @@ import pytest
 from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_tpu.ops.attention import (attention_reference, dot_product_attention,
-                                   flash_attention)
+from ray_tpu.ops.attention import (_k_chunk_bounds, _q_chunk_bounds,
+                                   attention_reference, chunk_classes,
+                                   dot_product_attention, flash_attention)
 from ray_tpu.ops.ring_attention import ring_attention
 
 
@@ -69,6 +70,103 @@ def test_flash_cross_length_causal_alignment():
     ref = attention_reference(q, k, v, causal=True)
     out = FLASH(q, k, v, True)
     np.testing.assert_allclose(out, ref, atol=2e-5)
+
+
+def _fwd_and_grads(fn, q, k, v):
+    def loss(q, k, v):
+        out = fn(q, k, v)
+        return (out.astype(jnp.float32) ** 2).sum(), out
+    (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                         has_aux=True)(q, k, v)
+    return (out, *grads)
+
+
+# name: (_qkv arguments, causal, blocks or None for the shipped default).
+# float32 cases keep the tolerances of the tests above (2e-5 forward, 5e-4
+# gradients); bf16 inputs are held to the float32 reference at 2e-2 of the
+# largest reference value (bf16 keeps 8 bits: 4e-3 relative a rounding,
+# several roundings a path).
+_PARITY_CASES = {
+    # the benchmark cells' shape: one 1024 tile, head width 64, odd heads
+    "causal_1024_d64_odd_heads": (dict(b=1, h=3, hk=3, s=1024), True, None),
+    "bf16_1024_d64": (dict(b=1, h=2, hk=2, s=1024, dtype=jnp.bfloat16),
+                      True, None),
+    "non_causal": (dict(s=256), False, None),
+    "q_shorter_than_k": (dict(s=128, sk=256), True, None),
+    "ragged_300": (dict(s=300), True, (128, 128)),
+    "gqa_4_2_d128": (dict(h=4, hk=2, s=256, d=128), True, None),
+    # several tiles: aligned (diagonal and interior tiles unroll), then
+    # unaligned and ragged (loops over bounds from the program ids)
+    "tiles_aligned": (dict(b=1, s=512), True, (256, 256)),
+    "tiles_aligned_cross_length": (dict(b=1, s=256, sk=512), True,
+                                   (128, 128)),
+    "tiles_unaligned": (dict(b=1, s=512), True, (128, 256)),
+    "tiles_ragged_non_causal": (dict(b=1, s=300), False, (128, 128)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PARITY_CASES))
+def test_flash_fwd_and_grads_match_reference(case):
+    kw, causal, blocks = _PARITY_CASES[case]
+    q, k, v = _qkv(**kw)
+    block_kw = dict(zip(("block_q", "block_k"), blocks)) if blocks else {}
+    got = _fwd_and_grads(
+        lambda q, k, v: flash_attention(q, k, v, causal, interpret=True,
+                                        **block_kw), q, k, v)
+    f32 = lambda x: x.astype(jnp.float32)
+    want = _fwd_and_grads(
+        lambda q, k, v: attention_reference(q, k, v, causal=causal),
+        f32(q), f32(k), f32(v))
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        assert a.dtype == q.dtype
+        if q.dtype == jnp.bfloat16:
+            atol = 2e-2 * float(jnp.max(jnp.abs(b)))
+        else:
+            atol = 2e-5 if name == "out" else 5e-4
+        np.testing.assert_allclose(np.asarray(f32(a)), np.asarray(b),
+                                   atol=atol, err_msg=f"{case}: {name}")
+
+
+@pytest.mark.parametrize("tile,sub,chunk,computed,interior,edge", [
+    (512, 512, 512, 0.75, 1, 2),      # before PR 26: whole 512 tiles
+    (1024, 128, 128, 0.5625, 28, 8),  # dkv's rectangles (transposed)
+    (1024, 256, 256, 0.625, 6, 4),
+    (1024, 512, 512, 0.75, 1, 2),     # forward and dq as shipped
+])
+def test_chunk_classes_of_the_benchmark_cells(tile, sub, chunk, computed,
+                                              interior, edge):
+    got = chunk_classes(1024, 1024, True, tile=tile, sub=sub, chunk=chunk)
+    assert got["computed_share"] == computed
+    assert (got["interior"], got["edge"]) == (interior, edge)
+    assert got["dead"] + interior + edge == (1024 // sub) * (1024 // chunk)
+    full = chunk_classes(1024, 1024, False, tile=tile, sub=sub, chunk=chunk)
+    assert full["computed_share"] == 1.0 and full["edge"] == 0
+
+
+@pytest.mark.parametrize("q_len,k_len,rel", [
+    (1024, 1024, 0), (512, 1024, 512), (1024, 1024, None), (300, 300, 0)])
+def test_chunk_bounds_agree_between_kernels(q_len, k_len, rel):
+    """The forward / dq walk (queries fixed, key chunks) and the dkv walk
+    (keys fixed, query chunks) give every square rectangle one class."""
+    size = 128
+    nq, nk = -(-q_len // size), -(-k_len // size)
+
+    def cls(bounds, c):
+        lo, mid, hi, end = bounds
+        return ("dead" if c < lo or c >= end else
+                "interior" if mid <= c < hi else "edge")
+
+    for qi in range(nq):
+        interior_end, live_end = _k_chunk_bounds(qi * size, size, rel,
+                                                 k_len, chunk=size)
+        for ki in range(nk):
+            by_q = ("interior" if ki < interior_end else
+                    "edge" if ki < live_end else "dead")
+            if (qi + 1) * size > q_len and by_q == "interior":
+                by_q = "edge"      # dkv must mask padded queries
+            bounds = _q_chunk_bounds(ki * size, size, rel, q_len, k_len,
+                                     chunk=size)
+            assert cls(bounds, qi) == by_q, (qi, ki)
 
 
 def test_dispatch_validates_impl():

@@ -283,8 +283,7 @@ def _tiny_step(attention_impl):
                        max_seq_len=256, activation="gelu",
                        norm="layernorm", positions="learned",
                        tie_embeddings=True, remat=True,
-                       remat_policy="dots", attention_impl=attention_impl,
-                       attn_block_q=128, attn_block_k=128)
+                       remat_policy="dots", attention_impl=attention_impl)
     model, optimizer = GPT(config), make_optimizer()
     state = jax.eval_shape(
         lambda: init_train_state(model, optimizer, jax.random.PRNGKey(0)))
