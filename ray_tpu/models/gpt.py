@@ -1,10 +1,11 @@
 """Decoder-only transformer, TPU-first.
 
 One implementation covers the GPT-2 family (learned positions, GELU MLP,
-LayerNorm) and the Llama family (RoPE, SwiGLU, RMSNorm, GQA) through
-`GPTConfig` switches — the reference ships these as external torch models
-driven by Ray Train (`release/train_tests`, SURVEY §6 north-star configs);
-here the model itself is framework-native.
+LayerNorm), the Llama family (RoPE, SwiGLU, RMSNorm, GQA) and OLMoE's
+sparse-expert block (QK-norm, dropless top-k experts, `models/moe.py`)
+through `GPTConfig` switches — the reference ships these as external torch
+models driven by Ray Train (`release/train_tests`, SURVEY §6 north-star
+configs); here the model itself is framework-native.
 
 TPU-first choices:
   * scan-over-layers with stacked params — one compiled block body,
@@ -53,6 +54,11 @@ class GPTConfig:
     positions: str = "learned"        # "learned" | "rope"
     rope_theta: float = 10000.0
     tie_embeddings: bool = True
+    # None -> the family's usual epsilon (1e-6 rmsnorm, 1e-5 layernorm)
+    norm_eps: Optional[float] = None
+    # RMSNorm with a learned scale on the whole projected q and k (all heads
+    # together), before RoPE — OLMoE / OLMo-2
+    qk_norm: bool = False
     # pipeline parallelism: microbatches per global batch (0 -> = pp).
     # Stages come from the mesh's pp axis; GSPMD-style schedule (scan
     # over steps, stage-sharded rolling buffer -> collective-permute).
@@ -61,8 +67,11 @@ class GPTConfig:
     # SURVEY §2.4 — first-class here)
     n_experts: int = 0
     moe_top_k: int = 2
-    moe_capacity_factor: float = 1.25
-    moe_aux_coeff: float = 0.01
+    # rescale a token's top-k router weights to sum to 1 (Mixtral) or leave
+    # them as the softmax gave them (OLMoE: `norm_topk_prob: false`)
+    moe_norm_topk_prob: bool = True
+    moe_aux_coeff: float = 0.01       # load-balancing loss, all k choices
+    moe_router_z_coeff: float = 0.0   # mean squared logsumexp of the router
     # numerics
     dtype: Any = jnp.bfloat16         # activation dtype
     param_dtype: Any = jnp.float32
@@ -80,6 +89,12 @@ class GPTConfig:
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads if self.n_kv_heads is not None else self.n_heads
+
+    @property
+    def eps(self) -> float:
+        if self.norm_eps is not None:
+            return self.norm_eps
+        return 1e-6 if self.norm == "rmsnorm" else 1e-5
 
     @property
     def head_dim(self) -> int:
@@ -206,6 +221,9 @@ class GPT:
             "wv": _normal(keys[2], (L, d, hk, hd), std, pd),
             "wo": _normal(keys[3], (L, h, hd, d), resid_std, pd),
         }
+        if c.qk_norm:
+            blocks["q_norm"] = ones((L, h, hd))
+            blocks["k_norm"] = ones((L, hk, hd))
         if c.n_experts > 0:
             E = c.n_experts
             blocks["router"] = _normal(keys[4], (L, d, E), std, pd)
@@ -253,6 +271,9 @@ class GPT:
             "wv": ("layers", "embed", "kv_heads", "head_dim"),
             "wo": ("layers", "heads", "head_dim", "embed"),
         }
+        if c.qk_norm:
+            blocks["q_norm"] = ("layers", "heads", "head_dim")
+            blocks["k_norm"] = ("layers", "kv_heads", "head_dim")
         if c.n_experts > 0:
             blocks["router"] = ("layers", "embed", None)
             blocks["w_up"] = ("layers", "expert", "embed", "mlp")
@@ -287,15 +308,24 @@ class GPT:
         c = self.config
         xf = x.astype(jnp.float32)
         if c.norm == "rmsnorm":
-            xf = xf * lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + 1e-6)
+            xf = xf * lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + c.eps)
             return (xf * scale.astype(jnp.float32)).astype(c.dtype)
         mean = jnp.mean(xf, -1, keepdims=True)
         var = jnp.var(xf, -1, keepdims=True)
-        xf = (xf - mean) * lax.rsqrt(var + 1e-5)
+        xf = (xf - mean) * lax.rsqrt(var + c.eps)
         out = xf * scale.astype(jnp.float32)
         if bias is not None:
             out = out + bias.astype(jnp.float32)
         return out.astype(c.dtype)
+
+    def _qk_norm(self, x, scale):
+        """RMSNorm over all heads of a projection together. x: [B, S, H, Dh],
+        scale: [H, Dh]."""
+        c = self.config
+        xf = x.astype(jnp.float32)
+        xf = xf * lax.rsqrt(jnp.mean(xf * xf, (-2, -1), keepdims=True)
+                            + c.eps)
+        return (xf * scale.astype(jnp.float32)).astype(c.dtype)
 
     def _rope(self, x, positions):
         """x: [B, S, H, D_h]; positions: [B, S]."""
@@ -388,6 +418,9 @@ class GPT:
             q = jnp.einsum("bsd,dhk->bshk", h, w["wq"].astype(dt))
             k = jnp.einsum("bsd,dhk->bshk", h, w["wk"].astype(dt))
             v = jnp.einsum("bsd,dhk->bshk", h, w["wv"].astype(dt))
+            if c.qk_norm:
+                q = self._qk_norm(q, w["q_norm"])
+                k = self._qk_norm(k, w["k_norm"])
             if c.positions == "rope":
                 q = self._rope(q, positions)
                 k = self._rope(k, positions)
@@ -404,14 +437,13 @@ class GPT:
 
         with jax.named_scope("mlp"):
             h = self._norm(x, w["norm2"], w.get("bias2"))
-            aux = jnp.zeros((), jnp.float32)
+            aux = {}
             if c.n_experts > 0:
                 from .moe import moe_ffn
-                down, moe_metrics = moe_ffn(
+                down, aux = moe_ffn(
                     h, w["router"], w["w_up"], w["w_gate"], w["w_down"],
                     top_k=c.moe_top_k,
-                    capacity_factor=c.moe_capacity_factor, dtype=dt)
-                aux = moe_metrics["moe_aux_loss"]
+                    norm_topk_prob=c.moe_norm_topk_prob, dtype=dt)
             else:
                 up = jnp.einsum("bsd,df->bsf", h, w["w_up"].astype(dt))
                 if c.activation == "swiglu":
@@ -437,7 +469,10 @@ class GPT:
 
     def forward_with_aux(self, params: Params, tokens: jax.Array,
                          positions: Optional[jax.Array] = None):
-        """Returns (logits, aux_losses dict) — MoE load-balance terms."""
+        """Returns (logits, aux): with experts, the router's two losses as
+        means over the layers and, per layer, `moe_expert_tokens`
+        [n_layers, n_experts] and `moe_expert_choice` [n_layers, tokens,
+        top_k]; without, an empty dict."""
         c = self.config
         if positions is None:
             positions = jnp.broadcast_to(
@@ -480,7 +515,7 @@ class GPT:
         if self.pp_stages > 1:
             x = self._pipeline_blocks(block_fn, params["blocks"], x,
                                       positions)
-            aux_per_layer = jnp.zeros((1,), jnp.float32)
+            aux_per_layer = {}
         else:
             def scan_body(x, layer_w):
                 x, aux = block_fn(x, positions, layer_w)
@@ -500,7 +535,10 @@ class GPT:
             logits = self._constrain(logits, "act_batch", "act_seq",
                                      "act_vocab")
             logits = logits.astype(jnp.float32)
-        return logits, {"moe_aux_loss": aux_per_layer.mean()}
+        # the scan stacked each layer's facts: a loss term is [L] now
+        aux = {k: v.mean() if v.ndim == 1 else v
+               for k, v in aux_per_layer.items()}
+        return logits, aux
 
     def _pipeline_blocks(self, block_fn, blocks: Params, x: jax.Array,
                          positions: jax.Array) -> jax.Array:
@@ -598,7 +636,15 @@ class GPT:
             "tokens": mask.sum(),
         }
         if c.n_experts > 0:
-            loss = loss + c.moe_aux_coeff * aux["moe_aux_loss"]
-            metrics["moe_aux_loss"] = aux["moe_aux_loss"]
-            metrics["loss"] = loss
+            loss = (loss + c.moe_aux_coeff * aux["moe_aux_loss"]
+                    + c.moe_router_z_coeff * aux["moe_router_z"])
+            counts = aux["moe_expert_tokens"].astype(jnp.float32)   # [L, E]
+            metrics.update(
+                loss=loss, ce_loss=metrics["ppl_log"],
+                moe_aux_loss=aux["moe_aux_loss"],
+                moe_router_z=aux["moe_router_z"],
+                # over the layers: each layer's sum is tokens x top-k
+                moe_expert_tokens=aux["moe_expert_tokens"].sum(0),
+                moe_load_max_over_mean=(counts.max(-1)
+                                        / counts.mean(-1)).mean())
         return loss, metrics

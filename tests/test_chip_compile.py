@@ -97,6 +97,57 @@ def test_gpt2_medium_train_step_fits_one_chip(v5e):
     assert compiled.memory_analysis().temp_size_in_bytes < HBM_BYTES
 
 
+def _olmoe_config():
+    import json
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks/configs/olmoe_1b_7b.json")
+    with open(path) as f:
+        return json.load(f)
+
+
+def _olmoe_step(config, batch):
+    """The one-layer OLMoE step of the benchmark's `olmoe_1b_7b`
+    configuration, as its cell builds it, at `batch` rows of 4,096."""
+    from ray_tpu.models import (GPT, init_train_state, make_optimizer,
+                                make_train_step)
+    from ray_tpu.models.gpt import GPTConfig
+
+    kw = dict(config["model"], attention_impl="pallas")
+    kw["dtype"] = getattr(jnp, kw["dtype"])
+    kw["param_dtype"] = getattr(jnp, kw["param_dtype"])
+    model = GPT(GPTConfig(**kw))
+    opt = make_optimizer(**config["optimizer"])
+    state = jax.eval_shape(
+        lambda: init_train_state(model, opt, jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((batch, kw["max_seq_len"]), jnp.int32)
+    return make_train_step(model, opt), state, tokens
+
+
+def test_olmoe_one_layer_train_step_fills_one_chip(v5e):
+    """`olmoe-steady`'s step: one OLMoE layer at published widths with all
+    64 experts (dropless, by sort and grouped matmul), embedding and untied
+    head, float32 AdamW state, at the configuration's `batch_per_chip` rows
+    of 4,096 tokens. It fits, and one row more does not: this is what fixes
+    `batch_per_chip`."""
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    config = _olmoe_config()
+    rows = config["batch_per_chip"]
+    step, state, tokens = _olmoe_step(config, rows)
+    assert tokens.shape == (rows, 4096)
+    compiled = step.lower(_on(one_chip, state),
+                          {"tokens": _on(one_chip, tokens)}).compile()
+    # the three flash kernels, and the grouped matmuls are kernels too
+    assert compiled.as_text().count("tpu_custom_call") > 3
+    mem = compiled.memory_analysis()
+    # the donated state is aliased to the new one: 12 bytes a parameter
+    assert mem.alias_size_in_bytes > 7.4e9
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
+    step, state, tokens = _olmoe_step(config, rows + 1)
+    with pytest.raises(Exception, match="(?i)ran out of memory|exhausted"):
+        step.lower(_on(one_chip, state),
+                   {"tokens": _on(one_chip, tokens)}).compile()
+
+
 @pytest.mark.slow   # 12 s here, and tier-1 runs close to its time limit
 def test_gpt2_medium_fsdp4_train_step_compiles_for_the_host(v5e):
     """chip_smoke.py --chips 4: the same model on an fsdp=4 mesh built by

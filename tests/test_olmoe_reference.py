@@ -1,0 +1,164 @@
+"""The OLMoE-shaped model (`ray_tpu.models.GPT` with QK-norm, RMSNorm eps from
+the configuration, dropless top-k experts, the two router losses) against the
+plain reference `benchmarks/reference/olmoe.py`, in float32 on the CPU, on
+the same seeded weights and rows: logits, the three loss terms, every
+parameter's gradient, every (token, expert) choice.
+
+Tolerances: both sides compute in float32 and differ only in the order of
+their sums (the program sorts rows by expert and multiplies group by group;
+the reference multiplies every token by every expert and masks), so what is
+allowed is float32 rounding through two layers: 1e-4 on logits of size ~1,
+1e-5 on the loss terms, and on gradients 2e-4 of each leaf's largest entry.
+A path that dropped a token, normalised the top-k weights, counted only the
+first choice in the balance loss, or took the wrong epsilon is off by 1e-2
+or more.
+"""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmarks.reference import olmoe, olmoe_glue          # noqa: E402
+from ray_tpu.models import GPT                               # noqa: E402
+from ray_tpu.models.gpt import GPTConfig                     # noqa: E402
+
+AUX, ROUTER_Z = 0.01, 0.001
+SEQ = 128
+
+
+def _config(n_experts, top_k, **kw):
+    base = dict(vocab_size=256, n_layers=2, d_model=64, n_heads=4, d_ff=32,
+                max_seq_len=SEQ, activation="swiglu", norm="rmsnorm",
+                positions="rope", tie_embeddings=False, norm_eps=1e-5,
+                qk_norm=True, n_experts=n_experts, moe_top_k=top_k,
+                moe_norm_topk_prob=False, moe_aux_coeff=AUX,
+                moe_router_z_coeff=ROUTER_Z, z_loss=0.0, dtype=jnp.float32,
+                remat=False, attention_impl="reference")
+    base.update(kw)
+    return GPTConfig(**base)
+
+
+def _hparams(config):
+    return {"num_attention_heads": config.n_heads,
+            "num_key_value_heads": config.kv_heads,
+            "num_experts_per_tok": config.moe_top_k,
+            "norm_topk_prob": config.moe_norm_topk_prob,
+            "rms_norm_eps": config.norm_eps, "rope_theta": config.rope_theta}
+
+
+def _params(model, seed):
+    params = model.init(jax.random.PRNGKey(seed))
+    # scales that are not one, or a dropped norm scale would pass; a router
+    # wide enough that the choices are not near-ties
+    blocks = params["blocks"]
+    keys = jax.random.split(jax.random.PRNGKey(seed + 100), 6)
+    for k, name in zip(keys, ("norm1", "norm2", "q_norm", "k_norm")):
+        blocks[name] = blocks[name] + 0.2 * jax.random.normal(
+            k, blocks[name].shape)
+    params["norm_f"] = params["norm_f"] + 0.2 * jax.random.normal(
+        keys[4], params["norm_f"].shape)
+    blocks["router"] = blocks["router"] * 20.0
+    for name in ("w_up", "w_gate", "w_down"):
+        blocks[name] = blocks[name] * 10.0
+    return params
+
+
+def _reference(params, tokens, config):
+    top, layers = olmoe_glue.reference_weights(params, None, jax.devices())
+    return olmoe.loss_terms(tokens, top, layers, _hparams(config))
+
+
+def _reference_total(params, tokens, config):
+    terms = _reference(params, tokens, config)
+    return (terms["ce"] + AUX * terms["load_balance"]
+            + ROUTER_Z * terms["router_z"])
+
+
+def _compare(config, params, tokens):
+    model = GPT(config)
+    with jax.default_matmul_precision("highest"):
+        logits, aux = model.forward_with_aux(params, tokens)
+        (total, metrics), grads = jax.value_and_grad(
+            model.loss, has_aux=True)(params, {"tokens": tokens})
+        ref = _reference(params, tokens, config)
+        ref_total, ref_grads = jax.value_and_grad(_reference_total)(
+            params, tokens, config)
+    n_tokens = tokens.size
+    # routing: the same choices, the same counts, nothing dropped
+    assert np.array_equal(np.sort(np.asarray(aux["moe_expert_choice"]), -1),
+                          np.sort(np.asarray(ref["chosen"]), -1))
+    assert np.array_equal(np.asarray(aux["moe_expert_tokens"]),
+                          np.asarray(ref["counts"]))
+    assert int(metrics["moe_expert_tokens"].sum()) == (
+        n_tokens * config.moe_top_k * config.n_layers)
+    counts = np.asarray(ref["counts"], np.float64)
+    assert float(metrics["moe_load_max_over_mean"]) == pytest.approx(
+        (counts.max(-1) / counts.mean(-1)).mean(), rel=1e-5)
+    # values
+    assert float(jnp.max(jnp.abs(logits - ref["logits"]))) < 1e-4
+    assert abs(float(metrics["ce_loss"]) - float(ref["ce"])) < 1e-5
+    assert abs(float(metrics["moe_aux_loss"])
+               - float(ref["load_balance"])) < 1e-5
+    assert float(metrics["moe_router_z"]) == pytest.approx(
+        float(ref["router_z"]), rel=1e-5)
+    assert abs(float(total) - float(ref_total)) < 1e-5
+    assert float(metrics["loss"]) == float(total)
+    # every parameter's gradient
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    ref_flat = dict(jax.tree_util.tree_flatten_with_path(ref_grads)[0])
+    for path, g in flat:
+        want = ref_flat[path]
+        scale = float(jnp.max(jnp.abs(want)))
+        assert scale > 0, jax.tree_util.keystr(path)
+        assert float(jnp.max(jnp.abs(g - want))) <= 2e-4 * scale, (
+            jax.tree_util.keystr(path))
+    return ref, metrics
+
+
+@pytest.mark.parametrize("n_experts,top_k", [(8, 2), (64, 8), (4, 1)],
+                         ids=["8x2", "olmoe_ratio_64x8", "switch_4x1"])
+def test_system_agrees_with_the_plain_reference(n_experts, top_k):
+    config = _config(n_experts, top_k)
+    params = _params(GPT(config), seed=3)
+    tokens = jax.random.randint(jax.random.PRNGKey(4), (2, SEQ), 0, 256)
+    ref, metrics = _compare(config, params, tokens)
+    # the balance loss counts all k choices: k at uniform routing
+    assert 0.8 * top_k < float(ref["load_balance"]) < 2.5 * top_k
+
+
+def test_normalised_top_k_weights_follow_the_switch():
+    """`norm_topk_prob: true` (Mixtral's convention) is the same algorithm
+    with the weights rescaled; the reference has the same switch."""
+    config = _config(8, 2, moe_norm_topk_prob=True)
+    params = _params(GPT(config), seed=5)
+    tokens = jax.random.randint(jax.random.PRNGKey(6), (2, SEQ), 0, 256)
+    _compare(config, params, tokens)
+
+
+def test_one_expert_takes_most_tokens_and_one_takes_none():
+    """A router pushed so that expert 0 is chosen by nearly every token and
+    expert 1 by none: a capacity of 1.25 x the mean would have dropped more
+    than half of expert 0's tokens. The dropless path agrees with the
+    reference as closely as under balanced routing, and an empty group is an
+    ordinary input."""
+    config = _config(8, 2)
+    params = _params(GPT(config), seed=7)
+    # every token's hidden state gets a common component c; the router reads
+    # it with a large positive weight for expert 0, a negative one for 1
+    c = jax.random.normal(jax.random.PRNGKey(8), (config.d_model,))
+    params["tok_embed"] = params["tok_embed"] + 0.5 * c
+    router = params["blocks"]["router"]
+    router = router.at[:, :, 0].add(0.2 * c).at[:, :, 1].add(-0.2 * c)
+    params["blocks"]["router"] = router
+    tokens = jax.random.randint(jax.random.PRNGKey(9), (2, SEQ), 0, 256)
+    ref, metrics = _compare(config, params, tokens)
+    counts = np.asarray(ref["counts"])
+    assert (counts[:, 0] > tokens.size // 2).all(), counts
+    assert (counts[:, 1] == 0).all(), counts
+    assert float(metrics["moe_load_max_over_mean"]) > 3.0
