@@ -47,7 +47,8 @@ def main():
     from ray_tpu.parallel.mesh import MeshSpec, build_mesh
 
     n_dev = len(jax.devices())
-    # "dots" remat saves matmul outputs (recompute only elementwise);
+    # "dots" remat saves q, k, v, the MLP's up projection and the flash
+    # kernel's output (recompute the out-projection and the elementwise);
     # b12/chip is the largest batch that fits HBM with the saved
     # activations (b16: "Used 17.42G of 15.75G hbm"). Batch scales with
     # device count so act_batch stays shardable over dp.

@@ -29,14 +29,22 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
-from ..ops.attention import attention_reference, dot_product_attention
+from ..ops.attention import (FLASH_RESIDUAL_NAMES, attention_reference,
+                             dot_product_attention)
 from ..ops.ring_attention import ring_attention
 from ..parallel.sharding import (DEFAULT_RULES, ShardingRules,
                                  with_logical_constraint)
 
 Params = Dict[str, Any]
+
+# What `remat_policy="dots"` keeps of a block for the backward pass
+# (`GPTConfig.remat_policy`): the names `_block` gives its projections, and
+# the flash kernel's output and log-sum-exp.
+_DOTS_SAVED_NAMES = ("attn_q", "attn_k", "attn_v", "mlp_up", "mlp_gate",
+                     *FLASH_RESIDUAL_NAMES)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -77,10 +85,19 @@ class GPTConfig:
     param_dtype: Any = jnp.float32
     # training
     remat: bool = True
-    # what the per-block checkpoint saves for backward: "full" recomputes
-    # everything (lowest memory, ~4/3x flops); "dots" saves matmul
-    # outputs and recomputes only cheap elementwise ops (the usual MFU
-    # sweet spot when HBM allows)
+    # what the per-block checkpoint (`remat=True`) saves for the backward
+    # pass. "full" saves the block's input alone and runs the whole block
+    # again, flash forward kernel included (lowest memory, ~4/3x flops).
+    # "dots" saves what costs more to recompute than to keep: q, k, v, the
+    # MLP's up (and gate) projection, and the flash kernel's output and
+    # log-sum-exp (B*S*D*2 bytes a layer, lane-dense), so a block runs three
+    # kernel calls and not four. It recomputes the out-projection (its
+    # output is as large as the kernel's and a d x d matmul is cheaper than
+    # a second forward kernel; both do not fit beside 12 x 1024 rows of
+    # GPT-2-medium on a v5e) and the elementwise rest: norms, RoPE,
+    # activations, casts. The batched and grouped matmuls of an expert
+    # block are not saved. `remat=False` saves whatever autodiff needs and
+    # recomputes nothing.
     remat_policy: str = "full"
     z_loss: float = 1e-4
     # attention kernel: "auto" | "pallas" | "pallas_interpret" | "reference"
@@ -418,6 +435,9 @@ class GPT:
             q = jnp.einsum("bsd,dhk->bshk", h, w["wq"].astype(dt))
             k = jnp.einsum("bsd,dhk->bshk", h, w["wk"].astype(dt))
             v = jnp.einsum("bsd,dhk->bshk", h, w["wv"].astype(dt))
+            q = checkpoint_name(q, "attn_q")
+            k = checkpoint_name(k, "attn_k")
+            v = checkpoint_name(v, "attn_v")
             if c.qk_norm:
                 q = self._qk_norm(q, w["q_norm"])
                 k = self._qk_norm(k, w["k_norm"])
@@ -446,9 +466,11 @@ class GPT:
                     norm_topk_prob=c.moe_norm_topk_prob, dtype=dt)
             else:
                 up = jnp.einsum("bsd,df->bsf", h, w["w_up"].astype(dt))
+                up = checkpoint_name(up, "mlp_up")
                 if c.activation == "swiglu":
                     gate = jnp.einsum("bsd,df->bsf", h,
                                       w["w_gate"].astype(dt))
+                    gate = checkpoint_name(gate, "mlp_gate")
                     act = jax.nn.silu(gate) * up
                 else:
                     act = jax.nn.gelu(up, approximate=True)
@@ -499,10 +521,10 @@ class GPT:
 
         block_fn = self._block
         if c.remat:
+            cp = jax.checkpoint_policies
             policies = {
-                "full": jax.checkpoint_policies.nothing_saveable,
-                "dots":
-                    jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+                "full": cp.nothing_saveable,
+                "dots": cp.save_only_these_names(*_DOTS_SAVED_NAMES),
             }
             if c.remat_policy not in policies:
                 raise ValueError(

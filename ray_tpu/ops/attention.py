@@ -47,6 +47,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -344,7 +345,7 @@ class _Tiling:
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                *, scale, t, sub, chunk):
+                *, scale, t, sub, chunk, transposed_out):
     """One (q tile, k tile) step of the forward pass.
 
     Scores are laid out [keys, queries]. Each `sub` queries of the q tile
@@ -355,7 +356,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     the select after the exp exists only where a query can have no key at
     all. q is scaled once per sub-block. Matmul operands are in the inputs'
     dtype (p cast to it); scores, exp, m, l, the accumulator and lse are
-    float32.
+    float32. With `transposed_out` the output block is written as it was
+    accumulated, [head_dim, queries].
     """
     qb, kb = t.ids(2, 3)
     q_valid, k_valid = t.valid(qb, kb)
@@ -404,12 +406,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     @_when(kb == t.nk - 1)
     def _finalize():
         l = jnp.where(l_ref[:] == 0.0, 1.0, l_ref[:])
-        o_ref[0, 0] = (acc_ref[:] / l).T.astype(o_ref.dtype)
+        o = acc_ref[:] / l                  # [head_dim, block_q]
+        o_ref[0, 0] = (o if transposed_out else o.T).astype(o_ref.dtype)
         lse_ref[0, 0] = m_ref[:] + jnp.log(l)
 
 
-def _fwd_pallas(q, k, v, *, scale, causal, block_q, block_k, interpret):
-    """Returns (out [B, H, S, D] in q's dtype, lse [B, H, S] float32)."""
+def _fwd_pallas(q, k, v, *, scale, causal, block_q, block_k, interpret,
+                transposed_out=False):
+    """Returns (out [B, H, S, D] in q's dtype, lse [B, H, S] float32); with
+    `transposed_out`, out is [B, H, D, S]."""
     batch, num_q_heads, q_len, head_dim = q.shape
     num_kv_heads, k_len = k.shape[1], k.shape[2]
     group = num_q_heads // num_kv_heads
@@ -422,18 +427,23 @@ def _fwd_pallas(q, k, v, *, scale, causal, block_q, block_k, interpret):
         (1, 1, t.block_k, head_dim),
         lambda b, h, i, j: (b, h // group,
                             jnp.minimum(j, t.last_live_k(i)), 0))
+    out_spec, out_shape = q_spec, q.shape
+    if transposed_out:
+        out_spec = pl.BlockSpec((1, 1, head_dim, t.block_q),
+                                lambda b, h, i, j: (b, h, 0, i))
+        out_shape = (batch, num_q_heads, head_dim, q_len)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, t=t, sub=sub,
-                          chunk=chunk),
+                          chunk=chunk, transposed_out=transposed_out),
         grid=(batch, num_q_heads, t.nq, t.nk),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[
-            q_spec,
+            out_spec,
             pl.BlockSpec((1, 1, 1, t.block_q),
                          lambda b, h, i, j: (b, h, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct(out_shape, q.dtype),
             jax.ShapeDtypeStruct((batch, num_q_heads, 1, q_len),
                                  jnp.float32),
         ],
@@ -658,20 +668,52 @@ def flash_attention(q, k, v, causal: bool = True,
     return out
 
 
+# Names of the forward kernel's two results as `jax.checkpoint` sees them. A
+# policy that lists them (`GPT`'s "dots") keeps them for the backward pass,
+# which then does not run the forward kernel a second time; under any other
+# policy, and outside `jax.checkpoint`, a name does nothing.
+FLASH_RESIDUAL_NAMES = ("flash_out", "flash_lse")
+
+
+def _narrow(q):
+    """Head width that does not fill the 128 lanes of a tile."""
+    return q.shape[-1] % 128 != 0
+
+
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
+    """The residual that carries the output is lane-dense. An array whose
+    minor dimension is narrower than the 128 lanes of a tile is stored
+    padded to them (head width 64: twice its size, as a saved activation and
+    in every copy of it), so at such a width the kernel writes the output as
+    it accumulated it, [B, H, D, S], and that is what is kept. The primal
+    result is derived from the named value: were it a side copy, a
+    checkpoint that saves the name would still rerun the kernel for whoever
+    reads the result."""
     scale_val = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    transposed = _narrow(q)
     out, lse = _fwd_pallas(q, k, v, scale=scale_val, causal=causal,
                            block_q=block_q, block_k=block_k,
-                           interpret=interpret)
-    return out, (q, k, v, out, lse)
+                           interpret=interpret, transposed_out=transposed)
+    out = checkpoint_name(out, FLASH_RESIDUAL_NAMES[0])
+    lse = checkpoint_name(lse, FLASH_RESIDUAL_NAMES[1])
+    primal = jnp.swapaxes(out, 2, 3) if transposed else out
+    return primal, (q, k, v, out, lse)
 
 
 def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
     q, k, v, out, lse = res
     scale_val = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    delta = None
+    if _narrow(q):
+        # the kernels want the output for delta = rowsum(dO * O) alone: taken
+        # from the transposed output it costs no copy back to [B, H, S, D]
+        delta = jnp.sum(jnp.swapaxes(g, 2, 3).astype(jnp.float32)
+                        * out.astype(jnp.float32), axis=2)
+        out = None
     dq, dk, dv = _bwd_pallas(q, k, v, out, lse, g, scale=scale_val,
                              causal=causal, block_q=block_q,
-                             block_k=block_k, interpret=interpret)
+                             block_k=block_k, interpret=interpret,
+                             delta=delta)
     return dq, dk, dv
 
 

@@ -68,13 +68,21 @@ def test_flash_attention_fwd_bwd_compiles_at_bench_width(v5e, shape):
     assert compiled.as_text().count("tpu_custom_call") >= 3
 
 
-def _gpt2_medium_step(mesh, batch):
+def _kernel_calls(compiled, name=""):
+    """The compiled program's Pallas calls whose `pallas_call` name starts
+    with `name`: a scanned block's calls count once each."""
+    return [line for line in compiled.as_text().splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line
+            and f"/{name}" in line]
+
+
+def _gpt2_medium_step(mesh, batch, remat_policy="dots"):
     from ray_tpu.models import (GPT, gpt2_medium, init_train_state,
                                 make_optimizer, make_train_step)
 
     # "auto" asks jax.default_backend(), which is the CPU here: name the
     # kernel, as the step on the chip resolves it
-    cfg = gpt2_medium(max_seq_len=1024, remat_policy="dots",
+    cfg = gpt2_medium(max_seq_len=1024, remat_policy=remat_policy,
                       attention_impl="pallas")
     model = GPT(cfg, mesh=mesh) if mesh is not None else GPT(cfg)
     opt = make_optimizer()
@@ -84,17 +92,29 @@ def _gpt2_medium_step(mesh, batch):
     return model, opt, make_train_step(model, opt, mesh=mesh), state, tokens
 
 
-def test_gpt2_medium_train_step_fits_one_chip(v5e):
+@pytest.mark.parametrize("remat_policy,kernel_calls",
+                         [("dots", 3), ("full", 4)])
+def test_gpt2_medium_train_step_fits_one_chip(v5e, remat_policy,
+                                              kernel_calls):
     """chip_smoke.py's and bench.py's step: batch 12, "dots" remat. The
-    compiler refuses a program that exceeds HBM (batch 16 does)."""
+    compiler refuses a program that exceeds HBM (batch 16 does). "dots"
+    saves the flash kernel's output, so a block runs the forward kernel
+    once, then dq and dkv; "full" runs the forward kernel again in the
+    backward pass. Nor may the compiler make room by recomputing on its
+    own: with the kernel's and the out-projection's outputs both saved it
+    ran the logits matmul twice (7 ms a step on the chip, PERF.md PR 29)."""
     one_chip = SingleDeviceSharding(v5e.devices[0])
-    _, _, step, state, tokens = _gpt2_medium_step(None, batch=12)
+    _, _, step, state, tokens = _gpt2_medium_step(None, 12, remat_policy)
     compiled = step.lower(_on(one_chip, state),
                           {"tokens": _on(one_chip, tokens)}).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert len(_kernel_calls(compiled)) == kernel_calls
+    assert len(_kernel_calls(compiled, "flash_fwd")) == kernel_calls - 2
+    assert ".remat" not in compiled.as_text()
     # compiling at all means it fits; the donated state is aliased to the
-    # new one, so what must fit beside it is the temporaries
-    assert compiled.memory_analysis().temp_size_in_bytes < HBM_BYTES
+    # new one, so what must fit beside it is the temporaries: 14.37 GiB
+    # under "dots", with the kernel's output saved lane-dense, [B, H, 64, S]
+    # (as [B, H, S, 64] it is padded to 128 lanes, twice the size)
+    assert compiled.memory_analysis().temp_size_in_bytes < 15.0 * 2 ** 30
 
 
 def _olmoe_config():
@@ -136,8 +156,10 @@ def test_olmoe_one_layer_train_step_fills_one_chip(v5e):
     assert tokens.shape == (rows, 4096)
     compiled = step.lower(_on(one_chip, state),
                           {"tokens": _on(one_chip, tokens)}).compile()
-    # the three flash kernels, and the grouped matmuls are kernels too
-    assert compiled.as_text().count("tpu_custom_call") > 3
+    # the three flash kernels, once each (no remat: nothing is run twice),
+    # and the grouped matmuls are kernels too
+    assert len(_kernel_calls(compiled, "flash_")) == 3
+    assert len(_kernel_calls(compiled)) > 3
     mem = compiled.memory_analysis()
     # the donated state is aliased to the new one: 12 bytes a parameter
     assert mem.alias_size_in_bytes > 7.4e9
@@ -161,8 +183,9 @@ def test_gpt2_medium_fsdp4_train_step_compiles_for_the_host(v5e):
     # the step's own in_shardings place the state
     compiled = step.lower(
         state, {"tokens": _on(batch_shardings(mesh), tokens)}).compile()
-    text = compiled.as_text()
-    assert "tpu_custom_call" in text and "all-gather" in text
+    # "dots" through the shard_map branch of GPT._attention: fwd, dq, dkv
+    assert len(_kernel_calls(compiled)) == 3
+    assert "all-gather" in compiled.as_text()
     total = sum(np.prod(x.shape) * x.dtype.itemsize
                 for x in jax.tree_util.tree_leaves(state))
     per_chip = compiled.memory_analysis().argument_size_in_bytes

@@ -5,6 +5,8 @@ assertion that matters on TPU is *parallelism equivalence*: the same step
 on a 1-device and an 8-device mesh (dp/fsdp/tp and sp/ring) must agree.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -77,6 +79,39 @@ def test_n_params_counts():
     # GPT-2 small is ~124M params; our count excludes norms/bias.
     assert 1.1e8 < cfg.n_params < 1.4e8
 
+
+@functools.lru_cache(maxsize=None)
+def _remat_grads(remat_policy):
+    """(jaxpr text, gradients) of the loss of a two-block model whose
+    attention is the flash kernel under the interpreter."""
+    remat = dict(remat=False) if remat_policy is None else dict(
+        remat=True, remat_policy=remat_policy)
+    cfg = GPTConfig(vocab_size=128, n_layers=2, d_model=128, n_heads=2,
+                    max_seq_len=128, tie_embeddings=True,
+                    attention_impl="pallas_interpret", **remat)
+    model = GPT(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    batch = _batch(cfg, b=2, s=128)
+    grad = jax.grad(lambda p: model.loss(p, batch)[0])
+    return str(jax.make_jaxpr(grad)(params)), jax.jit(grad)(params)
+
+
+@pytest.mark.parametrize("remat_policy,forward_calls",
+                         [("dots", 1), ("full", 2), (None, 1)])
+def test_remat_policy_decides_how_often_the_flash_forward_runs(
+        remat_policy, forward_calls):
+    """The blocks are one scan, so its body holds a block's calls: "dots"
+    saves the kernel's output and log-sum-exp and runs the forward kernel
+    once a block, as no remat does; "full" runs it again in the backward
+    pass. What is saved changes no number."""
+    text, grads = _remat_grads(remat_policy)
+    assert text.count("name=flash_fwd") == forward_calls
+    assert text.count("name=flash_bwd_dq") == 1
+    assert text.count("name=flash_bwd_dkv") == 1
+    _, want = _remat_grads(None)
+    for got, ref in zip(jax.tree_util.tree_leaves(grads),
+                        jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 
 # Feature probes for this box's jax (0.4.x): the sharded model paths

@@ -53,6 +53,23 @@ def test_flash_grads_match_reference():
         np.testing.assert_allclose(np.asarray(b), np.asarray(a), atol=5e-4)
 
 
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_flash_output_residual_is_lane_dense(head_dim):
+    """What the backward pass keeps of the output has a minor dimension of
+    whole 128-lane tiles: at head width 64 the kernel's transposed output,
+    [B, H, D, S], at 128 the output itself."""
+    from ray_tpu.ops.attention import _flash_fwd
+
+    q, k, v = _qkv(d=head_dim, dtype=jnp.bfloat16)
+    out, (_, _, _, saved, lse) = jax.eval_shape(
+        lambda q, k, v: _flash_fwd(q, k, v, True, None, 128, 128, True),
+        q, k, v)
+    assert out.shape == q.shape
+    assert saved.shape == ((2, 4, 64, 256) if head_dim == 64 else q.shape)
+    assert saved.shape[-1] % 128 == 0 and saved.dtype == q.dtype
+    assert lse.shape == (2, 4, 256)
+
+
 def test_flash_non_divisible_length():
     # 300 % 128 != 0: padded tiles must be masked, not NaN.
     q, k, v = _qkv(s=300)
@@ -95,6 +112,8 @@ _PARITY_CASES = {
     "q_shorter_than_k": (dict(s=128, sk=256), True, None),
     "ragged_300": (dict(s=300), True, (128, 128)),
     "gqa_4_2_d128": (dict(h=4, hk=2, s=256, d=128), True, None),
+    # the saved output is [B, H, D, S] at this width: as square as q
+    "seq_equals_head_dim_64": (dict(b=1, s=64), True, (64, 64)),
     # several tiles: aligned (diagonal and interior tiles unroll), then
     # unaligned and ragged (loops over bounds from the program ids)
     "tiles_aligned": (dict(b=1, s=512), True, (256, 256)),
