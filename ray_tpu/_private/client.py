@@ -653,7 +653,7 @@ class CoreClient:
         node store offers an arena slot (one mmap per process,
         ``native/object_arena.cpp``), else a dedicated segment."""
         from . import native
-        if CONFIG.use_native_arena and native.available():
+        if native.available():
             try:
                 ref = self._request(P.ALLOC_OBJECT,
                                     lambda rid: (rid, oid, total)).result()

@@ -39,10 +39,6 @@ _CONFIG_DEFS: Dict[str, tuple] = {
                               "caller's buffers until promotion, so a put value "
                               "must not be mutated afterwards — same immutability "
                               "contract the reference's plasma copies enforce)"),
-    "use_native_arena": (bool, True,
-                         "allocate store objects from the C++ shm arena "
-                         "(native/object_arena.cpp) when the library builds; "
-                         "falls back to per-object segments"),
     # --- scheduler ---
     "worker_pipeline_depth": (int, 4,
                               "max tasks leased to one busy worker (running "
@@ -284,7 +280,6 @@ _CONFIG_DEFS: Dict[str, tuple] = {
                                       "out-of-band as zero-copy iovecs "
                                       "instead of inside the pickle "
                                       "stream"),
-    "rpc_inline_chunk_bytes": (int, 1 << 20, "frame chunking for large messages"),
     # --- collectives ---
     "collective_chunk_bytes": (int, 1 << 20,
                                "ring collectives split tensors into chunks "
@@ -400,7 +395,6 @@ _CONFIG_DEFS: Dict[str, tuple] = {
                                     "cross-host object pulls stream in "
                                     "chunks of this size (reference: "
                                     "object_manager chunked Push/Pull)"),
-    "grpc_equivalent_port": (int, 0, "tcp port for the head control plane (0 = unix socket)"),
     # --- serve request observability ---
     "request_log_capacity": (int, 256,
                              "per-replica structured access-log ring "
@@ -426,16 +420,6 @@ _CONFIG_DEFS: Dict[str, tuple] = {
 }
 
 
-# Renamed knobs: old name -> canonical name. Old env vars
-# (RTPU_<OLD_NAME>) and _system_config keys keep working; attribute
-# reads of the old name resolve to the canonical value.
-_ALIASES: Dict[str, str] = {
-    "max_inline_object_bytes": "object_store_shm_threshold_bytes",
-    "object_spilling_threshold": "object_store_spill_threshold",
-    "spill_directory": "object_store_spill_dir",
-}
-
-
 class _Config:
     """Process-wide config singleton. Read via attribute access."""
 
@@ -451,14 +435,8 @@ class _Config:
                 values[name] = self._parse(typ, raw)
             else:
                 values[name] = default
-        for old, new in _ALIASES.items():
-            raw = os.environ.get(_ENV_PREFIX + old.upper())
-            if (raw is not None
-                    and os.environ.get(_ENV_PREFIX + new.upper()) is None):
-                values[new] = self._parse(_CONFIG_DEFS[new][0], raw)
         if system_config:
             for key, val in system_config.items():
-                key = _ALIASES.get(key, key)
                 if key not in _CONFIG_DEFS:
                     raise ValueError(f"unknown config key: {key}")
                 values[key] = val
@@ -474,7 +452,7 @@ class _Config:
 
     def __getattr__(self, name: str):
         try:
-            return self._values[_ALIASES.get(name, name)]
+            return self._values[name]
         except KeyError:
             raise AttributeError(name) from None
 

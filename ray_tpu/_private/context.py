@@ -35,9 +35,9 @@ current_namespace: contextvars.ContextVar = contextvars.ContextVar(
 # trace baggage / Serve's request context): a submitter binds a compact
 # tuple here and the next submissions carry it in spec.request_ctx —
 # INSIDE the one spec pickle stream, not as an extra arg slot (an arg
-# slot costs a separate pickle + load per call; the request_ab overhead
-# gate prices this path). Workers re-bind it around task execution, so
-# the whole nested call tree of one serve request shares the baggage.
+# slot costs a separate pickle + load per call). Workers re-bind it
+# around task execution, so the whole nested call tree of one serve
+# request shares the baggage.
 request_ctx: contextvars.ContextVar = contextvars.ContextVar(
     "rtpu_request_ctx", default=None)
 

@@ -23,8 +23,7 @@ Three guard classes, by ``FIELDS`` value:
   the race class that corrupts state in Python is the unguarded
   *write*, and that is what trips the report. For throughput, guarded
   reads are noted 1-in-8 and a *clean* guarded write whose record
-  already exists short-cuts to an O(1) held-name probe (the
-  ``fieldsan_ab`` gate pins the instrumented path < 1.25x) — an
+  already exists short-cuts to an O(1) held-name probe — an
   UNGUARDED access never takes a short-cut.
 - ``"thread:<pat>"``: single-thread-confined — only threads whose name
   contains ``<pat>`` may WRITE (e.g. ``thread:rtpu-dispatch`` for the
@@ -43,8 +42,7 @@ Three guard classes, by ``FIELDS`` value:
 
 With ``RTPU_FIELDSAN`` unset/0 everything here is inert: ``guarded``
 returns the class unchanged and ``instrument_module`` is a no-op, so a
-declaration costs nothing (bench_telemetry's ``fieldsan_ab`` gate pins
-the off path at parity). With ``RTPU_FIELDSAN=1`` (tier-1 sets this in
+declaration costs nothing. With ``RTPU_FIELDSAN=1`` (tier-1 sets this in
 conftest beside RTPU_LOCKSAN) declared instance fields become data
 descriptors and declared containers are wrapped in mutation-checking
 proxy subclasses (dict/list/set/deque/OrderedDict), so plain attribute
@@ -57,7 +55,7 @@ Violations go to ``violations()`` and stderr
 two-thread race test demonstrates the access being refused with both
 threads surviving. Stack capture on clean (guard-held) accesses is
 sampled 1-in-``RTPU_FIELDSAN_SAMPLE`` (default 16) to keep the
-instrumented hot path inside the fieldsan_ab budget; unguarded accesses
+instrumented hot path cheap; unguarded accesses
 — the interesting side of any pair — always capture.
 """
 
@@ -340,8 +338,8 @@ def _p_note(proxy, kind: str) -> None:
         return
     guard, key = spec
     if kind == "w":
-        # clean-verdict memo — the hot-path fast exit that holds the
-        # instrumented path inside the fieldsan_ab budget. Thread-
+        # clean-verdict memo — the hot-path fast exit that keeps the
+        # instrumented path cheap. Thread-
         # confined: the owning thread's verdict never changes, memo is
         # its id. Lock-guarded: once ONE clean write is recorded in
         # _last (memo=True), a further write while the guard is HELD

@@ -1532,8 +1532,7 @@ class GlobalControlPlane:
         Returns the ring's estimated byte total, or ``None`` when
         rate-limited/disabled."""
         # the live CONFIG check (beside the ring's init-time flag) lets
-        # an A/B toggle retention off in-process (bench_telemetry's
-        # history_ab gate measures exactly this knob)
+        # a running process toggle retention off
         if not (self.metrics_history.enabled
                 and CONFIG.metrics_history_capacity > 0):
             return None

@@ -281,18 +281,17 @@ class ObjectStore:
         # (unlike POSIX segments), so reuse is delayed by
         # CONFIG.arena_free_quarantine_s after an explicit free().
         self._quarantine: List[tuple] = []
-        if CONFIG.use_native_arena:
-            try:
-                from . import native
-                if native.available():
-                    # random suffix: pid+id can repeat across store
-                    # restarts in one process, and reader processes cache
-                    # mappings by path
-                    suffix = os.urandom(8).hex()
-                    path = f"/dev/shm/rtpu_arena_{suffix}"
-                    self._arena = native.Arena(path, self._capacity)
-            except Exception:
-                self._arena = None
+        try:
+            from . import native
+            if native.available():
+                # random suffix: pid+id can repeat across store
+                # restarts in one process, and reader processes cache
+                # mappings by path
+                suffix = os.urandom(8).hex()
+                path = f"/dev/shm/rtpu_arena_{suffix}"
+                self._arena = native.Arena(path, self._capacity)
+        except Exception:
+            self._arena = None
         # crash manifest: everything this store parks in /dev/shm is
         # recorded here (header: owner identity + arena path; one line
         # per segment), so reap_orphan_shm() can clean up after a
@@ -595,8 +594,8 @@ class ObjectStore:
     # concurrency: requires(store.entries)
     def _free_arena_block(self, e: _Entry) -> None:
         """Release an owned arena block; quarantine it if any reader may
-        still hold zero-copy views into it (ADVICE r1: unconditional free
-        reused blocks under live readers → silent corruption)."""
+        still hold zero-copy views into it (an unconditional free reused
+        blocks under live readers → silent corruption)."""
         off = e.meta.arena_ref[1]
         if e.ever_read and CONFIG.arena_free_quarantine_s > 0:
             self._quarantine.append(

@@ -112,7 +112,7 @@ M_COLL_REFORMS = telemetry.define(
 class CollectiveTimeoutError(TimeoutError):
     """A collective call's deadline passed. Carries the flight
     recorder's cluster-wide diagnosis so recovery code can act on the
-    VERDICT instead of string-matching the message: ``verdicts`` is the
+    verdict instead of string-matching the message: ``verdicts`` is the
     list of verdict dicts for this group (``dead_rank`` is the one the
     fault-tolerant wrappers reform on)."""
 

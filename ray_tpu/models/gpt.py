@@ -505,7 +505,7 @@ class GPT:
         # indices: left to inference, the partitioner shards the gather
         # output on tp and then falls back to "involuntary full
         # rematerialization" resharding it to (batch, seq) — the
-        # spmd_partitioner.cc warning in MULTICHIP_r03. Replicated
+        # spmd_partitioner.cc warning. Replicated
         # operand + sharded indices computes the gather directly in the
         # activation sharding.
         with jax.named_scope("embed"):

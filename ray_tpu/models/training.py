@@ -178,15 +178,3 @@ def eval_step_fn(model: GPT, mesh: Optional[Mesh] = None):
         model.param_logical_axes(), is_leaf=_is_axes)
     return jax.jit(eval_step, in_shardings=(param_shardings, None))
 
-
-def flops_per_token(config) -> float:
-    """~6 * n_params non-embedding FLOPs/token (fwd+bwd), attention extra.
-
-    Used by bench.py to report MFU.
-    """
-    n = config.n_params - config.vocab_size * config.d_model * (
-        1 if config.tie_embeddings else 2)
-    attn_extra = 12 * config.n_layers * config.d_model * config.max_seq_len
-    # lm head matmul counts (it's a real matmul): 6 * d * V
-    head = 6 * config.d_model * config.vocab_size
-    return 6.0 * n + attn_extra + head

@@ -115,8 +115,7 @@ class DeploymentHandle:
         HERE so the replica's queue-wait measurement covers routing +
         actor-call queueing. A compact TUPLE riding INSIDE the one spec
         pickle stream — NOT an extra arg slot, which costs a separate
-        pickle + load per call (the request_ab overhead gate prices
-        this path)."""
+        pickle + load per call."""
         if not _rc.enabled():
             return None
         ctx = _rc.current()

@@ -36,8 +36,8 @@ _rid_counter = itertools.count(1)
 
 
 def enabled() -> bool:
-    # direct _values read: this gates every handle call (both arms of
-    # the request_ab gate) and __getattr__ dispatch costs ~0.4µs
+    # direct _values read: this gates every handle call, and
+    # __getattr__ dispatch is one more Python call on that path
     return CONFIG._values["request_log_capacity"] > 0
 
 

@@ -1,24 +1,18 @@
-"""chip_smoke.py on the CPU, and the rule that decides who sees the chip.
+"""The rule that decides who sees the chip (`_private/accelerators.py`), and
+the worker pool that follows it.
 
-The smoke's phases run here at `llama_tiny` size with the expected
-platform passed as a function argument (the script itself takes no size
-or platform option). What these tests pin:
-
-- the LAST line of stdout is the contract's JSON object, also while a
-  worker writes to stdout and stderr until the runtime kills it;
-- plain `python chip_smoke.py` on a machine without a chip fails in
-  seconds, in the same shape, with `"ok": false`;
 - a worker's environment is a pure function of (parent environment, grant,
   detected-or-declared): only a TPU-granted process is left off the CPU pin;
+- whole slots buy a process of their own, a killed gang's chip comes back
+  before the next grant, and a granted worker's compile cache goes where
+  `JAX_COMPILATION_CACHE_DIR` says;
 - `ScalingConfig(use_tpu=True)` asks for what the nodes advertise;
 - telemetry's device sampling never opens a JAX backend.
 """
 
-import json
 import os
 import subprocess
 import sys
-import time
 
 import pytest
 
@@ -26,115 +20,6 @@ import ray_tpu
 from ray_tpu._private import accelerators
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-_PHASES = """
-import sys, time
-sys.path.insert(0, {repo!r})
-import chip_smoke, ray_tpu
-
-ray_tpu.init(num_cpus=4, num_tpus={chips})
-
-@ray_tpu.remote(num_cpus=0)
-class Chatty:
-    def run(self):
-        while True:      # until the runtime kills this process
-            print("chatter on stdout", flush=True)
-            print("chatter on stderr", file=sys.stderr, flush=True)
-            time.sleep(0.005)
-
-class SlowForwarding:
-    # stdout on which every forwarded chunk takes its time: a forwarder
-    # that is still running when the summary is printed writes after it
-    def __init__(self, out):
-        self._out = out
-    def write(self, text):
-        if text.startswith("(worker"):
-            time.sleep(0.7)
-        return self._out.write(text)
-    def __getattr__(self, name):
-        return getattr(self._out, name)
-
-chatty = Chatty.remote()
-chatty.run.remote()
-summary = chip_smoke.run_phases({chips}, "cpu", chip_smoke.TINY)
-sys.stdout = SlowForwarding(sys.stdout)
-time.sleep(1.0)          # by now a chunk is in flight all of the time
-code = chip_smoke.finish(summary)
-time.sleep(1.5)          # room for a forwarder that outlived shutdown()
-sys.exit(code)
-"""
-
-
-def _run(args, tmp_path, **env):
-    full = dict(os.environ, JAX_PLATFORMS="cpu", **env)
-    t0 = time.monotonic()
-    proc = subprocess.run([sys.executable, *args], cwd=tmp_path, env=full,
-                          capture_output=True, text=True, timeout=300)
-    return proc, time.monotonic() - t0
-
-
-def _last_line(proc) -> dict:
-    """The contract: the last line of stdout is one JSON object with
-    exactly these keys."""
-    lines = proc.stdout.splitlines()
-    assert lines, proc.stderr[-2000:]
-    last = json.loads(lines[-1])
-    assert set(last) == {"ok", "device"}, lines[-1]
-    assert set(last["device"]) == {"platform", "kind", "count"}, lines[-1]
-    assert isinstance(last["ok"], bool)
-    return last
-
-
-def _listing(path):
-    return sorted(os.listdir(path)) if os.path.isdir(path) else None
-
-
-@pytest.fixture(scope="module", autouse=True)
-def phase_runs(tmp_path_factory):
-    """Both tiny runs, started together before this module's first test and
-    read by its last two: each is mostly process starts and compiles, and
-    the suite's time limit is real. Each has its own cluster, cache and run
-    directory."""
-    fixed_before = _listing(os.path.join(REPO, ".jax_cache"))
-    runs = {}
-    for chips in (1, 4):
-        tmp = tmp_path_factory.mktemp(f"phases{chips}")
-        env = dict(
-            os.environ, JAX_PLATFORMS="cpu",
-            JAX_COMPILATION_CACHE_DIR=str(tmp / "cache"),
-            JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
-            XLA_FLAGS=f"--xla_force_host_platform_device_count={chips}")
-        with open(tmp / "out", "w") as out, open(tmp / "err", "w") as err:
-            runs[chips] = subprocess.Popen(
-                [sys.executable, "-c",
-                 _PHASES.format(repo=REPO, chips=chips)],
-                cwd=tmp, env=env, stdout=out, stderr=err), tmp
-    yield runs, fixed_before
-    for proc, _ in runs.values():
-        if proc.poll() is None:
-            proc.kill()
-
-
-@pytest.mark.skipif(accelerators.detect_tpus() > 0,
-                    reason="this machine has a chip")
-def test_plain_smoke_without_a_chip_fails_fast_in_the_same_shape(tmp_path):
-    proc, took = _run([os.path.join(REPO, "chip_smoke.py")], tmp_path)
-    last = _last_line(proc)
-    assert proc.returncode != 0 and last["ok"] is False
-    assert last["device"]["platform"] != "tpu"
-    assert '"platform": "tpu"' not in proc.stdout
-    assert took < 30, f"took {took:.0f}s: a placement wait crept in"
-
-
-def test_smoke_alone_in_a_directory_fails(tmp_path):
-    import shutil
-    shutil.copy(os.path.join(REPO, "chip_smoke.py"), tmp_path)
-    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
-    proc = subprocess.run([sys.executable, "chip_smoke.py"], cwd=tmp_path,
-                          env=env, capture_output=True, text=True,
-                          timeout=60)
-    assert proc.returncode != 0
-    assert '"ok": true' not in proc.stdout
 
 
 # ------------------------------------------------- rule A: who sees the chip
@@ -276,6 +161,40 @@ def test_killed_gang_returns_its_chip_before_the_next_grant():
         ray_tpu.shutdown()
 
 
+def test_granted_worker_compiles_into_the_given_cache_dir(tmp_path,
+                                                         monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR set, what a granted worker compiles
+    lands there and the fixed directory inside the checkout is not
+    touched."""
+    def listing(path):
+        return sorted(os.listdir(path)) if os.path.isdir(path) else None
+
+    cache = tmp_path / "cache"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(cache))
+    # a tiny program compiles too quickly to be cached by default
+    monkeypatch.setenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
+    fixed_before = listing(FIXED_CACHE)
+    ray_tpu.init(num_cpus=2, num_tpus=1)
+    try:
+        @ray_tpu.remote(num_tpus=1)
+        class Granted:
+            def compile(self):
+                import jax
+                import jax.numpy as jnp
+                out = jax.jit(lambda x: jnp.tanh(x @ x).sum())(
+                    jnp.ones((64, 64)))
+                return (float(out),
+                        os.environ.get("JAX_COMPILATION_CACHE_DIR"))
+
+        _, given = ray_tpu.get(Granted.remote().compile.remote(),
+                               timeout=120)
+        assert given == str(cache)
+        assert os.listdir(cache)
+        assert listing(FIXED_CACHE) == fixed_before
+    finally:
+        ray_tpu.shutdown()
+
+
 # ------------------------------------------------------- B: use_tpu=True
 
 @pytest.mark.parametrize("chips", [1, 4])
@@ -312,34 +231,3 @@ def test_sample_devices_does_not_initialise_a_backend():
                           env=dict(os.environ, JAX_PLATFORMS="cpu"),
                           capture_output=True, text=True, timeout=120)
     assert proc.returncode == 0, proc.stderr[-2000:]
-
-
-# ------------------------------------------ the phases, at tiny size (last:
-# the two runs started with the module and have had the tests above to finish)
-
-@pytest.mark.parametrize("chips", [1, 4])
-def test_phases_end_on_the_contract_line_despite_a_chatty_worker(
-        chips, phase_runs):
-    """Both fits of the one-chip run (and the sharded phase on four virtual
-    devices) at tiny size; a worker chatters throughout. Also: with
-    JAX_COMPILATION_CACHE_DIR set, that directory fills and the fixed
-    one inside the checkout is not touched."""
-    runs, fixed_before = phase_runs
-    proc, tmp = runs[chips]
-    proc.wait(timeout=300)
-    proc.stdout, proc.stderr = ((tmp / "out").read_text(),
-                                (tmp / "err").read_text())
-    cache = tmp / "cache"
-    last = _last_line(proc)
-    assert proc.returncode == 0 and last["ok"] is True, (
-        proc.stdout[-3000:] + proc.stderr[-3000:])
-    assert last["device"] == {"platform": "cpu", "kind": "cpu",
-                              "count": chips}
-    # the forwarder was on and busy, and still nothing followed the summary
-    assert "chatter on stdout" in proc.stdout
-    assert "chatter on stderr" in proc.stdout
-    assert os.listdir(cache)
-    assert _listing(os.path.join(REPO, ".jax_cache")) == fixed_before
-    claims = [json.loads(l) for l in proc.stdout.splitlines()
-              if l.startswith("{") and '"claim"' in l]
-    assert claims and all(c["claim"] is None for c in claims)

@@ -5,7 +5,8 @@ described and not attached (`on-chip-measurement` guide, section 2). This
 catches what interpret mode cannot — a kernel the Mosaic compiler refuses,
 a step that does not fit 16 GB of HBM, a shard_map that cannot be
 partitioned — at no chip time. Nothing runs: these tests say nothing about
-results or speed, and a pass here is not a chip run (`chip_smoke.py` is).
+results or speed, and a pass here is not a chip run (a cell of
+`benchmarks/run.py` is).
 
 Skipped where the topology cannot be described. The persistent compile
 cache is off around them: such a compile can be written to it but not read
@@ -96,8 +97,8 @@ def _gpt2_medium_step(mesh, batch, remat_policy="dots"):
                          [("dots", 3), ("full", 4)])
 def test_gpt2_medium_train_step_fits_one_chip(v5e, remat_policy,
                                               kernel_calls):
-    """chip_smoke.py's and bench.py's step: batch 12, "dots" remat. The
-    compiler refuses a program that exceeds HBM (batch 16 does). "dots"
+    """The step of `gpt2m-steady` and `gpt2m-ckpt`: batch 12, "dots" remat.
+    The compiler refuses a program that exceeds HBM (batch 16 does). "dots"
     saves the flash kernel's output, so a block runs the forward kernel
     once, then dq and dkv; "full" runs the forward kernel again in the
     backward pass. Nor may the compiler make room by recomputing on its
@@ -172,9 +173,9 @@ def test_olmoe_one_layer_train_step_fills_one_chip(v5e):
 
 @pytest.mark.slow   # 12 s here, and tier-1 runs close to its time limit
 def test_gpt2_medium_fsdp4_train_step_compiles_for_the_host(v5e):
-    """chip_smoke.py --chips 4: the same model on an fsdp=4 mesh built by
-    build_mesh from the four described chips, global batch 48; each chip
-    holds about a quarter of the state."""
+    """One worker granted a whole four-chip host: the same model on an
+    fsdp=4 mesh built by build_mesh from the four described chips, global
+    batch 48; each chip holds about a quarter of the state."""
     from ray_tpu.models.training import batch_shardings
     from ray_tpu.parallel.mesh import MeshSpec, build_mesh
 

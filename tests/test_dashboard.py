@@ -158,8 +158,7 @@ def test_head_process_serves_dashboard():
 
 def test_history_and_task_drilldown(dashboard):
     """Dashboard v1: utilization time series accumulates while a
-    workload runs; a task's state transitions are queryable by id
-    (VERDICT r04 ask #10)."""
+    workload runs; a task's state transitions are queryable by id."""
     import json
     import time
     import urllib.request

@@ -169,7 +169,7 @@ def test_trainer_streaming_ingest(rtpu_init):
 
 
 def test_read_csv_dtype_consistent_across_blocks(rtpu_init, tmp_path):
-    """ADVICE r04: dtype inference is per-FILE, not per-block — a late
+    """Dtype inference is per-FILE, not per-block — a late
     "n/a" must make the whole column strings, not just its block."""
     p = tmp_path / "mixed.csv"
     rows = [str(i) for i in range(20)] + ["n/a", "21"]
@@ -188,7 +188,7 @@ def test_read_csv_dtype_consistent_across_blocks(rtpu_init, tmp_path):
 
 
 def test_read_numpy_npz_list_and_dir(rtpu_init, tmp_path):
-    """ADVICE r04: .npz detection must work for list inputs and
+    """.npz detection must work for list inputs and
     directories (str(paths) endswith was wrong for both)."""
     np.savez(tmp_path / "z.npz", a=np.arange(4), b=np.ones(4))
     rows = list(rd.read_numpy([str(tmp_path / "z.npz")]).iter_rows())

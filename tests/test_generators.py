@@ -199,8 +199,7 @@ def test_stream_close_before_first_item(rtpu_init, tmp_path):
 def test_owner_local_stream_zero_head_traffic(rtpu_init):
     """Owner-local streams keep per-item control traffic OFF the head:
     no gen_update per item, no gen_consumed per consume, no gen_get per
-    end-probe (reference: ReportGeneratorItemReturns is worker<->owner;
-    VERDICT r04 weak #6 / ask #3)."""
+    end-probe (reference: ReportGeneratorItemReturns is worker<->owner)."""
     node = ray_tpu._global_node
     counts = {"gen_update": 0, "gen_consumed": 0, "gen_get": 0,
               "gen_done": 0}

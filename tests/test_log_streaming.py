@@ -86,3 +86,56 @@ def test_multinode_logs_reach_driver(rtpu_cluster, capsys):
 
     assert ray_tpu.get(far_away.remote(), timeout=60)
     _wait_for(capsys, "printed-on-the-other-node")
+
+
+class _SlowForwarding:
+    """stdout on which every forwarded chunk takes its time, and is noted
+    with the time it landed: a forwarder that is still running when
+    shutdown() returns writes after it."""
+
+    def __init__(self, out):
+        self._out = out
+        self.forwarded = []         # (landed at, text)
+
+    def write(self, text):
+        if text.startswith("(worker"):
+            time.sleep(0.3)
+            self.forwarded.append((time.monotonic(), text))
+        return self._out.write(text)
+
+    def __getattr__(self, name):
+        return getattr(self._out, name)
+
+
+def test_nothing_is_forwarded_after_shutdown_returns(monkeypatch):
+    """An actor chatters on stdout and stderr until the runtime kills it.
+    Its chatter reaches the driver, and shutdown() stops the forwarder
+    before it returns: a script's last line stays its last line."""
+    ray_tpu.init(num_cpus=2)
+    try:
+        @ray_tpu.remote(num_cpus=0)
+        class Chatty:
+            def run(self):
+                while True:
+                    print("chatter on stdout", flush=True)
+                    print("chatter on stderr", file=sys.stderr, flush=True)
+                    time.sleep(0.005)
+
+        chatty = Chatty.remote()
+        chatty.run.remote()
+        out = _SlowForwarding(sys.stdout)
+        monkeypatch.setattr(sys, "stdout", out)
+        deadline = time.monotonic() + 30
+        while time.monotonic() < deadline:
+            seen = "".join(text for _, text in out.forwarded)
+            if "chatter on stdout" in seen and "chatter on stderr" in seen:
+                break
+            time.sleep(0.1)
+        else:
+            raise AssertionError(f"chatter never reached the driver:\n{seen}")
+        time.sleep(0.5)             # by now a chunk is in flight all the time
+    finally:
+        ray_tpu.shutdown()
+    returned = time.monotonic()
+    time.sleep(1.5)                 # room for a forwarder that outlived it
+    assert not [text for at, text in out.forwarded if at > returned]

@@ -122,7 +122,7 @@ def test_sharded_compile_no_involuntary_remat(capfd):
     """Regression pin for the r03/r04 remat fix (gpt.py embedding gather):
     compiling the sp/tp/fsdp train step must emit zero spmd_partitioner
     "involuntary full rematerialization" warnings. A sharding-rule
-    regression would otherwise land silently (VERDICT r04 weak #4)."""
+    regression would otherwise land silently."""
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 virtual devices")
     cfg = llama_tiny()

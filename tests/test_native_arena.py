@@ -120,8 +120,7 @@ def test_arena_spill_restore_roundtrip(tmp_path):
 
 def test_free_while_read_quarantines_block(tmp_path):
     """free() of an arena object whose meta was handed to a reader must
-    not reuse the block immediately — readers may hold zero-copy views
-    (ADVICE r1 #2)."""
+    not reuse the block immediately — readers may hold zero-copy views."""
     from ray_tpu._private.config import CONFIG
     from ray_tpu._private.ids import ObjectID
     from ray_tpu._private.object_store import ObjectMeta, ObjectStore
@@ -178,7 +177,7 @@ def test_never_read_arena_free_is_immediate(tmp_path):
 def test_cross_node_get_marks_owner_read(rtpu_cluster):
     """A remote node's get() must route through the owning store so the
     entry is marked ever_read and can never be spilled-and-freed under a
-    live zero-copy reader (ADVICE r1 #1, high)."""
+    live zero-copy reader."""
     cluster = rtpu_cluster
     worker_node = cluster.add_node(num_cpus=2, resources={"side": 1.0})
 

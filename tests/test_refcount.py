@@ -276,7 +276,7 @@ def test_owner_routed_lookup_skips_head_directory():
     ownership_based_object_directory.h): getting a task's return from
     the node that ran it costs ZERO head directory lookups — the
     submitting node remembers where the task ran and reads that store
-    directly (VERDICT r04 ask #3, read path)."""
+    directly (the read path)."""
     import numpy as np
 
     import ray_tpu

@@ -212,7 +212,7 @@ def test_impala_multi_learner(rtpu_init):
 
 
 def test_dqn_learner_update_smoke():
-    """Pin ADVICE r04 high: DQNLearner._loss is jitted on first update
+    """DQNLearner._loss is jitted on first update
     (past learning_starts); a missing import inside the trace raised
     NameError there. Runs enough updates to cross a target sync."""
     from ray_tpu.rl.dqn import NEXT_OBS, DQNLearner

@@ -76,8 +76,8 @@ def test_rejected_keys(rtpu_init):
 
 def test_broken_env_fails_fast(rtpu_init, tmp_path):
     """Workers that die on startup must fail the task with
-    RuntimeEnvSetupError instead of pending forever (ADVICE r1 /
-    reference: PopWorker failure callback, ``worker_pool.h:152``)."""
+    RuntimeEnvSetupError instead of pending forever (reference:
+    PopWorker failure callback, ``worker_pool.h:152``)."""
     pkg = tmp_path / "broken"
     pkg.mkdir()
     # staged working_dir becomes the worker's cwd (= sys.path[0]), so
@@ -95,7 +95,7 @@ def test_broken_env_fails_fast(rtpu_init, tmp_path):
 
 def test_env_pool_eviction_no_starvation(tmp_path):
     """A pool full of idle other-env workers must evict one instead of
-    starving a new env forever (ADVICE r1 #3)."""
+    starving a new env forever."""
     ray_tpu.init(num_cpus=4)
     try:
         node = ray_tpu._global_node

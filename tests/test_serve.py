@@ -118,7 +118,7 @@ def test_autoscaling_up(serve_session):
 def test_batch_deadline_is_absolute():
     """Under a trickle of requests arriving faster than the batch
     timeout, the first caller must not wait longer than ~timeout — the
-    deadline is absolute per batch, not reset per arrival (ADVICE r1 #4)."""
+    deadline is absolute per batch, not reset per arrival."""
     import threading
     import time as _t
 

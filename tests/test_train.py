@@ -129,3 +129,96 @@ def test_jax_training_with_pytree_checkpoint(rtpu_init, tmp_path):
     restored = result.checkpoint.to_pytree()
     assert int(restored["step"]) == 2
     assert "tok_embed" in restored["params"]
+
+
+def _granted_loop(config):
+    """`steps` train steps at `llama_tiny` size in the worker that was granted
+    the chips, from a fresh or a restored TrainState; with `save`, ends on
+    the loss the next step is to report and a checkpoint of the state."""
+    import jax
+    import numpy as np
+    from ray_tpu import train
+    from ray_tpu.models import (GPT, llama_tiny, init_train_state,
+                                make_optimizer, make_train_step)
+    from ray_tpu.models.training import batch_shardings, eval_step_fn
+    from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+
+    chips = config["chips"]
+    devices = jax.devices()[:chips]
+    mesh = (build_mesh(MeshSpec(fsdp=chips), devices) if chips > 1
+            else None)
+    cfg = llama_tiny()
+    model = GPT(cfg, mesh=mesh)
+    opt = make_optimizer(learning_rate=1e-3, warmup_steps=2, total_steps=64)
+    state = init_train_state(model, opt, jax.random.PRNGKey(0), mesh=mesh)
+    if mesh is None:
+        state = jax.device_put(state, devices[0])   # placed, as a restored one
+    if config.get("resume"):
+        state = train.Checkpoint(config["resume"]).to_pytree(template=state)
+    spans = sorted({len(leaf.sharding.device_set)
+                    for leaf in jax.tree_util.tree_leaves(state.params)})
+    train.report({
+        "kind": "worker", "pid": os.getpid(), "params_span": spans,
+        "slots": ray_tpu.get_runtime_context().get_accelerator_ids()["TPU"]})
+
+    tokens = np.random.default_rng(0).integers(
+        0, cfg.vocab_size, (2 * chips, 64), dtype=np.int32)
+    batch = {"tokens": jax.device_put(
+        tokens, batch_shardings(mesh) if mesh else devices[0])}
+    step = make_train_step(model, opt, mesh=mesh)
+    for _ in range(config["steps"]):
+        at = int(state.step)
+        state, metrics = step(state, batch)
+        train.report({"kind": "step", "step": at,
+                      "loss": float(metrics["loss"])})
+    if config.get("save"):
+        # a step's loss is taken before its update: the resumed run's first
+        # step is held to this forward pass
+        next_loss = float(eval_step_fn(model, mesh=mesh)(
+            state.params, batch)["loss"])
+        train.report({"kind": "saved", "next_loss": next_loss},
+                     checkpoint=train.Checkpoint.from_pytree(state))
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_granted_fit_resumes_from_a_pytree_checkpoint_in_a_fresh_worker(
+        chips, tmp_path, monkeypatch):
+    """Save, let the gang be killed, resume: the second `fit()` needs the
+    first worker to have given its chips back, a new worker to be granted
+    them, and the TrainState to round-trip through `Checkpoint.from_pytree`
+    — sharded `fsdp=4` over four virtual devices when the grant is four."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    ray_tpu.init(num_cpus=4, num_tpus=chips)
+    try:
+        def fit(name, **config):
+            result = JaxTrainer(
+                _granted_loop,
+                train_loop_config={"chips": chips, **config},
+                scaling_config=ScalingConfig(num_workers=1, use_tpu=True),
+                run_config=RunConfig(name=name,
+                                     storage_path=str(tmp_path))).fit()
+            assert result.error is None
+            by_kind = {}
+            for r in result.metrics_history:
+                by_kind.setdefault(r["kind"], []).append(r)
+            (worker,) = by_kind["worker"]
+            assert worker["slots"] == list(range(chips))
+            assert worker["params_span"] == [chips]
+            assert worker["pid"] != os.getpid()
+            return result, worker, by_kind["step"], by_kind.get("saved")
+
+        first, worker1, steps1, saved = fit("train", steps=3, save=True)
+        assert [s["step"] for s in steps1] == [0, 1, 2]
+        assert steps1[-1]["loss"] < steps1[0]["loss"]
+        assert first.checkpoint is not None
+
+        _, worker2, steps2, _ = fit("resume", steps=2,
+                                    resume=first.checkpoint.path)
+        assert worker2["pid"] != worker1["pid"]
+        assert [s["step"] for s in steps2] == [3, 4]
+        # two computations of one loss from the same parameters and tokens:
+        # bf16 activations, another fusion and reduction order
+        assert steps2[0]["loss"] == pytest.approx(saved[0]["next_loss"],
+                                                  abs=2e-2)
+    finally:
+        ray_tpu.shutdown()

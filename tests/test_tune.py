@@ -128,7 +128,7 @@ def test_tuner_restore_reruns_unfinished(rtpu_init, tmp_path):
 
 def test_asha_judges_trials_that_skip_rung_values():
     """Trials whose time_attr jumps over a rung value must still face
-    the halving decision at the first report past it (ADVICE r1 #5)."""
+    the halving decision at the first report past it."""
     from ray_tpu.tune.schedulers import CONTINUE, STOP, ASHAScheduler
 
     s = ASHAScheduler(metric="loss", mode="min", max_t=30,
