@@ -32,8 +32,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
-from ..ops.attention import (FLASH_RESIDUAL_NAMES, attention_reference,
-                             dot_product_attention)
+from ..ops.attention import FLASH_RESIDUAL_NAMES, dot_product_attention
 from ..ops.ring_attention import ring_attention
 from ..parallel.sharding import (DEFAULT_RULES, ShardingRules,
                                  with_logical_constraint)
@@ -370,21 +369,22 @@ class GPT:
     def _attention(self, q, k, v):
         """q: [B, S, H, Dh], k/v: [B, S, Hk, Dh] → [B, S, H, Dh].
 
-        Kernels want [B, H, S, Dh]; ring attention additionally wants the
-        sequence axis *locally* sharded, so both pallas paths run under
-        shard_map with specs derived from the mesh.
+        The flash kernels read q, k, v as the projections wrote them and
+        write their gradients the same way (`ops/attention.py`,
+        `seq_major`): nothing is transposed on either side of them. Ring
+        attention keeps [B, H, S, Dh] and wants the sequence axis *locally*
+        sharded, so both pallas paths run under shard_map with specs derived
+        from the mesh.
         """
         c = self.config
-        qt = jnp.transpose(q, (0, 2, 1, 3))
-        kt = jnp.transpose(k, (0, 2, 1, 3))
-        vt = jnp.transpose(v, (0, 2, 1, 3))
         sp = self._sp_size()
         if getattr(self, "_in_pipeline", False):
             # pipeline mode runs blocks under vmap over the stage axis;
             # shard_map can't nest there, so use the einsum attention and
             # let GSPMD partition it (pallas-in-pipeline: future work)
-            ot = attention_reference(qt, kt, vt, causal=True)
-        elif sp > 1:
+            return dot_product_attention(q, k, v, causal=True,
+                                         impl="reference", seq_major=True)
+        if sp > 1:
             # Specs derive from the rules table like every other sharding
             # decision; the ring axis is whatever act_seq maps to.
             spec_q = self.rules.spec("act_batch", "act_heads", "act_seq",
@@ -401,23 +401,36 @@ class GPT:
 
             ot = jax.shard_map(local, mesh=self.mesh,
                                in_specs=(spec_q, spec_kv, spec_kv),
-                               out_specs=spec_q, check_vma=False)(qt, kt, vt)
-        elif self.mesh is not None:
-            spec_q = self.rules.spec("act_batch", "act_heads", None, None)
-            spec_kv = self.rules.spec("act_batch", "act_kv_heads", None,
-                                      None)
+                               out_specs=spec_q, check_vma=False)(
+                *(jnp.swapaxes(x, 1, 2) for x in (q, k, v)))
+            return jnp.swapaxes(ot, 1, 2)
 
-            def local(qb, kb, vb):
-                return dot_product_attention(
-                    qb, kb, vb, causal=True, impl=c.attention_impl)
+        def local(qb, kb, vb):
+            return dot_product_attention(qb, kb, vb, causal=True,
+                                         impl=c.attention_impl,
+                                         seq_major=True)
 
-            ot = jax.shard_map(local, mesh=self.mesh,
-                               in_specs=(spec_q, spec_kv, spec_kv),
-                               out_specs=spec_q, check_vma=False)(qt, kt, vt)
-        else:
-            ot = dot_product_attention(qt, kt, vt, causal=True,
-                                       impl=c.attention_impl)
-        return jnp.transpose(ot, (0, 2, 1, 3))
+        if self.mesh is None:
+            return local(q, k, v)
+
+        # across shard_map as [B, S, H * Dh]: the reshapes on either side
+        # of the boundary then cancel against the projections' and the
+        # kernels' own, where a [.., H, 64] array crossing it would be
+        # copied to a padded layout and back
+        def flat(x):
+            return x.reshape(*x.shape[:2], -1)
+
+        def local_flat(*qkv):
+            return flat(local(*(x.reshape(*x.shape[:2], -1, q.shape[-1])
+                                for x in qkv)))
+
+        spec_q = self.rules.spec("act_batch", None, "act_heads")
+        spec_kv = self.rules.spec("act_batch", None, "act_kv_heads")
+        out = jax.shard_map(local_flat, mesh=self.mesh,
+                            in_specs=(spec_q, spec_kv, spec_kv),
+                            out_specs=spec_q, check_vma=False)(
+            flat(q), flat(k), flat(v))
+        return out.reshape(q.shape)
 
     def _constrain(self, x, *logical):
         return with_logical_constraint(x, *logical, rules=self.rules,
@@ -432,12 +445,22 @@ class GPT:
         # HLO carry them), the program is the same with or without
         with jax.named_scope("attn_qkv"):
             h = self._norm(x, w["norm1"], w.get("bias1"))
-            q = jnp.einsum("bsd,dhk->bshk", h, w["wq"].astype(dt))
-            k = jnp.einsum("bsd,dhk->bshk", h, w["wk"].astype(dt))
-            v = jnp.einsum("bsd,dhk->bshk", h, w["wv"].astype(dt))
-            q = checkpoint_name(q, "attn_q")
-            k = checkpoint_name(k, "attn_k")
-            v = checkpoint_name(v, "attn_v")
+            # [B, S, H * Dh] against the weight as [D, H * Dh], and saved so
+            # under "dots": a 64-wide minor axis is stored, copied and
+            # multiplied at half of the 128 lanes, and a reshape to it from
+            # a kernel's full-lane operand is a copy. The heads are split
+            # out only for what works on them (QK-norm, RoPE, the kernels'
+            # own block maps).
+            def project(name, saved):
+                wt = w[name].astype(dt)
+                y = jnp.einsum("bsd,de->bse", h,
+                               wt.reshape(wt.shape[0], -1))
+                y = checkpoint_name(y, saved)
+                return y.reshape(*y.shape[:2], *wt.shape[1:])
+
+            q = project("wq", "attn_q")
+            k = project("wk", "attn_k")
+            v = project("wv", "attn_v")
             if c.qk_norm:
                 q = self._qk_norm(q, w["q_norm"])
                 k = self._qk_norm(k, w["k_norm"])
@@ -451,7 +474,10 @@ class GPT:
         with jax.named_scope("attn_kernel"):
             attn = self._attention(q, k, v)
         with jax.named_scope("attn_out"):
-            attn = jnp.einsum("bshk,hkd->bsd", attn, w["wo"].astype(dt))
+            wo = w["wo"].astype(dt)
+            attn = jnp.einsum("bse,ed->bsd",
+                              attn.reshape(*attn.shape[:2], -1),
+                              wo.reshape(-1, wo.shape[-1]))
             x = x + self._constrain(attn, "act_batch", "act_seq",
                                     "act_embed")
 

@@ -7,8 +7,17 @@ kernel: blocked online softmax, GQA-aware block mapping, and a custom VJP
 whose backward is two more Pallas kernels (dq and dk/dv) driven by the
 saved logsumexp.
 
-Shapes follow [batch, num_heads, seq, head_dim] ("BHSD"). GQA is
-expressed as num_q_heads = G * num_kv_heads; the kernels map q-head h to
+Two layouts, one set of kernel bodies (`_Heads`). Models hand q, k, v over
+as their projections wrote them, [batch, seq, num_heads, head_dim] (a free
+reshape of [batch, seq, num_heads * head_dim]: `seq_major`), and get dq, dk,
+dv back the same way. At a head width under the 128 lanes a grid step's
+block is 128 lanes of that minor axis, two heads of 64, walked one after
+the other, so nothing between a projection and a kernel is stored, copied
+or multiplied at half-filled lanes; the forward output and dO are [batch,
+num_heads, head_dim, seq], as the out-projection reads and writes them.
+`flash_attention`'s own entry, ring attention and head widths that fill the
+lanes keep [batch, num_heads, seq, head_dim] ("BHSD"), a block a head. GQA
+is expressed as num_q_heads = G * num_kv_heads; the kernels map q-head h to
 kv-head h // G in BlockSpec index maps, so no K/V replication ever
 materializes.
 
@@ -229,18 +238,31 @@ def _dot_tn(a, b):
                                preferred_element_type=jnp.float32)
 
 
-def _rows(ref, start, size, valid=None):
-    """Rows [start, start + size) of a [1, 1, rows, width] block. Rows from
-    `valid` on are zeroed if it is given: an out-of-bounds block read
-    returns unspecified padding (NaN under the interpreter), and 0 * NaN
-    would leak through the matmuls."""
+def _rows(ref, cols, start, size, valid=None):
+    """Rows [start, start + size) of one head's columns `cols` of a [rows,
+    lanes] block. Rows from `valid` on are zeroed if it is given: an
+    out-of-bounds block read returns unspecified padding (NaN under the
+    interpreter), and 0 * NaN would leak through the matmuls."""
     if isinstance(start, int):
-        x = ref[0, 0, start:start + size, :]
+        x = ref[start:start + size, cols]
     else:
-        x = ref[0, 0, pl.ds(pl.multiple_of(start, size), size), :]
+        x = ref[pl.ds(pl.multiple_of(start, size), size), cols]
     if valid is None:
         return x
     rows = start + jax.lax.broadcasted_iota(jnp.int32, (size, 1), 0)
+    return jnp.where(rows < valid, x, jnp.zeros_like(x))
+
+
+def _rows_t(ref, hh, start, size, valid=None):
+    """The same rows of head `hh` of a [heads, head_dim, rows] block, as the
+    [head_dim, size] tile they are stored as."""
+    if isinstance(start, int):
+        x = ref[hh, :, start:start + size]
+    else:
+        x = ref[hh, :, pl.ds(pl.multiple_of(start, size), size)]
+    if valid is None:
+        return x
+    rows = start + jax.lax.broadcasted_iota(jnp.int32, (1, size), 1)
     return jnp.where(rows < valid, x, jnp.zeros_like(x))
 
 
@@ -340,13 +362,114 @@ class _Tiling:
                         0, self.nq - 1)
 
 
+class _Heads:
+    """Where a call's heads lie in its arrays, and which of them a grid
+    step holds. One set of kernel bodies serves both layouts through it.
+
+    Head-major, [B, H, S, Dh]: a grid step's block of q, k, v and their
+    gradients is one head, [rows, Dh]. Sequence-major, [B, S, H * Dh] (the
+    projections' own, at a head width under the 128 lanes): a block is
+    [rows, 128 lanes], `per` = 128 // Dh heads side by side, and the bodies
+    walk them one after the other, each over its own columns. Where H is not
+    a multiple of `per` (gpt2_xl: 25 heads of 64) the last block holds fewer
+    heads and the bodies skip the absent ones on the program id. The rows
+    of statistics (lse, delta) and the transposed forward output are
+    head-major in both, `per` heads a block."""
+
+    def __init__(self, q_shape, k_shape, seq_major):
+        self.seq_major = seq_major
+        if seq_major:
+            self.batch, self.q_len, self.num, self.dim = q_shape
+            self.k_len, self.num_kv = k_shape[1], k_shape[2]
+        else:
+            self.batch, self.num, self.q_len, self.dim = q_shape
+            self.num_kv, self.k_len = k_shape[1], k_shape[2]
+        self.group = self.num // self.num_kv
+        self.lanes = self.dim
+        if seq_major:
+            assert seq_major_fits(q_shape, k_shape)
+            self.lanes = min(128, self.num * self.dim)
+        self.per = self.lanes // self.dim
+        self.steps = pl.cdiv(self.num, self.per)
+
+    def arrays(self, *xs):
+        """The arrays as the BlockSpecs index them."""
+        if not self.seq_major:
+            return xs
+        return tuple(x.reshape(*x.shape[:2], -1) for x in xs)
+
+    def shape(self, length):
+        """Of an array of `length` rows of every q head."""
+        if self.seq_major:
+            return (self.batch, length, self.num * self.dim)
+        return (self.batch, self.num, length, self.dim)
+
+    def spec(self, rows, row_block, kv=False):
+        """`rows` rows of a grid step's heads of q, do, dq (or, with `kv`,
+        of k and v under GQA); `row_block(i, j)` of the grid's last two
+        indices is the block along the sequence."""
+        head = (lambda h: h // self.group) if kv else (lambda h: h)
+        if self.seq_major:
+            return pl.BlockSpec(
+                (None, rows, self.lanes),
+                lambda b, h, i, j: (b, row_block(i, j), head(h)))
+        return pl.BlockSpec(
+            (None, None, rows, self.dim),
+            lambda b, h, i, j: (b, head(h), row_block(i, j), 0))
+
+    def stat_spec(self, rows, cols, index):
+        """A [rows, cols] block a head of a head-major [B, H, *, *] array;
+        `index(i, j)` gives the last two block indices."""
+        return pl.BlockSpec((None, self.per, rows, cols),
+                            lambda b, h, i, j: (b, h, *index(i, j)))
+
+    def cols(self, hh):
+        """Columns of the block's `hh`-th head (and its rows of a
+        transposed [per * Dh, n] accumulator)."""
+        return slice(hh * self.dim, (hh + 1) * self.dim)
+
+    def each(self, body):
+        """A function that runs `body(hh)` for every head of the grid
+        step's block. Called at the kernel's top level: the program id is
+        read there, not inside a conditional. Where the last block holds
+        fewer heads, it and the full blocks are two straight-line regions
+        (a conditional around each head would keep the scheduler from
+        overlapping one head's code with the next's in every block)."""
+        def run(heads=self.per):
+            for hh in range(heads):
+                body(hh)
+
+        rest = self.num % self.per
+        if rest == 0:
+            return run
+        block, last = pl.program_id(1), self.steps - 1
+
+        def split():
+            pl.when(block < last)(run)
+            pl.when(block == last)(functools.partial(run, rest))
+        return split
+
+
+def seq_major_fits(q_shape, k_shape):
+    """Whether the kernels read [B, S, H, Dh] arrays as they are: where the
+    head width divides the 128 lanes and there is no GQA group (two q heads
+    of a block would want different columns of one k block). A width that
+    fills the lanes is stored and multiplied at full lanes in either layout,
+    and its [rows, 128] blocks of [B, S, H * Dh] are 256-byte pieces to
+    fetch where a head-major block is one run: on the v5e the kernels were
+    a quarter slower so ([5, 16, 4096, 128], PERF.md PR 31)."""
+    dim = q_shape[-1]
+    return dim < 128 and 128 % dim == 0 and q_shape[2] == k_shape[2]
+
+
 # ---------------------------------------------------------------------------
 # Pallas forward kernel
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                *, scale, t, sub, chunk, transposed_out):
-    """One (q tile, k tile) step of the forward pass.
+                *, scale, t, heads, sub, chunk, transposed_out):
+    """One (q tile, k tile) step of the forward pass, for each head of the
+    block.
 
     Scores are laid out [keys, queries]. Each `sub` queries of the q tile
     walk their live `chunk`s of keys with online softmax; the running
@@ -370,17 +493,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    def walk(rel):
+    def walk(rel, hh):
+        cols, row = heads.cols(hh), slice(hh, hh + 1)
         for r0 in range(0, t.block_q, sub):
             rs = slice(r0, r0 + sub)
             interior_end, live_end = _k_chunk_bounds(
                 r0, sub, rel, t.span(q_valid, k_valid)[1], chunk=chunk)
-            q = _scaled(q_ref[0, 0, rs, :], scale)
+            q = _scaled(q_ref[rs, cols], scale)
 
             def step(c, carry, edge):
                 m_prev, l_prev, acc = carry
                 pad = k_valid if edge else None
-                st = _dot_nt(_rows(k_ref, c * chunk, chunk, pad), q)
+                st = _dot_nt(_rows(k_ref, cols, c * chunk, chunk, pad), q)
                 mask = _edge_mask(c * chunk, r0, st.shape, rel, q_valid,
                                   k_valid) if edge else None
                 if mask is not None:
@@ -391,70 +515,73 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
                 pt = jnp.exp(st - m_new)
                 if mask is not None and keyless:
                     pt = jnp.where(mask, pt, 0.0)   # m == NEG_INF: exp(0)
-                pv = _dot_tn(_rows(v_ref, c * chunk, chunk, pad),
+                pv = _dot_tn(_rows(v_ref, cols, c * chunk, chunk, pad),
                              pt.astype(dtype))
                 return (m_new,
                         l_prev * alpha + jnp.sum(pt, axis=0, keepdims=True),
                         acc * alpha + pv)
 
-            m_ref[:, rs], l_ref[:, rs], acc_ref[:, rs] = _walk(
+            m_ref[row, rs], l_ref[row, rs], acc_ref[cols, rs] = _walk(
                 (0, interior_end, live_end), step,
-                (m_ref[:, rs], l_ref[:, rs], acc_ref[:, rs]))
+                (m_ref[row, rs], l_ref[row, rs], acc_ref[cols, rs]))
 
-    t.for_each_class(qb, kb, walk)
+    heads.each(lambda hh: t.for_each_class(
+        qb, kb, functools.partial(walk, hh=hh)))()
 
-    @_when(kb == t.nk - 1)
-    def _finalize():
-        l = jnp.where(l_ref[:] == 0.0, 1.0, l_ref[:])
-        o = acc_ref[:] / l                  # [head_dim, block_q]
-        o_ref[0, 0] = (o if transposed_out else o.T).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_ref[:] + jnp.log(l)
+    def write(hh):
+        cols, row = heads.cols(hh), slice(hh, hh + 1)
+        l = jnp.where(l_ref[row] == 0.0, 1.0, l_ref[row])
+        o = acc_ref[cols] / l                   # [head_dim, block_q]
+        if transposed_out:
+            o_ref[hh] = o.astype(o_ref.dtype)
+        else:
+            o_ref[:, cols] = o.T.astype(o_ref.dtype)
+        lse_ref[hh] = m_ref[row] + jnp.log(l)
+
+    _when(kb == t.nk - 1)(heads.each(write))
 
 
 def _fwd_pallas(q, k, v, *, scale, causal, block_q, block_k, interpret,
-                transposed_out=False):
-    """Returns (out [B, H, S, D] in q's dtype, lse [B, H, S] float32); with
-    `transposed_out`, out is [B, H, D, S]."""
-    batch, num_q_heads, q_len, head_dim = q.shape
-    num_kv_heads, k_len = k.shape[1], k.shape[2]
-    group = num_q_heads // num_kv_heads
-    t = _Tiling(q_len, k_len, block_q, block_k, causal)
-    sub, chunk = _rect(t.block_q, t.block_k, head_dim, _FWD_RECT)
+                transposed_out=False, seq_major=False):
+    """q, k, v: [B, H, S, D] or, with `seq_major`, [B, S, H, D]. Returns
+    (out in q's layout and dtype, lse [B, H, S] float32); with
+    `transposed_out`, out is [B, H, D, S] in both layouts."""
+    heads = _Heads(q.shape, k.shape, seq_major)
+    t = _Tiling(heads.q_len, heads.k_len, block_q, block_k, causal)
+    sub, chunk = _rect(t.block_q, t.block_k, heads.dim, _FWD_RECT)
 
-    q_spec = pl.BlockSpec((1, 1, t.block_q, head_dim),
-                          lambda b, h, i, j: (b, h, i, 0))
-    kv_spec = pl.BlockSpec(
-        (1, 1, t.block_k, head_dim),
-        lambda b, h, i, j: (b, h // group,
-                            jnp.minimum(j, t.last_live_k(i)), 0))
-    out_spec, out_shape = q_spec, q.shape
+    q_spec = heads.spec(t.block_q, lambda i, j: i)
+    kv_spec = heads.spec(
+        t.block_k, lambda i, j: jnp.minimum(j, t.last_live_k(i)), kv=True)
+    out_spec, out_shape = q_spec, heads.shape(t.q_len)
     if transposed_out:
-        out_spec = pl.BlockSpec((1, 1, head_dim, t.block_q),
-                                lambda b, h, i, j: (b, h, 0, i))
-        out_shape = (batch, num_q_heads, head_dim, q_len)
+        out_spec = heads.stat_spec(heads.dim, t.block_q, lambda i, j: (0, i))
+        out_shape = (heads.batch, heads.num, heads.dim, t.q_len)
     out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, t=t, sub=sub,
-                          chunk=chunk, transposed_out=transposed_out),
-        grid=(batch, num_q_heads, t.nq, t.nk),
+        functools.partial(_fwd_kernel, scale=scale, t=t, heads=heads,
+                          sub=sub, chunk=chunk,
+                          transposed_out=transposed_out),
+        grid=(heads.batch, heads.steps, t.nq, t.nk),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[
             out_spec,
-            pl.BlockSpec((1, 1, 1, t.block_q),
-                         lambda b, h, i, j: (b, h, 0, i)),
+            heads.stat_spec(1, t.block_q, lambda i, j: (0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(out_shape, q.dtype),
-            jax.ShapeDtypeStruct((batch, num_q_heads, 1, q_len),
+            jax.ShapeDtypeStruct((heads.batch, heads.num, 1, t.q_len),
                                  jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((head_dim, t.block_q), jnp.float32),
-            pltpu.VMEM((1, t.block_q), jnp.float32),
-            pltpu.VMEM((1, t.block_q), jnp.float32),
+            pltpu.VMEM((heads.lanes, t.block_q), jnp.float32),
+            pltpu.VMEM((heads.per, t.block_q), jnp.float32),
+            pltpu.VMEM((heads.per, t.block_q), jnp.float32),
         ],
         interpret=interpret,
         name="flash_fwd",
-    )(q, k, v)
+    )(*heads.arrays(q, k, v))
+    if not transposed_out:
+        out = out.reshape(q.shape)
     return out, lse[:, :, 0]
 
 
@@ -463,12 +590,13 @@ def _fwd_pallas(q, k, v, *, scale, causal, block_q, block_k, interpret,
 # ---------------------------------------------------------------------------
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   acc_ref, *, scale, t, sub, chunk):
+                   acc_ref, *, scale, t, heads, sub, chunk, do_t):
     """One (q tile, k tile) step of dq, walked as the forward pass is:
     p = exp(s - lse) and ds = p * (dp - delta) per [chunk keys, sub
     queries] rectangle, dq^T ([head_dim, sub]) accumulated in registers
-    and scaled once at the end. lse and delta are [1, sub] rows. Matmul
-    operands in the inputs' dtype (ds cast to it), the rest float32."""
+    and scaled once at the end, when the block's heads are written with one
+    rounding. lse and delta are [1, sub] rows. Matmul operands in the
+    inputs' dtype (ds cast to it), the rest float32."""
     qb, kb = t.ids(2, 3)
     q_valid, k_valid = t.valid(qb, kb)
     dtype = q_ref.dtype
@@ -477,48 +605,53 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def walk(rel):
+    def walk(rel, hh):
+        cols = heads.cols(hh)
         for r0 in range(0, t.block_q, sub):
             rs = slice(r0, r0 + sub)
             interior_end, live_end = _k_chunk_bounds(
                 r0, sub, rel, t.span(q_valid, k_valid)[1], chunk=chunk)
-            q = _scaled(q_ref[0, 0, rs, :], scale)
-            do = do_ref[0, 0, rs, :]
-            lse = lse_ref[0, 0, :, rs]
-            delta = delta_ref[0, 0, :, rs]
+            q = _scaled(q_ref[rs, cols], scale)
+            do = do_ref[hh, :, rs] if do_t else do_ref[rs, cols]
+            lse = lse_ref[hh, :, rs]
+            delta = delta_ref[hh, :, rs]
 
             def step(c, acc, edge):
                 pad = k_valid if edge else None
-                k = _rows(k_ref, c * chunk, chunk, pad)
-                v = _rows(v_ref, c * chunk, chunk, pad)
+                k = _rows(k_ref, cols, c * chunk, chunk, pad)
+                v = _rows(v_ref, cols, c * chunk, chunk, pad)
                 pt = jnp.exp(_dot_nt(k, q) - lse)
                 mask = _edge_mask(c * chunk, r0, pt.shape, rel, q_valid,
                                   k_valid) if edge else None
                 if mask is not None:
                     # also: padded queries carry garbage lse
                     pt = jnp.where(mask, pt, 0.0)
-                dst = pt * (_dot_nt(v, do) - delta)
+                dp = _dot_nn(v, do) if do_t else _dot_nt(v, do)
+                dst = pt * (dp - delta)
                 return acc + _dot_tn(k, dst.astype(dtype))
 
-            acc_ref[:, rs] = _walk((0, interior_end, live_end), step,
-                                   acc_ref[:, rs])
+            acc_ref[cols, rs] = _walk((0, interior_end, live_end), step,
+                                      acc_ref[cols, rs])
 
-    t.for_each_class(qb, kb, walk)
+    heads.each(lambda hh: t.for_each_class(
+        qb, kb, functools.partial(walk, hh=hh)))()
 
     @_when(kb == t.nk - 1)
     def _finalize():
-        dq_ref[0, 0] = (acc_ref[:] * scale).T.astype(dq_ref.dtype)
+        dq_ref[:] = (acc_ref[:] * scale).T.astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, *, scale, t, sub, chunk):
+                    dk_ref, dv_ref, dk_acc, dv_acc, *, scale, t, heads, sub,
+                    chunk, do_t):
     """One (k tile, q tile) step of dk and dv: each `sub` keys of the k
     tile walk their live `chunk`s of queries, scores again [keys, queries],
     dk and dv ([sub, head_dim]) accumulated in registers. k is scaled once
-    per sub-block for the scores and dk once at the end. lse and delta
-    arrive whole, as [chunks, chunk] rows, so that the walk indexes them on
-    the sublane axis. Matmul operands in the inputs' dtype (p and ds cast
-    to it), the rest float32."""
+    per sub-block for the scores and dk once at the end, when the block's
+    heads are written with one rounding. lse and delta arrive whole, as
+    [chunks, chunk] rows, so that the walk indexes them on the sublane
+    axis. Matmul operands in the inputs' dtype (p and ds cast to it), the
+    rest float32. With `do_t`, dO comes [head_dim, queries] a head."""
     qb, kb = t.ids(3, 2)
     q_valid, k_valid = t.valid(qb, kb)
     n_chunks = t.block_q // chunk
@@ -529,143 +662,167 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    def walk(rel):
+    def walk(rel, hh):
+        cols = heads.cols(hh)
         for r0 in range(0, t.block_k, sub):
             rs = slice(r0, r0 + sub)
             live_start, interior_start, interior_end, live_end = \
                 _q_chunk_bounds(r0, sub, rel, *t.span(q_valid, k_valid),
                                 chunk=chunk)
-            k = _scaled(_rows(k_ref, r0, sub, k_valid), scale)
-            v = _rows(v_ref, r0, sub, k_valid)
+            k = _scaled(_rows(k_ref, cols, r0, sub, k_valid), scale)
+            v = _rows(v_ref, cols, r0, sub, k_valid)
 
             def step(c, carry, edge):
                 dk, dv = carry
                 pad = q_valid if edge else None
-                q = _rows(q_ref, c * chunk, chunk, pad)
-                do = _rows(do_ref, c * chunk, chunk, pad)
+                q = _rows(q_ref, cols, c * chunk, chunk, pad)
+                if do_t:
+                    do = _rows_t(do_ref, hh, c * chunk, chunk, pad)
+                else:
+                    do = _rows(do_ref, cols, c * chunk, chunk, pad)
                 row = qb * n_chunks + c
                 row = slice(row, row + 1) if _static(row) else pl.ds(row, 1)
-                lse = lse_ref[0, 0, row, :]
-                delta = delta_ref[0, 0, row, :]
+                lse = lse_ref[hh, row, :]
+                delta = delta_ref[hh, row, :]
                 pt = jnp.exp(_dot_nt(k, q) - lse)
                 mask = _edge_mask(r0, c * chunk, pt.shape, rel, q_valid,
                                   k_valid) if edge else None
                 if mask is not None:
                     pt = jnp.where(mask, pt, 0.0)
-                dv = dv + _dot_nn(pt.astype(dtype), do)
-                dst = pt * (_dot_nt(v, do) - delta)
+                p = pt.astype(dtype)
+                dv = dv + (_dot_nt(p, do) if do_t else _dot_nn(p, do))
+                dp = _dot_nn(v, do) if do_t else _dot_nt(v, do)
+                dst = pt * (dp - delta)
                 return dk + _dot_nn(dst.astype(dtype), q), dv
 
             # the diagonal's edge chunks come first, a ragged end's last
             carry = _walk((live_start, live_start, interior_start), step,
-                          (dk_acc[rs], dv_acc[rs]))
-            dk_acc[rs], dv_acc[rs] = _walk(
+                          (dk_acc[rs, cols], dv_acc[rs, cols]))
+            dk_acc[rs, cols], dv_acc[rs, cols] = _walk(
                 (interior_start, interior_end, live_end), step, carry)
 
-    t.for_each_class(qb, kb, walk)
+    heads.each(lambda hh: t.for_each_class(
+        qb, kb, functools.partial(walk, hh=hh)))()
 
     @_when(qb == t.nq - 1)
     def _finalize():
-        dk_ref[0, 0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
+        dk_ref[:] = (dk_acc[:] * scale).astype(dk_ref.dtype)
+        dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _bwd_pallas(q, k, v, out, lse, do, *, scale, causal, block_q, block_k,
-                interpret, delta=None, keep_f32=False):
-    """lse and delta (if given) are [B, H, S] float32."""
-    batch, num_q_heads, q_len, head_dim = q.shape
-    num_kv_heads, k_len = k.shape[1], k.shape[2]
-    group = num_q_heads // num_kv_heads
-    t = _Tiling(q_len, k_len, block_q, block_k, causal)
+def _bwd_pallas(q, k, v, lse, do, delta, *, scale, causal, block_q, block_k,
+                interpret, keep_f32=False, seq_major=False, do_t=False):
+    """q, k, v, do: [B, H, S, D] or, with `seq_major`, [B, S, H, D]; with
+    `do_t`, do is [B, H, D, S] in both, as the forward pass's transposed
+    output is. lse and delta = rowsum(dO * O): [B, H, S] float32. dq, dk, dv
+    come back in the inputs' layout, rounded once from the kernels' float32
+    accumulators to the inputs' dtype (left float32 with `keep_f32`)."""
+    heads = _Heads(q.shape, k.shape, seq_major)
+    t = _Tiling(heads.q_len, heads.k_len, block_q, block_k, causal)
+    q3, k3, v3 = heads.arrays(q, k, v)
+    do3 = do if do_t else heads.arrays(do)[0]
 
-    if delta is None:
-        # delta_i = rowsum(dO * O); cheap, fused by XLA.
-        delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                        axis=-1)
+    q_spec = heads.spec(t.block_q, lambda i, j: i)
+    kv_spec = heads.spec(
+        t.block_k, lambda i, j: jnp.minimum(j, t.last_live_k(i)), kv=True)
+    row_spec = heads.stat_spec(1, t.block_q, lambda i, j: (0, i))
+    do_spec = q_spec
+    if do_t:
+        do_spec = heads.stat_spec(heads.dim, t.block_q, lambda i, j: (0, i))
 
-    q_spec = pl.BlockSpec((1, 1, t.block_q, head_dim),
-                          lambda b, h, i, j: (b, h, i, 0))
-    kv_spec = pl.BlockSpec(
-        (1, 1, t.block_k, head_dim),
-        lambda b, h, i, j: (b, h // group,
-                            jnp.minimum(j, t.last_live_k(i)), 0))
-    row_spec = pl.BlockSpec((1, 1, 1, t.block_q),
-                            lambda b, h, i, j: (b, h, 0, i))
-
-    sub, chunk = _rect(t.block_q, t.block_k, head_dim, _DQ_RECT)
-    dq_dtype = jnp.float32 if keep_f32 else q.dtype
+    sub, chunk = _rect(t.block_q, t.block_k, heads.dim, _DQ_RECT)
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, t=t, sub=sub,
-                          chunk=chunk),
-        grid=(batch, num_q_heads, t.nq, t.nk),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        functools.partial(_bwd_dq_kernel, scale=scale, t=t, heads=heads,
+                          sub=sub, chunk=chunk, do_t=do_t),
+        grid=(heads.batch, heads.steps, t.nq, t.nk),
+        in_specs=[q_spec, kv_spec, kv_spec, do_spec, row_spec, row_spec],
         out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, dq_dtype),
-        scratch_shapes=[pltpu.VMEM((head_dim, t.block_q), jnp.float32)],
+        out_shape=jax.ShapeDtypeStruct(
+            q3.shape, jnp.float32 if keep_f32 else q.dtype),
+        scratch_shapes=[pltpu.VMEM((heads.lanes, t.block_q), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dq",
-    )(q, k, v, do, lse[:, :, None], delta[:, :, None])
+    )(q3, k3, v3, do3, lse[:, :, None], delta[:, :, None])
 
     # dk/dv: kv block is the outer grid axis, q blocks stream innermost.
-    sub, chunk = _rect(t.block_k, t.block_q, head_dim, _DKV_RECT)
+    sub, chunk = _rect(t.block_k, t.block_q, heads.dim, _DKV_RECT)
     rows = t.nq * t.block_q // chunk
 
     def chunked(x):
-        x = jnp.pad(x, ((0, 0), (0, 0), (0, rows * chunk - q_len)))
-        return x.reshape(batch, num_q_heads, rows, chunk)
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, rows * chunk - t.q_len)))
+        return x.reshape(heads.batch, heads.num, rows, chunk)
 
-    q_spec_i = pl.BlockSpec(
-        (1, 1, t.block_q, head_dim),
-        lambda b, h, j, i: (b, h, jnp.maximum(i, t.first_live_q(j)), 0))
-    kv_spec_i = pl.BlockSpec((1, 1, t.block_k, head_dim),
-                             lambda b, h, j, i: (b, h // group, j, 0))
-    row_spec_i = pl.BlockSpec((1, 1, rows, chunk),
-                              lambda b, h, j, i: (b, h, 0, 0))
-    kv_out_spec = pl.BlockSpec((1, 1, t.block_k, head_dim),
-                               lambda b, h, j, i: (b, h, j, 0))
+    live_q = lambda j, i: jnp.maximum(i, t.first_live_q(j))
+    q_spec_i = do_spec_i = heads.spec(t.block_q, live_q)
+    if do_t:
+        do_spec_i = heads.stat_spec(heads.dim, t.block_q,
+                                    lambda j, i: (0, live_q(j, i)))
+    kv_spec_i = heads.spec(t.block_k, lambda j, i: j, kv=True)
+    row_spec_i = heads.stat_spec(rows, chunk, lambda j, i: (0, 0))
+    kv_out_spec = heads.spec(t.block_k, lambda j, i: j)
 
-    # Accumulated per q-head, then reduced over the GQA group outside.
-    dkv_shape = (batch, num_q_heads, k_len, head_dim)
+    # Accumulated per q-head; a GQA group is reduced outside, in float32.
+    summed = heads.group > 1
+    dkv = jax.ShapeDtypeStruct(
+        heads.shape(t.k_len),
+        jnp.float32 if keep_f32 or summed else k.dtype)
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, t=t, sub=sub,
-                          chunk=chunk),
-        grid=(batch, num_q_heads, t.nk, t.nq),
-        in_specs=[q_spec_i, kv_spec_i, kv_spec_i, q_spec_i, row_spec_i,
+        functools.partial(_bwd_dkv_kernel, scale=scale, t=t, heads=heads,
+                          sub=sub, chunk=chunk, do_t=do_t),
+        grid=(heads.batch, heads.steps, t.nk, t.nq),
+        in_specs=[q_spec_i, kv_spec_i, kv_spec_i, do_spec_i, row_spec_i,
                   row_spec_i],
         out_specs=[kv_out_spec, kv_out_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct(dkv_shape, jnp.float32),
-            jax.ShapeDtypeStruct(dkv_shape, jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((t.block_k, head_dim), jnp.float32),
-                        pltpu.VMEM((t.block_k, head_dim), jnp.float32)],
+        out_shape=[dkv, dkv],
+        scratch_shapes=[pltpu.VMEM((t.block_k, heads.lanes), jnp.float32),
+                        pltpu.VMEM((t.block_k, heads.lanes), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dkv",
-    )(q, k, v, do, chunked(lse), chunked(delta))
+    )(q3, k3, v3, do3, chunked(lse), chunked(delta))
 
-    if group > 1:
-        dk = dk.reshape(batch, num_kv_heads, group, k_len, head_dim)
-        dk = dk.sum(axis=2)
-        dv = dv.reshape(batch, num_kv_heads, group, k_len, head_dim)
-        dv = dv.sum(axis=2)
-    if keep_f32:
-        return dq, dk, dv
-    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
+    if summed:
+        # [.., Hk, G, ..]: the group is the minor part of the head axis
+        axis = 2 if seq_major else 1
+        split = (*k.shape[:axis], heads.num_kv, heads.group,
+                 *k.shape[axis + 1:])
+        out_dtype = jnp.float32 if keep_f32 else k.dtype
+        dk = dk.reshape(split).sum(axis=axis + 1).astype(out_dtype)
+        dv = dv.reshape(split).sum(axis=axis + 1).astype(out_dtype)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
 # ---------------------------------------------------------------------------
 # Public flash attention with custom VJP
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal: bool = True,
                     scale: Optional[float] = None,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_k: int = DEFAULT_BLOCK_K,
-                    interpret: bool = False):
-    """FlashAttention-2 on TPU (Pallas). [B, H, S, D]; GQA via Hk | H."""
-    out, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret)
-    return out
+                    interpret: bool = False, seq_major: bool = False):
+    """FlashAttention-2 on TPU (Pallas). [B, H, S, D] or, with `seq_major`,
+    [B, S, H, D] in and out; GQA via Hk | H. A sequence-major call whose
+    heads the kernels do not read in place (`seq_major_fits`: a head width
+    that fills the lanes, or does not divide them, or a GQA group) goes
+    through the head-major kernels and XLA's transposes."""
+    if seq_major and not seq_major_fits(q.shape, k.shape):
+        return _via_head_major(
+            lambda q, k, v: _flash(q, k, v, causal, scale, block_q, block_k,
+                                   interpret, False), q, k, v)
+    return _flash(q, k, v, causal, scale, block_q, block_k, interpret,
+                  seq_major)
+
+
+def _via_head_major(fn, q, k, v):
+    """`fn`, which takes and returns [B, H, S, D], on [B, S, H, D] arrays."""
+    out = fn(*(jnp.swapaxes(x, 1, 2) for x in (q, k, v)))
+    return jnp.swapaxes(out, 1, 2)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, scale, block_q, block_k, interpret, seq_major):
+    return _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+                      seq_major)[0]
 
 
 # Names of the forward kernel's two results as `jax.checkpoint` sees them. A
@@ -680,7 +837,8 @@ def _narrow(q):
     return q.shape[-1] % 128 != 0
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+               seq_major):
     """The residual that carries the output is lane-dense. An array whose
     minor dimension is narrower than the 128 lanes of a tile is stored
     padded to them (head width 64: twice its size, as a saved activation and
@@ -693,31 +851,37 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
     transposed = _narrow(q)
     out, lse = _fwd_pallas(q, k, v, scale=scale_val, causal=causal,
                            block_q=block_q, block_k=block_k,
-                           interpret=interpret, transposed_out=transposed)
+                           interpret=interpret, transposed_out=transposed,
+                           seq_major=seq_major)
     out = checkpoint_name(out, FLASH_RESIDUAL_NAMES[0])
     lse = checkpoint_name(lse, FLASH_RESIDUAL_NAMES[1])
-    primal = jnp.swapaxes(out, 2, 3) if transposed else out
+    primal = out
+    if transposed:      # [B, H, D, S] -> the inputs' layout
+        primal = jnp.transpose(out, (0, 3, 1, 2) if seq_major
+                               else (0, 1, 3, 2))
     return primal, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
+def _flash_bwd(causal, scale, block_q, block_k, interpret, seq_major, res,
+               g):
     q, k, v, out, lse = res
     scale_val = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    delta = None
-    if _narrow(q):
-        # the kernels want the output for delta = rowsum(dO * O) alone: taken
-        # from the transposed output it costs no copy back to [B, H, S, D]
-        delta = jnp.sum(jnp.swapaxes(g, 2, 3).astype(jnp.float32)
-                        * out.astype(jnp.float32), axis=2)
-        out = None
-    dq, dk, dv = _bwd_pallas(q, k, v, out, lse, g, scale=scale_val,
-                             causal=causal, block_q=block_q,
-                             block_k=block_k, interpret=interpret,
-                             delta=delta)
-    return dq, dk, dv
+    # delta = rowsum(dO * O), [B, H, S]: all the kernels want of the output
+    narrow = _narrow(q)
+    if narrow:
+        # dO in the kept output's layout, [B, H, D, S]: the out-projection's
+        # backward matmul writes it so, delta costs no copy back to the
+        # inputs' layout, and the kernels read it as it is
+        g = jnp.transpose(g, (0, 2, 3, 1) if seq_major else (0, 1, 3, 2))
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=2 if narrow else -1)
+    return _bwd_pallas(q, k, v, lse, g, delta, scale=scale_val,
+                       causal=causal, block_q=block_q, block_k=block_k,
+                       interpret=interpret, seq_major=seq_major,
+                       do_t=narrow)
 
 
-flash_attention.defvjp(_flash_fwd, _flash_bwd)
+_flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -728,8 +892,10 @@ def dot_product_attention(q, k, v, causal: bool = True,
                           scale: Optional[float] = None,
                           impl: str = "auto",
                           block_q: int = DEFAULT_BLOCK_Q,
-                          block_k: int = DEFAULT_BLOCK_K) -> jax.Array:
-    """Attention entry point used by models.
+                          block_k: int = DEFAULT_BLOCK_K,
+                          seq_major: bool = False) -> jax.Array:
+    """Attention entry point used by models. [B, H, S, D] or, with
+    `seq_major`, [B, S, H, D] (the projections' own layout) in and out.
 
     impl: "auto" (pallas on TPU, reference elsewhere), "pallas",
     "pallas_interpret" (kernel under the interpreter — CPU tests),
@@ -738,11 +904,12 @@ def dot_product_attention(q, k, v, causal: bool = True,
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "reference"
     if impl == "reference":
-        return attention_reference(q, k, v, causal=causal, scale=scale)
-    if impl == "pallas":
+        reference = functools.partial(attention_reference, causal=causal,
+                                      scale=scale)
+        if seq_major:
+            return _via_head_major(reference, q, k, v)
+        return reference(q, k, v)
+    if impl in ("pallas", "pallas_interpret"):
         return flash_attention(q, k, v, causal, scale, block_q, block_k,
-                               False)
-    if impl == "pallas_interpret":
-        return flash_attention(q, k, v, causal, scale, block_q, block_k,
-                               True)
+                               impl == "pallas_interpret", seq_major)
     raise ValueError(f"unknown attention impl {impl!r}")

@@ -90,9 +90,9 @@ def _partial_fwd_pallas(q, k, v, scale, diag, block_q, block_k, interpret):
 
 def _partial_bwd_pallas(q, k, v, do, lse, delta, scale, diag, block_q,
                         block_k, interpret):
-    return _bwd_pallas(q, k, v, None, lse, do, scale=scale, causal=diag,
+    return _bwd_pallas(q, k, v, lse, do, delta, scale=scale, causal=diag,
                        block_q=block_q, block_k=block_k, interpret=interpret,
-                       delta=delta, keep_f32=True)
+                       keep_f32=True)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))

@@ -14,6 +14,7 @@ back without a chip.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -51,17 +52,26 @@ def _on(sharding, tree):
         tree)
 
 
+@pytest.mark.parametrize("seq_major", [True, False],
+                         ids=["seq_major", "head_major"])
 @pytest.mark.parametrize("shape", [(12, 16, 1024, 64), (16, 25, 1024, 64)],
                          ids=["gpt2_medium", "gpt2_xl"])
-def test_flash_attention_fwd_bwd_compiles_at_bench_width(v5e, shape):
+def test_flash_attention_fwd_bwd_compiles_at_bench_width(v5e, shape,
+                                                         seq_major):
     """The three flash kernels at the widths the benchmark's steps run,
     bf16, at the shipped tile: [batch 12, 16 heads, seq 1024, head_dim 64]
-    (gpt2_medium) and [16, 25, 1024, 64] (gpt2_xl, a chip's share)."""
+    (gpt2_medium) and [16, 25, 1024, 64] (gpt2_xl, a chip's share), in the
+    layout the models use ([B, S, H, 64]: 128-lane blocks of two heads,
+    the thirteenth block of gpt2_xl's 1,600 lanes half there) and in
+    `flash_attention`'s own (ring attention's)."""
     from ray_tpu.ops.attention import flash_attention
 
     def loss(q, k, v):
-        return flash_attention(q, k, v).astype(jnp.float32).sum()
+        return flash_attention(q, k, v, seq_major=seq_major).astype(
+            jnp.float32).sum()
 
+    if seq_major:
+        shape = (shape[0], shape[2], shape[1], shape[3])
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
                              sharding=SingleDeviceSharding(v5e.devices[0]))
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
@@ -110,12 +120,24 @@ def test_gpt2_medium_train_step_fits_one_chip(v5e, remat_policy,
                           {"tokens": _on(one_chip, tokens)}).compile()
     assert len(_kernel_calls(compiled)) == kernel_calls
     assert len(_kernel_calls(compiled, "flash_fwd")) == kernel_calls - 2
-    assert ".remat" not in compiled.as_text()
+    text = compiled.as_text()
+    assert ".remat" not in text
+    # the residuals stacked over the 24 layers: none has a 64-wide minor
+    # dimension in memory (stored at 128 lanes, twice its size: q, k and v
+    # were `bf16[24,12,1024,16,64]{4,2,3,1,0}` before PR 31), and q, k, v
+    # are [24, 12, 1024, 1024] as the projections write and the kernels read
+    stacks = set(re.findall(r"(?:bf16|f32)\[24,12,[0-9,]+\]\{[0-9,]+", text))
+    assert stacks
+    for stack in stacks:
+        dims, layout = stack.split("]{")
+        dims = [int(d) for d in dims.split("[")[1].split(",")]
+        assert dims[int(layout.split(",")[0])] % 128 == 0, stack
+    if remat_policy == "dots":
+        assert "bf16[24,12,1024,1024]{3,2,1,0" in stacks
     # compiling at all means it fits; the donated state is aliased to the
-    # new one, so what must fit beside it is the temporaries: 14.37 GiB
-    # under "dots", with the kernel's output saved lane-dense, [B, H, 64, S]
-    # (as [B, H, S, 64] it is padded to 128 lanes, twice the size)
-    assert compiled.memory_analysis().temp_size_in_bytes < 15.0 * 2 ** 30
+    # new one, so what must fit beside it is the temporaries: 12.68 GiB
+    # under "dots" (14.37 before PR 31, with q, k and v padded)
+    assert compiled.memory_analysis().temp_size_in_bytes < 13.0 * 2 ** 30
 
 
 def _olmoe_config():

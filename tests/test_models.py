@@ -114,6 +114,49 @@ def test_remat_policy_decides_how_often_the_flash_forward_runs(
         np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 
+def _loss_and_grads(attention_impl, n_heads, mesh_spec):
+    """Loss and parameter gradients of a two-block model with `n_heads`
+    heads of 64, bf16 activations, through `GPT._attention`'s plain branch
+    or, with a mesh, its shard_map branch."""
+    cfg = GPTConfig(vocab_size=128, n_layers=2, d_model=64 * n_heads,
+                    n_heads=n_heads, max_seq_len=128, remat=False,
+                    attention_impl=attention_impl)
+    batch = _batch(cfg, b=8, s=128)
+    if mesh_spec is None:
+        model = GPT(cfg)
+    else:
+        mesh = build_mesh(mesh_spec.resolve(8))
+        model = GPT(cfg, mesh=mesh)
+        batch = {"tokens": jax.device_put(batch["tokens"],
+                                          batch_shardings(mesh))}
+    params = model.init(jax.random.PRNGKey(0))
+    return jax.jit(jax.value_and_grad(
+        lambda p: model.loss(p, batch)[0]))(params)
+
+
+@pytest.mark.parametrize("n_heads,mesh_spec", [
+    (2, None), (3, None),                  # one block; a block and a half
+    (4, MeshSpec(dp=2, fsdp=2, tp=2)),     # two heads a device
+    (3, MeshSpec(dp=2, fsdp=4)),           # every head on every device
+], ids=["plain_2", "plain_3", "mesh_tp_4", "mesh_fsdp_3"])
+def test_flash_kernels_match_reference_through_the_model(n_heads, mesh_spec):
+    """`_attention` hands q, k, v to the flash kernels as the projections
+    wrote them, [B, S, H, 64], and transposes nothing: loss and gradients
+    agree with the einsum attention to bf16 rounding."""
+    if mesh_spec is not None and (not _HAS_SHARD_MAP
+                                  or len(jax.devices()) < 8):
+        pytest.skip("needs jax.shard_map and 8 virtual devices")
+    want_loss, want = _loss_and_grads("reference", n_heads, mesh_spec)
+    got_loss, got = _loss_and_grads("pallas_interpret", n_heads, mesh_spec)
+    np.testing.assert_allclose(got_loss, want_loss, rtol=2e-3)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree_util.tree_leaves(want)):
+        scale = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b, np.float32),
+            atol=2e-2 * scale, err_msg=jax.tree_util.keystr(path))
+
+
 # Feature probes for this box's jax (0.4.x): the sharded model paths
 # use the jax>=0.5 top-level APIs (jax.shard_map / jax.set_mesh).
 # skipif on the PROBE, not a version string, so the gate lifts itself

@@ -62,7 +62,8 @@ def test_flash_output_residual_is_lane_dense(head_dim):
 
     q, k, v = _qkv(d=head_dim, dtype=jnp.bfloat16)
     out, (_, _, _, saved, lse) = jax.eval_shape(
-        lambda q, k, v: _flash_fwd(q, k, v, True, None, 128, 128, True),
+        lambda q, k, v: _flash_fwd(q, k, v, True, None, 128, 128, True,
+                                   False),
         q, k, v)
     assert out.shape == q.shape
     assert saved.shape == ((2, 4, 64, 256) if head_dim == 64 else q.shape)
@@ -144,6 +145,67 @@ def test_flash_fwd_and_grads_match_reference(case):
             atol = 2e-5 if name == "out" else 5e-4
         np.testing.assert_allclose(np.asarray(f32(a)), np.asarray(b),
                                    atol=atol, err_msg=f"{case}: {name}")
+
+
+# The projections' own layout, [B, S, H, Dh], which `GPT._attention` hands
+# over: name: (_qkv arguments, blocks or None). A block is 128 lanes of
+# H * Dh: two heads of 64 (one in the last block of an odd count), eight of
+# 16, every head of a model narrower than the lanes; width 128 and GQA at a
+# narrow head go through the head-major kernels.
+_SEQ_MAJOR_CASES = {
+    "h16_d64_bf16": (dict(b=1, h=16, hk=16, s=256, dtype=jnp.bfloat16),
+                     None),
+    "h5_d64_half_block": (dict(b=2, h=5, hk=5, s=256), None),
+    "h2_d128": (dict(b=2, h=2, hk=2, s=256, d=128), None),
+    "gqa_4_2_d128": (dict(h=4, hk=2, s=256, d=128), None),
+    "gqa_4_2_d64": (dict(h=4, hk=2, s=256), None),
+    "ragged_300_h3_d64": (dict(b=1, h=3, hk=3, s=300), (128, 128)),
+    "tiles_aligned_h4_d64": (dict(b=1, h=4, hk=4, s=512), (256, 256)),
+    "h10_d16_eight_a_block": (dict(b=1, h=10, hk=10, s=128, d=16), None),
+    "h2_d16_under_the_lanes": (dict(b=1, h=2, hk=2, s=128, d=16), None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SEQ_MAJOR_CASES))
+def test_flash_seq_major_fwd_and_grads_match_reference(case):
+    kw, blocks = _SEQ_MAJOR_CASES[case]
+    q, k, v = (jnp.swapaxes(x, 1, 2) for x in _qkv(**kw))
+    block_kw = dict(zip(("block_q", "block_k"), blocks)) if blocks else {}
+    got = _fwd_and_grads(
+        lambda q, k, v: flash_attention(q, k, v, True, interpret=True,
+                                        seq_major=True, **block_kw), q, k, v)
+    f32 = lambda x: x.astype(jnp.float32)
+    want = _fwd_and_grads(
+        lambda q, k, v: dot_product_attention(q, k, v, impl="reference",
+                                              seq_major=True),
+        f32(q), f32(k), f32(v))
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        assert a.dtype == q.dtype and a.shape == b.shape
+        if q.dtype == jnp.bfloat16:
+            atol = 2e-2 * float(jnp.max(jnp.abs(b)))
+        else:
+            atol = 2e-5 if name == "out" else 5e-4
+        np.testing.assert_allclose(np.asarray(f32(a)), np.asarray(b),
+                                   atol=atol, err_msg=f"{case}: {name}")
+
+
+def test_flash_layouts_give_the_same_numbers():
+    """`flash_attention`'s [B, H, S, Dh] entry (ring attention's layout)
+    and the sequence-major one run the same bodies on the same heads:
+    outputs and gradients are equal bit for bit, bf16 included (dq, dk and
+    dv are rounded once, from the float32 accumulators, in both)."""
+    q, k, v = _qkv(b=1, h=4, hk=4, s=256, dtype=jnp.bfloat16)
+    head_major = _fwd_and_grads(
+        lambda q, k, v: flash_attention(q, k, v, True, interpret=True),
+        q, k, v)
+    seq_major = _fwd_and_grads(
+        lambda q, k, v: flash_attention(q, k, v, True, interpret=True,
+                                        seq_major=True),
+        *(jnp.swapaxes(x, 1, 2) for x in (q, k, v)))
+    for a, b in zip(head_major, seq_major):
+        np.testing.assert_array_equal(
+            np.asarray(a.astype(jnp.float32)),
+            np.asarray(jnp.swapaxes(b, 1, 2).astype(jnp.float32)))
 
 
 @pytest.mark.parametrize("tile,sub,chunk,computed,interior,edge", [
