@@ -12,10 +12,7 @@ from .gpt import (  # noqa: F401
     GPTConfig,
     gpt2_small,
     gpt2_medium,
-    gpt2_large,
     llama_tiny,
-    llama_1b,
-    llama_7b,
 )
 from .training import (  # noqa: F401
     TrainState,

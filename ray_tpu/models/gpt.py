@@ -1,14 +1,25 @@
 """Decoder-only transformer, TPU-first.
 
 One implementation covers the GPT-2 family (learned positions, GELU MLP,
-LayerNorm), the Llama family (RoPE, SwiGLU, RMSNorm, GQA) and OLMoE's
-sparse-expert block (QK-norm, dropless top-k experts, `models/moe.py`)
-through `GPTConfig` switches — the reference ships these as external torch
+LayerNorm), the Llama family (RoPE, SwiGLU, RMSNorm, GQA), OLMoE's
+sparse-expert block (QK-norm, dropless top-k experts, `models/moe.py`) and
+Qwen3-Next's hybrid (three Gated DeltaNet layers to one gated softmax
+attention layer, a shared expert beside a held share of the routed ones)
+through `GPTConfig` fields — the reference ships these as external torch
 models driven by Ray Train (`release/train_tests`, SURVEY §6 north-star
 configs); here the model itself is framework-native.
 
+The layers' kinds are data: `GPTConfig.layer_pattern` is one period of them
+("full": softmax attention, "linear": the gated delta rule of
+`ops/delta_rule.py`), every layer is `x + mixer(norm(x))` then
+`x + mlp(norm(x))`, and the scan runs over periods. A model of one kind is
+the period ("full",): its weights are stacked [L, ...] under
+`params["blocks"]`; with a longer period each kind's weights are stacked
+[periods, layers of that kind in a period, ...] under
+`params["blocks"][kind]`.
+
 TPU-first choices:
-  * scan-over-layers with stacked params — one compiled block body,
+  * scan-over-periods with stacked params — one compiled body a period,
     compile time O(1) in depth, and GSPMD gathers FSDP-sharded weights
     one layer at a time (ZeRO-3 semantics for free).
   * logical-axis names on every param/activation dim; the mesh mapping
@@ -23,8 +34,9 @@ TPU-first choices:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -33,11 +45,14 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
 from ..ops.attention import FLASH_RESIDUAL_NAMES, dot_product_attention
+from ..ops.delta_rule import gated_delta_rule
 from ..ops.ring_attention import ring_attention
 from ..parallel.sharding import (DEFAULT_RULES, ShardingRules,
                                  with_logical_constraint)
 
 Params = Dict[str, Any]
+
+LAYER_KINDS = ("full", "linear")
 
 # What `remat_policy="dots"` keeps of a block for the backward pass
 # (`GPTConfig.remat_policy`): the names `_block` gives its projections, and
@@ -55,17 +70,39 @@ class GPTConfig:
     n_kv_heads: Optional[int] = None  # None -> n_heads (MHA); < n_heads -> GQA
     d_ff: Optional[int] = None        # None -> 4*d_model (gelu) / 8/3*d (swiglu)
     max_seq_len: int = 1024
+    # one period of the layers' kinds, each of `LAYER_KINDS`: layer i is
+    # layer_pattern[i % len(layer_pattern)], and n_layers is whole periods
+    layer_pattern: Tuple[str, ...] = ("full",)
+    # width of an attention head; None -> d_model // n_heads
+    d_head: Optional[int] = None
     # family switches
     activation: str = "gelu"          # "gelu" | "swiglu"
-    norm: str = "layernorm"           # "layernorm" | "rmsnorm"
+    # "rmsnorm_1p": RMSNorm whose scale is 1 + w, w starting at zero
+    norm: str = "layernorm"           # "layernorm" | "rmsnorm" | "rmsnorm_1p"
     positions: str = "learned"        # "learned" | "rope"
     rope_theta: float = 10000.0
+    # the leading share of a head's width that RoPE turns; the rest passes
+    rope_fraction: float = 1.0
     tie_embeddings: bool = True
     # None -> the family's usual epsilon (1e-6 rmsnorm, 1e-5 layernorm)
     norm_eps: Optional[float] = None
-    # RMSNorm with a learned scale on the whole projected q and k (all heads
-    # together), before RoPE — OLMoE / OLMo-2
-    qk_norm: bool = False
+    # a norm on the projected q and k before RoPE. True: RMSNorm with a
+    # learned scale over all heads of the projection together (OLMoE /
+    # OLMo-2). "head": the model's own RMSNorm over each head's width, one
+    # scale of that width for all heads of q and one for k (Qwen3)
+    qk_norm: Union[bool, str] = False
+    # the q projection is twice as wide, each head's second half a gate:
+    # the kernel's output is multiplied by its sigmoid before the
+    # out-projection
+    attn_gate: bool = False
+    # "linear" layers (Gated DeltaNet): key heads (queries have as many) and
+    # value heads, each key head serving value_heads / key_heads value
+    # heads; their widths; the causal depthwise convolution's taps
+    linear_key_heads: int = 0
+    linear_value_heads: int = 0
+    linear_key_dim: int = 0
+    linear_value_dim: int = 0
+    linear_conv: int = 4
     # pipeline parallelism: microbatches per global batch (0 -> = pp).
     # Stages come from the mesh's pp axis; GSPMD-style schedule (scan
     # over steps, stage-sharded rolling buffer -> collective-permute).
@@ -79,6 +116,14 @@ class GPTConfig:
     moe_norm_topk_prob: bool = True
     moe_aux_coeff: float = 0.01       # load-balancing loss, all k choices
     moe_router_z_coeff: float = 0.0   # mean squared logsumexp of the router
+    # width of a SwiGLU expert with a sigmoid gate of its own that every
+    # token passes through beside the routed ones (0: none)
+    moe_shared_ff: int = 0
+    # the share of each layer's experts that lives here: experts
+    # moe_first_expert .. + moe_experts_held of the n_experts routed over
+    # (None: all of them). The layer computes their part of the result
+    moe_first_expert: int = 0
+    moe_experts_held: Optional[int] = None
     # numerics
     dtype: Any = jnp.bfloat16         # activation dtype
     param_dtype: Any = jnp.float32
@@ -102,6 +147,16 @@ class GPTConfig:
     # attention kernel: "auto" | "pallas" | "pallas_interpret" | "reference"
     attention_impl: str = "auto"
 
+    def __post_init__(self):
+        pattern = tuple(self.layer_pattern)     # a JSON file gives a list
+        object.__setattr__(self, "layer_pattern", pattern)
+        if not pattern or set(pattern) - set(LAYER_KINDS):
+            raise ValueError(f"layer_pattern {pattern!r}: a period of "
+                             f"{LAYER_KINDS}")
+        if self.n_layers % len(pattern):
+            raise ValueError(f"n_layers={self.n_layers} is not whole periods "
+                             f"of {pattern!r}")
+
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads if self.n_kv_heads is not None else self.n_heads
@@ -110,12 +165,19 @@ class GPTConfig:
     def eps(self) -> float:
         if self.norm_eps is not None:
             return self.norm_eps
-        return 1e-6 if self.norm == "rmsnorm" else 1e-5
+        return 1e-5 if self.norm == "layernorm" else 1e-6
 
     @property
     def head_dim(self) -> int:
+        if self.d_head is not None:
+            return self.d_head
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
+
+    @property
+    def experts_held(self) -> int:
+        return (self.n_experts if self.moe_experts_held is None
+                else self.moe_experts_held)
 
     @property
     def ff_dim(self) -> int:
@@ -129,16 +191,25 @@ class GPTConfig:
 
     @property
     def n_params(self) -> int:
-        """Approximate parameter count (excludes norms/bias)."""
+        """Approximate parameter count here (excludes norms/bias)."""
         d, f, v = self.d_model, self.ff_dim, self.vocab_size
         hd, h, hk = self.head_dim, self.n_heads, self.kv_heads
-        attn = d * h * hd + 2 * d * hk * hd + h * hd * d
+        full = ((2 if self.attn_gate else 1) * d * h * hd + 2 * d * hk * hd
+                + h * hd * d)
+        keys = self.linear_key_heads * self.linear_key_dim
+        values = self.linear_value_heads * self.linear_value_dim
+        linear = (d * 2 * (keys + values) + 2 * d * self.linear_value_heads
+                  + self.linear_conv * (2 * keys + values) + values * d)
         if self.n_experts > 0:
-            mlp = self.n_experts * 3 * d * f + d * self.n_experts
+            mlp = (self.experts_held * 3 * d * f + d * self.n_experts
+                   + 3 * d * self.moe_shared_ff)
         else:
             mlp = (3 if self.activation == "swiglu" else 2) * d * f
         emb = v * d * (1 if self.tie_embeddings else 2)
-        return self.n_layers * (attn + mlp) + emb
+        mixers = sum(full if kind == "full" else linear
+                     for kind in self.layer_pattern)
+        return (self.n_layers // len(self.layer_pattern)
+                * (mixers + len(self.layer_pattern) * mlp) + emb)
 
 
 # --- presets ---------------------------------------------------------------
@@ -149,10 +220,6 @@ def gpt2_small(**kw) -> GPTConfig:
 
 def gpt2_medium(**kw) -> GPTConfig:
     return GPTConfig(n_layers=24, d_model=1024, n_heads=16, **kw)
-
-
-def gpt2_large(**kw) -> GPTConfig:
-    return GPTConfig(n_layers=36, d_model=1280, n_heads=20, **kw)
 
 
 def _llama(**kw) -> GPTConfig:
@@ -166,15 +233,6 @@ def llama_tiny(**kw) -> GPTConfig:
     """Test-scale llama-style config (CPU-friendly)."""
     return _llama(n_layers=2, d_model=128, n_heads=4, n_kv_heads=2,
                   vocab_size=512, max_seq_len=256, **kw)
-
-
-def llama_1b(**kw) -> GPTConfig:
-    return _llama(n_layers=16, d_model=2048, n_heads=16, n_kv_heads=8, **kw)
-
-
-def llama_7b(**kw) -> GPTConfig:
-    return _llama(n_layers=32, d_model=4096, n_heads=32, d_ff=11008,
-                  max_seq_len=4096, **kw)
 
 
 # --- init ------------------------------------------------------------------
@@ -205,6 +263,12 @@ class GPT:
                 raise NotImplementedError(
                     "EP+PP combined (MoE aux-loss masking across pipeline "
                     "bubbles) is not supported yet")
+            if len(config.layer_pattern) > 1:
+                raise NotImplementedError(
+                    f"pipeline stages of a layer pattern "
+                    f"{config.layer_pattern!r} (stages of unequal layer "
+                    "kinds) are not supported yet: pp needs the period "
+                    '("full",)')
 
     @property
     def pp_stages(self) -> int:
@@ -215,50 +279,107 @@ class GPT:
             return self.mesh.shape[ax]
         return 1
 
+    @property
+    def _kinds(self) -> Dict[str, int]:
+        """Each kind of the pattern with its layers a period, in order of
+        first appearance."""
+        pattern = self.config.layer_pattern
+        return {kind: pattern.count(kind) for kind in dict.fromkeys(pattern)}
+
     # -- parameters --------------------------------------------------------
+
+    def _init_blocks(self, kind: str, lead: Tuple[int, ...], keys) -> Params:
+        """The stacked weights of the layers of one kind, `lead` the
+        stacking axes. `keys`: twelve, of which 7..9 are the model's own."""
+        c = self.config
+        pd = c.param_dtype
+        d, f, hd = c.d_model, c.ff_dim, c.head_dim
+        h, hk = c.n_heads, c.kv_heads
+        std = 0.02
+        resid_std = std / math.sqrt(2 * c.n_layers)
+        extra = jax.random.split(keys[11], 8)
+
+        def ones(*shape):
+            return jnp.ones(lead + shape, pd)
+
+        def zeros(*shape):
+            return jnp.zeros(lead + shape, pd)
+
+        def normal(key, *shape, std=std):
+            return _normal(key, lead + shape, std, pd)
+
+        # a scale of 1 either way: "rmsnorm_1p" stores what it adds to 1
+        unit = zeros if c.norm == "rmsnorm_1p" else ones
+        blocks = {"norm1": unit(d), "norm2": unit(d)}
+        if kind == "full":
+            blocks.update(
+                wq=normal(keys[0], d, h, (2 if c.attn_gate else 1) * hd),
+                wk=normal(keys[1], d, hk, hd),
+                wv=normal(keys[2], d, hk, hd),
+                wo=normal(keys[3], h, hd, d, std=resid_std))
+            if c.qk_norm == "head":
+                blocks.update(q_norm=unit(hd), k_norm=unit(hd))
+            elif c.qk_norm:
+                blocks.update(q_norm=ones(h, hd), k_norm=ones(hk, hd))
+        else:
+            nk, nv = c.linear_key_heads, c.linear_value_heads
+            keys_w, values_w = nk * c.linear_key_dim, nv * c.linear_value_dim
+            blocks.update(
+                w_qkvz=normal(extra[4], d, 2 * keys_w + 2 * values_w),
+                w_ba=normal(extra[5], d, 2 * nv),
+                conv_w=normal(extra[6], c.linear_conv,
+                              2 * keys_w + values_w),
+                # the public implementation's start: decay rates
+                # exp(A_log) uniform in (0, 16), the step's bias at 1
+                A_log=jnp.log(jax.random.uniform(
+                    extra[7], lead + (nv,), jnp.float32, 1e-3, 16.0)
+                ).astype(pd),
+                dt_bias=ones(nv),
+                lin_norm=ones(c.linear_value_dim),
+                w_lin_out=normal(extra[3], values_w, d, std=resid_std))
+        if c.n_experts > 0:
+            E, held = c.n_experts, c.experts_held
+            blocks.update(
+                router=normal(keys[4], d, E),
+                w_up=normal(keys[5], held, d, f),
+                w_gate=normal(keys[6], held, d, f),
+                w_down=normal(keys[10], held, f, d, std=resid_std))
+            if c.moe_shared_ff:
+                fs = c.moe_shared_ff
+                blocks.update(
+                    ws_up=normal(extra[0], d, fs),
+                    ws_gate=normal(extra[1], d, fs),
+                    ws_down=normal(extra[2], fs, d, std=resid_std),
+                    ws_open=zeros(d))
+        else:
+            blocks["w_up"] = normal(keys[4], d, f)
+            blocks["w_down"] = normal(keys[5], f, d, std=resid_std)
+            if c.activation == "swiglu":
+                blocks["w_gate"] = normal(keys[6], d, f)
+        if c.norm == "layernorm":
+            blocks.update(bias1=zeros(d), bias2=zeros(d))
+        return blocks
 
     def init(self, rng: jax.Array) -> Params:
         c = self.config
         pd = c.param_dtype
-        d, f, hd = c.d_model, c.ff_dim, c.head_dim
-        h, hk, L = c.n_heads, c.kv_heads, c.n_layers
+        d, L = c.d_model, c.n_layers
         std = 0.02
-        resid_std = std / math.sqrt(2 * L)
         keys = jax.random.split(rng, 12)
-
-        def ones(shape):
-            return jnp.ones(shape, pd)
-
-        blocks = {
-            "norm1": ones((L, d)),
-            "norm2": ones((L, d)),
-            "wq": _normal(keys[0], (L, d, h, hd), std, pd),
-            "wk": _normal(keys[1], (L, d, hk, hd), std, pd),
-            "wv": _normal(keys[2], (L, d, hk, hd), std, pd),
-            "wo": _normal(keys[3], (L, h, hd, d), resid_std, pd),
-        }
-        if c.qk_norm:
-            blocks["q_norm"] = ones((L, h, hd))
-            blocks["k_norm"] = ones((L, hk, hd))
-        if c.n_experts > 0:
-            E = c.n_experts
-            blocks["router"] = _normal(keys[4], (L, d, E), std, pd)
-            blocks["w_up"] = _normal(keys[5], (L, E, d, f), std, pd)
-            blocks["w_gate"] = _normal(keys[6], (L, E, d, f), std, pd)
-            blocks["w_down"] = _normal(keys[10], (L, E, f, d), resid_std,
-                                       pd)
+        if len(c.layer_pattern) == 1:
+            blocks = self._init_blocks(c.layer_pattern[0], (L,), keys)
         else:
-            blocks["w_up"] = _normal(keys[4], (L, d, f), std, pd)
-            blocks["w_down"] = _normal(keys[5], (L, f, d), resid_std, pd)
-            if c.activation == "swiglu":
-                blocks["w_gate"] = _normal(keys[6], (L, d, f), std, pd)
-        if c.norm == "layernorm":
-            blocks["bias1"] = jnp.zeros((L, d), pd)
-            blocks["bias2"] = jnp.zeros((L, d), pd)
+            periods = L // len(c.layer_pattern)
+            blocks = {
+                kind: self._init_blocks(
+                    kind, (periods, n),
+                    jax.random.split(jax.random.fold_in(rng, i + 1), 12))
+                for i, (kind, n) in enumerate(self._kinds.items())}
+        unit = jnp.zeros if c.norm == "rmsnorm_1p" else jnp.ones
         params: Params = {
             "tok_embed": _normal(keys[7], (c.vocab_size, d), std, pd),
             "blocks": blocks,
-            "norm_f": ones((d,)),
+            "norm_f": unit((d,), pd),
         }
         if c.positions == "learned":
             params["pos_embed"] = _normal(keys[8], (c.max_seq_len, d), std,
@@ -276,35 +397,59 @@ class GPT:
                 params["blocks"])
         return params
 
+    def _block_axes(self, kind: str) -> Dict[str, Tuple]:
+        """Logical axes of one layer's weights, without the stacking axes."""
+        c = self.config
+        axes: Dict[str, Tuple] = {"norm1": (None,), "norm2": (None,)}
+        if kind == "full":
+            axes.update(
+                wq=("embed", "heads", "head_dim"),
+                wk=("embed", "kv_heads", "head_dim"),
+                wv=("embed", "kv_heads", "head_dim"),
+                wo=("heads", "head_dim", "embed"))
+            if c.qk_norm == "head":
+                axes.update(q_norm=("head_dim",), k_norm=("head_dim",))
+            elif c.qk_norm:
+                axes.update(q_norm=("heads", "head_dim"),
+                            k_norm=("kv_heads", "head_dim"))
+        else:
+            # the projections' columns are q, k, v and z side by side and
+            # the convolution runs along them: not split over tp
+            axes.update(
+                w_qkvz=("embed", None), w_ba=("embed", None),
+                conv_w=(None, None), A_log=(None,), dt_bias=(None,),
+                lin_norm=(None,), w_lin_out=(None, "embed"))
+        if c.n_experts > 0:
+            axes.update(
+                router=("embed", None),
+                w_up=("expert", "embed", "mlp"),
+                w_gate=("expert", "embed", "mlp"),
+                w_down=("expert", "mlp", "embed"))
+            if c.moe_shared_ff:
+                axes.update(ws_up=("embed", "mlp"), ws_gate=("embed", "mlp"),
+                            ws_down=("mlp", "embed"), ws_open=(None,))
+        else:
+            axes.update(w_up=("embed", "mlp"), w_down=("mlp", "embed"))
+            if c.activation == "swiglu":
+                axes["w_gate"] = ("embed", "mlp")
+        if c.norm == "layernorm":
+            axes.update(bias1=(None,), bias2=(None,))
+        return axes
+
     def param_logical_axes(self) -> Params:
         """Pytree matching `init` output: tuples of logical axis names."""
         c = self.config
-        blocks = {
-            "norm1": ("layers", None),
-            "norm2": ("layers", None),
-            "wq": ("layers", "embed", "heads", "head_dim"),
-            "wk": ("layers", "embed", "kv_heads", "head_dim"),
-            "wv": ("layers", "embed", "kv_heads", "head_dim"),
-            "wo": ("layers", "heads", "head_dim", "embed"),
-        }
-        if c.qk_norm:
-            blocks["q_norm"] = ("layers", "heads", "head_dim")
-            blocks["k_norm"] = ("layers", "kv_heads", "head_dim")
-        if c.n_experts > 0:
-            blocks["router"] = ("layers", "embed", None)
-            blocks["w_up"] = ("layers", "expert", "embed", "mlp")
-            blocks["w_gate"] = ("layers", "expert", "embed", "mlp")
-            blocks["w_down"] = ("layers", "expert", "mlp", "embed")
+        if len(c.layer_pattern) == 1:
+            lead: Tuple = ("layers",)
+            if self.pp_stages > 1:
+                lead = ("stage",) + lead
+            blocks: Params = {
+                k: lead + v
+                for k, v in self._block_axes(c.layer_pattern[0]).items()}
         else:
-            blocks["w_up"] = ("layers", "embed", "mlp")
-            blocks["w_down"] = ("layers", "mlp", "embed")
-            if c.activation == "swiglu":
-                blocks["w_gate"] = ("layers", "embed", "mlp")
-        if c.norm == "layernorm":
-            blocks["bias1"] = ("layers", None)
-            blocks["bias2"] = ("layers", None)
-        if self.pp_stages > 1:
-            blocks = {k: ("stage",) + v for k, v in blocks.items()}
+            blocks = {kind: {k: ("layers", None) + v
+                             for k, v in self._block_axes(kind).items()}
+                      for kind in self._kinds}
         axes: Params = {
             "tok_embed": ("vocab", "embed"),
             "blocks": blocks,
@@ -323,9 +468,12 @@ class GPT:
     def _norm(self, x, scale, bias):
         c = self.config
         xf = x.astype(jnp.float32)
-        if c.norm == "rmsnorm":
+        if c.norm in ("rmsnorm", "rmsnorm_1p"):
             xf = xf * lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + c.eps)
-            return (xf * scale.astype(jnp.float32)).astype(c.dtype)
+            scale = scale.astype(jnp.float32)
+            if c.norm == "rmsnorm_1p":
+                scale = 1.0 + scale
+            return (xf * scale).astype(c.dtype)
         mean = jnp.mean(xf, -1, keepdims=True)
         var = jnp.var(xf, -1, keepdims=True)
         xf = (xf - mean) * lax.rsqrt(var + c.eps)
@@ -344,7 +492,17 @@ class GPT:
         return (xf * scale.astype(jnp.float32)).astype(c.dtype)
 
     def _rope(self, x, positions):
-        """x: [B, S, H, D_h]; positions: [B, S]."""
+        """x: [B, S, H, D_h]; positions: [B, S]. Turns the first
+        `rope_fraction` of each head's width (rotate-half over that part)
+        and passes the rest."""
+        c = self.config
+        hd = int(x.shape[-1] * c.rope_fraction)
+        if hd < x.shape[-1]:
+            turned = self._rope_whole(x[..., :hd], positions)
+            return jnp.concatenate([turned, x[..., hd:]], -1)
+        return self._rope_whole(x, positions)
+
+    def _rope_whole(self, x, positions):
         c = self.config
         hd = x.shape[-1]
         half = hd // 2
@@ -436,11 +594,10 @@ class GPT:
         return with_logical_constraint(x, *logical, rules=self.rules,
                                        mesh=self.mesh)
 
-    def _block(self, x, positions, w):
-        """One transformer block. x: [B, S, D] bf16."""
+    def _full_mixer(self, x, positions, w):
+        """Softmax attention on the normed input, residual included."""
         c = self.config
         dt = c.dtype
-
         # the scopes are metadata on the ops (the profiler's trace and the
         # HLO carry them), the program is the same with or without
         with jax.named_scope("attn_qkv"):
@@ -461,7 +618,12 @@ class GPT:
             q = project("wq", "attn_q")
             k = project("wk", "attn_k")
             v = project("wv", "attn_v")
-            if c.qk_norm:
+            if c.attn_gate:
+                q, gate = jnp.split(q, 2, axis=-1)
+            if c.qk_norm == "head":
+                q = self._norm(q, w["q_norm"], None)
+                k = self._norm(k, w["k_norm"], None)
+            elif c.qk_norm:
                 q = self._qk_norm(q, w["q_norm"])
                 k = self._qk_norm(k, w["k_norm"])
             if c.positions == "rope":
@@ -474,22 +636,92 @@ class GPT:
         with jax.named_scope("attn_kernel"):
             attn = self._attention(q, k, v)
         with jax.named_scope("attn_out"):
+            if c.attn_gate:
+                attn = attn * jax.nn.sigmoid(gate)
             wo = w["wo"].astype(dt)
             attn = jnp.einsum("bse,ed->bsd",
                               attn.reshape(*attn.shape[:2], -1),
                               wo.reshape(-1, wo.shape[-1]))
-            x = x + self._constrain(attn, "act_batch", "act_seq",
-                                    "act_embed")
+            return x + self._constrain(attn, "act_batch", "act_seq",
+                                       "act_embed")
+
+    def _linear_mixer(self, x, w):
+        """Gated DeltaNet on the normed input, residual included: q, k, v
+        from one projection through a short causal convolution and SiLU, the
+        gated delta rule per value head, a gated RMSNorm on its output."""
+        c = self.config
+        dt = c.dtype
+        f32 = jnp.float32
+        nk, nv = c.linear_key_heads, c.linear_value_heads
+        dk, dv = c.linear_key_dim, c.linear_value_dim
+        mixed = 2 * nk * dk + nv * dv
+        with jax.named_scope("attn_qkv"):
+            h = self._norm(x, w["norm1"], w.get("bias1"))
+            with jax.named_scope("gdn_proj"):
+                qkvz = jnp.einsum("bsd,de->bse", h, w["w_qkvz"].astype(dt))
+                ba = jnp.einsum("bsd,de->bse", h, w["w_ba"].astype(dt),
+                                preferred_element_type=f32)
+                qkv, z = qkvz[..., :mixed], qkvz[..., mixed:]
+            with jax.named_scope("gdn_conv"):
+                # out_t = sum_i conv_w[i] * in_{t - taps + 1 + i}, zeros
+                # before the row's start: one shifted product a tap
+                taps = w["conv_w"].astype(dt)
+                s = qkv.shape[1]
+                padded = jnp.pad(qkv, ((0, 0), (taps.shape[0] - 1, 0),
+                                       (0, 0)))
+                qkv = jax.nn.silu(sum(
+                    padded[:, i:i + s] * taps[i]
+                    for i in range(taps.shape[0])))
+                q, k, v = (
+                    part.reshape(*part.shape[:2], heads, -1)
+                    for part, heads in zip(
+                        jnp.split(qkv, (nk * dk, 2 * nk * dk), axis=-1),
+                        (nk, nk, nv)))
+
+                def unit(y, scale):
+                    yf = y.astype(f32)
+                    return (yf * (lax.rsqrt(jnp.sum(yf * yf, -1,
+                                                    keepdims=True) + c.eps)
+                                  * scale)).astype(dt)
+
+                q, k = unit(q, dk ** -0.5), unit(k, 1.0)
+                beta = jax.nn.sigmoid(ba[..., :nv])
+                g = -jnp.exp(w["A_log"].astype(f32)) * jax.nn.softplus(
+                    ba[..., nv:] + w["dt_bias"].astype(f32))
+        with jax.named_scope("attn_kernel"), jax.named_scope("gdn_rule"):
+            o = gated_delta_rule(q, k, v, g, beta)
+        with jax.named_scope("attn_out"), jax.named_scope("gdn_out"):
+            of = o.astype(f32)
+            of = of * lax.rsqrt(jnp.mean(of * of, -1, keepdims=True) + c.eps)
+            o = (of * w["lin_norm"].astype(f32)).astype(dt)
+            o = o.reshape(*o.shape[:2], -1) * jax.nn.silu(z)
+            out = jnp.einsum("bse,ed->bsd", o, w["w_lin_out"].astype(dt))
+            return x + self._constrain(out, "act_batch", "act_seq",
+                                       "act_embed")
+
+    def _block(self, x, positions, w, kind="full"):
+        """One block of the given kind. x: [B, S, D] bf16."""
+        c = self.config
+        dt = c.dtype
+        if kind == "full":
+            x = self._full_mixer(x, positions, w)
+        else:
+            x = self._linear_mixer(x, w)
 
         with jax.named_scope("mlp"):
             h = self._norm(x, w["norm2"], w.get("bias2"))
             aux = {}
             if c.n_experts > 0:
-                from .moe import moe_ffn
+                from .moe import moe_ffn, shared_expert_ffn
                 down, aux = moe_ffn(
                     h, w["router"], w["w_up"], w["w_gate"], w["w_down"],
                     top_k=c.moe_top_k,
-                    norm_topk_prob=c.moe_norm_topk_prob, dtype=dt)
+                    norm_topk_prob=c.moe_norm_topk_prob,
+                    first_expert=c.moe_first_expert, dtype=dt)
+                if c.moe_shared_ff:
+                    down = down + shared_expert_ffn(
+                        h, w["ws_up"], w["ws_gate"], w["ws_down"],
+                        w["ws_open"], dtype=dt)
             else:
                 up = jnp.einsum("bsd,df->bsf", h, w["w_up"].astype(dt))
                 up = checkpoint_name(up, "mlp_up")
@@ -519,8 +751,9 @@ class GPT:
                          positions: Optional[jax.Array] = None):
         """Returns (logits, aux): with experts, the router's two losses as
         means over the layers and, per layer, `moe_expert_tokens`
-        [n_layers, n_experts] and `moe_expert_choice` [n_layers, tokens,
-        top_k]; without, an empty dict."""
+        [n_layers, n_experts], `moe_expert_choice` [n_layers, tokens,
+        top_k] and, where the layers hold a share of their experts,
+        `moe_routed_here` [n_layers]; without, an empty dict."""
         c = self.config
         if positions is None:
             positions = jnp.broadcast_to(
@@ -545,7 +778,8 @@ class GPT:
                 x = x + pos_tbl[positions]
             x = self._constrain(x, "act_batch", "act_seq", "act_embed")
 
-        block_fn = self._block
+        block_fns = {kind: functools.partial(self._block, kind=kind)
+                     for kind in self._kinds}
         if c.remat:
             cp = jax.checkpoint_policies
             policies = {
@@ -557,19 +791,39 @@ class GPT:
                     f"remat_policy must be one of {sorted(policies)}, "
                     f"got {c.remat_policy!r} (use remat=False to disable "
                     "rematerialization entirely)")
-            block_fn = jax.checkpoint(block_fn,
-                                      policy=policies[c.remat_policy])
+            block_fns = {kind: jax.checkpoint(
+                fn, policy=policies[c.remat_policy])
+                for kind, fn in block_fns.items()}
 
         if self.pp_stages > 1:
-            x = self._pipeline_blocks(block_fn, params["blocks"], x,
+            x = self._pipeline_blocks(block_fns["full"], params["blocks"], x,
                                       positions)
             aux_per_layer = {}
-        else:
+        elif len(c.layer_pattern) == 1:
+            block_fn = block_fns[c.layer_pattern[0]]
+
             def scan_body(x, layer_w):
                 x, aux = block_fn(x, positions, layer_w)
                 return x, aux
 
             x, aux_per_layer = lax.scan(scan_body, x, params["blocks"])
+        else:
+            def period_body(x, period_w):
+                seen = dict.fromkeys(period_w, 0)
+                facts = []
+                for kind in c.layer_pattern:
+                    layer_w = jax.tree_util.tree_map(
+                        lambda a: a[seen[kind]], period_w[kind])
+                    seen[kind] += 1
+                    x, aux = block_fns[kind](x, positions, layer_w)
+                    facts.append(aux)
+                return x, jax.tree_util.tree_map(
+                    lambda *a: jnp.stack(a), *facts)
+
+            x, aux_per_period = lax.scan(period_body, x, params["blocks"])
+            # [periods, layers a period, ...] -> [L, ...]
+            aux_per_layer = jax.tree_util.tree_map(
+                lambda a: a.reshape(-1, *a.shape[2:]), aux_per_period)
         # one scope, `head_loss`, for the final norm and the logits here
         # and for the cross-entropy in `loss`
         with jax.named_scope("head_loss"):
@@ -584,7 +838,7 @@ class GPT:
                                      "act_vocab")
             logits = logits.astype(jnp.float32)
         # the scan stacked each layer's facts: a loss term is [L] now
-        aux = {k: v.mean() if v.ndim == 1 else v
+        aux = {k: v.mean() if k in ("moe_aux_loss", "moe_router_z") else v
                for k, v in aux_per_layer.items()}
         return logits, aux
 
@@ -695,4 +949,12 @@ class GPT:
                 moe_expert_tokens=aux["moe_expert_tokens"].sum(0),
                 moe_load_max_over_mean=(counts.max(-1)
                                         / counts.mean(-1)).mean())
+            if "moe_routed_here" in aux:
+                # a share of the experts: per layer, what each held expert
+                # was given beside the router's count of what it sent here
+                first = c.moe_first_expert
+                metrics.update(
+                    moe_expert_tokens=aux["moe_expert_tokens"][
+                        :, first:first + c.experts_held],
+                    moe_routed_here=aux["moe_routed_here"])
         return loss, metrics

@@ -193,6 +193,103 @@ def test_olmoe_one_layer_train_step_fills_one_chip(v5e):
                    {"tokens": _on(one_chip, tokens)}).compile()
 
 
+def _qwen3_next_config():
+    import json
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))),
+        "benchmarks/configs/qwen3_next_80b_a3b.json")
+    with open(path) as f:
+        return json.load(f)
+
+
+def test_flash_attention_compiles_at_qwen3_next_width(v5e):
+    """The three flash kernels as `qwen3next-steady`'s full-attention layer
+    calls them: [4 rows, 8192, 16 query heads on 2 KV heads, width 256],
+    bf16, handed over as the projections wrote them (a width that fills the
+    lanes takes the head-major kernels, a group of 8 their GQA block maps)."""
+    from ray_tpu.ops.attention import dot_product_attention
+
+    model = _qwen3_next_config()["model"]
+    rows = _qwen3_next_config()["batch_per_chip"]
+
+    def loss(q, k, v):
+        return dot_product_attention(q, k, v, causal=True, impl="pallas",
+                                     seq_major=True).astype(jnp.float32).sum()
+
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    q, k = (jax.ShapeDtypeStruct(
+        (rows, model["max_seq_len"], heads, model["d_head"]), jnp.bfloat16,
+        sharding=one_chip) for heads in (model["n_heads"],
+                                         model["n_kv_heads"]))
+    assert q.shape == (4, 8192, 16, 256) and k.shape == (4, 8192, 2, 256)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, k, k).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def _qwen3_next_step(config, batch):
+    """The one-period Qwen3-Next step of the benchmark's
+    `qwen3_next_80b_a3b` configuration, as its cell builds it, at `batch`
+    rows of 8,192."""
+    from ray_tpu.models import (GPT, init_train_state, make_optimizer,
+                                make_train_step)
+    from ray_tpu.models.gpt import GPTConfig
+
+    kw = dict(config["model"], attention_impl="pallas")
+    kw["dtype"] = getattr(jnp, kw["dtype"])
+    kw["param_dtype"] = getattr(jnp, kw["param_dtype"])
+    model = GPT(GPTConfig(**kw))
+    opt = make_optimizer(**config["optimizer"])
+    state = jax.eval_shape(
+        lambda: init_train_state(model, opt, jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((batch, kw["max_seq_len"]), jnp.int32)
+    return make_train_step(model, opt), state, tokens
+
+
+def test_qwen3_next_period_train_step_fills_one_chip(v5e):
+    """`qwen3next-steady`'s step: one period of Qwen3-Next at published
+    widths (three Gated DeltaNet layers, one gated full-attention layer,
+    each with a shared expert and 32 of 512 routed experts), 18,992 rows of
+    embedding and untied head, float32 AdamW state, at the configuration's
+    `batch_per_chip` rows of 8,192 tokens under "full" remat. It fits, and
+    one row more does not: this is what fixes `batch_per_chip`."""
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    config = _qwen3_next_config()
+    rows = config["batch_per_chip"]
+    step, state, tokens = _qwen3_next_step(config, rows)
+    assert tokens.shape == (rows, 8192)
+    compiled = step.lower(_on(one_chip, state),
+                          {"tokens": _on(one_chip, tokens)}).compile()
+    # the flash kernels of the one full-attention layer: forward, the
+    # forward recomputed under "full" remat, dq and dkv; the held experts'
+    # grouped matmuls are kernels too, inside loops whose trip count
+    # follows the pairs routed here
+    assert len(_kernel_calls(compiled, "flash_")) == 4
+    assert len(_kernel_calls(compiled)) > 4
+    text = compiled.as_text()
+    assert "while(" in text
+    # at these rows the compiler makes room on its own (PERF.md, PR 29's
+    # lesson; one row fewer compiles without): what it computes twice is
+    # tuple elements, elementwise passes over [rows, 8192, 2048] and one
+    # Gated DeltaNet projection, [rows, 8192, 12288] (8.8 ms a step on the
+    # chip, PERF.md PR 32) — no kernel's output, nothing of the head's
+    remats = re.findall(
+        r"%\S*\.remat\S* = (?:bf16|f32)\[([0-9,]+)\]\S* ([a-z\-]+)\(", text)
+    for dims, opcode in remats:
+        assert opcode in ("get-tuple-element", "fusion"), (dims, opcode)
+        assert (opcode == "get-tuple-element"
+                or np.prod([int(d) for d in dims.split(",")])
+                <= rows * 8192 * 12288), (dims, opcode)
+        assert not dims.endswith(",18992"), dims
+    mem = compiled.memory_analysis()
+    # the donated state is aliased to the new one: 12 bytes a parameter
+    assert mem.alias_size_in_bytes > 7.4e9
+    step, state, tokens = _qwen3_next_step(config, rows + 1)
+    with pytest.raises(Exception, match="(?i)ran out of memory|exhausted"):
+        step.lower(_on(one_chip, state),
+                   {"tokens": _on(one_chip, tokens)}).compile()
+
+
 @pytest.mark.slow   # 12 s here, and tier-1 runs close to its time limit
 def test_gpt2_medium_fsdp4_train_step_compiles_for_the_host(v5e):
     """One worker granted a whole four-chip host: the same model on an

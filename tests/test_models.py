@@ -74,6 +74,62 @@ def test_train_step_reduces_loss():
     assert int(state.step) == 8
 
 
+# From the commit before layer patterns (PR 31's tree), float32 on the CPU:
+# sum |logits|, logits[1, 5, 7], the loss, the gradients' global norm and
+# d loss / d wq[1, 3, 2, 1], each for `init(PRNGKey(0))` on
+# randint(PRNGKey(1), (2, 48)).
+_BEFORE_PATTERNS = {
+    "gpt2": (dict(vocab_size=512, n_layers=2, d_model=128, n_heads=4,
+                  max_seq_len=128, activation="gelu", norm="layernorm",
+                  positions="learned", tie_embeddings=True),
+             (8930.0478515625, -0.24748189747333527, 6.3406596183776855,
+              3.042456865310669, 3.0980416340753436e-05)),
+    "olmoe": (dict(vocab_size=256, n_layers=2, d_model=64, n_heads=4,
+                   d_ff=32, max_seq_len=128, activation="swiglu",
+                   norm="rmsnorm", positions="rope", tie_embeddings=False,
+                   norm_eps=1e-5, qk_norm=True, n_experts=8, moe_top_k=2,
+                   moe_norm_topk_prob=False, moe_aux_coeff=0.01,
+                   moe_router_z_coeff=0.001, remat=False,
+                   attention_impl="reference"),
+              (3113.0576171875, 0.016855686902999878, 5.5984954833984375,
+               1.429702877998352, -0.0014655494596809149)),
+}
+
+
+@pytest.mark.parametrize("family", list(_BEFORE_PATTERNS))
+def test_one_kind_of_layer_is_the_period_full(family):
+    """The layer pattern holds what exists: a model of one kind is the
+    period ("full",), its weights stacked [L, ...] as before, and its
+    logits and gradients are what the model gave before patterns."""
+    import optax
+    kw, want = _BEFORE_PATTERNS[family]
+    cfg = GPTConfig(dtype=jnp.float32, **kw)
+    assert cfg.layer_pattern == ("full",)
+    model = GPT(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    assert params["blocks"]["wq"].shape[0] == cfg.n_layers
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 48), 0,
+                                cfg.vocab_size)
+    logits = model.apply(params, tokens)
+    (loss, _), grads = jax.value_and_grad(model.loss, has_aux=True)(
+        params, {"tokens": tokens})
+    got = (float(jnp.abs(logits).sum()), float(logits[1, 5, 7]),
+           float(loss), float(optax.global_norm(grads)),
+           float(grads["blocks"]["wq"][1, 3, 2, 1]))
+    assert got == pytest.approx(want, rel=1e-5)
+
+
+def test_layer_pattern_is_whole_periods_of_known_kinds():
+    with pytest.raises(ValueError, match="whole periods"):
+        GPTConfig(n_layers=6, layer_pattern=("linear", "linear", "linear",
+                                             "full"))
+    with pytest.raises(ValueError, match="period of"):
+        GPTConfig(layer_pattern=("window",))
+    # a JSON file hands the period over as a list
+    assert GPTConfig(n_layers=4, layer_pattern=["linear", "full"]
+                     ).layer_pattern == ("linear", "full")
+
+
 def test_n_params_counts():
     cfg = gpt2_small()
     # GPT-2 small is ~124M params; our count excludes norms/bias.

@@ -4,7 +4,9 @@ import jax
 import numpy as np
 import pytest
 
-from ray_tpu.models import (GPT, init_train_state, llama_tiny,
+import jax.numpy as jnp
+
+from ray_tpu.models import (GPT, GPTConfig, init_train_state, llama_tiny,
                             make_optimizer, make_train_step)
 from ray_tpu.models.training import batch_shardings
 from ray_tpu.parallel.mesh import MeshSpec, build_mesh
@@ -69,6 +71,69 @@ def test_moe_ep_sharded():
     assert "ep" in str(state.params["blocks"]["w_up"].sharding.spec)
     assert int(m["moe_expert_tokens"].sum()) == (
         8 * 64 * cfg.moe_top_k * cfg.n_layers)
+
+
+def _hybrid_tiny(**kw):
+    """Test-scale Qwen3-Next shape: three Gated DeltaNet layers to one gated
+    full-attention layer, a shared expert, half of the experts held."""
+    base = dict(
+        vocab_size=512, n_layers=4, d_model=64, n_heads=4, n_kv_heads=2,
+        d_head=32, d_ff=32, max_seq_len=128,
+        layer_pattern=("linear", "linear", "linear", "full"),
+        activation="swiglu", norm="rmsnorm_1p", positions="rope",
+        rope_fraction=0.25, tie_embeddings=False, qk_norm="head",
+        attn_gate=True, linear_key_heads=2, linear_value_heads=4,
+        linear_key_dim=16, linear_value_dim=16, n_experts=8, moe_top_k=2,
+        moe_shared_ff=32, moe_first_expert=4, moe_experts_held=4,
+        dtype=jnp.float32)
+    base.update(kw)
+    return GPTConfig(**base)
+
+
+@_needs_shard_map
+def test_hybrid_pattern_fsdp_sharded():
+    """`param_logical_axes` covers every weight of both kinds of layer, and
+    a step under fsdp gives the loss the unsharded model gives."""
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 virtual devices")
+    cfg = _hybrid_tiny()
+    toks = _tokens(cfg, b=4, s=80)
+    opt = make_optimizer(learning_rate=1e-3, total_steps=20)
+    plain = GPT(cfg)
+    params = plain.init(jax.random.PRNGKey(0))
+    axes = plain.param_logical_axes()
+    assert jax.tree_util.tree_structure(params) == (
+        jax.tree_util.tree_structure(
+            axes, is_leaf=lambda x: isinstance(x, tuple)))
+    for leaf, logical in zip(
+            jax.tree_util.tree_leaves(params), jax.tree_util.tree_leaves(
+                axes, is_leaf=lambda x: isinstance(x, tuple))):
+        assert leaf.ndim == len(logical), (leaf.shape, logical)
+    want, _ = plain.loss(params, {"tokens": toks})
+
+    mesh = build_mesh(MeshSpec(fsdp=4).resolve(4), devices=jax.devices()[:4])
+    model = GPT(cfg, mesh=mesh)
+    state = init_train_state(model, opt, jax.random.PRNGKey(0), mesh=mesh)
+    assert "fsdp" in str(
+        state.params["blocks"]["linear"]["w_qkvz"].sharding.spec)
+    step = make_train_step(model, opt, mesh=mesh)
+    batch = {"tokens": jax.device_put(toks, batch_shardings(mesh))}
+    losses = []
+    for _ in range(3):
+        state, m = step(state, batch)
+        losses.append(float(m["loss"]))
+    assert losses[0] == pytest.approx(float(want), rel=1e-4)
+    assert losses[-1] < losses[0]
+    assert np.array_equal(np.asarray(m["moe_expert_tokens"]).sum(-1),
+                          np.asarray(m["moe_routed_here"]))
+
+
+def test_pipeline_refuses_a_layer_pattern():
+    if len(jax.devices()) < 2:
+        pytest.skip("needs 2 devices")
+    mesh = build_mesh(MeshSpec(pp=2, dp=-1).resolve(len(jax.devices())))
+    with pytest.raises(NotImplementedError, match="layer pattern"):
+        GPT(_hybrid_tiny(n_layers=8, n_experts=0), mesh=mesh)
 
 
 def test_pipeline_matches_reference():
