@@ -590,6 +590,44 @@ class GPT:
             flat(q), flat(k), flat(v))
         return out.reshape(q.shape)
 
+    def _delta_rule(self, q, k, v, g, beta):
+        """q, k: [B, S, Hk, Dk], v: [B, S, Hv, Dv], g, beta: [B, S, Hv] →
+        [B, S, Hv, Dv].
+
+        On a mesh the rule runs under shard_map, as the flash kernels do (a
+        Mosaic call is not partitioned automatically): rows over the batch
+        axes, whole key heads with their value heads over the heads' axis
+        where it divides them, the sequence whole on every device (the
+        state runs along it). q, k, v and o cross the boundary as
+        [B, S, H * D], for `_attention`'s reason. A pipeline never comes
+        here: `GPT` refuses a layer pattern on one."""
+        c = self.config
+
+        def local(*xs):
+            return gated_delta_rule(*xs, impl=c.attention_impl)
+
+        if self.mesh is None:
+            return local(q, k, v, g, beta)
+
+        def flat(x):
+            return x.reshape(*x.shape[:2], -1)
+
+        def local_flat(qb, kb, vb, gb, bb):
+            return flat(local(*(x.reshape(*x.shape[:2], -1, whole.shape[-1])
+                                for x, whole in ((qb, q), (kb, k), (vb, v))),
+                              gb, bb))
+
+        heads = self.rules.mesh_axes("act_heads") or ()
+        over = math.prod(self.mesh.shape.get(a, 1) for a in (
+            (heads,) if isinstance(heads, str) else heads))
+        spec = self.rules.spec("act_batch", None,
+                               "act_heads" if q.shape[2] % over == 0
+                               else None)
+        out = jax.shard_map(local_flat, mesh=self.mesh, in_specs=(spec,) * 5,
+                            out_specs=spec, check_vma=False)(
+            flat(q), flat(k), flat(v), g, beta)
+        return out.reshape(v.shape)
+
     def _constrain(self, x, *logical):
         return with_logical_constraint(x, *logical, rules=self.rules,
                                        mesh=self.mesh)
@@ -689,7 +727,7 @@ class GPT:
                 g = -jnp.exp(w["A_log"].astype(f32)) * jax.nn.softplus(
                     ba[..., nv:] + w["dt_bias"].astype(f32))
         with jax.named_scope("attn_kernel"), jax.named_scope("gdn_rule"):
-            o = gated_delta_rule(q, k, v, g, beta)
+            o = self._delta_rule(q, k, v, g, beta)
         with jax.named_scope("attn_out"), jax.named_scope("gdn_out"):
             of = o.astype(f32)
             of = of * lax.rsqrt(jnp.mean(of * of, -1, keepdims=True) + c.eps)

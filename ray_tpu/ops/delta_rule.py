@@ -24,31 +24,75 @@ kept in float32 (three bf16 passes a product beside bf16 inputs; the other
 products take their operands in the inputs' dtype with float32 accumulation;
 the state is float32). Every exponent is of a difference G_t - G_j with
 t >= j, so nothing overflows at decays near 0, and at decays near 1 nothing
-is divided by a small number.
+is divided by a small number. Rows whose length is no multiple of the chunk
+are padded with positions that write nothing (b = 0, g = 0, zero q, k, v).
 
-The chunks run under one `lax.scan` whose body is checkpointed: the backward
-pass keeps the state that entered each chunk (S / C states, not S) and makes
-the chunk's products again. The solve has a backward rule of its own (with
-T = (I - A)^-1, dA = T^T dT T^T: two products where autodiff would walk the
-factors back). Rows whose length is no multiple of the chunk are padded with
-positions that write nothing (b = 0, g = 0, zero q, k, v).
+Two forms of that one algorithm, chosen as attention's are (`impl`: "auto"
+is the kernels on a TPU and the `jnp` form elsewhere):
 
-What bounds it on a v5e (PERF.md, PR 32): `jnp` products of [64, 64] and
-[64, 128] matrices, a dozen microseconds each whatever their FLOPs, and the
-float32 state crossing HBM three times a chunk; a Pallas kernel that keeps
-the state and the chunk's matrices in VMEM is the next step, and what ships
-sits under the scope `gdn_rule` for the trace to find.
+* A Pallas kernel pair, `gdn_rule_fwd` and `gdn_rule_bwd`. A grid step is a
+  batch row, `_BLOCK_KEY_HEADS` key heads with their value heads and
+  `_BLOCK` positions of the row, the position axis innermost and
+  sequential; the float32 states of those value heads live in VMEM scratch
+  from the row's first chunk to its last, and a chunk's matrices (K K^T,
+  Q K^T, the decay mask, the inverse, U, W, the delta) never leave VMEM.
+  K K^T and Q K^T are made once for the value heads that share a key head.
+  q, k, v and o are read and written as the projections hold them,
+  [B, S, H * D]: a head is a block of lanes, so no transposed copy stands
+  on either side. A step first makes what no state enters, for all its
+  chunks and heads, the solves level by level side by side (six or seven
+  dependent levels of small products each: alone, a solve leaves the matrix
+  unit waiting), then walks the chunks from state to state. The backward
+  kernel takes the blocks last to first with dS in scratch: it reads the
+  state that entered the block (the forward's residual, [B, Hv,
+  S / `_BLOCK`, Dk, Dv] float32, a quarter of what a checkpointed scan of
+  chunks of 64 keeps), walks the block's chunks forward again from it,
+  then backward, and writes dq, dk, dv rounded once and dG, dbeta in
+  float32; T = (I - A)^-1 has the closed derivative dA = T^T dT T^T. Each
+  kernel cuts the block into chunks of its own size (`_FWD_CHUNK`,
+  `_BWD_CHUNK`): the chunked form is exact at any, and only the states at
+  the blocks' edges pass from one to the other. The primal of the
+  `custom_vjp` is a forward that writes no states: under a block's
+  rematerialisation the first forward pays nothing for residuals it drops.
+* `gated_delta_rule_reference`: `jnp` chunks under one checkpointed
+  `lax.scan`, the reference the kernels are held to and the form every
+  other backend and every width that is no multiple of the 128 lanes runs.
+
+On a v5e (PERF.md, PR 33): the `jnp` form is bound by the count of its small
+products and by the state crossing HBM three times a chunk; the kernels by
+the dependent small products of the solve and of the walk from state to
+state, which the matrix unit's depth and the lanes of a [64, 64] matrix
+half fill.
 """
 
 from __future__ import annotations
 
 import functools
+import itertools
+import math
+import types
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 CHUNK = 64      # a power of two: the solve squares its way up to it
+# What a grid step of the kernels holds, as straight-line code for the
+# scheduler to overlap: positions of the row (a chunk's solve waits for no
+# state, so the solves of a step go level by level together) and key heads
+# (their value heads' walks from state to state wait only for themselves).
+_BLOCK = 256
+_BLOCK_KEY_HEADS = 2
+# The kernels' chunks, each exact: the forward is mostly the solve, which
+# is cheapest at 64; the backward is mostly products and sums over [C, C]
+# and [C, d] matrices, which at 128 fill the lanes and the unit's depth
+# (swept on the v5e, PERF.md PR 33: backward 18.08 -> 15.46 ms a call at 128,
+# forward 6.95 against 8.50; the second size buys about 8 ms of a 976 ms
+# step, so one size is the fallback if the solve is rewritten).
+_FWD_CHUNK = 64
+_BWD_CHUNK = 128
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
@@ -127,15 +171,13 @@ def _chunk_step(state, xs, *, repeat: int):
     return state, out.astype(dt)
 
 
-def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
-                     beta: jax.Array) -> jax.Array:
-    """q, k: [B, S, Hk, Dk] (scaled and normalised by the caller); v:
-    [B, S, Hv, Dv], key head j serving value heads j * Hv / Hk onwards;
-    g (log of the decay, <= 0) and beta: [B, S, Hv]. Returns o:
-    [B, S, Hv, Dv] in v's dtype."""
+def gated_delta_rule_reference(q: jax.Array, k: jax.Array, v: jax.Array,
+                               g: jax.Array, beta: jax.Array) -> jax.Array:
+    """`gated_delta_rule` as `jnp` chunks under a checkpointed `lax.scan`:
+    the backward pass keeps the state that entered each chunk and makes the
+    chunk's products again."""
     b, s, hk, _ = q.shape
     hv = v.shape[2]
-    assert hv % hk == 0, (hk, hv)
     pad = -s % CHUNK
     n = (s + pad) // CHUNK
 
@@ -153,3 +195,447 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
                       tuple(chunks(x) for x in (q, k, v, g, beta)))
     out = jnp.moveaxis(jnp.swapaxes(out, 2, 3), 0, 1)   # [B, n, C, Hv, Dv]
     return out.reshape(b, n * CHUNK, hv, -1)[:, :s]
+
+
+# ---------------------------------------------------------------------------
+# The Pallas kernels
+# ---------------------------------------------------------------------------
+
+_NN = (((1,), (0,)), ((), ()))      # a [m, n] x b [n, d] -> [m, d]
+_NT = (((1,), (1,)), ((), ()))      # a [m, d] x b [n, d] -> [m, n]
+_TN = (((0,), (0,)), ((), ()))      # a [n, m] x b [n, d] -> [m, d]
+
+
+class _Chunk:
+    """What the forward and the backward kernel both make of a block's
+    chunks before any state enters: the products, in the precision of the
+    module's docstring, and each value head's matrices. `dt` is the inputs'
+    dtype. Matrices over two positions are [t, j] (row t, lane j) unless
+    their name ends in `_t`."""
+
+    def __init__(self, c, dt):
+        self.c, self.dt = c, dt
+        self.exact = dt == jnp.float32
+        row = lax.broadcasted_iota(jnp.int32, (c, c), 0)
+        lane = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+        self.eye, self.lower, self.strict = row == lane, row >= lane, row > lane
+        self.upper, self.strict_upper = row <= lane, row < lane
+        self.left = lax.broadcasted_iota(jnp.int32, (c, 2 * c), 1) < c
+
+    def mm(self, a, b, dims=_NN):
+        """Operands in the inputs' dtype, float32 accumulation."""
+        return lax.dot_general(
+            a.astype(self.dt), b.astype(self.dt), dims,
+            precision=lax.Precision.HIGHEST if self.exact else None,
+            preferred_element_type=jnp.float32)
+
+    def split(self, a):
+        """A float32 operand of the solve's products: itself beside float32
+        inputs, else its bf16 high and low parts."""
+        if self.exact:
+            return (a,)
+        high = a.astype(jnp.bfloat16)
+        return high, (a - high.astype(jnp.float32)).astype(jnp.bfloat16)
+
+    def mm_solve(self, a, b):
+        """a x b of split operands: at full precision beside float32
+        inputs, else three bf16 passes (high x high, high x low, low x
+        high)."""
+        if self.exact:
+            return self.mm(a[0], b[0])
+
+        def dot(x, y):
+            return lax.dot_general(x, y, _NN,
+                                   preferred_element_type=jnp.float32)
+
+        return dot(a[0], b[0]) + (dot(a[0], b[1]) + dot(a[1], b[0]))
+
+    def col(self, row):
+        """[1, C] -> [C, 1] (positions from lanes to sublanes)."""
+        return jnp.sum(jnp.where(self.eye, row, 0.0), axis=1, keepdims=True)
+
+    def row(self, col):
+        """[C, 1] -> [1, C]."""
+        return jnp.sum(jnp.where(self.eye, col, 0.0), axis=0, keepdims=True)
+
+    def inverses_t(self, a_ts):
+        """((I - a)^-1)^T for each a^T of the list, as `_unit_lower_inverse`
+        makes it, transposed: a power's square and the inverse's next factor
+        share their left operand, so a level is one product against
+        [inverse^T | power^T], and the list's matrices go level by level
+        together, which is what lets the scheduler fill one's waits with
+        another's work."""
+        c = self.c
+        powers = [self.split(a_t) for a_t in a_ts]
+        powers = [self.mm_solve(p, p) for p in powers]
+        both = [jnp.concatenate([jnp.where(self.eye, 1.0, a_t), p], axis=1)
+                for a_t, p in zip(a_ts, powers)]
+        for _ in range(c.bit_length() - 3):
+            steps = [self.mm_solve(self.split(p), self.split(z))
+                     for p, z in zip(powers, both)]
+            both = [jnp.where(self.left, z + s, s)
+                    for z, s in zip(both, steps)]
+            powers = [z[:, c:] for z in both]
+        return [z[:, :c] + self.mm_solve(self.split(p), self.split(z[:, :c]))
+                for p, z in zip(powers, both)]
+
+    def heads(self, q_ref, k_ref, v_ref, g_ref, beta_ref, key_heads):
+        """Every chunk of the block and every value head of its key heads,
+        chunk-major: q, k: [m C, key_heads Dk] refs; v: [m C, Hv Dv]; g (G),
+        beta: [Hv, m, C], Hv the value heads of the block's key heads."""
+        f32, dt, c = jnp.float32, self.dt, self.c
+        repeat = g_ref.shape[0] // key_heads
+        dk, dv = q_ref.shape[1] // key_heads, v_ref.shape[1] // g_ref.shape[0]
+        heads = []
+        for i, j in itertools.product(range(q_ref.shape[0] // c),
+                                      range(key_heads)):
+            rows, key_cols = slice(i * c, (i + 1) * c), slice(j * dk,
+                                                              (j + 1) * dk)
+            q, k = q_ref[rows, key_cols], k_ref[rows, key_cols]
+            qf, kf = q.astype(f32), k.astype(f32)
+            kk, qk = self.mm(k, k, _NT), self.mm(q, k, _NT)
+            for r in range(j * repeat, (j + 1) * repeat):
+                h = types.SimpleNamespace(
+                    i=i, r=r, rows=rows, key_cols=key_cols,
+                    cols=slice(r * dv, (r + 1) * dv),
+                    last_of_key=r + 1 == (j + 1) * repeat,
+                    q=q, k=k, qf=qf, kf=kf, kk=kk, qk=qk)
+                h.v = v_ref[rows, h.cols]
+                total, beta = g_ref[r, i:i + 1, :], beta_ref[r, i:i + 1, :]
+                total_c, h.beta_c = self.col(total), self.col(beta)
+                h.decay = jnp.exp(jnp.where(self.lower, total_c - total,
+                                            -jnp.inf))
+                decay_t = jnp.exp(jnp.where(self.upper, total - total_c,
+                                            -jnp.inf))
+                h.a_t = jnp.where(self.strict_upper, -beta * kk * decay_t,
+                                  0.0)
+                h.e_total = jnp.exp(total_c)                      # [C, 1]
+                h.k_gain = h.beta_c * h.e_total
+                # beta V and beta e^G K side by side: U, W are one product
+                h.vk = jnp.concatenate(
+                    [(h.beta_c * h.v.astype(f32)).astype(dt),
+                     (h.k_gain * kf).astype(dt)], axis=1)
+                h.qg = (h.e_total * qf).astype(dt)
+                h.scores = qk * h.decay
+                last = total_c[c - 1:c]                           # [1, 1]
+                h.e_last = jnp.exp(last)
+                h.k_left = jnp.exp(last - total_c)                # e^{G_C-G}
+                h.k_decayed = (h.k_left * kf).astype(dt)
+                heads.append(h)
+        for h, solve_t in zip(heads, self.inverses_t([h.a_t for h in heads])):
+            h.solve_t = solve_t
+            uw = self.mm(solve_t, h.vk, _TN)
+            h.u, h.w = uw[:, :dv], uw[:, dv:].astype(dt)
+        return heads
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest, chunk,
+                key_heads):
+    """One batch row, `key_heads` key heads with their Hv value heads, `m`
+    chunks of the row. q, k: [m C, key_heads Dk]; v, o: [m C, Hv Dv]; g (G,
+    summed from each chunk's start), beta: [Hv, m, C]; states (only the
+    `fwd` rule's call): [Hv, Dk, Dv], the state that entered the block;
+    scratch: [Hv, Dk, Dv]."""
+    state_ref = rest[-1]
+    states_ref = rest[0] if len(rest) > 1 else None
+    ch = _Chunk(chunk, q_ref.dtype)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        state_ref[...] = jnp.zeros_like(state_ref)
+
+    if states_ref is not None:
+        states_ref[...] = state_ref[...]
+    for h in ch.heads(q_ref, k_ref, v_ref, g_ref, beta_ref, key_heads):
+        state = state_ref[h.r]
+        # W S and (e^G Q) S as one product: the state is pushed once
+        from_state = ch.mm(jnp.concatenate([h.w, h.qg], 0), state)
+        delta = (h.u - from_state[:chunk]).astype(ch.dt)
+        o_ref[h.rows, h.cols] = (
+            from_state[chunk:] + ch.mm(h.scores, delta)).astype(o_ref.dtype)
+        state_ref[h.r] = h.e_last * state + ch.mm(h.k_decayed, delta, _TN)
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
+                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dstate_ref,
+                walk_ref, *, chunk, key_heads):
+    """The same blocks, taken last to first. do, dv: [m C, Hv Dv]; dq, dk:
+    [m C, key_heads Dk], summed over a key head's value heads; dg (with
+    respect to G), dbeta: [Hv, m, C] float32; scratch: dS, [Hv, Dk, Dv]
+    float32, the gradient of the state that leaves the chunk, and the
+    states that entered the block's chunks, [Hv, m, Dk, Dv], walked again
+    from the one the forward kept."""
+    f32, c = jnp.float32, chunk
+    ch = _Chunk(chunk, q_ref.dtype)
+    mm, dt = ch.mm, ch.dt
+    at_last = lax.broadcasted_iota(jnp.int32, (1, c), 1) == c - 1
+
+    def lanes(x):       # sum over a row's lanes, [C, d] -> [C, 1]
+        return jnp.sum(x, axis=1, keepdims=True)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        dstate_ref[...] = jnp.zeros_like(dstate_ref)
+
+    heads = ch.heads(q_ref, k_ref, v_ref, g_ref, beta_ref, key_heads)
+    walk_ref[:, 0] = states_ref[...]
+    for h in heads:     # the forward's walk from the block's first state
+        state = walk_ref[h.r, h.i]
+        h.state_dt = state.astype(dt)
+        h.delta = (h.u - mm(h.w, h.state_dt)).astype(dt)
+        if h.i + 1 < walk_ref.shape[1]:
+            walk_ref[h.r, h.i + 1] = h.e_last * state + mm(
+                h.k_decayed, h.delta, _TN)
+    # what dS enters, last chunk first: O = (e^G Q) S + scores delta and
+    # S_C = e^{G_C} S + k_decayed^T delta, with delta = U - W S
+    for h in reversed(heads):
+        h.do = do_ref[h.rows, h.cols]
+        dstate = dstate_ref[h.r]
+        dstate_dt = dstate.astype(dt)
+        h.d_delta = (mm(h.scores, h.do, _TN)
+                     + mm(h.k_decayed, dstate_dt)).astype(dt)
+        h.d_k_decayed = mm(h.delta, dstate_dt, _NT)
+        h.d_e_last = jnp.sum(lanes(dstate * walk_ref[h.r, h.i]), axis=0,
+                             keepdims=True)
+        dstate_ref[h.r] = h.e_last * dstate + mm(
+            jnp.concatenate([h.qg, h.w], 0),
+            jnp.concatenate([h.do, -h.d_delta], 0), _TN)
+    # the rest waits for no other chunk
+    dq = dk = jnp.zeros(heads[0].q.shape, f32)
+    for h in heads:
+        qf, kf = h.qf, h.kf
+        d_scores = mm(h.do, h.delta, _NT)
+        d_qg = mm(h.do, h.state_dt, _NT)
+        d_w = -mm(h.d_delta, h.state_dt, _NT)
+        left = lanes(h.d_k_decayed * h.k_left * kf)     # d(G_C - G_t)
+        d_last = h.e_last * h.d_e_last + jnp.sum(left, axis=0, keepdims=True)
+        # U, W = T [beta V, beta e^G K]; dA = T^T dT T^T
+        d_uw = jnp.concatenate([h.d_delta, d_w.astype(dt)], axis=1)
+        d_vk = mm(h.solve_t, d_uw)
+        dv = h.v.shape[1]
+        d_vb, d_kb = d_vk[:, :dv], d_vk[:, dv:]
+        solve_t = ch.split(h.solve_t)
+        d_a = ch.mm_solve(
+            ch.split(ch.mm_solve(solve_t, ch.split(mm(d_uw, h.vk, _NT)))),
+            solve_t)
+        # A = -beta K K^T decay below the diagonal
+        through = jnp.where(ch.strict, d_a, 0.0) * h.decay
+        d_kk = -h.beta_c * through
+        d_qk = d_scores * h.decay
+        # every exponent G_t - G_j: + its row's sum to t, - its column's to j
+        exponents = d_kk * h.kk + d_scores * h.scores
+        d_total_c = (lanes(exponents) - left + lanes(d_kb * h.k_gain * kf)
+                     + lanes(d_qg * h.e_total * qf))
+        d_total = (ch.row(d_total_c)
+                   - jnp.sum(exponents, axis=0, keepdims=True)
+                   + jnp.where(at_last, d_last, 0.0))
+        d_beta_c = (lanes(d_vb * h.v.astype(f32))
+                    + lanes(d_kb * kf) * h.e_total - lanes(through * h.kk))
+        dq = dq + h.e_total * d_qg + mm(d_qk, h.k)
+        dk = (dk + h.k_left * h.d_k_decayed + h.k_gain * d_kb
+              + mm(d_kk, h.k) + mm(d_kk, h.k, _TN) + mm(d_qk, h.q, _TN))
+        dv_ref[h.rows, h.cols] = (h.beta_c * d_vb).astype(dv_ref.dtype)
+        dg_ref[h.r, h.i:h.i + 1, :] = d_total
+        dbeta_ref[h.r, h.i:h.i + 1, :] = ch.row(d_beta_c)
+        if h.last_of_key:
+            dq_ref[h.rows, h.key_cols] = dq.astype(dq_ref.dtype)
+            dk_ref[h.rows, h.key_cols] = dk.astype(dk_ref.dtype)
+            dq = dk = jnp.zeros_like(dq)
+
+
+def _specs(q, v, chunk, reverse):
+    """One call's tiling: the grid (batch, block of key heads, block of m
+    chunks), the key heads a step, block specs by kind of array — rows of
+    keys [B, S, Hk Dk], rows of values [B, S, Hv Dv], `gates` (g and beta)
+    [B, Hv, S / block, m, C], `states` [B, Hv, S / block, Dk, Dv] — and
+    the shapes of the gates, of the states and of one step's states.
+    `reverse` walks the blocks last to first."""
+    b, s, hk, dk = q.shape
+    hv, dv = v.shape[2:]
+    m = min(_BLOCK, s) // chunk
+    steps = s // (m * chunk)
+    key_heads = math.gcd(_BLOCK_KEY_HEADS, hk)
+    here = key_heads * hv // hk         # value heads a step
+
+    def at(c):
+        return steps - 1 - c if reverse else c
+
+    specs = {
+        "keys": pl.BlockSpec((None, m * chunk, key_heads * dk),
+                             lambda i, j, c: (i, at(c), j)),
+        "values": pl.BlockSpec((None, m * chunk, here * dv),
+                               lambda i, j, c: (i, at(c), j)),
+        "gates": pl.BlockSpec((None, here, None, m, chunk),
+                              lambda i, j, c: (i, j, at(c), 0, 0)),
+        "states": pl.BlockSpec((None, here, None, dk, dv),
+                               lambda i, j, c: (i, j, at(c), 0, 0)),
+    }
+    return types.SimpleNamespace(
+        specs=specs, grid=(b, hk // key_heads, steps), key_heads=key_heads,
+        gates=(b, hv, steps, m, chunk), states=(b, hv, steps, dk, dv),
+        held=(here, dk, dv))
+
+
+_SEQUENTIAL = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+# The kernels' bodies are some thousands of operations written out: under
+# `jax.jit` a model's layers, and its loss and its evaluation, trace them
+# once between them (a second of Python a trace, several in a busy worker).
+@functools.partial(jax.jit,
+                   static_argnames=("chunk", "interpret", "with_states"))
+def _fwd_pallas(q, k, v, total, beta, *, chunk, interpret, with_states):
+    """q, k: [B, S, Hk, Dk]; v: [B, S, Hv, Dv]; total, beta: [B, Hv, S]
+    float32; S a multiple of the block. Returns o [B, S, Hv, Dv] and, with
+    `with_states`, the states that entered the blocks."""
+    t = _specs(q, v, chunk, False)
+    specs = t.specs
+    b, s = q.shape[:2]
+    out_specs = [specs["values"]]
+    out_shape = [jax.ShapeDtypeStruct((b, s, v.shape[2] * v.shape[3]),
+                                      v.dtype)]
+    if with_states:
+        out_specs.append(specs["states"])
+        out_shape.append(jax.ShapeDtypeStruct(t.states, jnp.float32))
+    out = pl.pallas_call(
+        functools.partial(_fwd_kernel, chunk=chunk, key_heads=t.key_heads),
+        grid=t.grid,
+        in_specs=[specs["keys"], specs["keys"], specs["values"],
+                  specs["gates"], specs["gates"]],
+        out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM(t.held, jnp.float32)],
+        compiler_params=_SEQUENTIAL, interpret=interpret,
+        name="gdn_rule_fwd",
+    )(q.reshape(b, s, -1), k.reshape(b, s, -1), v.reshape(b, s, -1),
+      total.reshape(t.gates), beta.reshape(t.gates))
+    return (out[0].reshape(v.shape), *out[1:])
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def _bwd_pallas(q, k, v, total, beta, states, do, *, chunk, interpret):
+    """The gradients of q, k, v (their dtypes) and of total and beta
+    (float32), from the states the forward kept and dO [B, S, Hv, Dv]."""
+    t = _specs(q, v, chunk, True)
+    specs = t.specs
+    b, s = q.shape[:2]
+    keys = jax.ShapeDtypeStruct((b, s, q.shape[2] * q.shape[3]), q.dtype)
+    gate = jax.ShapeDtypeStruct(t.gates, jnp.float32)
+    dq, dk, dv, dtotal, dbeta = pl.pallas_call(
+        functools.partial(_bwd_kernel, chunk=chunk, key_heads=t.key_heads),
+        grid=t.grid,
+        in_specs=[specs["keys"], specs["keys"], specs["values"],
+                  specs["gates"], specs["gates"], specs["states"],
+                  specs["values"]],
+        out_specs=[specs["keys"], specs["keys"], specs["values"],
+                   specs["gates"], specs["gates"]],
+        out_shape=[keys, keys,
+                   jax.ShapeDtypeStruct((b, s, v.shape[2] * v.shape[3]),
+                                        v.dtype), gate, gate],
+        scratch_shapes=[pltpu.VMEM(t.held, jnp.float32),
+                        pltpu.VMEM((t.held[0], t.gates[3], *t.held[1:]),
+                                   jnp.float32)],
+        compiler_params=_SEQUENTIAL, interpret=interpret,
+        name="gdn_rule_bwd",
+    )(q.reshape(b, s, -1), k.reshape(b, s, -1), v.reshape(b, s, -1),
+      total.reshape(t.gates), beta.reshape(t.gates), states,
+      do.reshape(b, s, -1))
+    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
+            dtotal.reshape(total.shape), dbeta.reshape(beta.shape))
+
+
+def _scoped(fn):
+    """The scopes the trace files the rule under (`GPT._linear_mixer`'s),
+    kept inside the `custom_vjp`'s rules as well."""
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        with jax.named_scope("attn_kernel"), jax.named_scope("gdn_rule"):
+            return fn(*args, **kwargs)
+    return scoped
+
+
+def _chunk_sums(g, chunk, reverse=False):
+    """g [B, Hv, S] summed from each chunk's start to each position (G) or,
+    with `reverse`, from each position to its chunk's end (what G's
+    gradient owes g)."""
+    chunks = g.reshape(*g.shape[:2], -1, chunk)
+    if reverse:
+        chunks = jnp.flip(chunks, -1)
+    sums = jnp.cumsum(chunks, axis=-1)
+    return (jnp.flip(sums, -1) if reverse else sums).reshape(g.shape)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _rule(q, k, v, g, beta, interpret):
+    """q, k, v: [B, S, H, D]; g, beta: [B, Hv, S] float32; S whole blocks."""
+    return _fwd_pallas(q, k, v, _chunk_sums(g, _FWD_CHUNK), beta,
+                       chunk=_FWD_CHUNK, interpret=interpret,
+                       with_states=False)[0]
+
+
+@_scoped
+def _rule_fwd(q, k, v, g, beta, interpret):
+    out, states = _fwd_pallas(q, k, v, _chunk_sums(g, _FWD_CHUNK), beta,
+                              chunk=_FWD_CHUNK, interpret=interpret,
+                              with_states=True)
+    return out, (q, k, v, g, beta, states)
+
+
+@_scoped
+def _rule_bwd(interpret, residuals, do):
+    q, k, v, g, beta, states = residuals
+    *grads, d_total, d_beta = _bwd_pallas(
+        q, k, v, _chunk_sums(g, _BWD_CHUNK), beta, states, do,
+        chunk=_BWD_CHUNK, interpret=interpret)
+    return (*grads, _chunk_sums(d_total, _BWD_CHUNK, reverse=True), d_beta)
+
+
+_rule.defvjp(_rule_fwd, _rule_bwd)
+
+
+def _gated_delta_rule_pallas(q, k, v, g, beta, *, interpret):
+    """Rows padded to whole blocks of whole chunks of either kernel, g and
+    beta as [B, Hv, S] float32 rows."""
+    s = q.shape[1]
+    pad = -s % max(_FWD_CHUNK, _BWD_CHUNK)
+    pad += -(s + pad) % min(_BLOCK, s + pad)
+
+    def padded(x):
+        return jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
+
+    def head_rows(x):   # [B, S, Hv] -> [B, Hv, S] float32
+        return jnp.swapaxes(padded(x.astype(jnp.float32)), 1, 2)
+
+    out = _rule(padded(q), padded(k), padded(v), head_rows(g),
+                head_rows(beta), interpret)
+    return out[:, :s]
+
+
+def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+                     beta: jax.Array, impl: str = "auto") -> jax.Array:
+    """q, k: [B, S, Hk, Dk] (scaled and normalised by the caller); v:
+    [B, S, Hv, Dv], key head j serving value heads j * Hv / Hk onwards;
+    g (log of the decay, <= 0) and beta: [B, S, Hv]. Returns o:
+    [B, S, Hv, Dv] in v's dtype.
+
+    impl: as `ops.attention.dot_product_attention`'s — "auto" (the kernels
+    on a TPU, `jnp` elsewhere), "pallas", "pallas_interpret" (the kernels
+    under the interpreter: CPU tests), "reference". On a TPU the kernels
+    take key and value widths that are multiples of the 128 lanes: under
+    "auto" other widths run the `jnp` form, and "pallas" refuses them."""
+    assert v.shape[2] % q.shape[2] == 0, (q.shape, v.shape)
+    lanes = not (q.shape[-1] % 128 or v.shape[-1] % 128)
+    if impl == "auto":
+        impl = ("pallas" if lanes and jax.default_backend() == "tpu"
+                else "reference")
+    if impl == "pallas" and not lanes:
+        raise ValueError(
+            "the delta rule's kernels take key and value widths that are "
+            f"multiples of 128 on a TPU, got {q.shape[-1]} and "
+            f"{v.shape[-1]}: use impl='auto' or 'reference'")
+    if impl == "reference":
+        return gated_delta_rule_reference(q, k, v, g, beta)
+    if impl in ("pallas", "pallas_interpret"):
+        return _gated_delta_rule_pallas(q, k, v, g, beta,
+                                        interpret=impl == "pallas_interpret")
+    raise ValueError(f"unknown delta rule impl {impl!r}")

@@ -87,6 +87,12 @@ def _kernel_calls(compiled, name=""):
             and f"/{name}" in line]
 
 
+def _kernel_names(compiled, name):
+    """Those calls' `pallas_call` names, sorted."""
+    return sorted(re.search(rf"/({name}\w*)/pallas_call", line).group(1)
+                  for line in _kernel_calls(compiled, name))
+
+
 def _gpt2_medium_step(mesh, batch, remat_policy="dots"):
     from ray_tpu.models import (GPT, gpt2_medium, init_train_state,
                                 make_optimizer, make_train_step)
@@ -227,10 +233,47 @@ def test_flash_attention_compiles_at_qwen3_next_width(v5e):
     assert compiled.as_text().count("tpu_custom_call") >= 3
 
 
-def _qwen3_next_step(config, batch):
+def test_delta_rule_kernels_compile_at_qwen3_next_width(v5e):
+    """The gated delta rule's kernel pair as `qwen3next-steady`'s three
+    Gated DeltaNet layers call it: [4 rows, 8192, 16 key heads serving 32
+    value heads, width 128], bf16 with float32 g and beta, read and written
+    as the projections hold them. Mosaic takes both, and their lines of the
+    compiled text carry the scope the trace's readers look for: filed
+    elsewhere, `gdn_rule_roofline` would divide the rule's least time by
+    the leftover plumbing."""
+    from ray_tpu.ops.delta_rule import gated_delta_rule
+
+    model = _qwen3_next_config()["model"]
+    rows = _qwen3_next_config()["batch_per_chip"]
+
+    def loss(q, k, v, g, beta):
+        with jax.named_scope("attn_kernel"), jax.named_scope("gdn_rule"):
+            return gated_delta_rule(q, k, v, g, beta, impl="pallas").astype(
+                jnp.float32).sum()
+
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    q, v, g = (jax.ShapeDtypeStruct(
+        (rows, model["max_seq_len"], *minor), dtype, sharding=one_chip)
+        for minor, dtype in (
+            ((model["linear_key_heads"], model["linear_key_dim"]),
+             jnp.bfloat16),
+            ((model["linear_value_heads"], model["linear_value_dim"]),
+             jnp.bfloat16),
+            ((model["linear_value_heads"],), jnp.float32)))
+    assert q.shape == (4, 8192, 16, 128) and v.shape == (4, 8192, 32, 128)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        q, q, v, g, g).compile()
+    assert _kernel_names(compiled, "gdn_rule_") == ["gdn_rule_bwd",
+                                                    "gdn_rule_fwd"]
+    for line in _kernel_calls(compiled, "gdn_rule_"):
+        path = re.search(r'op_name="([^"]*)"', line).group(1)
+        assert "/gdn_rule/" in path.split("gdn_rule_")[0], path
+
+
+def _qwen3_next_step(config, batch, mesh=None):
     """The one-period Qwen3-Next step of the benchmark's
     `qwen3_next_80b_a3b` configuration, as its cell builds it, at `batch`
-    rows of 8,192."""
+    rows of 8,192 (on a mesh the step's own in_shardings place the state)."""
     from ray_tpu.models import (GPT, init_train_state, make_optimizer,
                                 make_train_step)
     from ray_tpu.models.gpt import GPTConfig
@@ -238,12 +281,12 @@ def _qwen3_next_step(config, batch):
     kw = dict(config["model"], attention_impl="pallas")
     kw["dtype"] = getattr(jnp, kw["dtype"])
     kw["param_dtype"] = getattr(jnp, kw["param_dtype"])
-    model = GPT(GPTConfig(**kw))
+    model = GPT(GPTConfig(**kw), **({"mesh": mesh} if mesh else {}))
     opt = make_optimizer(**config["optimizer"])
     state = jax.eval_shape(
         lambda: init_train_state(model, opt, jax.random.PRNGKey(0)))
     tokens = jax.ShapeDtypeStruct((batch, kw["max_seq_len"]), jnp.int32)
-    return make_train_step(model, opt), state, tokens
+    return make_train_step(model, opt, mesh=mesh), state, tokens
 
 
 def test_qwen3_next_period_train_step_fills_one_chip(v5e):
@@ -251,8 +294,11 @@ def test_qwen3_next_period_train_step_fills_one_chip(v5e):
     widths (three Gated DeltaNet layers, one gated full-attention layer,
     each with a shared expert and 32 of 512 routed experts), 18,992 rows of
     embedding and untied head, float32 AdamW state, at the configuration's
-    `batch_per_chip` rows of 8,192 tokens under "full" remat. It fits, and
-    one row more does not: this is what fixes `batch_per_chip`."""
+    `batch_per_chip` rows of 8,192 tokens under "full" remat. It fits with
+    little recomputed by the compiler on its own, and one row more does not
+    fit at all: refused by 65 MB with the `jnp` delta rule (PR 32) and by
+    686 MB with the kernel pair (PR 33: 16.42 of 15.75 GiB, program 9.42
+    and arguments 6.99), so 4 rows is still what fills the chip."""
     one_chip = SingleDeviceSharding(v5e.devices[0])
     config = _qwen3_next_config()
     rows = config["batch_per_chip"]
@@ -265,14 +311,21 @@ def test_qwen3_next_period_train_step_fills_one_chip(v5e):
     # grouped matmuls are kernels too, inside loops whose trip count
     # follows the pairs routed here
     assert len(_kernel_calls(compiled, "flash_")) == 4
-    assert len(_kernel_calls(compiled)) > 4
+    # the delta rule's, three for each of the period's three Gated DeltaNet
+    # layers (the scan is over periods; a period's layers are written out):
+    # the primal forward, which writes no states, the `fwd` rule's forward
+    # under "full" remat, which writes them, and the backward
+    assert _kernel_names(compiled, "gdn_rule_") == (
+        ["gdn_rule_bwd"] * 3 + ["gdn_rule_fwd"] * 6)
+    assert len(_kernel_calls(compiled)) > 13
     text = compiled.as_text()
     assert "while(" in text
     # at these rows the compiler makes room on its own (PERF.md, PR 29's
     # lesson; one row fewer compiles without): what it computes twice is
-    # tuple elements, elementwise passes over [rows, 8192, 2048] and one
-    # Gated DeltaNet projection, [rows, 8192, 12288] (8.8 ms a step on the
-    # chip, PERF.md PR 32) — no kernel's output, nothing of the head's
+    # tuple elements, an elementwise pass over [rows, 8192, 2048] and the
+    # Gated DeltaNet projection, [rows, 8192, 12288], of each of the three
+    # layers (8.8 ms each a step on the chip, PERF.md PR 33) — no kernel's
+    # output, nothing of the head's
     remats = re.findall(
         r"%\S*\.remat\S* = (?:bf16|f32)\[([0-9,]+)\]\S* ([a-z\-]+)\(", text)
     for dims, opcode in remats:
@@ -284,10 +337,39 @@ def test_qwen3_next_period_train_step_fills_one_chip(v5e):
     mem = compiled.memory_analysis()
     # the donated state is aliased to the new one: 12 bytes a parameter
     assert mem.alias_size_in_bytes > 7.4e9
+    # the step's temporaries, 8.87 GiB (`hbm_program_gb` 9.52): one more
+    # [rows, 8192, 4096] bf16 array kept across a layer is 0.25 GiB
+    assert mem.temp_size_in_bytes < 9.0 * 2 ** 30
     step, state, tokens = _qwen3_next_step(config, rows + 1)
     with pytest.raises(Exception, match="(?i)ran out of memory|exhausted"):
         step.lower(_on(one_chip, state),
                    {"tokens": _on(one_chip, tokens)}).compile()
+
+
+@pytest.mark.parametrize("axes", [dict(fsdp=4), dict(fsdp=2, tp=2)],
+                         ids=["fsdp4", "fsdp2_tp2"])
+def test_qwen3_next_period_train_step_compiles_for_the_host(v5e, axes):
+    """The same step on the host's four chips, 4 rows of 8,192 between
+    them: the delta rule's kernels run under `GPT._delta_rule`'s
+    shard_map (a Mosaic call is not partitioned automatically, and left
+    bare the step does not lower), a chip's rows under fsdp, and under tp
+    also its half of the 16 key heads with their value heads."""
+    from ray_tpu.models.training import batch_shardings
+    from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+
+    config = _qwen3_next_config()
+    mesh = build_mesh(MeshSpec(**axes), devices=v5e.devices)
+    step, state, tokens = _qwen3_next_step(
+        config, config["batch_per_chip"], mesh)
+    compiled = step.lower(
+        state, {"tokens": _on(batch_shardings(mesh), tokens)}).compile()
+    assert _kernel_names(compiled, "gdn_rule_") == (
+        ["gdn_rule_bwd"] * 3 + ["gdn_rule_fwd"] * 6)
+    assert len(_kernel_calls(compiled, "flash_")) == 4
+    # a chip's share of the rule: [rows / fsdp, 8192, 16 / tp key heads]
+    assert (f"bf16[{4 // axes['fsdp']},8192,{2048 // axes.get('tp', 1)}]"
+            in compiled.as_text())
+    assert "all-gather" in compiled.as_text()
 
 
 @pytest.mark.slow   # 12 s here, and tier-1 runs close to its time limit

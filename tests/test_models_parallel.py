@@ -91,12 +91,20 @@ def _hybrid_tiny(**kw):
 
 
 @_needs_shard_map
-def test_hybrid_pattern_fsdp_sharded():
+@pytest.mark.parametrize("mesh_axes,kw", [
+    (dict(fsdp=4), {}),
+    # the delta rule's kernels (under the interpreter) inside the mixer's
+    # shard_map, a key head with its two value heads on each side of tp
+    (dict(fsdp=2, tp=2), dict(attention_impl="pallas_interpret")),
+    # one key head does not split over tp: the rule runs whole on both
+    (dict(fsdp=2, tp=2), dict(linear_key_heads=1, linear_value_heads=2)),
+], ids=["fsdp4", "fsdp2_tp2_kernels", "fsdp2_tp2_one_key_head"])
+def test_hybrid_pattern_fsdp_sharded(mesh_axes, kw):
     """`param_logical_axes` covers every weight of both kinds of layer, and
-    a step under fsdp gives the loss the unsharded model gives."""
+    a step on a mesh gives the loss the unsharded model gives."""
     if len(jax.devices()) < 4:
         pytest.skip("needs 4 virtual devices")
-    cfg = _hybrid_tiny()
+    cfg = _hybrid_tiny(**kw)
     toks = _tokens(cfg, b=4, s=80)
     opt = make_optimizer(learning_rate=1e-3, total_steps=20)
     plain = GPT(cfg)
@@ -111,7 +119,7 @@ def test_hybrid_pattern_fsdp_sharded():
         assert leaf.ndim == len(logical), (leaf.shape, logical)
     want, _ = plain.loss(params, {"tokens": toks})
 
-    mesh = build_mesh(MeshSpec(fsdp=4).resolve(4), devices=jax.devices()[:4])
+    mesh = build_mesh(MeshSpec(**mesh_axes).resolve(4), devices=jax.devices()[:4])
     model = GPT(cfg, mesh=mesh)
     state = init_train_state(model, opt, jax.random.PRNGKey(0), mesh=mesh)
     assert "fsdp" in str(

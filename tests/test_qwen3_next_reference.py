@@ -265,20 +265,10 @@ def _recurrence(q, k, v, g, beta):
     return jnp.moveaxis(out, 0, 1)
 
 
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("rate", [1e-3, 1.0, 40.0],
-                         ids=["decay_near_1", "decay_mid", "decay_near_0"])
-def test_chunked_delta_rule_matches_the_recurrence(rate, dtype):
-    """Forward and every input's gradient, on a row of 150 (two chunks and
-    a ragged third), two value heads a key head, against the recurrence in
-    float32. In float32 both sides differ by the order of their sums; in
-    bfloat16 (the branch the chip runs: q, k, v in bf16, g and beta in
-    float32 as `GPT._linear_mixer` hands them over) by bf16's rounding of
-    the same inputs."""
-    f32 = jnp.float32
-    wide = dtype == "float32"
-    keys = jax.random.split(jax.random.PRNGKey(0 if wide else 5), 6)
-    b, s, hk, hv, d = 2, 150, 2, 4, 16 if wide else 32
+def _delta_rule_inputs(seed, dtype, rate, b, s, hk, hv, d):
+    """q and k normalised as `GPT._linear_mixer` hands them over, g and beta
+    in float32, and a weight for the result's sum."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
     q, k = (jax.random.normal(key, (b, s, hk, d)) for key in keys[:2])
     q = (q / jnp.linalg.norm(q, axis=-1, keepdims=True) / np.sqrt(d)
          ).astype(dtype)
@@ -287,16 +277,37 @@ def test_chunked_delta_rule_matches_the_recurrence(rate, dtype):
     g = -rate * jax.nn.softplus(jax.random.normal(keys[3], (b, s, hv)))
     beta = jax.nn.sigmoid(jax.random.normal(keys[4], (b, s, hv)))
     weight = jax.random.normal(keys[5], (b, s, hv, d))
-    args = (q, k, v, g, beta)
+    return (q, k, v, g, beta), weight
+
+
+def _rule_and_grads(impl, args, weight):
+    def weighted(*a):
+        return (gated_delta_rule(*a, impl=impl).astype(jnp.float32)
+                * weight).sum()
+
+    return (gated_delta_rule(*args, impl=impl),
+            *jax.grad(weighted, argnums=range(5))(*args))
+
+
+@pytest.mark.parametrize("impl", ["reference", "pallas_interpret"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("rate", [1e-3, 1.0, 40.0],
+                         ids=["decay_near_1", "decay_mid", "decay_near_0"])
+def test_chunked_delta_rule_matches_the_recurrence(rate, dtype, impl):
+    """Forward and every input's gradient, on a row of 150 (two chunks and
+    a ragged third), two value heads a key head, against the recurrence in
+    float32: the `jnp` form and the kernel pair under the interpreter. In
+    float32 both sides differ by the order of their sums; in bfloat16 (the
+    branch the chip runs: q, k, v in bf16, g and beta in float32 as
+    `GPT._linear_mixer` hands them over) by bf16's rounding of the same
+    inputs."""
+    f32 = jnp.float32
+    wide = dtype == "float32"
+    args, weight = _delta_rule_inputs(0 if wide else 5, dtype, rate, 2, 150,
+                                      2, 4, 16 if wide else 32)
     exact = tuple(a.astype(f32) for a in args)
-
-    def rule(*a):
-        return gated_delta_rule(*a).astype(f32)
-
     with jax.default_matmul_precision("highest" if wide else "default"):
-        out = gated_delta_rule(*args)
-        grads = jax.grad(lambda *a: (rule(*a) * weight).sum(),
-                         argnums=range(5))(*args)
+        out, *grads = _rule_and_grads(impl, args, weight)
     with jax.default_matmul_precision("highest"):
         want = _recurrence(*exact)
         wants = jax.grad(lambda *a: (_recurrence(*a) * weight).sum(),
@@ -310,6 +321,38 @@ def test_chunked_delta_rule_matches_the_recurrence(rate, dtype):
         largest = float(jnp.max(jnp.abs(ref)))
         allowed = 2e-5 * max(1.0, largest) if wide else 0.02 * largest
         assert float(jnp.max(jnp.abs(got.astype(f32) - ref))) <= allowed, name
+
+
+def test_delta_rule_kernels_carry_the_state_across_grid_steps():
+    """At the head width the chip runs (128 keys, 128 values, bf16) on a
+    row of 600: ten chunks, so three grid steps of the chunk axis, the last
+    ragged. The kernels under the interpreter against the `jnp` form: the
+    state the forward carries from step to step, dS the backward carries
+    the other way, and dq, dk summed over a key head's two value heads."""
+    f32 = jnp.float32
+    args, weight = _delta_rule_inputs(7, "bfloat16", 1.0, 1, 600, 1, 2, 128)
+    got = _rule_and_grads("pallas_interpret", args, weight)
+    want = _rule_and_grads("reference", args, weight)
+    for name, a, b in zip("o q k v g beta".split(), got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        largest = float(jnp.max(jnp.abs(b.astype(f32))))
+        assert float(jnp.max(jnp.abs(a.astype(f32) - b.astype(f32)))
+                     ) <= 0.02 * largest, name
+
+
+def test_delta_rule_kernels_refuse_a_width_they_do_not_take():
+    """Asked for by name, the kernels refuse a head width that is no
+    multiple of the 128 lanes; only "auto" falls to the `jnp` form by the
+    shape (here by the backend too), so a measurement that named the
+    kernels never reads the reference instead."""
+    args, _ = _delta_rule_inputs(3, "bfloat16", 1.0, 1, 64, 1, 2, 16)
+    with pytest.raises(ValueError, match="multiples of 128"):
+        gated_delta_rule(*args, impl="pallas")
+    with pytest.raises(ValueError, match="unknown delta rule impl"):
+        gated_delta_rule(*args, impl="mosaic")
+    auto = gated_delta_rule(*args, impl="auto")
+    want = gated_delta_rule(*args, impl="reference")
+    assert jnp.array_equal(auto, want)
 
 
 @pytest.mark.parametrize("seed", [1, 5])
