@@ -6,6 +6,8 @@ mix, a cell or a per-layer metric by adding files and entries:
     workload.config   -> the `file` of the entry of `configs` with that name
     workload.traffic  -> <dir>/traffic/<traffic>.json   for a <dir> in `paths`
     traffic.kind      -> <dir>/loops/<kind>.py          (imported as a module)
+    what a configuration names (its reference, glue and work module)
+                      -> <dir>/<that path>              (imported as a module)
     per-layer metric  -> <dir>/layer_metrics/<name>.py  (loaded by path)
 """
 
@@ -19,6 +21,7 @@ import os
 from typing import Any, Callable, Dict, List, Optional
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+RUNS_DIR = ".bench_runs"    # under the checkout, git-ignored: a run's files
 
 
 class NoResult(Exception):
@@ -85,13 +88,18 @@ def resolve(workload: str, benchmark_file: Optional[str] = None,
         paths=paths, root=root)
 
 
+def module(root: str, paths: List[str], relative: str):
+    """A module of the benchmark by its path under one of `paths`
+    (`reference/gpt2.py`), imported by its dotted name."""
+    path = _find(root, paths, *relative.split("/"))
+    dotted = os.path.relpath(path, root)[:-len(".py")].replace(os.sep, ".")
+    return importlib.import_module(dotted)
+
+
 def loop_module(cell: Cell):
     """The module that runs this cell's `kind` of traffic."""
-    kind = cell.traffic["kind"]
-    path = _find(cell.root, cell.paths, "loops", kind + ".py")
-    package = os.path.relpath(os.path.dirname(path), cell.root)
-    return importlib.import_module(
-        package.replace(os.sep, ".") + "." + kind)
+    return module(cell.root, cell.paths,
+                  "loops/" + cell.traffic["kind"] + ".py")
 
 
 def layer_reader(cell: Cell, metric: str) -> Callable[[Dict[str, Any]],

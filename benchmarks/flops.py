@@ -61,15 +61,23 @@ def flash_attention_work(model: Mapping[str, Any], seq_len: int,
                          sequences: int, act_bytes: int = 2
                          ) -> Dict[str, float]:
     """What causal attention needs in one training step of `sequences` rows
-    on one chip, over all layers: FLOPs (2 matmuls forward, 4 backward, half
-    the square) and the bytes that must cross HBM at least once (forward
-    reads q, k, v and writes o; backward reads q, k, v, o, do and writes dq,
-    dk, dv; `act_bytes` each)."""
-    d, layers = int(model["d_model"]), int(model["n_layers"])
+    on one chip, over the layers that attend, from the configuration's own
+    geometry: the "full" layers of its `layer_pattern` (every layer where it
+    states none), `n_heads` query heads and `n_kv_heads` key and value heads
+    of the head width it states (`d_head`, else d_model / n_heads). FLOPs:
+    2 matmuls forward, 4 backward, half the square. Bytes, what must cross
+    HBM at least once: forward reads q, k, v and writes o; backward reads q,
+    k, v, o, do and writes dq, dk, dv (`act_bytes` each)."""
+    pattern = tuple(model.get("layer_pattern") or ("full",))
+    layers = int(model["n_layers"]) // len(pattern) * pattern.count("full")
+    heads = int(model["n_heads"])
+    kv_heads = int(model.get("n_kv_heads") or heads)
+    width = int(model.get("d_head") or int(model["d_model"]) // heads)
     tokens = sequences * seq_len
     return {
-        "flops": 6.0 * layers * d * seq_len * tokens,
-        "bytes": 12.0 * layers * d * tokens * act_bytes,
+        "flops": 6.0 * layers * heads * width * seq_len * tokens,
+        "bytes": 6.0 * layers * (heads + kv_heads) * width * tokens
+        * act_bytes,
     }
 
 

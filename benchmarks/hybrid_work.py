@@ -3,10 +3,11 @@ delta rule's least operations and bytes, and a reader of the raw trace for
 the scopes a hybrid step has one level inside the ones `program_trace` knows.
 
 Kept with the benchmark beside `flops.py` (one kind of layer, all experts
-here) and `moe_work.py` (the routed experts' matmuls); this file is for a
-model whose `layer_pattern` mixes softmax attention ("full") with Gated
-DeltaNet layers ("linear"), whose head width is its own (`d_head`), and whose
-layers hold a share of their routed experts beside a shared one.
+here) and `moe_work.py` (the routed experts' matmuls); this file is the work
+module (`work.module`, with `work.routing_check`: `held_share_routed`) of a
+configuration whose `layer_pattern` mixes softmax attention ("full") with
+Gated DeltaNet layers ("linear"), whose head width is its own (`d_head`),
+and whose layers hold a share of their routed experts beside a shared one.
 
 Model FLOPs a token, forward + backward, recomputation never counted
 (PERF.md section 2's definition, per kind of layer):
@@ -48,11 +49,10 @@ never raises.
 from __future__ import annotations
 
 import json
-import statistics
 import time
-from typing import Any, Dict, Mapping, Optional, Sequence
+from typing import Any, Dict, List, Mapping, Optional, Sequence
 
-from benchmarks import moe_work, program_trace, trace_reduce
+from benchmarks import program_trace, trace_reduce
 
 GDN_SCOPES = ("gdn_proj", "gdn_conv", "gdn_rule", "gdn_out")
 MOE_SCOPES = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine",
@@ -157,16 +157,56 @@ def delta_rule_work(model: Mapping[str, Any], tokens: int,
 
 # ------------------------------------------------- from the step's reports
 
-def pairs_per_token(run: Mapping[str, Any]) -> Optional[float]:
-    """Median over the window's steps of the (token, expert) pairs routed to
-    held experts over the step's tokens, a mean over the layers."""
-    window = run["window"]
-    records = window.get("step_records") or []
-    tokens = window.get("tokens_per_step")
-    values = [statistics.fmean(r["moe_routed_here"]) / tokens
-              for r in records[window.get("first_window_record", 0):]
-              if r.get("moe_routed_here") and tokens]
-    return statistics.median(values) if values else None
+def held_share_routed(model: Mapping[str, Any], steps, checked, reference,
+                      tokens_per_step: int) -> List[str]:
+    """The routing check of a model whose layers hold a share of their
+    experts: no (token, expert) pair routed to a held expert was dropped.
+    In every step of every report and for every layer, what the held experts
+    were given (`moe_expert_tokens` [layers, held], the grouped matmuls' own
+    group sizes) sums to the router's count of its own choices that fell on
+    them (`moe_routed_here` [layers]); and on the reference rows the
+    system's per-expert counts differ from the reference's by no more than
+    the choices that disagree explain (each moves two counts by one: counts
+    that are not the choices' fail this whatever the precision) and by no
+    more than the configuration's `counts_differ_max`, a limit between what
+    sound runs and a float8 path read."""
+    layers = int(model["n_layers"])
+    held = int(model.get("moe_experts_held") or model["n_experts"])
+    if not steps:
+        return ["no report carried the steps' metrics"]
+    problems = []
+    short = []
+    for s in steps:
+        given, routed = s.get("moe_expert_tokens"), s.get("moe_routed_here")
+        if (not isinstance(given, list) or not isinstance(routed, list)
+                or len(given) != layers or len(routed) != layers
+                or any(not isinstance(g, list) or len(g) != held
+                       for g in given)):
+            return [f"a step reported no [{layers}, {held}] moe_expert_tokens"
+                    f" beside [{layers}] moe_routed_here"]
+        if any(sum(g) != r for g, r in zip(given, routed)):
+            short.append(([sum(g) for g in given], routed))
+    if short:
+        problems.append(
+            f"{len(short)} of {len(steps)} reported steps gave the held "
+            f"experts other than the pairs routed to them: first "
+            f"{short[0][0]} given, {short[0][1]} routed")
+    if "counts_differ" in checked:
+        disagree = round((1.0 - checked["choice_agreement"])
+                         * checked["choices"])
+        if checked["counts_differ"] > 2 * disagree:
+            problems.append(
+                f"per-expert counts differ from the reference's by "
+                f"{checked['counts_differ']}, more than the {disagree} "
+                f"choices that disagree explain")
+        counts_differ_max = reference.get("counts_differ_max")
+        if (counts_differ_max is not None
+                and checked["counts_differ"] > counts_differ_max):
+            problems.append(
+                f"per-expert counts differ from the reference's by "
+                f"{checked['counts_differ']}, over the configuration's "
+                f"{counts_differ_max}")
+    return problems
 
 
 # ----------------------------------------------------- from the raw trace
@@ -214,7 +254,7 @@ def analyse(planes: Sequence[Dict[str, Any]], step_module: str
             continue
         seconds = own / 1e9 / n_steps
         short = trace_reduce.short_name(name)[0]
-        if moe_work.GROUPED_MATMUL.match(short):
+        if program_trace.GROUPED_MATMUL.match(short):
             matmul_s += seconds
             continue
         path = stats.get("tf_op") or ""

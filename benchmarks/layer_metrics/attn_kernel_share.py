@@ -1,16 +1,17 @@
 """Kernels: the Pallas flash-attention calls' share of the step's device
-time, in percent. The calls are the trace's custom calls (the step has no
-other): forward, the forward recomputed under remat, dq and dkv."""
+time, in percent. The calls are the trace's kernels named `flash_*` by
+`pl.pallas_call(name=...)` (`program_trace.kernels_seconds`): forward, the
+forward recomputed under remat, dq and dkv — `flash_fwd_ms + flash_dq_ms +
+flash_dkv_ms` over `step_device_ms`. A step's other kernels (the grouped
+matmuls, the delta rule's pair) have readers of their own."""
 
 import statistics
 
-from benchmarks import trace_reduce
+from benchmarks import program_trace
 
 
 def read(run):
-    trace = run["trace"]
-    if not trace:
+    trace, took = run["trace"], program_trace.kernels_seconds(run, "flash_")
+    if not trace or not took:
         return None
-    step_s = statistics.median(trace["step_device_ms"]) / 1e3
-    return 100.0 * trace_reduce.op_seconds_per_step(
-        trace, trace_reduce.PALLAS_CALLS) / step_s
+    return 100.0 * took / (statistics.median(trace["step_device_ms"]) / 1e3)

@@ -1,19 +1,16 @@
 """Kernels: the flash-attention calls' share of their roofline, in percent —
-the least time the chip could take for causal attention over all layers of
-one step (`flops.flash_attention_work`: the larger of FLOPs over the bf16
-peak and bytes over the HBM peak; at these shapes compute bounds it) over the
-device time the calls took, the forward recomputed under remat included."""
+the least time the chip could take for causal attention over the layers of
+one step that attend (`flops.flash_attention_work`, from the configuration's
+own geometry: the larger of FLOPs over the bf16 peak and bytes over the HBM
+peak; at these shapes compute bounds it) over the device time of the kernels
+named `flash_*`, the forward recomputed under remat included."""
 
-from benchmarks import flops, trace_reduce
+from benchmarks import flops, program_trace
 
 
 def read(run):
-    trace, peaks = run["trace"], run["peaks"]
-    if not trace or not peaks:
-        return None
-    took = trace_reduce.op_seconds_per_step(
-        trace, trace_reduce.PALLAS_CALLS)
-    if took <= 0:
+    took, peaks = program_trace.kernels_seconds(run, "flash_"), run["peaks"]
+    if not took or not peaks:
         return None
     cell = run["cell"]
     work = flops.flash_attention_work(

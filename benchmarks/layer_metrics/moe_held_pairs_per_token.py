@@ -5,11 +5,11 @@ mean over the layers, the median over the window's steps. At uniform routing
 it is top-k x held / routed over (0.625 for 32 of 512 at top-10); the held
 experts' matmuls, gathers and adds grow with it."""
 
-from benchmarks import hybrid_work
+from benchmarks import moe_work
 
 
 def read(run):
     try:
-        return hybrid_work.pairs_per_token(run)
+        return moe_work.pairs_per_token(run["window"])
     except Exception:   # noqa: BLE001 — a reader never raises
         return None

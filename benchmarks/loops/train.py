@@ -11,8 +11,9 @@ through `train.report`; the driver turns the reports into the result line.
 The loop is the yardstick, so it lives here and not in `ray_tpu/`: `step()`
 is enqueued without a per-step `block_until_ready`; the scalar loss of step
 i-2 is fetched after step i is enqueued (run-ahead of two, the device queue
-never drains); the window is closed by one `block_until_ready`; every call
-into a layer of the system sits in a host span (`spans.py`).
+never drains), the step's other metrics with it (outputs of the same
+program); the window is closed by one `block_until_ready`; every call into a
+layer of the system sits in a host span (`spans.py`).
 
 The rate of the steps is a median over the window: the moment each step's
 loss arrives is the moment the step completed, and `tokens_per_s_per_chip`
@@ -21,6 +22,33 @@ is tokens a step over the median time between consecutive completions
 on a quiet machine, seconds on a shared one) moves two readings of a hundred
 and not the median; what such stalls cost the window is the per-layer metric
 `window_idle_share`.
+
+One loop runs every model. What differs between models is named by the
+configuration's file, each a path under the benchmark's directories:
+
+- `reference.module`: the plain reference, `loss_terms(tokens, top, layers,
+  config) -> {"ce", ...}` (`config` being the configuration's file;
+  optionally `counts` [L, E] and `chosen` [L, T, k] where the model routes),
+  and `reference.glue`: `reference_weights(params, mesh, devices) -> (top,
+  layers)`, the program's parameters in the reference's layout. The
+  reference is given one row at a time (a float32 4k x 4k score matrix a
+  head is 1 GB a row). `reference.loss_atol` holds the system's evaluation
+  cross-entropy to the reference's, `reference.choice_agreement_min` the
+  share of (token, expert) choices that agree.
+- `reference.first_loss_halfwidth`: the first step's cross-entropy
+  (`ce_loss` of the step's metrics; `ppl_log` where a model reports no other
+  term) is what a head initialised at 0.02 on unit-RMS inputs gives,
+  `expected_first_loss`, within it.
+- `work.module`: `model_flops_per_token(model, seq_len[, pairs_per_token])`,
+  the pairs being what the steps reported as routed to the experts held here
+  (`moe_work.pairs_per_token`; left out where they report none), and
+  optionally `work.routing_check`, a function of that module,
+  `(model, steps, checked, reference, tokens_per_step) -> [problem, ...]`
+  over every reported step's metrics and the reference check's result.
+
+Saves are a traffic parameter: `ckpt_every` steps (0 = none), through the
+system's checkpoint path, each watched from the driver's side until it is
+durable and the last one read back.
 
 What a loop module must provide (see README.md): `run(cell, *, seed,
 seconds, trace, process_start_wall, rehearsal, say) -> dict`, the result
@@ -37,23 +65,22 @@ import os
 import re
 import shutil
 import statistics
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
 
-from benchmarks import cells, flops, spans as spans_mod, traffic_gen
+from benchmarks import cells, moe_work, spans as spans_mod, traffic_gen
 
-RUNS_DIR = ".bench_runs"        # under the checkout; git-ignored
 WORKER_SAVES = "worker_saves"   # in a run's directory: where the loop writes
 KEEP_TRACE_ENV = "BENCH_KEEP_TRACE_DIR"   # copy the raw trace here (debugging)
 STEP_MODULE = "train_step"      # in the step program's name in the trace
 
-# The loss must start at ln(vocab) (random init) and fall: by step 40 of the
-# warm-up schedule every chip run of PR 23 had fallen by more than 1.5 nats
-# toward the unigram entropy (6.46); 0.5 leaves room and still fails a model
-# that does not learn.
-FIRST_LOSS_ATOL = 0.5
+# The cross-entropy must fall: by step 40 of the warm-up schedule every chip
+# run of PR 23 had fallen by more than 1.5 nats toward the unigram entropy
+# (6.46); 0.5 leaves room and still fails a model that does not learn.
 LOSS_FALL_MIN = 0.5
+HEAD_INIT_STD = 0.02        # `GPT.init`: every matrix, the head among them
 # float32 sum of squares on the device against float64 on the host
 CHECKSUM_RTOL = 1e-3
 
@@ -73,7 +100,29 @@ def median_step_seconds(done) -> Optional[float]:
     return statistics.median(gaps) if gaps else None
 
 
+def expected_first_loss(model: Dict[str, Any]) -> float:
+    """Cross-entropy at initialisation: logits of a head with entries of
+    standard deviation 0.02 on inputs of unit RMS (the final norm's output)
+    are normal with variance 0.02^2 d, and E[logsumexp] - E[logit of the
+    target] = ln V + variance / 2."""
+    return (math.log(model["vocab_size"])
+            + HEAD_INIT_STD ** 2 * model["d_model"] / 2)
+
+
+def first_loss_problems(ce: List[float], model: Dict[str, Any],
+                        halfwidth: float) -> List[str]:
+    """The first step's cross-entropy is what the seeded initialisation
+    gives: a head or an embedding at another scale, or a loss over another
+    slice of the vocabulary, moves it by more than the half-width."""
+    centre = expected_first_loss(model)
+    if ce and not abs(ce[0] - centre) <= halfwidth:
+        return [f"first cross-entropy {ce[0]:.4f} is not within {halfwidth} "
+                f"of ln V + 0.02^2 d / 2 = {centre:.4f}"]
+    return []
+
+
 # ------------------------------------------------------------------ helpers
+
 
 def _key(path) -> str:
     """A pytree key path as `a/b/0/c`, the same for jax's and orbax's
@@ -124,56 +173,55 @@ def _model_config(config: Dict[str, Any]):
 
 # -------------------------------------------------------------- worker side
 
-def _reference_weights(params, mesh, devices):
-    """The program's parameters in the reference's (the checkpoints') layout,
-    one layer at a time on device 0. Glue, not reference: it only reshapes."""
+def _reference_check(cfg, model, state, mesh, devices, tokens, sharding):
+    """The system's evaluation against the plain reference's, on the same
+    parameters and rows, at the run's real width: the cross-entropy, and
+    where the model routes, the (token, expert) choices and the counts."""
     import jax
     import jax.numpy as jnp
-    from jax import lax
-    from jax.sharding import NamedSharding, PartitionSpec
+    import numpy as np
 
-    def layer(blocks, i):
-        w = {k: lax.dynamic_index_in_dim(v, i, 0, keepdims=False)
-             for k, v in blocks.items()}
-        d = w["wq"].shape[0]
-        return {
-            "ln_1.g": w["norm1"], "ln_1.b": w["bias1"],
-            "attn.c_attn.w": jnp.concatenate(
-                [w[k].reshape(d, -1) for k in ("wq", "wk", "wv")], axis=1),
-            "attn.c_proj.w": w["wo"].reshape(-1, d),
-            "ln_2.g": w["norm2"], "ln_2.b": w["bias2"],
-            "mlp.c_fc.w": w["w_up"], "mlp.c_proj.w": w["w_down"],
-        }
+    config = cfg["config"]
+    reference = cells.module(cfg["root"], cfg["paths"],
+                             config["reference"]["module"])
+    glue = cells.module(cfg["root"], cfg["paths"],
+                        config["reference"]["glue"])
 
-    replicated = (NamedSharding(mesh, PartitionSpec())
-                  if mesh is not None else None)
-    take = jax.jit(layer, out_shardings=replicated)
-    n_layers = params["blocks"]["wq"].shape[0]
-    top = jax.device_put(
-        {"wte": params["tok_embed"], "wpe": params["pos_embed"],
-         "ln_f.g": params["norm_f"], "ln_f.b": params["bias_f"]},
-        devices[0])
-    layers = (jax.device_put(take(params["blocks"], jnp.int32(i)),
-                             devices[0]) for i in range(n_layers))
-    return top, layers
-
-
-def _reference_check(model, state, mesh, devices, tokens, sharding):
-    """The system's evaluation loss against the plain reference's, on the
-    same parameters and rows, at the run's real width."""
-    import jax
-    import jax.numpy as jnp
-    from benchmarks.reference import gpt2
-    from ray_tpu.models.training import eval_step_fn
+    def evaluate(params, batch):
+        _, metrics = model.loss(params, batch)
+        if not model.config.n_experts:
+            return metrics, {}
+        _, aux = model.forward_with_aux(params, batch["tokens"])
+        return metrics, {k: aux[k] for k in ("moe_expert_tokens",
+                                             "moe_expert_choice") if k in aux}
 
     tokens = jnp.asarray(tokens, jnp.int32)
     batch = {"tokens": jax.device_put(tokens, sharding)
              if sharding is not None else tokens}
-    system = float(eval_step_fn(model, mesh)(state.params, batch)["ppl_log"])
-    top, layers = _reference_weights(state.params, mesh, devices)
-    ref_loss, _ = gpt2.loss(jax.device_put(tokens, devices[0]), top, layers,
-                            n_head=model.config.n_heads)
-    return system, float(ref_loss)
+    metrics, routing = jax.device_get(jax.jit(evaluate)(state.params, batch))
+    out = {"system_loss": float(metrics.get("ce_loss", metrics["ppl_log"]))}
+
+    top, layers = glue.reference_weights(state.params, mesh, devices)
+    layers = list(layers)
+    rows = [jax.device_get({k: v for k, v in reference.loss_terms(
+        jax.device_put(tokens[i:i + 1], devices[0]), top, layers,
+        config).items() if k != "logits"})
+        for i in range(tokens.shape[0])]
+    out["reference_loss"] = float(np.mean([r["ce"] for r in rows]))
+    if routing and "chosen" in rows[0]:
+        n_experts = routing["moe_expert_tokens"].shape[-1]
+        chosen = np.concatenate([r["chosen"] for r in rows], axis=1)
+
+        def mask(choice):       # [L, T, k] -> [L, T, E]
+            return (choice[..., None] == np.arange(n_experts)).any(-2)
+
+        agree = (mask(routing["moe_expert_choice"]) & mask(chosen)).sum()
+        counts = np.sum([r["counts"] for r in rows], axis=0)
+        out["choice_agreement"] = float(agree) / chosen.size
+        out["counts_differ"] = int(np.abs(
+            counts - routing["moe_expert_tokens"]).sum())
+        out["choices"] = int(chosen.size)
+    return out
 
 
 def train_loop(cfg: Dict[str, Any]) -> None:
@@ -239,9 +287,8 @@ def train_loop(cfg: Dict[str, Any]) -> None:
 
     # ---- the reference check, at the published width, before the window
     sharding = batch_shardings(mesh) if mesh is not None else None
-    system_loss, ref_loss = _reference_check(
-        model, state, mesh, devices, np.asarray(cfg["reference_rows"]),
-        sharding)
+    checked = _reference_check(cfg, model, state, mesh, devices,
+                               np.asarray(cfg["reference_rows"]), sharding)
     t = phase("reference_check_s", t)
 
     # ---- the one step shape: compile (or cache hit), then warm up
@@ -266,8 +313,8 @@ def train_loop(cfg: Dict[str, Any]) -> None:
         for p, leaf in jax.tree_util.tree_flatten_with_path(s)[0]})
 
     steps_done = 0
-    pending: collections.deque = collections.deque()    # (step, loss)
-    losses: List[float] = []
+    pending: collections.deque = collections.deque()    # (step, metrics)
+    records: List[Dict[str, Any]] = []  # every fetched step's metrics
     done: List[tuple] = []      # (segment, step, host clock at its loss)
     segment = 0                 # a save or the window's start opens a new one
     unreported = 0
@@ -280,10 +327,16 @@ def train_loop(cfg: Dict[str, Any]) -> None:
 
     def fetch() -> None:
         """The oldest step in flight: wait for its loss. It arrives when
-        the step's program ends, so that moment is the step's completion."""
-        step, loss = pending.popleft()
-        losses.append(float(loss))
+        the step's program ends, so that moment is the step's completion;
+        the step's other metrics are outputs of the same program."""
+        step, metrics = pending.popleft()
+        loss = float(metrics["loss"])
         done.append((segment, step, time.perf_counter()))
+        rest = jax.device_get({k: v for k, v in metrics.items()
+                               if k != "loss"})
+        records.append({"loss": loss, **{
+            k: float(v) if v.ndim == 0 else v.tolist()
+            for k, v in rest.items()}})
 
     def one_step() -> bool:
         nonlocal state, steps_done, unreported, open_save
@@ -300,7 +353,7 @@ def train_loop(cfg: Dict[str, Any]) -> None:
         with log.span("step_enqueue"):
             state, metrics = compiled(state, batch)
         steps_done += 1
-        pending.append((steps_done, metrics["loss"]))
+        pending.append((steps_done, metrics))
         if len(pending) > in_flight:
             with log.span("loss_fetch"):
                 fetch()
@@ -309,7 +362,7 @@ def train_loop(cfg: Dict[str, Any]) -> None:
             with log.span("report"):
                 train.report({"kind": "losses",
                               "until_step": steps_done - len(pending),
-                              "losses": losses[-unreported:]})
+                              "steps": records[-unreported:]})
             unreported = 0
         return True
 
@@ -413,7 +466,8 @@ def train_loop(cfg: Dict[str, Any]) -> None:
         "goodput_tokens_per_s_per_chip": goodput,
         "compiles_in_window": compiles_in_window,
         "stream_exhausted": exhausted,
-        "losses": losses, "spans": log.summary(w0_ns, w1_ns),
+        "step_records": records, "first_window_record": first_window_row,
+        "spans": log.summary(w0_ns, w1_ns),
         "phases": phases, "state_bytes": state_bytes,
         "program_bytes": program_bytes,
         "memory_analysis": {
@@ -425,7 +479,7 @@ def train_loop(cfg: Dict[str, Any]) -> None:
         "peak_bytes_in_use": [m.get("peak_bytes_in_use") for m in memory],
         "bytes_limit": [m.get("bytes_limit") for m in memory],
         "pallas_custom_calls": hlo.count("tpu_custom_call"),
-        "system_loss": system_loss, "reference_loss": ref_loss,
+        "reference": checked,
         "compile_seconds": compiles[:compiles_before]})
 
     # ---- the traced segment, after the window: trace_steps whole steps
@@ -571,11 +625,13 @@ def run(cell: cells.Cell, *, seed: int, seconds: float, trace: bool,
     from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig
 
     config, traffic = cell.config, cell.traffic
+    work = cells.module(cell.root, cell.paths, config["work"]["module"])
     platform = "cpu" if rehearsal else "tpu"
     peaks_table = cells.load_json(os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         "peaks.json"))
     problems: List[str] = []
+    compared: List[List[Any]] = []      # [what, value, limit]
 
     t0 = time.perf_counter()
     if rehearsal:
@@ -591,7 +647,7 @@ def run(cell: cells.Cell, *, seed: int, seconds: float, trace: bool,
                 f"this machine offers {advertised} TPU chip(s), the cell "
                 f"{cell.name} needs {cell.chips}")
 
-        storage = os.path.join(cell.root, RUNS_DIR, cell.name)
+        storage = os.path.join(cell.root, cells.RUNS_DIR, cell.name)
         shutil.rmtree(storage, ignore_errors=True)
         os.makedirs(storage)
 
@@ -612,7 +668,8 @@ def run(cell: cells.Cell, *, seed: int, seconds: float, trace: bool,
         loop_config = {
             "config": config, "traffic": traffic, "chips": cell.chips,
             "platform": platform, "seed": seed, "seconds": seconds,
-            "trace": trace, "storage": storage,
+            "trace": trace, "storage": storage, "root": cell.root,
+            "paths": cell.paths,
             "reference_rows": reference_rows.tolist()}
         ckpt_every = int(traffic.get("ckpt_every") or 0)
         watcher = None
@@ -662,36 +719,71 @@ def run(cell: cells.Cell, *, seed: int, seconds: float, trace: bool,
             "window_tokens_per_s_per_chip", "goodput_tokens_per_s_per_chip",
             "compiles_in_window", "phases",
             "state_bytes", "program_bytes", "memory_analysis",
-            "peak_bytes_in_use", "pallas_custom_calls", "system_loss",
-            "reference_loss", "compile_seconds", "spans")})
-        losses = window["losses"]
-        vocab = config["model"]["vocab_size"]
+            "peak_bytes_in_use", "pallas_custom_calls", "reference",
+            "compile_seconds", "spans")})
+        records = window["step_records"]
+        model = config["model"]
+        reference = config["reference"]
         if window["compiles_in_window"]:
             problems.append(f"{window['compiles_in_window']} compilation(s) "
                             f"inside the window")
         if window["stream_exhausted"]:
             problems.append("the traffic file's blocks ran out before the "
                             "window closed")
-        bad_losses = sum(1 for x in losses if not math.isfinite(x))
-        if losses and abs(losses[0] - math.log(vocab)) > FIRST_LOSS_ATOL:
-            problems.append(f"first loss {losses[0]:.4f} is not within "
-                            f"{FIRST_LOSS_ATOL} of ln V {math.log(vocab):.4f}")
-        last = statistics.fmean(losses[-10:]) if losses else float("nan")
-        if not losses or not last < losses[0] - LOSS_FALL_MIN:
-            problems.append(f"loss did not fall by {LOSS_FALL_MIN}: first "
-                            f"{losses[:1]}, mean of last ten {last:.4f}")
-        tolerance = config["reference"]["loss_atol"]
-        if not abs(window["system_loss"] - window["reference_loss"]
+        bad_losses = sum(1 for r in records if not math.isfinite(r["loss"]))
+        # the cross-entropy alone, where the model adds other terms
+        ce = [r.get("ce_loss", r["ppl_log"]) for r in records]
+        centre = expected_first_loss(model)
+        problems.extend(first_loss_problems(
+            ce, model, reference["first_loss_halfwidth"]))
+        last = statistics.fmean(ce[-10:]) if ce else float("nan")
+        if not ce or not last < ce[0] - LOSS_FALL_MIN:
+            problems.append(f"cross-entropy did not fall by {LOSS_FALL_MIN}: "
+                            f"first {ce[:1]}, mean of last ten {last:.4f}")
+        checked = window["reference"]
+        tolerance = reference["loss_atol"]
+        if not abs(checked["system_loss"] - checked["reference_loss"]
                    ) <= tolerance:
             problems.append(
-                f"evaluation loss {window['system_loss']:.6f} differs from "
-                f"the reference's {window['reference_loss']:.6f} by more "
-                f"than {tolerance}")
+                f"evaluation cross-entropy {checked['system_loss']:.6f} "
+                f"differs from the reference's "
+                f"{checked['reference_loss']:.6f} by more than {tolerance}")
+        agreement = reference.get("choice_agreement_min")
+        if agreement is not None and not checked.get(
+                "choice_agreement", 0.0) >= agreement:
+            problems.append(
+                f"{checked.get('choice_agreement')} of the (token, expert) "
+                f"choices agree with the reference's, under {agreement}")
+        if config["work"].get("routing_check"):
+            problems.extend(getattr(work, config["work"]["routing_check"])(
+                model, [step for r in reports if r.get("kind") == "losses"
+                        for step in r["steps"]],
+                checked, reference, window["tokens_per_step"]))
         if platform == "tpu" and not window["pallas_custom_calls"]:
             problems.append("no tpu_custom_call in the step: attention did "
                             "not lower to the Pallas kernels")
-        say(kind="losses", first=losses[:3], last_ten_mean=last,
-            n=len(losses), ln_vocab=math.log(vocab),
+        # each number compared, beside its limit (what a model has none of
+        # is left out)
+        compared += [row for row in [
+            ["first cross-entropy less ln V + 0.02^2 d / 2",
+             ce[0] - centre if ce else None,
+             reference["first_loss_halfwidth"]],
+            ["first cross-entropy less the mean of the last ten",
+             ce[0] - last if ce else None, LOSS_FALL_MIN],
+            ["evaluation cross-entropy less the reference's",
+             checked["system_loss"] - checked["reference_loss"], tolerance],
+            ["share of (token, expert) choices that agree",
+             checked.get("choice_agreement"), agreement],
+            ["per-expert counts that differ from the reference's",
+             checked.get("counts_differ"),
+             reference.get("counts_differ_max")]] if row[1] is not None]
+        in_window = records[window["first_window_record"]:]
+        say(kind="losses", first=records[:3], last_ten_ce_mean=last,
+            n=len(records), expected_first_ce=centre,
+            window_medians={
+                k: statistics.median(r[k] for r in in_window)
+                for k in (in_window[0] if in_window else {})
+                if isinstance(in_window[0][k], float)},
             unigram_entropy=traffic_gen.unigram_entropy(traffic["tokens"]))
 
         # ---- the saves
@@ -709,7 +801,11 @@ def run(cell: cells.Cell, *, seed: int, seconds: float, trace: bool,
             say(kind="saves", saves=saves)
             if not [s for s in saves if not s["traced"]]:
                 problems.append("no whole save cycle inside the window")
-            problems.extend(_check_last_checkpoint(reports, watcher, say))
+            read_back_problems, worst = _check_last_checkpoint(
+                reports, watcher, say)
+            problems.extend(read_back_problems)
+            compared.append(["worst relative error of a leaf's sum of "
+                             "squares, read back", worst, CHECKSUM_RTOL])
 
         # ---- metrics
         setup_s = window["window_start_wall"] - process_start_wall
@@ -717,12 +813,18 @@ def run(cell: cells.Cell, *, seed: int, seconds: float, trace: bool,
         end_to_end = {
             "tokens_per_s_per_chip": window["tokens_per_s_per_chip"],
             "setup_s": setup_s}
+        # where the steps report the pairs routed to the experts held here,
+        # the model's work follows them
+        pairs = moe_work.pairs_per_token(window)
+        flops_per_token = work.model_flops_per_token(
+            model, traffic["seq_len"], *(() if pairs is None else (pairs,)))
+        say(kind="model_flops", per_token=flops_per_token,
+            pairs_per_token=pairs)
         run_facts = {
             "cell": {"name": cell.name, "chips": cell.chips,
                      "config": config, "traffic": traffic},
             "peaks": peak, "device": device,
-            "flops_per_token": flops.model_flops_per_token(
-                config["model"], traffic["seq_len"]),
+            "flops_per_token": flops_per_token,
             "worker": worker, "window": window, "spans": window["spans"],
             "saves": timed, "setup_s": setup_s, "end_to_end": end_to_end,
             "trace": (_one(reports, "trace") or {}).get("reduced"),
@@ -762,21 +864,29 @@ def run(cell: cells.Cell, *, seed: int, seconds: float, trace: bool,
         if jax_backend_initialized():
             problems.append("the driver process opened a JAX backend")
             line["correct"] = False
-        say(kind="verdict", problems=problems)
+        say(kind="verdict", problems=problems, compared=compared)
         return line
     finally:
         ray_tpu.shutdown()
-        shutil.rmtree(os.path.join(cell.root, RUNS_DIR, cell.name),
+        shutil.rmtree(os.path.join(cell.root, cells.RUNS_DIR, cell.name),
                       ignore_errors=True)
+        # each number compared beside its limit: the run's last lines on
+        # standard error
+        for what, value, limit in compared:
+            print(f"compared: {what}: {value!r} (limit {limit!r})",
+                  file=sys.stderr)
+        for problem in problems:
+            print(f"not correct: {problem}", file=sys.stderr)
 
 
-def _check_last_checkpoint(reports, watcher, say) -> List[str]:
+def _check_last_checkpoint(reports, watcher, say):
     """The last durable checkpoint, read back on the host: it must carry the
-    step number and the per-leaf checksums taken on the device at the save."""
+    step number and the per-leaf checksums taken on the device at the save.
+    Returns (problems, the worst leaf's relative error)."""
     import ray_tpu
 
     if not watcher.durable:
-        return ["no durable checkpoint to read back"]
+        return ["no durable checkpoint to read back"], None
     index = max(watcher.durable)
     report = next(r for r in reports
                   if r.get("kind") == "save" and r["save"] == index)
@@ -803,4 +913,4 @@ def _check_last_checkpoint(reports, watcher, say) -> List[str]:
     say(kind="read_back", save=index, step=back["step"],
         leaves=len(report["checksums"]), worst_relative_error=worst,
         seconds=time.perf_counter() - t0)
-    return problems
+    return problems, worst

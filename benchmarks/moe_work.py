@@ -1,23 +1,26 @@
 """The sparse-expert block's work and its share of a traced step.
 
-Kept with the benchmark beside `flops.py`: what the expert matmuls need (from
-shapes alone) and a small reader of the raw trace for the four scopes the
-program puts inside `mlp` (`ray_tpu/models/moe.py`): `moe_router` (logits,
-softmax, top-k), `moe_dispatch` (sort, counts, the gather of rows into expert
-order), `moe_experts` (the three grouped matmuls and the SwiGLU product) and
-`moe_combine` (the gather back and the weighted sum over k). `program_trace`
-files all four under `mlp` (its `SCOPES` is closed); this module reads the
-same `tf_op` paths one level further in.
+Kept with the benchmark beside `flops.py`, and the work module of a
+configuration whose layers are of one kind and hold all their experts
+(`work.module`: model FLOPs a token are `flops.py`'s, which counts the top-k
+experts a token is routed to; `work.routing_check`: `every_pair_routed`).
+Here: what the expert matmuls need, from shapes and from the (token, expert)
+pairs the steps reported, and a small reader of the raw trace for the four
+scopes the program puts inside `mlp` (`ray_tpu/models/moe.py`): `moe_router`
+(logits, softmax, top-k), `moe_dispatch` (sort, counts, the gather of rows
+into expert order), `moe_experts` (the three grouped matmuls and the SwiGLU
+product) and `moe_combine` (the gather back and the weighted sum over k).
+`program_trace` files all four under `mlp`; this module reads the same
+`tf_op` paths one level further in.
 
 The grouped matmuls themselves carry no scope: the TPU compiler rewrites
 `jax.lax.ragged_dot` into custom calls named `ragged-dot-none*` (and a small
 `ragged-dot-metadata*` before each group of them) whose `tf_op` is that name
 and not jax's name stack (seen in the first trace of `olmoe-steady`, PR 27).
-They are found by name, as the flash kernels are, and counted with
-`moe_experts`; `program_trace` files them under `unscoped`, so in a cell with
-experts `mlp_share` lacks them and `unscoped_share` holds them. A kernel of
-the repo's own that replaces them gets a `name=` and a line in
-`GROUPED_MATMUL`.
+They are found by name and counted with `moe_experts`; `program_trace` files
+them under `mlp` by the same names (`program_trace.GROUPED_MATMUL`). A
+kernel of the repo's own that replaces them gets a `name=` and is found as
+every Pallas kernel is.
 
 A program without these scopes, or a run without a device trace, reads as
 nothing: every reader returns None and never raises.
@@ -26,42 +29,90 @@ nothing: every reader returns None and never raises.
 from __future__ import annotations
 
 import json
-import re
+import statistics
 import time
-from typing import Any, Dict, Mapping, Optional, Sequence
+from typing import Any, Dict, List, Mapping, Optional, Sequence
 
-from benchmarks import program_trace, trace_reduce
+from benchmarks import flops, program_trace, trace_reduce
 
 SCOPES = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine")
-# the grouped matmuls' device operations, by instruction name: forward, the
-# forward recomputed under remat, and both backward products
-GROUPED_MATMUL = re.compile(r"^ragged-dot")
+
+model_flops_per_token = flops.model_flops_per_token
 
 
-def expert_matmul_work(model: Mapping[str, Any], tokens: int,
+# ------------------------------------------------- from the step's reports
+
+def _window_records(window: Mapping[str, Any]) -> List[Mapping[str, Any]]:
+    records = window.get("step_records") or []
+    return records[window.get("first_window_record", 0):]
+
+
+def pairs_per_step(window: Mapping[str, Any]) -> Optional[float]:
+    """(token, expert) pairs the experts held on this chip were given in one
+    step, over all layers: the median over the window's steps of what the
+    step itself reported — `moe_routed_here` [layers] where the layers hold
+    a share of their experts, else `moe_expert_tokens` (all of them: tokens
+    x top-k x layers). None where the steps report neither."""
+    given = [r.get("moe_routed_here") or r.get("moe_expert_tokens")
+             for r in _window_records(window)]
+    values = [sum(g) for g in given if g]
+    return statistics.median(values) if values else None
+
+
+def pairs_per_token(window: Mapping[str, Any]) -> Optional[float]:
+    """Median over the window's steps of the (token, expert) pairs routed to
+    held experts over the step's tokens, a mean over the layers. None unless
+    the steps report `moe_routed_here` (a share of the experts held)."""
+    tokens = window.get("tokens_per_step")
+    values = [statistics.fmean(r["moe_routed_here"]) / tokens
+              for r in _window_records(window)
+              if r.get("moe_routed_here") and tokens]
+    return statistics.median(values) if values else None
+
+
+def every_pair_routed(model: Mapping[str, Any], steps, checked, reference,
+                      tokens_per_step: int) -> List[str]:
+    """The routing check of a model that holds all its experts: every step
+    of every report sent each of its tokens to top-k experts in every layer
+    (`moe_expert_tokens` sums to tokens x top-k x layers: no token
+    dropped)."""
+    want = tokens_per_step * int(model["moe_top_k"]) * int(model["n_layers"])
+    short = [s for s in steps
+             if sum(s.get("moe_expert_tokens", ())) != want]
+    if not steps:
+        return ["no report carried the steps' metrics"]
+    if short:
+        return [f"{len(short)} of {len(steps)} reported steps routed other "
+                f"than {want} (token, expert) pairs: first "
+                f"{sum(short[0].get('moe_expert_tokens', ()))}"]
+    return []
+
+
+# ------------------------------------------------------------ from shapes
+
+def expert_matmul_work(model: Mapping[str, Any], pairs: float,
                        act_bytes: int = 2) -> Dict[str, float]:
-    """What the routed experts' matmuls need in one training step of
-    `tokens` tokens on one chip, over all layers. FLOPs: three matmuls a
-    SwiGLU expert, each token through top-k experts, 2 FLOPs a multiply-add,
-    forward once and backward twice (recomputation never counted):
-    18 x tokens x k x d x f a layer. Bytes, the least that must cross HBM:
-    forward reads the rows once for up and gate, writes up and gate, reads
-    the product and writes the output; backward reads each matmul's output
-    cotangent and saved input and writes its input cotangent; every expert's
-    three matrices are read forward and backward and their gradients written
-    once (`act_bytes` each: the program multiplies bf16 copies and the
-    matmuls hand back bf16 gradients)."""
+    """What the routed experts' matmuls need in one training step in which
+    the experts held on this chip are given `pairs` (token, expert) pairs,
+    over all layers (tokens x top-k x layers where every expert is held).
+    FLOPs: three matmuls a SwiGLU expert, 2 FLOPs a multiply-add, forward
+    once and backward twice (recomputation never counted): 18 x d x f a
+    pair. Bytes, the least that must cross HBM: forward reads the rows once
+    for up and gate, writes up and gate, reads the product and writes the
+    output; backward reads each matmul's output cotangent and saved input
+    and writes its input cotangent; every held expert's three matrices are
+    read forward and backward and their gradients written once (`act_bytes`
+    each: the program multiplies bf16 copies and the matmuls hand back bf16
+    gradients)."""
     d, f = int(model["d_model"]), int(model["d_ff"])
-    layers, k = int(model["n_layers"]), int(model["moe_top_k"])
-    experts = int(model["n_experts"])
-    rows_d, rows_f = tokens * k * d, tokens * k * f
+    held = int(model.get("moe_experts_held") or model["n_experts"])
+    rows_d, rows_f = pairs * d, pairs * f
     forward = 2 * rows_d + 3 * rows_f
     backward = 3 * rows_d + 4 * rows_f
-    weights = 3 * experts * d * f
+    weights = 3 * int(model["n_layers"]) * held * d * f
     return {
-        "flops": 18.0 * layers * tokens * k * d * f,
-        "bytes": float(layers) * act_bytes * (forward + backward
-                                              + 3 * weights),
+        "flops": 18.0 * pairs * d * f,
+        "bytes": float(act_bytes) * (forward + backward + 3 * weights),
     }
 
 
@@ -110,7 +161,7 @@ def analyse(planes: Sequence[Dict[str, Any]], step_module: str
             continue
         seconds = own / 1e9 / n_steps
         short = trace_reduce.short_name(name)[0]
-        if GROUPED_MATMUL.match(short):
+        if program_trace.GROUPED_MATMUL.match(short):
             matmul_s += seconds
             matmul_ops[short] = matmul_ops.get(short, 0.0) + seconds
             continue

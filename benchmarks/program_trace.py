@@ -21,6 +21,11 @@ gaps labelled by the benchmark's `bench:` spans. This module reads what
   `recompute_share` under-reads by about that much. `jax.profiler.
   ProfileData` does not hand out metadata stats, so the file is read with a
   small protobuf wire reader (`read_xspace`): no jax, no backend.
+  A Pallas kernel is whatever `pl.pallas_call(name=...)` named it: its path
+  ends `<name>/pallas_call:`, and the kernels of a trace are the names it
+  holds. The TPU compiler's own grouped matmuls (`ragged-dot*`, what
+  `lax.ragged_dot` becomes) carry that name in place of a path; they are
+  filed under `mlp`, the only scope that issues them.
 - every host thread's `rtpu:<span>` annotations (`util/tracing.start_span`),
   on the profiler's clock: idle device time is summed by the innermost open
   span of each thread.
@@ -44,20 +49,21 @@ import time
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 from benchmarks import cells, trace_reduce
-from benchmarks.loops.train import RUNS_DIR     # where a run keeps its trace
 
 SCOPES = ("embed", "attn_qkv", "attn_kernel", "attn_out", "mlp",
           "head_loss", "optimizer")
 UNSCOPED = "unscoped"
 PASSES = ("forward", "recompute", "backward", "other")
-KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+# the grouped matmuls (forward, recomputed, both backward products): device
+# operations the compiler names itself and strips of their path
+GROUPED_MATMUL = re.compile(r"^ragged-dot")
 SPAN_PREFIX = "rtpu:"
 D2H_EVENT = "np.asarray(jax.Array)"
 COMPLETION_EVENT = "CompleteCallbacks"     # the runtime's, with a `run_id`
 SAVE_SPAN = SPAN_PREFIX + "checkpoint::orbax_save"
 
 _TOKEN = re.compile(r"[A-Za-z_][A-Za-z0-9_]*")
-_KERNEL = re.compile(r"\b(flash_bwd_dkv|flash_bwd_dq|flash_fwd)\b")
+_KERNEL = re.compile(r"([A-Za-z_][A-Za-z0-9_]*)/pallas_call:?$")
 _PROGRAM_ID = re.compile(r"\((\d+)\)\s*$")
 
 
@@ -230,8 +236,9 @@ def pass_of(path: str) -> str:
     return "other"
 
 
-def kernel_of(name: str, path: str) -> Optional[str]:
-    found = _KERNEL.search(name) or _KERNEL.search(path or "")
+def kernel_of(path: str) -> Optional[str]:
+    """The `name=` of the `pl.pallas_call` a device operation is, if one."""
+    found = _KERNEL.search(path or "")
     return found.group(1) if found else None
 
 
@@ -325,10 +332,10 @@ def analyse(planes: List[Dict[str, Any]], step_module: str
     if program_id not in {e[3].get("program_id") for e in ops}:
         program_id = None
 
-    # ---- device seconds a step, by scope x pass; the three kernels
+    # ---- device seconds a step, by scope x pass; the kernels, by name
     by_scope = {scope: dict.fromkeys(PASSES, 0.0)
                 for scope in SCOPES + (UNSCOPED,)}
-    kernels = dict.fromkeys(KERNELS, 0.0)
+    kernels: Dict[str, float] = {}
     unscoped: Dict[str, float] = {}
     other_programs = scoped_ops = 0
     for (name, start, _, stats), own in trace_reduce.self_times(ops):
@@ -339,16 +346,16 @@ def analyse(planes: List[Dict[str, Any]], step_module: str
             other_programs += 1
             continue
         path = stats.get("tf_op") or ""
-        scope = scope_of(path)
+        short = trace_reduce.short_name(name)[0]
+        scope = "mlp" if GROUPED_MATMUL.match(short) else scope_of(path)
         scoped_ops += scope != UNSCOPED
         by_scope[scope][pass_of(path)] += own / 1e9 / n_steps
-        short = trace_reduce.short_name(name)[0]
         if scope == UNSCOPED:
             key = f"{short} {path.rstrip(':')[-60:]}"
             unscoped[key] = unscoped.get(key, 0.0) + own / 1e9 / n_steps
-        kernel = kernel_of(short, path)
+        kernel = kernel_of(path)
         if kernel:
-            kernels[kernel] += own / 1e9 / n_steps
+            kernels[kernel] = kernels.get(kernel, 0.0) + own / 1e9 / n_steps
     step_s = sum(sum(row.values()) for row in by_scope.values())
 
     # ---- idle device time under the program's host spans, on the
@@ -424,7 +431,7 @@ _cache: Dict[str, Optional[Dict[str, Any]]] = {}
 
 def trace_file(cell_name: str, root: str = cells.ROOT) -> Optional[str]:
     found = sorted(glob.glob(os.path.join(
-        root, RUNS_DIR, cell_name, "trace", "plugins", "profile", "*",
+        root, cells.RUNS_DIR, cell_name, "trace", "plugins", "profile", "*",
         "*.xplane.pb")))
     return found[-1] if found else None
 
@@ -474,3 +481,14 @@ def kernel_ms(run: Dict[str, Any], kernel: str) -> Optional[float]:
     if not trace or not trace["kernels_s_per_step"].get(kernel):
         return None
     return 1e3 * trace["kernels_s_per_step"][kernel]
+
+
+def kernels_seconds(run: Dict[str, Any], prefix: str) -> Optional[float]:
+    """Device seconds a step in the kernels whose name starts with `prefix`
+    (`flash_`: forward, the forward recomputed under remat, dq and dkv);
+    None if the trace holds none."""
+    trace = of_run(run)
+    if not trace:
+        return None
+    return sum(v for k, v in trace["kernels_s_per_step"].items()
+               if k.startswith(prefix)) or None

@@ -103,3 +103,14 @@ def loss(tokens, top: Mapping[str, Any], layers: Iterable[Dict[str, Any]],
     for w in layers:
         x = block(x, w, n_head=n_head)
     return head_loss(x, tokens, top["ln_f.g"], top["ln_f.b"], top["wte"])
+
+
+def loss_terms(tokens, top: Mapping[str, Any],
+               layers: Iterable[Dict[str, Any]],
+               config: Mapping[str, Any]) -> Dict[str, Any]:
+    """What the benchmark's loop asks of every reference (`loops/train.py`):
+    the cross-entropy `ce` and the `logits`, the number of heads from the
+    configuration's file."""
+    ce, logits = loss(tokens, top, layers,
+                      n_head=int(config["model"]["n_heads"]))
+    return {"ce": ce, "logits": logits}

@@ -64,6 +64,16 @@ def test_flash_work_and_roofline():
     tokens = 12 * 1024
     assert work["flops"] == 6 * 24 * 1024 * 1024 * tokens
     assert work["bytes"] == 12 * 24 * 1024 * tokens * 2
+    # the configuration's own geometry: the layers of the pattern that
+    # attend, the head width it states, fewer key and value heads. One
+    # period of Qwen3-Next at 4 x 8192: one layer of 16 x 256, 33.5 ms at
+    # the peak (PERF.md section 3), not n_layers x d_model = 4 x 2048
+    hybrid = cells.load_json(os.path.join(
+        ROOT, "benchmarks/configs/qwen3_next_80b_a3b.json"))["model"]
+    work = flops.flash_attention_work(hybrid, 8192, 4)
+    assert work["flops"] == 6 * 1 * 16 * 256 * 8192 * 4 * 8192
+    assert work["bytes"] == 6 * (16 + 2) * 256 * 4 * 8192 * 2
+    assert round(work["flops"] / 197e12 * 1e3, 1) == 33.5
     peak = cells.load_json(os.path.join(ROOT, "benchmarks/peaks.json"))[
         "TPU v5 lite"]
     line = flops.roofline_seconds(work, peak)
@@ -100,8 +110,7 @@ def test_reference_agrees_with_the_program_at_a_tiny_width():
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax
     import jax.numpy as jnp
-    from benchmarks.loops.train import _reference_weights
-    from benchmarks.reference import gpt2
+    from benchmarks.reference import gpt2, gpt2_glue
     from ray_tpu.models import GPT
     from ray_tpu.models.gpt import GPTConfig
 
@@ -116,10 +125,117 @@ def test_reference_agrees_with_the_program_at_a_tiny_width():
     with jax.default_matmul_precision("highest"):
         logits = model.apply(params, tokens)
         _, metrics = model.loss(params, {"tokens": tokens})
-    top, layers = _reference_weights(params, None, jax.devices())
-    ref_loss, ref_logits = gpt2.loss(tokens, top, layers, n_head=4)
-    assert float(jnp.max(jnp.abs(ref_logits - logits))) < 1e-4
-    assert abs(float(ref_loss) - float(metrics["ppl_log"])) < 1e-5
+    top, layers = gpt2_glue.reference_weights(params, None, jax.devices())
+    terms = gpt2.loss_terms(tokens, top, layers, {"model": {"n_heads": 4}})
+    assert float(jnp.max(jnp.abs(terms["logits"] - logits))) < 1e-4
+    assert abs(float(terms["ce"]) - float(metrics["ppl_log"])) < 1e-5
+
+
+# ---------------------------------------------------- the first-loss check
+
+def test_the_first_loss_is_centred_on_the_head_at_init():
+    from benchmarks.loops.train import expected_first_loss
+    olmoe = cells.load_json(os.path.join(
+        ROOT, "benchmarks/configs/olmoe_1b_7b.json"))["model"]
+    assert round(expected_first_loss(olmoe), 3) == 11.235
+    # GPT-2 XL's: + 0.32 over ln V, GPT-2 medium's + 0.20
+    assert math.isclose(expected_first_loss(
+        {"vocab_size": 50304, "d_model": 1600}), math.log(50304) + 0.32)
+    assert math.isclose(expected_first_loss(
+        {"vocab_size": 50304, "d_model": 1024}), math.log(50304) + 0.2048)
+
+
+def _cut_at_the_cells_width(config_name, vocab_scale=1.0):
+    """The configuration's model cut between embedding and head: the width
+    and the vocabulary, which the first loss depends on, stay. The hybrid's
+    layers go down to toy sizes (its final norm hands the untied head rows of
+    unit RMS whatever they do); GPT-2 keeps two whole layers at its own
+    widths, because its head is its embedding and what the blocks add to the
+    residual stream decides how much of a token's own embedding the head
+    still sees."""
+    import jax.numpy as jnp
+    config = cells.load_json(os.path.join(
+        ROOT, "benchmarks/configs", config_name + ".json"))
+    model = dict(config["model"], max_seq_len=512, dtype=jnp.float32,
+                 param_dtype=jnp.float32, remat=False,
+                 attention_impl="reference")
+    model["vocab_size"] = int(model["vocab_size"] * vocab_scale)
+    if "layer_pattern" in model:
+        model.update(n_heads=2, n_kv_heads=1, d_head=16, d_ff=16,
+                     linear_key_heads=2, linear_value_heads=2,
+                     linear_key_dim=16, linear_value_dim=16, n_experts=8,
+                     moe_top_k=2, moe_experts_held=8, moe_shared_ff=16)
+    else:
+        model.update(n_layers=2)
+    return config, model
+
+
+# (configuration, cell, fault, the shift it causes from .. to, caught)
+PLANTED = [
+    ("qwen3_next_80b_a3b", "qwen3next-steady", None, 0.0, 0.0, False),
+    ("qwen3_next_80b_a3b", "qwen3next-steady", "head x 1.5", 0.4, 0.6, True),
+    ("qwen3_next_80b_a3b", "qwen3next-steady", "head x 0.5", -0.4, -0.25,
+     True),
+    ("gpt2_xl", "gpt2xl-fsdp4", None, 0.0, 0.0, False),
+    ("gpt2_xl", "gpt2xl-fsdp4", "head x 1.5", 0.3, 0.45, False),
+    ("gpt2_xl", "gpt2xl-fsdp4", "head x 2", 0.8, 1.1, True),
+    ("gpt2_xl", "gpt2xl-fsdp4", "rows x 1.65", 0.45, 0.55, False),
+    ("gpt2_xl", "gpt2xl-fsdp4", "rows x 2.7", 0.95, 1.05, True)]
+
+
+@pytest.mark.parametrize("config_name,workload,fault,low,high,caught",
+                         PLANTED, ids=[f"{c}-{f}" for c, _, f, *_ in PLANTED])
+def test_a_planted_fault_against_the_first_loss_limit(config_name, workload,
+                                                      fault, low, high,
+                                                      caught):
+    """The faults `first_loss_halfwidth` is there for, planted at the cell's
+    width and vocabulary on the cell's own Zipf rows, forward only on the
+    CPU: a head (for GPT-2 the tied embedding) whose entries have standard
+    deviation 0.03, 0.04 or 0.01 where the seeded one has 0.02, which moves
+    the first cross-entropy by about (std^2 - 0.02^2) d / 2; and a loss over
+    1.65 or 2.7 times the vocabulary's rows, which moves it by the
+    logarithm. Each case holds the shift the fault causes, against the sound
+    model of the same seed, and whether the configuration's half-width
+    catches it at this seed, whose sound reading lies within 0.05 of the
+    centre. Qwen3-Next's 0.25 catches a head at 0.03. GPT-2 XL's 0.5 does
+    not, nor 1.65 times the rows (kind `train`'s old limit, 0.5 around ln V,
+    did, and refused sound seeds: PERF.md section 6, PR 34): its first
+    cross-entropy strays from the centre with a standard deviation of 0.11
+    from seed to seed, and the half-width is four of those; it catches a
+    head at 0.04 and 2.7 times the rows."""
+    import jax
+    import jax.numpy as jnp
+    from benchmarks.loops.train import (expected_first_loss,
+                                        first_loss_problems)
+    from ray_tpu.models import GPT
+    from ray_tpu.models.gpt import GPTConfig
+
+    cell = cells.resolve(workload)
+    rows = traffic_gen.packed_rows(cell.traffic, 4, 12)["tokens"]
+    batch = {"tokens": jnp.asarray(rows[:, :512], jnp.int32)}
+    what, _, times = (fault or "nothing x 1").partition(" x ")
+
+    def first_ce(vocab_scale=1.0, head_scale=1.0):
+        config, model = _cut_at_the_cells_width(config_name, vocab_scale)
+        gpt = GPT(GPTConfig(**model))
+        params = jax.jit(gpt.init)(jax.random.PRNGKey(11))
+        head = "lm_head" if "lm_head" in params else "tok_embed"
+        params[head] = params[head] * head_scale
+        _, metrics = jax.jit(gpt.loss)(params, batch)
+        return config, float(metrics.get("ce_loss", metrics["ppl_log"]))
+
+    config, sound = first_ce()
+    assert abs(sound - expected_first_loss(config["model"])) < 0.05, sound
+    _, planted = first_ce(**{"rows": {"vocab_scale": float(times)},
+                             "head": {"head_scale": float(times)},
+                             "nothing": {}}[what])
+    assert low <= planted - sound <= high, planted - sound
+    problems = first_loss_problems(
+        [planted], config["model"],
+        config["reference"]["first_loss_halfwidth"])
+    assert bool(problems) is caught, (sound, planted)
+    if problems:
+        assert "first cross-entropy" in problems[0]
 
 
 # ------------------------------------------------------------ trace reduce
@@ -288,6 +404,13 @@ def test_benchmark_json_meets_the_contract_and_names_files_that_load():
         assert cell.config["chips"] == w["chips"]
         assert os.path.isfile(os.path.join(
             ROOT, "benchmarks/loops", cell.traffic["kind"] + ".py"))
+        # one loop; what differs between models the configuration names
+        assert cell.traffic["kind"] == "train"
+        for named in (cell.config["reference"]["module"],
+                      cell.config["reference"]["glue"],
+                      cell.config["work"]["module"]):
+            assert os.path.isfile(os.path.join(ROOT, "benchmarks", named))
+        assert 0 < cell.config["reference"]["first_loss_halfwidth"] < 0.8
         assert any(m["name"] == "setup_s" for m in cell.end_to_end)
         assert len(cell.end_to_end) >= 2 and cell.per_layer
         for m in cell.per_layer:

@@ -1,5 +1,5 @@
 """The checks of what PR 27 added to the benchmark: the `olmoe_1b_7b`
-configuration, the expert matmuls' work, kind `train_ref` and the four
+configuration, the expert matmuls' work, its routing check and the four
 `moe_*` readers. CPU only, not part of tier-1:
 
     python -m pytest benchmarks/tests/test_olmoe.py -q
@@ -52,7 +52,9 @@ def test_the_configuration_keeps_every_published_width():
     assert model["tie_embeddings"] is published["tie_word_embeddings"]
     cell = cells.resolve("olmoe-steady")
     assert cell.traffic["seq_len"] == model["max_seq_len"]
-    assert cell.traffic["kind"] == "train_ref"
+    assert cell.traffic["kind"] == "train"
+    assert config["work"] == {"module": "moe_work.py",
+                              "routing_check": "every_pair_routed"}
 
 
 def test_model_and_expert_flops_by_hand():
@@ -66,7 +68,9 @@ def test_model_and_expert_flops_by_hand():
     assert flops.model_flops_per_token(model, 4096) == by_hand
     assert round(by_hand / 1e6, 1) == 1071.9
     tokens = 5 * 4096
-    work = moe_work.expert_matmul_work(model, tokens)
+    # every expert is held: tokens x top-k x layers (token, expert) pairs
+    work = moe_work.expert_matmul_work(model, tokens * k * 1)
+    assert moe_work.model_flops_per_token is flops.model_flops_per_token
     assert work["flops"] == 18 * tokens * k * d * f
     assert work["flops"] == 6 * tokens * (k * 3 * d * f)
     rows_d, rows_f, weights = tokens * k * d, tokens * k * f, 3 * e * d * f
@@ -79,12 +83,27 @@ def test_model_and_expert_flops_by_hand():
     assert math.isclose(line["seconds"], work["flops"] / 197e12)
 
 
-def test_the_first_loss_is_centred_on_the_head_at_init():
-    from benchmarks.loops.train_ref import expected_first_loss
-    assert round(expected_first_loss(_config()["model"]), 3) == 11.235
-    # GPT-2 XL's, which PERF.md's section 7 worked out by hand: + 0.32
-    assert math.isclose(expected_first_loss(
-        {"vocab_size": 50304, "d_model": 1600}), math.log(50304) + 0.32)
+def test_a_dropped_token_is_a_problem():
+    model = {"n_layers": 2, "moe_top_k": 2, "n_experts": 4}
+    step = {"moe_expert_tokens": [10, 10, 10, 10]}      # 10 x 2 x 2
+    assert moe_work.every_pair_routed(model, [step, step], {}, {}, 10) == []
+    short = moe_work.every_pair_routed(
+        model, [step, {"moe_expert_tokens": [10, 10, 10, 9]}], {}, {}, 10)
+    assert len(short) == 1 and "1 of 2" in short[0] and "39" in short[0]
+    assert moe_work.every_pair_routed(model, [{"loss": 1.0}], {}, {}, 10)
+    assert moe_work.every_pair_routed(model, [], {}, {}, 10)
+    # the pairs a reader takes its work from are what the steps reported
+    window = {"first_window_record": 1, "tokens_per_step": 10,
+              "step_records": [{"moe_expert_tokens": [99]}, step, step]}
+    assert moe_work.pairs_per_step(window) == 40
+    assert moe_work.pairs_per_token(window) is None
+    window["step_records"] = [{"moe_expert_tokens": [[1, 2], [3, 4]],
+                               "moe_routed_here": [3, 7]}]
+    window["first_window_record"] = 0
+    assert moe_work.pairs_per_step(window) == 10
+    assert moe_work.pairs_per_token(window) == 0.5
+    assert moe_work.pairs_per_step({"step_records": [{"loss": 1.0}]}) is None
+    assert moe_work.pairs_per_step({}) is None
 
 
 def test_moe_scopes_are_read_one_level_inside_mlp():
@@ -115,9 +134,9 @@ def _rehearse(trace):
 
 
 @pytest.mark.parametrize("trace", [0, 1])
-def test_an_olmoe_shaped_cell_runs_through_train_ref(trace):
+def test_an_olmoe_shaped_cell_runs_through_the_one_loop(trace):
     """`olmoe-steady` at a toy width, through the unedited harness and kind
-    `train_ref`, on the CPU: the reference from the configuration, the
+    `train`, on the CPU: the reference from the configuration, the
     centred first-loss check, the routing check, the step's metrics in the
     reports."""
     line, progress = _rehearse(trace)
@@ -166,9 +185,12 @@ def _run(name):
             "peaks": peak, "trace": {"step_module": "train_step"},
             "window": {"first_window_record": 2, "step_records": [
                 {"moe_load_max_over_mean": 9.0}, {"loss": 1.0},
-                {"moe_load_max_over_mean": 1.5},
-                {"moe_load_max_over_mean": 2.5},
-                {"moe_load_max_over_mean": 3.5}]}}
+                {"moe_load_max_over_mean": 1.5,
+                 "moe_expert_tokens": [512, 256, 256]},
+                {"moe_load_max_over_mean": 2.5,
+                 "moe_expert_tokens": [500, 500, 24]},
+                {"moe_load_max_over_mean": 3.5,
+                 "moe_expert_tokens": [1024]}]}}
 
 
 def test_the_moe_readers_on_a_recorded_v5e_trace():
@@ -195,13 +217,12 @@ def test_the_moe_readers_on_a_recorded_v5e_trace():
     assert math.isclose(got["expert_matmul_s_per_step"], sum(ops.values()),
                         rel_tol=1e-9)
     # where `program_trace` files the same operations: the four scopes
-    # inside `mlp`, the grouped matmuls among the unscoped
+    # inside `mlp` by their paths, the grouped matmuls there by their names
     in_scopes = sum(sum(row.values()) for row in table.values())
-    assert in_scopes <= sum(whole["device_s_per_step"]["mlp"].values())
-    assert got["expert_matmul_s_per_step"] <= sum(
-        whole["device_s_per_step"]["unscoped"].values())
-    assert any(k.startswith("ragged-dot")
-               for k in whole["unscoped_top_s_per_step"])
+    assert in_scopes + got["expert_matmul_s_per_step"] <= sum(
+        whole["device_s_per_step"]["mlp"].values()) * (1 + 1e-9)
+    assert not any(k.startswith("ragged-dot")
+                   for k in whole["unscoped_top_s_per_step"])
 
     run = _run("recorded-olmoe")
     moe_work._cache["recorded-olmoe"] = dict(
@@ -219,8 +240,11 @@ def test_the_moe_readers_on_a_recorded_v5e_trace():
         in_scopes - sum(table["moe_experts"].values())) / step)
     # the toy's matmuls are far too small to be near a roofline; the share
     # is model FLOPs at the peak over the time they took, under 100
+    # at the pairs the window's steps reported: 2 x 128 tokens x top-2 x 2
+    # layers, every expert held
     model = run["cell"]["config"]["model"]
-    work = moe_work.expert_matmul_work(model, 2 * 128)
+    assert moe_work.pairs_per_step(run["window"]) == 1024 == 2 * 128 * 2 * 2
+    work = moe_work.expert_matmul_work(model, 1024)
     assert work["flops"] == 18 * 2 * 256 * 2 * 128 * 64
     assert read["moe_gmm_roofline"] == pytest.approx(
         100 * flops.roofline_seconds(work, run["peaks"])["seconds"]

@@ -1,5 +1,5 @@
 """The checks of what PR 32 added to the benchmark: the `qwen3_next_80b_a3b`
-configuration, the hybrid's work file, kind `train_hybrid` and the five
+configuration, the hybrid's work file, its routing check and the five
 readers (`gdn_share`, `gdn_rule_ms`, `gdn_rule_roofline`, `moe_held_share`,
 `moe_held_pairs_per_token`). CPU only, not part of tier-1:
 
@@ -21,7 +21,8 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 sys.path.insert(0, ROOT)
 
-from benchmarks import cells, flops, hybrid_work, program_trace  # noqa: E402
+from benchmarks import (cells, flops, hybrid_work, moe_work,  # noqa: E402
+                        program_trace)
 
 FIXTURES = os.path.join(HERE, "fixtures")
 TINY = os.path.join(FIXTURES, "BENCHMARK.hybrid_tiny.json")
@@ -78,12 +79,23 @@ def test_the_configuration_keeps_every_published_width():
     assert model["moe_first_expert"] == config["first_expert_held"] == 0
     cell = cells.resolve("qwen3next-steady")
     assert cell.traffic["seq_len"] == model["max_seq_len"] == 8192
-    assert cell.traffic["kind"] == "train_hybrid"
+    assert cell.traffic["kind"] == "train"
+    assert config["work"] == {"module": "hybrid_work.py",
+                              "routing_check": "held_share_routed"}
     assert cell.traffic["eot_id"] == cell.traffic["tokens"]["support"] == (
         config["vocab_size"] - 1)
     assert {m["name"] for m in cell.per_layer} >= set(
         NEW_METRICS + SHARED_METRICS)
-    assert "moe_gmm_roofline" not in {m["name"] for m in cell.per_layer}
+    # since PR 34 from the pairs the steps reported, so it reads here too
+    assert "moe_gmm_roofline" in {m["name"] for m in cell.per_layer}
+    work = moe_work.expert_matmul_work(model, 0.46 * 4 * 8192 * 4)
+    assert work["flops"] == 18 * 0.46 * 4 * 8192 * 4 * 2048 * 512
+    # 32 held experts' three matrices a layer, read twice and written once
+    assert work["bytes"] > 2 * 3 * (3 * 4 * 32 * 2048 * 512)
+    peak = cells.load_json(os.path.join(ROOT, "benchmarks/peaks.json"))[
+        "TPU v5 lite"]
+    assert flops.roofline_seconds(work, peak)["bound"] == "compute"
+    assert 5.5e-3 < flops.roofline_seconds(work, peak)["seconds"] < 6.0e-3
 
 
 def test_model_flops_by_hand():
@@ -165,10 +177,10 @@ def _rehearse(trace):
 
 
 @pytest.mark.parametrize("trace", [0, 1])
-def test_a_hybrid_cell_runs_through_train_hybrid(trace):
+def test_a_hybrid_cell_runs_through_the_one_loop(trace):
     """`qwen3next-steady` at a toy width (one period, experts 4..7 of 16
     held, rows of 160: two chunks and a ragged third), through the unedited
-    harness and kind `train_hybrid`, on the CPU: the reference and its glue
+    harness and kind `train`, on the CPU: the reference and its glue
     from the configuration, the share's no-drop check, the step's metrics in
     the reports, model FLOPs from the pairs routed here."""
     line, progress = _rehearse(trace)
@@ -193,9 +205,12 @@ def test_a_hybrid_cell_runs_through_train_hybrid(trace):
     assert [sum(g) for g in given] == routed
     assert {"ce_loss", "moe_aux_loss", "moe_load_max_over_mean"} <= set(first)
     work = next(p for p in progress if p.get("kind") == "model_flops")
-    assert work["uniform_pairs_per_token"] == 0.5
+    cell = cells.resolve("tiny-hybrid", TINY)
+    assert hybrid_work.uniform_pairs_per_token(cell.config["model"]) == 0.5
     assert 0.05 < work["pairs_per_token"] < 2.0
-    assert work["per_token"] == pytest.approx(sum(work["by_part"].values()))
+    assert work["per_token"] == pytest.approx(sum(hybrid_work.flops_by_part(
+        cell.config["model"], cell.traffic["seq_len"],
+        work["pairs_per_token"]).values()))
     if trace:
         # the counter's reader answers; the device readers find no device
         # plane on the CPU and are left out of the line
@@ -211,75 +226,33 @@ def test_a_hybrid_cell_runs_through_train_hybrid(trace):
 
 
 def test_a_dropped_pair_is_a_problem():
-    from benchmarks.loops.train_hybrid import _share_problems
+    def problems_of(steps, checked, counts_differ_max=None):
+        return hybrid_work.held_share_routed(
+            model, steps, checked, {"counts_differ_max": counts_differ_max},
+            100)
+
     model = {"n_layers": 2, "n_experts": 8, "moe_experts_held": 2}
     step = {"moe_expert_tokens": [[3, 4], [0, 9]], "moe_routed_here": [7, 9]}
-    reports = [{"kind": "losses", "steps": [step, dict(step)]}]
+    steps = [step, dict(step)]
     checked = {"choice_agreement": 0.99, "choices": 1000, "counts_differ": 20}
-    assert _share_problems(model, reports, checked) == []
+    assert problems_of(steps, checked) == []
     short = dict(step, moe_expert_tokens=[[3, 4], [0, 8]])
-    problems = _share_problems(
-        model, [{"kind": "losses", "steps": [step, short]}], checked)
+    problems = problems_of([step, short], checked)
     assert len(problems) == 1 and "1 of 2" in problems[0]
     # counts that differ by more than the disagreeing choices explain
-    assert _share_problems(model, reports, dict(checked, counts_differ=21))
+    assert problems_of(steps, dict(checked, counts_differ=21))
     # and by more than the configuration allows: the real file's limit lies
     # between the sound runs' 6,236 and the float8 reference's 51,114
-    assert _share_problems(model, reports, checked, 20) == []
-    over = _share_problems(model, reports, checked, 19)
+    assert problems_of(steps, checked, 20) == []
+    over = problems_of(steps, checked, 19)
     assert len(over) == 1 and "over the configuration's 19" in over[0]
     assert 3 * 6236 > _config()["reference"]["counts_differ_max"] > 6236 * 2
     assert _config()["reference"]["counts_differ_max"] * 2 < 51114
     # a program that reports the counts flat (all experts held) or not at all
-    assert _share_problems(model, [{"kind": "losses", "steps": [
-        {"moe_expert_tokens": [3, 4]}]}], checked)
-    assert _share_problems(model, [], checked)
-
-
-@pytest.mark.parametrize("head_std", [0.02, 0.03, 0.01])
-def test_a_head_at_another_scale_is_outside_the_first_loss_limit(head_std):
-    """The fault `first_loss_halfwidth` exists to catch, planted: the cell's
-    width and vocabulary (what the first loss depends on; the layers between
-    are cut to toy sizes, the final norm hands the head rows of unit RMS
-    whatever they do), the cell's own Zipf rows, and a head whose entries
-    have standard deviation 0.03 or 0.01 where the seeded one has 0.02. That
-    moves the first cross-entropy by 0.03^2 x 2048 / 2 - 0.02^2 x 2048 / 2 =
-    +0.51 or by -0.31: outside the limit, and the first inside the 0.75 the
-    file had before."""
-    import jax
-    import jax.numpy as jnp
-    from benchmarks import traffic_gen
-    from benchmarks.loops.train_hybrid import _first_loss_problems
-    from ray_tpu.models import GPT
-    from ray_tpu.models.gpt import GPTConfig
-
-    config = _config()
-    cell = cells.resolve("qwen3next-steady")
-    model = dict(config["model"], n_heads=2, n_kv_heads=1, d_head=16,
-                 d_ff=16, linear_key_heads=2, linear_value_heads=2,
-                 linear_key_dim=16, linear_value_dim=16, n_experts=8,
-                 moe_top_k=2, moe_experts_held=8, moe_shared_ff=16,
-                 max_seq_len=256, dtype=jnp.float32,
-                 param_dtype=jnp.float32, remat=False,
-                 attention_impl="reference")
-    assert (model["d_model"], model["vocab_size"]) == (2048, 18992)
-    gpt = GPT(GPTConfig(**model))
-    params = jax.jit(gpt.init)(jax.random.PRNGKey(3200000301))
-    params["lm_head"] = params["lm_head"] * (head_std / 0.02)
-    rows = traffic_gen.packed_rows(cell.traffic, 4, 3200000302)["tokens"]
-    _, metrics = jax.jit(gpt.loss)(
-        params, {"tokens": jnp.asarray(rows[:, :257], jnp.int32)})
-    ce = [float(metrics["ce_loss"])]
-    halfwidth = config["reference"]["first_loss_halfwidth"]
-    assert 0.2 <= halfwidth <= 0.3
-    problems = _first_loss_problems(ce, config["model"], halfwidth)
-    if head_std == 0.02:
-        assert problems == [], ce
-    else:
-        assert len(problems) == 1 and "first cross-entropy" in problems[0]
-        assert abs(ce[0] - 10.261) > 0.28
-    if head_std == 0.03:
-        assert _first_loss_problems(ce, config["model"], 0.75) == []
+    assert problems_of([{"moe_expert_tokens": [3, 4]}], checked)
+    assert problems_of([{"moe_expert_tokens": [3, 4],
+                         "moe_routed_here": [3, 4]}], checked)
+    assert problems_of([], checked)
 
 
 def test_a_program_without_the_scopes_reads_as_nothing():

@@ -35,7 +35,6 @@ COLLECTIVE = re.compile(
     r"^%?(all-gather|all-reduce|reduce-scatter|collective-permute|"
     r"all-to-all|collective-broadcast|ragged-all-to-all)")
 MAX_OPS = 400           # rows of the per-operation table handed on
-PALLAS_CALLS = r"^custom-call$"     # opcode of the step's Pallas kernels
 
 Event = Tuple[str, float, float, str]     # name, start ns, duration ns, opcode
 _OPCODE = re.compile(r" ([a-z][a-z0-9_\-]*)\(")
