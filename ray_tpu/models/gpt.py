@@ -42,10 +42,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
-from jax.sharding import Mesh
+from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.attention import FLASH_RESIDUAL_NAMES, dot_product_attention
 from ..ops.delta_rule import gated_delta_rule
+from ..ops.gated_deltanet import gdn_conv, gdn_gated_norm
 from ..ops.ring_attention import ring_attention
 from ..parallel.sharding import (DEFAULT_RULES, ShardingRules,
                                  with_logical_constraint)
@@ -683,10 +684,28 @@ class GPT:
             return x + self._constrain(attn, "act_batch", "act_seq",
                                        "act_embed")
 
+    def _over_rows(self, fn, arrays, weights):
+        """fn(*arrays, *weights) for [B, S, ...] arrays: on a mesh under
+        shard_map, rows over the batch axes and everything else whole on
+        every device (the Gated DeltaNet layer outside its rule is not split
+        over tp: `_block_axes`), for `_delta_rule`'s reason."""
+        if self.mesh is None:
+            return fn(*arrays, *weights)
+        rows = self.rules.spec("act_batch", None, None)
+        return jax.shard_map(
+            fn, mesh=self.mesh,
+            in_specs=(rows,) * len(arrays) + (P(),) * len(weights),
+            out_specs=rows, check_vma=False)(*arrays, *weights)
+
     def _linear_mixer(self, x, w):
-        """Gated DeltaNet on the normed input, residual included: q, k, v
-        from one projection through a short causal convolution and SiLU, the
-        gated delta rule per value head, a gated RMSNorm on its output."""
+        """Gated DeltaNet on the normed input, residual included: the
+        projections (q~ k~ v~ | z | b a), the convolution pass (a short
+        causal convolution, SiLU and the per-head q / k normalisation:
+        `ops.gated_deltanet.gdn_conv`), the gated delta rule per value head,
+        the gated-norm pass (`gdn_gated_norm`) and the out-projection. q, k,
+        v, o and z stay [B, S, H * D] from the projection to the
+        out-projection; `attention_impl` picks the kernels or the `jnp` form
+        of the two passes as it does the rule's."""
         c = self.config
         dt = c.dtype
         f32 = jnp.float32
@@ -696,43 +715,32 @@ class GPT:
         with jax.named_scope("attn_qkv"):
             h = self._norm(x, w["norm1"], w.get("bias1"))
             with jax.named_scope("gdn_proj"):
-                qkvz = jnp.einsum("bsd,de->bse", h, w["w_qkvz"].astype(dt))
+                # q~ k~ v~ and z as two products: each pass then reads and
+                # differentiates an array of its own, where columns of one
+                # would be sliced out and their gradients joined again
+                w_qkvz = w["w_qkvz"].astype(dt)
+                qkv = jnp.einsum("bsd,de->bse", h, w_qkvz[:, :mixed])
+                z = jnp.einsum("bsd,de->bse", h, w_qkvz[:, mixed:])
                 ba = jnp.einsum("bsd,de->bse", h, w["w_ba"].astype(dt),
                                 preferred_element_type=f32)
-                qkv, z = qkvz[..., :mixed], qkvz[..., mixed:]
             with jax.named_scope("gdn_conv"):
-                # out_t = sum_i conv_w[i] * in_{t - taps + 1 + i}, zeros
-                # before the row's start: one shifted product a tap
-                taps = w["conv_w"].astype(dt)
-                s = qkv.shape[1]
-                padded = jnp.pad(qkv, ((0, 0), (taps.shape[0] - 1, 0),
-                                       (0, 0)))
-                qkv = jax.nn.silu(sum(
-                    padded[:, i:i + s] * taps[i]
-                    for i in range(taps.shape[0])))
-                q, k, v = (
-                    part.reshape(*part.shape[:2], heads, -1)
-                    for part, heads in zip(
-                        jnp.split(qkv, (nk * dk, 2 * nk * dk), axis=-1),
-                        (nk, nk, nv)))
-
-                def unit(y, scale):
-                    yf = y.astype(f32)
-                    return (yf * (lax.rsqrt(jnp.sum(yf * yf, -1,
-                                                    keepdims=True) + c.eps)
-                                  * scale)).astype(dt)
-
-                q, k = unit(q, dk ** -0.5), unit(k, 1.0)
+                q, k, v = self._over_rows(
+                    functools.partial(
+                        gdn_conv, key_heads=nk, key_dim=dk, value_dim=dv,
+                        eps=c.eps, impl=c.attention_impl),
+                    (qkv,), (w["conv_w"],))
+                q, k, v = (part.reshape(*part.shape[:2], heads, -1)
+                           for part, heads in ((q, nk), (k, nk), (v, nv)))
                 beta = jax.nn.sigmoid(ba[..., :nv])
                 g = -jnp.exp(w["A_log"].astype(f32)) * jax.nn.softplus(
                     ba[..., nv:] + w["dt_bias"].astype(f32))
         with jax.named_scope("attn_kernel"), jax.named_scope("gdn_rule"):
             o = self._delta_rule(q, k, v, g, beta)
         with jax.named_scope("attn_out"), jax.named_scope("gdn_out"):
-            of = o.astype(f32)
-            of = of * lax.rsqrt(jnp.mean(of * of, -1, keepdims=True) + c.eps)
-            o = (of * w["lin_norm"].astype(f32)).astype(dt)
-            o = o.reshape(*o.shape[:2], -1) * jax.nn.silu(z)
+            o = self._over_rows(
+                functools.partial(gdn_gated_norm, eps=c.eps,
+                                  impl=c.attention_impl),
+                (o.reshape(*o.shape[:2], -1), z), (w["lin_norm"],))
             out = jnp.einsum("bse,ed->bsd", o, w["w_lin_out"].astype(dt))
             return x + self._constrain(out, "act_batch", "act_seq",
                                        "act_embed")

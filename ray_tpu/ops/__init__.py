@@ -3,8 +3,15 @@
 The hot ops of the compute path. Each op ships (a) a pure-jnp reference
 implementation (used on CPU and as the ground truth in tests) and (b) a
 Pallas TPU kernel tuned for MXU/VMEM, selected automatically on TPU
-backends.
+backends: softmax attention (`attention`, `ring_attention`), the gated
+delta rule (`delta_rule`) and the Gated DeltaNet layer's two passes around
+it (`gated_deltanet`: convolution + SiLU + q/k normalisation, and the gated
+RMSNorm), each pair forward and backward.
 """
 
 from .attention import dot_product_attention, flash_attention  # noqa: F401
+from .delta_rule import (gated_delta_rule,  # noqa: F401
+                         gated_delta_rule_reference)
+from .gated_deltanet import (gdn_conv, gdn_conv_reference,  # noqa: F401
+                             gdn_gated_norm, gdn_gated_norm_reference)
 from .ring_attention import ring_attention  # noqa: F401

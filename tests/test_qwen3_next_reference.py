@@ -42,6 +42,7 @@ from benchmarks.reference import qwen3_next, qwen3_next_glue   # noqa: E402
 from ray_tpu.models import GPT                                  # noqa: E402
 from ray_tpu.models.gpt import GPTConfig                        # noqa: E402
 from ray_tpu.models.moe import moe_ffn                          # noqa: E402
+from ray_tpu.ops import gated_deltanet                          # noqa: E402
 from ray_tpu.ops.delta_rule import gated_delta_rule             # noqa: E402
 
 AUX = 0.001
@@ -128,6 +129,10 @@ CASES = {
                                           moe_experts_held=4, seq=150),
     "gqa_group_1": dict(n_heads=2, n_kv_heads=2, linear_key_heads=4,
                         moe_first_expert=12, moe_experts_held=4),
+    # every kernel under the interpreter: the flash kernels, the delta
+    # rule's pair and the Gated DeltaNet layer's two passes around it
+    "through_the_kernels": dict(attention_impl="pallas_interpret",
+                                moe_first_expert=4, moe_experts_held=8),
 }
 
 
@@ -338,6 +343,136 @@ def test_delta_rule_kernels_carry_the_state_across_grid_steps():
         largest = float(jnp.max(jnp.abs(b.astype(f32))))
         assert float(jnp.max(jnp.abs(a.astype(f32) - b.astype(f32)))
                      ) <= 0.02 * largest, name
+
+
+# The Gated DeltaNet layer's passes around the rule (`ops/gated_deltanet.py`),
+# with `_ROWS` set to 64: (row length, key heads, key width, value heads,
+# value width).
+PASSES = {
+    # two blocks and 22 positions of a third; v's columns start at no whole
+    # block of its lanes, so the parts are sliced out and dx joined
+    "two_blocks_and_a_ragged_third": (150, 2, 8, 4, 12),
+    "shorter_than_a_block": (40, 2, 16, 4, 16),
+    # whole blocks at the chip's head width: q, k, v read in place and the
+    # three backward calls write one dx through its aliases
+    "whole_blocks_read_in_place": (128, 1, 128, 2, 128),
+}
+
+
+def _held_to(name, got, want, dtype):
+    """float32: the order of the sums; bfloat16: one rounding of the output
+    (an 8-bit mantissa rounds by at most 2^-9 of the value; sums the
+    kernels keep in float32 are held as float32)."""
+    f32 = jnp.float32
+    assert got.shape == want.shape, name
+    largest = float(jnp.max(jnp.abs(want)))
+    error = jnp.abs(got.astype(f32) - want)
+    if dtype == "float32" or got.dtype == f32:
+        assert got.dtype == f32, name
+        assert float(jnp.max(error)) <= 2e-6 * max(1.0, largest), name
+    else:
+        assert got.dtype == dtype, name
+        assert bool(jnp.all(error <= 2.0 ** -8 * jnp.abs(want)
+                            + 1e-6 * largest)), name
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(PASSES))
+def test_convolution_pass_kernels_match_the_jnp_form(case, dtype,
+                                                     monkeypatch):
+    """`gdn_conv_fwd` / `gdn_conv_bwd` under the interpreter against the
+    `jnp` form in float32 on the same inputs: q, k, v, d qkv and d conv_w.
+    The first taps - 1 positions against a history of zeros and the
+    positions around a block's edge against the positions before it,
+    written out."""
+    monkeypatch.setattr(gated_deltanet, "_ROWS", 64)
+    f32 = jnp.float32
+    s, kh, kd, vh, vd = PASSES[case]
+    keys = jax.random.split(jax.random.PRNGKey(11), 5)
+    x = jax.random.normal(keys[0], (2, s, 2 * kh * kd + vh * vd)).astype(
+        dtype)
+    w = 0.5 * jax.random.normal(keys[1], (4, x.shape[-1]))
+    # cotangents the outputs' dtype holds, so that both sides are given
+    # the same ones
+    weights = [jax.random.normal(key, (2, s, n)).astype(dtype).astype(f32)
+               for key, n in zip(keys[2:], (kh * kd, kh * kd, vh * vd))]
+
+    def run(impl, x):
+        def weighted(x, w):
+            outs = gated_deltanet.gdn_conv(
+                x, w, key_heads=kh, key_dim=kd, value_dim=vd, eps=1e-6,
+                impl=impl)
+            return sum((o.astype(f32) * t).sum()
+                       for o, t in zip(outs, weights)), outs
+
+        (_, outs), grads = jax.value_and_grad(
+            weighted, argnums=(0, 1), has_aux=True)(x, w)
+        return (*outs, *grads)
+
+    got = run("pallas_interpret", x)
+    want = run("reference", x.astype(f32))
+    for name, a, b in zip("q k v dqkv dconv_w".split(), got, want):
+        _held_to(name, a, b, dtype)
+    # v is SiLU of the taps' sum alone
+    xv, wv = x.astype(f32)[..., 2 * kh * kd:], w[:, 2 * kh * kd:]
+    for t in (0, 1, 2, 63, 64, 65, 66):
+        if t >= s:
+            continue
+        pre = sum(wv[i] * xv[:, t - 3 + i] for i in range(4)
+                  if t - 3 + i >= 0)
+        _held_to(f"v at {t}", got[2][:, t], jax.nn.silu(pre), dtype)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(PASSES))
+def test_gated_norm_pass_kernels_match_the_jnp_form(case, dtype,
+                                                    monkeypatch):
+    """`gdn_norm_fwd` / `gdn_norm_bwd` under the interpreter against the
+    `jnp` form in float32 on the same inputs: the result, do, dz and
+    d lin_norm, at a scale that is not its start."""
+    monkeypatch.setattr(gated_deltanet, "_ROWS", 64)
+    f32 = jnp.float32
+    s, _, _, vh, vd = PASSES[case]
+    keys = jax.random.split(jax.random.PRNGKey(12), 4)
+    o, z = (jax.random.normal(key, (2, s, vh * vd)).astype(dtype)
+            for key in keys[:2])
+    scale = 1.0 + 0.3 * jax.random.normal(keys[2], (vd,))
+    weight = jax.random.normal(keys[3], o.shape).astype(dtype).astype(f32)
+
+    def run(impl, o, z):
+        def weighted(o, z, scale):
+            y = gated_deltanet.gdn_gated_norm(o, z, scale, eps=1e-6,
+                                              impl=impl)
+            return (y.astype(f32) * weight).sum(), y
+
+        (_, y), grads = jax.value_and_grad(
+            weighted, argnums=(0, 1, 2), has_aux=True)(o, z, scale)
+        return (y, *grads)
+
+    got = run("pallas_interpret", o, z)
+    want = run("reference", o.astype(f32), z.astype(f32))
+    for name, a, b in zip("y do dz dlin_norm".split(), got, want):
+        _held_to(name, a, b, dtype)
+
+
+def test_the_passes_kernels_refuse_a_width_they_do_not_take():
+    """As the rule's: named, the kernels refuse a head width that is no
+    whole number of 128-lane tiles, and "auto" takes the `jnp` form by the
+    shape (here by the backend too)."""
+    x = jnp.ones((1, 16, 2 * 2 * 16 + 4 * 16), jnp.bfloat16)
+    w = jnp.ones((4, x.shape[-1]))
+    kw = dict(key_heads=2, key_dim=16, value_dim=16, eps=1e-6)
+    with pytest.raises(ValueError, match="multiples of 128"):
+        gated_deltanet.gdn_conv(x, w, impl="pallas", **kw)
+    with pytest.raises(ValueError, match="unknown Gated DeltaNet impl"):
+        gated_deltanet.gdn_conv(x, w, impl="mosaic", **kw)
+    for a, b in zip(gated_deltanet.gdn_conv(x, w, impl="auto", **kw),
+                    gated_deltanet.gdn_conv(x, w, impl="reference", **kw)):
+        assert jnp.array_equal(a, b)
+    o = jnp.ones((1, 16, 64), jnp.bfloat16)
+    with pytest.raises(ValueError, match="multiples of 128"):
+        gated_deltanet.gdn_gated_norm(o, o, jnp.ones((16,)), eps=1e-6,
+                                      impl="pallas")
 
 
 def test_delta_rule_kernels_refuse_a_width_they_do_not_take():
