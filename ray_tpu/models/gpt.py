@@ -763,7 +763,11 @@ class GPT:
                     h, w["router"], w["w_up"], w["w_gate"], w["w_down"],
                     top_k=c.moe_top_k,
                     norm_topk_prob=c.moe_norm_topk_prob,
-                    first_expert=c.moe_first_expert, dtype=dt)
+                    first_expert=c.moe_first_expert, dtype=dt,
+                    # a Mosaic call is not partitioned automatically: on a
+                    # mesh the held experts' rows are summed in `jnp`
+                    impl=(c.attention_impl if self.mesh is None
+                          else "reference"))
                 if c.moe_shared_ff:
                     down = down + shared_expert_ffn(
                         h, w["ws_up"], w["ws_gate"], w["ws_down"],

@@ -20,8 +20,16 @@ contiguous run of the sorted order. That run has no static length, so it is
 walked in chunks (`HELD_CHUNK_SHARE` times the run's length at balanced
 routing) by a loop whose trip count is the run's length: every pair of the
 run is gathered, multiplied and added to its token's row, however many there
-are, and memory is one chunk's whatever the skew. Rows routed elsewhere cost a sort key and nothing more; what the absent
-experts would add is left out.
+are, and memory is one chunk's whatever the skew. Added how: a chunk's rows
+come out of the grouped matmuls sorted by expert; one sort of the chunk's
+token ids and one row gather put them in token order, where a token's rows
+(at most K, its choices being distinct experts) are a run, and the runs are
+summed into their tokens in float32 as they stream past
+(`ops.segment_sum.sorted_segment_sum`: a Pallas kernel on a TPU, a segment
+sum over sorted ids elsewhere) — forward for the weighted results, backward
+for the rows' gradients. There is no row scatter-add: on a TPU one costs
+several row gathers (PERF.md, PRs 32 and 36). Rows routed elsewhere cost a
+sort key and nothing more; what the absent experts would add is left out.
 """
 
 from __future__ import annotations
@@ -32,6 +40,8 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ..ops.segment_sum import sorted_segment_sum
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -80,22 +90,38 @@ def _swiglu_groups(rows, w_up, w_gate, w_down, sizes):
 def _held_chunk(c, x, gate_vals, order, start, counts, rows_per_chunk):
     """Trip `c` of the walk over the run of pairs routed to held experts
     (`counts` of them per held expert, the run starting at `start` of the
-    sorted `order`): the pairs' ids, tokens and routing weights, which of
-    the chunk's rows are real, the rows of x, and how many of the chunk's
-    rows each held expert takes."""
+    sorted `order`, which is followed by a chunk's length of padding so that
+    a chunk is a slice of it wherever it starts): the pairs' ids, tokens and
+    routing weights, which of the chunk's rows are real, the rows of x, and
+    how many of the chunk's rows each held expert takes."""
     with jax.named_scope("moe_dispatch"):
         top_k = gate_vals.shape[-1]
         ends = jnp.cumsum(counts)
         lo = c * rows_per_chunk
-        at = lo + jnp.arange(rows_per_chunk, dtype=jnp.int32)
-        valid = at < ends[-1]
-        pair = order[jnp.minimum(start + at, order.shape[0] - 1)]
+        valid = lo + jnp.arange(rows_per_chunk, dtype=jnp.int32) < ends[-1]
+        pair = lax.dynamic_slice(order, (start + lo,), (rows_per_chunk,))
         token = pair // top_k
         weight = jnp.where(valid, gate_vals.reshape(-1)[pair], 0.0)
         sizes = jnp.clip(jnp.minimum(ends, lo + rows_per_chunk)
                          - jnp.maximum(ends - counts, lo), 0, None)
         return (pair, token, weight, valid, x[token],
                 sizes.astype(jnp.int32))
+
+
+def _sum_into_tokens(onto, c, rows, token, valid, weight=None, *, impl):
+    """onto[t] plus the sum of weight x rows over the chunk's real rows of
+    token t, in float32: trip `c`'s rows added to their tokens' rows. The
+    chunk comes sorted by expert; one sort of its token ids (rows that are
+    not real last) and one row gather put it in token order, and there a
+    token's rows are a run, summed by `ops.segment_sum` as they stream past
+    — where `onto.at[token].add(rows)` is a scatter, which on a TPU costs
+    several such gathers. The first trip does not read `onto`."""
+    ids, at, *weight = lax.sort(
+        (jnp.where(valid, token, onto.shape[0]),
+         jnp.arange(token.shape[0], dtype=jnp.int32),
+         *(() if weight is None else (weight,))), num_keys=1)
+    return sorted_segment_sum(rows[at], ids, onto.shape[0], *weight,
+                              onto=(onto, c > 0), impl=impl)
 
 
 def _held_chunk_rows(pairs: int, share: float) -> int:
@@ -109,9 +135,9 @@ def _n_chunks(counts, rows_per_chunk):
     return (counts.sum() + rows_per_chunk - 1) // rows_per_chunk
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
 def _held_experts(x, gate_vals, w_up, w_gate, w_down, order, start, counts,
-                  rows_per_chunk: int):
+                  rows_per_chunk: int, impl: str):
     """The held experts' part of the block's result. x: [T, D]; gate_vals:
     [T, K] float32; the weights of the H held experts; `order` the (token,
     choice) pairs sorted by expert, `start` where the held experts' run
@@ -127,9 +153,8 @@ def _held_experts(x, gate_vals, w_up, w_gate, w_down, order, start, counts,
             c, x, gate_vals, order, start, counts, rows_per_chunk)
         y = _swiglu_groups(rows, w_up, w_gate, w_down, sizes)
         with jax.named_scope("moe_combine"):
-            y = jnp.where(valid[:, None],
-                          y.astype(jnp.float32) * weight[:, None], 0.0)
-            return out.at[token].add(y), given + sizes
+            return _sum_into_tokens(out, c, y, token, valid, weight,
+                                    impl=impl), given + sizes
 
     return lax.fori_loop(
         0, _n_chunks(counts, rows_per_chunk), trip,
@@ -137,13 +162,13 @@ def _held_experts(x, gate_vals, w_up, w_gate, w_down, order, start, counts,
 
 
 def _held_experts_fwd(x, gate_vals, w_up, w_gate, w_down, order, start,
-                      counts, rows_per_chunk):
+                      counts, rows_per_chunk, impl):
     out = _held_experts(x, gate_vals, w_up, w_gate, w_down, order, start,
-                        counts, rows_per_chunk)
+                        counts, rows_per_chunk, impl)
     return out, (x, gate_vals, w_up, w_gate, w_down, order, start, counts)
 
 
-def _held_experts_bwd(rows_per_chunk, res, cotangents):
+def _held_experts_bwd(rows_per_chunk, impl, res, cotangents):
     x, gate_vals, w_up, w_gate, w_down, order, start, counts = res
     d_out = cotangents[0]
     f32 = jnp.float32
@@ -163,8 +188,7 @@ def _held_experts_bwd(rows_per_chunk, res, cotangents):
                            0.0).astype(y.dtype)
         d_rows, *dw = pull(dy)
         with jax.named_scope("moe_dispatch"):
-            dx = dx.at[token].add(jnp.where(valid[:, None],
-                                            d_rows.astype(f32), 0.0))
+            dx = _sum_into_tokens(dx, c, d_rows, token, valid, impl=impl)
         return dx, d_gate, tuple(a + b.astype(f32)
                                  for a, b in zip(d_weights, dw))
 
@@ -199,8 +223,8 @@ def shared_expert_ffn(x: jax.Array, w_up: jax.Array, w_gate: jax.Array,
 def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
             w_gate: jax.Array, w_down: jax.Array, *,
             top_k: int = 2, norm_topk_prob: bool = True,
-            first_expert: int = 0,
-            dtype=jnp.bfloat16) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+            first_expert: int = 0, dtype=jnp.bfloat16,
+            impl: str = "auto") -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """x: [B, S, D]; router_w: [D, E]; w_up/w_gate: [E, D, F];
     w_down: [E, F, D] → ([B, S, D], aux), aux holding the load-balancing
     loss over all K choices (E * sum_e fraction_e * mean prob_e: K at uniform
@@ -215,6 +239,8 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
     held expert the rows its matmuls were given, for an absent one the
     router's count — and `moe_routed_here` is the router's own count of the
     choices that fell on held experts, which the held entries must sum to.
+    `impl` picks the form of the sum that returns the held experts' rows to
+    token order (`ops.segment_sum`), as it picks the other kernels.
     """
     b, s, d = x.shape
     e = router_w.shape[-1]
@@ -235,7 +261,7 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
     held = w_up.shape[0]
     if held < e:
         return _moe_ffn_held(x, logits, probs, gate_vals, expert_idx, w_up,
-                             w_gate, w_down, first_expert, dtype)
+                             w_gate, w_down, first_expert, dtype, impl)
 
     with jax.named_scope("moe_dispatch"):
         flat_expert = expert_idx.reshape(-1)                    # [T*K]
@@ -266,7 +292,7 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
 
 
 def _moe_ffn_held(x, logits, probs, gate_vals, expert_idx, w_up, w_gate,
-                  w_down, first_expert, dtype):
+                  w_down, first_expert, dtype, impl):
     """`moe_ffn` from the routing on, for a layer that holds experts
     `first_expert` .. `first_expert + H` of the E routed over."""
     b, s, d = x.shape
@@ -275,22 +301,27 @@ def _moe_ffn_held(x, logits, probs, gate_vals, expert_idx, w_up, w_gate,
     here = slice(first_expert, first_expert + held)
 
     with jax.named_scope("moe_dispatch"):
-        flat_expert = expert_idx.reshape(-1)
-        order = jnp.argsort(flat_expert, stable=True).astype(jnp.int32)
+        # the sort hands back its keys with the order: the experts as
+        # sorted, without a gather of T x K elements through the order
+        by_expert, order = lax.sort(
+            (expert_idx.reshape(-1),
+             jnp.arange(n_tokens * top_k, dtype=jnp.int32)),
+            num_keys=1, is_stable=True)
         # where each expert's run begins in the sorted order: the counts
         # without a scatter-add of T x K ones into E bins
-        bounds = jnp.searchsorted(flat_expert[order],
-                                  jnp.arange(e + 1, dtype=flat_expert.dtype)
+        bounds = jnp.searchsorted(by_expert,
+                                  jnp.arange(e + 1, dtype=by_expert.dtype)
                                   ).astype(jnp.int32)
         routed = bounds[1:] - bounds[:-1]
         start = bounds[first_expert]
+        rows_per_chunk = _held_chunk_rows(n_tokens * top_k, held / e)
+        order = jnp.pad(order, (0, rows_per_chunk))
     # the walk gathers (dispatch), multiplies (experts) and adds back
     # (combine) chunk by chunk, each under its scope
     out, given = _held_experts(
         x.reshape(n_tokens, d).astype(dtype), gate_vals,
         w_up.astype(dtype), w_gate.astype(dtype), w_down.astype(dtype),
-        order, start, routed[here], _held_chunk_rows(n_tokens * top_k,
-                                                     held / e))
+        order, start, routed[here], rows_per_chunk, impl)
 
     fraction = routed.astype(jnp.float32) / n_tokens
     in_share = (expert_idx >= first_expert) & (

@@ -6,7 +6,9 @@ Pallas TPU kernel tuned for MXU/VMEM, selected automatically on TPU
 backends: softmax attention (`attention`, `ring_attention`), the gated
 delta rule (`delta_rule`) and the Gated DeltaNet layer's two passes around
 it (`gated_deltanet`: convolution + SiLU + q/k normalisation, and the gated
-RMSNorm), each pair forward and backward.
+RMSNorm), each pair forward and backward; and the sum of rows sorted by
+segment into their segments (`segment_sum`), which returns the held
+experts' rows to token order in `models.moe`.
 """
 
 from .attention import dot_product_attention, flash_attention  # noqa: F401
@@ -15,3 +17,5 @@ from .delta_rule import (gated_delta_rule,  # noqa: F401
 from .gated_deltanet import (gdn_conv, gdn_conv_reference,  # noqa: F401
                              gdn_gated_norm, gdn_gated_norm_reference)
 from .ring_attention import ring_attention  # noqa: F401
+from .segment_sum import (sorted_segment_sum,  # noqa: F401
+                          sorted_segment_sum_reference)

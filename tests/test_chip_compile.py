@@ -390,6 +390,14 @@ def test_qwen3_next_period_train_step_fills_one_chip(v5e):
         ["gdn_norm_bwd"] * 3 + ["gdn_norm_fwd"] * 6)
     text = compiled.as_text()
     assert "while(" in text
+    # the held experts' rows return to token order by `ops.segment_sum`'s
+    # kernel, a call a walk (PR 36): the forward walk and the backward walk
+    # of each of the period's four layers — the forward walk that "full"
+    # remat would make again is dead code, the backward rule makes a
+    # chunk's products itself. The parent's program had a row scatter-add
+    # into f32[32768,2048] in each of those eight places
+    assert _kernel_names(compiled, "moe_segsum") == ["moe_segsum"] * 8
+    assert not re.search(r"= f32\[32768,2048\]\S* scatter\(", text)
     # the compiler makes no room on its own any more (PERF.md, PR 29's
     # lesson), and between a layer's projection and its out-projection no
     # activation is copied, padded, sliced out, joined or transposed
@@ -438,6 +446,9 @@ def test_qwen3_next_period_train_step_compiles_for_the_host(v5e, axes):
     assert _kernel_names(compiled, "gdn_norm_") == (
         ["gdn_norm_bwd"] * 3 + ["gdn_norm_fwd"] * 6)
     assert len(_kernel_calls(compiled, "flash_")) == 4
+    # the held experts' rows are summed in `jnp` on a mesh: no Mosaic call
+    # that the partitioner would have to split (PR 36)
+    assert not _kernel_calls(compiled, "moe_segsum")
     # a chip's share of the rule: [rows / fsdp, 8192, 16 / tp key heads]
     assert (f"bf16[{4 // axes['fsdp']},8192,{2048 // axes.get('tp', 1)}]"
             in compiled.as_text())
