@@ -398,9 +398,9 @@ FIELDS: Dict[str, str] = {
     "telemetry._digest_gen":
         "atomic:generation bump on reset(); handles re-resolve on "
         "mismatch",
-    "telemetry._jax_listener_installed":
-        "atomic:set-once latch; a duplicate listener install is "
-        "idempotent at the jax API",
+    "telemetry._jax_listeners_installed":
+        "atomic:double-checked latch — probed lock-free, set under "
+        "telemetry.runtime once the listeners are registered",
     # --- object store
     "object_store.ObjectStore._entries": "store.entries|static",
     "object_store.ObjectStore._used": "store.entries|static",

@@ -20,8 +20,12 @@ Three layers:
    and synchronously before an export.
 3. sample  — a per-node sampler thread records host stats (RSS, load,
    object-store fill) and JAX device stats (``device.memory_stats()``
-   HBM use/limit, jit compile counts), degrading to a no-op on
-   CPU-only JAX.
+   HBM use/limit), degrading to a no-op on CPU-only JAX.
+
+Besides, ``install_jax_listeners()`` turns what jax reports of its own
+compile path (``jax.monitoring``: every trace, lowering, backend compile
+and cache lookup, with the function's name) into one histogram by stage
+and function and, where tracing is on, ``jax::<stage>`` span rows.
 
 When tracing is enabled, histogram observations carry the current
 ``trace_id`` as an exemplar so slow outliers link back to spans.
@@ -285,7 +289,7 @@ _runtime_lock = locksan.lock("telemetry.runtime")
 _flusher_started = False
 _sampler_started = False
 _last_flush = 0.0
-_jax_listener_installed = False
+_jax_listeners_installed = False
 
 
 def _shard(key: tuple) -> _Shard:
@@ -814,9 +818,25 @@ M_HBM_LIMIT = define(
     "gauge", "rtpu_device_hbm_bytes_limit",
     "Accelerator memory limit per JAX device (sampled; absent on "
     "CPU-only JAX)")
-M_JAX_COMPILES = define(
-    "counter", "rtpu_jax_compiles_total",
-    "JAX compilation events observed in this process")
+M_JAX_COMPILE = define(
+    "histogram", "rtpu_jax_compile_seconds",
+    "Seconds of one stage of jax's compile path for one function, as "
+    "jax.monitoring reports them: stage=trace (Python to jaxpr), lower "
+    "(jaxpr to StableHLO; Pallas bodies to Mosaic) or backend_compile "
+    "(XLA's compile, or the persistent cache's lookup and "
+    "deserialisation); fun=jax's fun_name without its jit(...) wrapper; "
+    "cache=hit|miss|off on backend_compile only (miss: compiled and "
+    "written to the persistent cache; off: no cache, or an executable "
+    "under the cache's thresholds of time and size). Own time: a span's "
+    "duration less the jax spans nested inside it on its thread, so a "
+    "stage's sum over every fun is wall time spent in that stage",
+    buckets=LONG_BUCKETS)
+M_JAX_CACHE_RETRIEVAL = define(
+    "histogram", "rtpu_jax_cache_retrieval_seconds",
+    "Seconds one hit of jax's persistent compilation cache took to read "
+    "and deserialise the executable (jax names no function here; the "
+    "time is inside the backend_compile span that follows)",
+    buckets=LONG_BUCKETS)
 M_WORKER_BACKGROUND = define(
     "histogram", "rtpu_worker_background_seconds",
     "Seconds one activation of a process's periodic background thread "
@@ -950,7 +970,9 @@ def sample_devices() -> int:
     # (or, through a submodule, break that import with a half-made module)
     if jax is None or getattr(jax.__spec__, "_initializing", False):
         return 0
-    _install_jax_compile_listener()
+    # the fallback: a process that loads jax through the worker's
+    # `worker::load_code`, or a train worker, installed them already
+    install_jax_listeners()
     reported = 0
     try:
         if not accelerators.jax_backend_initialized():
@@ -976,23 +998,151 @@ def sample_devices() -> int:
     return reported
 
 
-def _install_jax_compile_listener() -> None:
-    """Count JAX compile events (once per process, only when jax is
-    already imported — telemetry never pulls jax in itself)."""
-    global _jax_listener_installed
-    if _jax_listener_installed or "jax" not in sys.modules:
+# ------------------------------------------------- jax's compile path
+# What jax 0.9 reports through `jax.monitoring`, pinned against the
+# installed jax by tests/test_jax_compile_telemetry.py: the three stages
+# as time spans (wall-clock start and end, `fun_name`), each announced at
+# its start by a scalar of the same name, and the persistent cache's
+# plain events, which fire inside a backend_compile span and carry no name.
+JAX_STAGE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+}
+JAX_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",  # at the entry's write
+}
+JAX_CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+class _JaxThread(threading.local):
+    """One thread's view of jax's compile path: `open` = jax spans begun
+    and not yet reported; `unclaimed` = (start, end) of the reported spans
+    that no enclosing span has claimed yet, in time order and disjoint;
+    `cache` = what the cache has said since the thread's last
+    backend_compile span closed."""
+
+    def __init__(self):
+        self.open = 0
+        self.unclaimed: List[Tuple[float, float]] = []
+        self.cache = "off"
+
+
+_jax_local = _JaxThread()
+
+
+def _jax_fun(fun_name: str) -> str:
+    """jax's name of a function, the same at every stage: tracing reports
+    `train_step`, lowering and compiling the module `jit(train_step)`."""
+    for wrapper in ("jit(", "pmap("):
+        if fun_name.startswith(wrapper) and fun_name.endswith(")"):
+            return fun_name[len(wrapper):-1]
+    return fun_name
+
+
+def _on_jax_span_begin(event: str, value: float, **kw) -> None:
+    if event in JAX_STAGE_EVENTS:
+        _jax_local.open += 1
+
+
+def _on_jax_cache_event(event: str, **kw) -> None:
+    said = JAX_CACHE_EVENTS.get(event)
+    if said is not None:
+        _jax_local.cache = said
+
+
+def _on_jax_cache_retrieval(event: str, duration_secs: float, **kw) -> None:
+    if event == JAX_CACHE_RETRIEVAL_EVENT:
+        hist_observe(M_JAX_CACHE_RETRIEVAL, duration_secs)
+
+
+def _on_jax_span(event: str, start_time: float, end_time: float,
+                 fun_name: str = "", **kw) -> None:
+    """One stage of one function has ended. jax traces a jitted function
+    called inside another inside the outer trace and reports the inner one
+    first, so the series gets each span's own time: its duration less the
+    spans reported since it began, which are its children (they end this
+    thread's list, because unclaimed spans are disjoint and in order)."""
+    stage = JAX_STAGE_EVENTS.get(event)
+    if stage is None:
         return
-    _jax_listener_installed = True
     try:
-        from jax import monitoring
-
-        def _on_event(event: str, **kw) -> None:
-            if "compile" in event:
-                counter_inc(M_JAX_COMPILES)
-
-        monitoring.register_event_listener(_on_event)
-    except Exception:   # noqa: BLE001 — older/newer jax API drift
+        _record_jax_span(stage, start_time, end_time, str(fun_name))
+    except Exception:   # noqa: BLE001 — never break the caller's compile
         pass
+
+
+def _record_jax_span(stage: str, start_time: float, end_time: float,
+                     fun_name: str) -> None:
+    local = _jax_local
+    unclaimed = local.unclaimed
+    own = end_time - start_time
+    while unclaimed and unclaimed[-1][0] >= start_time:
+        child_start, child_end = unclaimed.pop()
+        own -= child_end - child_start
+    # listeners installed inside a span never saw it begin: outermost
+    enclosing = local.open = max(local.open - 1, 0)
+    if enclosing:
+        unclaimed.append((start_time, end_time))
+    else:
+        unclaimed.clear()
+    fun = _jax_fun(fun_name)
+    tags = (("stage", stage), ("fun", fun))
+    attributes = {"fun": fun}
+    if stage == "backend_compile":
+        cache, local.cache = local.cache, "off"
+        tags += (("cache", cache),)
+        attributes["cache"] = cache
+    hist_observe(M_JAX_COMPILE, max(own, 0.0), tags)
+    if not enclosing:
+        from ..util import tracing
+        tracing.record_span("jax::" + stage, start_time, end_time,
+                            attributes)
+
+
+def install_jax_listeners() -> bool:
+    """Register this module's listeners with `jax.monitoring`, once a
+    process; True once they are in. Every trace, lowering and backend
+    compile from then on is an observation of `rtpu_jax_compile_seconds`
+    {stage, fun, cache} and every cache hit one of
+    `rtpu_jax_cache_retrieval_seconds`. With tracing on (or under a
+    force-traced task) each span that no other jax span encloses is also a
+    row `jax::trace` / `jax::lower` / `jax::backend_compile` with jax's own
+    start and end, under the context current on its thread; the nested
+    ones (the hundreds of `add` and `multiply` a step's trace holds) are
+    in their parent's row and in the series. No `rtpu:` profiler
+    annotation: a span is reported after it closed, and XLA's own compile
+    TraceMe is already on the profiler's host line.
+
+    Does nothing unless jax is imported, and whole: telemetry never imports
+    jax itself, never opens a backend, and a thread that touched jax while
+    another is still inside `import jax` would break that import. Call it
+    where jax's work starts (a train worker's `__init__`, the worker's
+    `worker::load_code`); the flusher's `sample_devices` is the fallback
+    for every other process, up to a tick late."""
+    global _jax_listeners_installed
+    if _jax_listeners_installed:
+        return True
+    jax = sys.modules.get("jax")
+    if jax is None or getattr(jax.__spec__, "_initializing", False):
+        return False
+    with _runtime_lock:
+        if _jax_listeners_installed:
+            return True
+        try:    # all four or none: a jax without one registers nothing
+            monitoring = jax.monitoring
+            registrations = (
+                (monitoring.register_scalar_listener, _on_jax_span_begin),
+                (monitoring.register_event_time_span_listener, _on_jax_span),
+                (monitoring.register_event_listener, _on_jax_cache_event),
+                (monitoring.register_event_duration_secs_listener,
+                 _on_jax_cache_retrieval))
+        except AttributeError:      # older/newer jax API drift
+            return False
+        for register, listener in registrations:
+            register(listener)
+        _jax_listeners_installed = True
+    return True
 
 
 # guarded-by plane: wrap the declared module-level registries in

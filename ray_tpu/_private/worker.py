@@ -49,6 +49,25 @@ M_ACTOR_RESTORES = telemetry.define(
     "Restarted actors whose state was replayed from their latest "
     "checkpoint (restore_checkpoint ran before any queued call) "
     "instead of starting empty from __init__")
+M_WORKER_START = telemetry.define(
+    "histogram", "rtpu_worker_start_seconds",
+    "Seconds of one phase of a worker process's start, disjoint, observed "
+    "once a process when its first task arrives: phase=runtime (main() "
+    "entered until REGISTER is sent: the runtime's construction, its "
+    "socket, the imports it makes after main), first_task (REGISTER sent "
+    "until the first EXECUTE_TASK / EXECUTE_BATCH frame arrives: the "
+    "node's side of registration and the dispatch; in a prestarted "
+    "process also its wait for work); chips=the accelerator slots that "
+    "first task holds, as on rtpu_worker_background_seconds",
+    buckets=telemetry.LONG_BUCKETS)
+M_LOAD_CODE = telemetry.define(
+    "histogram", "rtpu_worker_load_code_seconds",
+    "Seconds a worker spent unpickling one actor class or remote function "
+    "(kind=actor_class|function, name=its qualified name), once a process "
+    "for each: the imports its module makes are in it (a train worker's "
+    "ray_tpu.train, jax and orbax; a serve replica's model code), so this "
+    "is where a cold start's load time goes",
+    buckets=telemetry.LONG_BUCKETS)
 
 
 @fieldsan.guarded
@@ -98,12 +117,16 @@ class WorkerRuntime:
         self._ckpt_counter = itertools.count(1)
         self._ckpt_calls = 0
         self._ckpt_last_t = time.monotonic()
+        # time.time() at which REGISTER was sent, until the first task
+        # arrives and `rtpu_worker_start_seconds` is observed
+        self._registered_wall: Optional[float] = None
 
     # ------------------------------------------------------------ main loop
     def run(self) -> None:
         signal.signal(signal.SIGINT, self._on_sigint)
         self.conn.send((P.REGISTER, (P.KIND_WORKER,
                                      self.worker_id.binary(), os.getpid())))
+        self._registered_wall = time.time()
         self._exec_thread.start()
         while True:
             # burst receive: leases the node's writer coalesced enqueue
@@ -112,6 +135,10 @@ class WorkerRuntime:
             if msgs is None:
                 os._exit(0)
             for op, payload in msgs:
+                if (self._registered_wall is not None
+                        and op in (P.EXECUTE_TASK, P.EXECUTE_BATCH)):
+                    self._observe_start(
+                        payload if op == P.EXECUTE_TASK else payload[0])
                 if op == P.EXECUTE_TASK:
                     if not self._maybe_bounce(payload):
                         self._enqueue_execute(payload)
@@ -133,6 +160,20 @@ class WorkerRuntime:
                     os._exit(0)
                 else:
                     self.client.handle_message(op, payload)
+
+    def _observe_start(self, first) -> None:
+        """The first task is here: this process's start, in two phases.
+        ``chips`` (the slots that task holds) tells a process started for
+        a granted actor from the pool's, which wait for work."""
+        arrived = time.time()
+        registered, self._registered_wall = self._registered_wall, None
+        chips = ("chips", str(len(first[1].accel_ids or ())))
+        entered = context.worker_started_wall
+        if entered is not None:
+            telemetry.hist_observe(M_WORKER_START, registered - entered,
+                                   (("phase", "runtime"), chips))
+        telemetry.hist_observe(M_WORKER_START, arrived - registered,
+                               (("phase", "first_task"), chips))
 
     def _maybe_bounce(self, payload) -> bool:
         """Reader-side: a plain-task lease arriving while the exec
@@ -278,7 +319,7 @@ class WorkerRuntime:
         try:
             with span_cm:
                 if kind == "task":
-                    fn = self._get_function(spec.function_id)
+                    fn = self._get_function(spec.function_id, spec.name)
                     args, kwargs = self._load_args(spec, deps)
                     failpoints.fp("worker.task.begin", name=spec.name)
                     result = fn(*args, **kwargs)
@@ -371,7 +412,8 @@ class WorkerRuntime:
 
     def _create_actor(self, actor_spec: P.ActorSpec, spec: P.TaskSpec,
                       deps) -> Any:
-        cls = ser.loads_function(actor_spec.class_blob)
+        cls = self._load_code("actor_class", actor_spec.name,
+                              actor_spec.class_blob)
         args, kwargs = self._load_args(spec, deps)
         self._actor_spec = actor_spec
         context.current_actor_id = actor_spec.actor_id
@@ -485,16 +527,29 @@ class WorkerRuntime:
         telemetry.counter_inc(M_ACTOR_CKPTS)
         return seq
 
-    def _get_function(self, function_id: bytes):
+    def _get_function(self, function_id: bytes, name: str):
         fn = self._functions.get(function_id)
         if fn is None:
             blob = self.client.fetch_function(function_id)
             if blob is None:
                 raise RuntimeError(
                     f"function {function_id.hex()[:12]} not found in KV")
-            fn = ser.loads_function(blob)
+            fn = self._load_code("function", name, blob)
             self._functions[function_id] = fn
         return fn
+
+    @staticmethod
+    def _load_code(kind: str, name: str, blob: bytes):
+        """Unpickle an actor class or a remote function, timed: the load
+        runs the imports of the module that defines it. If that brought in
+        jax, its compile path reports to telemetry from here on — from the
+        thread that has just finished the import, before user code jits."""
+        from ..util import tracing
+        with tracing.timed_span("worker::load_code", M_LOAD_CODE,
+                                (("kind", kind), ("name", name))):
+            code = ser.loads_function(blob)
+        telemetry.install_jax_listeners()
+        return code
 
     def _load_args(self, spec: P.TaskSpec, deps: Dict[ObjectID, ObjectMeta]):
         args = [self._load_one(slot, deps) for slot in spec.args]
@@ -577,9 +632,13 @@ class WorkerRuntime:
         # serve replicas, data blocks, user metrics) ship at task
         # boundaries — rate-limited so a storm of tiny recording tasks
         # pays at most ~5 control-plane frames/s, not one per task; the
-        # background flusher covers the tail
+        # background flusher covers the tail. An actor's creation ships
+        # nothing by itself: what it recorded (the process's start, the
+        # class's load) rides with the first call's flush and leaves that
+        # call its slot under the rate limit
         from . import telemetry
-        telemetry.maybe_flush()
+        if kind != "actor_create":
+            telemetry.maybe_flush()
 
     def _stream_returns(self, spec: P.TaskSpec, kind: str,
                         result: Any) -> None:

@@ -260,8 +260,9 @@ def collect_event_labels(pkg_dir: str, files=None) -> Dict[str, str]:
 
 def collect_span_prefixes(pkg_dir: str, files=None) -> Dict[str, str]:
     """Span-name prefixes (``xxx::``) that open a string constant in the
-    name argument of ``start_span``/``begin_span``/``timed_span`` calls
-    (``"task::" + name`` and ``"train::report"`` alike)."""
+    name argument of ``start_span``/``begin_span``/``timed_span``/
+    ``record_span`` calls (``"task::" + name`` and ``"train::report"``
+    alike)."""
     out: Dict[str, str] = {}
     for rel, tree in (files if files is not None
                       else _walk_files(pkg_dir)):
@@ -271,7 +272,8 @@ def collect_span_prefixes(pkg_dir: str, files=None) -> Dict[str, str]:
             fn = node.func
             name = (fn.attr if isinstance(fn, ast.Attribute)
                     else fn.id if isinstance(fn, ast.Name) else None)
-            if name not in ("start_span", "begin_span", "timed_span"):
+            if name not in ("start_span", "begin_span", "timed_span",
+                            "record_span"):
                 continue
             for sub in ast.walk(node.args[0]):
                 found = (_SPAN_PREFIX_RE.match(sub.value)

@@ -48,8 +48,11 @@ M_GANG_START = telemetry.define(
     "enters its main(): scheduling, process start, the runtime's imports; "
     "0 in a process that was already up), load (until __init__ is "
     "entered: registration, fetching and unpickling the actor class, which "
-    "imports ray_tpu.train and jax), run_wait (until run() is entered: the "
-    "driver splitting its datasets, the loop shipped and unpickled)",
+    "imports ray_tpu.train and jax; in a fresh process it is made up of "
+    "rtpu_worker_start_seconds{phase=runtime} and {phase=first_task}, "
+    "rtpu_worker_load_code_seconds{name=_TrainWorker} and the loading of "
+    "__init__'s arguments), run_wait (until run() is entered: the driver "
+    "splitting its datasets, the loop shipped and unpickled)",
     buckets=telemetry.LONG_BUCKETS)
 M_REPORT_LAG = telemetry.define(
     "histogram", "rtpu_train_report_lag_seconds",
@@ -70,6 +73,9 @@ class _TrainWorker:
     def __init__(self, rank: int, world_size: int, storage_path: str,
                  experiment_name: str, created_wall: float):
         self._entered_wall = time.time()
+        # jax is imported by now (this module's own imports): whatever the
+        # loop traces, lowers and compiles is counted from its first line
+        telemetry.install_jax_listeners()
         self.rank = rank
         self.world_size = world_size
         self.storage_path = storage_path
@@ -167,15 +173,13 @@ class JaxTrainer:
         error: Optional[Exception] = None
 
         while True:
-            with tracing.start_span("train::results_queue"):
-                queue = Queue()
+            queue = Queue()
             gang = self._spawn_gang(name, storage)
             # fresh streaming shards per attempt: the pipeline re-executes
             # from the start on an elastic restart
-            with tracing.start_span("train::streaming_split"):
-                shard_sets = {
-                    ds_name: ds.streaming_split(self._scaling.num_workers)
-                    for ds_name, ds in self._datasets.items()}
+            shard_sets = {
+                ds_name: ds.streaming_split(self._scaling.num_workers)
+                for ds_name, ds in self._datasets.items()}
             try:
                 refs = [w.run.remote(self._loop, self._loop_config, queue,
                                      latest_ckpt.path if latest_ckpt
@@ -242,6 +246,8 @@ class JaxTrainer:
     def _spawn_gang(self, name: str, storage: str) -> dict:
         sc = self._scaling
         bundle = sc.bundle()
+        # the one wait of a gang's start on the driver's side: the queue,
+        # the workers and the shards are submissions, which do not block
         with tracing.start_span("train::pg_ready"):
             pg = placement_group([bundle] * sc.num_workers,
                                  strategy=sc.placement_strategy)
@@ -261,18 +267,17 @@ class JaxTrainer:
             # the phases of `rtpu_train_gang_start_seconds` start here,
             # at the first `.remote()`: the worker observes them
             created_wall = time.time()
-            with tracing.start_span("train::create_workers"):
-                for rank in range(sc.num_workers):
-                    strat = PlacementGroupSchedulingStrategy(
-                        placement_group=pg,
-                        placement_group_bundle_index=rank)
-                    opts = {"scheduling_strategy": strat,
-                            "num_cpus": bundle.get("CPU", 1.0)}
-                    extra = {k: v for k, v in bundle.items() if k != "CPU"}
-                    if extra:
-                        opts["resources"] = extra
-                    workers.append(_TrainWorker.options(**opts).remote(
-                        rank, sc.num_workers, storage, name, created_wall))
+            for rank in range(sc.num_workers):
+                strat = PlacementGroupSchedulingStrategy(
+                    placement_group=pg,
+                    placement_group_bundle_index=rank)
+                opts = {"scheduling_strategy": strat,
+                        "num_cpus": bundle.get("CPU", 1.0)}
+                extra = {k: v for k, v in bundle.items() if k != "CPU"}
+                if extra:
+                    opts["resources"] = extra
+                workers.append(_TrainWorker.options(**opts).remote(
+                    rank, sc.num_workers, storage, name, created_wall))
             return {"pg": pg, "workers": workers}
         except Exception:
             for w in workers:

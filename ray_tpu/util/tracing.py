@@ -176,6 +176,21 @@ def end_span(span: Optional[dict], error: Optional[str] = None) -> None:
     _record(span)
 
 
+def record_span(name: str, start_time: float, end_time: float,
+                attributes: Optional[Dict[str, Any]] = None) -> None:
+    """A row for a span that is already over when it is reported, with
+    its own start and end on ``time.time()``'s clock, as a child of the
+    context current on this thread (jax reports a compile stage after it
+    has closed). Gated as every row is: tracing on, or a force-traced
+    span open on this thread. No profiler annotation, which only a span
+    entered live can be."""
+    if not (enabled() or current_span() is not None):
+        return
+    span = _new_span(name, get_current_context(), attributes)
+    span["start_time"], span["end_time"] = start_time, end_time
+    _record(span)
+
+
 def _record(span: dict) -> None:
     with _buffer_lock:
         _buffer.append(span)

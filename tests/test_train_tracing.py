@@ -29,10 +29,14 @@ WORKER_SPANS = ("train::report", "train::report_checkpoint",
                 "train::report_put", "checkpoint::save",
                 "checkpoint::clear_target", "checkpoint::orbax_save",
                 "data::block_wait", "data::block_get", "data::to_device")
-DRIVER_SPANS = ("train::results_queue", "train::pg_ready",
-                "train::create_workers", "train::streaming_split",
-                "train::drain", "train::persist_checkpoint",
-                "train::prune_checkpoints")
+DRIVER_SPANS = ("train::pg_ready", "train::drain",
+                "train::persist_checkpoint", "train::prune_checkpoints")
+# ISSUE 37 took them out: each timed a submission that does not block
+REMOVED_SPANS = ("train::results_queue", "train::create_workers",
+                 "train::streaming_split")
+REMOVED_SERIES = ("rtpu_jax_compiles_total",)
+JAX_STAGES = ("trace", "lower", "backend_compile")
+FIRST_LINE = "first_line_of_the_loop"
 # child -> parent, as ISSUE 24's table nests them
 NESTING = {"train::report_checkpoint": "train::report",
            "train::report_put": "train::report",
@@ -44,11 +48,18 @@ NESTING = {"train::report_checkpoint": "train::report",
 
 
 def _loop(config):
-    """Two device batches, two pytree checkpoints (the second prunes the
-    first: `num_to_keep=1`), reported with the worker's pid."""
+    """A function jitted on the first line; then two device batches, two
+    pytree checkpoints (the second prunes the first: `num_to_keep=1`),
+    reported with the worker's pid."""
+    import jax
     import jax.numpy as jnp
     from ray_tpu import train
 
+    @jax.jit
+    def first_line_of_the_loop(x):
+        return x + 1
+
+    first_line_of_the_loop(jnp.zeros(3)).block_until_ready()
     batches = train.get_dataset_shard("train").iter_device_batches(
         batch_size=4, dtype=jnp.int32)
     for i, batch in zip(range(2), batches):
@@ -59,13 +70,13 @@ def _loop(config):
                       "rows": int(batch["x"].shape[0])}, checkpoint=ckpt)
 
 
-def _fit(tmp_path):
+def _fit(tmp_path, granted=False):
     dataset = rd.Dataset(block_refs=[
         ray_tpu.put({"x": np.arange(4, dtype=np.int32) + 4 * i})
         for i in range(4)])
     return JaxTrainer(
         _loop, train_loop_config={"dir": str(tmp_path / "saves")},
-        scaling_config=ScalingConfig(num_workers=1),
+        scaling_config=ScalingConfig(num_workers=1, use_tpu=granted),
         datasets={"train": dataset},
         run_config=RunConfig(name="fit", storage_path=str(tmp_path),
                              checkpoint_config=CheckpointConfig(
@@ -82,15 +93,19 @@ def _wait_for(read, wanted, timeout=20.0):
         time.sleep(0.2)
 
 
-def _run(tmp_path, traced):
+def _run(tmp_path, traced, granted=False):
     """One fit in a runtime of its own, and what the control plane holds
-    of it afterwards; the runtime is down again when this returns."""
-    ray_tpu.init(num_cpus=4, _system_config={"tracing_enabled": traced})
+    of it afterwards; the runtime is down again when this returns.
+    `granted`: the worker holds a declared chip (JAX finds the CPU), so it
+    is a process started for it after the driver's first `.remote()`."""
+    ray_tpu.init(num_cpus=4, _system_config={"tracing_enabled": traced},
+                 **({"num_tpus": 1} if granted else {}))
     try:
         tracing.drain()
-        result = _fit(tmp_path)
+        result = _fit(tmp_path, granted)
         wanted = set(WORKER_SPANS + DRIVER_SPANS) | {
-            "worker::sample_devices", "worker::telemetry_flush"}
+            "worker::sample_devices", "worker::telemetry_flush",
+            "worker::load_code", "jax::backend_compile"}
         spans = _wait_for(
             state_api.list_spans,
             lambda rows: not traced
@@ -99,7 +114,8 @@ def _run(tmp_path, traced):
             state_api.summarize_metrics,
             lambda s: s.get("rtpu_train_report_seconds", {}).get("count")
             == 2 and "rtpu_data_feed_batches_total" in s
-            and "rtpu_worker_background_seconds" in s)
+            and "rtpu_worker_background_seconds" in s
+            and "rtpu_jax_compile_seconds" in s)
         return {"result": result, "spans": spans, "summary": summary,
                 "metrics": state_api.list_metrics()}
     finally:
@@ -115,6 +131,12 @@ def traced_fit(tmp_path_factory):
 @pytest.fixture(scope="module")
 def untraced_fit(tmp_path_factory):
     return _run(tmp_path_factory.mktemp("untraced"), traced=False)
+
+
+@pytest.fixture(scope="module")
+def granted_fit(tmp_path_factory):
+    return _run(tmp_path_factory.mktemp("granted"), traced=False,
+                granted=True)
 
 
 @pytest.mark.parametrize("name", WORKER_SPANS + DRIVER_SPANS)
@@ -149,11 +171,96 @@ def test_worker_thread_spans_come_from_the_flusher(traced_fit):
     """`worker::` spans are the telemetry flusher's activations, in the
     train worker as in every process; they sit in no task's trace."""
     mine = [s for s in traced_fit["spans"]
-            if s["name"].startswith("worker::")]
+            if s["name"].startswith("worker::")
+            and s["name"] != "worker::load_code"]
     assert {s["name"] for s in mine} == {"worker::sample_devices",
                                          "worker::telemetry_flush"}
     assert traced_fit["result"].metrics["pid"] in {s["pid"] for s in mine}
     assert all(s["parent_id"] is None for s in mine)
+
+
+@pytest.mark.parametrize("name", REMOVED_SPANS + REMOVED_SERIES)
+def test_what_measured_nothing_is_gone(traced_fit, name):
+    assert name not in {s["name"] for s in traced_fit["spans"]}
+    assert name not in {r["name"] for r in traced_fit["metrics"]}
+    # the README lists a train span by its stage, a series by its name
+    listed = "`" + name.split("::")[-1] + "`"
+    for path, text in (("README.md", listed),
+                       ("ray_tpu/train/trainer.py", name),
+                       ("ray_tpu/_private/telemetry.py", name)):
+        with open(os.path.join(ROOT, path)) as f:
+            assert text not in f.read(), path
+
+
+def _series(fit, series, **tags):
+    return [r for r in fit["metrics"] if r["name"] == series
+            and all(r["tags"].get(k) == v for k, v in tags.items())]
+
+
+@pytest.mark.parametrize("stage", JAX_STAGES)
+def test_a_function_jitted_on_the_loops_first_line_is_in_the_table(
+        untraced_fit, stage):
+    """The listeners are in before the loop's first line (the worker
+    installs them where it loads `_TrainWorker`), not a flusher's tick
+    later; tracing is off, and the series count all the same."""
+    [row] = _series(untraced_fit, "rtpu_jax_compile_seconds", stage=stage,
+                    fun=FIRST_LINE)
+    assert row["count"] == 1 and 0 <= row["sum"] < 60
+    assert ("cache" in row["tags"]) == (stage == "backend_compile")
+
+
+def test_the_compile_of_the_loops_first_line_is_a_row_under_run(traced_fit):
+    spans = traced_fit["spans"]
+    by_id = {s["span_id"]: s for s in spans}
+    for stage in JAX_STAGES:
+        [row] = [s for s in spans if s["name"] == "jax::" + stage
+                 and s["attributes"].get("fun") == FIRST_LINE]
+        assert row["pid"] == traced_fit["result"].metrics["pid"]
+        assert row["end_time"] >= row["start_time"]
+        parent = by_id[row["parent_id"]]
+        assert parent["name"] == "actor_call::_TrainWorker.run"
+        assert parent["start_time"] <= row["start_time"]
+    assert row["attributes"]["cache"] in ("hit", "miss", "off")
+
+
+def test_loading_the_train_worker_is_one_row_in_its_creation(traced_fit):
+    spans = traced_fit["spans"]
+    by_id = {s["span_id"]: s for s in spans}
+    loads = [s for s in spans if s["name"] == "worker::load_code"]
+    # one a class (or function) a process
+    assert len({(s["pid"], by_id[s["parent_id"]]["name"]) for s in loads}) \
+        == len(loads)
+    [mine] = [s for s in loads
+              if s["pid"] == traced_fit["result"].metrics["pid"]]
+    assert by_id[mine["parent_id"]]["name"] == \
+        "actor_create::_TrainWorker.__init__"
+    [row] = _series(traced_fit, "rtpu_worker_load_code_seconds",
+                    kind="actor_class", name="_TrainWorker")
+    assert row["count"] == 1
+    assert row["sum"] == pytest.approx(
+        mine["end_time"] - mine["start_time"], abs=0.05)
+
+
+def test_a_granted_workers_start_divides_the_gangs_load_phase(granted_fit):
+    """A process started for the gang's worker: `main()` to `REGISTER`, to
+    the creation task's arrival, then `_TrainWorker` unpickled — in order,
+    disjoint, inside `rtpu_train_gang_start_seconds{phase=load}`."""
+    assert granted_fit["result"].error is None
+    phases = {r["tags"]["phase"]: r for r in _series(
+        granted_fit, "rtpu_worker_start_seconds", chips="1")}
+    assert set(phases) == {"runtime", "first_task"}
+    assert all(r["count"] == 1 and r["sum"] >= 0 for r in phases.values())
+    [loaded] = _series(granted_fit, "rtpu_worker_load_code_seconds",
+                       kind="actor_class", name="_TrainWorker")
+    [load] = _series(granted_fit, "rtpu_train_gang_start_seconds",
+                     phase="load")
+    assert loaded["count"] == load["count"] == 1
+    parts = sum(r["sum"] for r in phases.values()) + loaded["sum"]
+    assert 0 < parts <= load["sum"]
+    # every other process observed its own start too, once a phase
+    others = _series(granted_fit, "rtpu_worker_start_seconds", chips="0")
+    assert {r["tags"]["phase"]: r["count"] for r in others} == {
+        "runtime": others[0]["count"], "first_task": others[0]["count"]}
 
 
 def test_tracing_off_buffers_no_row_and_the_series_still_count(
