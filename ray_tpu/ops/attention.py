@@ -4,8 +4,11 @@ The reference framework has no attention kernel of its own (it defers to
 torch); on TPU the attention inner loop is the single hottest op of the
 flagship models, so it gets a first-class FlashAttention-2 style Pallas
 kernel: blocked online softmax, GQA-aware block mapping, and a custom VJP
-whose backward is two more Pallas kernels (dq and dk/dv) driven by the
-saved logsumexp.
+whose backward is one more Pallas kernel, `flash_bwd`, driven by the saved
+logsumexp: it makes a rectangle's p and ds once and feeds dq, dk and dv
+from them. Rows whose float32 dq accumulator does not fit in VMEM take a
+kernel pair instead (`flash_bwd_dq`, `flash_bwd_dkv`), which makes every
+rectangle's p and ds twice.
 
 Two layouts, one set of kernel bodies (`_Heads`). Models hand q, k, v over
 as their projections wrote them, [batch, seq, num_heads, head_dim] (a free
@@ -44,8 +47,9 @@ How the kernels spend their time (measured on the v5e, PERF.md PR 26):
   the MXU bf16; p and ds are cast to it), always with float32
   accumulation. Scores, exp, the running maximum and sum, lse, delta and
   every accumulator are float32. float32 inputs run float32 matmuls. The
-  softmax scale is folded into q (forward, dq) or k (dkv) once per block
-  and into the dq / dk accumulators once at the end, not into each score.
+  softmax scale is folded into q (forward, dq) or k (dkv, the fused
+  backward) once per block and into the dq / dk accumulators once at the
+  end, not into each score.
 """
 
 from __future__ import annotations
@@ -68,11 +72,13 @@ DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
 
 # Rectangles of the walk inside a tile, (sub, chunk), by kernel: `sub` is
-# the block that stays put (queries in forward and dq, keys in dkv) and
-# `chunk` what the loop steps over. Swept with the tile.
+# the block that stays put (queries in forward and dq, keys in dkv and in
+# the fused backward kernel) and `chunk` what the loop steps over. Swept
+# with the tile (PERF.md PR 26; the fused kernel's, PR 38).
 _FWD_RECT = (512, 512)
 _DQ_RECT = (512, 512)
 _DKV_RECT = (128, 128)
+_BWD_RECT = (256, 256)
 _ACC_VREGS = 32   # registers (1024 float32) an accumulator may ride a loop in
 
 
@@ -217,7 +223,7 @@ def _edge_mask(k0, q0, shape, rel, q_valid, k_valid):
 
 
 # ---------------------------------------------------------------------------
-# What the three kernels share
+# What the kernels share
 # ---------------------------------------------------------------------------
 
 def _dot_nt(a, b):
@@ -236,6 +242,14 @@ def _dot_tn(a, b):
     """a [n, d] x b [n, m] -> [d, m], float32 accumulation."""
     return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
                                preferred_element_type=jnp.float32)
+
+
+def _span(start, size):
+    """Index of [start, start + size), `start` a multiple of `size`: a slice
+    where it is static, an aligned dynamic slice where it is traced."""
+    if _static(start):
+        return slice(start, start + size)
+    return pl.ds(pl.multiple_of(start, size), size)
 
 
 def _rows(ref, cols, start, size, valid=None):
@@ -710,42 +724,197 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
 
 
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *kt_refs, scale, t,
+                heads, sub, chunk, do_t):
+    """One (k tile, q tile) step of all three gradients, walked as
+    `_bwd_dkv_kernel` walks: each `sub` keys of the k tile walk their live
+    `chunk`s of queries, and a rectangle's p = exp(s - lse) and ds = p *
+    (dp - delta) are made once and feed three products. dk and dv ([sub,
+    head_dim]) ride the walk; dq^T of the rectangle ([head_dim, chunk], from
+    a k^T that is made once a sub-block and kept in VMEM, a scratch a head
+    of the block, the same [head_dim, block_k] array in both layouts: the
+    compiler would remake it a rectangle) is added into the row's float32
+    accumulator, [q tiles, head_dim, block_q] in VMEM, which stays across
+    the row's k tiles. In an unrolled walk that product is issued one
+    rectangle late, after the next rectangle's own four: ds is its
+    stationary operand, and placed right behind the products that make ds
+    it stalls the matrix units (a third of the schedule, PERF.md PR 38).
+    dq's output block is the whole row, a q tile of it written (scaled,
+    one rounding) at the last k tile. Operands, scale folding, lse and
+    delta rows and `do_t` as in the pair."""
+    qb, kb = t.ids(3, 2)
+    q_valid, k_valid = t.valid(qb, kb)
+    n_chunks = t.block_q // chunk
+    dtype = q_ref.dtype
+
+    @_when(kb == 0)
+    def _init_dq():
+        dq_acc[qb] = jnp.zeros(dq_acc.shape[1:], dq_acc.dtype)
+
+    @_when(qb == 0)
+    def _init_dkv():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    def walk(rel, hh):
+        cols = heads.cols(hh)
+        for r0 in range(0, t.block_k, sub):
+            rs = slice(r0, r0 + sub)
+            live_start, interior_start, interior_end, live_end = bounds = \
+                _q_chunk_bounds(r0, sub, rel, *t.span(q_valid, k_valid),
+                                chunk=chunk)
+            late = _static(*bounds)
+            k = _rows(k_ref, cols, r0, sub, k_valid)
+            k_scaled = _scaled(k, scale)
+            kt_refs[hh][:, rs] = k.T
+            v = _rows(v_ref, cols, r0, sub, k_valid)
+
+            def add_dq(dst, c):
+                dq_acc[qb, cols, _span(c * chunk, chunk)] += _dot_nn(
+                    kt_refs[hh][:, rs], dst)
+
+            def step(c, carry, edge):
+                dk, dv, pending = carry
+                pad = q_valid if edge else None
+                q = _rows(q_ref, cols, c * chunk, chunk, pad)
+                if do_t:
+                    do = _rows_t(do_ref, hh, c * chunk, chunk, pad)
+                else:
+                    do = _rows(do_ref, cols, c * chunk, chunk, pad)
+                row = qb * n_chunks + c
+                row = slice(row, row + 1) if _static(row) else pl.ds(row, 1)
+                lse = lse_ref[hh, row, :]
+                delta = delta_ref[hh, row, :]
+                pt = jnp.exp(_dot_nt(k_scaled, q) - lse)
+                mask = _edge_mask(r0, c * chunk, pt.shape, rel, q_valid,
+                                  k_valid) if edge else None
+                if mask is not None:
+                    pt = jnp.where(mask, pt, 0.0)
+                p = pt.astype(dtype)
+                dv = dv + (_dot_nt(p, do) if do_t else _dot_nn(p, do))
+                dp = _dot_nn(v, do) if do_t else _dot_nt(v, do)
+                dst = (pt * (dp - delta)).astype(dtype)
+                dk = dk + _dot_nn(dst, q)
+                if pending is not None:
+                    add_dq(*pending)
+                if late:
+                    return dk, dv, (dst, c)
+                add_dq(dst, c)
+                return dk, dv, None
+
+            # the diagonal's edge chunks come first, a ragged end's last
+            carry = _walk((live_start, live_start, interior_start), step,
+                          (dk_acc[rs, cols], dv_acc[rs, cols], None))
+            dk_acc[rs, cols], dv_acc[rs, cols], pending = _walk(
+                (interior_start, interior_end, live_end), step, carry)
+            if pending is not None:
+                add_dq(*pending)
+
+    heads.each(lambda hh: t.for_each_class(
+        qb, kb, functools.partial(walk, hh=hh)))()
+
+    @_when(kb == t.nk - 1)
+    def _finalize_dq():
+        dq_ref[_span(qb * t.block_q, t.block_q), :] = (
+            dq_acc[qb] * scale).T.astype(dq_ref.dtype)
+
+    @_when(qb == t.nq - 1)
+    def _finalize_dkv():
+        dk_ref[:] = (dk_acc[:] * scale).astype(dk_ref.dtype)
+        dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
+
+
+# What the compiler gives a call's blocks and scratch where the call states no
+# limit (the v5e's; a newer chip's is larger, and a need between the two that
+# is stated there asks for nothing new).
+_VMEM_UNASKED = 16 * 2 ** 20
+
+
+def _vmem_capacity():
+    """A core's VMEM on the device the call is traced for, by Pallas's table
+    of chips (`get_tpu_info`: the mesh's described device, else the default
+    one). Where the table knows neither (the interpreter; a compile for a
+    described chip on a host without one) the v5e's, the chip this repo's
+    compiles describe."""
+    try:
+        return pltpu.get_tpu_info().vmem_capacity_bytes
+    except ValueError:
+        return 128 * 2 ** 20
+
+
+def _fused_vmem_bytes(t, heads, dtype, dq_dtype, dkv_dtype):
+    """VMEM that `_bwd_kernel` needs at this shape: a block of every operand
+    and result twice (the pipeline's two buffers), the three float32
+    accumulators, dq's and its output block a whole row long, and the k
+    tile's transpose."""
+    size = lambda dt: jnp.dtype(dt).itemsize
+    row = t.nq * t.block_q
+    blocks = ((2 * t.block_q + 2 * t.block_k) * heads.lanes * size(dtype)
+              + 2 * heads.per * row * 4                        # lse, delta
+              + row * heads.lanes * size(dq_dtype)
+              + 2 * t.block_k * heads.lanes * size(dkv_dtype))
+    held = (2 * blocks + (row + 2 * t.block_k) * heads.lanes * 4
+            + t.block_k * heads.lanes * size(dtype))
+    return held + held // 8      # and room for the compiler's own spills
+
+
 def _bwd_pallas(q, k, v, lse, do, delta, *, scale, causal, block_q, block_k,
                 interpret, keep_f32=False, seq_major=False, do_t=False):
     """q, k, v, do: [B, H, S, D] or, with `seq_major`, [B, S, H, D]; with
     `do_t`, do is [B, H, D, S] in both, as the forward pass's transposed
     output is. lse and delta = rowsum(dO * O): [B, H, S] float32. dq, dk, dv
     come back in the inputs' layout, rounded once from the kernels' float32
-    accumulators to the inputs' dtype (left float32 with `keep_f32`)."""
+    accumulators to the inputs' dtype (left float32 with `keep_f32`).
+
+    One kernel, `flash_bwd`, wherever a row's dq accumulator fits in VMEM
+    beside the blocks (`_fused_vmem_bytes`); the pair `flash_bwd_dq` +
+    `flash_bwd_dkv`, which makes every rectangle's p and ds twice, for
+    longer rows."""
     heads = _Heads(q.shape, k.shape, seq_major)
     t = _Tiling(heads.q_len, heads.k_len, block_q, block_k, causal)
     q3, k3, v3 = heads.arrays(q, k, v)
     do3 = do if do_t else heads.arrays(do)[0]
+    # dk, dv are accumulated per q-head; a GQA group is reduced outside, in
+    # float32
+    summed = heads.group > 1
+    dq_out = jax.ShapeDtypeStruct(
+        q3.shape, jnp.float32 if keep_f32 else q.dtype)
+    dkv_out = jax.ShapeDtypeStruct(
+        heads.shape(t.k_len), jnp.float32 if keep_f32 or summed else k.dtype)
+    vmem = _fused_vmem_bytes(t, heads, q.dtype, dq_out.dtype, dkv_out.dtype)
+    # half of the core's: the model above is a count of buffers, not the
+    # compiler's allocation, and a call that does not fit fails to compile
+    fused = vmem <= _vmem_capacity() // 2
+    body = dict(scale=scale, t=t, heads=heads, do_t=do_t)
 
-    q_spec = heads.spec(t.block_q, lambda i, j: i)
-    kv_spec = heads.spec(
-        t.block_k, lambda i, j: jnp.minimum(j, t.last_live_k(i)), kv=True)
-    row_spec = heads.stat_spec(1, t.block_q, lambda i, j: (0, i))
-    do_spec = q_spec
-    if do_t:
-        do_spec = heads.stat_spec(heads.dim, t.block_q, lambda i, j: (0, i))
+    if not fused:
+        q_spec = heads.spec(t.block_q, lambda i, j: i)
+        kv_spec = heads.spec(
+            t.block_k, lambda i, j: jnp.minimum(j, t.last_live_k(i)),
+            kv=True)
+        row_spec = heads.stat_spec(1, t.block_q, lambda i, j: (0, i))
+        do_spec = q_spec
+        if do_t:
+            do_spec = heads.stat_spec(heads.dim, t.block_q,
+                                      lambda i, j: (0, i))
+        sub, chunk = _rect(t.block_q, t.block_k, heads.dim, _DQ_RECT)
+        dq = pl.pallas_call(
+            functools.partial(_bwd_dq_kernel, sub=sub, chunk=chunk, **body),
+            grid=(heads.batch, heads.steps, t.nq, t.nk),
+            in_specs=[q_spec, kv_spec, kv_spec, do_spec, row_spec, row_spec],
+            out_specs=q_spec,
+            out_shape=dq_out,
+            scratch_shapes=[pltpu.VMEM((heads.lanes, t.block_q),
+                                       jnp.float32)],
+            interpret=interpret,
+            name="flash_bwd_dq",
+        )(q3, k3, v3, do3, lse[:, :, None], delta[:, :, None])
 
-    sub, chunk = _rect(t.block_q, t.block_k, heads.dim, _DQ_RECT)
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, t=t, heads=heads,
-                          sub=sub, chunk=chunk, do_t=do_t),
-        grid=(heads.batch, heads.steps, t.nq, t.nk),
-        in_specs=[q_spec, kv_spec, kv_spec, do_spec, row_spec, row_spec],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct(
-            q3.shape, jnp.float32 if keep_f32 else q.dtype),
-        scratch_shapes=[pltpu.VMEM((heads.lanes, t.block_q), jnp.float32)],
-        interpret=interpret,
-        name="flash_bwd_dq",
-    )(q3, k3, v3, do3, lse[:, :, None], delta[:, :, None])
-
-    # dk/dv: kv block is the outer grid axis, q blocks stream innermost.
-    sub, chunk = _rect(t.block_k, t.block_q, heads.dim, _DKV_RECT)
+    # dk/dv and the fused kernel: kv block is the outer grid axis, q blocks
+    # stream innermost.
+    sub, chunk = _rect(t.block_k, t.block_q, heads.dim,
+                       _BWD_RECT if fused else _DKV_RECT)
     rows = t.nq * t.block_q // chunk
 
     def chunked(x):
@@ -760,25 +929,35 @@ def _bwd_pallas(q, k, v, lse, do, delta, *, scale, causal, block_q, block_k,
     kv_spec_i = heads.spec(t.block_k, lambda j, i: j, kv=True)
     row_spec_i = heads.stat_spec(rows, chunk, lambda j, i: (0, 0))
     kv_out_spec = heads.spec(t.block_k, lambda j, i: j)
-
-    # Accumulated per q-head; a GQA group is reduced outside, in float32.
-    summed = heads.group > 1
-    dkv = jax.ShapeDtypeStruct(
-        heads.shape(t.k_len),
-        jnp.float32 if keep_f32 or summed else k.dtype)
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, t=t, heads=heads,
-                          sub=sub, chunk=chunk, do_t=do_t),
+    kv_acc = pltpu.VMEM((t.block_k, heads.lanes), jnp.float32)
+    call = dict(
         grid=(heads.batch, heads.steps, t.nk, t.nq),
         in_specs=[q_spec_i, kv_spec_i, kv_spec_i, do_spec_i, row_spec_i,
                   row_spec_i],
-        out_specs=[kv_out_spec, kv_out_spec],
-        out_shape=[dkv, dkv],
-        scratch_shapes=[pltpu.VMEM((t.block_k, heads.lanes), jnp.float32),
-                        pltpu.VMEM((t.block_k, heads.lanes), jnp.float32)],
-        interpret=interpret,
-        name="flash_bwd_dkv",
-    )(q3, k3, v3, do3, chunked(lse), chunked(delta))
+        interpret=interpret)
+    operands = (q3, k3, v3, do3, chunked(lse), chunked(delta))
+    if fused:
+        dq, dk, dv = pl.pallas_call(
+            functools.partial(_bwd_kernel, sub=sub, chunk=chunk, **body),
+            out_specs=[heads.spec(t.nq * t.block_q, lambda j, i: 0),
+                       kv_out_spec, kv_out_spec],
+            out_shape=[dq_out, dkv_out, dkv_out],
+            scratch_shapes=[
+                pltpu.VMEM((t.nq, heads.lanes, t.block_q), jnp.float32),
+                kv_acc, kv_acc,
+                *[pltpu.VMEM((heads.dim, t.block_k), q.dtype)] * heads.per],
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=vmem if vmem > _VMEM_UNASKED else None),
+            name="flash_bwd", **call,
+        )(*operands)
+    else:
+        dk, dv = pl.pallas_call(
+            functools.partial(_bwd_dkv_kernel, sub=sub, chunk=chunk, **body),
+            out_specs=[kv_out_spec, kv_out_spec],
+            out_shape=[dkv_out, dkv_out],
+            scratch_shapes=[kv_acc, kv_acc],
+            name="flash_bwd_dkv", **call,
+        )(*operands)
 
     if summed:
         # [.., Hk, G, ..]: the group is the minor part of the head axis

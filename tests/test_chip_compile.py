@@ -58,8 +58,9 @@ def _on(sharding, tree):
                          ids=["gpt2_medium", "gpt2_xl"])
 def test_flash_attention_fwd_bwd_compiles_at_bench_width(v5e, shape,
                                                          seq_major):
-    """The three flash kernels at the widths the benchmark's steps run,
-    bf16, at the shipped tile: [batch 12, 16 heads, seq 1024, head_dim 64]
+    """The flash kernels (the forward and, since PR 38, one backward kernel
+    in place of the pair) at the widths the benchmark's steps run, bf16, at
+    the shipped tile: [batch 12, 16 heads, seq 1024, head_dim 64]
     (gpt2_medium) and [16, 25, 1024, 64] (gpt2_xl, a chip's share), in the
     layout the models use ([B, S, H, 64]: 128-lane blocks of two heads,
     the thirteenth block of gpt2_xl's 1,600 lanes half there) and in
@@ -76,21 +77,27 @@ def test_flash_attention_fwd_bwd_compiles_at_bench_width(v5e, shape,
                              sharding=SingleDeviceSharding(v5e.devices[0]))
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         x, x, x).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 3
+    assert _kernel_names(compiled, "flash_") == ["flash_bwd", "flash_fwd"]
 
 
 def _kernel_calls(compiled, name=""):
     """The compiled program's Pallas calls whose `pallas_call` name starts
-    with `name`: a scanned block's calls count once each."""
+    with `name` (every Mosaic call, the compiler's own grouped matmuls
+    among them, with none): a scanned block's calls count once each. The
+    name closes
+    the call's path, `.../flash_bwd/pallas_call` or, under a transform
+    with no scope around it, `.../transpose(jvp(flash_bwd))/pallas_call`."""
     return [line for line in compiled.as_text().splitlines()
             if 'custom_call_target="tpu_custom_call"' in line
-            and f"/{name}" in line]
+            and (not name
+                 or re.search(rf"[/(]{name}\w*\)*/pallas_call", line))]
 
 
 def _kernel_names(compiled, name):
     """Those calls' `pallas_call` names, sorted."""
-    return sorted(re.search(rf"/({name}\w*)/pallas_call", line).group(1)
-                  for line in _kernel_calls(compiled, name))
+    return sorted(
+        re.search(rf"[/(]({name}\w*)\)*/pallas_call", line).group(1)
+        for line in _kernel_calls(compiled, name))
 
 
 def _gpt2_medium_step(mesh, batch, remat_policy="dots"):
@@ -110,24 +117,31 @@ def _gpt2_medium_step(mesh, batch, remat_policy="dots"):
 
 
 @pytest.mark.parametrize("remat_policy,kernel_calls",
-                         [("dots", 3), ("full", 4)])
+                         [("dots", 2), ("full", 3)])
 def test_gpt2_medium_train_step_fits_one_chip(v5e, remat_policy,
                                               kernel_calls):
     """The step of `gpt2m-steady` and `gpt2m-ckpt`: batch 12, "dots" remat.
     The compiler refuses a program that exceeds HBM (batch 16 does). "dots"
     saves the flash kernel's output, so a block runs the forward kernel
-    once, then dq and dkv; "full" runs the forward kernel again in the
-    backward pass. Nor may the compiler make room by recomputing on its
-    own: with the kernel's and the out-projection's outputs both saved it
-    ran the logits matmul twice (7 ms a step on the chip, PERF.md PR 29)."""
+    once, then the backward kernel (one, `flash_bwd`, since PR 38: at one
+    1024 tile a row the pair is never picked); "full" runs the forward
+    kernel again in the backward pass. Nor may the compiler make room by
+    recomputing on its own: with the kernel's and the out-projection's
+    outputs both saved it ran the logits matmul twice (7 ms a step on the
+    chip, PERF.md PR 29). Nor may a kernel's layout cost copies beside it:
+    the step held 12 `copy` instructions under "dots" and 11 under "full"
+    with the pair (10 and 10 with the one kernel)."""
     one_chip = SingleDeviceSharding(v5e.devices[0])
     _, _, step, state, tokens = _gpt2_medium_step(None, 12, remat_policy)
     compiled = step.lower(_on(one_chip, state),
                           {"tokens": _on(one_chip, tokens)}).compile()
     assert len(_kernel_calls(compiled)) == kernel_calls
-    assert len(_kernel_calls(compiled, "flash_fwd")) == kernel_calls - 2
+    assert len(_kernel_calls(compiled, "flash_fwd")) == kernel_calls - 1
+    assert _kernel_names(compiled, "flash_bwd") == ["flash_bwd"]
     text = compiled.as_text()
     assert ".remat" not in text
+    assert len(re.findall(r"= \S+ copy\(", text)) <= (
+        12 if remat_policy == "dots" else 11)
     # the residuals stacked over the 24 layers: none has a 64-wide minor
     # dimension in memory (stored at 128 lanes, twice its size: q, k and v
     # were `bf16[24,12,1024,16,64]{4,2,3,1,0}` before PR 31), and q, k, v
@@ -185,10 +199,11 @@ def test_olmoe_one_layer_train_step_fills_one_chip(v5e):
     assert tokens.shape == (rows, 4096)
     compiled = step.lower(_on(one_chip, state),
                           {"tokens": _on(one_chip, tokens)}).compile()
-    # the three flash kernels, once each (no remat: nothing is run twice),
-    # and the grouped matmuls are kernels too
-    assert len(_kernel_calls(compiled, "flash_")) == 3
-    assert len(_kernel_calls(compiled)) > 3
+    # the flash kernels, once each (no remat: nothing is run twice; at
+    # [5, 16, 4096, 128] the row's dq accumulator fits and the backward is
+    # the one kernel), and the grouped matmuls are kernels too
+    assert _kernel_names(compiled, "flash_") == ["flash_bwd", "flash_fwd"]
+    assert len(_kernel_calls(compiled)) > 2
     mem = compiled.memory_analysis()
     # the donated state is aliased to the new one: 12 bytes a parameter
     assert mem.alias_size_in_bytes > 7.4e9
@@ -209,10 +224,15 @@ def _qwen3_next_config():
 
 
 def test_flash_attention_compiles_at_qwen3_next_width(v5e):
-    """The three flash kernels as `qwen3next-steady`'s full-attention layer
-    calls them: [4 rows, 8192, 16 query heads on 2 KV heads, width 256],
-    bf16, handed over as the projections wrote them (a width that fills the
-    lanes takes the head-major kernels, a group of 8 their GQA block maps)."""
+    """The flash kernels as `qwen3next-steady`'s full-attention layer calls
+    them: [4 rows, 8192, 16 query heads on 2 KV heads, width 256], bf16,
+    handed over as the projections wrote them (a width that fills the lanes
+    takes the head-major kernels, a group of 8 their GQA block maps). The
+    backward is the one kernel: the row's [256, 8192] float32 dq
+    accumulator and its whole-row output block take more VMEM than a
+    kernel is given unasked, so the call states what it needs, computed
+    from its shapes, and compiles within it; so does a row twice as long,
+    and one four times as long takes the pair."""
     from ray_tpu.ops.attention import dot_product_attention
 
     model = _qwen3_next_config()["model"]
@@ -228,9 +248,33 @@ def test_flash_attention_compiles_at_qwen3_next_width(v5e):
         sharding=one_chip) for heads in (model["n_heads"],
                                          model["n_kv_heads"]))
     assert q.shape == (4, 8192, 16, 256) and k.shape == (4, 8192, 2, 256)
+    from ray_tpu.ops import attention
+
+    def vmem_stated(compiled):
+        call, = _kernel_calls(compiled, "flash_bwd")
+        return int(re.search(
+            r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', call).group(1))
+
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, k, k).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 3
+    assert _kernel_names(compiled, "flash_") == ["flash_bwd", "flash_fwd"]
+    half = attention._vmem_capacity() // 2      # no TPU here: the v5e's
+    assert half == 64 * 2 ** 20
+    assert attention._VMEM_UNASKED < vmem_stated(compiled) <= half
+    # twice the row, one head: still one kernel, within what it states;
+    # four times the row: the pair
+    q2 = jax.ShapeDtypeStruct((1, 16384, 1, 256), jnp.bfloat16,
+                              sharding=one_chip)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q2, q2, q2).compile()
+    assert _kernel_names(compiled, "flash_bwd") == ["flash_bwd"]
+    assert vmem_stated(compiled) <= half
+    q4 = jax.ShapeDtypeStruct((1, 32768, 1, 256), jnp.bfloat16,
+                              sharding=one_chip)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q4, q4, q4).compile()
+    assert _kernel_names(compiled, "flash_bwd") == [
+        "flash_bwd_dkv", "flash_bwd_dq"]
 
 
 def test_delta_rule_kernels_compile_at_qwen3_next_width(v5e):
@@ -372,10 +416,11 @@ def test_qwen3_next_period_train_step_fills_one_chip(v5e):
     compiled = step.lower(_on(one_chip, state),
                           {"tokens": _on(one_chip, tokens)}).compile()
     # the flash kernels of the one full-attention layer: forward, the
-    # forward recomputed under "full" remat, dq and dkv; the held experts'
-    # grouped matmuls are kernels too, inside loops whose trip count
-    # follows the pairs routed here
-    assert len(_kernel_calls(compiled, "flash_")) == 4
+    # forward recomputed under "full" remat, and the one backward kernel
+    # (PR 38); the held experts' grouped matmuls are kernels too, inside
+    # loops whose trip count follows the pairs routed here
+    assert _kernel_names(compiled, "flash_") == [
+        "flash_bwd", "flash_fwd", "flash_fwd"]
     # the delta rule's, three for each of the period's three Gated DeltaNet
     # layers (the scan is over periods; a period's layers are written out):
     # the primal forward, which writes no states, the `fwd` rule's forward
@@ -445,7 +490,8 @@ def test_qwen3_next_period_train_step_compiles_for_the_host(v5e, axes):
         ["gdn_conv_bwd"] * 9 + ["gdn_conv_fwd"] * 18)
     assert _kernel_names(compiled, "gdn_norm_") == (
         ["gdn_norm_bwd"] * 3 + ["gdn_norm_fwd"] * 6)
-    assert len(_kernel_calls(compiled, "flash_")) == 4
+    assert _kernel_names(compiled, "flash_") == [
+        "flash_bwd", "flash_fwd", "flash_fwd"]
     # the held experts' rows are summed in `jnp` on a mesh: no Mosaic call
     # that the partitioner would have to split (PR 36)
     assert not _kernel_calls(compiled, "moe_segsum")
@@ -468,13 +514,51 @@ def test_gpt2_medium_fsdp4_train_step_compiles_for_the_host(v5e):
     # the step's own in_shardings place the state
     compiled = step.lower(
         state, {"tokens": _on(batch_shardings(mesh), tokens)}).compile()
-    # "dots" through the shard_map branch of GPT._attention: fwd, dq, dkv
-    assert len(_kernel_calls(compiled)) == 3
+    # "dots" through the shard_map branch of GPT._attention: the forward
+    # kernel and the one backward kernel
+    assert _kernel_names(compiled, "flash_") == ["flash_bwd", "flash_fwd"]
+    assert len(_kernel_calls(compiled)) == 2
     assert "all-gather" in compiled.as_text()
     total = sum(np.prod(x.shape) * x.dtype.itemsize
                 for x in jax.tree_util.tree_leaves(state))
     per_chip = compiled.memory_analysis().argument_size_in_bytes
     assert 0.2 * total < per_chip < 0.4 * total
+
+
+def test_gpt2_xl_fsdp4_train_step_holds_the_one_backward_kernel(v5e):
+    """`gpt2xl-fsdp4`'s step as `benchmarks/configs/gpt2_xl.json` describes
+    it: 48 layers of 25 heads of 64 on an fsdp=4 mesh of the host's four
+    chips, 16 x 1024 rows a chip under "full" remat. A block runs the
+    forward kernel, runs it again in the backward pass and then the one
+    backward kernel, whose thirteenth block of two heads is half full; the
+    pair is not in the step, and the compiler recomputes nothing on its
+    own."""
+    import json
+    from ray_tpu.models import (GPT, GPTConfig, init_train_state,
+                                make_optimizer, make_train_step)
+    from ray_tpu.models.training import batch_shardings
+    from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks/configs/gpt2_xl.json")
+    with open(path) as f:
+        config = json.load(f)
+    mesh = build_mesh(MeshSpec(**config["mesh"]), devices=v5e.devices)
+    cfg = GPTConfig(**{**config["model"], "dtype": jnp.bfloat16,
+                       "param_dtype": jnp.float32,
+                       "attention_impl": "pallas"})
+    model, opt = GPT(cfg, mesh=mesh), make_optimizer()
+    state = jax.eval_shape(
+        lambda: init_train_state(model, opt, jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct(
+        (config["batch_per_chip"] * config["chips"], cfg.max_seq_len),
+        jnp.int32)
+    compiled = make_train_step(model, opt, mesh=mesh).lower(
+        state, {"tokens": _on(batch_shardings(mesh), tokens)}).compile()
+    assert _kernel_names(compiled, "flash_") == [
+        "flash_bwd", "flash_fwd", "flash_fwd"]
+    assert len(_kernel_calls(compiled)) == 3
+    assert ".remat" not in compiled.as_text()
 
 
 def test_ring_attention_fwd_bwd_compiles_over_four_chips(v5e):

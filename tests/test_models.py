@@ -6,6 +6,7 @@ on a 1-device and an 8-device mesh (dp/fsdp/tp and sp/ring) must agree.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -162,8 +163,10 @@ def test_remat_policy_decides_how_often_the_flash_forward_runs(
     pass. What is saved changes no number."""
     text, grads = _remat_grads(remat_policy)
     assert text.count("name=flash_fwd") == forward_calls
-    assert text.count("name=flash_bwd_dq") == 1
-    assert text.count("name=flash_bwd_dkv") == 1
+    # one backward kernel a block (PR 38): the tiny row fits in VMEM, so
+    # the pair `flash_bwd_dq` + `flash_bwd_dkv` is not picked
+    assert len(re.findall(r"name=flash_bwd\b", text)) == 1
+    assert "name=flash_bwd_d" not in text
     _, want = _remat_grads(None)
     for got, ref in zip(jax.tree_util.tree_leaves(grads),
                         jax.tree_util.tree_leaves(want)):

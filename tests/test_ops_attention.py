@@ -4,6 +4,7 @@ attention vs the jnp reference. Runs on the virtual 8-device CPU mesh
 kernels testable without real hardware)."""
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -12,9 +13,11 @@ import pytest
 from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_tpu.ops.attention import (_k_chunk_bounds, _q_chunk_bounds,
-                                   attention_reference, chunk_classes,
-                                   dot_product_attention, flash_attention)
+from ray_tpu.ops import attention
+from ray_tpu.ops.attention import (_bwd_pallas, _fwd_pallas, _k_chunk_bounds,
+                                   _q_chunk_bounds, attention_reference,
+                                   chunk_classes, dot_product_attention,
+                                   flash_attention)
 from ray_tpu.ops.ring_attention import ring_attention
 
 
@@ -206,6 +209,156 @@ def test_flash_layouts_give_the_same_numbers():
         np.testing.assert_array_equal(
             np.asarray(a.astype(jnp.float32)),
             np.asarray(jnp.swapaxes(b, 1, 2).astype(jnp.float32)))
+
+
+# The backward pass alone, as `_flash_bwd` and ring attention call it: name:
+# (_qkv arguments, causal, blocks, seq_major, keep_f32, fused). `fused` says
+# which form `_bwd_pallas` picks at the shape: one kernel, `flash_bwd`,
+# wherever the row's dq accumulator fits in VMEM beside the blocks, the pair
+# `flash_bwd_dq` + `flash_bwd_dkv` otherwise; "forced_pair" describes a core
+# with little VMEM to put a small row on the rule's far side.
+_BWD_CASES = {
+    "seq_major_two_heads_a_block": (dict(b=1, h=4, hk=4, s=512), True,
+                                    None, True, False, True),
+    "seq_major_odd_heads": (dict(b=2, h=3, hk=3, s=256), True, None, True,
+                            False, True),
+    "seq_major_bf16": (dict(b=1, h=2, hk=2, s=512, dtype=jnp.bfloat16),
+                       True, None, True, False, True),
+    "head_major_d128": (dict(b=1, h=2, hk=2, s=256, d=128), True, None,
+                        False, False, True),
+    "gqa_group_d128": (dict(b=1, h=4, hk=2, s=256, d=128), True, None,
+                       False, False, True),
+    "ragged_300": (dict(b=1, h=2, hk=2, s=300), True, (128, 128), False,
+                   False, True),
+    "cross_length": (dict(b=1, h=2, hk=2, s=128, sk=256), True, (128, 128),
+                     False, False, True),
+    "non_causal_keep_f32": (dict(b=1, h=2, hk=2, s=256), False, (128, 128),
+                            False, True, True),
+    "row_of_four_tiles": (dict(b=1, h=2, hk=2, s=512), True, (128, 128),
+                          False, False, True),
+    "row_of_four_tiles_unaligned": (dict(b=1, h=2, hk=2, s=512), True,
+                                    (128, 256), False, False, True),
+    "row_of_four_tiles_forced_pair": (dict(b=1, h=2, hk=2, s=512), True,
+                                      (128, 128), False, False, False),
+}
+
+
+def _bwd_forms(case, monkeypatch):
+    """(dq, dk, dv) of the form the rule picks, of the pair on the same
+    inputs, and of the float32 reference, with the jaxpr of the first."""
+    kw, causal, blocks, seq_major, keep_f32, fused = _BWD_CASES[case]
+    q, k, v = _qkv(**kw)
+    do = jax.random.normal(jax.random.PRNGKey(1), q.shape, q.dtype)
+    f32 = lambda x: x.astype(jnp.float32)
+    _, vjp = jax.vjp(
+        lambda q, k, v: attention_reference(q, k, v, causal=causal),
+        f32(q), f32(k), f32(v))
+    want = vjp(f32(do))
+    block_q, block_k = blocks or (1024, 1024)
+    scale = q.shape[-1] ** -0.5
+    out, lse = _fwd_pallas(q, k, v, scale=scale, causal=causal,
+                           block_q=block_q, block_k=block_k, interpret=True)
+    delta = jnp.sum(f32(do) * f32(out), axis=-1)
+    if seq_major:
+        q, k, v, do = (jnp.swapaxes(x, 1, 2) for x in (q, k, v, do))
+
+    def bwd(q, k, v, do):
+        got = _bwd_pallas(q, k, v, lse, do, delta, scale=scale,
+                          causal=causal, block_q=block_q, block_k=block_k,
+                          interpret=True, keep_f32=keep_f32,
+                          seq_major=seq_major)
+        return tuple(jnp.swapaxes(x, 1, 2) if seq_major else x for x in got)
+
+    if not fused:
+        monkeypatch.setattr(attention, "_vmem_capacity", lambda: 2 ** 17)
+    text = str(jax.make_jaxpr(bwd)(q, k, v, do))
+    picked = bwd(q, k, v, do)
+    monkeypatch.setattr(attention, "_vmem_capacity", lambda: 0)
+    pair = bwd(q, k, v, do)
+    return picked, pair, want, text
+
+
+@pytest.mark.parametrize("case", sorted(_BWD_CASES))
+def test_flash_bwd_matches_the_reference_and_the_pair(case, monkeypatch):
+    """The fused backward kernel against the float32 reference and against
+    the kernel pair on the same inputs: the same p and ds in the same
+    dtypes, so they differ by the order of float32 sums (and, bf16, by the
+    rounding that order moves), and the fused form is held to the
+    reference as closely as the pair is."""
+    kw, _, _, _, keep_f32, fused = _BWD_CASES[case]
+    picked, pair, want, text = _bwd_forms(case, monkeypatch)
+    names = set(re.findall(r"name=(flash_\w+)", text))
+    assert names == ({"flash_bwd"} if fused
+                     else {"flash_bwd_dq", "flash_bwd_dkv"})
+    bf16 = kw.get("dtype") == jnp.bfloat16
+    for name, a, b, ref in zip(("dq", "dk", "dv"), picked, pair, want):
+        assert a.dtype == (jnp.float32 if keep_f32 or not bf16
+                           else jnp.bfloat16)
+        assert a.shape == b.shape == ref.shape
+        a, b, ref = (np.asarray(x.astype(jnp.float32)) for x in (a, b, ref))
+        top = float(np.max(np.abs(ref)))
+        atol = 2e-2 * top if bf16 else 5e-4
+        np.testing.assert_allclose(a, ref, atol=atol, err_msg=f"{name}")
+        # against the pair: float32 sums in another order, one bf16
+        # rounding of the result where the inputs are bf16
+        np.testing.assert_allclose(a, b, atol=2 ** -7 * top if bf16
+                                   else 2e-5 * max(top, 1.0),
+                                   err_msg=f"{name} against the pair")
+        # no worse against the reference than the pair is
+        assert np.max(np.abs(a - ref)) <= 1.5 * np.max(np.abs(b - ref)) \
+            + 1e-6 * top, name
+
+
+_V5E, _V4 = ("TPU v5 lite", 1), ("TPU v4", 2)
+
+
+@pytest.mark.parametrize("cell,chip,q_shape,k_shape,seq_major,fused", [
+    ("gpt2m-steady", _V5E, (12, 1024, 16, 64), (12, 1024, 16, 64), True,
+     True),
+    ("gpt2xl-fsdp4", _V5E, (16, 1024, 25, 64), (16, 1024, 25, 64), True,
+     True),
+    ("olmoe-steady", _V5E, (5, 16, 4096, 128), (5, 16, 4096, 128), False,
+     True),
+    ("qwen3next-steady", _V5E, (4, 16, 8192, 256), (4, 2, 8192, 256),
+     False, True),
+    # a row that no v5e kernel could hold: 64k keys of width 128
+    ("longer-than-vmem", _V5E, (1, 1, 65536, 128), (1, 1, 65536, 128),
+     False, False),
+    # the rule asks the device: a v4 core has 16 MiB where the v5e's has
+    # 128, so the [4096, 128] row (9.4 MiB by the rule's count) takes the
+    # pair there and the one-tile row (5.9 MiB) still fuses
+    ("olmoe-steady-on-a-v4", _V4, (5, 16, 4096, 128), (5, 16, 4096, 128),
+     False, False),
+    ("gpt2m-steady-on-a-v4", _V4, (12, 1024, 16, 64), (12, 1024, 16, 64),
+     True, True),
+])
+def test_bwd_form_at_the_benchmark_cells_shapes(cell, chip, q_shape, k_shape,
+                                                seq_major, fused):
+    """Which form `_bwd_pallas` picks is a function of static shapes and of
+    the VMEM of the device the call is traced for (described here, as a
+    mesh describes it): the fused kernel where the row's accumulator and
+    the blocks fit in half of it (as each cell's attention layers call it:
+    bf16, the shipped tile), the pair beyond."""
+    from jax.sharding import AbstractDevice, AbstractMesh
+
+    heads = attention._Heads(q_shape, k_shape, seq_major)
+    narrow = heads.dim % 128 != 0
+    x = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype)
+    stats = x((heads.batch, heads.num, heads.q_len), jnp.float32)
+    do = x((heads.batch, heads.num, heads.dim, heads.q_len) if narrow
+           else q_shape)
+    with jax.sharding.use_abstract_mesh(AbstractMesh(
+            (), (), abstract_device=AbstractDevice(*chip))):
+        text = str(jax.make_jaxpr(
+            lambda q, k, v, lse, do, delta: _bwd_pallas(
+                q, k, v, lse, do, delta, scale=1.0, causal=True,
+                block_q=attention.DEFAULT_BLOCK_Q,
+                block_k=attention.DEFAULT_BLOCK_K, interpret=False,
+                seq_major=seq_major, do_t=narrow))(
+            x(q_shape), x(k_shape), x(k_shape), stats, do, stats))
+    names = set(re.findall(r"name=(flash_\w+)", text))
+    assert names == ({"flash_bwd"} if fused
+                     else {"flash_bwd_dq", "flash_bwd_dkv"}), cell
 
 
 @pytest.mark.parametrize("tile,sub,chunk,computed,interior,edge", [
