@@ -765,7 +765,8 @@ class GPT:
                     norm_topk_prob=c.moe_norm_topk_prob,
                     first_expert=c.moe_first_expert, dtype=dt,
                     # a Mosaic call is not partitioned automatically: on a
-                    # mesh the held experts' rows are summed in `jnp`
+                    # mesh the router's top-k is `lax.top_k` and the held
+                    # experts' rows are summed in `jnp`
                     impl=(c.attention_impl if self.mesh is None
                           else "reference"))
                 if c.moe_shared_ff:

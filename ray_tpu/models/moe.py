@@ -2,8 +2,15 @@
 
 The reference has NO native MoE/EP (SURVEY §2.4: "absent — only via
 external frameworks"); here it's first-class. Softmax top-k routing with no
-capacity and no dropped token: the T x K (token, expert) assignments are
-sorted by expert (stable, so token order is kept inside a group), the rows
+capacity and no dropped token. The router is float32 in truth: the logits
+are a `HIGHEST` product of the activations cast up and every bit of the
+router's weights (where the activations are bf16 the compiler leaves out
+the passes that would multiply the cast's zero terms: three of six on a
+v5e, PERF.md, PR 41), the softmax is over all E, and the K largest
+probabilities of a token are `ops.router_topk`'s — K rounds of a maximum in
+one Pallas kernel at an E of whole 128-lane tiles on a TPU, `lax.top_k`
+elsewhere, the same values, indices and order among equals either way. The
+T x K (token, expert) assignments are sorted by expert (stable, so token order is kept inside a group), the rows
 gathered into that order, and the three SwiGLU matmuls run as grouped
 matmuls over the E ragged groups (`jax.lax.ragged_dot`: FLOPs and memory
 grow with T x K, with no factor of E and no [T, E, C] tensor). The weighted
@@ -41,6 +48,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops.router_topk import router_topk
 from ..ops.segment_sum import sorted_segment_sum
 
 
@@ -239,8 +247,10 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
     held expert the rows its matmuls were given, for an absent one the
     router's count — and `moe_routed_here` is the router's own count of the
     choices that fell on held experts, which the held entries must sum to.
-    `impl` picks the form of the sum that returns the held experts' rows to
-    token order (`ops.segment_sum`), as it picks the other kernels.
+    `impl` picks the form of the router's top-k (`ops.router_topk`; an E
+    that is no multiple of 128 keeps `lax.top_k` under any `impl`) and of
+    the sum that returns the held experts' rows to token order
+    (`ops.segment_sum`), as it picks the other kernels.
     """
     b, s, d = x.shape
     e = router_w.shape[-1]
@@ -249,12 +259,20 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
 
     with jax.named_scope("moe_router"):
         # float32 in truth: on a TPU a float32 matmul at the default
-        # precision rounds its operands to bf16
+        # precision rounds its operands to bf16. `HIGHEST` is six bf16
+        # passes for two float32 operands; where one is bf16 cast up, as
+        # `_norm`'s output is, the compiler leaves out the passes that
+        # would multiply its zero terms (PERF.md, PR 41: this product and
+        # its dW run at three passes' time, dx at six)
         logits = jnp.dot(xf.astype(jnp.float32),
                          router_w.astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)       # [T, E]
         probs = jax.nn.softmax(logits, axis=-1)
-        gate_vals, expert_idx = lax.top_k(probs, top_k)         # [T, K]
+        # the selection's kernel takes a router of whole 128-lane tiles; a
+        # narrower one (OLMoE's 64) keeps `lax.top_k` whatever kernels the
+        # model runs
+        gate_vals, expert_idx = router_topk(                    # [T, K]
+            probs, top_k, impl=impl if e % 128 == 0 else "reference")
         if norm_topk_prob:
             gate_vals = gate_vals / gate_vals.sum(-1, keepdims=True)
 

@@ -8,7 +8,8 @@ delta rule (`delta_rule`) and the Gated DeltaNet layer's two passes around
 it (`gated_deltanet`: convolution + SiLU + q/k normalisation, and the gated
 RMSNorm), each pair forward and backward; and the sum of rows sorted by
 segment into their segments (`segment_sum`), which returns the held
-experts' rows to token order in `models.moe`.
+experts' rows to token order in `models.moe`, and the K largest of each row
+of a router's probabilities (`router_topk`).
 """
 
 from .attention import dot_product_attention, flash_attention  # noqa: F401
@@ -17,5 +18,6 @@ from .delta_rule import (gated_delta_rule,  # noqa: F401
 from .gated_deltanet import (gdn_conv, gdn_conv_reference,  # noqa: F401
                              gdn_gated_norm, gdn_gated_norm_reference)
 from .ring_attention import ring_attention  # noqa: F401
+from .router_topk import router_topk  # noqa: F401
 from .segment_sum import (sorted_segment_sum,  # noqa: F401
                           sorted_segment_sum_reference)

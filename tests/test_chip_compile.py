@@ -443,6 +443,22 @@ def test_qwen3_next_period_train_step_fills_one_chip(v5e):
     # into f32[32768,2048] in each of those eight places
     assert _kernel_names(compiled, "moe_segsum") == ["moe_segsum"] * 8
     assert not re.search(r"= f32\[32768,2048\]\S* scatter\(", text)
+    # the router's top-10 of 512 is `ops.router_topk`'s kernel (PR 41), a
+    # call a layer in the forward pass and one in its recomputation, under
+    # the router's scope; its backward rule is compares and selects, no
+    # kernel. The parent's program sorted f32[32768,512] rows there and
+    # scattered [32768, 10] values into 16.7 M elements on the way back
+    topk = _kernel_calls(compiled, "moe_topk_")
+    assert _kernel_names(compiled, "moe_topk_") == ["moe_topk_rounds"] * 8
+    assert all("/moe_router/" in line for line in topk)
+    assert sum("rematted_computation" in line for line in topk) == 4
+    assert not any("transpose(jvp" in line for line in topk
+                   if "rematted_computation" not in line)
+    router = [line for line in text.splitlines() if "/moe_router/" in line]
+    assert router
+    assert not [line for line in router
+                if re.search(r" (sort|scatter)\(", line)
+                and "[32768,512]" in line]
     # the compiler makes no room on its own any more (PERF.md, PR 29's
     # lesson), and between a layer's projection and its out-projection no
     # activation is copied, padded, sliced out, joined or transposed
@@ -495,6 +511,8 @@ def test_qwen3_next_period_train_step_compiles_for_the_host(v5e, axes):
     # the held experts' rows are summed in `jnp` on a mesh: no Mosaic call
     # that the partitioner would have to split (PR 36)
     assert not _kernel_calls(compiled, "moe_segsum")
+    # and the router keeps `lax.top_k` there (PR 41)
+    assert not _kernel_calls(compiled, "moe_topk_")
     # a chip's share of the rule: [rows / fsdp, 8192, 16 / tp key heads]
     assert (f"bf16[{4 // axes['fsdp']},8192,{2048 // axes.get('tp', 1)}]"
             in compiled.as_text())

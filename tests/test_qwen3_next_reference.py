@@ -133,6 +133,12 @@ CASES = {
     # rule's pair and the Gated DeltaNet layer's two passes around it
     "through_the_kernels": dict(attention_impl="pallas_interpret",
                                 moe_first_expert=4, moe_experts_held=8),
+    # a router of a whole 128-lane tile: its top-k is `ops.router_topk`'s
+    # kernel too (a narrower one keeps `lax.top_k`)
+    "through_the_router_kernel": dict(attention_impl="pallas_interpret",
+                                      n_experts=128, moe_top_k=6,
+                                      moe_first_expert=40,
+                                      moe_experts_held=32),
 }
 
 
