@@ -164,8 +164,8 @@ def held_share_routed(model: Mapping[str, Any], steps, checked, reference,
     In every step of every report and for every layer, what the held experts
     were given (`moe_expert_tokens` [layers, held], the grouped matmuls' own
     group sizes) sums to the router's count of its own choices that fell on
-    them (`moe_routed_here` [layers]); and on the reference rows the
-    system's per-expert counts differ from the reference's by no more than
+    them (`moe_routed_here` [layers]); and on the first timed batch the timed
+    step's own per-expert counts differ from the reference's by no more than
     the choices that disagree explain (each moves two counts by one: counts
     that are not the choices' fail this whatever the precision) and by no
     more than the configuration's `counts_differ_max`, a limit between what

@@ -1,9 +1,11 @@
 """Kernels: the Pallas flash-attention calls' share of the step's device
 time, in percent. The calls are the trace's kernels named `flash_*` by
 `pl.pallas_call(name=...)` (`program_trace.kernels_seconds`): forward, the
-forward recomputed under remat, dq and dkv — `flash_fwd_ms + flash_dq_ms +
-flash_dkv_ms` over `step_device_ms`. A step's other kernels (the grouped
-matmuls, the delta rule's pair) have readers of their own."""
+forward recomputed under remat, and the backward — `flash_fwd_ms +
+flash_bwd_ms` over `step_device_ms` in every cell since PR 38 (the prefix
+reads the pair `flash_bwd_dq` / `flash_bwd_dkv` too, where a step runs it).
+A step's other kernels (the grouped matmuls, the delta rule's pair) have
+readers of their own."""
 
 import statistics
 

@@ -26,15 +26,31 @@ and not the median; what such stalls cost the window is the per-layer metric
 One loop runs every model. What differs between models is named by the
 configuration's file, each a path under the benchmark's directories:
 
-- `reference.module`: the plain reference, `loss_terms(tokens, top, layers,
-  config) -> {"ce", ...}` (`config` being the configuration's file;
-  optionally `counts` [L, E] and `chosen` [L, T, k] where the model routes),
+- `reference.module`: the plain reference, `training(config, operands) ->
+  {"embed", "block", "head", "routes"}` (`config` being the configuration's
+  file), the model in the pieces `reference/train_steps.py` differentiates,
   and `reference.glue`: `reference_weights(params, mesh, devices) -> (top,
-  layers)`, the program's parameters in the reference's layout. The
-  reference is given one row at a time (a float32 4k x 4k score matrix a
-  head is 1 GB a row). `reference.loss_atol` holds the system's evaluation
-  cross-entropy to the reference's, `reference.choice_agreement_min` the
-  share of (token, expert) choices that agree.
+  layers)`, a tree shaped like the program's parameters in the reference's
+  layout. `reference.objective` and `reference.adamw` state the training
+  objective's coefficients and the optimizer's step, `reference.steps` how
+  many of the timed object's first steps are followed (3) and
+  `reference.rows_per_pass` how many rows a device the reference takes at
+  once.
+- What `correct` compares (`step_check.py`): the warm-up IS the timed
+  object's first steps — the compiled step the window times, from the seeded
+  state, on the traffic's first blocks after the one it was lowered on,
+  through the window's own call and feed. The loop keeps those steps'
+  records and batches, every leaf's norm of the optimizer's first moment
+  after one step and of the parameters' change after the last followed step
+  (both through the glue, so leaf by leaf in the reference's layout). After
+  the window has closed, the peak has been read, the trace taken and the
+  trained state freed, the seeded parameters are made again and the
+  reference follows the same steps on the same batches, all rows, in
+  float32 at "highest" with AdamW written out. Held to `step_loss_atol`,
+  `grad_norm_rtol`, `grad_leaf_rtol`, `change_leaf_rtol`; where the model
+  routes, the first timed step's per-expert counts against the reference's
+  on that batch (`counts_differ_max`) and the choices of the program's
+  evaluation on that batch (`choice_agreement_min`).
 - `reference.first_loss_halfwidth`: the first step's cross-entropy
   (`ce_loss` of the step's metrics; `ppl_log` where a model reports no other
   term) is what a head initialised at 0.02 on unit-RMS inputs gives,
@@ -58,7 +74,9 @@ line.
 from __future__ import annotations
 
 import collections
+import gc
 import glob
+import importlib
 import json
 import math
 import os
@@ -70,7 +88,8 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
-from benchmarks import cells, moe_work, spans as spans_mod, traffic_gen
+from benchmarks import (cells, moe_work, spans as spans_mod, step_check,
+                        traffic_gen)
 
 WORKER_SAVES = "worker_saves"   # in a run's directory: where the loop writes
 KEEP_TRACE_ENV = "BENCH_KEEP_TRACE_DIR"   # copy the raw trace here (debugging)
@@ -173,59 +192,127 @@ def _model_config(config: Dict[str, Any]):
 
 # -------------------------------------------------------------- worker side
 
-def _reference_check(cfg, model, state, mesh, devices, tokens, sharding):
-    """The system's evaluation against the plain reference's, on the same
-    parameters and rows, at the run's real width: the cross-entropy, and
-    where the model routes, the (token, expert) choices and the counts."""
+def _first_moment(opt_state):
+    """Adam's first moment inside an optax state: a tree shaped like the
+    parameters."""
+    if hasattr(opt_state, "mu"):
+        return opt_state.mu
+    if isinstance(opt_state, (tuple, list)):
+        for part in opt_state:
+            found = _first_moment(part)
+            if found is not None:
+                return found
+    return None
+
+
+def _kept_sumsq(cfg, mesh, devices, seeded=None):
+    """A jitted `(tree, key) -> {leaf: sum of squares}` over a tree shaped
+    like the program's parameters, leaf by leaf in the reference's layout
+    (the glue is traced, so nothing is copied out and the process's peak
+    stays the step's); with `seeded`, of the tree less `seeded(key)`."""
     import jax
     import jax.numpy as jnp
+    _, glue, train_steps = _reference_modules(cfg)
+
+    def sums(tree, key):
+        if seeded is not None:
+            tree = jax.tree_util.tree_map(jnp.subtract, tree, seeded(key))
+        return train_steps.traced_sumsq(
+            *glue.reference_weights(tree, mesh, devices))
+
+    def floats(tree, key):
+        return {k: float(v) for k, v in
+                jax.device_get(jitted(tree, key)).items()}
+
+    jitted = jax.jit(sums)
+    return floats
+
+
+def _reference_modules(cfg):
+    from benchmarks.reference import train_steps
+    group = cfg["config"]["reference"]
+    return (cells.module(cfg["root"], cfg["paths"], group["module"]),
+            cells.module(cfg["root"], cfg["paths"], group["glue"]),
+            train_steps)
+
+
+def _choice_agreement(ours, theirs, n_experts: int) -> float:
+    """The share of (token, expert) choices, [L, T, k] each, that two
+    routings have in common, a layer at a time."""
     import numpy as np
 
-    config = cfg["config"]
-    reference = cells.module(cfg["root"], cfg["paths"],
-                             config["reference"]["module"])
-    glue = cells.module(cfg["root"], cfg["paths"],
-                        config["reference"]["glue"])
+    def mask(choices):          # [1, T, k] -> [1, T, E]
+        return (choices[..., None] == np.arange(n_experts)).any(-2)
 
-    def evaluate(params, batch):
-        _, metrics = model.loss(params, batch)
-        if not model.config.n_experts:
-            return metrics, {}
-        _, aux = model.forward_with_aux(params, batch["tokens"])
-        return metrics, {k: aux[k] for k in ("moe_expert_tokens",
-                                             "moe_expert_choice") if k in aux}
+    agree = sum(int((mask(ours[i:i + 1]) & mask(theirs[i:i + 1])).sum())
+                for i in range(theirs.shape[0]))
+    return agree / theirs.size
 
-    tokens = jnp.asarray(tokens, jnp.int32)
-    batch = {"tokens": jax.device_put(tokens, sharding)
-             if sharding is not None else tokens}
-    metrics, routing = jax.device_get(jax.jit(evaluate)(state.params, batch))
-    out = {"system_loss": float(metrics.get("ce_loss", metrics["ppl_log"]))}
 
-    top, layers = glue.reference_weights(state.params, mesh, devices)
-    layers = list(layers)
-    rows = [jax.device_get({k: v for k, v in reference.loss_terms(
-        jax.device_put(tokens[i:i + 1], devices[0]), top, layers,
-        config).items() if k != "logits"})
-        for i in range(tokens.shape[0])]
-    out["reference_loss"] = float(np.mean([r["ce"] for r in rows]))
-    if routing and "chosen" in rows[0]:
-        n_experts = routing["moe_expert_tokens"].shape[-1]
-        chosen = np.concatenate([r["chosen"] for r in rows], axis=1)
+def _routing_against(model, params, batch, first_record, followed):
+    """Where the model routes: the program's evaluation of the first timed
+    batch on the seeded parameters gives its (token, expert) choices; the
+    counts are the timed first step's own. Both against the reference's on
+    that batch."""
+    import jax
+    import numpy as np
 
-        def mask(choice):       # [L, T, k] -> [L, T, E]
-            return (choice[..., None] == np.arange(n_experts)).any(-2)
+    choice = jax.device_get(jax.jit(
+        lambda p, tokens: model.forward_with_aux(p, tokens)[1][
+            "moe_expert_choice"])(params, batch))       # [L, B*S, k]
+    chosen, counts = followed["chosen"], followed["counts"]
+    given = np.asarray(first_record["moe_expert_tokens"])
+    return {"choice_agreement": _choice_agreement(choice, chosen,
+                                                  counts.shape[-1]),
+            "choices": int(chosen.size),
+            "counts_differ": int(np.abs(
+                _counts_as_reported(counts, given.ndim, model.config)
+                - given).sum())}
 
-        agree = (mask(routing["moe_expert_choice"]) & mask(chosen)).sum()
-        counts = np.sum([r["counts"] for r in rows], axis=0)
-        out["choice_agreement"] = float(agree) / chosen.size
-        out["counts_differ"] = int(np.abs(
-            counts - routing["moe_expert_tokens"]).sum())
-        out["choices"] = int(chosen.size)
+
+def _counts_as_reported(counts, ndim: int, c):
+    """The reference's per-expert counts [L, E] in the form the step reports
+    its own: summed over the layers where every expert is held ([E]), else
+    what the held experts were given ([L, held])."""
+    if ndim == 1:
+        return counts.sum(0)
+    return counts[:, c.moe_first_expert:c.moe_first_expert + c.experts_held]
+
+
+def _follow_reference(cfg, model, init_params, mesh, devices, sharding,
+                      batches, first_record):
+    """After the window: the plain reference through the same first steps,
+    from the seeded parameters made again, on the batches the timed steps
+    were given. Returns what `step_check.compare` and the routing check
+    read."""
+    import jax
+    import jax.numpy as jnp
+
+    reference, glue, train_steps = _reference_modules(cfg)
+    key = jax.random.PRNGKey(cfg["seed"])
+
+    def start():
+        return glue.reference_weights(init_params(key), mesh, devices)
+
+    followed = train_steps.follow(reference, cfg["config"], start, batches,
+                                  devices)
+    out = {k: followed[k] for k in ("steps", "grad_sumsq", "change_sumsq")}
+    if "chosen" in followed:
+        tokens = jnp.asarray(batches[0], jnp.int32)
+        if sharding is not None:
+            tokens = jax.device_put(tokens, sharding)
+        out["routing"] = _routing_against(model, init_params(key), tokens,
+                                          first_record, followed)
     return out
 
 
 def train_loop(cfg: Dict[str, Any]) -> None:
     first_line_wall = time.time()
+    if cfg.get("patch"):
+        # tests only (`rehearsal`): break the program underneath, here in
+        # the worker, before anything of it is imported by name
+        module, _, name = cfg["patch"].partition(":")
+        getattr(importlib.import_module(module), name)()
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -285,11 +372,12 @@ def train_loop(cfg: Dict[str, Any]) -> None:
                       for leaf in jax.tree_util.tree_leaves(state))
     t = phase("state_init_s", t)
 
-    # ---- the reference check, at the published width, before the window
     sharding = batch_shardings(mesh) if mesh is not None else None
-    checked = _reference_check(cfg, model, state, mesh, devices,
-                               np.asarray(cfg["reference_rows"]), sharding)
-    t = phase("reference_check_s", t)
+    init_params = jax.jit(model.init, out_shardings=(
+        placement.params if mesh is not None else placement))
+    followed_steps = int(config["reference"].get("steps", 3))
+    kept_batches: List[Any] = []        # of the first followed steps
+    system: Dict[str, Any] = {}         # what step_check.compare reads
 
     # ---- the one step shape: compile (or cache hit), then warm up
     batch_rows = config["batch_per_chip"] * cfg["chips"]
@@ -344,6 +432,8 @@ def train_loop(cfg: Dict[str, Any]) -> None:
             batch = next(batches, None)
         if batch is None:
             return False
+        if steps_done < followed_steps:
+            kept_batches.append(np.asarray(jax.device_get(batch["tokens"])))
         if open_save is not None:
             # the loop is moving again. The stall ends where the step is
             # handed over: the first call after a save takes 0.2 s, and the
@@ -396,11 +486,28 @@ def train_loop(cfg: Dict[str, Any]) -> None:
         saves.append(record)
         open_save = record
 
-    for _ in range(int(traffic["warmup_steps"])):
+    # ---- the warm-up: the timed object's first steps, which the reference
+    # follows after the window. After one step the optimizer's first moment
+    # is (1 - b1) x the gradient it got; after the last followed step the
+    # parameters' change from the seeded ones; each leaf by leaf through the
+    # glue, before the next step donates the state.
+    t_kept = 0.0
+    seed_key = jax.random.PRNGKey(cfg["seed"])
+    for i in range(1, max(int(traffic["warmup_steps"]), followed_steps) + 1):
         one_step()
+        t0 = time.perf_counter()
+        if i == 1:     # (1 - b1) x the gradient Adam got
+            system["moment_sumsq"] = _kept_sumsq(cfg, mesh, devices)(
+                _first_moment(state.opt_state), seed_key)
+        if i == followed_steps:
+            system["change_sumsq"] = _kept_sumsq(
+                cfg, mesh, devices, model.init)(state.params, seed_key)
+        t_kept += time.perf_counter() - t0
     while pending:
         fetch()
     jax.block_until_ready(state)
+    system["records"] = records[:followed_steps]
+    phases["first_steps_kept_s"] = t_kept
     t = phase("warmup_steps_s", t)
     if ckpt_every:
         # until the saves take what they take in a long job: on the v5e
@@ -479,7 +586,7 @@ def train_loop(cfg: Dict[str, Any]) -> None:
         "peak_bytes_in_use": [m.get("peak_bytes_in_use") for m in memory],
         "bytes_limit": [m.get("bytes_limit") for m in memory],
         "pallas_custom_calls": hlo.count("tpu_custom_call"),
-        "reference": checked,
+        "first_steps": system,
         "compile_seconds": compiles[:compiles_before]})
 
     # ---- the traced segment, after the window: trace_steps whole steps
@@ -520,6 +627,21 @@ def train_loop(cfg: Dict[str, Any]) -> None:
                       if found else 0,
                       "trace_s": t_reduce - t_trace,
                       "reduce_s": time.perf_counter() - t_reduce})
+
+    # ---- the reference, after the window: its peak has been read and the
+    # trained state goes first
+    # The step's executable and every cached program go with it: what a
+    # loaded program holds on the chip is in no `bytes_in_use` (after one
+    # follow olmoe-steady's readings process had 246 MB free of 15.75 GiB
+    # with 57 MB in use).
+    t_check = time.perf_counter()
+    del state, compiled, lowered
+    jax.clear_caches()
+    gc.collect()
+    followed = _follow_reference(cfg, model, init_params, mesh, devices,
+                                 sharding, kept_batches, system["records"][0])
+    train.report({"kind": "reference", **followed,
+                  "reference_check_s": time.perf_counter() - t_check})
 
     train.report({"kind": "done", "steps": steps_done,
                   "saves": [{k: v for k, v in s.items()
@@ -631,7 +753,7 @@ def run(cell: cells.Cell, *, seed: int, seconds: float, trace: bool,
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         "peaks.json"))
     problems: List[str] = []
-    compared: List[List[Any]] = []      # [what, value, limit]
+    compared: List[List[Any]] = []      # [name, what, value, limit]
 
     t0 = time.perf_counter()
     if rehearsal:
@@ -660,8 +782,6 @@ def run(cell: cells.Cell, *, seed: int, seconds: float, trace: bool,
         dataset = rd.Dataset(block_refs=[
             ray_tpu.put({"tokens": rows[i * batch_rows:(i + 1) * batch_rows]})
             for i in range(n_blocks)])
-        reference_rows = traffic_gen.packed_rows(
-            traffic, max(2, cell.chips), seed + 1_000_003)["tokens"]
         say(kind="traffic", blocks=n_blocks, rows_per_block=batch_rows,
             make_s=time.perf_counter() - t1)
 
@@ -670,7 +790,7 @@ def run(cell: cells.Cell, *, seed: int, seconds: float, trace: bool,
             "platform": platform, "seed": seed, "seconds": seconds,
             "trace": trace, "storage": storage, "root": cell.root,
             "paths": cell.paths,
-            "reference_rows": reference_rows.tolist()}
+            "patch": (rehearsal or {}).get("patch")}
         ckpt_every = int(traffic.get("ckpt_every") or 0)
         watcher = None
         if ckpt_every:
@@ -686,8 +806,9 @@ def run(cell: cells.Cell, *, seed: int, seconds: float, trace: bool,
             watcher.stop()
         reports = result.metrics_history
         for r in reports:
-            if r.get("kind") in ("worker", "trace", "done"):
-                say(**{k: v for k, v in r.items() if k != "reduced"})
+            if r.get("kind") in ("worker", "trace", "done", "reference"):
+                say(**{k: v for k, v in r.items()
+                       if k not in ("reduced", "grad_sumsq", "change_sumsq")})
 
         # ---- what ran where
         worker = _one(reports, "worker") or {}
@@ -713,13 +834,17 @@ def run(cell: cells.Cell, *, seed: int, seconds: float, trace: bool,
                                f"{problems}")
 
         # ---- the window
+        followed = _one(reports, "reference")
+        if followed is not None:    # the check's seconds, after the window
+            window["phases"]["reference_check_s"] = followed[
+                "reference_check_s"]
         say(kind="window", **{k: window[k] for k in (
             "seconds", "steps", "blocked_in_saves_s",
             "tokens_per_s_per_chip", "median_step_s", "steps_timed",
             "window_tokens_per_s_per_chip", "goodput_tokens_per_s_per_chip",
             "compiles_in_window", "phases",
             "state_bytes", "program_bytes", "memory_analysis",
-            "peak_bytes_in_use", "pallas_custom_calls", "reference",
+            "peak_bytes_in_use", "pallas_custom_calls",
             "compile_seconds", "spans")})
         records = window["step_records"]
         model = config["model"]
@@ -740,14 +865,15 @@ def run(cell: cells.Cell, *, seed: int, seconds: float, trace: bool,
         if not ce or not last < ce[0] - LOSS_FALL_MIN:
             problems.append(f"cross-entropy did not fall by {LOSS_FALL_MIN}: "
                             f"first {ce[:1]}, mean of last ten {last:.4f}")
-        checked = window["reference"]
-        tolerance = reference["loss_atol"]
-        if not abs(checked["system_loss"] - checked["reference_loss"]
-                   ) <= tolerance:
-            problems.append(
-                f"evaluation cross-entropy {checked['system_loss']:.6f} "
-                f"differs from the reference's "
-                f"{checked['reference_loss']:.6f} by more than {tolerance}")
+        # the timed object's first steps against the reference's
+        if followed is None:
+            problems.append("the reference never followed the first steps")
+            followed, step_rows = {}, []
+        else:
+            step_rows, step_problems = step_check.compare(
+                window["first_steps"], followed, reference)
+            problems.extend(step_problems)
+        checked = followed.get("routing", {})
         agreement = reference.get("choice_agreement_min")
         if agreement is not None and not checked.get(
                 "choice_agreement", 0.0) >= agreement:
@@ -765,18 +891,21 @@ def run(cell: cells.Cell, *, seed: int, seconds: float, trace: bool,
         # each number compared, beside its limit (what a model has none of
         # is left out)
         compared += [row for row in [
-            ["first cross-entropy less ln V + 0.02^2 d / 2",
+            ["first_ce_off_centre",
+             "first cross-entropy less ln V + 0.02^2 d / 2",
              ce[0] - centre if ce else None,
              reference["first_loss_halfwidth"]],
-            ["first cross-entropy less the mean of the last ten",
-             ce[0] - last if ce else None, LOSS_FALL_MIN],
-            ["evaluation cross-entropy less the reference's",
-             checked["system_loss"] - checked["reference_loss"], tolerance],
-            ["share of (token, expert) choices that agree",
+            ["ce_fall", "first cross-entropy less the mean of the last ten "
+             "(at least)", ce[0] - last if ce else None, LOSS_FALL_MIN],
+            *step_rows,
+            ["choice_agreement", "share of (token, expert) choices on the "
+             "first timed batch that agree (at least)",
              checked.get("choice_agreement"), agreement],
-            ["per-expert counts that differ from the reference's",
+            ["counts_differ", "per-expert counts of the first timed step "
+             "that differ from the reference's",
              checked.get("counts_differ"),
-             reference.get("counts_differ_max")]] if row[1] is not None]
+             reference.get("counts_differ_max")]]
+            if row[2] is not None and row[3] is not None]
         in_window = records[window["first_window_record"]:]
         say(kind="losses", first=records[:3], last_ten_ce_mean=last,
             n=len(records), expected_first_ce=centre,
@@ -804,8 +933,9 @@ def run(cell: cells.Cell, *, seed: int, seconds: float, trace: bool,
             read_back_problems, worst = _check_last_checkpoint(
                 reports, watcher, say)
             problems.extend(read_back_problems)
-            compared.append(["worst relative error of a leaf's sum of "
-                             "squares, read back", worst, CHECKSUM_RTOL])
+            compared.append(["ckpt_leaf_error", "worst relative error of a "
+                             "leaf's sum of squares, read back", worst,
+                             CHECKSUM_RTOL])
 
         # ---- metrics
         setup_s = window["window_start_wall"] - process_start_wall
@@ -865,6 +995,9 @@ def run(cell: cells.Cell, *, seed: int, seconds: float, trace: bool,
             problems.append("the driver process opened a JAX backend")
             line["correct"] = False
         say(kind="verdict", problems=problems, compared=compared)
+        # each number compared beside its limit, last in the line
+        line["compared"] = {name: {"value": value, "limit": limit}
+                            for name, _, value, limit in compared}
         return line
     finally:
         ray_tpu.shutdown()
@@ -872,8 +1005,8 @@ def run(cell: cells.Cell, *, seed: int, seconds: float, trace: bool,
                       ignore_errors=True)
         # each number compared beside its limit: the run's last lines on
         # standard error
-        for what, value, limit in compared:
-            print(f"compared: {what}: {value!r} (limit {limit!r})",
+        for name, what, value, limit in compared:
+            print(f"compared: {name} = {value!r} (limit {limit!r}): {what}",
                   file=sys.stderr)
         for problem in problems:
             print(f"not correct: {problem}", file=sys.stderr)

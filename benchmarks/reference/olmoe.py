@@ -52,6 +52,20 @@ and `top`: `embed_tokens` [V, d], `norm` [d], `lm_head` [d, V].
 `num_key_value_heads`, `num_experts_per_tok`, `norm_topk_prob`,
 `rms_norm_eps`, `rope_theta`. Layers are taken one at a time so that a caller
 can hand them over one at a time.
+
+The training objective (`training`, for `reference/train_steps.py`): `ce`
+plus the output z-loss, z * mean(logsumexp(logits)^2) over the same
+positions (the repo's form, `GPTConfig.z_loss`), plus the configuration's
+coefficients times `load_balance` and `router_z`; the three coefficients are
+the configuration's `reference.objective`. Over a batch in several passes the
+share f_e is the whole batch's (`fraction`, given; it has no gradient), so
+each pass adds its rows' part of E * sum_e f_e * P_e. For the backward pass
+the experts are a `lax.scan` over the stacked weights, each expert's forward
+run again when its gradient is taken (`jax.checkpoint`): the same sums in the
+same order as the loop, less memory. `operands`, where given, is the type
+every matmul's two operands are rounded to before they are multiplied in
+float32 (bfloat16, float8_e4m3fn): the control of a path of lower precision,
+never the reference.
 """
 
 from __future__ import annotations
@@ -62,6 +76,8 @@ from typing import Any, Dict, Iterable, Mapping
 
 import jax
 import jax.numpy as jnp
+
+from benchmarks.reference.operands import mm as _mm
 
 _PRECISION = "highest"
 
@@ -91,37 +107,37 @@ def embed(tokens, embed_tokens):
     return embed_tokens.astype(jnp.float32)[tokens]
 
 
-@functools.partial(jax.jit, static_argnames=("n_head", "n_kv_head", "eps",
-                                             "theta"))
-def attention(x, w: Dict[str, Any], *, n_head: int, n_kv_head: int,
-              eps: float, theta: float):
+def _attention(x, w: Dict[str, Any], *, n_head: int, n_kv_head: int,
+               eps: float, theta: float, operands=None):
     """The attention half of a block, residual included. x: [B, S, d]."""
     with jax.default_matmul_precision(_PRECISION):
         w = {k: v.astype(jnp.float32) for k, v in w.items()}
         b, s, d = x.shape
         hd = w["q_proj"].shape[1] // n_head
         h = _rms_norm(x, w["input_layernorm"], eps)
-        q = _rms_norm(h @ w["q_proj"], w["q_norm"], eps)
-        k = _rms_norm(h @ w["k_proj"], w["k_norm"], eps)
-        v = h @ w["v_proj"]
+        q = _rms_norm(_mm(h, w["q_proj"], operands), w["q_norm"], eps)
+        k = _rms_norm(_mm(h, w["k_proj"], operands), w["k_norm"], eps)
+        v = _mm(h, w["v_proj"], operands)
         q = _rope(q.reshape(b, s, n_head, hd).transpose(0, 2, 1, 3), theta)
         k = _rope(k.reshape(b, s, n_kv_head, hd).transpose(0, 2, 1, 3),
                   theta)
         v = v.reshape(b, s, n_kv_head, hd).transpose(0, 2, 1, 3)
         k = jnp.repeat(k, n_head // n_kv_head, axis=1)
         v = jnp.repeat(v, n_head // n_kv_head, axis=1)
-        scores = q @ k.transpose(0, 1, 3, 2) / math.sqrt(hd)
+        scores = _mm(q, k.transpose(0, 1, 3, 2), operands) / math.sqrt(hd)
         causal = jnp.tril(jnp.ones((s, s), bool))
         scores = jnp.where(causal, scores, -jnp.inf)
-        out = jax.nn.softmax(scores, axis=-1) @ v
+        out = _mm(jax.nn.softmax(scores, axis=-1), v, operands)
         out = out.transpose(0, 2, 1, 3).reshape(b, s, n_head * hd)
-        return x + out @ w["o_proj"]
+        return x + _mm(out, w["o_proj"], operands)
 
 
-@functools.partial(jax.jit, static_argnames=("top_k", "norm_topk_prob",
-                                             "eps"))
-def route(x, post_attention_layernorm, gate, *, top_k: int,
-          norm_topk_prob: bool, eps: float):
+attention = jax.jit(_attention, static_argnames=(
+    "n_head", "n_kv_head", "eps", "theta", "operands"))
+
+
+def _route(x, post_attention_layernorm, gate, *, top_k: int,
+           norm_topk_prob: bool, eps: float, operands=None):
     """The sparse block's input and routing, over the T = B*S tokens: the
     normed hidden states [T, d], the router logits [T, E], the chosen
     experts [T, k], and as dense [T, E] matrices the routing weights (zero
@@ -130,7 +146,7 @@ def route(x, post_attention_layernorm, gate, *, top_k: int,
         d = x.shape[-1]
         h = _rms_norm(x, post_attention_layernorm.astype(jnp.float32),
                       eps).reshape(-1, d)
-        logits = h @ gate.astype(jnp.float32)
+        logits = _mm(h, gate.astype(jnp.float32), operands)
         probs = jax.nn.softmax(logits, axis=-1)
         weights, chosen = jax.lax.top_k(probs, top_k)
         if norm_topk_prob:
@@ -140,41 +156,55 @@ def route(x, post_attention_layernorm, gate, *, top_k: int,
         return h, logits, chosen, dense, one_hot.sum(1) > 0
 
 
-@jax.jit
-def expert(h, gate_proj, up_proj, down_proj, weight, mask):
+route = jax.jit(_route, static_argnames=("top_k", "norm_topk_prob", "eps",
+                                         "operands"))
+
+
+def _expert(h, gate_proj, up_proj, down_proj, weight, mask, operands=None):
     """One expert on every token, times the token's weight for it, and zero
     for a token that did not choose it. h: [T, d]; weight, mask: [T]."""
     with jax.default_matmul_precision(_PRECISION):
         gate_proj, up_proj, down_proj = (
             m.astype(jnp.float32) for m in (gate_proj, up_proj, down_proj))
-        out = (jax.nn.silu(h @ gate_proj) * (h @ up_proj)) @ down_proj
+        out = _mm(jax.nn.silu(_mm(h, gate_proj, operands))
+                  * _mm(h, up_proj, operands), down_proj, operands)
         return jnp.where(mask[:, None], out * weight[:, None], 0.0)
 
 
-def block(x, w: Mapping[str, Any], hparams: Mapping[str, Any]):
-    """One block. Returns (x, the layer's routing facts): `load_balance`
-    and `router_z` (scalars), `counts` [E] and `chosen` [T, k]."""
+expert = jax.jit(_expert, static_argnames=("operands",))
+
+
+def _block(x, w: Mapping[str, Any], hparams: Mapping[str, Any],
+           fraction=None, operands=None):
+    """`block`, traced as one: the experts a scan over the stacked weights,
+    in the loop's order. `fraction` [E], where given, stands for this call's
+    own share of the pairs in `load_balance` (a batch in several passes)."""
     eps = float(hparams["rms_norm_eps"])
     top_k = int(hparams["num_experts_per_tok"])
-    x = attention(x, {k: w[k] for k in (
+    x = _attention(x, {k: w[k] for k in (
         "input_layernorm", "q_proj", "k_proj", "v_proj", "q_norm", "k_norm",
         "o_proj")}, n_head=int(hparams["num_attention_heads"]),
         n_kv_head=int(hparams["num_key_value_heads"]), eps=eps,
-        theta=float(hparams["rope_theta"]))
-    h, logits, chosen, dense, mask = route(
+        theta=float(hparams["rope_theta"]), operands=operands)
+    h, logits, chosen, dense, mask = _route(
         x, w["post_attention_layernorm"], w["gate"], top_k=top_k,
-        norm_topk_prob=bool(hparams["norm_topk_prob"]), eps=eps)
+        norm_topk_prob=bool(hparams["norm_topk_prob"]), eps=eps,
+        operands=operands)
     n_experts = logits.shape[-1]
-    out = jnp.zeros_like(h)
-    for e in range(n_experts):
-        out = out + expert(h, w["experts.gate_proj"][e],
-                           w["experts.up_proj"][e],
-                           w["experts.down_proj"][e], dense[:, e],
-                           mask[:, e])
+    one = jax.checkpoint(functools.partial(_expert, operands=operands))
+
+    def add_expert(out, e):
+        gate_proj, up_proj, down_proj, weight, chose = e
+        return out + one(h, gate_proj, up_proj, down_proj, weight, chose), None
+
+    out, _ = jax.lax.scan(add_expert, jnp.zeros_like(h), (
+        w["experts.gate_proj"], w["experts.up_proj"], w["experts.down_proj"],
+        dense.T, mask.T))
     counts = mask.sum(0)
     probs = jax.nn.softmax(logits, axis=-1)
     # tokens_per_expert of the modelling code, summed over the k choices
-    fraction = counts.astype(jnp.float32) / mask.shape[0]
+    if fraction is None:
+        fraction = counts.astype(jnp.float32) / mask.shape[0]
     facts = {
         "load_balance": n_experts * jnp.sum(fraction * probs.mean(0)),
         "router_z": jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2),
@@ -182,16 +212,43 @@ def block(x, w: Mapping[str, Any], hparams: Mapping[str, Any]):
     return x + out.reshape(x.shape), facts
 
 
-@functools.partial(jax.jit, static_argnames=("eps",))
-def head_loss(x, tokens, norm, lm_head, *, eps: float):
-    """Final RMSNorm, untied head, and the mean next-token cross-entropy
-    (nats) over positions 0..S-2 of every row. Returns (loss, logits)."""
+_HPARAMS = ("num_attention_heads", "num_key_value_heads",
+            "num_experts_per_tok", "norm_topk_prob", "rms_norm_eps",
+            "rope_theta")
+
+
+@functools.partial(jax.jit, static_argnames=("hparams", "operands"))
+def _block_jit(x, w, fraction, *, hparams, operands):
+    return _block(x, w, dict(hparams), fraction, operands)
+
+
+def block(x, w: Mapping[str, Any], hparams: Mapping[str, Any],
+          fraction=None, operands=None):
+    """One block. Returns (x, the layer's routing facts): `load_balance`
+    and `router_z` (scalars), `counts` [E] and `chosen` [T, k]."""
+    return _block_jit(x, dict(w), fraction, hparams=tuple(
+        (k, hparams[k]) for k in _HPARAMS), operands=operands)
+
+
+def _head_terms(x, tokens, norm, lm_head, *, eps: float, operands=None):
+    """Final RMSNorm, untied head; over positions 0..S-2 of every row the
+    mean next-token cross-entropy (nats) and the mean squared log-sum-exp of
+    the logits (what the output z-loss multiplies). Returns (ce, lse2,
+    logits)."""
     with jax.default_matmul_precision(_PRECISION):
         x = _rms_norm(x, norm.astype(jnp.float32), eps)
-        logits = x @ lm_head.astype(jnp.float32)
-        logp = jax.nn.log_softmax(logits[:, :-1], axis=-1)
-        nll = -jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1)[..., 0]
-        return nll.mean(), logits
+        logits = _mm(x, lm_head.astype(jnp.float32), operands)
+        lse = jax.nn.logsumexp(logits[:, :-1], axis=-1)
+        target = jnp.take_along_axis(logits[:, :-1], tokens[:, 1:, None],
+                                     axis=-1)[..., 0]
+        return (lse - target).mean(), (lse ** 2).mean(), logits
+
+
+@functools.partial(jax.jit, static_argnames=("eps",))
+def head_loss(x, tokens, norm, lm_head, *, eps: float):
+    """The mean next-token cross-entropy and the logits."""
+    ce, _, logits = _head_terms(x, tokens, norm, lm_head, eps=eps)
+    return ce, logits
 
 
 def loss_terms(tokens, top: Mapping[str, Any],
@@ -214,3 +271,24 @@ def loss_terms(tokens, top: Mapping[str, Any],
         "router_z": jnp.mean(jnp.stack([f["router_z"] for f in facts])),
         "counts": jnp.stack([f["counts"] for f in facts]),
         "chosen": jnp.stack([f["chosen"] for f in facts])}
+
+
+def training(config: Mapping[str, Any], operands=None) -> Dict[str, Any]:
+    """The model in the pieces `reference/train_steps.py` differentiates one
+    at a time: `embed(top, tokens)`, `block(index)(w, x, fraction) -> (x,
+    terms, facts)` and `head(top, x, tokens) -> (ce, lse2)`. `config` is the
+    configuration's file, whose top level holds the published keys."""
+    hparams = {k: config[k] for k in _HPARAMS}
+
+    def one_block(w, x, fraction):
+        x, facts = _block(x, w, hparams, fraction, operands)
+        return (x, {k: facts[k] for k in ("load_balance", "router_z")},
+                {k: facts[k] for k in ("counts", "chosen")})
+
+    def head(top, x, tokens):
+        return _head_terms(x, tokens, top["norm"], top["lm_head"],
+                           eps=float(hparams["rms_norm_eps"]),
+                           operands=operands)[:2]
+
+    return {"embed": lambda top, tokens: embed(tokens, top["embed_tokens"]),
+            "block": lambda index: one_block, "head": head, "routes": True}

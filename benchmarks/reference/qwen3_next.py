@@ -72,6 +72,23 @@ Departures from the modelling code and the checkpoints, each noted:
   each layer's term is computed alone and the layers are averaged;
 - weights are [in, out] (y = x @ W), the experts' stacked [H, in, out].
 
+The training objective (`training`, for `reference/train_steps.py`): `ce`
+plus the output z-loss, z * mean(logsumexp(logits)^2) over the same
+positions (the repo's form, `GPTConfig.z_loss`), plus the configuration's
+coefficient times `load_balance`; both coefficients are the configuration's
+`reference.objective`. Over a batch in several passes the share f_e is the
+whole batch's (`fraction`, given; it has no gradient). What is done so that
+a backward pass fits beside 10 GB of parameters, gradient and moments, none
+of which changes a sum or its order: the recurrence is a scan over segments
+of `_SEGMENT` positions, each segment's positions run again when its gradient
+is taken (`jax.checkpoint`; the plain scan would keep a 2 MB state a
+position, 17 GB a row of 8,192); a head's score matrix is made again for its
+gradient; the experts are a `lax.scan` over the stacked weights, each
+expert's forward run again. `operands`, where given, is the type every
+matmul's two operands are rounded to before they are multiplied in float32
+(bfloat16, float8_e4m3fn; the recurrence's own sums stay float32): the
+control of a path of lower precision, never the reference.
+
 One layer's weights, a dict. Both kinds:
     input_layernorm [d]   post_attention_layernorm [d]   mlp.gate [d, E]
     mlp.experts.gate_proj mlp.experts.up_proj [H, d, f]
@@ -100,8 +117,11 @@ from typing import Any, Dict, Iterable, Mapping
 import jax
 import jax.numpy as jnp
 
+from benchmarks.reference.operands import mm as _mm
+
 _PRECISION = "highest"
 _L2_EPS = 1e-6
+_SEGMENT = 64       # positions of the recurrence run again as one piece
 
 
 def _rms_norm(x, weight, eps):
@@ -134,20 +154,22 @@ def embed(tokens, embed_tokens):
     return embed_tokens.astype(jnp.float32)[tokens]
 
 
-@functools.partial(jax.jit, static_argnames=("n_head", "n_kv_head", "eps",
-                                             "theta", "fraction"))
-def full_attention(x, w: Dict[str, Any], *, n_head: int, n_kv_head: int,
-                   eps: float, theta: float, fraction: float):
+def _full_attention(x, w: Dict[str, Any], *, n_head: int, n_kv_head: int,
+                    eps: float, theta: float, fraction: float,
+                    operands=None):
     """The gated softmax-attention half of a block, residual included."""
     with jax.default_matmul_precision(_PRECISION):
         w = {k: v.astype(jnp.float32) for k, v in w.items()}
         b, s, d = x.shape
         hd = w["self_attn.q_norm"].shape[0]
         h = _rms_norm(x, w["input_layernorm"], eps)
-        q_gate = (h @ w["self_attn.q_proj"]).reshape(b, s, n_head, 2 * hd)
+        q_gate = _mm(h, w["self_attn.q_proj"], operands).reshape(
+            b, s, n_head, 2 * hd)
         q, gate = q_gate[..., :hd], q_gate[..., hd:]
-        k = (h @ w["self_attn.k_proj"]).reshape(b, s, n_kv_head, hd)
-        v = (h @ w["self_attn.v_proj"]).reshape(b, s, n_kv_head, hd)
+        k = _mm(h, w["self_attn.k_proj"], operands).reshape(
+            b, s, n_kv_head, hd)
+        v = _mm(h, w["self_attn.v_proj"], operands).reshape(
+            b, s, n_kv_head, hd)
         q = _rms_norm(q, w["self_attn.q_norm"], eps).transpose(0, 2, 1, 3)
         k = _rms_norm(k, w["self_attn.k_norm"], eps).transpose(0, 2, 1, 3)
         q, k = _rope(q, theta, fraction), _rope(k, theta, fraction)
@@ -156,21 +178,26 @@ def full_attention(x, w: Dict[str, Any], *, n_head: int, n_kv_head: int,
         v = jnp.repeat(v, n_head // n_kv_head, axis=1)
         causal = jnp.tril(jnp.ones((s, s), bool))
 
+        @jax.checkpoint
         def one_head(qkv):      # a float32 [S, S] score matrix at a time
             qh, kh, vh = qkv                                # [B, S, hd]
-            scores = qh @ kh.transpose(0, 2, 1) / math.sqrt(hd)
+            scores = _mm(qh, kh.transpose(0, 2, 1), operands) / math.sqrt(hd)
             scores = jnp.where(causal, scores, -jnp.inf)
-            return jax.nn.softmax(scores, axis=-1) @ vh
+            return _mm(jax.nn.softmax(scores, axis=-1), vh, operands)
 
         out = jax.lax.map(one_head, tuple(
             t.transpose(1, 0, 2, 3) for t in (q, k, v)))    # [H, B, S, hd]
         out = out.transpose(1, 2, 0, 3) * jax.nn.sigmoid(gate)
-        return x + out.reshape(b, s, n_head * hd) @ w["self_attn.o_proj"]
+        return x + _mm(out.reshape(b, s, n_head * hd),
+                       w["self_attn.o_proj"], operands)
 
 
-@functools.partial(jax.jit, static_argnames=("n_key", "n_value", "eps"))
-def gated_delta_net(x, w: Dict[str, Any], *, n_key: int, n_value: int,
-                    eps: float):
+full_attention = jax.jit(_full_attention, static_argnames=(
+    "n_head", "n_kv_head", "eps", "theta", "fraction", "operands"))
+
+
+def _gated_delta_net(x, w: Dict[str, Any], *, n_key: int, n_value: int,
+                     eps: float, operands=None):
     """The Gated DeltaNet half of a block, residual included: the
     recurrence one position at a time."""
     with jax.default_matmul_precision(_PRECISION):
@@ -180,8 +207,8 @@ def gated_delta_net(x, w: Dict[str, Any], *, n_key: int, n_value: int,
         taps, channels = w["linear_attn.conv1d"].shape
         dk = (channels - n_value * dv) // (2 * n_key)
         h = _rms_norm(x, w["input_layernorm"], eps)
-        qkvz = h @ w["linear_attn.in_proj_qkvz"]
-        ba = h @ w["linear_attn.in_proj_ba"]
+        qkvz = _mm(h, w["linear_attn.in_proj_qkvz"], operands)
+        ba = _mm(h, w["linear_attn.in_proj_ba"], operands)
         mixed, z = qkvz[..., :channels], qkvz[..., channels:]
         padded = jnp.pad(mixed, ((0, 0), (taps - 1, 0), (0, 0)))
         conv = jnp.zeros_like(mixed)
@@ -208,20 +235,32 @@ def gated_delta_net(x, w: Dict[str, Any], *, n_key: int, n_value: int,
             state = state + k_t[..., :, None] * delta[..., None, :]
             return state, jnp.einsum("bhkv,bhk->bhv", state, q_t)
 
+        # one position at a time, in segments so that a gradient can run a
+        # segment again and not keep every position's state
+        seg = _SEGMENT if s % _SEGMENT == 0 else 1
+
+        @jax.checkpoint
+        def segment(state, at):
+            return jax.lax.scan(position, state, at)
+
         _, o = jax.lax.scan(
-            position, jnp.zeros((b, n_value, dk, dv), jnp.float32),
-            tuple(jnp.moveaxis(t, 1, 0) for t in (q, k, v, g, beta)))
-        o = jnp.moveaxis(o, 0, 1)                           # [B, S, Hv, dv]
+            segment, jnp.zeros((b, n_value, dk, dv), jnp.float32),
+            tuple(jnp.moveaxis(t, 1, 0).reshape(
+                (s // seg, seg) + t.shape[:1] + t.shape[2:])
+                for t in (q, k, v, g, beta)))
+        o = jnp.moveaxis(o.reshape((s,) + o.shape[2:]), 0, 1)   # [B,S,Hv,dv]
         variance = jnp.mean(o * o, axis=-1, keepdims=True)
         o = w["linear_attn.norm"] * (o / jnp.sqrt(variance + eps))
         o = o.reshape(b, s, n_value * dv) * jax.nn.silu(z)
-        return x + o @ w["linear_attn.out_proj"]
+        return x + _mm(o, w["linear_attn.out_proj"], operands)
 
 
-@functools.partial(jax.jit, static_argnames=("top_k", "norm_topk_prob",
-                                             "eps"))
-def route(x, post_attention_layernorm, gate, *, top_k: int,
-          norm_topk_prob: bool, eps: float):
+gated_delta_net = jax.jit(_gated_delta_net, static_argnames=(
+    "n_key", "n_value", "eps", "operands"))
+
+
+def _route(x, post_attention_layernorm, gate, *, top_k: int,
+           norm_topk_prob: bool, eps: float, operands=None):
     """The expert block's input and routing over the T = B*S tokens: the
     normed hidden states [T, d], the router logits [T, E], the chosen
     experts [T, k], and as dense [T, E] matrices the routing weights (zero
@@ -230,7 +269,7 @@ def route(x, post_attention_layernorm, gate, *, top_k: int,
         d = x.shape[-1]
         h = _rms_norm(x, post_attention_layernorm.astype(jnp.float32),
                       eps).reshape(-1, d)
-        logits = h @ gate.astype(jnp.float32)
+        logits = _mm(h, gate.astype(jnp.float32), operands)
         probs = jax.nn.softmax(logits, axis=-1)
         weights, chosen = jax.lax.top_k(probs, top_k)
         if norm_topk_prob:
@@ -240,88 +279,159 @@ def route(x, post_attention_layernorm, gate, *, top_k: int,
         return h, logits, chosen, dense, one_hot.sum(1) > 0
 
 
-@jax.jit
-def expert(h, gate_proj, up_proj, down_proj, weight, mask):
+route = jax.jit(_route, static_argnames=("top_k", "norm_topk_prob", "eps",
+                                         "operands"))
+
+
+def _expert(h, gate_proj, up_proj, down_proj, weight, mask, operands=None):
     """One expert on every token, times the token's weight for it, and zero
     for a token that did not choose it. h: [T, d]; weight, mask: [T]."""
     with jax.default_matmul_precision(_PRECISION):
         gate_proj, up_proj, down_proj = (
             m.astype(jnp.float32) for m in (gate_proj, up_proj, down_proj))
-        out = (jax.nn.silu(h @ gate_proj) * (h @ up_proj)) @ down_proj
+        out = _mm(jax.nn.silu(_mm(h, gate_proj, operands))
+                  * _mm(h, up_proj, operands), down_proj, operands)
         return jnp.where(mask[:, None], out * weight[:, None], 0.0)
 
 
-@jax.jit
-def shared_expert(h, gate_proj, up_proj, down_proj, shared_gate):
+expert = jax.jit(_expert, static_argnames=("operands",))
+
+
+def _shared_expert(h, gate_proj, up_proj, down_proj, shared_gate,
+                   operands=None):
     """The expert every token passes through, times its sigmoid gate."""
     with jax.default_matmul_precision(_PRECISION):
         gate_proj, up_proj, down_proj, shared_gate = (
             m.astype(jnp.float32)
             for m in (gate_proj, up_proj, down_proj, shared_gate))
-        out = (jax.nn.silu(h @ gate_proj) * (h @ up_proj)) @ down_proj
-        return jax.nn.sigmoid(h @ shared_gate)[:, None] * out
+        out = _mm(jax.nn.silu(_mm(h, gate_proj, operands))
+                  * _mm(h, up_proj, operands), down_proj, operands)
+        return jax.nn.sigmoid(_mm(h, shared_gate, operands))[:, None] * out
 
 
-def expert_block(x, w: Mapping[str, Any], hparams: Mapping[str, Any],
-                 shared: bool = True):
-    """The expert half of a block WITHOUT its residual: what the given
-    experts (and, if asked, the shared one) add, and the routing facts."""
-    h, logits, chosen, dense, mask = route(
+shared_expert = jax.jit(_shared_expert, static_argnames=("operands",))
+
+
+def _expert_block(x, w: Mapping[str, Any], hparams: Mapping[str, Any],
+                  shared: bool = True, fraction=None, operands=None):
+    """`expert_block`, traced as one: the given experts a scan over the
+    stacked weights, in their order. `fraction` [E], where given, stands for
+    this call's own share of the pairs in `load_balance` (a batch in
+    several passes)."""
+    h, logits, chosen, dense, mask = _route(
         x, w["post_attention_layernorm"], w["mlp.gate"],
         top_k=int(hparams["num_experts_per_tok"]),
         norm_topk_prob=bool(hparams["norm_topk_prob"]),
-        eps=float(hparams["rms_norm_eps"]))
+        eps=float(hparams["rms_norm_eps"]), operands=operands)
     n_experts = logits.shape[-1]
     first = int(hparams.get("first_expert_held", 0))
-    out = jnp.zeros_like(h)
-    for i in range(w["mlp.experts.gate_proj"].shape[0]):
-        out = out + expert(h, w["mlp.experts.gate_proj"][i],
-                           w["mlp.experts.up_proj"][i],
-                           w["mlp.experts.down_proj"][i],
-                           dense[:, first + i], mask[:, first + i])
+    held = w["mlp.experts.gate_proj"].shape[0]
+    one = jax.checkpoint(functools.partial(_expert, operands=operands))
+
+    def add_expert(out, e):
+        gate_proj, up_proj, down_proj, weight, chose = e
+        return out + one(h, gate_proj, up_proj, down_proj, weight, chose), None
+
+    out, _ = jax.lax.scan(add_expert, jnp.zeros_like(h), (
+        w["mlp.experts.gate_proj"], w["mlp.experts.up_proj"],
+        w["mlp.experts.down_proj"], dense[:, first:first + held].T,
+        mask[:, first:first + held].T))
     if shared:
-        out = out + shared_expert(
+        out = out + _shared_expert(
             h, w["mlp.shared_expert.gate_proj"],
             w["mlp.shared_expert.up_proj"], w["mlp.shared_expert.down_proj"],
-            w["mlp.shared_expert_gate"])
+            w["mlp.shared_expert_gate"], operands)
     counts = mask.sum(0)
     probs = jax.nn.softmax(logits, axis=-1)
-    fraction = counts.astype(jnp.float32) / mask.shape[0]
+    if fraction is None:
+        fraction = counts.astype(jnp.float32) / mask.shape[0]
     facts = {
         "load_balance": n_experts * jnp.sum(fraction * probs.mean(0)),
         "counts": counts, "chosen": chosen}
     return out.reshape(x.shape), facts
 
 
-def block(x, w: Mapping[str, Any], hparams: Mapping[str, Any], index: int):
+_HPARAMS = ("full_attention_interval", "num_attention_heads",
+            "num_key_value_heads", "rope_theta", "partial_rotary_factor",
+            "linear_num_key_heads", "linear_num_value_heads",
+            "num_experts_per_tok", "norm_topk_prob", "rms_norm_eps")
+
+
+def _static(hparams: Mapping[str, Any]):
+    return tuple((k, hparams[k]) for k in _HPARAMS) + (
+        ("first_expert_held", int(hparams.get("first_expert_held", 0))),)
+
+
+@functools.partial(jax.jit, static_argnames=("hparams", "shared"))
+def _expert_block_jit(x, w, *, hparams, shared):
+    return _expert_block(x, w, dict(hparams), shared)
+
+
+def expert_block(x, w: Mapping[str, Any], hparams: Mapping[str, Any],
+                 shared: bool = True):
+    """The expert half of a block WITHOUT its residual: what the given
+    experts (and, if asked, the shared one) add, and the routing facts."""
+    return _expert_block_jit(
+        x, {k: v for k, v in w.items() if k.startswith(
+            ("mlp.", "post_attention_layernorm"))},
+        hparams=_static(hparams), shared=shared)
+
+
+def _block(x, w: Mapping[str, Any], hparams: Mapping[str, Any], index: int,
+           fraction=None, operands=None):
     """Layer `index` (from 0). Returns (x, the layer's routing facts)."""
     eps = float(hparams["rms_norm_eps"])
     names = [k for k in w if k.startswith(("self_attn.", "linear_attn."))]
     mixer = {k: w[k] for k in names + ["input_layernorm"]}
     if (index + 1) % int(hparams["full_attention_interval"]) == 0:
-        x = full_attention(
+        x = _full_attention(
             x, mixer, n_head=int(hparams["num_attention_heads"]),
             n_kv_head=int(hparams["num_key_value_heads"]), eps=eps,
             theta=float(hparams["rope_theta"]),
-            fraction=float(hparams["partial_rotary_factor"]))
+            fraction=float(hparams["partial_rotary_factor"]),
+            operands=operands)
     else:
-        x = gated_delta_net(
+        x = _gated_delta_net(
             x, mixer, n_key=int(hparams["linear_num_key_heads"]),
-            n_value=int(hparams["linear_num_value_heads"]), eps=eps)
-    out, facts = expert_block(x, w, hparams)
+            n_value=int(hparams["linear_num_value_heads"]), eps=eps,
+            operands=operands)
+    out, facts = _expert_block(x, w, hparams, True, fraction, operands)
     return x + out, facts
+
+
+@functools.partial(jax.jit, static_argnames=("hparams", "index"))
+def _block_jit(x, w, *, hparams, index):
+    return _block(x, w, dict(hparams), index)
+
+
+def block(x, w: Mapping[str, Any], hparams: Mapping[str, Any], index: int):
+    """Layer `index` (from 0). Returns (x, the layer's routing facts)."""
+    full = (index + 1) % int(hparams["full_attention_interval"]) == 0
+    # one program a kind of layer, not one a layer
+    return _block_jit(x, dict(w), hparams=_static(hparams),
+                      index=int(hparams["full_attention_interval"]) - 1
+                      if full else 0)
+
+
+def _head_terms(x, tokens, norm, lm_head, *, eps: float, operands=None):
+    """Final RMSNorm, untied head; over positions 0..S-2 of every row the
+    mean next-token cross-entropy (nats) and the mean squared log-sum-exp of
+    the logits (what the output z-loss multiplies). Returns (ce, lse2,
+    logits)."""
+    with jax.default_matmul_precision(_PRECISION):
+        x = _rms_norm(x, norm.astype(jnp.float32), eps)
+        logits = _mm(x, lm_head.astype(jnp.float32), operands)
+        lse = jax.nn.logsumexp(logits[:, :-1], axis=-1)
+        target = jnp.take_along_axis(logits[:, :-1], tokens[:, 1:, None],
+                                     axis=-1)[..., 0]
+        return (lse - target).mean(), (lse ** 2).mean(), logits
 
 
 @functools.partial(jax.jit, static_argnames=("eps",))
 def head_loss(x, tokens, norm, lm_head, *, eps: float):
-    """Final RMSNorm, untied head, and the mean next-token cross-entropy
-    (nats) over positions 0..S-2 of every row. Returns (loss, logits)."""
-    with jax.default_matmul_precision(_PRECISION):
-        x = _rms_norm(x, norm.astype(jnp.float32), eps)
-        logits = x @ lm_head.astype(jnp.float32)
-        logp = jax.nn.log_softmax(logits[:, :-1], axis=-1)
-        nll = -jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1)[..., 0]
-        return nll.mean(), logits
+    """The mean next-token cross-entropy and the logits."""
+    ce, _, logits = _head_terms(x, tokens, norm, lm_head, eps=eps)
+    return ce, logits
 
 
 def loss_terms(tokens, top: Mapping[str, Any],
@@ -343,3 +453,31 @@ def loss_terms(tokens, top: Mapping[str, Any],
             [f["load_balance"] for f in facts])),
         "counts": jnp.stack([f["counts"] for f in facts]),
         "chosen": jnp.stack([f["chosen"] for f in facts])}
+
+
+def training(config: Mapping[str, Any], operands=None) -> Dict[str, Any]:
+    """The model in the pieces `reference/train_steps.py` differentiates one
+    at a time: `embed(top, tokens)`, `block(index)(w, x, fraction) -> (x,
+    terms, facts)` (one function a kind of layer) and `head(top, x, tokens)
+    -> (ce, lse2)`. `config` is the configuration's file, whose top level
+    holds the published keys."""
+    hparams = dict(_static(config))
+    interval = int(hparams["full_attention_interval"])
+
+    def kind(index):
+        def one_block(w, x, fraction):
+            x, facts = _block(x, w, hparams, index, fraction, operands)
+            return (x, {"load_balance": facts["load_balance"]},
+                    {k: facts[k] for k in ("counts", "chosen")})
+        return one_block
+
+    linear, full = kind(0), kind(interval - 1)
+
+    def head(top, x, tokens):
+        return _head_terms(x, tokens, top["norm"], top["lm_head"],
+                           eps=float(hparams["rms_norm_eps"]),
+                           operands=operands)[:2]
+
+    return {"embed": lambda top, tokens: embed(tokens, top["embed_tokens"]),
+            "block": lambda index: full if (index + 1) % interval == 0
+            else linear, "head": head, "routes": True}

@@ -410,7 +410,19 @@ def test_benchmark_json_meets_the_contract_and_names_files_that_load():
                       cell.config["reference"]["glue"],
                       cell.config["work"]["module"]):
             assert os.path.isfile(os.path.join(ROOT, "benchmarks", named))
-        assert 0 < cell.config["reference"]["first_loss_halfwidth"] < 0.8
+        group = cell.config["reference"]
+        assert 0 < group["first_loss_halfwidth"] < 0.8
+        # what the first steps are held to, and what the reference follows
+        for limit in ("step_loss_atol", "grad_norm_rtol", "grad_leaf_rtol",
+                      "change_leaf_rtol"):
+            assert 0 < group[limit] < 1, (w["name"], limit)
+        assert 1 <= group["steps"] <= 3 and "z_loss" in group["objective"]
+        assert set(group["adamw"]) == {
+            "learning_rate", "warmup_steps", "total_steps", "end_fraction",
+            "b1", "b2", "eps", "weight_decay", "clip"}
+        assert group["adamw"]["learning_rate"] == cell.config["optimizer"][
+            "learning_rate"]
+        assert cell.traffic["warmup_steps"] >= group["steps"]
         assert any(m["name"] == "setup_s" for m in cell.end_to_end)
         assert len(cell.end_to_end) >= 2 and cell.per_layer
         for m in cell.per_layer:
@@ -461,7 +473,7 @@ def test_a_cell_made_only_of_new_files_runs_end_to_end(workload, trace,
     `tests/` knows them."""
     line = _rehearse(workload, trace, devices)
     assert set(line) == {"correct", "attempted", "failed", "metrics",
-                         "device"}
+                         "device", "compared"}
     # a CPU run is never a result: it says so itself
     assert line["correct"] is False and line["device"]["platform"] == "cpu"
     assert line["device"]["count"] == devices

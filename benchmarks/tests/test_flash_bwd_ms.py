@@ -48,13 +48,11 @@ def _planes(kernels):
 @pytest.mark.parametrize("kernels,want", [
     # the fused form: one backward kernel beside the forward
     ([("flash_fwd", 10), ("flash_bwd", 20)],
-     {"flash_bwd_ms": 0.02, "flash_dq_ms": None, "flash_dkv_ms": None,
-      "flash_fwd_ms": 0.01}),
+     {"flash_bwd_ms": 0.02, "flash_fwd_ms": 0.01}),
     # the pair (the parent's step, and rows too long for the fused form):
     # `flash_bwd` is a prefix of both names and reads neither
     ([("flash_fwd", 10), ("flash_bwd_dq", 12), ("flash_bwd_dkv", 14)],
-     {"flash_bwd_ms": None, "flash_dq_ms": 0.012, "flash_dkv_ms": 0.014,
-      "flash_fwd_ms": 0.01}),
+     {"flash_bwd_ms": None, "flash_fwd_ms": 0.01}),
 ], ids=["fused", "pair"])
 def test_flash_bwd_ms_reads_the_kernel_of_that_name(kernels, want):
     cell = cells.resolve("gpt2m-steady")

@@ -145,6 +145,5 @@ def test_every_cell_reports_the_reader_for_setup_s(name):
     bench = cells.load_json(os.path.join(ROOT, "BENCHMARK.json"))
     [entry] = [m for m in bench["per_layer"] if m["name"] == name]
     assert entry["moves"] == "setup_s" and "workloads" not in entry
-    assert bench["per_layer"].index(entry) >= len(bench["per_layer"]) - 6
     assert os.path.isfile(os.path.join(
         ROOT, "benchmarks", "layer_metrics", name + ".py"))

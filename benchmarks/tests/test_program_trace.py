@@ -153,8 +153,8 @@ def test_a_program_without_scopes_or_spans_reads_as_nothing():
     try:
         for name in ("mlp_share", "attn_proj_share", "head_loss_share",
                      "optimizer_share", "recompute_share",
-                     "unscoped_share", "flash_fwd_ms", "flash_dq_ms",
-                     "flash_dkv_ms", "idle_program_share", "ckpt_d2h_s"):
+                     "unscoped_share", "flash_fwd_ms", "flash_bwd_ms",
+                     "idle_program_share", "ckpt_d2h_s"):
             cell = cells.resolve("gpt2m-ckpt")
             assert cells.layer_reader(cell, name)(run) is None, name
     finally:
@@ -338,8 +338,10 @@ def test_the_readers_give_what_the_kernels_names_give(recorded, benchmark,
         read = {m: cells.layer_reader(cells.resolve("olmoe-steady"), m)(run)
                 for m in ("attn_kernel_share", "flash_attn_roofline",
                           "mlp_share", "unscoped_share", "moe_gmm_roofline",
-                          "flash_fwd_ms", "flash_dq_ms", "flash_dkv_ms",
-                          "step_device_ms")}
+                          "flash_fwd_ms", "step_device_ms")}
+        # the recorded steps are PR 27's: their backward is the pair
+        backward_ms = sum(program_trace.kernel_ms(run, name) or 0.0 for name
+                          in ("flash_bwd_dq", "flash_bwd_dkv"))
     finally:
         del program_trace._cache[recorded], moe_work._cache[recorded]
 
@@ -352,8 +354,7 @@ def test_the_readers_give_what_the_kernels_names_give(recorded, benchmark,
     assert read["attn_kernel_share"] == pytest.approx(
         100 * flash_s / (read["step_device_ms"] / 1e3), rel=1e-3)
     assert read["attn_kernel_share"] == pytest.approx(
-        (read["flash_fwd_ms"] + read["flash_dq_ms"] + read["flash_dkv_ms"])
-        / read["step_device_ms"] * 100)
+        (read["flash_fwd_ms"] + backward_ms) / read["step_device_ms"] * 100)
     work = flops.flash_attention_work(model, seq, rows)
     assert work["flops"] == 6 * model["n_layers"] * model["d_model"] * \
         seq * rows * seq
