@@ -20,7 +20,7 @@ routed to count.
 
 from __future__ import annotations
 
-from typing import Any, Dict, Mapping
+from typing import Any, Dict, Iterable, Mapping
 
 
 def _ff_dim(model: Mapping[str, Any]) -> int:
@@ -79,6 +79,39 @@ def flash_attention_work(model: Mapping[str, Any], seq_len: int,
         "bytes": 6.0 * layers * (heads + kv_heads) * width * tokens
         * act_bytes,
     }
+
+
+def forward_flops(model: Mapping[str, Any], lengths: Iterable[int]) -> float:
+    """Forward-only FLOPs the model needs to score documents of these
+    lengths, each alone (a served request: `loops/serve.py`): a third of the
+    training count for every matmul (2 FLOPs a multiply-add in place of 6),
+    attention over each document's own causal triangle —
+
+        2 x (matmul parameters of the blocks + d_model x vocab) x L
+      + 2 x n_layers x d_model x L^2
+
+    Rows and row tails that a deployment pads are work it chose, not work
+    the request needs: never counted, so `serve_mfu` and the flash kernel's
+    served roofline read the same work whatever batches, buckets or kernels
+    implement it."""
+    d, layers = int(model["d_model"]), int(model["n_layers"])
+    per_token = 2.0 * (layers * matmul_params_per_layer(model)
+                       + d * int(model["vocab_size"]))
+    return sum(per_token * n + 2.0 * layers * d * float(n) * n
+               for n in lengths)
+
+
+def flash_forward_work(model: Mapping[str, Any], lengths: Iterable[int],
+                       act_bytes: int = 2) -> Dict[str, float]:
+    """What causal attention needs, forward only, over documents of these
+    lengths, each alone: the forward third of `flash_attention_work` (2 of
+    its 6 matmuls; q, k, v read and o written once)."""
+    total = {"flops": 0.0, "bytes": 0.0}
+    for n in lengths:
+        whole = flash_attention_work(model, int(n), 1, act_bytes)
+        total["flops"] += whole["flops"] / 3.0
+        total["bytes"] += whole["bytes"] / 3.0
+    return total
 
 
 def roofline_seconds(work: Mapping[str, float], peak: Mapping[str, float]

@@ -64,7 +64,9 @@ configuration's file, each a path under the benchmark's directories:
 
 Saves are a traffic parameter: `ckpt_every` steps (0 = none), through the
 system's checkpoint path, each watched from the driver's side until it is
-durable and the last one read back.
+durable and the last one read back. Set-up runs the save path's one-off work
+(the checksum's program, orbax's import) and no whole save: `setup_s` swung
+with the machine's disk while two stood there.
 
 What a loop module must provide (see README.md): `run(cell, *, seed,
 seconds, trace, process_start_wall, rehearsal, say) -> dict`, the result
@@ -510,12 +512,14 @@ def train_loop(cfg: Dict[str, Any]) -> None:
     phases["first_steps_kept_s"] = t_kept
     t = phase("warmup_steps_s", t)
     if ckpt_every:
-        # until the saves take what they take in a long job: on the v5e
-        # machines the first is slow (cold), the second fast (the page cache
-        # has room), all later ones steady
-        for _ in range(int(traffic["warmup_saves"])):
-            save()
-        t = phase("warmup_saves_s", t)
+        # the save path's one-off costs: the checksum's program and the
+        # import `Checkpoint.from_pytree` makes on its first call. No whole
+        # save: each is the state twice through the machine's disk, and
+        # set-up swung with that disk (PERF.md section 6, PR 44)
+        jax.block_until_ready(checksum(state))
+        t = phase("save_checksum_s", t)
+        import orbax.checkpoint     # noqa: F401
+        t = phase("save_import_s", t)
     compiles_before = len(compiles)
 
     # ---- the window
@@ -542,12 +546,10 @@ def train_loop(cfg: Dict[str, Any]) -> None:
     w1_ns = time.perf_counter_ns()
     window_steps = steps_done - first_window_step
     compiles_in_window = len(compiles) - compiles_before
-    warmup_saves = int(traffic["warmup_saves"]) if ckpt_every else 0
-    window_saves = saves[warmup_saves:]
     # the window's mean rate: its steps over the window less the seconds the
     # loop was blocked in saves (none in a cell without saves). Every late
     # wake-up of the host that drains the device queue is in it.
-    blocked_s = sum(s.get("stall_s", 0.0) for s in window_saves)
+    blocked_s = sum(s.get("stall_s", 0.0) for s in saves)
     window_rate = (window_steps * tokens_per_step
                    / (w1 - w0 - blocked_s) / cfg["chips"])
     # the rate of the steps, the end-to-end metric: a median over the window
@@ -558,9 +560,9 @@ def train_loop(cfg: Dict[str, Any]) -> None:
     # goodput: whole save cycles only, each from one save's return to the
     # next, stalls and all
     goodput = None
-    if window_saves:
-        goodput = (len(window_saves) * ckpt_every * tokens_per_step
-                   / (window_saves[-1]["return_t"] - w0) / cfg["chips"])
+    if saves:
+        goodput = (len(saves) * ckpt_every * tokens_per_step
+                   / (saves[-1]["return_t"] - w0) / cfg["chips"])
     memory = [d.memory_stats() or {} for d in devices]
 
     train.report({
@@ -646,7 +648,7 @@ def train_loop(cfg: Dict[str, Any]) -> None:
     train.report({"kind": "done", "steps": steps_done,
                   "saves": [{k: v for k, v in s.items()
                              if not k.startswith("_") and k != "checksums"}
-                            for s in saves[warmup_saves:]]})
+                            for s in saves]})
 
 
 # -------------------------------------------------------------- driver side
@@ -917,7 +919,7 @@ def run(cell: cells.Cell, *, seed: int, seconds: float, trace: bool,
 
         # ---- the saves
         done = _one(reports, "done") or {"saves": []}
-        saves = done["saves"]                   # those after the warm-up
+        saves = done["saves"]
         failed_saves = 0
         for s in saves:
             facts = watcher.durable.get(s["save"]) if watcher else None

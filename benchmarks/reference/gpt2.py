@@ -138,6 +138,36 @@ def loss_terms(tokens, top: Mapping[str, Any],
     return {"ce": ce, "logits": logits}
 
 
+def _head_scores(x, tokens, ln_f_g, ln_f_b, wte, operands=None):
+    """Final LayerNorm, tied head; for positions 0..S-2 of every row the
+    log-probability of the token that follows."""
+    with jax.default_matmul_precision(_PRECISION):
+        x = _layer_norm(x, ln_f_g.astype(jnp.float32),
+                        ln_f_b.astype(jnp.float32))
+        logits = _mm(x[:, :-1], wte.astype(jnp.float32).T, operands)
+        target = jnp.take_along_axis(logits, tokens[:, 1:, None],
+                                     axis=-1)[..., 0]
+        return target - jax.nn.logsumexp(logits, axis=-1)
+
+
+head_scores = jax.jit(_head_scores, static_argnames=("operands",))
+
+
+def token_logprobs(tokens, top: Mapping[str, Any],
+                   layers: Iterable[Dict[str, Any]],
+                   config: Mapping[str, Any], operands=None):
+    """Forward only, what a scoring request is answered with
+    (`loops/serve.py`): tokens [B, S] int32 -> [B, S-1] float32, the
+    log-probability of each token 1..S-1 given the tokens before it. Nothing
+    here knows of batches, buckets or padding. `operands` is the control."""
+    n_head = int(config["model"]["n_heads"])
+    x = embed(tokens, top["wte"], top["wpe"])
+    for w in layers:
+        x = block(x, w, n_head=n_head, operands=operands)
+    return head_scores(x, tokens, top["ln_f.g"], top["ln_f.b"], top["wte"],
+                       operands=operands)
+
+
 def training(config: Mapping[str, Any], operands=None) -> Dict[str, Any]:
     """The model in the pieces `reference/train_steps.py` differentiates one
     at a time: `embed(top, tokens)`, `block(index)(w, x, fraction)` and

@@ -404,29 +404,39 @@ def test_benchmark_json_meets_the_contract_and_names_files_that_load():
         assert cell.config["chips"] == w["chips"]
         assert os.path.isfile(os.path.join(
             ROOT, "benchmarks/loops", cell.traffic["kind"] + ".py"))
-        # one loop; what differs between models the configuration names
-        assert cell.traffic["kind"] == "train"
-        for named in (cell.config["reference"]["module"],
-                      cell.config["reference"]["glue"],
-                      cell.config["work"]["module"]):
-            assert os.path.isfile(os.path.join(ROOT, "benchmarks", named))
-        group = cell.config["reference"]
-        assert 0 < group["first_loss_halfwidth"] < 0.8
-        # what the first steps are held to, and what the reference follows
-        for limit in ("step_loss_atol", "grad_norm_rtol", "grad_leaf_rtol",
-                      "change_leaf_rtol"):
-            assert 0 < group[limit] < 1, (w["name"], limit)
-        assert 1 <= group["steps"] <= 3 and "z_loss" in group["objective"]
-        assert set(group["adamw"]) == {
-            "learning_rate", "warmup_steps", "total_steps", "end_fraction",
-            "b1", "b2", "eps", "weight_decay", "clip"}
-        assert group["adamw"]["learning_rate"] == cell.config["optimizer"][
-            "learning_rate"]
-        assert cell.traffic["warmup_steps"] >= group["steps"]
+        # one loop a kind; what differs between models the configuration
+        # names
+        assert cell.traffic["kind"] in ("train", "serve")
+        if cell.traffic["kind"] == "serve":
+            _check_served_cell(cell)
+        else:
+            for named in (cell.config["reference"]["module"],
+                          cell.config["reference"]["glue"],
+                          cell.config["work"]["module"]):
+                assert os.path.isfile(os.path.join(ROOT, "benchmarks", named))
+            group = cell.config["reference"]
+            assert 0 < group["first_loss_halfwidth"] < 0.8
+            # what the first steps are held to, and what the reference follows
+            for limit in ("step_loss_atol", "grad_norm_rtol", "grad_leaf_rtol",
+                          "change_leaf_rtol"):
+                assert 0 < group[limit] < 1, (w["name"], limit)
+            assert 1 <= group["steps"] <= 3 and "z_loss" in group["objective"]
+            assert set(group["adamw"]) == {
+                "learning_rate", "warmup_steps", "total_steps", "end_fraction",
+                "b1", "b2", "eps", "weight_decay", "clip"}
+            assert group["adamw"]["learning_rate"] == cell.config["optimizer"][
+                "learning_rate"]
+            assert cell.traffic["warmup_steps"] >= group["steps"]
         assert any(m["name"] == "setup_s" for m in cell.end_to_end)
         assert len(cell.end_to_end) >= 2 and cell.per_layer
         for m in cell.per_layer:
-            assert callable(cells.layer_reader(cell, m["name"]))
+            # `<reader>.<suffix>` is one reader's number under two entries,
+            # one for each end-to-end metric it moves (`loops/serve.py`)
+            assert callable(cells.layer_reader(cell,
+                                               m["name"].split(".")[0]))
+            # an entry reads in a cell only if the cell reports what it moves
+            assert any(e["name"] == m["moves"] for e in cell.end_to_end), (
+                w["name"], m["name"])
     for c in bench["configs"]:
         assert any(w["config"] == c["name"] for w in bench["workloads"])
         config = cells.load_json(os.path.join(ROOT, c["file"]))
@@ -438,11 +448,40 @@ def test_benchmark_json_meets_the_contract_and_names_files_that_load():
         assert f"| {layer} |" in perf, layer
 
 
+def _check_served_cell(cell):
+    """What `loops/serve.py` needs named: the served group beside the
+    configuration's file, the traffic's arrivals, buckets and sample."""
+    from benchmarks.loops import serve
+    served = serve.served_group(cell.root, cell.paths, cell.config_name)
+    assert served["dtype"] in ("bfloat16", "float32")
+    for named in (served["reference"]["module"], served["reference"]["glue"],
+                  served["work"]["module"]):
+        assert os.path.isfile(os.path.join(ROOT, "benchmarks", named))
+    reference = cells.module(cell.root, cell.paths,
+                             served["reference"]["module"])
+    work = cells.module(cell.root, cell.paths, served["work"]["module"])
+    assert callable(reference.token_logprobs)
+    assert callable(work.forward_flops) and callable(work.flash_forward_work)
+    for limit in ("score_gap_max", "score_gap_rms"):
+        assert 0 < served["reference"][limit] < 1, limit
+    assert len(served["reference"]["why"]) > 200
+    traffic = cell.traffic
+    assert traffic["arrivals"]["rate_per_s"] > 0
+    assert traffic["deadline_ms"] > 0 and len(traffic["why"]) > 200
+    batching = traffic["batching"]
+    assert max(batching["lengths"]) == cell.config["model"]["max_seq_len"]
+    assert max(batching["lengths"]) >= traffic["documents"]["length"]["max"]
+    assert max(batching["rows"]) <= batching["max_batch_size"]
+    assert traffic["check"]["sample"] >= 64
+    assert traffic["tokens"]["support"] <= cell.config["model"]["vocab_size"]
+
+
 # --------------------------------------------------------------- rehearsal
 
-def _rehearse(workload, trace, devices, seconds="3"):
+def _rehearse(workload, trace, devices, seconds="3", errors=None):
     """One cell of the fixtures' own BENCHMARK file through `run.main`, in a
-    process of its own (it starts and stops a runtime)."""
+    process of its own (it starts and stops a runtime). `errors`, a list,
+    gets what the run wrote to standard error."""
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                JAX_COMPILATION_CACHE_DIR=os.path.join(
                    ROOT, ".bench_runs", "test_cache"))
@@ -459,6 +498,8 @@ def _rehearse(workload, trace, devices, seconds="3"):
     done = subprocess.run([sys.executable, "-c", code], env=env, cwd=ROOT,
                           capture_output=True, text=True, timeout=600)
     assert done.returncode == 0, done.stderr[-3000:]
+    if errors is not None:
+        errors.append(done.stderr)
     return json.loads(done.stdout.strip().splitlines()[-1])
 
 
@@ -493,6 +534,21 @@ def test_a_cell_made_only_of_new_files_runs_end_to_end(workload, trace,
         # a share of idle time may be 0 or, by the median's error, under it
         assert name == "window_idle_share" or m["value"] > 0, name
         assert math.isfinite(m["value"]) and m["unit"], name
+
+
+def test_no_whole_save_before_the_window_and_nothing_compiles_in_it():
+    """A checkpointing cell makes no whole save before its window (PR 44:
+    two stood there, the state four times through the machine's disk, and
+    `setup_s` swung with the disk). The save path's one-off work, the
+    checksum's program and orbax's import, is still set-up's: the window's
+    first save compiles nothing."""
+    assert "warmup_saves" not in cells.resolve("gpt2m-ckpt").traffic
+    errors = []
+    line = _rehearse("tiny-ckpt", 0, 1, errors=errors)
+    assert line["attempted"] > 0 and line["failed"] == 0
+    assert "ckpt_leaf_error" in line["compared"]
+    assert "not correct: ran on 'cpu'" in errors[0]     # the lines are there
+    assert "inside the window" not in errors[0]
 
 
 def test_no_program_no_result(tmp_path):
