@@ -43,6 +43,17 @@ How the kernels spend their time (measured on the v5e, PERF.md PR 26):
   diagonal or wholly below it) the walk unrolls into straight-line code
   that the compiler's scheduler overlaps; ragged or unaligned shapes take
   the same walk as loops over bounds computed from the program ids.
+* The forward (PERF.md PR 45) walks rectangles of 128 queries x 128 keys
+  at every width the models run (56% of the causal square at S = 1024,
+  62.5% at 512, 75% at 256, one rectangle at 128). Its unrolled walk is one
+  list of the rectangles of all the heads of a grid step, and k.q of the
+  next `_FWD_AHEAD` rectangles is issued before a rectangle's softmax and
+  p.v: the matrix units take their instructions in program order, and p.v,
+  whose stationary operand is the softmax's last result, would hold the
+  next scores behind it while the vector slots work. At a head width under
+  the 128 lanes v^T of the k tile is written once a head to a VMEM scratch
+  (as a product contracting v's rows it is remade a rectangle, streamed
+  out of the matrix unit at half rate); at full lanes the unit keeps pace.
 * Precision. Matmul operands are in the inputs' dtype (bf16 inputs feed
   the MXU bf16; p and ds are cast to it), always with float32
   accumulation. Scores, exp, the running maximum and sum, lse, delta and
@@ -74,8 +85,12 @@ DEFAULT_BLOCK_K = 1024
 # Rectangles of the walk inside a tile, (sub, chunk), by kernel: `sub` is
 # the block that stays put (queries in forward and dq, keys in dkv and in
 # the fused backward kernel) and `chunk` what the loop steps over. Swept
-# with the tile (PERF.md PR 26; the fused kernel's, PR 38).
-_FWD_RECT = (512, 512)
+# with the tile (PERF.md PR 26; the fused kernel's, PR 38; the forward's
+# with its scores made ahead, PR 45: alone on the v5e [128, 128] twelve ahead
+# beat [256, 128] and [256, 256] at widths 64, 128 and 256, rows of one to
+# eight k tiles).
+_FWD_RECT = (128, 128)
+_FWD_AHEAD = 12   # rectangles of scores an unrolled forward walk makes ahead
 _DQ_RECT = (512, 512)
 _DKV_RECT = (128, 128)
 _BWD_RECT = (256, 256)
@@ -444,14 +459,22 @@ class _Heads:
 
     def each(self, body):
         """A function that runs `body(hh)` for every head of the grid
-        step's block. Called at the kernel's top level: the program id is
-        read there, not inside a conditional. Where the last block holds
-        fewer heads, it and the full blocks are two straight-line regions
-        (a conditional around each head would keep the scheduler from
-        overlapping one head's code with the next's in every block)."""
-        def run(heads=self.per):
-            for hh in range(heads):
+        step's block, one after the other (`together`)."""
+        def run(heads):
+            for hh in heads:
                 body(hh)
+        return self.together(run)
+
+    def together(self, body):
+        """A function that runs `body(heads)` on the range of heads the
+        grid step's block holds. Called at the kernel's top level: the
+        program id is read there, not inside a conditional. Where the last
+        block holds fewer heads, it and the full blocks are two
+        straight-line regions (a conditional around each head would keep
+        the scheduler from overlapping one head's code with the next's in
+        every block)."""
+        def run(heads=self.per):
+            body(range(heads))
 
         rest = self.num % self.per
         if rest == 0:
@@ -480,9 +503,101 @@ def seq_major_fits(q_shape, k_shape):
 # Pallas forward kernel
 # ---------------------------------------------------------------------------
 
+def _scores(k_ref, cols, q, r0, c, chunk, rel, q_valid=None, k_valid=None,
+            edge=False):
+    """(st, mask): the float32 scores [keys, queries] of the tile's `c`-th
+    chunk of keys against the scaled queries `q`, the tile's from `r0` on,
+    NEG_INF where an edge rectangle's mask drops them, and that mask (None
+    where nothing masks)."""
+    st = _dot_nt(_rows(k_ref, cols, c * chunk, chunk,
+                       k_valid if edge else None), q)
+    mask = _edge_mask(c * chunk, r0, st.shape, rel, q_valid,
+                      k_valid) if edge else None
+    if mask is not None:
+        st = jnp.where(mask, st, NEG_INF)
+    return st, mask
+
+
+@functools.partial(jax.jit, static_argnames="v_transposed")
+def _online_softmax(carry, st, v, mask=None, v_transposed=False):
+    """The running (m, l, acc) of a block of queries — [1, n] rows and the
+    transposed [head_dim, n] accumulator — after one rectangle of scores
+    `st` and its values `v` ([keys, head_dim], or [head_dim, keys] with
+    `v_transposed`). p is cast to v's dtype for p.v, the rest is float32.
+    `mask` is given only where a query can have no key at all: m is NEG_INF
+    there and exp(st - m) is exp(0). Jitted for the trace's sake, as
+    `_fwd_unrolled` is: a walk's first trace calls it once a rectangle, and
+    as bare `jnp` calls that was half of that trace."""
+    m_prev, l_prev, acc = carry
+    m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    pt = jnp.exp(st - m_new)
+    if mask is not None:
+        pt = jnp.where(mask, pt, 0.0)
+    p = pt.astype(v.dtype)
+    pv = _dot_nn(v, p) if v_transposed else _dot_tn(v, p)
+    return (m_new, l_prev * alpha + jnp.sum(pt, axis=0, keepdims=True),
+            acc * alpha + pv)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "rel", "heads", "dim", "scale", "sub", "chunk", "keyless"))
+def _fwd_unrolled(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, vt_refs, *,
+                  rel, heads, dim, scale, sub, chunk, keyless):
+    """The forward walk of a whole tile whose place is static (`rel`), for
+    the `heads` of the block, each `dim` wide: straight-line code.
+
+    It is one list of the rectangles of all those heads, and the scores of
+    the next `_FWD_AHEAD` rectangles are made before a rectangle's softmax
+    and its p.v. The matrix units take their instructions in program order:
+    p.v, whose stationary operand is the softmax's last result, otherwise
+    holds the next scores behind it while the vector slots work, and the
+    vector slots then wait for those scores (PERF.md PR 45).
+
+    With `vt_refs` (a scratch a head of the block, [head_dim, block_k]) v^T
+    of the k tile is written there once a head and p.v streams it from
+    there; without, p.v contracts v's rows and the matrix unit makes v^T a
+    rectangle (`_fwd_pallas` says where which).
+
+    Jitted, on the block's refs, for the trace's sake and not the
+    program's: the walk is 36 rectangles a head at 1024 positions, a
+    hundred a head of a long row's two tile classes, and every program that
+    holds the kernel traces its body anew — the served cells' sixteen bucket
+    programs paid 5.7 s of `setup_s` for that. The trace caches this
+    function by the refs' shapes and the static place, so a process traces
+    a walk once a tile shape; the kernel's lowering inlines it."""
+    block_q, block_k = q_ref.shape[0], k_ref.shape[0]
+    rects = []      # (scores' arguments, head, queries' slice, chunk, last)
+    for hh in heads:
+        cols = slice(hh * dim, (hh + 1) * dim)
+        if vt_refs:
+            vt_refs[hh][...] = v_ref[:, cols].T
+        for r0 in range(0, block_q, sub):
+            interior_end, live_end = _k_chunk_bounds(r0, sub, rel, block_k,
+                                                     chunk=chunk)
+            q = _scaled(q_ref[r0:r0 + sub, cols], scale)
+            rects += [((k_ref, cols, q, r0, c, chunk, rel, None, None,
+                        c >= interior_end), hh, slice(r0, r0 + sub), c,
+                       c == live_end - 1) for c in range(live_end)]
+
+    made = [_scores(*args) for args, *_ in rects[:_FWD_AHEAD]]
+    for i, ((_, cols, *_), hh, rs, c, last) in enumerate(rects):
+        if i + _FWD_AHEAD < len(rects):
+            made.append(_scores(*rects[i + _FWD_AHEAD][0]))
+        row, keys = slice(hh, hh + 1), slice(c * chunk, (c + 1) * chunk)
+        if c == 0:
+            carry = m_ref[row, rs], l_ref[row, rs], acc_ref[cols, rs]
+        st, mask = made.pop(0)
+        carry = _online_softmax(
+            carry, st, vt_refs[hh][:, keys] if vt_refs else v_ref[keys, cols],
+            mask if keyless else None, v_transposed=bool(vt_refs))
+        if last:
+            m_ref[row, rs], l_ref[row, rs], acc_ref[cols, rs] = carry
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                *, scale, t, heads, sub, chunk, transposed_out):
-    """One (q tile, k tile) step of the forward pass, for each head of the
+                *vt_refs, scale, t, heads, sub, chunk, transposed_out):
+    """One (q tile, k tile) step of the forward pass, for the heads of the
     block.
 
     Scores are laid out [keys, queries]. Each `sub` queries of the q tile
@@ -491,14 +606,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     sub]) ride the walk in registers and rest in VMEM scratch between k
     tiles. Dead chunks are not visited, only edge chunks build a mask, and
     the select after the exp exists only where a query can have no key at
-    all. q is scaled once per sub-block. Matmul operands are in the inputs'
-    dtype (p cast to it); scores, exp, m, l, the accumulator and lse are
-    float32. With `transposed_out` the output block is written as it was
-    accumulated, [head_dim, queries].
+    all. q is scaled once per sub-block. A tile whose place is static walks
+    unrolled, its scores made ahead (`_fwd_unrolled`); a ragged or unaligned
+    one by loops, a sub-block after the other, the products in place.
+    Matmul operands are in the inputs' dtype (p cast to it); scores, exp,
+    m, l, the accumulator and lse are float32. With `transposed_out` the
+    output block is written as it was accumulated, [head_dim, queries].
     """
     qb, kb = t.ids(2, 3)
     q_valid, k_valid = t.valid(qb, kb)
-    dtype = q_ref.dtype
     keyless = t.causal and t.off < 0      # queries before the first key
 
     @_when(kb == 0)
@@ -507,40 +623,40 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    def walk(rel, hh):
-        cols, row = heads.cols(hh), slice(hh, hh + 1)
-        for r0 in range(0, t.block_q, sub):
-            rs = slice(r0, r0 + sub)
-            interior_end, live_end = _k_chunk_bounds(
-                r0, sub, rel, t.span(q_valid, k_valid)[1], chunk=chunk)
-            q = _scaled(q_ref[rs, cols], scale)
+    def walk(rel, hhs):
+        if _static(rel) and k_valid is None:
+            return _fwd_unrolled(
+                q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, vt_refs,
+                rel=rel, heads=tuple(hhs), dim=heads.dim, scale=scale,
+                sub=sub, chunk=chunk, keyless=keyless)
+        for hh in hhs:
+            cols, row = heads.cols(hh), slice(hh, hh + 1)
+            if vt_refs:
+                vt_refs[hh][...] = _rows(v_ref, cols, 0, t.block_k, k_valid).T
+            for r0 in range(0, t.block_q, sub):
+                rs = slice(r0, r0 + sub)
+                interior_end, live_end = _k_chunk_bounds(
+                    r0, sub, rel, t.span(q_valid, k_valid)[1], chunk=chunk)
+                q = _scaled(q_ref[rs, cols], scale)
 
-            def step(c, carry, edge):
-                m_prev, l_prev, acc = carry
-                pad = k_valid if edge else None
-                st = _dot_nt(_rows(k_ref, cols, c * chunk, chunk, pad), q)
-                mask = _edge_mask(c * chunk, r0, st.shape, rel, q_valid,
-                                  k_valid) if edge else None
-                if mask is not None:
-                    st = jnp.where(mask, st, NEG_INF)
-                m_new = jnp.maximum(m_prev,
-                                    jnp.max(st, axis=0, keepdims=True))
-                alpha = jnp.exp(m_prev - m_new)
-                pt = jnp.exp(st - m_new)
-                if mask is not None and keyless:
-                    pt = jnp.where(mask, pt, 0.0)   # m == NEG_INF: exp(0)
-                pv = _dot_tn(_rows(v_ref, cols, c * chunk, chunk, pad),
-                             pt.astype(dtype))
-                return (m_new,
-                        l_prev * alpha + jnp.sum(pt, axis=0, keepdims=True),
-                        acc * alpha + pv)
+                def step(c, carry, edge):
+                    st, mask = _scores(k_ref, cols, q, r0, c, chunk, rel,
+                                       q_valid, k_valid, edge)
+                    if vt_refs:
+                        v = vt_refs[hh][:, _span(c * chunk, chunk)]
+                    else:
+                        v = _rows(v_ref, cols, c * chunk, chunk,
+                                  k_valid if edge else None)
+                    return _online_softmax(
+                        carry, st, v, mask if keyless else None,
+                        v_transposed=bool(vt_refs))
 
-            m_ref[row, rs], l_ref[row, rs], acc_ref[cols, rs] = _walk(
-                (0, interior_end, live_end), step,
-                (m_ref[row, rs], l_ref[row, rs], acc_ref[cols, rs]))
+                m_ref[row, rs], l_ref[row, rs], acc_ref[cols, rs] = _walk(
+                    (0, interior_end, live_end), step,
+                    (m_ref[row, rs], l_ref[row, rs], acc_ref[cols, rs]))
 
-    heads.each(lambda hh: t.for_each_class(
-        qb, kb, functools.partial(walk, hh=hh)))()
+    heads.together(lambda hhs: t.for_each_class(
+        qb, kb, functools.partial(walk, hhs=hhs)))()
 
     def write(hh):
         cols, row = heads.cols(hh), slice(hh, hh + 1)
@@ -563,6 +679,11 @@ def _fwd_pallas(q, k, v, *, scale, causal, block_q, block_k, interpret,
     heads = _Heads(q.shape, k.shape, seq_major)
     t = _Tiling(heads.q_len, heads.k_len, block_q, block_k, causal)
     sub, chunk = _rect(t.block_q, t.block_k, heads.dim, _FWD_RECT)
+    # v^T scratches, one a head of the block: a v narrower than the lanes
+    # streams its transpose out of the matrix unit at half rate, 8 rows an
+    # instruction, again for every rectangle; at full lanes the unit keeps
+    # pace and the scratch costs more than it saves (PERF.md PR 45)
+    staged = heads.per if heads.dim < 128 else 0
 
     q_spec = heads.spec(t.block_q, lambda i, j: i)
     kv_spec = heads.spec(
@@ -590,6 +711,7 @@ def _fwd_pallas(q, k, v, *, scale, causal, block_q, block_k, interpret,
             pltpu.VMEM((heads.lanes, t.block_q), jnp.float32),
             pltpu.VMEM((heads.per, t.block_q), jnp.float32),
             pltpu.VMEM((heads.per, t.block_q), jnp.float32),
+            *[pltpu.VMEM((heads.dim, t.block_k), q.dtype)] * staged,
         ],
         interpret=interpret,
         name="flash_fwd",

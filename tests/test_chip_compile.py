@@ -78,6 +78,36 @@ def test_flash_attention_fwd_bwd_compiles_at_bench_width(v5e, shape,
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         x, x, x).compile()
     assert _kernel_names(compiled, "flash_") == ["flash_bwd", "flash_fwd"]
+    assert _asks_no_vmem(compiled, "flash_fwd")
+
+
+@pytest.mark.parametrize("length", [128, 256, 512, 1024])
+def test_flash_forward_compiles_at_the_served_buckets(v5e, length):
+    """The forward kernel alone, as the served cells' bucket programs hold
+    it: bf16, no gradient, [32 rows, a bucket's length, 16 heads of 64] as
+    the projections wrote them (under 3 s a bucket). Its blocks (twice),
+    accumulators, the v^T scratch a head and the rectangles of scores made
+    ahead fit in what the compiler gives a kernel unasked."""
+    from ray_tpu.ops.attention import dot_product_attention
+
+    x = jax.ShapeDtypeStruct((32, length, 16, 64), jnp.bfloat16,
+                             sharding=SingleDeviceSharding(v5e.devices[0]))
+    compiled = jax.jit(lambda q, k, v: dot_product_attention(
+        q, k, v, causal=True, impl="pallas", seq_major=True)).lower(
+            x, x, x).compile()
+    assert _kernel_names(compiled, "flash_") == ["flash_fwd"]
+    assert _asks_no_vmem(compiled, "flash_fwd")
+
+
+def _asks_no_vmem(compiled, name):
+    """Whether the kernel's calls state no scoped-VMEM limit of their own
+    (beside a call that states one the compiler writes its default, 16 MiB
+    on the v5e, on the others): what they hold then fits in that default,
+    or the compile would have failed."""
+    calls = _kernel_calls(compiled, name)
+    stated = [int(size) for call in calls for size in re.findall(
+        r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', call)]
+    return bool(calls) and all(size <= 16 * 2 ** 20 for size in stated)
 
 
 def _kernel_calls(compiled, name=""):
@@ -130,7 +160,8 @@ def test_gpt2_medium_train_step_fits_one_chip(v5e, remat_policy,
     outputs both saved it ran the logits matmul twice (7 ms a step on the
     chip, PERF.md PR 29). Nor may a kernel's layout cost copies beside it:
     the step held 12 `copy` instructions under "dots" and 11 under "full"
-    with the pair (10 and 10 with the one kernel)."""
+    with the pair, 10 and 10 with the one backward kernel, and as many with
+    PR 45's forward, which states no VMEM limit either."""
     one_chip = SingleDeviceSharding(v5e.devices[0])
     _, _, step, state, tokens = _gpt2_medium_step(None, 12, remat_policy)
     compiled = step.lower(_on(one_chip, state),
@@ -140,8 +171,8 @@ def test_gpt2_medium_train_step_fits_one_chip(v5e, remat_policy,
     assert _kernel_names(compiled, "flash_bwd") == ["flash_bwd"]
     text = compiled.as_text()
     assert ".remat" not in text
-    assert len(re.findall(r"= \S+ copy\(", text)) <= (
-        12 if remat_policy == "dots" else 11)
+    assert len(re.findall(r"= \S+ copy\(", text)) <= 10
+    assert _asks_no_vmem(compiled, "flash_fwd")
     # the residuals stacked over the 24 layers: none has a 64-wide minor
     # dimension in memory (stored at 128 lanes, twice its size: q, k and v
     # were `bf16[24,12,1024,16,64]{4,2,3,1,0}` before PR 31), and q, k, v
@@ -203,6 +234,7 @@ def test_olmoe_one_layer_train_step_fills_one_chip(v5e):
     # [5, 16, 4096, 128] the row's dq accumulator fits and the backward is
     # the one kernel), and the grouped matmuls are kernels too
     assert _kernel_names(compiled, "flash_") == ["flash_bwd", "flash_fwd"]
+    assert _asks_no_vmem(compiled, "flash_fwd")
     assert len(_kernel_calls(compiled)) > 2
     mem = compiled.memory_analysis()
     # the donated state is aliased to the new one: 12 bytes a parameter
@@ -258,6 +290,7 @@ def test_flash_attention_compiles_at_qwen3_next_width(v5e):
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, k, k).compile()
     assert _kernel_names(compiled, "flash_") == ["flash_bwd", "flash_fwd"]
+    assert _asks_no_vmem(compiled, "flash_fwd")
     half = attention._vmem_capacity() // 2      # no TPU here: the v5e's
     assert half == 64 * 2 ** 20
     assert attention._VMEM_UNASKED < vmem_stated(compiled) <= half
@@ -576,6 +609,7 @@ def test_gpt2_xl_fsdp4_train_step_holds_the_one_backward_kernel(v5e):
     assert _kernel_names(compiled, "flash_") == [
         "flash_bwd", "flash_fwd", "flash_fwd"]
     assert len(_kernel_calls(compiled)) == 3
+    assert _asks_no_vmem(compiled, "flash_fwd")
     assert ".remat" not in compiled.as_text()
 
 

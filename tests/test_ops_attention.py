@@ -211,6 +211,165 @@ def test_flash_layouts_give_the_same_numbers():
             np.asarray(jnp.swapaxes(b, 1, 2).astype(jnp.float32)))
 
 
+# The forward kernel alone, as `_flash_fwd`, the served path and ring
+# attention call it, at each class of shape the benchmark's cells run, cut
+# to the interpreter's reach: name: (_qkv arguments, causal, blocks or None
+# for the shipped tile, seq_major, each row's document length or None). A
+# document shorter than its row is right-padded, as the served path's
+# buckets are: its real positions are held to the document alone.
+_BF16 = dict(dtype=jnp.bfloat16)
+_FWD_CASES = {
+    # GPT-2: two heads a 128-lane block, one 1024 tile
+    "two_heads_a_block_1024": (dict(b=1, h=2, hk=2, s=1024, **_BF16), True,
+                               None, True, None),
+    # gpt2_xl: an odd count, the last block half full
+    "odd_heads_half_block": (dict(b=1, h=3, hk=3, s=512, **_BF16), True,
+                             None, True, None),
+    # OLMoE: width 128, a row of four k tiles
+    "d128_four_k_tiles": (dict(b=1, h=2, hk=2, s=512, d=128, **_BF16), True,
+                          (128, 128), False, None),
+    # Qwen3-Next: a GQA group at width 256, several k tiles
+    "gqa_group_d256": (dict(b=1, h=4, hk=2, s=256, d=256, **_BF16), True,
+                       (128, 128), False, None),
+    # the served buckets: bf16, forward only, right-padded documents
+    "bucket_128": (dict(b=2, h=2, hk=2, s=128, **_BF16), True, None, True,
+                   (128, 37)),
+    "bucket_256": (dict(b=2, h=2, hk=2, s=256, **_BF16), True, None, True,
+                   (130, 255)),
+    "bucket_512": (dict(b=2, h=2, hk=2, s=512, **_BF16), True, None, True,
+                   (400, 257)),
+    "bucket_1024": (dict(b=1, h=2, hk=2, s=1024, **_BF16), True, None, True,
+                    (777,)),
+    "ragged_300": (dict(b=1, h=2, hk=2, s=300), True, (128, 128), False,
+                   None),
+    "cross_length": (dict(b=1, h=2, hk=2, s=128, sk=256), True, (128, 128),
+                     False, None),
+    "q_longer_than_k": (dict(b=1, h=2, hk=2, s=256, sk=128), True, None,
+                        False, None),
+    # ring attention's partial: no mask, float32 kept
+    "non_causal_keep_f32": (dict(b=1, h=2, hk=2, s=256), False, (128, 128),
+                            False, None),
+}
+
+# What the parent's forward kernel (PR 44's tree, 512 x 512 rectangles, p.v
+# in place) gave under the interpreter at eight places of out and four of
+# lse of each case (`_samples`): the kernel may add in another order, not
+# in another precision.
+_FWD_PARENT = {
+    "bucket_1024": (
+        [-0.6679688, 0.05541992, 0.0390625, -0.04589844, 0.1162109, -0.1196289,
+         0.06494141, -0.07470703],
+        [-0.5967165, 7.013887, 6.382701, 7.389666]),
+    "bucket_128": (
+        [-0.6679688, -0.08789062, -0.0100708, 0.1157227, -0.2519531,
+         0.0005683899, 0.03759766, -0.07226562],
+        [-0.5967165, 4.079811, 4.879762, 5.122957]),
+    "bucket_256": (
+        [-0.6679688, 0.3300781, -0.2519531, 0.05419922, -0.0625, -0.0177002,
+         0.08007812, -0.03271484],
+        [-0.5967165, 5.217459, 5.559694, 5.924831]),
+    "bucket_512": (
+        [-0.6679688, 0.05541992, -0.0625, -0.1044922, 0.1162109, -0.1196289,
+         0.1279297, -0.1865234],
+        [-0.5967165, 5.559694, 6.382701, 6.668979]),
+    "cross_length": (
+        [-0.04504188, -0.3304721, 0.2263153, 0.1282314, 0.1247439, 0.081916,
+         0.01881928, 0.07707193],
+        [5.334502, 5.908154, 5.766953, 5.962478]),
+    "d128_four_k_tiles": (
+        [-0.6679688, -0.078125, 0.1611328, 0.05053711, -0.05639648, -0.1630859,
+         -0.01953125, -0.08398438],
+        [-0.6982549, 6.241089, 5.574415, 6.748142]),
+    "gqa_group_d256": (
+        [-0.6679688, 0.1757812, -0.02612305, 0.04467773, 0.2451172, 0.08984375,
+         -0.2392578, -0.1591797],
+        [-0.6964874, 5.096807, 5.563406, 5.975451]),
+    "non_causal_keep_f32": (
+        [-0.1725049, -0.08803102, 0.09398368, -0.01346552, -0.04215935,
+         0.02492446, -0.1030271, 0.08762099],
+        [6.078001, 6.387012, 5.925965, 6.102642]),
+    "odd_heads_half_block": (
+        [-0.6679688, 0.06591797, 0.02575684, -0.1689453, -0.1044922, 0.1650391,
+         0.02368164, -0.04125977],
+        [-0.5967165, 6.59877, 6.616065, 6.620105]),
+    "q_longer_than_k": (
+        [0, 0, 0.001217768, -0.05473015, 0, 0, -0.1813978, -0.2656818],
+        [-1e+30, 4.813403, -1e+30, 5.255448]),
+    "ragged_300": (
+        [1.295636, 0.3069276, -0.07012283, 0.1180166, 0.08767437, 0.1687294,
+         0.1202324, 0.01905929],
+        [-0.6854866, 6.050971, 4.971652, 6.158551]),
+    "two_heads_a_block_1024": (
+        [-0.6679688, 0.05541992, 0.0390625, -0.04589844, 0.1162109, -0.1196289,
+         0.06494141, -0.07470703],
+        [-0.5967165, 7.013887, 6.382701, 7.389666]),
+}
+
+
+def _samples(x, n):
+    flat = np.asarray(x, np.float32).reshape(-1)
+    return flat[np.linspace(0, flat.size - 1, n).astype(int)]
+
+
+def _forward(case):
+    """(out [B, H, S, D] float32, lse [B, H, S], and the q, k, v they came
+    from, head-major) of a forward case, through `_flash_fwd` as the models
+    call it."""
+    kw, causal, blocks, seq_major, _ = _FWD_CASES[case]
+    q, k, v = _qkv(**kw)
+    blocks = blocks or (attention.DEFAULT_BLOCK_Q, attention.DEFAULT_BLOCK_K)
+    layout = (lambda x: jnp.swapaxes(x, 1, 2)) if seq_major else (lambda x: x)
+    out, (*_, lse) = attention._flash_fwd(
+        layout(q), layout(k), layout(v), causal, None, *blocks, True,
+        seq_major)
+    assert out.dtype == q.dtype and lse.dtype == jnp.float32
+    return np.asarray(layout(out), np.float32), np.asarray(lse), q, k, v
+
+
+def _reference(q, k, v, causal):
+    """(out, lse) in float32 of [B, H, S, D] inputs."""
+    f32 = lambda x: x.astype(jnp.float32)
+    group = q.shape[1] // k.shape[1]
+    s = jnp.einsum("bhqd,bhkd->bhqk", f32(q),
+                   jnp.repeat(f32(k), group, axis=1)) / np.sqrt(q.shape[-1])
+    if causal:
+        qi = jnp.arange(q.shape[2])[:, None] + k.shape[2] - q.shape[2]
+        s = jnp.where(jnp.arange(k.shape[2])[None, :] <= qi, s, -jnp.inf)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    out = attention_reference(f32(q), f32(k), f32(v), causal=causal)
+    return np.asarray(out), np.asarray(lse)
+
+
+@pytest.mark.parametrize("case", sorted(_FWD_CASES))
+def test_flash_fwd_out_and_lse(case):
+    """out and lse of the forward kernel against the float32 reference —
+    a padded row's real positions against its document alone — and against
+    the parent's kernel to one bf16 step."""
+    kw, causal, _, _, docs = _FWD_CASES[case]
+    out, lse, q, k, v = _forward(case)
+    bf16 = q.dtype == jnp.bfloat16
+    for b in range(q.shape[0]):
+        n = docs[b] if docs else q.shape[2]
+        nk = docs[b] if docs else k.shape[2]
+        want, want_lse = _reference(q[b:b + 1, :, :n], k[b:b + 1, :, :nk],
+                                    v[b:b + 1, :, :nk], causal)
+        live = np.isfinite(want_lse[0])      # a query before the first key
+        # one bf16 step at the largest value: p is rounded to bf16 for p.v
+        atol = 2.0 ** -8 * np.abs(want).max() if bf16 else 2e-5
+        np.testing.assert_allclose(out[b, :, :n][live], want[0][live],
+                                   atol=atol, err_msg=case)
+        # bf16: q is scaled in bf16, and 128 ** -0.5 is no power of two
+        np.testing.assert_allclose(lse[b, :, :n][live], want_lse[0][live],
+                                   atol=1e-2 if bf16 else 1e-5, rtol=1e-5,
+                                   err_msg=case)
+    parent_out, parent_lse = _FWD_PARENT[case]
+    step = 2.0 ** -7 if bf16 else 2.0 ** -20    # of a value in [1, 2)
+    np.testing.assert_allclose(_samples(out, 8), parent_out, rtol=step,
+                               atol=1e-6, err_msg=case)
+    np.testing.assert_allclose(_samples(lse, 4), parent_lse, rtol=1e-6,
+                               atol=1e-5, err_msg=case)
+
+
 # The backward pass alone, as `_flash_bwd` and ring attention call it: name:
 # (_qkv arguments, causal, blocks, seq_major, keep_f32, fused). `fused` says
 # which form `_bwd_pallas` picks at the shape: one kernel, `flash_bwd`,
@@ -365,7 +524,7 @@ def test_bwd_form_at_the_benchmark_cells_shapes(cell, chip, q_shape, k_shape,
     (512, 512, 512, 0.75, 1, 2),      # before PR 26: whole 512 tiles
     (1024, 128, 128, 0.5625, 28, 8),  # dkv's rectangles (transposed)
     (1024, 256, 256, 0.625, 6, 4),
-    (1024, 512, 512, 0.75, 1, 2),     # forward and dq as shipped
+    (1024, 512, 512, 0.75, 1, 2),     # dq as shipped, the forward till PR 45
 ])
 def test_chunk_classes_of_the_benchmark_cells(tile, sub, chunk, computed,
                                               interior, edge):
@@ -375,6 +534,54 @@ def test_chunk_classes_of_the_benchmark_cells(tile, sub, chunk, computed,
     assert got["dead"] + interior + edge == (1024 // sub) * (1024 // chunk)
     full = chunk_classes(1024, 1024, False, tile=tile, sub=sub, chunk=chunk)
     assert full["computed_share"] == 1.0 and full["edge"] == 0
+
+
+@pytest.mark.parametrize("seq,width,dead,interior,edge,computed", [
+    (128, 64, 0, 0, 1, 1.0),           # the served buckets: one rectangle,
+    (256, 64, 1, 1, 2, 0.75),          # then the diagonal's share falls
+    (512, 64, 6, 6, 4, 0.625),
+    (1024, 64, 28, 28, 8, 0.5625),     # GPT-2 (0.75 at 512 x 512 till PR 45)
+    (4096, 128, 496, 496, 32, 0.515625),       # OLMoE, four k tiles a row
+    (8192, 256, 2016, 2016, 64, 0.5078125),    # Qwen3-Next, eight
+])
+def test_forward_rectangles_at_the_cells_shapes(seq, width, dead, interior,
+                                                edge, computed,
+                                                monkeypatch):
+    """The rectangles the forward kernel walks at each (row length, head
+    width) the benchmark's cells run: 128 queries x 128 keys everywhere —
+    `_rect` cuts nothing at these widths, a [256, 128] float32 accumulator
+    is the 32 registers it may have — counted by `chunk_classes` at its
+    defaults, which are the forward's. The sizes follow static shapes alone:
+    the same for any batch, head count and layout."""
+    tile = min(seq, attention.DEFAULT_BLOCK_Q)
+    assert attention._rect(tile, tile, width, attention._FWD_RECT) == (
+        128, 128)
+    got = chunk_classes(seq, seq, True)
+    assert got == chunk_classes(seq, seq, True, sub=128, chunk=128)
+    assert got == {"dead": dead, "interior": interior, "edge": edge,
+                   "computed_share": computed}
+
+    seen = set()
+    kernel = attention._fwd_kernel
+
+    def recording(*refs, sub, chunk, **kw):
+        seen.add((sub, chunk))
+        return kernel(*refs, sub=sub, chunk=chunk, **kw)
+
+    monkeypatch.setattr(attention, "_fwd_kernel", recording)
+    for batch, heads, seq_major in ((1, 2, False), (3, 2, False),
+                                    (2, 4, True)):
+        if seq_major and width >= 128:
+            continue        # the models hand such a width over head-major
+        shape = ((batch, seq, heads, width) if seq_major
+                 else (batch, heads, seq, width))
+        x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+        jax.eval_shape(functools.partial(
+            _fwd_pallas, scale=1.0, causal=True,
+            block_q=attention.DEFAULT_BLOCK_Q,
+            block_k=attention.DEFAULT_BLOCK_K, interpret=True,
+            seq_major=seq_major), x, x, x)
+    assert seen == {(128, 128)}
 
 
 @pytest.mark.parametrize("q_len,k_len,rel", [
