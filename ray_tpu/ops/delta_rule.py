@@ -40,29 +40,26 @@ is the kernels on a TPU and the `jnp` form elsewhere):
   q, k, v and o are read and written as the projections hold them,
   [B, S, H * D]: a head is a block of lanes, so no transposed copy stands
   on either side. A step first makes what no state enters, for all its
-  chunks and heads, the solves level by level side by side (six or seven
-  dependent levels of small products each: alone, a solve leaves the matrix
-  unit waiting), then walks the chunks from state to state. The backward
-  kernel takes the blocks last to first with dS in scratch: it reads the
-  state that entered the block (the forward's residual, [B, Hv,
-  S / `_BLOCK`, Dk, Dv] float32, a quarter of what a checkpointed scan of
-  chunks of 64 keeps), walks the block's chunks forward again from it,
-  then backward, and writes dq, dk, dv rounded once and dG, dbeta in
-  float32; T = (I - A)^-1 has the closed derivative dA = T^T dT T^T. Each
-  kernel cuts the block into chunks of its own size (`_FWD_CHUNK`,
-  `_BWD_CHUNK`): the chunked form is exact at any, and only the states at
-  the blocks' edges pass from one to the other. The primal of the
-  `custom_vjp` is a forward that writes no states: under a block's
-  rematerialisation the first forward pays nothing for residuals it drops.
+  chunks and heads, the solves level by level side by side (six dependent
+  levels of small products: alone, a solve leaves the matrix unit waiting),
+  then walks the chunks from state to state. The matrix units take their
+  products in program order, so every chain of dependent products is
+  written across the heads, never head by head. The backward kernel takes
+  the blocks last to first with dS in scratch: it reads the state that
+  entered the block (the forward's residual, [B, Hv, S / `_BLOCK`, Dk, Dv]
+  float32, a quarter of what a checkpointed scan of chunks keeps), walks
+  the block's chunks forward again from it, then backward, and writes dq,
+  dk, dv rounded once and dG, dbeta in float32; T = (I - A)^-1 has the
+  closed derivative dA = T^T dT T^T. The `custom_vjp`'s primal is a forward
+  that writes no states: under a block's rematerialisation the first
+  forward pays nothing for residuals it drops.
 * `gated_delta_rule_reference`: `jnp` chunks under one checkpointed
   `lax.scan`, the reference the kernels are held to and the form every
   other backend and every width that is no multiple of the 128 lanes runs.
 
-On a v5e (PERF.md, PR 33): the `jnp` form is bound by the count of its small
-products and by the state crossing HBM three times a chunk; the kernels by
-the dependent small products of the solve and of the walk from state to
-state, which the matrix unit's depth and the lanes of a [64, 64] matrix
-half fill.
+On a v5e (PERF.md, PRs 33 and 46): the `jnp` form is bound by the count of
+its small products and by the state crossing HBM three times a chunk; the
+kernels by the matrix units' issue slots and, in the solves, by the spills.
 """
 
 from __future__ import annotations
@@ -78,21 +75,16 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-CHUNK = 64      # a power of two: the solve squares its way up to it
+# A power of two (the solve squares its way up to it), two of which fit the
+# matrix unit's depth; the `jnp` form's and both kernels' (on the v5e, PERF.md
+# PR 46: ms a call at 64 / 128, forward 4.76 / 5.41, backward 9.60 / 9.47).
+CHUNK = 64
 # What a grid step of the kernels holds, as straight-line code for the
 # scheduler to overlap: positions of the row (a chunk's solve waits for no
 # state, so the solves of a step go level by level together) and key heads
 # (their value heads' walks from state to state wait only for themselves).
 _BLOCK = 256
 _BLOCK_KEY_HEADS = 2
-# The kernels' chunks, each exact: the forward is mostly the solve, which
-# is cheapest at 64; the backward is mostly products and sums over [C, C]
-# and [C, d] matrices, which at 128 fill the lanes and the unit's depth
-# (swept on the v5e, PERF.md PR 33: backward 18.08 -> 15.46 ms a call at 128,
-# forward 6.95 against 8.50; the second size buys about 8 ms of a 976 ms
-# step, so one size is the fallback if the solve is rewritten).
-_FWD_CHUNK = 64
-_BWD_CHUNK = 128
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
@@ -206,49 +198,45 @@ _NT = (((1,), (1,)), ((), ()))      # a [m, d] x b [n, d] -> [m, n]
 _TN = (((0,), (0,)), ((), ()))      # a [n, m] x b [n, d] -> [m, d]
 
 
+def _split(a):
+    """The bf16 high and low parts of a float32 operand of the solve."""
+    high = a.astype(jnp.bfloat16)
+    return high, (a - high.astype(jnp.float32)).astype(jnp.bfloat16)
+
+
+def _mm_solve(a, b):
+    """a x b of split operands, a [., C], in three bf16 passes, of which
+    high x high and low x high are one product, [a_hi | a_lo] x [[b_hi],
+    [b_hi]]: the matrix unit sums them along its depth of 128 in float32."""
+    dot = functools.partial(lax.dot_general, dimension_numbers=_NN,
+                            preferred_element_type=jnp.float32)
+    return (dot(jnp.concatenate(a, axis=1), jnp.concatenate([b[0], b[0]]))
+            + dot(a[0], b[1]))
+
+
 class _Chunk:
-    """What the forward and the backward kernel both make of a block's
-    chunks before any state enters: the products, in the precision of the
-    module's docstring, and each value head's matrices. `dt` is the inputs'
-    dtype. Matrices over two positions are [t, j] (row t, lane j) unless
-    their name ends in `_t`."""
+    """What both kernels make of a block's chunks before any state enters:
+    the products, in the precision of the module's docstring, and each value
+    head's matrices. `dt` is the inputs' dtype. Matrices over two positions
+    are [t, j] (row t, lane j) unless their name ends in `_t`."""
 
     def __init__(self, c, dt):
-        self.c, self.dt = c, dt
-        self.exact = dt == jnp.float32
+        self.c, self.dt, self.exact = c, dt, dt == jnp.float32
         row = lax.broadcasted_iota(jnp.int32, (c, c), 0)
         lane = lax.broadcasted_iota(jnp.int32, (c, c), 1)
         self.eye, self.lower, self.strict = row == lane, row >= lane, row > lane
         self.upper, self.strict_upper = row <= lane, row < lane
         self.left = lax.broadcasted_iota(jnp.int32, (c, 2 * c), 1) < c
+        self.split = (lambda a: (a,)) if self.exact else _split
+        self.mm_solve = ((lambda a, b: self.mm(a[0], b[0])) if self.exact
+                         else _mm_solve)
 
     def mm(self, a, b, dims=_NN):
         """Operands in the inputs' dtype, float32 accumulation."""
-        return lax.dot_general(
-            a.astype(self.dt), b.astype(self.dt), dims,
-            precision=lax.Precision.HIGHEST if self.exact else None,
-            preferred_element_type=jnp.float32)
-
-    def split(self, a):
-        """A float32 operand of the solve's products: itself beside float32
-        inputs, else its bf16 high and low parts."""
-        if self.exact:
-            return (a,)
-        high = a.astype(jnp.bfloat16)
-        return high, (a - high.astype(jnp.float32)).astype(jnp.bfloat16)
-
-    def mm_solve(self, a, b):
-        """a x b of split operands: at full precision beside float32
-        inputs, else three bf16 passes (high x high, high x low, low x
-        high)."""
-        if self.exact:
-            return self.mm(a[0], b[0])
-
-        def dot(x, y):
-            return lax.dot_general(x, y, _NN,
-                                   preferred_element_type=jnp.float32)
-
-        return dot(a[0], b[0]) + (dot(a[0], b[1]) + dot(a[1], b[0]))
+        precision = lax.Precision.HIGHEST if self.exact else None
+        return lax.dot_general(a.astype(self.dt), b.astype(self.dt), dims,
+                               precision=precision,
+                               preferred_element_type=jnp.float32)
 
     def col(self, row):
         """[1, C] -> [C, 1] (positions from lanes to sublanes)."""
@@ -258,87 +246,90 @@ class _Chunk:
         """[C, 1] -> [1, C]."""
         return jnp.sum(jnp.where(self.eye, col, 0.0), axis=0, keepdims=True)
 
-    def inverses_t(self, a_ts):
-        """((I - a)^-1)^T for each a^T of the list, as `_unit_lower_inverse`
-        makes it, transposed: a power's square and the inverse's next factor
-        share their left operand, so a level is one product against
-        [inverse^T | power^T], and the list's matrices go level by level
-        together, which is what lets the scheduler fill one's waits with
-        another's work."""
-        c = self.c
-        powers = [self.split(a_t) for a_t in a_ts]
-        powers = [self.mm_solve(p, p) for p in powers]
-        both = [jnp.concatenate([jnp.where(self.eye, 1.0, a_t), p], axis=1)
-                for a_t, p in zip(a_ts, powers)]
-        for _ in range(c.bit_length() - 3):
-            steps = [self.mm_solve(self.split(p), self.split(z))
-                     for p, z in zip(powers, both)]
-            both = [jnp.where(self.left, z + s, s)
-                    for z, s in zip(both, steps)]
-            powers = [z[:, c:] for z in both]
-        return [z[:, :c] + self.mm_solve(self.split(p), self.split(z[:, :c]))
-                for p, z in zip(powers, both)]
-
     def heads(self, q_ref, k_ref, v_ref, g_ref, beta_ref, key_heads):
-        """Every chunk of the block and every value head of its key heads,
-        chunk-major: q, k: [m C, key_heads Dk] refs; v: [m C, Hv Dv]; g (G),
-        beta: [Hv, m, C], Hv the value heads of the block's key heads."""
+        """The block's chunks, first to last, each the list of its value
+        heads: q, k: [m C, key_heads Dk] refs; v: [m C, Hv Dv]; g (G), beta:
+        [Hv, m, C], Hv the value heads of the block's key heads."""
         f32, dt, c = jnp.float32, self.dt, self.c
         repeat = g_ref.shape[0] // key_heads
         dk, dv = q_ref.shape[1] // key_heads, v_ref.shape[1] // g_ref.shape[0]
-        heads = []
-        for i, j in itertools.product(range(q_ref.shape[0] // c),
-                                      range(key_heads)):
+        heads, chunks = [], range(q_ref.shape[0] // c)
+        for i, j in itertools.product(chunks, range(key_heads)):
             rows, key_cols = slice(i * c, (i + 1) * c), slice(j * dk,
                                                               (j + 1) * dk)
             q, k = q_ref[rows, key_cols], k_ref[rows, key_cols]
             qf, kf = q.astype(f32), k.astype(f32)
             kk, qk = self.mm(k, k, _NT), self.mm(q, k, _NT)
             for r in range(j * repeat, (j + 1) * repeat):
-                h = types.SimpleNamespace(
-                    i=i, r=r, rows=rows, key_cols=key_cols,
-                    cols=slice(r * dv, (r + 1) * dv),
+                cols = slice(r * dv, (r + 1) * dv)
+                heads.append(types.SimpleNamespace(
+                    i=i, r=r, rows=rows, key_cols=key_cols, cols=cols,
                     last_of_key=r + 1 == (j + 1) * repeat,
-                    q=q, k=k, qf=qf, kf=kf, kk=kk, qk=qk)
-                h.v = v_ref[rows, h.cols]
-                total, beta = g_ref[r, i:i + 1, :], beta_ref[r, i:i + 1, :]
-                total_c, h.beta_c = self.col(total), self.col(beta)
-                h.decay = jnp.exp(jnp.where(self.lower, total_c - total,
-                                            -jnp.inf))
-                decay_t = jnp.exp(jnp.where(self.upper, total - total_c,
-                                            -jnp.inf))
-                h.a_t = jnp.where(self.strict_upper, -beta * kk * decay_t,
-                                  0.0)
-                h.e_total = jnp.exp(total_c)                      # [C, 1]
-                h.k_gain = h.beta_c * h.e_total
-                # beta V and beta e^G K side by side: U, W are one product
-                h.vk = jnp.concatenate(
-                    [(h.beta_c * h.v.astype(f32)).astype(dt),
-                     (h.k_gain * kf).astype(dt)], axis=1)
-                h.qg = (h.e_total * qf).astype(dt)
-                h.scores = qk * h.decay
-                last = total_c[c - 1:c]                           # [1, 1]
-                h.e_last = jnp.exp(last)
-                h.k_left = jnp.exp(last - total_c)                # e^{G_C-G}
-                h.k_decayed = (h.k_left * kf).astype(dt)
-                heads.append(h)
-        for h, solve_t in zip(heads, self.inverses_t([h.a_t for h in heads])):
+                    q=q, k=k, qf=qf, kf=kf, kk=kk, qk=qk, **_matrices(
+                        kk, qk, qf, kf, v_ref[rows, cols],
+                        g_ref[r, i:i + 1, :], beta_ref[r, i:i + 1, :], dt=dt)))
+        solves = _inverses_t([h.a_t for h in heads], dt=dt)
+        for h, solve_t in zip(heads, solves):
             h.solve_t = solve_t
             uw = self.mm(solve_t, h.vk, _TN)
             h.u, h.w = uw[:, :dv], uw[:, dv:].astype(dt)
-        return heads
+        return [[h for h in heads if h.i == i] for i in chunks]
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest, chunk,
+@functools.partial(jax.jit, static_argnames="dt")
+def _inverses_t(a_ts, *, dt):
+    """((I - a)^-1)^T for each a^T of the list, as `_unit_lower_inverse`
+    makes it, transposed: a power's square and the inverse's next factor
+    share their left operand, so a level is one product against
+    [inverse^T | power^T], and the list's matrices go level by level
+    together: the scheduler fills one's waits with another's work. Jitted,
+    as `_matrices`, for the trace's sake: the three kernels' traces share
+    one of it, and an operation written out costs a busy worker 0.8 ms."""
+    c = a_ts[0].shape[0]
+    self = _Chunk(c, dt)
+    powers = [self.split(a_t) for a_t in a_ts]
+    powers = [self.mm_solve(p, p) for p in powers]
+    both = [jnp.concatenate([jnp.where(self.eye, 1.0, a_t), p], axis=1)
+            for a_t, p in zip(a_ts, powers)]
+    for _ in range(c.bit_length() - 3):
+        steps = [self.mm_solve(self.split(p), self.split(z))
+                 for p, z in zip(powers, both)]
+        both = [jnp.where(self.left, z + s, s) for z, s in zip(both, steps)]
+        powers = [z[:, c:] for z in both]
+    return [z[:, :c] + self.mm_solve(self.split(p), self.split(z[:, :c]))
+            for p, z in zip(powers, both)]
+
+
+@functools.partial(jax.jit, static_argnames="dt")
+def _matrices(kk, qk, qf, kf, v, total, beta, *, dt):
+    """A value head's matrices of one chunk; total (G), beta: [1, C]."""
+    self = _Chunk(total.shape[1], dt)
+    total_c, beta_c = self.col(total), self.col(beta)
+    decay = jnp.exp(jnp.where(self.lower, total_c - total, -jnp.inf))
+    decay_t = jnp.exp(jnp.where(self.upper, total - total_c, -jnp.inf))
+    e_total = jnp.exp(total_c)                                  # [C, 1]
+    k_gain = beta_c * e_total
+    last = total_c[-1:]                                         # [1, 1]
+    k_left = jnp.exp(last - total_c)                            # e^{G_C-G}
+    return dict(
+        v=v, beta_c=beta_c, decay=decay, e_total=e_total, k_gain=k_gain,
+        k_left=k_left, e_last=jnp.exp(last),
+        a_t=jnp.where(self.strict_upper, -beta * kk * decay_t, 0.0),
+        # beta V and beta e^G K side by side: U, W are one product
+        vk=jnp.concatenate([(beta_c * v.astype(jnp.float32)).astype(dt),
+                            (k_gain * kf).astype(dt)], axis=1),
+        qg=(e_total * qf).astype(dt), scores=qk * decay,
+        k_decayed=(k_left * kf).astype(dt))
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
                 key_heads):
     """One batch row, `key_heads` key heads with their Hv value heads, `m`
     chunks of the row. q, k: [m C, key_heads Dk]; v, o: [m C, Hv Dv]; g (G,
-    summed from each chunk's start), beta: [Hv, m, C]; states (only the
-    `fwd` rule's call): [Hv, Dk, Dv], the state that entered the block;
-    scratch: [Hv, Dk, Dv]."""
-    state_ref = rest[-1]
-    states_ref = rest[0] if len(rest) > 1 else None
-    ch = _Chunk(chunk, q_ref.dtype)
+    summed from each chunk's start), beta: [Hv, m, C]; states (the `fwd`
+    rule's call only), scratch: [Hv, Dk, Dv], the state entering the block."""
+    state_ref, states_ref = rest[-1], rest[0] if len(rest) > 1 else None
+    ch = _Chunk(CHUNK, q_ref.dtype)
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
@@ -346,27 +337,29 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest, chunk,
 
     if states_ref is not None:
         states_ref[...] = state_ref[...]
-    for h in ch.heads(q_ref, k_ref, v_ref, g_ref, beta_ref, key_heads):
-        state = state_ref[h.r]
-        # W S and (e^G Q) S as one product: the state is pushed once
-        from_state = ch.mm(jnp.concatenate([h.w, h.qg], 0), state)
-        delta = (h.u - from_state[:chunk]).astype(ch.dt)
-        o_ref[h.rows, h.cols] = (
-            from_state[chunk:] + ch.mm(h.scores, delta)).astype(o_ref.dtype)
-        state_ref[h.r] = h.e_last * state + ch.mm(h.k_decayed, delta, _TN)
+    for heads in ch.heads(q_ref, k_ref, v_ref, g_ref, beta_ref, key_heads):
+        for h in heads:
+            h.state = state_ref[h.r]
+            # W S and (e^G Q) S as one product: the state is pushed once
+            h.from_state = ch.mm(jnp.concatenate([h.w, h.qg], 0), h.state)
+        for h in heads:
+            h.delta = (h.u - h.from_state[:CHUNK]).astype(ch.dt)
+            state_ref[h.r] = h.e_last * h.state + ch.mm(h.k_decayed, h.delta,
+                                                        _TN)
+        for h in heads:     # after what the next chunk waits for
+            o_ref[h.rows, h.cols] = (h.from_state[CHUNK:] + ch.mm(
+                h.scores, h.delta)).astype(o_ref.dtype)
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
                 dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dstate_ref,
-                walk_ref, *, chunk, key_heads):
+                walk_ref, *, key_heads):
     """The same blocks, taken last to first. do, dv: [m C, Hv Dv]; dq, dk:
     [m C, key_heads Dk], summed over a key head's value heads; dg (with
-    respect to G), dbeta: [Hv, m, C] float32; scratch: dS, [Hv, Dk, Dv]
-    float32, the gradient of the state that leaves the chunk, and the
-    states that entered the block's chunks, [Hv, m, Dk, Dv], walked again
-    from the one the forward kept."""
-    f32, c = jnp.float32, chunk
-    ch = _Chunk(chunk, q_ref.dtype)
+    respect to G), dbeta: [Hv, m, C] float32; scratch: dS [Hv, Dk, Dv]
+    float32, of the state that leaves the chunk, and the states that entered
+    the block's chunks, [Hv, m, Dk, Dv], walked again from the forward's."""
+    f32, c, ch = jnp.float32, CHUNK, _Chunk(CHUNK, q_ref.dtype)
     mm, dt = ch.mm, ch.dt
     at_last = lax.broadcasted_iota(jnp.int32, (1, c), 1) == c - 1
 
@@ -377,47 +370,57 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
     def _init():
         dstate_ref[...] = jnp.zeros_like(dstate_ref)
 
-    heads = ch.heads(q_ref, k_ref, v_ref, g_ref, beta_ref, key_heads)
+    chunks = ch.heads(q_ref, k_ref, v_ref, g_ref, beta_ref, key_heads)
+    heads = [h for chunk_heads in chunks for h in chunk_heads]
     walk_ref[:, 0] = states_ref[...]
-    for h in heads:     # the forward's walk from the block's first state
-        state = walk_ref[h.r, h.i]
-        h.state_dt = state.astype(dt)
-        h.delta = (h.u - mm(h.w, h.state_dt)).astype(dt)
-        if h.i + 1 < walk_ref.shape[1]:
-            walk_ref[h.r, h.i + 1] = h.e_last * state + mm(
-                h.k_decayed, h.delta, _TN)
+    for chunk_heads in chunks:      # the forward's walk from the first state
+        for h in chunk_heads:
+            h.state = walk_ref[h.r, h.i]
+            h.state_dt = h.state.astype(dt)
+            h.delta = (h.u - mm(h.w, h.state_dt)).astype(dt)
+        if chunk_heads is not chunks[-1]:
+            for h in chunk_heads:
+                walk_ref[h.r, h.i + 1] = h.e_last * h.state + mm(
+                    h.k_decayed, h.delta, _TN)
     # what dS enters, last chunk first: O = (e^G Q) S + scores delta and
     # S_C = e^{G_C} S + k_decayed^T delta, with delta = U - W S
-    for h in reversed(heads):
-        h.do = do_ref[h.rows, h.cols]
-        dstate = dstate_ref[h.r]
-        dstate_dt = dstate.astype(dt)
-        h.d_delta = (mm(h.scores, h.do, _TN)
-                     + mm(h.k_decayed, dstate_dt)).astype(dt)
-        h.d_k_decayed = mm(h.delta, dstate_dt, _NT)
-        h.d_e_last = jnp.sum(lanes(dstate * walk_ref[h.r, h.i]), axis=0,
-                             keepdims=True)
-        dstate_ref[h.r] = h.e_last * dstate + mm(
-            jnp.concatenate([h.qg, h.w], 0),
-            jnp.concatenate([h.do, -h.d_delta], 0), _TN)
+    for chunk_heads in reversed(chunks):
+        for h in chunk_heads:
+            h.do = do_ref[h.rows, h.cols]
+            h.d_delta = mm(h.scores, h.do, _TN)
+        for h in chunk_heads:
+            h.dstate = dstate_ref[h.r]
+            h.dstate_dt = h.dstate.astype(dt)
+            h.d_delta = (h.d_delta + mm(h.k_decayed, h.dstate_dt)).astype(dt)
+        for h in chunk_heads:
+            dstate_ref[h.r] = h.e_last * h.dstate + mm(
+                jnp.concatenate([h.qg, h.w], 0),
+                jnp.concatenate([h.do, -h.d_delta], 0), _TN)
+        for h in chunk_heads:       # off the chain
+            h.d_k_decayed = mm(h.delta, h.dstate_dt, _NT)
+            h.d_e_last = jnp.sum(lanes(h.dstate * walk_ref[h.r, h.i]), axis=0,
+                                 keepdims=True)
     # the rest waits for no other chunk
-    dq = dk = jnp.zeros(heads[0].q.shape, f32)
     for h in heads:
-        qf, kf = h.qf, h.kf
-        d_scores = mm(h.do, h.delta, _NT)
-        d_qg = mm(h.do, h.state_dt, _NT)
+        h.d_scores = mm(h.do, h.delta, _NT)
+        h.d_qg = mm(h.do, h.state_dt, _NT)
         d_w = -mm(h.d_delta, h.state_dt, _NT)
+        h.d_uw = jnp.concatenate([h.d_delta, d_w.astype(dt)], axis=1)
+    # U, W = T [beta V, beta e^G K]; dA = T^T dT T^T
+    for h in heads:
+        h.d_vk = mm(h.solve_t, h.d_uw)
+        h.d_solve = ch.split(mm(h.d_uw, h.vk, _NT))
+        h.solve_split = ch.split(h.solve_t)
+    d_as = [ch.split(ch.mm_solve(h.solve_split, h.d_solve)) for h in heads]
+    d_as = [ch.mm_solve(d_a, h.solve_split) for d_a, h in zip(d_as, heads)]
+    @jax.jit    # sixteen calls, one trace
+    def rest(h, d_a, dq, dk):
+        h = types.SimpleNamespace(**h)
+        qf, kf, d_scores, d_qg, d_vk = h.qf, h.kf, h.d_scores, h.d_qg, h.d_vk
         left = lanes(h.d_k_decayed * h.k_left * kf)     # d(G_C - G_t)
         d_last = h.e_last * h.d_e_last + jnp.sum(left, axis=0, keepdims=True)
-        # U, W = T [beta V, beta e^G K]; dA = T^T dT T^T
-        d_uw = jnp.concatenate([h.d_delta, d_w.astype(dt)], axis=1)
-        d_vk = mm(h.solve_t, d_uw)
         dv = h.v.shape[1]
         d_vb, d_kb = d_vk[:, :dv], d_vk[:, dv:]
-        solve_t = ch.split(h.solve_t)
-        d_a = ch.mm_solve(
-            ch.split(ch.mm_solve(solve_t, ch.split(mm(d_uw, h.vk, _NT)))),
-            solve_t)
         # A = -beta K K^T decay below the diagonal
         through = jnp.where(ch.strict, d_a, 0.0) * h.decay
         d_kk = -h.beta_c * through
@@ -434,16 +437,21 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
         dq = dq + h.e_total * d_qg + mm(d_qk, h.k)
         dk = (dk + h.k_left * h.d_k_decayed + h.k_gain * d_kb
               + mm(d_kk, h.k) + mm(d_kk, h.k, _TN) + mm(d_qk, h.q, _TN))
-        dv_ref[h.rows, h.cols] = (h.beta_c * d_vb).astype(dv_ref.dtype)
-        dg_ref[h.r, h.i:h.i + 1, :] = d_total
-        dbeta_ref[h.r, h.i:h.i + 1, :] = ch.row(d_beta_c)
+        return (dq, dk, (h.beta_c * d_vb).astype(dv_ref.dtype), d_total,
+                ch.row(d_beta_c))
+
+    dq = dk = jnp.zeros(heads[0].q.shape, f32)
+    for h, d_a in zip(heads, d_as):
+        held = {n: x for n, x in vars(h).items() if isinstance(x, jax.Array)}
+        (dq, dk, dv_ref[h.rows, h.cols], dg_ref[h.r, h.i:h.i + 1, :],
+         dbeta_ref[h.r, h.i:h.i + 1, :]) = rest(held, d_a, dq, dk)
         if h.last_of_key:
             dq_ref[h.rows, h.key_cols] = dq.astype(dq_ref.dtype)
             dk_ref[h.rows, h.key_cols] = dk.astype(dk_ref.dtype)
             dq = dk = jnp.zeros_like(dq)
 
 
-def _specs(q, v, chunk, reverse):
+def _specs(q, v, reverse):
     """One call's tiling: the grid (batch, block of key heads, block of m
     chunks), the key heads a step, block specs by kind of array — rows of
     keys [B, S, Hk Dk], rows of values [B, S, Hv Dv], `gates` (g and beta)
@@ -452,7 +460,7 @@ def _specs(q, v, chunk, reverse):
     `reverse` walks the blocks last to first."""
     b, s, hk, dk = q.shape
     hv, dv = v.shape[2:]
-    m = min(_BLOCK, s) // chunk
+    chunk, m = CHUNK, min(_BLOCK, s) // CHUNK
     steps = s // (m * chunk)
     key_heads = math.gcd(_BLOCK_KEY_HEADS, hk)
     here = key_heads * hv // hk         # value heads a step
@@ -481,15 +489,13 @@ _SEQUENTIAL = pltpu.CompilerParams(
 
 
 # The kernels' bodies are some thousands of operations written out: under
-# `jax.jit` a model's layers, and its loss and its evaluation, trace them
-# once between them (a second of Python a trace, several in a busy worker).
-@functools.partial(jax.jit,
-                   static_argnames=("chunk", "interpret", "with_states"))
-def _fwd_pallas(q, k, v, total, beta, *, chunk, interpret, with_states):
+# `jax.jit` a model's layers, its loss and its evaluation trace them once.
+@functools.partial(jax.jit, static_argnames=("interpret", "with_states"))
+def _fwd_pallas(q, k, v, total, beta, *, interpret, with_states):
     """q, k: [B, S, Hk, Dk]; v: [B, S, Hv, Dv]; total, beta: [B, Hv, S]
     float32; S a multiple of the block. Returns o [B, S, Hv, Dv] and, with
     `with_states`, the states that entered the blocks."""
-    t = _specs(q, v, chunk, False)
+    t = _specs(q, v, False)
     specs = t.specs
     b, s = q.shape[:2]
     out_specs = [specs["values"]]
@@ -499,7 +505,7 @@ def _fwd_pallas(q, k, v, total, beta, *, chunk, interpret, with_states):
         out_specs.append(specs["states"])
         out_shape.append(jax.ShapeDtypeStruct(t.states, jnp.float32))
     out = pl.pallas_call(
-        functools.partial(_fwd_kernel, chunk=chunk, key_heads=t.key_heads),
+        functools.partial(_fwd_kernel, key_heads=t.key_heads),
         grid=t.grid,
         in_specs=[specs["keys"], specs["keys"], specs["values"],
                   specs["gates"], specs["gates"]],
@@ -512,17 +518,17 @@ def _fwd_pallas(q, k, v, total, beta, *, chunk, interpret, with_states):
     return (out[0].reshape(v.shape), *out[1:])
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def _bwd_pallas(q, k, v, total, beta, states, do, *, chunk, interpret):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _bwd_pallas(q, k, v, total, beta, states, do, *, interpret):
     """The gradients of q, k, v (their dtypes) and of total and beta
     (float32), from the states the forward kept and dO [B, S, Hv, Dv]."""
-    t = _specs(q, v, chunk, True)
+    t = _specs(q, v, True)
     specs = t.specs
     b, s = q.shape[:2]
     keys = jax.ShapeDtypeStruct((b, s, q.shape[2] * q.shape[3]), q.dtype)
     gate = jax.ShapeDtypeStruct(t.gates, jnp.float32)
     dq, dk, dv, dtotal, dbeta = pl.pallas_call(
-        functools.partial(_bwd_kernel, chunk=chunk, key_heads=t.key_heads),
+        functools.partial(_bwd_kernel, key_heads=t.key_heads),
         grid=t.grid,
         in_specs=[specs["keys"], specs["keys"], specs["values"],
                   specs["gates"], specs["gates"], specs["states"],
@@ -554,11 +560,10 @@ def _scoped(fn):
     return scoped
 
 
-def _chunk_sums(g, chunk, reverse=False):
+def _chunk_sums(g, reverse=False):
     """g [B, Hv, S] summed from each chunk's start to each position (G) or,
-    with `reverse`, from each position to its chunk's end (what G's
-    gradient owes g)."""
-    chunks = g.reshape(*g.shape[:2], -1, chunk)
+    with `reverse`, from each position to its chunk's end (G's gradient)."""
+    chunks = g.reshape(*g.shape[:2], -1, CHUNK)
     if reverse:
         chunks = jnp.flip(chunks, -1)
     sums = jnp.cumsum(chunks, axis=-1)
@@ -568,36 +573,31 @@ def _chunk_sums(g, chunk, reverse=False):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def _rule(q, k, v, g, beta, interpret):
     """q, k, v: [B, S, H, D]; g, beta: [B, Hv, S] float32; S whole blocks."""
-    return _fwd_pallas(q, k, v, _chunk_sums(g, _FWD_CHUNK), beta,
-                       chunk=_FWD_CHUNK, interpret=interpret,
+    return _fwd_pallas(q, k, v, _chunk_sums(g), beta, interpret=interpret,
                        with_states=False)[0]
 
 
 @_scoped
 def _rule_fwd(q, k, v, g, beta, interpret):
-    out, states = _fwd_pallas(q, k, v, _chunk_sums(g, _FWD_CHUNK), beta,
-                              chunk=_FWD_CHUNK, interpret=interpret,
+    total = _chunk_sums(g)
+    out, states = _fwd_pallas(q, k, v, total, beta, interpret=interpret,
                               with_states=True)
-    return out, (q, k, v, g, beta, states)
+    return out, (q, k, v, total, beta, states)
 
 
 @_scoped
 def _rule_bwd(interpret, residuals, do):
-    q, k, v, g, beta, states = residuals
-    *grads, d_total, d_beta = _bwd_pallas(
-        q, k, v, _chunk_sums(g, _BWD_CHUNK), beta, states, do,
-        chunk=_BWD_CHUNK, interpret=interpret)
-    return (*grads, _chunk_sums(d_total, _BWD_CHUNK, reverse=True), d_beta)
+    *grads, d_total, d_beta = _bwd_pallas(*residuals, do, interpret=interpret)
+    return (*grads, _chunk_sums(d_total, reverse=True), d_beta)
 
 
 _rule.defvjp(_rule_fwd, _rule_bwd)
 
 
 def _gated_delta_rule_pallas(q, k, v, g, beta, *, interpret):
-    """Rows padded to whole blocks of whole chunks of either kernel, g and
-    beta as [B, Hv, S] float32 rows."""
+    """Rows padded to whole blocks, g and beta as [B, Hv, S] float32 rows."""
     s = q.shape[1]
-    pad = -s % max(_FWD_CHUNK, _BWD_CHUNK)
+    pad = -s % CHUNK
     pad += -(s + pad) % min(_BLOCK, s + pad)
 
     def padded(x):

@@ -351,6 +351,85 @@ def test_delta_rule_kernels_carry_the_state_across_grid_steps():
                      ) <= 0.02 * largest, name
 
 
+def _solve_inputs(c, rate, width, seed):
+    """a^T as `_Chunk.heads` makes it of a chunk of c positions: strictly
+    upper, -beta_t k_t.k_j e^{G_t - G_j} at [j, t]."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    k = jax.random.normal(keys[0], (c, width))
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    total = jnp.cumsum(-rate * jax.nn.softplus(jax.random.normal(keys[1],
+                                                                 (c,))))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[2], (c,)))
+    upper = jnp.arange(c)[:, None] < jnp.arange(c)[None, :]
+    return jnp.where(upper, -beta[None, :] * (k @ k.T) * jnp.exp(
+        jnp.where(upper, total[None, :] - total[:, None], 0.0)), 0.0)
+
+
+def _three_dot_inverses_t(a_ts):
+    """The solve as the kernels made it until PR 46, the yardstick of the
+    bf16 branch: (I + a)(I + a^2)(I + a^4)..., each product three separate
+    bf16 passes summed in float32."""
+    def split(a):
+        high = a.astype(jnp.bfloat16)
+        return high, (a - high.astype(jnp.float32)).astype(jnp.bfloat16)
+
+    def mm(a, b):
+        (a0, a1), (b0, b1) = split(a), split(b)
+
+        def dot(x, y):
+            return jnp.matmul(x, y, preferred_element_type=jnp.float32)
+
+        return dot(a0, b0) + (dot(a0, b1) + dot(a1, b0))
+
+    inverses = []
+    for a_t in a_ts:
+        c = a_t.shape[0]
+        inverse, power = jnp.eye(c) + a_t, a_t
+        for _ in range(c.bit_length() - 2):
+            power = mm(power, power)
+            inverse = inverse + mm(power, inverse)
+        inverses.append(inverse)
+    return inverses
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("c", [32, 64])
+def test_delta_rule_solve_matches_the_inverse(c, dtype):
+    """`_inverses_t`, the kernels' (I - a)^-1 transposed, of three
+    chunks side by side (one of decays near 1 and narrow keys, so that T is
+    far from I) against numpy's inverse in float64, as a share of its
+    largest entry, at the kernels' chunk and at half of it. Beside float32
+    inputs it is float32's; beside bf16 inputs (three bf16 passes a
+    product, two of them summed along the matrix unit's depth) no further
+    off than the three separate passes that it replaced, or than float32's
+    limit where both are inside it: the two differ in the order of three
+    sums, by 1.3% at most here, and a tenth is allowed. (A chunk of 128,
+    the backward kernel's until PR 46, reads 1.8e-4 on the third input by
+    the yardstick, and 8.4e-6 in float32: the squares up to a^64 lose what
+    a chunk of 64 keeps.)"""
+    from ray_tpu.ops.delta_rule import _inverses_t
+
+    a_ts = [_solve_inputs(c, rate, width, seed)
+            for seed, (rate, width) in enumerate([(1.0, 128), (0.1, 32),
+                                                  (1e-3, 12)])]
+    wants = [np.linalg.inv(np.eye(c) - np.asarray(a_t, np.float64))
+             for a_t in a_ts]
+
+    def errors(gots):
+        return [float(np.max(np.abs(np.asarray(got, np.float64) - want))
+                      / np.max(np.abs(want)))
+                for got, want in zip(gots, wants)]
+
+    with jax.default_matmul_precision(
+            "highest" if dtype == "float32" else "default"):
+        got = errors(_inverses_t(a_ts, dt=jnp.dtype(dtype)))
+        was = errors(_three_dot_inverses_t(a_ts))
+    assert np.max(np.abs(wants[2] - np.eye(c))) > 0.5      # far from I
+    for new, old in zip(got, was):
+        assert new <= (2e-6 if dtype == "float32"
+                       else max(1.1 * old, 2e-6)), (got, was)
+
+
 # The Gated DeltaNet layer's passes around the rule (`ops/gated_deltanet.py`),
 # with `_ROWS` set to 64: (row length, key heads, key width, value heads,
 # value width).
