@@ -5,9 +5,10 @@ lifecycle is per *host* (4 chips per host). The reference has no
 equivalent (its unit is one process per GPU with NCCL groups); here a
 ``MeshGroup`` is a placement-group gang of host actors driven in
 lockstep: every ``run()`` invokes the same method on every host actor
-concurrently, which is exactly the multi-controller JAX model
-(`jax.distributed` — every host runs the same program, XLA runs the
-collectives over ICI/DCN).
+concurrently, the shape of the multi-controller JAX model (every host
+runs the same program). Each actor's JAX sees its own host's devices
+only: nothing here initialises `jax.distributed`, so no program spans
+hosts yet.
 
 On a single-host dev box (or CPU tests) each actor simply owns the local
 devices; the lockstep structure is identical, so code written against

@@ -18,6 +18,13 @@ the period ("full",): its weights are stacked [L, ...] under
 [periods, layers of that kind in a period, ...] under
 `params["blocks"][kind]`.
 
+A kind's weights are declared once, below `GPTConfig`: `_MIXERS` and
+`_FFNS` give each kind of mixer and of FFN the function of the config that
+lists its weights (shape, logical axes, how each starts) beside the method
+of `GPT` that uses them. `GPT.init`, `GPT.param_logical_axes` and
+`GPTConfig.n_params` are walks over those lists, `GPT._block` looks its two
+halves up in the same tables, and `LAYER_KINDS` is the mixers' keys.
+
 TPU-first choices:
   * scan-over-periods with stacked params — one compiled body a period,
     compile time O(1) in depth, and GSPMD gathers FSDP-sharded weights
@@ -36,7 +43,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Any, Dict, Optional, Tuple, Union
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -52,8 +59,6 @@ from ..parallel.sharding import (DEFAULT_RULES, ShardingRules,
                                  with_logical_constraint)
 
 Params = Dict[str, Any]
-
-LAYER_KINDS = ("full", "linear")
 
 # What `remat_policy="dots"` keeps of a block for the backward pass
 # (`GPTConfig.remat_policy`): the names `_block` gives its projections, and
@@ -192,25 +197,16 @@ class GPTConfig:
 
     @property
     def n_params(self) -> int:
-        """Approximate parameter count here (excludes norms/bias)."""
-        d, f, v = self.d_model, self.ff_dim, self.vocab_size
-        hd, h, hk = self.head_dim, self.n_heads, self.kv_heads
-        full = ((2 if self.attn_gate else 1) * d * h * hd + 2 * d * hk * hd
-                + h * hd * d)
-        keys = self.linear_key_heads * self.linear_key_dim
-        values = self.linear_value_heads * self.linear_value_dim
-        linear = (d * 2 * (keys + values) + 2 * d * self.linear_value_heads
-                  + self.linear_conv * (2 * keys + values) + values * d)
-        if self.n_experts > 0:
-            mlp = (self.experts_held * 3 * d * f + d * self.n_experts
-                   + 3 * d * self.moe_shared_ff)
-        else:
-            mlp = (3 if self.activation == "swiglu" else 2) * d * f
-        emb = v * d * (1 if self.tie_embeddings else 2)
-        mixers = sum(full if kind == "full" else linear
-                     for kind in self.layer_pattern)
-        return (self.n_layers // len(self.layer_pattern)
-                * (mixers + len(self.layer_pattern) * mlp) + emb)
+        """Approximate parameter count here: the weights that start as a
+        normal draw (the matrices; norms, biases and decay rates are left
+        out), bar the learned positions."""
+        def drawn(weights):
+            return sum(math.prod(w.shape) for name, w in weights.items()
+                       if isinstance(w.start, float) and name != "pos_embed")
+
+        periods = self.n_layers // len(self.layer_pattern)
+        return drawn(_model_weights(self)) + periods * sum(
+            drawn(_layer_weights(self, kind)) for kind in self.layer_pattern)
 
 
 # --- presets ---------------------------------------------------------------
@@ -236,10 +232,169 @@ def llama_tiny(**kw) -> GPTConfig:
                   vocab_size=512, max_seq_len=256, **kw)
 
 
-# --- init ------------------------------------------------------------------
+# --- the kinds of layer: their weights, declared once -----------------------
 
-def _normal(key, shape, std, dtype):
-    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+_STD = 0.02
+# `init` splits its key in twelve for a kind's layers (7..9 are the model's
+# own) and the twelfth in eight more: a weight's `key` counts through both
+_EXTRA = 12
+
+
+@dataclasses.dataclass(frozen=True)
+class _Weight:
+    """One weight of a layer (or of the model), without the stacking axes."""
+    shape: Tuple[int, ...]
+    axes: Tuple[Optional[str], ...]     # the logical axis of each dimension
+    # "ones" | "zeros" | "decay" | the std of a normal draw
+    start: Union[str, float]
+    key: Optional[int] = None           # what a draw is drawn from
+
+    def __post_init__(self):
+        assert len(self.shape) == len(self.axes), self
+        assert (self.key is None) == (self.start in ("ones", "zeros")), self
+
+    def make(self, key_of, lead: Tuple[int, ...], dtype) -> jax.Array:
+        shape = lead + self.shape
+        if self.start in ("ones", "zeros"):
+            return getattr(jnp, self.start)(shape, dtype)
+        if self.start == "decay":
+            # the public implementation's start: decay rates exp(A_log)
+            # uniform in (0, 16)
+            return jnp.log(jax.random.uniform(
+                key_of(self.key), shape, jnp.float32, 1e-3, 16.0)
+            ).astype(dtype)
+        return (jax.random.normal(key_of(self.key), shape, jnp.float32)
+                * self.start).astype(dtype)
+
+
+def _resid_std(c: GPTConfig) -> float:
+    return _STD / math.sqrt(2 * c.n_layers)
+
+
+def _unit(c: GPTConfig) -> str:
+    """A norm's scale of 1: "rmsnorm_1p" stores what it adds to 1."""
+    return "zeros" if c.norm == "rmsnorm_1p" else "ones"
+
+
+def _full_weights(c: GPTConfig) -> Dict[str, _Weight]:
+    d, hd, h, hk = c.d_model, c.head_dim, c.n_heads, c.kv_heads
+    weights = dict(
+        wq=_Weight((d, h, (2 if c.attn_gate else 1) * hd),
+                   ("embed", "heads", "head_dim"), _STD, 0),
+        wk=_Weight((d, hk, hd), ("embed", "kv_heads", "head_dim"), _STD, 1),
+        wv=_Weight((d, hk, hd), ("embed", "kv_heads", "head_dim"), _STD, 2),
+        wo=_Weight((h, hd, d), ("heads", "head_dim", "embed"),
+                   _resid_std(c), 3))
+    if c.qk_norm == "head":
+        weights.update(q_norm=_Weight((hd,), ("head_dim",), _unit(c)),
+                       k_norm=_Weight((hd,), ("head_dim",), _unit(c)))
+    elif c.qk_norm:
+        weights.update(
+            q_norm=_Weight((h, hd), ("heads", "head_dim"), "ones"),
+            k_norm=_Weight((hk, hd), ("kv_heads", "head_dim"), "ones"))
+    return weights
+
+
+def _linear_weights(c: GPTConfig) -> Dict[str, _Weight]:
+    d, nv = c.d_model, c.linear_value_heads
+    keys_w = c.linear_key_heads * c.linear_key_dim
+    values_w = nv * c.linear_value_dim
+    # the projections' columns are q, k, v and z side by side and the
+    # convolution runs along them: not split over tp
+    return dict(
+        w_qkvz=_Weight((d, 2 * keys_w + 2 * values_w), ("embed", None),
+                       _STD, _EXTRA + 4),
+        w_ba=_Weight((d, 2 * nv), ("embed", None), _STD, _EXTRA + 5),
+        conv_w=_Weight((c.linear_conv, 2 * keys_w + values_w), (None, None),
+                       _STD, _EXTRA + 6),
+        A_log=_Weight((nv,), (None,), "decay", _EXTRA + 7),
+        dt_bias=_Weight((nv,), (None,), "ones"),       # the step's bias at 1
+        lin_norm=_Weight((c.linear_value_dim,), (None,), "ones"),
+        w_lin_out=_Weight((values_w, d), (None, "embed"), _resid_std(c),
+                          _EXTRA + 3))
+
+
+def _dense_weights(c: GPTConfig) -> Dict[str, _Weight]:
+    d, f = c.d_model, c.ff_dim
+    weights = dict(
+        w_up=_Weight((d, f), ("embed", "mlp"), _STD, 4),
+        w_down=_Weight((f, d), ("mlp", "embed"), _resid_std(c), 5))
+    if c.activation == "swiglu":
+        weights["w_gate"] = _Weight((d, f), ("embed", "mlp"), _STD, 6)
+    return weights
+
+
+def _expert_weights(c: GPTConfig) -> Dict[str, _Weight]:
+    d, f, held = c.d_model, c.ff_dim, c.experts_held
+    weights = dict(
+        router=_Weight((d, c.n_experts), ("embed", None), _STD, 4),
+        w_up=_Weight((held, d, f), ("expert", "embed", "mlp"), _STD, 5),
+        w_gate=_Weight((held, d, f), ("expert", "embed", "mlp"), _STD, 6),
+        w_down=_Weight((held, f, d), ("expert", "mlp", "embed"),
+                       _resid_std(c), 10))
+    if c.moe_shared_ff:
+        fs = c.moe_shared_ff
+        weights.update(
+            ws_up=_Weight((d, fs), ("embed", "mlp"), _STD, _EXTRA + 0),
+            ws_gate=_Weight((d, fs), ("embed", "mlp"), _STD, _EXTRA + 1),
+            ws_down=_Weight((fs, d), ("mlp", "embed"), _resid_std(c),
+                            _EXTRA + 2),
+            ws_open=_Weight((d,), (None,), "zeros"))
+    return weights
+
+
+class _Half(NamedTuple):
+    """A kind of mixer or of FFN: what lists its weights, and what applies
+    them (of a `GPT`: the model, the layer's input, positions, weights)."""
+    weights: Callable[[GPTConfig], Dict[str, _Weight]]
+    apply: Callable[..., Any]
+
+
+_MIXERS = {
+    "full": _Half(_full_weights,
+                  lambda m, x, positions, w: m._full_mixer(x, positions, w)),
+    "linear": _Half(_linear_weights,
+                    lambda m, x, positions, w: m._linear_mixer(x, w)),
+}
+_FFNS = {
+    "dense": _Half(_dense_weights, lambda m, h, w: m._dense_ffn(h, w)),
+    "experts": _Half(_expert_weights, lambda m, h, w: m._expert_ffn(h, w)),
+}
+LAYER_KINDS = tuple(_MIXERS)
+
+
+def _ffn_of(c: GPTConfig) -> _Half:
+    return _FFNS["experts" if c.n_experts > 0 else "dense"]
+
+
+def _layer_weights(c: GPTConfig, kind: str) -> Dict[str, _Weight]:
+    """The weights of one layer of `kind`: its two norms', its mixer's and
+    its FFN's."""
+    d = c.d_model
+    weights = dict(norm1=_Weight((d,), (None,), _unit(c)),
+                   norm2=_Weight((d,), (None,), _unit(c)),
+                   **_MIXERS[kind].weights(c), **_ffn_of(c).weights(c))
+    if c.norm == "layernorm":
+        weights.update(bias1=_Weight((d,), (None,), "zeros"),
+                       bias2=_Weight((d,), (None,), "zeros"))
+    return weights
+
+
+def _model_weights(c: GPTConfig) -> Dict[str, _Weight]:
+    """The model's weights outside its layers."""
+    d = c.d_model
+    weights = dict(
+        tok_embed=_Weight((c.vocab_size, d), ("vocab", "embed"), _STD, 7),
+        norm_f=_Weight((d,), (None,), _unit(c)))
+    if c.positions == "learned":
+        weights["pos_embed"] = _Weight((c.max_seq_len, d), (None, "embed"),
+                                       _STD, 8)
+    if c.norm == "layernorm":
+        weights["bias_f"] = _Weight((d,), (None,), "zeros")
+    if not c.tie_embeddings:
+        weights["lm_head"] = _Weight((d, c.vocab_size), ("embed", "vocab"),
+                                     _STD, 9)
+    return weights
 
 
 class GPT:
@@ -271,14 +426,18 @@ class GPT:
                     "kinds) are not supported yet: pp needs the period "
                     '("full",)')
 
-    @property
-    def pp_stages(self) -> int:
+    def _mesh_size(self, logical: str) -> int:
+        """Size of the one mesh axis `logical` maps to (1 with no mesh)."""
         if self.mesh is None:
             return 1
-        ax = self.rules.mesh_axes("stage")
+        ax = self.rules.mesh_axes(logical)
         if isinstance(ax, str) and ax in self.mesh.shape:
             return self.mesh.shape[ax]
         return 1
+
+    @property
+    def pp_stages(self) -> int:
+        return self._mesh_size("stage")
 
     @property
     def _kinds(self) -> Dict[str, int]:
@@ -289,180 +448,57 @@ class GPT:
 
     # -- parameters --------------------------------------------------------
 
-    def _init_blocks(self, kind: str, lead: Tuple[int, ...], keys) -> Params:
+    def _init_layers(self, kind: str, lead: Tuple[int, ...], keys) -> Params:
         """The stacked weights of the layers of one kind, `lead` the
-        stacking axes. `keys`: twelve, of which 7..9 are the model's own."""
-        c = self.config
-        pd = c.param_dtype
-        d, f, hd = c.d_model, c.ff_dim, c.head_dim
-        h, hk = c.n_heads, c.kv_heads
-        std = 0.02
-        resid_std = std / math.sqrt(2 * c.n_layers)
-        extra = jax.random.split(keys[11], 8)
+        stacking axes, `keys` the twelve."""
+        extra = jax.random.split(keys[_EXTRA - 1], 8)
 
-        def ones(*shape):
-            return jnp.ones(lead + shape, pd)
+        def key_of(i):
+            return keys[i] if i < _EXTRA else extra[i - _EXTRA]
 
-        def zeros(*shape):
-            return jnp.zeros(lead + shape, pd)
-
-        def normal(key, *shape, std=std):
-            return _normal(key, lead + shape, std, pd)
-
-        # a scale of 1 either way: "rmsnorm_1p" stores what it adds to 1
-        unit = zeros if c.norm == "rmsnorm_1p" else ones
-        blocks = {"norm1": unit(d), "norm2": unit(d)}
-        if kind == "full":
-            blocks.update(
-                wq=normal(keys[0], d, h, (2 if c.attn_gate else 1) * hd),
-                wk=normal(keys[1], d, hk, hd),
-                wv=normal(keys[2], d, hk, hd),
-                wo=normal(keys[3], h, hd, d, std=resid_std))
-            if c.qk_norm == "head":
-                blocks.update(q_norm=unit(hd), k_norm=unit(hd))
-            elif c.qk_norm:
-                blocks.update(q_norm=ones(h, hd), k_norm=ones(hk, hd))
-        else:
-            nk, nv = c.linear_key_heads, c.linear_value_heads
-            keys_w, values_w = nk * c.linear_key_dim, nv * c.linear_value_dim
-            blocks.update(
-                w_qkvz=normal(extra[4], d, 2 * keys_w + 2 * values_w),
-                w_ba=normal(extra[5], d, 2 * nv),
-                conv_w=normal(extra[6], c.linear_conv,
-                              2 * keys_w + values_w),
-                # the public implementation's start: decay rates
-                # exp(A_log) uniform in (0, 16), the step's bias at 1
-                A_log=jnp.log(jax.random.uniform(
-                    extra[7], lead + (nv,), jnp.float32, 1e-3, 16.0)
-                ).astype(pd),
-                dt_bias=ones(nv),
-                lin_norm=ones(c.linear_value_dim),
-                w_lin_out=normal(extra[3], values_w, d, std=resid_std))
-        if c.n_experts > 0:
-            E, held = c.n_experts, c.experts_held
-            blocks.update(
-                router=normal(keys[4], d, E),
-                w_up=normal(keys[5], held, d, f),
-                w_gate=normal(keys[6], held, d, f),
-                w_down=normal(keys[10], held, f, d, std=resid_std))
-            if c.moe_shared_ff:
-                fs = c.moe_shared_ff
-                blocks.update(
-                    ws_up=normal(extra[0], d, fs),
-                    ws_gate=normal(extra[1], d, fs),
-                    ws_down=normal(extra[2], fs, d, std=resid_std),
-                    ws_open=zeros(d))
-        else:
-            blocks["w_up"] = normal(keys[4], d, f)
-            blocks["w_down"] = normal(keys[5], f, d, std=resid_std)
-            if c.activation == "swiglu":
-                blocks["w_gate"] = normal(keys[6], d, f)
-        if c.norm == "layernorm":
-            blocks.update(bias1=zeros(d), bias2=zeros(d))
-        return blocks
+        return {name: w.make(key_of, lead, self.config.param_dtype)
+                for name, w in _layer_weights(self.config, kind).items()}
 
     def init(self, rng: jax.Array) -> Params:
         c = self.config
-        pd = c.param_dtype
-        d, L = c.d_model, c.n_layers
-        std = 0.02
-        keys = jax.random.split(rng, 12)
+        L = c.n_layers
+        keys = jax.random.split(rng, _EXTRA)
         if len(c.layer_pattern) == 1:
-            blocks = self._init_blocks(c.layer_pattern[0], (L,), keys)
+            blocks = self._init_layers(c.layer_pattern[0], (L,), keys)
         else:
             periods = L // len(c.layer_pattern)
             blocks = {
-                kind: self._init_blocks(
+                kind: self._init_layers(
                     kind, (periods, n),
-                    jax.random.split(jax.random.fold_in(rng, i + 1), 12))
+                    jax.random.split(jax.random.fold_in(rng, i + 1), _EXTRA))
                 for i, (kind, n) in enumerate(self._kinds.items())}
-        unit = jnp.zeros if c.norm == "rmsnorm_1p" else jnp.ones
-        params: Params = {
-            "tok_embed": _normal(keys[7], (c.vocab_size, d), std, pd),
-            "blocks": blocks,
-            "norm_f": unit((d,), pd),
-        }
-        if c.positions == "learned":
-            params["pos_embed"] = _normal(keys[8], (c.max_seq_len, d), std,
-                                          pd)
-        if c.norm == "layernorm":
-            params["bias_f"] = jnp.zeros((d,), pd)
-        if not c.tie_embeddings:
-            params["lm_head"] = _normal(keys[9], (d, c.vocab_size), std, pd)
         P = self.pp_stages
         if P > 1:
             # stage-stack: [L, ...] -> [P, L/P, ...]; the stage axis is
             # sharded over pp so each stage holds only its layers
-            params["blocks"] = jax.tree_util.tree_map(
-                lambda a: a.reshape((P, L // P) + a.shape[1:]),
-                params["blocks"])
-        return params
-
-    def _block_axes(self, kind: str) -> Dict[str, Tuple]:
-        """Logical axes of one layer's weights, without the stacking axes."""
-        c = self.config
-        axes: Dict[str, Tuple] = {"norm1": (None,), "norm2": (None,)}
-        if kind == "full":
-            axes.update(
-                wq=("embed", "heads", "head_dim"),
-                wk=("embed", "kv_heads", "head_dim"),
-                wv=("embed", "kv_heads", "head_dim"),
-                wo=("heads", "head_dim", "embed"))
-            if c.qk_norm == "head":
-                axes.update(q_norm=("head_dim",), k_norm=("head_dim",))
-            elif c.qk_norm:
-                axes.update(q_norm=("heads", "head_dim"),
-                            k_norm=("kv_heads", "head_dim"))
-        else:
-            # the projections' columns are q, k, v and z side by side and
-            # the convolution runs along them: not split over tp
-            axes.update(
-                w_qkvz=("embed", None), w_ba=("embed", None),
-                conv_w=(None, None), A_log=(None,), dt_bias=(None,),
-                lin_norm=(None,), w_lin_out=(None, "embed"))
-        if c.n_experts > 0:
-            axes.update(
-                router=("embed", None),
-                w_up=("expert", "embed", "mlp"),
-                w_gate=("expert", "embed", "mlp"),
-                w_down=("expert", "mlp", "embed"))
-            if c.moe_shared_ff:
-                axes.update(ws_up=("embed", "mlp"), ws_gate=("embed", "mlp"),
-                            ws_down=("mlp", "embed"), ws_open=(None,))
-        else:
-            axes.update(w_up=("embed", "mlp"), w_down=("mlp", "embed"))
-            if c.activation == "swiglu":
-                axes["w_gate"] = ("embed", "mlp")
-        if c.norm == "layernorm":
-            axes.update(bias1=(None,), bias2=(None,))
-        return axes
+            blocks = jax.tree_util.tree_map(
+                lambda a: a.reshape((P, L // P) + a.shape[1:]), blocks)
+        return {"blocks": blocks,
+                **{name: w.make(lambda i: keys[i], (), c.param_dtype)
+                   for name, w in _model_weights(c).items()}}
 
     def param_logical_axes(self) -> Params:
         """Pytree matching `init` output: tuples of logical axis names."""
         c = self.config
+
+        def axes(kind, lead):
+            return {name: lead + w.axes
+                    for name, w in _layer_weights(c, kind).items()}
+
         if len(c.layer_pattern) == 1:
-            lead: Tuple = ("layers",)
-            if self.pp_stages > 1:
-                lead = ("stage",) + lead
-            blocks: Params = {
-                k: lead + v
-                for k, v in self._block_axes(c.layer_pattern[0]).items()}
+            blocks: Params = axes(
+                c.layer_pattern[0],
+                ("stage", "layers") if self.pp_stages > 1 else ("layers",))
         else:
-            blocks = {kind: {k: ("layers", None) + v
-                             for k, v in self._block_axes(kind).items()}
+            blocks = {kind: axes(kind, ("layers", None))
                       for kind in self._kinds}
-        axes: Params = {
-            "tok_embed": ("vocab", "embed"),
-            "blocks": blocks,
-            "norm_f": (None,),
-        }
-        if c.positions == "learned":
-            axes["pos_embed"] = (None, "embed")
-        if c.norm == "layernorm":
-            axes["bias_f"] = (None,)
-        if not c.tie_embeddings:
-            axes["lm_head"] = ("embed", "vocab")
-        return axes
+        return {**{name: w.axes for name, w in _model_weights(c).items()},
+                "blocks": blocks}
 
     # -- building blocks ---------------------------------------------------
 
@@ -516,15 +552,6 @@ class GPT:
         out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
         return out.astype(x.dtype)
 
-    def _sp_size(self) -> int:
-        """Size of the mesh axis act_seq maps to (sequence parallelism)."""
-        if self.mesh is None:
-            return 1
-        ax = self.rules.mesh_axes("act_seq")
-        if isinstance(ax, str) and ax in self.mesh.shape:
-            return self.mesh.shape[ax]
-        return 1
-
     def _attention(self, q, k, v):
         """q: [B, S, H, Dh], k/v: [B, S, Hk, Dh] → [B, S, H, Dh].
 
@@ -536,14 +563,13 @@ class GPT:
         from the mesh.
         """
         c = self.config
-        sp = self._sp_size()
-        if getattr(self, "_in_pipeline", False):
+        if self.pp_stages > 1:
             # pipeline mode runs blocks under vmap over the stage axis;
             # shard_map can't nest there, so use the einsum attention and
             # let GSPMD partition it (pallas-in-pipeline: future work)
             return dot_product_attention(q, k, v, causal=True,
                                          impl="reference", seq_major=True)
-        if sp > 1:
+        if self._mesh_size("act_seq") > 1:      # sequence parallelism
             # Specs derive from the rules table like every other sharding
             # decision; the ring axis is whatever act_seq maps to.
             spec_q = self.rules.spec("act_batch", "act_heads", "act_seq",
@@ -688,7 +714,7 @@ class GPT:
         """fn(*arrays, *weights) for [B, S, ...] arrays: on a mesh under
         shard_map, rows over the batch axes and everything else whole on
         every device (the Gated DeltaNet layer outside its rule is not split
-        over tp: `_block_axes`), for `_delta_rule`'s reason."""
+        over tp: `_linear_weights`), for `_delta_rule`'s reason."""
         if self.mesh is None:
             return fn(*arrays, *weights)
         rows = self.rules.spec("act_batch", None, None)
@@ -745,48 +771,47 @@ class GPT:
             return x + self._constrain(out, "act_batch", "act_seq",
                                        "act_embed")
 
-    def _block(self, x, positions, w, kind="full"):
-        """One block of the given kind. x: [B, S, D] bf16."""
+    def _dense_ffn(self, h, w):
+        """The MLP on the normed input h: (its output, no facts)."""
         c = self.config
         dt = c.dtype
-        if kind == "full":
-            x = self._full_mixer(x, positions, w)
+        up = jnp.einsum("bsd,df->bsf", h, w["w_up"].astype(dt))
+        up = checkpoint_name(up, "mlp_up")
+        if c.activation == "swiglu":
+            gate = jnp.einsum("bsd,df->bsf", h, w["w_gate"].astype(dt))
+            gate = checkpoint_name(gate, "mlp_gate")
+            act = jax.nn.silu(gate) * up
         else:
-            x = self._linear_mixer(x, w)
+            act = jax.nn.gelu(up, approximate=True)
+        act = self._constrain(act, "act_batch", "act_seq", "act_mlp")
+        return jnp.einsum("bsf,fd->bsd", act, w["w_down"].astype(dt)), {}
 
+    def _expert_ffn(self, h, w):
+        """The routed experts held here, and the shared one where the model
+        has it, on the normed input h: (their output, the router's
+        facts)."""
+        from .moe import moe_ffn, shared_expert_ffn
+        c = self.config
+        down, aux = moe_ffn(
+            h, w["router"], w["w_up"], w["w_gate"], w["w_down"],
+            top_k=c.moe_top_k, norm_topk_prob=c.moe_norm_topk_prob,
+            first_expert=c.moe_first_expert, dtype=c.dtype,
+            # a Mosaic call is not partitioned automatically: on a mesh the
+            # router's top-k is `lax.top_k` and the held experts' rows are
+            # summed in `jnp`
+            impl=c.attention_impl if self.mesh is None else "reference")
+        if c.moe_shared_ff:
+            down = down + shared_expert_ffn(
+                h, w["ws_up"], w["ws_gate"], w["ws_down"], w["ws_open"],
+                dtype=c.dtype)
+        return down, aux
+
+    def _block(self, x, positions, w, kind="full"):
+        """One block of the given kind. x: [B, S, D] bf16."""
+        x = _MIXERS[kind].apply(self, x, positions, w)
         with jax.named_scope("mlp"):
             h = self._norm(x, w["norm2"], w.get("bias2"))
-            aux = {}
-            if c.n_experts > 0:
-                from .moe import moe_ffn, shared_expert_ffn
-                down, aux = moe_ffn(
-                    h, w["router"], w["w_up"], w["w_gate"], w["w_down"],
-                    top_k=c.moe_top_k,
-                    norm_topk_prob=c.moe_norm_topk_prob,
-                    first_expert=c.moe_first_expert, dtype=dt,
-                    # a Mosaic call is not partitioned automatically: on a
-                    # mesh the router's top-k is `lax.top_k` and the held
-                    # experts' rows are summed in `jnp`
-                    impl=(c.attention_impl if self.mesh is None
-                          else "reference"))
-                if c.moe_shared_ff:
-                    down = down + shared_expert_ffn(
-                        h, w["ws_up"], w["ws_gate"], w["ws_down"],
-                        w["ws_open"], dtype=dt)
-            else:
-                up = jnp.einsum("bsd,df->bsf", h, w["w_up"].astype(dt))
-                up = checkpoint_name(up, "mlp_up")
-                if c.activation == "swiglu":
-                    gate = jnp.einsum("bsd,df->bsf", h,
-                                      w["w_gate"].astype(dt))
-                    gate = checkpoint_name(gate, "mlp_gate")
-                    act = jax.nn.silu(gate) * up
-                else:
-                    act = jax.nn.gelu(up, approximate=True)
-                act = self._constrain(act, "act_batch", "act_seq",
-                                      "act_mlp")
-                down = jnp.einsum("bsf,fd->bsd", act,
-                                  w["w_down"].astype(dt))
+            down, aux = _ffn_of(self.config).apply(self, h, w)
             x = x + self._constrain(down, "act_batch", "act_seq",
                                     "act_embed")
         return x, aux
@@ -913,47 +938,42 @@ class GPT:
                                "act_embed")
         pos_mb = positions.reshape(M, mb, S)[0]
 
-        self._in_pipeline = True
-        try:
-            def stage_step(carry, t):
-                state, outs = carry
-                # shift: stage s hands its activation to stage s+1
-                state = jnp.roll(state, shift=1, axis=0)
-                # feed the next microbatch into stage 0
-                inp = lax.dynamic_index_in_dim(
-                    x_mb, jnp.clip(t, 0, M - 1), axis=0, keepdims=False)
-                state = state.at[0].set(
-                    jnp.where(t < M, inp, state[0]))
-                state = self._constrain(state, "stage", "act_batch",
-                                        "act_seq", "act_embed")
+        def stage_step(carry, t):
+            state, outs = carry
+            # shift: stage s hands its activation to stage s+1
+            state = jnp.roll(state, shift=1, axis=0)
+            # feed the next microbatch into stage 0
+            inp = lax.dynamic_index_in_dim(
+                x_mb, jnp.clip(t, 0, M - 1), axis=0, keepdims=False)
+            state = state.at[0].set(jnp.where(t < M, inp, state[0]))
+            state = self._constrain(state, "stage", "act_batch",
+                                    "act_seq", "act_embed")
 
-                # every stage applies its L/P layers (vmap over stages;
-                # per-stage scan over layers)
-                def one_stage(stage_params, xs):
-                    def body(h, layer_w):
-                        h, _ = block_fn(h, pos_mb, layer_w)
-                        return h, None
-                    out, _ = lax.scan(body, xs, stage_params)
-                    return out
+            # every stage applies its L/P layers (vmap over stages;
+            # per-stage scan over layers)
+            def one_stage(stage_params, xs):
+                def body(h, layer_w):
+                    h, _ = block_fn(h, pos_mb, layer_w)
+                    return h, None
+                out, _ = lax.scan(body, xs, stage_params)
+                return out
 
-                state = jax.vmap(one_stage)(blocks, state)
-                state = self._constrain(state, "stage", "act_batch",
-                                        "act_seq", "act_embed")
-                # collect the last stage's output once the fill drains
-                out_idx = jnp.clip(t - (P - 1), 0, M - 1)
-                outs = lax.cond(
-                    t >= P - 1,
-                    lambda o: lax.dynamic_update_index_in_dim(
-                        o, state[P - 1], out_idx, axis=0),
-                    lambda o: o, outs)
-                return (state, outs), None
+            state = jax.vmap(one_stage)(blocks, state)
+            state = self._constrain(state, "stage", "act_batch",
+                                    "act_seq", "act_embed")
+            # collect the last stage's output once the fill drains
+            out_idx = jnp.clip(t - (P - 1), 0, M - 1)
+            outs = lax.cond(
+                t >= P - 1,
+                lambda o: lax.dynamic_update_index_in_dim(
+                    o, state[P - 1], out_idx, axis=0),
+                lambda o: o, outs)
+            return (state, outs), None
 
-            state0 = jnp.zeros((P, mb, S, D), c.dtype)
-            outs0 = jnp.zeros((M, mb, S, D), c.dtype)
-            (_, outs), _ = lax.scan(stage_step, (state0, outs0),
-                                    jnp.arange(M + P - 1))
-        finally:
-            self._in_pipeline = False
+        state0 = jnp.zeros((P, mb, S, D), c.dtype)
+        outs0 = jnp.zeros((M, mb, S, D), c.dtype)
+        (_, outs), _ = lax.scan(stage_step, (state0, outs0),
+                                jnp.arange(M + P - 1))
         return outs.reshape(B, S, D)
 
     def loss(self, params: Params, batch: Dict[str, jax.Array]

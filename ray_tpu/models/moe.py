@@ -1,4 +1,4 @@
-"""Mixture-of-Experts FFN, dropless, with expert parallelism.
+"""Mixture-of-Experts FFN, dropless.
 
 The reference has NO native MoE/EP (SURVEY §2.4: "absent — only via
 external frameworks"); here it's first-class. Softmax top-k routing with no
@@ -17,7 +17,8 @@ grow with T x K, with no factor of E and no [T, E, C] tensor). The weighted
 results return to token order through the inverse permutation and are summed
 over K. An expert with no token is an empty group; an expert with many times
 the mean is a long one. Expert weights carry a leading "expert" logical axis
-sharded over the ``ep`` mesh axis.
+that the rules map to the ``ep`` mesh axis: an annotation the partitioner is
+left to honour (no exchange of tokens between chips is written here).
 
 A layer may hold only a share of its experts (`first_expert` and as many as
 the weights it is given: one chip's part of a layer that several share). It

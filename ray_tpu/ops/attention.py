@@ -75,6 +75,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ._impl import resolve_impl
+
 NEG_INF = -1e30
 
 # Grid tile: the whole sequence up to this length (swept on the v5e at the
@@ -1198,19 +1200,14 @@ def dot_product_attention(q, k, v, causal: bool = True,
     """Attention entry point used by models. [B, H, S, D] or, with
     `seq_major`, [B, S, H, D] (the projections' own layout) in and out.
 
-    impl: "auto" (pallas on TPU, reference elsewhere), "pallas",
-    "pallas_interpret" (kernel under the interpreter — CPU tests),
-    "reference".
+    impl: as `ops._impl.resolve_impl` takes it; the kernels take any width.
     """
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "reference"
+    impl = resolve_impl(impl, "attention")
     if impl == "reference":
         reference = functools.partial(attention_reference, causal=causal,
                                       scale=scale)
         if seq_major:
             return _via_head_major(reference, q, k, v)
         return reference(q, k, v)
-    if impl in ("pallas", "pallas_interpret"):
-        return flash_attention(q, k, v, causal, scale, block_q, block_k,
-                               impl == "pallas_interpret", seq_major)
-    raise ValueError(f"unknown attention impl {impl!r}")
+    return flash_attention(q, k, v, causal, scale, block_q, block_k,
+                           impl == "pallas_interpret", seq_major)

@@ -27,8 +27,7 @@ t >= j, so nothing overflows at decays near 0, and at decays near 1 nothing
 is divided by a small number. Rows whose length is no multiple of the chunk
 are padded with positions that write nothing (b = 0, g = 0, zero q, k, v).
 
-Two forms of that one algorithm, chosen as attention's are (`impl`: "auto"
-is the kernels on a TPU and the `jnp` form elsewhere):
+Two forms of that one algorithm, chosen by `impl` (`ops/_impl.py`):
 
 * A Pallas kernel pair, `gdn_rule_fwd` and `gdn_rule_bwd`. A grid step is a
   batch row, `_BLOCK_KEY_HEADS` key heads with their value heads and
@@ -74,6 +73,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ._impl import resolve_impl
 
 # A power of two (the solve squares its way up to it), two of which fit the
 # matrix unit's depth; the `jnp` form's and both kernels' (on the v5e, PERF.md
@@ -618,24 +619,11 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     g (log of the decay, <= 0) and beta: [B, S, Hv]. Returns o:
     [B, S, Hv, Dv] in v's dtype.
 
-    impl: as `ops.attention.dot_product_attention`'s — "auto" (the kernels
-    on a TPU, `jnp` elsewhere), "pallas", "pallas_interpret" (the kernels
-    under the interpreter: CPU tests), "reference". On a TPU the kernels
-    take key and value widths that are multiples of the 128 lanes: under
-    "auto" other widths run the `jnp` form, and "pallas" refuses them."""
+    impl: as `ops._impl.resolve_impl` takes it; the kernels take key and
+    value widths of whole 128-lane tiles."""
     assert v.shape[2] % q.shape[2] == 0, (q.shape, v.shape)
-    lanes = not (q.shape[-1] % 128 or v.shape[-1] % 128)
-    if impl == "auto":
-        impl = ("pallas" if lanes and jax.default_backend() == "tpu"
-                else "reference")
-    if impl == "pallas" and not lanes:
-        raise ValueError(
-            "the delta rule's kernels take key and value widths that are "
-            f"multiples of 128 on a TPU, got {q.shape[-1]} and "
-            f"{v.shape[-1]}: use impl='auto' or 'reference'")
+    impl = resolve_impl(impl, "delta rule", q.shape[-1], v.shape[-1])
     if impl == "reference":
         return gated_delta_rule_reference(q, k, v, g, beta)
-    if impl in ("pallas", "pallas_interpret"):
-        return _gated_delta_rule_pallas(q, k, v, g, beta,
-                                        interpret=impl == "pallas_interpret")
-    raise ValueError(f"unknown delta rule impl {impl!r}")
+    return _gated_delta_rule_pallas(q, k, v, g, beta,
+                                    interpret=impl == "pallas_interpret")

@@ -12,9 +12,8 @@ After it, over the rule's o and the projection's z, [B, S, Hv Dv]:
     y = o * rsqrt(mean_head o^2 + eps) * lin_norm * SiLU(z)
 
 Both are memory-bound: the work is a few dozen vector operations an element
-and each array should cross HBM once. Two forms of each, chosen as the
-rule's are (`impl`: "auto" is the kernels on a TPU at head widths of whole
-128-lane tiles and the `jnp` form elsewhere):
+and each array should cross HBM once. Two forms of each, chosen by `impl`
+(`ops/_impl.py`; the kernels at head widths of whole 128-lane tiles):
 
 * Two Pallas kernel pairs, `gdn_conv_fwd` / `gdn_conv_bwd` and
   `gdn_norm_fwd` / `gdn_norm_bwd`, each a `jax.custom_vjp`. Every array is
@@ -62,6 +61,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ._impl import resolve_impl
 
 # A grid step: positions of the row and lanes of whole heads (a block's size
 # is what amortises the step's fixed cost and its DMAs), and within them the
@@ -525,31 +526,13 @@ _norm.defvjp(_norm_fwd, _norm_bwd)
 # Entry points
 # ---------------------------------------------------------------------------
 
-def _resolve(impl, *widths):
-    """`impl` as `ops.delta_rule.gated_delta_rule` takes it: "auto" is the
-    kernels on a TPU when every head width is whole 128-lane tiles, and
-    "pallas" by name refuses other widths."""
-    lanes = not any(d % 128 for d in widths)
-    if impl == "auto":
-        impl = ("pallas" if lanes and jax.default_backend() == "tpu"
-                else "reference")
-    if impl == "pallas" and not lanes:
-        raise ValueError(
-            "the Gated DeltaNet passes' kernels take head widths that are "
-            f"multiples of 128 on a TPU, got {widths}: use impl='auto' or "
-            "'reference'")
-    if impl not in ("pallas", "pallas_interpret", "reference"):
-        raise ValueError(f"unknown Gated DeltaNet impl {impl!r}")
-    return impl
-
-
 def gdn_conv(qkv: jax.Array, conv_w: jax.Array, *, key_heads: int,
              key_dim: int, value_dim: int, eps: float, impl: str = "auto"):
     """qkv: [B, S, 2 Hk Dk + Hv Dv], the projection's q~ | k~ | v~ columns;
     conv_w: [taps, the same channels]. Returns q, k [B, S, Hk Dk] (unit
     length a head, q scaled by Dk^-1/2 besides) and v [B, S, Hv Dv], in
     qkv's dtype, as the delta rule takes them."""
-    impl = _resolve(impl, key_dim, value_dim)
+    impl = resolve_impl(impl, "Gated DeltaNet passes", key_dim, value_dim)
     if impl == "reference":
         return gdn_conv_reference(qkv, conv_w, key_heads=key_heads,
                                   key_dim=key_dim, eps=eps)
@@ -564,7 +547,7 @@ def gdn_gated_norm(o: jax.Array, z: jax.Array, scale: jax.Array, *,
     """o, z: [B, S, Hv Dv]; scale (lin_norm): [Dv]. Returns the RMS-normed o
     times scale times SiLU(z), in o's dtype: the out-projection's
     operand."""
-    impl = _resolve(impl, scale.shape[0])
+    impl = resolve_impl(impl, "Gated DeltaNet passes", scale.shape[0])
     if impl == "reference":
         return gdn_gated_norm_reference(o, z, scale, eps=eps)
     return _norm(o, z, scale, (("eps", eps),

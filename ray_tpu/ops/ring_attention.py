@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ._impl import resolve_impl
 from .attention import (DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q, NEG_INF,
                         _bwd_pallas, _fwd_pallas)
 
@@ -109,16 +110,8 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = True,
     return out
 
 
-def _resolve(impl):
-    if impl == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "reference"
-    if impl not in ("reference", "pallas", "pallas_interpret"):
-        raise ValueError(f"unknown attention impl {impl!r}")
-    return impl
-
-
 def _partial_fns(impl, scale, block_q, block_k):
-    impl = _resolve(impl)
+    impl = resolve_impl(impl, "ring attention")
     if impl == "reference":
         fwd = lambda q, k, v, diag: _partial_fwd_reference(q, k, v, scale,
                                                            diag)

@@ -10,8 +10,8 @@ elements. K is small beside E, so K rounds of (the row's maximum, the lowest
 index that holds it, that entry blanked) read the rows once and do K passes
 over a tile that stays in VMEM.
 
-Two forms, chosen as the other kernels are (`impl`: "auto" is the kernel on
-a TPU at a row width of whole 128-lane tiles and `lax.top_k` elsewhere):
+Two forms, chosen by `impl` (`ops/_impl.py`; the kernel at a row width of
+whole 128-lane tiles, `lax.top_k` as the other form):
 
 * `moe_topk_rounds`, a Pallas kernel. A grid step takes `_TOKENS` rows of all E
   probabilities and turns the tile token-minor, [E, tokens]: a row's
@@ -39,6 +39,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ._impl import resolve_impl
 
 # Rows a grid step. The tile is straight-line code over its vector
 # registers (E x tokens / 1,024 of them a pass, 3 passes a round): fewer
@@ -118,20 +120,9 @@ def router_topk(probs: jax.Array, k: int, *, impl: str = "auto"
     ([T, K], descending) and their indices ([T, K] int32; among equals the
     lowest first), as `lax.top_k(probs, k)` does (module docstring).
 
-    impl: as the other kernels' — "auto" (the kernel on a TPU at an E of
-    whole 128-lane tiles, `lax.top_k` elsewhere), "pallas",
-    "pallas_interpret" (the kernel under the interpreter: CPU tests),
-    "reference". "pallas" refuses an E that is no multiple of 128."""
-    lanes = probs.shape[1] % 128 == 0
-    if impl == "auto":
-        impl = ("pallas" if lanes and jax.default_backend() == "tpu"
-                else "reference")
-    if impl == "pallas" and not lanes:
-        raise ValueError(
-            "the top-k kernel takes rows that are a multiple of 128 wide on "
-            f"a TPU, got {probs.shape[1]}: use impl='auto' or 'reference'")
-    if impl not in ("pallas", "pallas_interpret", "reference"):
-        raise ValueError(f"unknown top-k impl {impl!r}")
+    impl: as `ops._impl.resolve_impl` takes it, "reference" being
+    `lax.top_k`; the kernel takes an E of whole 128-lane tiles."""
+    impl = resolve_impl(impl, "router top-k", probs.shape[1])
     if impl == "reference":
         return lax.top_k(probs, k)
     return _topk(probs, k, impl == "pallas_interpret")

@@ -8,8 +8,8 @@ streaming pass: every row is read once, every segment written once. An id
 of `num_segments` or more marks a row that belongs nowhere; sorted, such
 rows are last. A segment may have no row (it reads zero) or any number.
 
-Two forms, chosen as the other kernels are (`impl`: "auto" is the kernel on
-a TPU at a row width of whole 128-lane tiles and the `jnp` form elsewhere):
+Two forms, chosen by `impl` (`ops/_impl.py`; the kernel at a row width of
+whole 128-lane tiles):
 
 * `moe_segsum`, a Pallas kernel. The segments are cut into blocks of
   `_SEGMENTS` and the rows into chunks of `_ROWS`; a block's rows lie in a
@@ -51,6 +51,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ._impl import resolve_impl
 
 # A grid step: the segments of an output block, the rows of a chunk and the
 # lanes of both. A block's float32 tile and a chunk, each double-buffered,
@@ -225,17 +227,7 @@ def sorted_segment_sum(rows: jax.Array, ids: jax.Array, num_segments: int,
     array is known to hold zeros — a loop's first trip — and is then not
     read). Not differentiable: its callers carry backward rules of their
     own."""
-    lanes = rows.shape[1] % 128 == 0
-    if impl == "auto":
-        impl = ("pallas" if lanes and jax.default_backend() == "tpu"
-                else "reference")
-    if impl == "pallas" and not lanes:
-        raise ValueError(
-            "the segment sum's kernel takes rows that are a multiple of 128 "
-            f"wide on a TPU, got {rows.shape[1]}: use impl='auto' or "
-            "'reference'")
-    if impl not in ("pallas", "pallas_interpret", "reference"):
-        raise ValueError(f"unknown segment sum impl {impl!r}")
+    impl = resolve_impl(impl, "segment sum", rows.shape[1])
     if impl == "reference":
         out = sorted_segment_sum_reference(rows, ids, num_segments, weights)
         return out if onto is None else out + jnp.where(onto[1], onto[0], 0)

@@ -1,11 +1,12 @@
-"""Learner / LearnerGroup: SGD as one jitted SPMD program.
+"""Learner / LearnerGroup: SGD as one jitted program.
 
 Reference: ``rllib/core/learner/learner.py:229`` (update :1230),
 ``learner_group.py:61``. The reference data-parallelizes learners with
-torch DDP over NCCL; here a single jitted update runs over a device
-mesh (dp axis) — multi-chip gradient psum is inside the program. The
-LearnerGroup actor form exists for placement (run the learner on a TPU
-host while rollouts run elsewhere), not for gradient plumbing.
+torch DDP over NCCL; here the update is a single `jax.jit` call on the
+learner's default device: the class builds no mesh, so the update is not
+sharded over chips. The LearnerGroup actor form exists for placement
+(run the learner on a TPU host while rollouts run elsewhere); with
+several learners it averages their weights on the host.
 """
 
 from __future__ import annotations
@@ -161,8 +162,7 @@ class LearnerGroup:
     """Placement wrapper: run the learner on its own (TPU-host) actor.
 
     num_learners>1 splits each batch and averages weights after update —
-    only useful multi-host; on one slice prefer one learner with a dp
-    mesh (SPMD does the averaging exactly via gradient psum).
+    only useful multi-host.
     """
 
     def __init__(self, module: DiscretePolicyModule, *,

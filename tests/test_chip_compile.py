@@ -1,22 +1,15 @@
-"""Compile the chip path's programs for a described TPU v5e, without one.
-
-The TPU compiler is installed with jax and compiles for a topology that is
-described and not attached (`on-chip-measurement` guide, section 2). This
-catches what interpret mode cannot — a kernel the Mosaic compiler refuses,
-a step that does not fit 16 GB of HBM, a shard_map that cannot be
-partitioned — at no chip time. Nothing runs: these tests say nothing about
-results or speed, and a pass here is not a chip run (a cell of
-`benchmarks/run.py` is).
-
-Skipped where the topology cannot be described. The persistent compile
-cache is off around them: such a compile can be written to it but not read
-back without a chip.
+"""Compile the chip path's kernels, the GPT-2 steps and the steps on the
+host's four chips for a described TPU v5e, without one (`_chip.py` says
+how, and what a pass here is not). The one-chip steps of the sparse-expert
+and the hybrid configurations, minutes each, close the files of those
+configurations' other tests, `test_olmoe_reference.py` and
+`test_qwen3_next_ops.py`: `--dist loadfile` hands a file whole to one
+worker, files of many tests first, so a long compile in a file of few
+tests would start last and a file that held them all was tier-1's wall.
 """
 
 import os
 import re
-
-os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 import jax
 import jax.numpy as jnp
@@ -25,31 +18,8 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-HBM_BYTES = 15.75 * 2 ** 30     # what the v5e compiler reports as capacity
-
-
-@pytest.fixture(scope="module")
-def v5e():
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:   # noqa: BLE001 — no TPU compiler in this install
-        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield topo
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
-
-
-def _on(sharding, tree):
-    """ShapeDtypeStructs of `tree`, every leaf placed by `sharding`."""
-    return jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
-        tree)
+from _chip import (_asks_no_vmem, _kernel_calls, _kernel_names,  # noqa: F401
+                   _moved, _on, _qwen3_next_config, _qwen3_next_step, v5e)
 
 
 @pytest.mark.parametrize("seq_major", [True, False],
@@ -97,37 +67,6 @@ def test_flash_forward_compiles_at_the_served_buckets(v5e, length):
             x, x, x).compile()
     assert _kernel_names(compiled, "flash_") == ["flash_fwd"]
     assert _asks_no_vmem(compiled, "flash_fwd")
-
-
-def _asks_no_vmem(compiled, name):
-    """Whether the kernel's calls state no scoped-VMEM limit of their own
-    (beside a call that states one the compiler writes its default, 16 MiB
-    on the v5e, on the others): what they hold then fits in that default,
-    or the compile would have failed."""
-    calls = _kernel_calls(compiled, name)
-    stated = [int(size) for call in calls for size in re.findall(
-        r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', call)]
-    return bool(calls) and all(size <= 16 * 2 ** 20 for size in stated)
-
-
-def _kernel_calls(compiled, name=""):
-    """The compiled program's Pallas calls whose `pallas_call` name starts
-    with `name` (every Mosaic call, the compiler's own grouped matmuls
-    among them, with none): a scanned block's calls count once each. The
-    name closes
-    the call's path, `.../flash_bwd/pallas_call` or, under a transform
-    with no scope around it, `.../transpose(jvp(flash_bwd))/pallas_call`."""
-    return [line for line in compiled.as_text().splitlines()
-            if 'custom_call_target="tpu_custom_call"' in line
-            and (not name
-                 or re.search(rf"[/(]{name}\w*\)*/pallas_call", line))]
-
-
-def _kernel_names(compiled, name):
-    """Those calls' `pallas_call` names, sorted."""
-    return sorted(
-        re.search(rf"[/(]({name}\w*)\)*/pallas_call", line).group(1)
-        for line in _kernel_calls(compiled, name))
 
 
 def _gpt2_medium_step(mesh, batch, remat_policy="dots"):
@@ -189,70 +128,6 @@ def test_gpt2_medium_train_step_fits_one_chip(v5e, remat_policy,
     # new one, so what must fit beside it is the temporaries: 12.68 GiB
     # under "dots" (14.37 before PR 31, with q, k and v padded)
     assert compiled.memory_analysis().temp_size_in_bytes < 13.0 * 2 ** 30
-
-
-def _olmoe_config():
-    import json
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmarks/configs/olmoe_1b_7b.json")
-    with open(path) as f:
-        return json.load(f)
-
-
-def _olmoe_step(config, batch):
-    """The one-layer OLMoE step of the benchmark's `olmoe_1b_7b`
-    configuration, as its cell builds it, at `batch` rows of 4,096."""
-    from ray_tpu.models import (GPT, init_train_state, make_optimizer,
-                                make_train_step)
-    from ray_tpu.models.gpt import GPTConfig
-
-    kw = dict(config["model"], attention_impl="pallas")
-    kw["dtype"] = getattr(jnp, kw["dtype"])
-    kw["param_dtype"] = getattr(jnp, kw["param_dtype"])
-    model = GPT(GPTConfig(**kw))
-    opt = make_optimizer(**config["optimizer"])
-    state = jax.eval_shape(
-        lambda: init_train_state(model, opt, jax.random.PRNGKey(0)))
-    tokens = jax.ShapeDtypeStruct((batch, kw["max_seq_len"]), jnp.int32)
-    return make_train_step(model, opt), state, tokens
-
-
-def test_olmoe_one_layer_train_step_fills_one_chip(v5e):
-    """`olmoe-steady`'s step: one OLMoE layer at published widths with all
-    64 experts (dropless, by sort and grouped matmul), embedding and untied
-    head, float32 AdamW state, at the configuration's `batch_per_chip` rows
-    of 4,096 tokens. It fits, and one row more does not: this is what fixes
-    `batch_per_chip`."""
-    one_chip = SingleDeviceSharding(v5e.devices[0])
-    config = _olmoe_config()
-    rows = config["batch_per_chip"]
-    step, state, tokens = _olmoe_step(config, rows)
-    assert tokens.shape == (rows, 4096)
-    compiled = step.lower(_on(one_chip, state),
-                          {"tokens": _on(one_chip, tokens)}).compile()
-    # the flash kernels, once each (no remat: nothing is run twice; at
-    # [5, 16, 4096, 128] the row's dq accumulator fits and the backward is
-    # the one kernel), and the grouped matmuls are kernels too
-    assert _kernel_names(compiled, "flash_") == ["flash_bwd", "flash_fwd"]
-    assert _asks_no_vmem(compiled, "flash_fwd")
-    assert len(_kernel_calls(compiled)) > 2
-    mem = compiled.memory_analysis()
-    # the donated state is aliased to the new one: 12 bytes a parameter
-    assert mem.alias_size_in_bytes > 7.4e9
-    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
-    step, state, tokens = _olmoe_step(config, rows + 1)
-    with pytest.raises(Exception, match="(?i)ran out of memory|exhausted"):
-        step.lower(_on(one_chip, state),
-                   {"tokens": _on(one_chip, tokens)}).compile()
-
-
-def _qwen3_next_config():
-    import json
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))),
-        "benchmarks/configs/qwen3_next_80b_a3b.json")
-    with open(path) as f:
-        return json.load(f)
 
 
 def test_flash_attention_compiles_at_qwen3_next_width(v5e):
@@ -397,120 +272,6 @@ def test_gated_deltanet_pass_kernels_compile_at_qwen3_next_width(v5e):
     # nothing of an activation's size is copied, padded, sliced out or
     # joined around them
     assert not _moved(compiled.as_text(), rows)
-
-
-def _moved(text, rows):
-    """The compiled program's copies, pads, slices, concatenates and
-    transposes (inside fusions too) that write a [rows, 8192, 1024 or more]
-    array under one of the Gated DeltaNet layer's scopes."""
-    return [line for line in text.splitlines() if re.search(
-        rf"= (?:bf16|f32)\[{rows},8192,[0-9]{{4,}}\]\S* "
-        r"(?:copy|pad|slice|concatenate|transpose)\(", line)
-        and "/gdn_" in line]
-
-
-def _qwen3_next_step(config, batch, mesh=None):
-    """The one-period Qwen3-Next step of the benchmark's
-    `qwen3_next_80b_a3b` configuration, as its cell builds it, at `batch`
-    rows of 8,192 (on a mesh the step's own in_shardings place the state)."""
-    from ray_tpu.models import (GPT, init_train_state, make_optimizer,
-                                make_train_step)
-    from ray_tpu.models.gpt import GPTConfig
-
-    kw = dict(config["model"], attention_impl="pallas")
-    kw["dtype"] = getattr(jnp, kw["dtype"])
-    kw["param_dtype"] = getattr(jnp, kw["param_dtype"])
-    model = GPT(GPTConfig(**kw), **({"mesh": mesh} if mesh else {}))
-    opt = make_optimizer(**config["optimizer"])
-    state = jax.eval_shape(
-        lambda: init_train_state(model, opt, jax.random.PRNGKey(0)))
-    tokens = jax.ShapeDtypeStruct((batch, kw["max_seq_len"]), jnp.int32)
-    return make_train_step(model, opt, mesh=mesh), state, tokens
-
-
-def test_qwen3_next_period_train_step_fills_one_chip(v5e):
-    """`qwen3next-steady`'s step: one period of Qwen3-Next at published
-    widths (three Gated DeltaNet layers, one gated full-attention layer,
-    each with a shared expert and 32 of 512 routed experts), 18,992 rows of
-    embedding and untied head, float32 AdamW state, at the configuration's
-    `batch_per_chip` rows of 8,192 tokens under "full" remat. Since PR 35
-    (the Gated DeltaNet layer's two passes as kernels) it fits with nothing
-    recomputed by the compiler on its own (PR 33: three [4, 8192, 12288]
-    projections, a [4, 8192, 8192] pass and three [4, 8192, 2048] ones) and
-    holds no copy of an activation around the passes; one row more, refused
-    by 65 MB with the `jnp` delta rule (PR 32) and by 686 MB with its
-    kernel pair (PR 33), now compiles too, again with no `.remat`:
-    `batch_per_chip` is the benchmark's to change (PERF.md, section 7)."""
-    one_chip = SingleDeviceSharding(v5e.devices[0])
-    config = _qwen3_next_config()
-    rows = config["batch_per_chip"]
-    step, state, tokens = _qwen3_next_step(config, rows)
-    assert tokens.shape == (rows, 8192)
-    compiled = step.lower(_on(one_chip, state),
-                          {"tokens": _on(one_chip, tokens)}).compile()
-    # the flash kernels of the one full-attention layer: forward, the
-    # forward recomputed under "full" remat, and the one backward kernel
-    # (PR 38); the held experts' grouped matmuls are kernels too, inside
-    # loops whose trip count follows the pairs routed here
-    assert _kernel_names(compiled, "flash_") == [
-        "flash_bwd", "flash_fwd", "flash_fwd"]
-    # the delta rule's, three for each of the period's three Gated DeltaNet
-    # layers (the scan is over periods; a period's layers are written out):
-    # the primal forward, which writes no states, the `fwd` rule's forward
-    # under "full" remat, which writes them, and the backward
-    assert _kernel_names(compiled, "gdn_rule_") == (
-        ["gdn_rule_bwd"] * 3 + ["gdn_rule_fwd"] * 6)
-    # the passes around it, the same three to a layer: the convolution a
-    # call each for q, k and v, the gated norm one
-    assert _kernel_names(compiled, "gdn_conv_") == (
-        ["gdn_conv_bwd"] * 9 + ["gdn_conv_fwd"] * 18)
-    assert _kernel_names(compiled, "gdn_norm_") == (
-        ["gdn_norm_bwd"] * 3 + ["gdn_norm_fwd"] * 6)
-    text = compiled.as_text()
-    assert "while(" in text
-    # the held experts' rows return to token order by `ops.segment_sum`'s
-    # kernel, a call a walk (PR 36): the forward walk and the backward walk
-    # of each of the period's four layers — the forward walk that "full"
-    # remat would make again is dead code, the backward rule makes a
-    # chunk's products itself. The parent's program had a row scatter-add
-    # into f32[32768,2048] in each of those eight places
-    assert _kernel_names(compiled, "moe_segsum") == ["moe_segsum"] * 8
-    assert not re.search(r"= f32\[32768,2048\]\S* scatter\(", text)
-    # the router's top-10 of 512 is `ops.router_topk`'s kernel (PR 41), a
-    # call a layer in the forward pass and one in its recomputation, under
-    # the router's scope; its backward rule is compares and selects, no
-    # kernel. The parent's program sorted f32[32768,512] rows there and
-    # scattered [32768, 10] values into 16.7 M elements on the way back
-    topk = _kernel_calls(compiled, "moe_topk_")
-    assert _kernel_names(compiled, "moe_topk_") == ["moe_topk_rounds"] * 8
-    assert all("/moe_router/" in line for line in topk)
-    assert sum("rematted_computation" in line for line in topk) == 4
-    assert not any("transpose(jvp" in line for line in topk
-                   if "rematted_computation" not in line)
-    router = [line for line in text.splitlines() if "/moe_router/" in line]
-    assert router
-    assert not [line for line in router
-                if re.search(r" (sort|scatter)\(", line)
-                and "[32768,512]" in line]
-    # the compiler makes no room on its own any more (PERF.md, PR 29's
-    # lesson), and between a layer's projection and its out-projection no
-    # activation is copied, padded, sliced out, joined or transposed
-    assert ".remat" not in text
-    assert not _moved(text, rows)
-    mem = compiled.memory_analysis()
-    # the donated state is aliased to the new one: 12 bytes a parameter
-    assert mem.alias_size_in_bytes > 7.4e9
-    # the step's temporaries, 7.26 GiB (8.87 in PR 33, 9.62 in PR 32): one
-    # more [rows, 8192, 4096] bf16 array kept across a layer is 0.25 GiB
-    assert mem.temp_size_in_bytes < 7.4 * 2 ** 30
-    step, state, tokens = _qwen3_next_step(config, rows + 1)
-    compiled = step.lower(_on(one_chip, state),
-                          {"tokens": _on(one_chip, tokens)}).compile()
-    # 8.44 GiB beside 6.99 of donated state, of 15.75
-    mem = compiled.memory_analysis()
-    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
-    assert mem.temp_size_in_bytes < 8.6 * 2 ** 30
-    assert ".remat" not in compiled.as_text()
 
 
 @pytest.mark.parametrize("axes", [dict(fsdp=4), dict(fsdp=2, tp=2)],

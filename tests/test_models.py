@@ -6,6 +6,8 @@ on a 1-device and an 8-device mesh (dp/fsdp/tp and sp/ring) must agree.
 """
 
 import functools
+import json
+import os
 import re
 
 import jax
@@ -13,11 +15,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import (GPT, GPTConfig, gpt2_small, llama_tiny,
-                            init_train_state, make_optimizer,
+from ray_tpu.models import (GPT, GPTConfig, gpt2_medium, gpt2_small,
+                            llama_tiny, init_train_state, make_optimizer,
                             make_train_step)
 from ray_tpu.models.training import batch_shardings
 from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _batch(cfg, b=4, s=64, seed=1):
@@ -135,6 +139,58 @@ def test_n_params_counts():
     cfg = gpt2_small()
     # GPT-2 small is ~124M params; our count excludes norms/bias.
     assert 1.1e8 < cfg.n_params < 1.4e8
+
+
+def _json_model(path):
+    """The `model` of a benchmark configuration as a `GPTConfig`."""
+    with open(os.path.join(_ROOT, "benchmarks", path)) as f:
+        kw = json.load(f)["model"]
+    kw.update({k: getattr(jnp, kw[k]) for k in ("dtype", "param_dtype")})
+    return GPTConfig(**kw)
+
+
+_DECLARED = {
+    "gpt2_small": gpt2_small, "gpt2_medium": gpt2_medium,
+    "llama_tiny": llama_tiny,
+    **{name: functools.partial(_json_model,
+                               f"tests/fixtures/configs/{name}.json")
+       for name in ("gpt2_tiny", "gpt2_tiny_fsdp4", "olmoe_tiny",
+                    "qwen3_next_tiny")}}
+
+
+@pytest.mark.parametrize("name,stages", [
+    (name, stages) for name in _DECLARED for stages in (1, 2)
+    # a pipeline takes neither experts nor a layer pattern
+    if stages == 1 or name not in ("olmoe_tiny", "qwen3_next_tiny")])
+def test_every_weight_is_declared_with_axes_of_its_rank(name, stages):
+    """`init` and `param_logical_axes` walk one declaration: the trees have
+    one structure and a weight's axes are as many as its dimensions, the
+    stacking axes (layers; stages of layers; periods and layers of a kind)
+    among them."""
+    mesh = build_mesh(MeshSpec(pp=2), devices=jax.devices()[:2]
+                      ) if stages == 2 else None
+    model = GPT(_DECLARED[name](), mesh=mesh)
+    assert model.pp_stages == stages
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    axes = model.param_logical_axes()
+    is_axes = lambda x: isinstance(x, tuple)    # noqa: E731
+    assert (jax.tree_util.tree_structure(shapes)
+            == jax.tree_util.tree_structure(axes, is_leaf=is_axes))
+    for path, leaf in jax.tree_util.tree_leaves_with_path(shapes):
+        names = functools.reduce(lambda tree, key: tree[key.key], path, axes)
+        assert len(names) == leaf.ndim, jax.tree_util.keystr(path)
+
+
+@pytest.mark.parametrize("name,count", [
+    ("gpt2_small", 123568128), ("gpt2_medium", 353501184),
+    ("llama_tiny", 622592), ("configs/gpt2_xl.json", 1555046400),
+    ("configs/olmoe_1b_7b.json", 625606656),
+    ("configs/qwen3_next_80b_a3b.json", 625639424)])
+def test_n_params_is_what_the_written_out_count_gave(name, count):
+    """`n_params` is read off the weights' declaration; before PR 47 it was
+    a formula beside it, and these are that formula's numbers."""
+    cfg = _DECLARED[name]() if name in _DECLARED else _json_model(name)
+    assert cfg.n_params == count
 
 
 @functools.lru_cache(maxsize=None)

@@ -12,6 +12,9 @@ allowed is float32 rounding through two layers: 1e-4 on logits of size ~1,
 A path that dropped a token, normalised the top-k weights, counted only the
 first choice in the balance loss, or took the wrong epsilon is off by 1e-2
 or more.
+
+Last, the configuration's step on the chip: `olmoe-steady`'s train step
+compiled for a described TPU v5e, without one (`_chip.py`).
 """
 
 import os
@@ -21,9 +24,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import SingleDeviceSharding
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+from _chip import (HBM_BYTES, _asks_no_vmem, _kernel_calls,  # noqa: E402,F401
+                   _kernel_names, _on, benchmark_config, v5e)
 from benchmarks.reference import olmoe, olmoe_glue          # noqa: E402
 from ray_tpu.models import GPT                               # noqa: E402
 from ray_tpu.models.gpt import GPTConfig                     # noqa: E402
@@ -162,3 +168,54 @@ def test_one_expert_takes_most_tokens_and_one_takes_none():
     assert (counts[:, 0] > tokens.size // 2).all(), counts
     assert (counts[:, 1] == 0).all(), counts
     assert float(metrics["moe_load_max_over_mean"]) > 3.0
+
+
+def _olmoe_config():
+    return benchmark_config("olmoe_1b_7b")
+
+
+def _olmoe_step(config, batch):
+    """The one-layer OLMoE step of the benchmark's `olmoe_1b_7b`
+    configuration, as its cell builds it, at `batch` rows of 4,096."""
+    from ray_tpu.models import (GPT, init_train_state, make_optimizer,
+                                make_train_step)
+    from ray_tpu.models.gpt import GPTConfig
+
+    kw = dict(config["model"], attention_impl="pallas")
+    kw["dtype"] = getattr(jnp, kw["dtype"])
+    kw["param_dtype"] = getattr(jnp, kw["param_dtype"])
+    model = GPT(GPTConfig(**kw))
+    opt = make_optimizer(**config["optimizer"])
+    state = jax.eval_shape(
+        lambda: init_train_state(model, opt, jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((batch, kw["max_seq_len"]), jnp.int32)
+    return make_train_step(model, opt), state, tokens
+
+
+def test_olmoe_one_layer_train_step_fills_one_chip(v5e):
+    """`olmoe-steady`'s step: one OLMoE layer at published widths with all
+    64 experts (dropless, by sort and grouped matmul), embedding and untied
+    head, float32 AdamW state, at the configuration's `batch_per_chip` rows
+    of 4,096 tokens. It fits, and one row more does not: this is what fixes
+    `batch_per_chip`."""
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    config = _olmoe_config()
+    rows = config["batch_per_chip"]
+    step, state, tokens = _olmoe_step(config, rows)
+    assert tokens.shape == (rows, 4096)
+    compiled = step.lower(_on(one_chip, state),
+                          {"tokens": _on(one_chip, tokens)}).compile()
+    # the flash kernels, once each (no remat: nothing is run twice; at
+    # [5, 16, 4096, 128] the row's dq accumulator fits and the backward is
+    # the one kernel), and the grouped matmuls are kernels too
+    assert _kernel_names(compiled, "flash_") == ["flash_bwd", "flash_fwd"]
+    assert _asks_no_vmem(compiled, "flash_fwd")
+    assert len(_kernel_calls(compiled)) > 2
+    mem = compiled.memory_analysis()
+    # the donated state is aliased to the new one: 12 bytes a parameter
+    assert mem.alias_size_in_bytes > 7.4e9
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
+    step, state, tokens = _olmoe_step(config, rows + 1)
+    with pytest.raises(Exception, match="(?i)ran out of memory|exhausted"):
+        step.lower(_on(one_chip, state),
+                   {"tokens": _on(one_chip, tokens)}).compile()
