@@ -5,13 +5,16 @@ LayerNorm), the Llama family (RoPE, SwiGLU, RMSNorm, GQA), OLMoE's
 sparse-expert block (QK-norm, dropless top-k experts, `models/moe.py`) and
 Qwen3-Next's hybrid (three Gated DeltaNet layers to one gated softmax
 attention layer, a shared expert beside a held share of the routed ones)
-through `GPTConfig` fields — the reference ships these as external torch
-models driven by Ray Train (`release/train_tests`, SURVEY §6 north-star
-configs); here the model itself is framework-native.
+and learned sparse attention (an indexer's choice of keys a query, forward
+only) through `GPTConfig` fields — the reference ships these as external
+torch models driven by Ray Train (`release/train_tests`, SURVEY §6
+north-star configs); here the model itself is framework-native.
 
 The layers' kinds are data: `GPTConfig.layer_pattern` is one period of them
 ("full": softmax attention, "linear": the gated delta rule of
-`ops/delta_rule.py`), every layer is `x + mixer(norm(x))` then
+`ops/delta_rule.py`, "sparse": softmax attention over the keys that the
+indexer of `ops/sparse_index.py` chooses), every layer is
+`x + mixer(norm(x))` then
 `x + mlp(norm(x))`, and the scan runs over periods. A model of one kind is
 the period ("full",): its weights are stacked [L, ...] under
 `params["blocks"]`; with a longer period each kind's weights are stacked
@@ -40,6 +43,7 @@ TPU-first choices:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -55,6 +59,7 @@ from ..ops.attention import FLASH_RESIDUAL_NAMES, dot_product_attention
 from ..ops.delta_rule import gated_delta_rule
 from ..ops.gated_deltanet import gdn_conv, gdn_gated_norm
 from ..ops.ring_attention import ring_attention
+from ..ops.sparse_index import sparse_index
 from ..parallel.sharding import (DEFAULT_RULES, ShardingRules,
                                  with_logical_constraint)
 
@@ -65,6 +70,9 @@ Params = Dict[str, Any]
 # the flash kernel's output and log-sum-exp.
 _DOTS_SAVED_NAMES = ("attn_q", "attn_k", "attn_v", "mlp_up", "mlp_gate",
                      *FLASH_RESIDUAL_NAMES)
+
+# the std of a weight's seeded normal draw
+_STD = 0.02
 
 
 @dataclasses.dataclass(frozen=True)
@@ -109,6 +117,12 @@ class GPTConfig:
     linear_key_dim: int = 0
     linear_value_dim: int = 0
     linear_conv: int = 4
+    # "sparse" layers: softmax attention over the `sparse_topk` causal keys
+    # a query that an indexer scores highest (0: the model has none) — the
+    # indexer's heads and their width, against one key head they share
+    sparse_topk: int = 0
+    index_heads: int = 0
+    index_head_dim: int = 0
     # pipeline parallelism: microbatches per global batch (0 -> = pp).
     # Stages come from the mesh's pp axis; GSPMD-style schedule (scan
     # over steps, stage-sharded rolling buffer -> collective-permute).
@@ -130,6 +144,13 @@ class GPTConfig:
     # (None: all of them). The layer computes their part of the result
     moe_first_expert: int = 0
     moe_experts_held: Optional[int] = None
+    # the seeded start of the token embedding: the std of its normal draw
+    # (every other draw is `_STD`). At 0.02 a token's row is a fortieth of
+    # what a pre-normed branch writes back, so a few layers in the stream is
+    # what the context's mean left there and every token routes alike; at 1
+    # the stream is the token's, as a trained model's is (for an untied
+    # head: a tied one is this matrix, and its logits' scale)
+    embed_std: float = _STD
     # numerics
     dtype: Any = jnp.bfloat16         # activation dtype
     param_dtype: Any = jnp.float32
@@ -162,6 +183,11 @@ class GPTConfig:
         if self.n_layers % len(pattern):
             raise ValueError(f"n_layers={self.n_layers} is not whole periods "
                              f"of {pattern!r}")
+        if "sparse" in pattern and not (self.sparse_topk > 0
+                                        and self.index_heads > 0
+                                        and self.index_head_dim > 0):
+            raise ValueError('a "sparse" layer needs sparse_topk, '
+                             "index_heads and index_head_dim")
 
     @property
     def kv_heads(self) -> int:
@@ -234,7 +260,6 @@ def llama_tiny(**kw) -> GPTConfig:
 
 # --- the kinds of layer: their weights, declared once -----------------------
 
-_STD = 0.02
 # `init` splits its key in twelve for a kind's layers (7..9 are the model's
 # own) and the twelfth in eight more: a weight's `key` counts through both
 _EXTRA = 12
@@ -295,6 +320,19 @@ def _full_weights(c: GPTConfig) -> Dict[str, _Weight]:
     return weights
 
 
+def _sparse_weights(c: GPTConfig) -> Dict[str, _Weight]:
+    """A "full" layer's, and the indexer's: its heads' queries, the one key
+    head they share with its LayerNorm, and a weight a head."""
+    d, hi, di = c.d_model, c.index_heads, c.index_head_dim
+    return dict(
+        _full_weights(c),
+        wq_idx=_Weight((d, hi, di), ("embed", None, None), _STD, _EXTRA + 4),
+        wk_idx=_Weight((d, di), ("embed", None), _STD, _EXTRA + 5),
+        w_idx=_Weight((d, hi), ("embed", None), _STD, _EXTRA + 6),
+        k_idx_norm=_Weight((di,), (None,), "ones"),
+        k_idx_bias=_Weight((di,), (None,), "zeros"))
+
+
 def _linear_weights(c: GPTConfig) -> Dict[str, _Weight]:
     d, nv = c.d_model, c.linear_value_heads
     keys_w = c.linear_key_heads * c.linear_key_dim
@@ -345,7 +383,8 @@ def _expert_weights(c: GPTConfig) -> Dict[str, _Weight]:
 
 class _Half(NamedTuple):
     """A kind of mixer or of FFN: what lists its weights, and what applies
-    them (of a `GPT`: the model, the layer's input, positions, weights)."""
+    them (of a `GPT`: the model, the layer's input, positions, weights),
+    giving its output and its facts."""
     weights: Callable[[GPTConfig], Dict[str, _Weight]]
     apply: Callable[..., Any]
 
@@ -354,7 +393,10 @@ _MIXERS = {
     "full": _Half(_full_weights,
                   lambda m, x, positions, w: m._full_mixer(x, positions, w)),
     "linear": _Half(_linear_weights,
-                    lambda m, x, positions, w: m._linear_mixer(x, w)),
+                    lambda m, x, positions, w: (m._linear_mixer(x, w), {})),
+    "sparse": _Half(_sparse_weights,
+                    lambda m, x, positions, w: m._full_mixer(
+                        x, positions, w, sparse=True)),
 }
 _FFNS = {
     "dense": _Half(_dense_weights, lambda m, h, w: m._dense_ffn(h, w)),
@@ -384,7 +426,8 @@ def _model_weights(c: GPTConfig) -> Dict[str, _Weight]:
     """The model's weights outside its layers."""
     d = c.d_model
     weights = dict(
-        tok_embed=_Weight((c.vocab_size, d), ("vocab", "embed"), _STD, 7),
+        tok_embed=_Weight((c.vocab_size, d), ("vocab", "embed"), c.embed_std,
+                          7),
         norm_f=_Weight((d,), (None,), _unit(c)))
     if c.positions == "learned":
         weights["pos_embed"] = _Weight((c.max_seq_len, d), (None, "embed"),
@@ -552,8 +595,10 @@ class GPT:
         out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
         return out.astype(x.dtype)
 
-    def _attention(self, q, k, v):
-        """q: [B, S, H, Dh], k/v: [B, S, Hk, Dh] → [B, S, H, Dh].
+    def _attention(self, q, k, v, selection=None):
+        """q: [B, S, H, Dh], k/v: [B, S, Hk, Dh] → [B, S, H, Dh]; with
+        `selection` (`ops.sparse_index.Selection`) over each query's chosen
+        keys alone, on one device.
 
         The flash kernels read q, k, v as the projections wrote them and
         write their gradients the same way (`ops/attention.py`,
@@ -563,6 +608,15 @@ class GPT:
         from the mesh.
         """
         c = self.config
+        if selection is not None:
+            if self.mesh is not None:
+                raise NotImplementedError(
+                    "a \"sparse\" layer on a mesh: the indexer's choice of "
+                    "keys is not partitioned (a Mosaic call is not, and no "
+                    "shard_map carries it yet)")
+            return dot_product_attention(
+                q, k, v, causal=True, impl=c.attention_impl, seq_major=True,
+                selection=selection)
         if self.pp_stages > 1:
             # pipeline mode runs blocks under vmap over the stage axis;
             # shard_map can't nest there, so use the einsum attention and
@@ -659,10 +713,35 @@ class GPT:
         return with_logical_constraint(x, *logical, rules=self.rules,
                                        mesh=self.mesh)
 
-    def _full_mixer(self, x, positions, w):
-        """Softmax attention on the normed input, residual included."""
+    def _index(self, h, positions, w):
+        """The indexer of a "sparse" layer on the normed input h: its heads'
+        queries, the key head they share (LayerNorm, then RoPE over the
+        whole width as on the queries) and a weight a head, and the choice
+        of `sparse_topk` keys a query they make."""
         c = self.config
         dt = c.dtype
+        wq = w["wq_idx"].astype(dt)
+        q = jnp.einsum("bsd,de->bse", h, wq.reshape(wq.shape[0], -1))
+        k = jnp.einsum("bsd,de->bse", h, w["wk_idx"].astype(dt))
+        weight = jnp.einsum("bsd,dh->bsh", h, w["w_idx"].astype(dt))
+        kf = k.astype(jnp.float32)
+        kf = kf - jnp.mean(kf, -1, keepdims=True)
+        kf = kf * lax.rsqrt(jnp.mean(kf * kf, -1, keepdims=True) + c.eps)
+        k = (kf * w["k_idx_norm"].astype(jnp.float32)
+             + w["k_idx_bias"].astype(jnp.float32)).astype(dt)
+        q = self._rope_whole(q.reshape(*q.shape[:2], *wq.shape[1:]),
+                             positions)
+        k = self._rope_whole(k[:, :, None], positions)[:, :, 0]
+        return sparse_index(q, k, weight, c.sparse_topk,
+                            impl=c.attention_impl)
+
+    def _full_mixer(self, x, positions, w, sparse=False):
+        """Softmax attention on the normed input, residual included, and the
+        layer's facts: with `sparse`, over the keys the layer's indexer
+        chooses, and how many (query, key) pairs that was."""
+        c = self.config
+        dt = c.dtype
+        selection, facts = None, {}
         # the scopes are metadata on the ops (the profiler's trace and the
         # HLO carry them), the program is the same with or without
         with jax.named_scope("attn_qkv"):
@@ -698,8 +777,14 @@ class GPT:
                                 "head_dim")
             k = self._constrain(k, "act_batch", "act_seq", "act_kv_heads",
                                 "head_dim")
-        with jax.named_scope("attn_kernel"):
-            attn = self._attention(q, k, v)
+            if sparse:
+                with jax.named_scope("dsa_index"):
+                    selection = self._index(h, positions, w)
+                    facts["dsa_selected_pairs"] = selection.counts.sum()
+        with jax.named_scope("attn_kernel"), (
+                jax.named_scope("dsa_attend") if sparse
+                else contextlib.nullcontext()):
+            attn = self._attention(q, k, v, selection)
         with jax.named_scope("attn_out"):
             if c.attn_gate:
                 attn = attn * jax.nn.sigmoid(gate)
@@ -708,7 +793,7 @@ class GPT:
                               attn.reshape(*attn.shape[:2], -1),
                               wo.reshape(-1, wo.shape[-1]))
             return x + self._constrain(attn, "act_batch", "act_seq",
-                                       "act_embed")
+                                       "act_embed"), facts
 
     def _over_rows(self, fn, arrays, weights):
         """fn(*arrays, *weights) for [B, S, ...] arrays: on a mesh under
@@ -808,13 +893,17 @@ class GPT:
 
     def _block(self, x, positions, w, kind="full"):
         """One block of the given kind. x: [B, S, D] bf16."""
-        x = _MIXERS[kind].apply(self, x, positions, w)
+        x, facts = _MIXERS[kind].apply(self, x, positions, w)
+        if "sparse" in self.config.layer_pattern:
+            # a layer without an indexer chose none: the facts of every
+            # layer of a period are stacked
+            facts.setdefault("dsa_selected_pairs", jnp.int32(0))
         with jax.named_scope("mlp"):
             h = self._norm(x, w["norm2"], w.get("bias2"))
             down, aux = _ffn_of(self.config).apply(self, h, w)
             x = x + self._constrain(down, "act_batch", "act_seq",
                                     "act_embed")
-        return x, aux
+        return x, {**facts, **aux}
 
     # -- forward -----------------------------------------------------------
 
@@ -829,7 +918,9 @@ class GPT:
         means over the layers and, per layer, `moe_expert_tokens`
         [n_layers, n_experts], `moe_expert_choice` [n_layers, tokens,
         top_k] and, where the layers hold a share of their experts,
-        `moe_routed_here` [n_layers]; without, an empty dict."""
+        `moe_routed_here` [n_layers]; with "sparse" layers,
+        `dsa_selected_pairs` [n_layers], the (query, key) pairs each
+        layer's indexer chose; with neither, an empty dict."""
         c = self.config
         if positions is None:
             positions = jnp.broadcast_to(
@@ -984,6 +1075,13 @@ class GPT:
         Targets are tokens shifted left; the final position is masked.
         """
         c = self.config
+        if "sparse" in c.layer_pattern:
+            raise NotImplementedError(
+                'a model with a "sparse" layer is not trained: the objective '
+                "that trains the indexer (a divergence to the main "
+                "attention's distribution, its schedule and coefficient) is "
+                "no part of the configuration, and the layer has no backward "
+                "pass")
         tokens = batch["tokens"]
         logits, aux = self.forward_with_aux(params, tokens)  # [B,S,V] f32
         with jax.named_scope("head_loss"):

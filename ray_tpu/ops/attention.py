@@ -105,8 +105,11 @@ _ACC_VREGS = 32   # registers (1024 float32) an accumulator may ride a loop in
 
 def attention_reference(q: jax.Array, k: jax.Array, v: jax.Array,
                         causal: bool = True,
-                        scale: Optional[float] = None) -> jax.Array:
-    """Plain jnp attention with GQA. q: [B, H, S, D]; k/v: [B, Hk, S, D]."""
+                        scale: Optional[float] = None,
+                        mask: Optional[jax.Array] = None) -> jax.Array:
+    """Plain jnp attention with GQA. q: [B, H, S, D]; k/v: [B, Hk, S, D];
+    `mask`, where given, [B, keys, queries]: the keys a query attends (a
+    `Selection.mask`), every head alike."""
     *_, num_q_heads, q_len, head_dim = q.shape
     num_kv_heads = k.shape[-3]
     k_len = k.shape[-2]
@@ -122,6 +125,9 @@ def attention_reference(q: jax.Array, k: jax.Array, v: jax.Array,
         qi = jax.lax.broadcasted_iota(jnp.int32, (q_len, k_len), 0)
         kj = jax.lax.broadcasted_iota(jnp.int32, (q_len, k_len), 1)
         s = jnp.where(kj <= qi + (k_len - q_len), s, NEG_INF)
+    if mask is not None:
+        s = jnp.where(jnp.swapaxes(mask, -1, -2)[..., None, :, :] != 0, s,
+                      NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("...hqk,...hkd->...hqd", p, v.astype(jnp.float32))
     return out.astype(q.dtype)
@@ -443,16 +449,16 @@ class _Heads:
         if self.seq_major:
             return pl.BlockSpec(
                 (None, rows, self.lanes),
-                lambda b, h, i, j: (b, row_block(i, j), head(h)))
+                lambda b, h, i, j, *_: (b, row_block(i, j), head(h)))
         return pl.BlockSpec(
             (None, None, rows, self.dim),
-            lambda b, h, i, j: (b, head(h), row_block(i, j), 0))
+            lambda b, h, i, j, *_: (b, head(h), row_block(i, j), 0))
 
     def stat_spec(self, rows, cols, index):
         """A [rows, cols] block a head of a head-major [B, H, *, *] array;
         `index(i, j)` gives the last two block indices."""
         return pl.BlockSpec((None, self.per, rows, cols),
-                            lambda b, h, i, j: (b, h, *index(i, j)))
+                            lambda b, h, i, j, *_: (b, h, *index(i, j)))
 
     def cols(self, hh):
         """Columns of the block's `hh`-th head (and its rows of a
@@ -506,15 +512,21 @@ def seq_major_fits(q_shape, k_shape):
 # ---------------------------------------------------------------------------
 
 def _scores(k_ref, cols, q, r0, c, chunk, rel, q_valid=None, k_valid=None,
-            edge=False):
+            edge=False, sel_ref=None):
     """(st, mask): the float32 scores [keys, queries] of the tile's `c`-th
     chunk of keys against the scaled queries `q`, the tile's from `r0` on,
     NEG_INF where an edge rectangle's mask drops them, and that mask (None
-    where nothing masks)."""
+    where nothing masks). With `sel_ref`, the tile's [keys, queries] block
+    of a choice of keys (`ops/sparse_index.py`: causal by itself), the mask
+    of every rectangle is the choice."""
     st = _dot_nt(_rows(k_ref, cols, c * chunk, chunk,
                        k_valid if edge else None), q)
-    mask = _edge_mask(c * chunk, r0, st.shape, rel, q_valid,
-                      k_valid) if edge else None
+    if sel_ref is not None:
+        mask = sel_ref[_span(c * chunk, chunk),
+                       r0:r0 + q.shape[0]].astype(jnp.int32) != 0
+    else:
+        mask = _edge_mask(c * chunk, r0, st.shape, rel, q_valid,
+                          k_valid) if edge else None
     if mask is not None:
         st = jnp.where(mask, st, NEG_INF)
     return st, mask
@@ -544,8 +556,9 @@ def _online_softmax(carry, st, v, mask=None, v_transposed=False):
 
 @functools.partial(jax.jit, static_argnames=(
     "rel", "heads", "dim", "scale", "sub", "chunk", "keyless"))
-def _fwd_unrolled(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, vt_refs, *,
-                  rel, heads, dim, scale, sub, chunk, keyless):
+def _fwd_unrolled(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, vt_refs,
+                  sel_ref=None, *, rel, heads, dim, scale, sub, chunk,
+                  keyless):
     """The forward walk of a whole tile whose place is static (`rel`), for
     the `heads` of the block, each `dim` wide: straight-line code.
 
@@ -579,8 +592,8 @@ def _fwd_unrolled(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, vt_refs, *,
                                                      chunk=chunk)
             q = _scaled(q_ref[r0:r0 + sub, cols], scale)
             rects += [((k_ref, cols, q, r0, c, chunk, rel, None, None,
-                        c >= interior_end), hh, slice(r0, r0 + sub), c,
-                       c == live_end - 1) for c in range(live_end)]
+                        c >= interior_end, sel_ref), hh, slice(r0, r0 + sub),
+                       c, c == live_end - 1) for c in range(live_end)]
 
     made = [_scores(*args) for args, *_ in rects[:_FWD_AHEAD]]
     for i, ((_, cols, *_), hh, rs, c, last) in enumerate(rects):
@@ -598,7 +611,8 @@ def _fwd_unrolled(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, vt_refs, *,
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                *vt_refs, scale, t, heads, sub, chunk, transposed_out):
+                *vt_refs, scale, t, heads, sub, chunk, transposed_out,
+                sel_ref=None, live_ref=None):
     """One (q tile, k tile) step of the forward pass, for the heads of the
     block.
 
@@ -614,10 +628,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     Matmul operands are in the inputs' dtype (p cast to it); scores, exp,
     m, l, the accumulator and lse are float32. With `transposed_out` the
     output block is written as it was accumulated, [head_dim, queries].
+    With a choice of keys (`sel_ref`, the tile's [keys, queries] block of a
+    `Selection.mask`) every rectangle is masked by it, a query may have no
+    chosen key in a rectangle, and a tile in which no key is chosen
+    (`live_ref`, [batch x q tiles x k tiles] in SMEM: the pairs chosen a
+    tile) is not walked.
     """
     qb, kb = t.ids(2, 3)
     q_valid, k_valid = t.valid(qb, kb)
-    keyless = t.causal and t.off < 0      # queries before the first key
+    # queries before the first key, or with no chosen key in a rectangle
+    keyless = (t.causal and t.off < 0) or sel_ref is not None
+    if live_ref is not None:
+        chosen = live_ref[(pl.program_id(0) * t.nq + qb) * t.nk + kb]
 
     @_when(kb == 0)
     def _init():
@@ -626,9 +648,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
 
     def walk(rel, hhs):
+        if live_ref is not None:
+            return pl.when(chosen > 0)(
+                functools.partial(walk_live, rel, hhs))
+        return walk_live(rel, hhs)
+
+    def walk_live(rel, hhs):
         if _static(rel) and k_valid is None:
             return _fwd_unrolled(
-                q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, vt_refs,
+                q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, vt_refs, sel_ref,
                 rel=rel, heads=tuple(hhs), dim=heads.dim, scale=scale,
                 sub=sub, chunk=chunk, keyless=keyless)
         for hh in hhs:
@@ -643,7 +671,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
 
                 def step(c, carry, edge):
                     st, mask = _scores(k_ref, cols, q, r0, c, chunk, rel,
-                                       q_valid, k_valid, edge)
+                                       q_valid, k_valid, edge, sel_ref)
                     if vt_refs:
                         v = vt_refs[hh][:, _span(c * chunk, chunk)]
                     else:
@@ -673,14 +701,46 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     _when(kb == t.nk - 1)(heads.each(write))
 
 
+def _selected(kernel):
+    """`_fwd_kernel` as a call that carries a choice of keys hands it its
+    refs: the tiles' chosen pairs first (scalar-prefetched), the choice's
+    block after v's."""
+    def with_choice(live_ref, q_ref, k_ref, v_ref, sel_ref, *rest):
+        kernel(q_ref, k_ref, v_ref, *rest, sel_ref=sel_ref,
+               live_ref=live_ref)
+    return with_choice
+
+
+def _live_tiles(selection, t):
+    """[B x q tiles x k tiles] int32: the pairs a `Selection` chooses in
+    each tile of the grid (all ones where its counts' tiles do not divide
+    the grid's: nothing is skipped then)."""
+    b, rows, s = selection.counts.shape
+    per = s // rows
+    if t.block_k % per or s % t.block_k or s % t.block_q:
+        return jnp.ones((b * t.nq * t.nk,), jnp.int32)
+    tiles = selection.counts.reshape(b, t.nk, t.block_k // per, t.nq,
+                                     t.block_q).sum((2, 4))
+    return jnp.swapaxes(tiles, 1, 2).reshape(-1)
+
+
 def _fwd_pallas(q, k, v, *, scale, causal, block_q, block_k, interpret,
-                transposed_out=False, seq_major=False):
+                transposed_out=False, seq_major=False, selection=None):
     """q, k, v: [B, H, S, D] or, with `seq_major`, [B, S, H, D]. Returns
     (out in q's layout and dtype, lse [B, H, S] float32); with
-    `transposed_out`, out is [B, H, D, S] in both layouts."""
+    `transposed_out`, out is [B, H, D, S] in both layouts. With `selection`
+    (`ops.sparse_index.Selection`, queries and keys of one causal sequence
+    of whole tiles) a query attends its chosen keys alone: the same body,
+    as the kernel `dsa_attend_fwd`."""
     heads = _Heads(q.shape, k.shape, seq_major)
     t = _Tiling(heads.q_len, heads.k_len, block_q, block_k, causal)
     sub, chunk = _rect(t.block_q, t.block_k, heads.dim, _FWD_RECT)
+    if selection is not None and (not causal or t.off or t.ragged_q
+                                  or t.ragged_k):
+        raise NotImplementedError(
+            "attention over a choice of keys: one causal sequence of whole "
+            f"tiles, got {heads.q_len} queries, {heads.k_len} keys under "
+            f"tiles of {t.block_q} x {t.block_k}")
     # v^T scratches, one a head of the block: a v narrower than the lanes
     # streams its transpose out of the matrix unit at half rate, 8 rows an
     # instruction, again for every rectangle; at full lanes the unit keeps
@@ -694,30 +754,44 @@ def _fwd_pallas(q, k, v, *, scale, causal, block_q, block_k, interpret,
     if transposed_out:
         out_spec = heads.stat_spec(heads.dim, t.block_q, lambda i, j: (0, i))
         out_shape = (heads.batch, heads.num, heads.dim, t.q_len)
-    out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, t=t, heads=heads,
-                          sub=sub, chunk=chunk,
-                          transposed_out=transposed_out),
+    kernel = functools.partial(_fwd_kernel, scale=scale, t=t, heads=heads,
+                               sub=sub, chunk=chunk,
+                               transposed_out=transposed_out)
+    grid = dict(
         grid=(heads.batch, heads.steps, t.nq, t.nk),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[
             out_spec,
             heads.stat_spec(1, t.block_q, lambda i, j: (0, i)),
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct(out_shape, q.dtype),
-            jax.ShapeDtypeStruct((heads.batch, heads.num, 1, t.q_len),
-                                 jnp.float32),
-        ],
         scratch_shapes=[
             pltpu.VMEM((heads.lanes, t.block_q), jnp.float32),
             pltpu.VMEM((heads.per, t.block_q), jnp.float32),
             pltpu.VMEM((heads.per, t.block_q), jnp.float32),
             *[pltpu.VMEM((heads.dim, t.block_k), q.dtype)] * staged,
+        ])
+    operands = heads.arrays(q, k, v)
+    if selection is not None:
+        # the choice's block of a grid step, [keys, queries] as the scores
+        # are laid out; dead steps name the row's last live block again
+        grid["in_specs"].append(pl.BlockSpec(
+            (None, t.block_k, t.block_q),
+            lambda b, h, i, j, *_: (b, jnp.minimum(j, t.last_live_k(i)), i)))
+        grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, **grid))
+        kernel = _selected(kernel)
+        operands = (_live_tiles(selection, t), *operands, selection.mask)
+    out, lse = pl.pallas_call(
+        kernel,
+        out_shape=[
+            jax.ShapeDtypeStruct(out_shape, q.dtype),
+            jax.ShapeDtypeStruct((heads.batch, heads.num, 1, t.q_len),
+                                 jnp.float32),
         ],
         interpret=interpret,
-        name="flash_fwd",
-    )(*heads.arrays(q, k, v))
+        name="flash_fwd" if selection is None else "dsa_attend_fwd",
+        **grid,
+    )(*operands)
     if not transposed_out:
         out = out.reshape(q.shape)
     return out, lse[:, :, 0]
@@ -1196,18 +1270,35 @@ def dot_product_attention(q, k, v, causal: bool = True,
                           impl: str = "auto",
                           block_q: int = DEFAULT_BLOCK_Q,
                           block_k: int = DEFAULT_BLOCK_K,
-                          seq_major: bool = False) -> jax.Array:
+                          seq_major: bool = False,
+                          selection=None) -> jax.Array:
     """Attention entry point used by models. [B, H, S, D] or, with
     `seq_major`, [B, S, H, D] (the projections' own layout) in and out.
+    With `selection` (`ops.sparse_index.Selection`) each query attends its
+    chosen keys alone, forward only: the kernel `dsa_attend_fwd`, which has
+    no backward pass.
 
     impl: as `ops._impl.resolve_impl` takes it; the kernels take any width.
     """
     impl = resolve_impl(impl, "attention")
     if impl == "reference":
-        reference = functools.partial(attention_reference, causal=causal,
-                                      scale=scale)
+        reference = functools.partial(
+            attention_reference, causal=causal, scale=scale,
+            mask=None if selection is None else selection.mask)
         if seq_major:
             return _via_head_major(reference, q, k, v)
         return reference(q, k, v)
-    return flash_attention(q, k, v, causal, scale, block_q, block_k,
-                           impl == "pallas_interpret", seq_major)
+    if selection is None:
+        return flash_attention(q, k, v, causal, scale, block_q, block_k,
+                               impl == "pallas_interpret", seq_major)
+
+    def attend(q, k, v, seq_major=False):
+        return _fwd_pallas(
+            q, k, v, scale=scale or 1.0 / math.sqrt(q.shape[-1]),
+            causal=causal, block_q=block_q, block_k=block_k,
+            interpret=impl == "pallas_interpret", seq_major=seq_major,
+            selection=selection)[0]
+
+    if seq_major and not seq_major_fits(q.shape, k.shape):
+        return _via_head_major(attend, q, k, v)
+    return attend(q, k, v, seq_major)
