@@ -24,6 +24,12 @@ is expressed as num_q_heads = G * num_kv_heads; the kernels map q-head h to
 kv-head h // G in BlockSpec index maps, so no K/V replication ever
 materializes.
 
+`dsa_attend_fwd` is the forward walk under a learned choice of keys
+(`ops/sparse_index.py`; forward only): the same rectangles, online softmax
+and order of products, a KV head's whole group of query heads a grid step,
+so that the choice's block is fetched and decoded once for the heads that
+share it (`_attend_kernel`, PERF.md PR 50).
+
 How the kernels spend their time (measured on the v5e, PERF.md PR 26):
 
 * Tile classes. A grid step covers a large tile (by default the whole
@@ -401,7 +407,8 @@ class _Tiling:
 
 class _Heads:
     """Where a call's heads lie in its arrays, and which of them a grid
-    step holds. One set of kernel bodies serves both layouts through it.
+    step holds. One set of kernel bodies serves the first two placements
+    through it; the third is the walk under a choice of keys' own.
 
     Head-major, [B, H, S, Dh]: a grid step's block of q, k, v and their
     gradients is one head, [rows, Dh]. Sequence-major, [B, S, H * Dh] (the
@@ -409,12 +416,16 @@ class _Heads:
     [rows, 128 lanes], `per` = 128 // Dh heads side by side, and the bodies
     walk them one after the other, each over its own columns. Where H is not
     a multiple of `per` (gpt2_xl: 25 heads of 64) the last block holds fewer
-    heads and the bodies skip the absent ones on the program id. The rows
-    of statistics (lse, delta) and the transposed forward output are
-    head-major in both, `per` heads a block."""
+    heads and the bodies skip the absent ones on the program id. Grouped
+    (`grouped`, head-major arrays; `_attend_pallas`): a grid step is a KV
+    head, its block of q and of the output the `per` = `group` query heads
+    that attend it, [group, rows, Dh], against that head's one block of k
+    and v — what the group shares is fetched once for it. The rows of
+    statistics (lse, delta) and the transposed forward output are head-major
+    in all three, `per` heads a block."""
 
-    def __init__(self, q_shape, k_shape, seq_major):
-        self.seq_major = seq_major
+    def __init__(self, q_shape, k_shape, seq_major, grouped=False):
+        self.seq_major, self.grouped = seq_major, grouped
         if seq_major:
             self.batch, self.q_len, self.num, self.dim = q_shape
             self.k_len, self.num_kv = k_shape[1], k_shape[2]
@@ -426,7 +437,7 @@ class _Heads:
         if seq_major:
             assert seq_major_fits(q_shape, k_shape)
             self.lanes = min(128, self.num * self.dim)
-        self.per = self.lanes // self.dim
+        self.per = self.group if grouped else self.lanes // self.dim
         self.steps = pl.cdiv(self.num, self.per)
 
     def arrays(self, *xs):
@@ -445,6 +456,10 @@ class _Heads:
         """`rows` rows of a grid step's heads of q, do, dq (or, with `kv`,
         of k and v under GQA); `row_block(i, j)` of the grid's last two
         indices is the block along the sequence."""
+        if self.grouped:
+            return pl.BlockSpec(
+                (None, None if kv else self.per, rows, self.dim),
+                lambda b, h, i, j, *_: (b, h, row_block(i, j), 0))
         head = (lambda h: h // self.group) if kv else (lambda h: h)
         if self.seq_major:
             return pl.BlockSpec(
@@ -512,21 +527,15 @@ def seq_major_fits(q_shape, k_shape):
 # ---------------------------------------------------------------------------
 
 def _scores(k_ref, cols, q, r0, c, chunk, rel, q_valid=None, k_valid=None,
-            edge=False, sel_ref=None):
+            edge=False):
     """(st, mask): the float32 scores [keys, queries] of the tile's `c`-th
     chunk of keys against the scaled queries `q`, the tile's from `r0` on,
     NEG_INF where an edge rectangle's mask drops them, and that mask (None
-    where nothing masks). With `sel_ref`, the tile's [keys, queries] block
-    of a choice of keys (`ops/sparse_index.py`: causal by itself), the mask
-    of every rectangle is the choice."""
+    where nothing masks)."""
     st = _dot_nt(_rows(k_ref, cols, c * chunk, chunk,
                        k_valid if edge else None), q)
-    if sel_ref is not None:
-        mask = sel_ref[_span(c * chunk, chunk),
-                       r0:r0 + q.shape[0]].astype(jnp.int32) != 0
-    else:
-        mask = _edge_mask(c * chunk, r0, st.shape, rel, q_valid,
-                          k_valid) if edge else None
+    mask = _edge_mask(c * chunk, r0, st.shape, rel, q_valid,
+                      k_valid) if edge else None
     if mask is not None:
         st = jnp.where(mask, st, NEG_INF)
     return st, mask
@@ -556,9 +565,8 @@ def _online_softmax(carry, st, v, mask=None, v_transposed=False):
 
 @functools.partial(jax.jit, static_argnames=(
     "rel", "heads", "dim", "scale", "sub", "chunk", "keyless"))
-def _fwd_unrolled(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, vt_refs,
-                  sel_ref=None, *, rel, heads, dim, scale, sub, chunk,
-                  keyless):
+def _fwd_unrolled(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, vt_refs, *,
+                  rel, heads, dim, scale, sub, chunk, keyless):
     """The forward walk of a whole tile whose place is static (`rel`), for
     the `heads` of the block, each `dim` wide: straight-line code.
 
@@ -592,8 +600,8 @@ def _fwd_unrolled(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, vt_refs,
                                                      chunk=chunk)
             q = _scaled(q_ref[r0:r0 + sub, cols], scale)
             rects += [((k_ref, cols, q, r0, c, chunk, rel, None, None,
-                        c >= interior_end, sel_ref), hh, slice(r0, r0 + sub),
-                       c, c == live_end - 1) for c in range(live_end)]
+                        c >= interior_end), hh, slice(r0, r0 + sub), c,
+                       c == live_end - 1) for c in range(live_end)]
 
     made = [_scores(*args) for args, *_ in rects[:_FWD_AHEAD]]
     for i, ((_, cols, *_), hh, rs, c, last) in enumerate(rects):
@@ -611,8 +619,7 @@ def _fwd_unrolled(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, vt_refs,
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                *vt_refs, scale, t, heads, sub, chunk, transposed_out,
-                sel_ref=None, live_ref=None):
+                *vt_refs, scale, t, heads, sub, chunk, transposed_out):
     """One (q tile, k tile) step of the forward pass, for the heads of the
     block.
 
@@ -628,18 +635,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     Matmul operands are in the inputs' dtype (p cast to it); scores, exp,
     m, l, the accumulator and lse are float32. With `transposed_out` the
     output block is written as it was accumulated, [head_dim, queries].
-    With a choice of keys (`sel_ref`, the tile's [keys, queries] block of a
-    `Selection.mask`) every rectangle is masked by it, a query may have no
-    chosen key in a rectangle, and a tile in which no key is chosen
-    (`live_ref`, [batch x q tiles x k tiles] in SMEM: the pairs chosen a
-    tile) is not walked.
     """
     qb, kb = t.ids(2, 3)
     q_valid, k_valid = t.valid(qb, kb)
-    # queries before the first key, or with no chosen key in a rectangle
-    keyless = (t.causal and t.off < 0) or sel_ref is not None
-    if live_ref is not None:
-        chosen = live_ref[(pl.program_id(0) * t.nq + qb) * t.nk + kb]
+    keyless = t.causal and t.off < 0      # queries before the first key
 
     @_when(kb == 0)
     def _init():
@@ -648,15 +647,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
 
     def walk(rel, hhs):
-        if live_ref is not None:
-            return pl.when(chosen > 0)(
-                functools.partial(walk_live, rel, hhs))
-        return walk_live(rel, hhs)
-
-    def walk_live(rel, hhs):
         if _static(rel) and k_valid is None:
             return _fwd_unrolled(
-                q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, vt_refs, sel_ref,
+                q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, vt_refs,
                 rel=rel, heads=tuple(hhs), dim=heads.dim, scale=scale,
                 sub=sub, chunk=chunk, keyless=keyless)
         for hh in hhs:
@@ -671,7 +664,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
 
                 def step(c, carry, edge):
                     st, mask = _scores(k_ref, cols, q, r0, c, chunk, rel,
-                                       q_valid, k_valid, edge, sel_ref)
+                                       q_valid, k_valid, edge)
                     if vt_refs:
                         v = vt_refs[hh][:, _span(c * chunk, chunk)]
                     else:
@@ -701,14 +694,65 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     _when(kb == t.nk - 1)(heads.each(write))
 
 
-def _selected(kernel):
-    """`_fwd_kernel` as a call that carries a choice of keys hands it its
-    refs: the tiles' chosen pairs first (scalar-prefetched), the choice's
-    block after v's."""
-    def with_choice(live_ref, q_ref, k_ref, v_ref, sel_ref, *rest):
-        kernel(q_ref, k_ref, v_ref, *rest, sel_ref=sel_ref,
-               live_ref=live_ref)
-    return with_choice
+def _fwd_pallas(q, k, v, *, scale, causal, block_q, block_k, interpret,
+                transposed_out=False, seq_major=False):
+    """q, k, v: [B, H, S, D] or, with `seq_major`, [B, S, H, D]. Returns
+    (out in q's layout and dtype, lse [B, H, S] float32); with
+    `transposed_out`, out is [B, H, D, S] in both layouts."""
+    heads = _Heads(q.shape, k.shape, seq_major)
+    t = _Tiling(heads.q_len, heads.k_len, block_q, block_k, causal)
+    sub, chunk = _rect(t.block_q, t.block_k, heads.dim, _FWD_RECT)
+    # v^T scratches, one a head of the block: a v narrower than the lanes
+    # streams its transpose out of the matrix unit at half rate, 8 rows an
+    # instruction, again for every rectangle; at full lanes the unit keeps
+    # pace and the scratch costs more than it saves (PERF.md PR 45)
+    staged = heads.per if heads.dim < 128 else 0
+
+    q_spec = heads.spec(t.block_q, lambda i, j: i)
+    kv_spec = heads.spec(
+        t.block_k, lambda i, j: jnp.minimum(j, t.last_live_k(i)), kv=True)
+    out_spec, out_shape = q_spec, heads.shape(t.q_len)
+    if transposed_out:
+        out_spec = heads.stat_spec(heads.dim, t.block_q, lambda i, j: (0, i))
+        out_shape = (heads.batch, heads.num, heads.dim, t.q_len)
+    out, lse = pl.pallas_call(
+        functools.partial(_fwd_kernel, scale=scale, t=t, heads=heads,
+                          sub=sub, chunk=chunk,
+                          transposed_out=transposed_out),
+        grid=(heads.batch, heads.steps, t.nq, t.nk),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=[
+            out_spec,
+            heads.stat_spec(1, t.block_q, lambda i, j: (0, i)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct(out_shape, q.dtype),
+            jax.ShapeDtypeStruct((heads.batch, heads.num, 1, t.q_len),
+                                 jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((heads.lanes, t.block_q), jnp.float32),
+            pltpu.VMEM((heads.per, t.block_q), jnp.float32),
+            pltpu.VMEM((heads.per, t.block_q), jnp.float32),
+            *[pltpu.VMEM((heads.dim, t.block_k), q.dtype)] * staged,
+        ],
+        interpret=interpret,
+        name="flash_fwd",
+    )(*heads.arrays(q, k, v))
+    if not transposed_out:
+        out = out.reshape(q.shape)
+    return out, lse[:, :, 0]
+
+
+# ---------------------------------------------------------------------------
+# The forward walk under a choice of keys (`dsa_attend_fwd`)
+# ---------------------------------------------------------------------------
+
+# Where a query's running maximum starts under a choice: far above the
+# NEG_INF that a dropped key's score becomes, so that exp(score - maximum) of
+# a dropped key is exactly 0 whether or not the query has a kept key yet, and
+# far below any real score, so that the first kept key takes the maximum over.
+_NO_KEY_YET = -1e20
 
 
 def _live_tiles(selection, t):
@@ -724,77 +768,160 @@ def _live_tiles(selection, t):
     return jnp.swapaxes(tiles, 1, 2).reshape(-1)
 
 
-def _fwd_pallas(q, k, v, *, scale, causal, block_q, block_k, interpret,
-                transposed_out=False, seq_major=False, selection=None):
-    """q, k, v: [B, H, S, D] or, with `seq_major`, [B, S, H, D]. Returns
-    (out in q's layout and dtype, lse [B, H, S] float32); with
-    `transposed_out`, out is [B, H, D, S] in both layouts. With `selection`
-    (`ops.sparse_index.Selection`, queries and keys of one causal sequence
-    of whole tiles) a query attends its chosen keys alone: the same body,
-    as the kernel `dsa_attend_fwd`."""
-    heads = _Heads(q.shape, k.shape, seq_major)
+@functools.partial(jax.jit, static_argnames=("rel", "scale", "sub", "chunk"))
+def _attend_unrolled(hh, q_ref, k_ref, v_ref, bias_ref, acc_ref, m_ref, l_ref,
+                     *, rel, scale, sub, chunk):
+    """The walk of a whole tile whose place is static (`rel`) for the
+    block's `hh`-th query head (traced: the heads are a loop around this,
+    so that the straight-line code is one head's and not the group's):
+    `_fwd_unrolled`'s order, the scores of the next `_FWD_AHEAD` rectangles
+    made before a rectangle's softmax and its p.v. What the choice costs a
+    rectangle is one addition a score: `bias_ref` holds 0 for a chosen key
+    and NEG_INF for any other, and neither the scores nor p are selected on
+    (`_NO_KEY_YET`). Jitted on the refs for the trace's sake, as
+    `_fwd_unrolled` is."""
+    block_q, block_k = q_ref.shape[1], k_ref.shape[0]
+    rects = []      # (scaled queries, their slice, chunk, the slice's last)
+    for r0 in range(0, block_q, sub):
+        _, live_end = _k_chunk_bounds(r0, sub, rel, block_k, chunk=chunk)
+        q = _scaled(q_ref[hh, r0:r0 + sub, :], scale)
+        rects += [(q, slice(r0, r0 + sub), c, c == live_end - 1)
+                  for c in range(live_end)]
+
+    def scores(q, rs, c, _last):
+        keys = slice(c * chunk, (c + 1) * chunk)
+        return _dot_nt(k_ref[keys, :], q) + bias_ref[keys, rs]
+
+    made = [scores(*rect) for rect in rects[:_FWD_AHEAD]]
+    for i, (_, rs, c, last) in enumerate(rects):
+        if i + _FWD_AHEAD < len(rects):
+            made.append(scores(*rects[i + _FWD_AHEAD]))
+        if c == 0:
+            carry = m_ref[hh, :, rs], l_ref[hh, :, rs], acc_ref[hh, :, rs]
+        carry = _online_softmax(carry, made.pop(0),
+                                v_ref[c * chunk:(c + 1) * chunk, :])
+        if last:
+            m_ref[hh, :, rs], l_ref[hh, :, rs], acc_ref[hh, :, rs] = carry
+
+
+def _attend_kernel(live_ref, q_ref, k_ref, v_ref, sel_ref, o_ref, acc_ref,
+                   m_ref, l_ref, bias_ref, *, scale, t, group, sub, chunk):
+    """One (q tile, k tile) step of the forward pass under a choice of keys,
+    for the `group` query heads of one KV head: q_ref and o_ref [group,
+    block_q, head_dim], k_ref and v_ref that head's [block_k, head_dim],
+    sel_ref the tile's [keys, queries] block of the `Selection.mask`, which
+    every head of the group reads.
+
+    The choice is decoded once a step for all of them: sel_ref's int8 becomes
+    a float32 bias in VMEM, 0 where the key is chosen and NEG_INF where not,
+    and a head's scores of a rectangle are k.q plus the bias's rectangle. A
+    query's running maximum starts at `_NO_KEY_YET`, so what a dropped key
+    adds to its sum and accumulator is exp of about NEG_INF, exactly 0, and
+    a kept key's p is what the masked dense form gives; a query with no
+    chosen key at all ends with l = 0 and an output of 0. The heads are a
+    loop, a head's walk straight-line (`_attend_unrolled`); the statistics
+    and the transposed accumulator, a head each, rest in VMEM scratch between
+    k tiles as `_fwd_kernel`'s do. The choice is causal by itself and the
+    tiles are whole and aligned, so a live tile is on the diagonal or wholly
+    below it; a tile in which no key is chosen (`live_ref`, [batch x q tiles
+    x k tiles] in SMEM: the pairs chosen a tile) is not walked."""
+    qb, kb = t.ids(2, 3)
+    chosen = live_ref[(pl.program_id(0) * t.nq + qb) * t.nk + kb]
+
+    @_when(kb == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, _NO_KEY_YET)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    def walk(rel):
+        @pl.when(chosen > 0)
+        def _walk_live():
+            def decode(c, _):
+                keys = _span(c * chunk, chunk)
+                bias_ref[keys, :] = jnp.where(
+                    sel_ref[keys, :].astype(jnp.int32) != 0, 0.0, NEG_INF)
+                return 0
+
+            jax.lax.fori_loop(0, t.block_k // chunk, decode, 0)
+
+            def head(hh, _):
+                _attend_unrolled(hh, q_ref, k_ref, v_ref, bias_ref, acc_ref,
+                                 m_ref, l_ref, rel=rel, scale=scale, sub=sub,
+                                 chunk=chunk)
+                return 0
+
+            jax.lax.fori_loop(0, group, head, 0)
+
+    t.for_each_class(qb, kb, walk)
+
+    @_when(kb == t.nk - 1)
+    def _write():
+        def head(hh, _):
+            l = jnp.where(l_ref[hh] == 0.0, 1.0, l_ref[hh])
+            o_ref[hh] = (acc_ref[hh] / l).T.astype(o_ref.dtype)
+            return 0
+
+        jax.lax.fori_loop(0, group, head, 0)
+
+
+def _attend_pallas(q, k, v, selection, *, scale, causal, block_q, block_k,
+                   interpret):
+    """Causal attention of q [B, H, S, D] over the keys that `selection`
+    (`ops.sparse_index.Selection`, queries and keys of one sequence of whole
+    tiles, as many keys as queries a tile) chooses for each query, every
+    head alike: the kernel `dsa_attend_fwd`. Forward only: it keeps no lse.
+
+    The grid's head axis runs over the KV heads (`_Heads`' group placement):
+    a step holds the query heads that share a k / v block and the choice's
+    block, so both are fetched once a group and the choice is decoded once
+    for it (`_attend_kernel`)."""
+    heads = _Heads(q.shape, k.shape, False, grouped=True)
     t = _Tiling(heads.q_len, heads.k_len, block_q, block_k, causal)
-    sub, chunk = _rect(t.block_q, t.block_k, heads.dim, _FWD_RECT)
-    if selection is not None and (not causal or t.off or t.ragged_q
-                                  or t.ragged_k):
+    if not causal or t.off or t.ragged_q or t.ragged_k or not t.aligned:
         raise NotImplementedError(
             "attention over a choice of keys: one causal sequence of whole "
-            f"tiles, got {heads.q_len} queries, {heads.k_len} keys under "
-            f"tiles of {t.block_q} x {t.block_k}")
-    # v^T scratches, one a head of the block: a v narrower than the lanes
-    # streams its transpose out of the matrix unit at half rate, 8 rows an
-    # instruction, again for every rectangle; at full lanes the unit keeps
-    # pace and the scratch costs more than it saves (PERF.md PR 45)
-    staged = heads.per if heads.dim < 128 else 0
-
+            f"tiles, as many keys as queries a tile, got {heads.q_len} "
+            f"queries, {heads.k_len} keys under tiles of {t.block_q} x "
+            f"{t.block_k}")
+    sub, chunk = _rect(t.block_q, t.block_k, heads.dim, _FWD_RECT)
+    live_k = lambda i, j: jnp.minimum(j, t.last_live_k(i))
     q_spec = heads.spec(t.block_q, lambda i, j: i)
-    kv_spec = heads.spec(
-        t.block_k, lambda i, j: jnp.minimum(j, t.last_live_k(i)), kv=True)
-    out_spec, out_shape = q_spec, heads.shape(t.q_len)
-    if transposed_out:
-        out_spec = heads.stat_spec(heads.dim, t.block_q, lambda i, j: (0, i))
-        out_shape = (heads.batch, heads.num, heads.dim, t.q_len)
-    kernel = functools.partial(_fwd_kernel, scale=scale, t=t, heads=heads,
-                               sub=sub, chunk=chunk,
-                               transposed_out=transposed_out)
-    grid = dict(
-        grid=(heads.batch, heads.steps, t.nq, t.nk),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=[
-            out_spec,
-            heads.stat_spec(1, t.block_q, lambda i, j: (0, i)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((heads.lanes, t.block_q), jnp.float32),
-            pltpu.VMEM((heads.per, t.block_q), jnp.float32),
-            pltpu.VMEM((heads.per, t.block_q), jnp.float32),
-            *[pltpu.VMEM((heads.dim, t.block_k), q.dtype)] * staged,
-        ])
-    operands = heads.arrays(q, k, v)
-    if selection is not None:
-        # the choice's block of a grid step, [keys, queries] as the scores
-        # are laid out; dead steps name the row's last live block again
-        grid["in_specs"].append(pl.BlockSpec(
-            (None, t.block_k, t.block_q),
-            lambda b, h, i, j, *_: (b, jnp.minimum(j, t.last_live_k(i)), i)))
-        grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, **grid))
-        kernel = _selected(kernel)
-        operands = (_live_tiles(selection, t), *operands, selection.mask)
-    out, lse = pl.pallas_call(
-        kernel,
-        out_shape=[
-            jax.ShapeDtypeStruct(out_shape, q.dtype),
-            jax.ShapeDtypeStruct((heads.batch, heads.num, 1, t.q_len),
-                                 jnp.float32),
-        ],
+    kv_spec = heads.spec(t.block_k, live_k, kv=True)
+    stat = pltpu.VMEM((heads.group, 1, t.block_q), jnp.float32)
+    # two buffers of every block; a head's accumulator and its two rows of
+    # statistics (a row is stored as eight); the bias
+    size = jnp.dtype(q.dtype).itemsize
+    vmem = (2 * ((2 * heads.group * t.block_q + 2 * t.block_k) * heads.dim
+                 * size + t.block_k * t.block_q)
+            + heads.group * (heads.dim + 16) * t.block_q * 4
+            + t.block_k * t.block_q * 4)
+    return pl.pallas_call(
+        functools.partial(_attend_kernel, scale=scale, t=t, group=heads.group,
+                          sub=sub, chunk=chunk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(heads.batch, heads.steps, t.nq, t.nk),
+            in_specs=[
+                q_spec, kv_spec, kv_spec,
+                # the choice's block of a grid step, [keys, queries] as the
+                # scores are laid out; dead steps name the row's last live
+                # block again
+                pl.BlockSpec((None, t.block_k, t.block_q),
+                             lambda b, h, i, j, *_: (b, live_k(i, j), i)),
+            ],
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((heads.group, heads.dim, t.block_q), jnp.float32),
+                stat, stat,
+                pltpu.VMEM((t.block_k, t.block_q), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(vmem + vmem // 4, _VMEM_UNASKED)),
         interpret=interpret,
-        name="flash_fwd" if selection is None else "dsa_attend_fwd",
-        **grid,
-    )(*operands)
-    if not transposed_out:
-        out = out.reshape(q.shape)
-    return out, lse[:, :, 0]
+        name="dsa_attend_fwd",
+    )(_live_tiles(selection, t), q, k, v, selection.mask)
 
 
 # ---------------------------------------------------------------------------
@@ -1292,13 +1419,10 @@ def dot_product_attention(q, k, v, causal: bool = True,
         return flash_attention(q, k, v, causal, scale, block_q, block_k,
                                impl == "pallas_interpret", seq_major)
 
-    def attend(q, k, v, seq_major=False):
-        return _fwd_pallas(
-            q, k, v, scale=scale or 1.0 / math.sqrt(q.shape[-1]),
+    def attend(q, k, v):
+        return _attend_pallas(
+            q, k, v, selection, scale=scale or 1.0 / math.sqrt(q.shape[-1]),
             causal=causal, block_q=block_q, block_k=block_k,
-            interpret=impl == "pallas_interpret", seq_major=seq_major,
-            selection=selection)[0]
+            interpret=impl == "pallas_interpret")
 
-    if seq_major and not seq_major_fits(q.shape, k.shape):
-        return _via_head_major(attend, q, k, v)
-    return attend(q, k, v, seq_major)
+    return _via_head_major(attend, q, k, v) if seq_major else attend(q, k, v)

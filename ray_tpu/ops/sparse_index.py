@@ -12,7 +12,11 @@ What comes back is the choice as the attention kernels read it
 (`ops/attention.py`, `selection=`), a `Selection`:
 
     mask   [B, S keys, S queries] int8: 1 where the query attends the key.
-           Keys on the second axis, as the kernels lay their scores out
+           Keys on the second axis, as the kernels lay their scores out.
+           Every head reads the same choice: `dsa_attend_fwd` fetches a
+           [k tile, q tile] block of it once for the query heads of a KV
+           head, turns it once into a float32 bias (0 chosen, -1e30 not)
+           and adds that to each head's scores
     counts [B, S / tile, S queries] int32: how many of a query's chosen
            keys lie in each tile of `count_tile(S)` keys — the per-tile
            summary from which a caller finds the rectangles that hold no

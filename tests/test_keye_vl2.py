@@ -25,13 +25,14 @@ from jax.sharding import SingleDeviceSharding
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
     __file__))))
 
-from _chip import _kernel_names, benchmark_config, v5e     # noqa: E402,F401
+from _chip import (_kernel_calls, _kernel_names,          # noqa: E402,F401
+                   benchmark_config, v5e)
 from benchmarks.reference import keye_vl2, keye_vl2_glue   # noqa: E402
 from ray_tpu.models.gpt import GPT, GPTConfig, llama_tiny  # noqa: E402
 from ray_tpu.ops.attention import (_Tiling, _live_tiles,   # noqa: E402
                                    dot_product_attention)
-from ray_tpu.ops.sparse_index import (count_tile, index_scores,  # noqa: E402
-                                      sparse_index)
+from ray_tpu.ops.sparse_index import (_summary, count_tile,  # noqa: E402
+                                      index_scores, sparse_index)
 
 TOPK = 64
 PUBLISHED = dict(
@@ -160,12 +161,20 @@ def _attention_inputs(batch, length, heads, kv_heads, dim, seed=1):
 
 
 @pytest.mark.parametrize("heads,kv_heads,dim,block", [
-    (4, 4, 64, 1024),       # the projections' own layout, two heads a block
-    (4, 2, 128, 1024),      # a GQA group at full lanes: head-major
-    (4, 2, 128, 128)],      # several tiles a row
-    ids=["seq_major", "gqa_128", "gqa_128_tiles"])
+    (4, 4, 64, 1024),       # no group, a head narrower than the lanes
+    (4, 2, 128, 1024),      # a GQA group at full lanes
+    (4, 2, 128, 128),       # two q tiles x two k tiles
+    (2, 2, 128, 128),       # groups of 1, 4 and 8 over those four tiles
+    (8, 2, 128, 128),
+    (8, 1, 128, 128),
+    (8, 1, 64, 1024)],
+    ids=["seq_major", "gqa_128", "gqa_128_tiles", "group_1_tiles",
+         "group_4_tiles", "group_8_tiles", "group_8_narrow"])
 def test_attention_over_a_choice_is_the_masked_dense_form(heads, kv_heads,
                                                           dim, block):
+    """Every query head of a grid step's group, at every tile of the grid,
+    reads the one block of the choice: held to the masked dense form head
+    by head."""
     q, k, v = _attention_inputs(2, 256, heads, kv_heads, dim)
     choice = sparse_index(*_index_inputs(2, 256), 32, impl="reference")
     want = dot_product_attention(q, k, v, impl="reference", seq_major=True,
@@ -174,8 +183,77 @@ def test_attention_over_a_choice_is_the_masked_dense_form(heads, kv_heads,
                                 seq_major=True, selection=choice,
                                 block_q=block, block_k=block)
     dense = dot_product_attention(q, k, v, impl="reference", seq_major=True)
-    assert float(jnp.abs(got - want).max()) < 1e-5
+    assert float(jnp.abs(got - want).max(axis=(0, 1, 3)).max()) < 1e-5
     assert float(jnp.abs(dense - want).max()) > 0.1     # the choice matters
+
+
+def test_a_query_without_a_chosen_key_in_a_rectangle_a_tile_or_at_all():
+    """The walk selects on nothing after the exp: a query's running maximum
+    starts far above what a dropped score becomes, so a rectangle, or a whole
+    k tile, in which a query has no chosen key adds exactly nothing to it,
+    before its first kept key and after it. 512 positions under tiles of 256
+    (rectangles of 128): queries 130..139 have no key among the first 128,
+    queries 300..339 none in the first k tile, queries 260..263 none in the
+    second (their last), and queries 5..8 and 400..404 none at all — those
+    come out 0, every other as the masked dense form has it."""
+    chosen = np.asarray(sparse_index(*_index_inputs(1, 512), 48,
+                                     impl="reference").mask[0]) != 0
+    chosen[:128, 130:140] = False       # [keys, queries]
+    chosen[:256, 300:340] = False
+    chosen[256:, 260:264] = False
+    keyless = np.r_[5:9, 400:405]
+    chosen[:, keyless] = False
+    assert chosen[:, 130:140].any(0).all() and chosen[:, 300:340].any(0).all()
+    assert chosen[:, 260:264].any(0).all()
+    choice = _summary(jnp.asarray(chosen.T[None]))
+    q, k, v = _attention_inputs(1, 512, 8, 2, 128)
+    want = dot_product_attention(q, k, v, impl="reference", seq_major=True,
+                                 selection=choice)
+    got = dot_product_attention(q, k, v, impl="pallas_interpret",
+                                seq_major=True, selection=choice,
+                                block_q=256, block_k=256)
+    assert bool(jnp.isfinite(got).all())
+    keyed = np.setdiff1d(np.arange(512), keyless)
+    assert float(jnp.abs(got - want)[:, keyed].max()) < 1e-5
+    assert float(jnp.abs(got[:, keyless]).max()) == 0.0
+    assert float(jnp.abs(got[:, keyed]).min(axis=(0, 2, 3)).max()) > 0
+
+
+def _pallas_calls(jaxpr, name):
+    """The `pallas_call` equations called `name`, nested ones included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call" and \
+                eqn.params["name"] == name:
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _pallas_calls(sub, name)
+    return found
+
+
+def test_the_choice_is_fetched_once_a_gqa_group():
+    """The grid of `dsa_attend_fwd` runs over the KV heads, not the query
+    heads: a step holds the eight query heads of Keye's group against their
+    one k / v block and the one block of the choice, whose index ignores the
+    head — so the choice's bytes a call are batch x KV heads x live tiles x
+    a block, an eighth of a head a step."""
+    q, k, v = _attention_inputs(2, 512, 32, 4, 128)
+    choice = sparse_index(*_index_inputs(2, 512), 64, impl="reference")
+    jaxpr = jax.make_jaxpr(lambda q, k, v, s: dot_product_attention(
+        q, k, v, impl="pallas_interpret", seq_major=True, selection=s,
+        block_q=256, block_k=256))(q, k, v, choice)
+    (call,) = _pallas_calls(jaxpr.jaxpr, "dsa_attend_fwd")
+    mapping = call.params["grid_mapping"]
+    assert tuple(mapping.grid) == (2, 4, 2, 2)  # batch, KV heads, q, k tiles
+    blocks = [tuple(getattr(d, "block_size", None) for d in b.block_shape)
+              for b in mapping.block_mappings]
+    assert blocks == [
+        (None, 8, 256, 128),        # q: the group's eight heads
+        (None, None, 256, 128),     # k and v: their one KV head
+        (None, None, 256, 128),
+        (None, 256, 256),           # the choice: no head axis at all
+        (None, 8, 256, 128)]        # the output
+    assert not _pallas_calls(jaxpr.jaxpr, "flash_fwd")
 
 
 def test_tiles_without_a_chosen_key_are_not_walked():
@@ -442,6 +520,12 @@ def test_the_largest_served_bucket_compiles_and_fits_a_v5e(v5e):
     assert _kernel_names(compiled, "moe_") == ["moe_segsum",
                                                "moe_topk_rounds"]
     assert not _kernel_names(compiled, "flash_")
+    # the group's blocks, accumulators and the decoded choice: past the
+    # compiler's 16 MiB, so the call says what it needs, a fifth of the core's
+    (call,) = _kernel_calls(compiled, "dsa_attend_fwd")
+    stated = int(re.search(
+        r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', call).group(1))
+    assert 16 * 2 ** 20 < stated < 28 * 2 ** 20, stated
     mem = compiled.memory_analysis()
     total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
