@@ -918,9 +918,9 @@ class GPT:
         means over the layers and, per layer, `moe_expert_tokens`
         [n_layers, n_experts], `moe_expert_choice` [n_layers, tokens,
         top_k] and, where the layers hold a share of their experts,
-        `moe_routed_here` [n_layers]; with "sparse" layers,
-        `dsa_selected_pairs` [n_layers], the (query, key) pairs each
-        layer's indexer chose; with neither, an empty dict."""
+        `moe_routed_here` and `moe_rows_walked` [n_layers]; with "sparse"
+        layers, `dsa_selected_pairs` [n_layers], the (query, key) pairs
+        each layer's indexer chose; with neither, an empty dict."""
         c = self.config
         if positions is None:
             positions = jnp.broadcast_to(
@@ -1120,10 +1120,12 @@ class GPT:
                                         / counts.mean(-1)).mean())
             if "moe_routed_here" in aux:
                 # a share of the experts: per layer, what each held expert
-                # was given beside the router's count of what it sent here
+                # was given beside the router's count of what it sent here,
+                # and the rows the walk took for them, real or not
                 first = c.moe_first_expert
                 metrics.update(
                     moe_expert_tokens=aux["moe_expert_tokens"][
                         :, first:first + c.experts_held],
-                    moe_routed_here=aux["moe_routed_here"])
+                    moe_routed_here=aux["moe_routed_here"],
+                    moe_rows_walked=aux["moe_rows_walked"])
         return loss, metrics

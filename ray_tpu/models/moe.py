@@ -25,12 +25,27 @@ the weights it is given: one chip's part of a layer that several share). It
 then routes over all of them, and computes the part of the result that its
 own experts give: the (token, expert) pairs routed to them, found as one
 contiguous run of the sorted order. That run has no static length, so it is
-walked in chunks (`HELD_CHUNK_SHARE` times the run's length at balanced
-routing) by a loop whose trip count is the run's length: every pair of the
-run is gathered, multiplied and added to its token's row, however many there
-are, and memory is one chunk's whatever the skew. Added how: a chunk's rows
-come out of the grouped matmuls sorted by expert; one sort of the chunk's
-token ids and one row gather put them in token order, where a token's rows
+walked in trips of a static number of rows by a loop whose trip count follows
+the run's length: every pair of the run is gathered, multiplied and added to
+its token's row, however many there are. A trip costs what its static rows
+cost, real or not (XLA's gather takes every index it is handed, the grouped
+matmuls every row), so a trip takes one of a short ladder of sizes
+(`_held_trip_sizes`): whole trips are the chunk's — `HELD_CHUNK_SHARE` times
+the run's length at balanced routing, which bounds the walk's memory
+whatever the skew — and the trip that takes what is left is the smallest size
+that holds it, chosen inside the trip (`_walk`). The one smaller size lies
+midway between the balance and the chunk: a layer routed about its share,
+a little under or a little over, so walks three quarters of the rows the
+chunk alone would, whichever side of the balance it falls on, and a hot one
+takes no more trips than before. The ladder is short because a size is a
+body of its own, forward and backward, wherever a program holds the walk:
+the bodies are jitted functions of their arrays (`_held_trip`,
+`_held_trip_bwd`), lowered once a size a program however many layers call
+them, but the compiler still builds each where it is called, and a program's
+start pays for every kernel its executable holds (PERF.md, PR 52). Added how: a
+chunk's rows come out of the grouped matmuls sorted by expert; one sort of
+the chunk's token ids (made once a trip, outside the choice of its size)
+and one row gather put them in token order, where a token's rows
 (at most K, its choices being distinct experts) are a run, and the runs are
 summed into their tokens in float32 as they stream past
 (`ops.segment_sum.sorted_segment_sum`: a Pallas kernel on a TPU, a segment
@@ -43,7 +58,7 @@ sort key and nothing more; what the absent experts would add is left out.
 from __future__ import annotations
 
 import functools
-from typing import Dict, Tuple
+from typing import Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -80,11 +95,20 @@ def _take_rows_bwd(copies, inverse, g):
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
-# Pairs a trip of the held share's loop gathers, multiplies and adds back,
-# as a multiple of the share's pairs at balanced routing (T x K x held / E):
-# the static bound on its memory (rows x d_model), not on how many pairs it
-# takes. At 2 a share takes one trip unless it runs hotter than twice the
-# balance, so a step's time does not move with every swing of the routing.
+# Rows of the walk's largest trip, the chunk, as a multiple of the share's
+# pairs at balanced routing (T x K x held / E): the static bound on the
+# walk's memory (rows x d_model), not on how many pairs it takes. At 2 a
+# share takes one trip unless it runs hotter than twice the balance. What a
+# trip that is not full may take instead is `_held_trip_sizes`'s: one size
+# midway between the balance and the chunk, and nothing finer — every size is
+# another body, forward and backward, in each program's executable. Not the
+# balance itself: layers routed about their share scatter around it, so a
+# size there cuts through the middle of them, and which side a layer falls
+# on, half the chunk's rows or all of them, changes with the seed — the
+# step's time followed the routing (`qwen3next-steady` spread 1.1% over its
+# seeds, `keye2-score-16k-over` 3.6%; PERF.md, PR 52: what each size bought
+# and cost). Midway, a layer up to one and a half times its share takes the
+# smaller trip, and one beyond that was never the usual case.
 HELD_CHUNK_SHARE = 2.0
 
 
@@ -96,118 +120,210 @@ def _swiglu_groups(rows, w_up, w_gate, w_down, sizes):
         return lax.ragged_dot(jax.nn.silu(gate) * up, w_down, sizes)
 
 
-def _held_chunk(c, x, gate_vals, order, start, counts, rows_per_chunk):
-    """Trip `c` of the walk over the run of pairs routed to held experts
-    (`counts` of them per held expert, the run starting at `start` of the
-    sorted `order`, which is followed by a chunk's length of padding so that
-    a chunk is a slice of it wherever it starts): the pairs' ids, tokens and
-    routing weights, which of the chunk's rows are real, the rows of x, and
-    how many of the chunk's rows each held expert takes."""
+class _Run(NamedTuple):
+    """What every trip of a walk over the held experts' run reads and none
+    changes: x [T, D]; gate_vals [T, K] float32; the weights of the H held
+    experts; `order`, the (token, choice) pairs sorted by expert and followed
+    by a chunk's length of padding, so that a trip is a slice of it wherever
+    it starts; `start`, where the held experts' run begins in it; `counts`
+    [H], how many pairs each held expert takes."""
+    x: jax.Array
+    gate_vals: jax.Array
+    w_up: jax.Array
+    w_gate: jax.Array
+    w_down: jax.Array
+    order: jax.Array
+    start: jax.Array
+    counts: jax.Array
+
+
+def _held_chunk(lo, rows, run: _Run):
+    """A trip of the walk: `rows` pairs from the run's `lo`-th on. Returns
+    the pairs' ids and tokens, which of the trip's rows are real, the rows
+    of x, and how many of the trip's rows each held expert takes."""
     with jax.named_scope("moe_dispatch"):
-        top_k = gate_vals.shape[-1]
-        ends = jnp.cumsum(counts)
-        lo = c * rows_per_chunk
-        valid = lo + jnp.arange(rows_per_chunk, dtype=jnp.int32) < ends[-1]
-        pair = lax.dynamic_slice(order, (start + lo,), (rows_per_chunk,))
-        token = pair // top_k
-        weight = jnp.where(valid, gate_vals.reshape(-1)[pair], 0.0)
-        sizes = jnp.clip(jnp.minimum(ends, lo + rows_per_chunk)
-                         - jnp.maximum(ends - counts, lo), 0, None)
-        return (pair, token, weight, valid, x[token],
-                sizes.astype(jnp.int32))
+        ends = jnp.cumsum(run.counts)
+        valid = lo + jnp.arange(rows, dtype=jnp.int32) < ends[-1]
+        pair = lax.dynamic_slice(run.order, (run.start + lo,), (rows,))
+        token = pair // run.gate_vals.shape[-1]
+        sizes = jnp.clip(jnp.minimum(ends, lo + rows)
+                         - jnp.maximum(ends - run.counts, lo), 0, None)
+        return pair, token, valid, run.x[token], sizes.astype(jnp.int32)
 
 
-def _sum_into_tokens(onto, c, rows, token, valid, weight=None, *, impl):
-    """onto[t] plus the sum of weight x rows over the chunk's real rows of
-    token t, in float32: trip `c`'s rows added to their tokens' rows. The
-    chunk comes sorted by expert; one sort of its token ids (rows that are
-    not real last) and one row gather put it in token order, and there a
-    token's rows are a run, summed by `ops.segment_sum` as they stream past
-    — where `onto.at[token].add(rows)` is a scatter, which on a TPU costs
-    several such gathers. The first trip does not read `onto`."""
-    ids, at, *weight = lax.sort(
-        (jnp.where(valid, token, onto.shape[0]),
-         jnp.arange(token.shape[0], dtype=jnp.int32),
-         *(() if weight is None else (weight,))), num_keys=1)
-    return sorted_segment_sum(rows[at], ids, onto.shape[0], *weight,
-                              onto=(onto, c > 0), impl=impl)
+@functools.partial(jax.jit, static_argnames=("chunk", "top_k", "n_tokens",
+                                             "pairs"))
+def _by_token(lo, order, start, total, *, chunk: int, top_k: int,
+              n_tokens: int, pairs: bool):
+    """A chunk's length of the run from its `lo`-th pair on, put in token
+    order by one sort: each row's token (`n_tokens` for a row that is not
+    real: those sort last, so the first rows of the result hold every real
+    row of whatever size of trip takes what is left), its place in the
+    slice (0 for a row that is not real) and, with `pairs`, its pair's id as
+    the sort's payload. Made once a trip, outside the choice of its size:
+    the sort is the largest piece of a trip's code and of its compile, and
+    costs the device next to nothing (PERF.md, PR 52)."""
+    pair = lax.dynamic_slice(order, (start + lo,), (chunk,))
+    place = jnp.arange(chunk, dtype=jnp.int32)
+    ids, at, *pair = lax.sort(
+        (jnp.where(lo + place < total, pair // top_k, n_tokens), place,
+         *((pair,) if pairs else ())), num_keys=1)
+    return (ids, jnp.where(ids < n_tokens, at, 0), *pair)
+
+
+def _sum_into_tokens(onto, lo, rows, by_token, weight=None, *, impl):
+    """onto[t] plus the sum of weight x rows over the trip's real rows of
+    token t, in float32: the rows of the trip that starts at the run's
+    `lo`-th pair added to their tokens' rows. The trip comes sorted by
+    expert; `by_token` (`_by_token`, cut to the trip's rows) and one row
+    gather put it in token order, and there a token's rows are a run,
+    summed by `ops.segment_sum` as they stream past — where
+    `onto.at[token].add(rows)` is a scatter, which on a TPU costs several
+    such gathers. `weight` is in token order already. The first trip does
+    not read `onto`."""
+    ids, at = by_token
+    return sorted_segment_sum(rows[at], ids, onto.shape[0], weight,
+                              onto=(onto, lo > 0), impl=impl)
 
 
 def _held_chunk_rows(pairs: int, share: float) -> int:
-    """Rows a trip of the walk: `HELD_CHUNK_SHARE` times the share's pairs
-    at balance, in whole 1,024s, and no more than all the pairs."""
+    """Rows of the walk's largest trip: `HELD_CHUNK_SHARE` times the share's
+    pairs at balance, in whole 1,024s, and no more than all the pairs."""
     rows = -(-int(HELD_CHUNK_SHARE * pairs * share) // 1024) * 1024
     return max(1, min(rows, pairs))
 
 
-def _n_chunks(counts, rows_per_chunk):
-    return (counts.sum() + rows_per_chunk - 1) // rows_per_chunk
+def _held_trip_sizes(pairs: int, share: float) -> Tuple[int, ...]:
+    """The rows a trip of the walk may take, largest first (`_walk`): the
+    chunk, and for a last trip the rows midway between the share's pairs at
+    balance and the chunk, in whole 1,024s, where that is less."""
+    chunk = _held_chunk_rows(pairs, share)
+    midway = -(-int((1.0 + HELD_CHUNK_SHARE) / 2 * pairs * share)
+               // 1024) * 1024
+    return (chunk, midway) if 0 < midway < chunk else (chunk,)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
-def _held_experts(x, gate_vals, w_up, w_gate, w_down, order, start, counts,
-                  rows_per_chunk: int, impl: str):
-    """The held experts' part of the block's result. x: [T, D]; gate_vals:
-    [T, K] float32; the weights of the H held experts; `order` the (token,
-    choice) pairs sorted by expert, `start` where the held experts' run
-    begins in it and `counts` [H] how many pairs each takes. Returns the
-    result in token order ([T, D] float32) and the rows each held expert was
-    given ([H] int32, the grouped matmuls' own group sizes summed over the
-    trips). The loop's trip count follows the run, so autodiff cannot pass
-    it: the backward pass is the same walk, each chunk's products made again
-    and differentiated."""
-    def trip(c, carry):
-        out, given = carry
-        _, token, weight, valid, rows, sizes = _held_chunk(
-            c, x, gate_vals, order, start, counts, rows_per_chunk)
-        y = _swiglu_groups(rows, w_up, w_gate, w_down, sizes)
-        with jax.named_scope("moe_combine"):
-            return _sum_into_tokens(out, c, y, token, valid, weight,
-                                    impl=impl), given + sizes
+def _walk(trip, carry, sums, run: _Run, *more, sizes, pairs, scope):
+    """`carry` and `sums` through the trips that cover the run. `sizes` are
+    the rows a trip may take, largest first: whole trips are the chunk's,
+    `sizes[0]`, and the trip that takes what is left is the smallest size
+    that holds it — chosen inside the trip, by a `lax.switch` on the pairs
+    left, so the one loop holds one body a size.
+    `trip(carry, lo, by_token, run, *more, rows=)` walks `rows` pairs from
+    the run's `lo`-th on and returns the new carry and what it adds to
+    `sums`. The adding is done here, outside the switch: a conditional's
+    results are buffers of their own, and sums handed through it — the three
+    float32 gradients of the held weights, 134 MB each in Qwen3-Next's step
+    — were copied on every trip (read in the step compiled for a v5e:
+    PERF.md, PR 52). The [T, D] rows do go through: `ops.segment_sum`
+    writes them in place. The trip's order by token is made here too
+    (`_by_token`, under `scope`; with the pairs' ids where `pairs`), once
+    for whichever size takes it."""
+    chunk, total = sizes[0], run.counts.sum()
+    n_tokens, top_k = run.gate_vals.shape
 
-    return lax.fori_loop(
-        0, _n_chunks(counts, rows_per_chunk), trip,
-        (jnp.zeros(x.shape, jnp.float32), jnp.zeros_like(counts)))
+    def body(c, state):
+        carry, sums = state
+        lo = c * chunk
+        with jax.named_scope(scope):
+            by_token = _by_token(lo, run.order, run.start, total, chunk=chunk,
+                                 top_k=top_k, n_tokens=n_tokens, pairs=pairs)
+        carry, addends = lax.switch(
+            sum((total - lo <= rows).astype(jnp.int32) for rows in sizes[1:]),
+            [functools.partial(trip, rows=rows) for rows in sizes],
+            carry, lo, by_token, run, *more)
+        return carry, jax.tree_util.tree_map(
+            lambda a, b: a + b.astype(a.dtype), sums, addends)
+
+    return lax.fori_loop(0, (total + chunk - 1) // chunk, body,
+                         (carry, sums))
 
 
-def _held_experts_fwd(x, gate_vals, w_up, w_gate, w_down, order, start,
-                      counts, rows_per_chunk, impl):
-    out = _held_experts(x, gate_vals, w_up, w_gate, w_down, order, start,
-                        counts, rows_per_chunk, impl)
-    return out, (x, gate_vals, w_up, w_gate, w_down, order, start, counts)
+# The walk's two trips, forward and backward, are functions of their arrays
+# that close over nothing, jitted with the trip's rows and `impl` static: a
+# program that holds several walks of the same shapes (a period of layers
+# written out inside the scan, each walked forward, forward again under the
+# checkpoint and backward) lowers each trip once a size, and traces it once a
+# size wherever jax's cache of traces reaches, which is the trace around it
+# (a kind of block under `jax.checkpoint`, the backward pass).
+
+@functools.partial(jax.jit, static_argnames=("rows", "impl"))
+def _held_trip(out, lo, by_token, run: _Run, *, rows: int, impl: str):
+    """`rows` pairs of the held experts' run from its `lo`-th on, forward:
+    gathered, multiplied and added to `out`, the result so far in token
+    order ([T, D] float32). Returns it with what the trip adds to the
+    walk's counts: the rows each held expert was given, and the rows it
+    took, real or not."""
+    *_, taken, sizes = _held_chunk(lo, rows, run)
+    y = _swiglu_groups(taken, run.w_up, run.w_gate, run.w_down, sizes)
+    with jax.named_scope("moe_combine"):
+        ids, at, pair = (a[:rows] for a in by_token)
+        out = _sum_into_tokens(out, lo, y, (ids, at),
+                               run.gate_vals.reshape(-1)[pair], impl=impl)
+    return out, (sizes, jnp.int32(rows))
 
 
-def _held_experts_bwd(rows_per_chunk, impl, res, cotangents):
-    x, gate_vals, w_up, w_gate, w_down, order, start, counts = res
-    d_out = cotangents[0]
+@functools.partial(jax.jit, static_argnames=("rows", "impl"))
+def _held_trip_bwd(carry, lo, by_token, run: _Run, d_out, *, rows: int,
+                   impl: str):
+    """The same trip backward: its products made again and differentiated.
+    `carry`: the gradients so far of x ([T, D] float32) and of the routing
+    weights ([T x K]). Returns them with what the trip adds to the
+    gradients of the three held weights."""
     f32 = jnp.float32
+    dx, d_gate = carry
+    pair, token, valid, taken, sizes = _held_chunk(lo, rows, run)
+    y, pull = jax.vjp(
+        lambda r, *w: _swiglu_groups(r, *w, sizes), taken, run.w_up,
+        run.w_gate, run.w_down)
+    with jax.named_scope("moe_combine"):
+        dy = d_out[token]
+        d_gate = d_gate.at[pair].add(jnp.where(
+            valid, (dy * y.astype(f32)).sum(-1), 0.0))
+        weight = run.gate_vals.reshape(-1)[pair]
+        dy = jnp.where(valid[:, None], dy * weight[:, None],
+                       0.0).astype(y.dtype)
+    d_taken, *dw = pull(dy)
+    with jax.named_scope("moe_dispatch"):
+        dx = _sum_into_tokens(dx, lo, d_taken,
+                              tuple(a[:rows] for a in by_token), impl=impl)
+    return (dx, d_gate), tuple(dw)
 
-    def trip(c, carry):
-        dx, d_gate, d_weights = carry
-        pair, token, weight, valid, rows, sizes = _held_chunk(
-            c, x, gate_vals, order, start, counts, rows_per_chunk)
-        y, pull = jax.vjp(
-            lambda r, *w: _swiglu_groups(r, *w, sizes), rows, w_up, w_gate,
-            w_down)
-        with jax.named_scope("moe_combine"):
-            dy = d_out[token]
-            d_gate = d_gate.at[pair].add(jnp.where(
-                valid, (dy * y.astype(f32)).sum(-1), 0.0))
-            dy = jnp.where(valid[:, None], dy * weight[:, None],
-                           0.0).astype(y.dtype)
-        d_rows, *dw = pull(dy)
-        with jax.named_scope("moe_dispatch"):
-            dx = _sum_into_tokens(dx, c, d_rows, token, valid, impl=impl)
-        return dx, d_gate, tuple(a + b.astype(f32)
-                                 for a, b in zip(d_weights, dw))
 
-    dx, d_gate, d_weights = lax.fori_loop(
-        0, _n_chunks(counts, rows_per_chunk), trip,
-        (jnp.zeros(x.shape, f32), jnp.zeros(gate_vals.size, f32),
-         tuple(jnp.zeros(w.shape, f32) for w in (w_up, w_gate, w_down))))
-    return (dx.astype(x.dtype), d_gate.reshape(gate_vals.shape).astype(
-        gate_vals.dtype), *(d.astype(w.dtype) for d, w in zip(
-            d_weights, (w_up, w_gate, w_down))), None, None, None)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _held_experts(run: _Run, trip_sizes: Tuple[int, ...], impl: str):
+    """The held experts' part of the block's result: the result in token
+    order ([T, D] float32), the rows each held expert was given ([H] int32,
+    the grouped matmuls' own group sizes summed over the trips) and the
+    rows the walk took for them (int32: each trip's rows, real or not,
+    counted as it is taken). The loop's trip count follows the run, so
+    autodiff cannot pass it: the backward pass is the same walk, each
+    trip's products made again and differentiated."""
+    out, (given, walked) = _walk(
+        functools.partial(_held_trip, impl=impl),
+        jnp.zeros(run.x.shape, jnp.float32),
+        (jnp.zeros_like(run.counts), jnp.int32(0)), run,
+        sizes=trip_sizes, pairs=True, scope="moe_combine")
+    return out, given, walked
+
+
+def _held_experts_fwd(run, trip_sizes, impl):
+    return _held_experts(run, trip_sizes, impl), run
+
+
+def _held_experts_bwd(trip_sizes, impl, run, cotangents):
+    f32 = jnp.float32
+    weights = (run.w_up, run.w_gate, run.w_down)
+    (dx, d_gate), d_weights = _walk(
+        functools.partial(_held_trip_bwd, impl=impl),
+        (jnp.zeros(run.x.shape, f32), jnp.zeros(run.gate_vals.size, f32)),
+        tuple(jnp.zeros(w.shape, f32) for w in weights), run, cotangents[0],
+        sizes=trip_sizes, pairs=False, scope="moe_dispatch")
+    return (_Run(dx.astype(run.x.dtype),
+                 d_gate.reshape(run.gate_vals.shape).astype(
+                     run.gate_vals.dtype),
+                 *(d.astype(w.dtype) for d, w in zip(d_weights, weights)),
+                 None, None, None),)
 
 
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
@@ -333,14 +449,14 @@ def _moe_ffn_held(x, logits, probs, gate_vals, expert_idx, w_up, w_gate,
                                   ).astype(jnp.int32)
         routed = bounds[1:] - bounds[:-1]
         start = bounds[first_expert]
-        rows_per_chunk = _held_chunk_rows(n_tokens * top_k, held / e)
-        order = jnp.pad(order, (0, rows_per_chunk))
+        trip_sizes = _held_trip_sizes(n_tokens * top_k, held / e)
+        order = jnp.pad(order, (0, trip_sizes[0]))
     # the walk gathers (dispatch), multiplies (experts) and adds back
     # (combine) chunk by chunk, each under its scope
-    out, given = _held_experts(
+    out, given, walked = _held_experts(_Run(
         x.reshape(n_tokens, d).astype(dtype), gate_vals,
         w_up.astype(dtype), w_gate.astype(dtype), w_down.astype(dtype),
-        order, start, routed[here], rows_per_chunk, impl)
+        order, start, routed[here]), trip_sizes, impl)
 
     fraction = routed.astype(jnp.float32) / n_tokens
     in_share = (expert_idx >= first_expert) & (
@@ -351,5 +467,6 @@ def _moe_ffn_held(x, logits, probs, gate_vals, expert_idx, w_up, w_gate,
         "moe_expert_tokens": routed.at[here].set(given),
         "moe_expert_choice": expert_idx,
         "moe_routed_here": in_share.sum().astype(jnp.int32),
+        "moe_rows_walked": walked,
     }
     return out.reshape(b, s, d).astype(dtype), aux

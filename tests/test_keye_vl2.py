@@ -436,8 +436,11 @@ def test_the_held_walk_follows_a_collapsed_routing_and_drops_no_pair(
                            dtype=jnp.float32, impl="reference")
     rows = moe._held_chunk_rows(512 * 8, 16 / 128)
     assert rows == 1024
+    # midway to the chunk in whole 1,024s is the chunk here: one size
+    assert moe._held_trip_sizes(512 * 8, 16 / 128) == (1024,)
     assert int(aux["moe_routed_here"]) == trips * rows
     assert int(aux["moe_expert_tokens"][:16].sum()) == trips * rows
+    assert int(aux["moe_rows_walked"]) == trips * rows
 
     probs = jax.nn.softmax(x[0] @ router, -1)
     gates, chosen = lax.top_k(probs, 8)
@@ -449,6 +452,109 @@ def test_the_held_walk_follows_a_collapsed_routing_and_drops_no_pair(
                            @ w_down[e])
     assert (float(jnp.abs(plain).max()) > 1e-3) == (trips > 0)
     assert float(jnp.abs(out[0] - plain).max()) < 1e-5
+
+
+def _rows_walked(pairs, sizes):
+    """The walk's policy said plainly: a trip takes the smallest of `sizes`
+    that holds what is left, the largest where none does."""
+    rows = 0
+    while rows < pairs:
+        rows += min((s for s in sizes if s >= pairs - rows),
+                    default=max(sizes))
+    return rows
+
+
+def _routed_exactly(here, tokens, top_k, e, held, seed):
+    """Logits [tokens, e] whose top-k send exactly `here` (token, expert)
+    pairs to experts 0 .. held: all of a token's choices for the first
+    here // top_k tokens, the remainder for the next, none after."""
+    rng = np.random.default_rng(seed)
+    logits = rng.uniform(0.0, 0.5, (tokens, e)).astype(np.float32)
+    to_held = np.clip(here - top_k * np.arange(tokens), 0, top_k)
+    for t in range(tokens):
+        chosen = np.concatenate([
+            rng.choice(held, to_held[t], replace=False),
+            held + rng.choice(e - held, top_k - to_held[t], replace=False)])
+        logits[t, chosen] += 3.0 + 0.25 * rng.permutation(top_k)
+    return jnp.asarray(logits)
+
+
+@pytest.mark.parametrize("impl", ["reference", "pallas_interpret"])
+@pytest.mark.parametrize("here", [
+    0, 1, 2048, 3071, 3072, 3073, 4095, 4096, 4097, 7168, 7169, 9000],
+    ids=["no_pair", "one_pair", "the_balance", "the_smaller_trip_less_one",
+         "the_smaller_trip", "the_smaller_trip_and_one",
+         "the_chunk_less_one", "the_chunk", "the_chunk_and_one",
+         "the_chunk_and_the_smaller_trip",
+         "the_chunk_the_smaller_trip_and_one", "two_chunks_and_a_part"])
+def test_the_held_walk_is_the_held_experts_part_whatever_the_run_s_length(
+        here, impl, monkeypatch):
+    """2,048 tokens x top-8 over 128 experts of which 16 are held: 2,048
+    pairs at balance, so whole trips of 4,096 rows and a last trip of 3,072,
+    midway, where that holds what is left. A router made to send exactly `here`
+    pairs to the held experts (a token's logits are its input: the router is
+    the identity): the result, what each held expert was given and the
+    gradients of the input, the router and the three expert matrices are
+    those of `moe_ffn` over all 128 experts where the 112 others' weights
+    are zero — the all-experts path, which walks nothing — in no trip, one
+    or several; the rows the walk took are whole chunks and then the
+    smallest size that holds the rest, never fewer than the pairs; and with
+    the smaller size taken off the ladder (the parent's walk: the chunk alone)
+    the result, the counts and the gradients of the input and the router
+    come out bit for bit the same (the expert matrices' to a rounding: the
+    CPU's grouped matmul sums a gradient over the trip's static rows, the
+    zeros of the rows that are not real among them)."""
+    from ray_tpu.models import moe
+    tokens, top_k, e, held, f = 2048, 8, 128, 16, 32
+    sizes = moe._held_trip_sizes(tokens * top_k, held / e)
+    assert sizes == (4096, 3072)
+    keys = jax.random.split(jax.random.PRNGKey(here), 4)
+    x = _routed_exactly(here, tokens, top_k, e, held, here)[None]
+    router = jnp.eye(e)
+    w_up, w_gate = (jax.random.normal(k, (held, e, f)) * 0.1
+                    for k in keys[:2])
+    w_down = jax.random.normal(keys[2], (held, f, e)) * 0.1
+    mix = jax.random.normal(keys[3], (tokens, e))
+
+    def part(x, router, w_up, w_gate, w_down):
+        out, aux = moe.moe_ffn(x, router, w_up, w_gate, w_down, top_k=top_k,
+                               dtype=jnp.float32, impl=impl)
+        return (out[0] * mix).sum(), (out[0], aux)
+
+    def run(*weights):
+        with jax.default_matmul_precision("highest"):
+            (_, (out, aux)), grads = jax.jit(jax.value_and_grad(
+                part, argnums=(0, 1, 2, 3, 4), has_aux=True))(
+                    x, router, *weights)
+        return out, aux, grads
+
+    out, aux, grads = run(w_up, w_gate, w_down)
+    want, facts, wants = run(*(
+        jnp.concatenate([w, jnp.zeros((e - held, *w.shape[1:]))])
+        for w in (w_up, w_gate, w_down)))
+    assert int(aux["moe_routed_here"]) == here
+    assert np.array_equal(aux["moe_expert_tokens"], facts["moe_expert_tokens"])
+    assert int(aux["moe_expert_tokens"][:held].sum()) == here
+    assert int(aux["moe_rows_walked"]) == _rows_walked(here, sizes) >= here
+    assert (float(jnp.abs(want).max()) > 1e-3) == (here > 0)
+    assert float(jnp.abs(out - want).max()) < 1e-5
+    for got, wanted in zip(grads, wants):
+        wanted = wanted[:held] if wanted.ndim == 3 else wanted
+        assert float(jnp.abs(got - wanted).max()) <= 2e-5 * max(
+            1.0, float(jnp.abs(wanted).max()))
+
+    monkeypatch.setattr(moe, "_held_trip_sizes",
+                        lambda pairs, share: sizes[:1])
+    alone, aux_alone, grads_alone = run(w_up, w_gate, w_down)
+    assert int(aux_alone["moe_rows_walked"]) == -(-here // 4096) * 4096
+    assert np.array_equal(out, alone)
+    assert np.array_equal(aux["moe_expert_tokens"],
+                          aux_alone["moe_expert_tokens"])
+    for got, wanted in zip(grads[:2], grads_alone[:2]):
+        assert np.array_equal(got, wanted)
+    for got, wanted in zip(grads[2:], grads_alone[2:]):
+        assert float(jnp.abs(got - wanted).max()) <= 1e-6 * max(
+            1.0, float(jnp.abs(wanted).max()))
 
 
 # --------------------------------------------- what ("full",) was, it stays
@@ -517,7 +623,9 @@ def test_the_largest_served_bucket_compiles_and_fits_a_v5e(v5e):
         params, jax.ShapeDtypeStruct((rows, length), jnp.int32,
                                      sharding=one_chip)).compile()
     assert _kernel_names(compiled, "dsa_") == ["dsa_attend_fwd", "dsa_index"]
-    assert _kernel_names(compiled, "moe_") == ["moe_segsum",
+    # (the held walk's two sizes of trip, a body each in the one scanned
+    # layer: PR 52)
+    assert _kernel_names(compiled, "moe_") == ["moe_segsum", "moe_segsum",
                                                "moe_topk_rounds"]
     assert not _kernel_names(compiled, "flash_")
     # the group's blocks, accumulators and the decoded choice: past the

@@ -20,6 +20,7 @@ in a file of many tests, because `--dist loadfile` hands out the files of
 many tests first: in a file of its own it started last.
 """
 
+import collections
 import re
 
 import jax
@@ -364,15 +365,25 @@ def test_qwen3_next_period_train_step_fills_one_chip(v5e):
     projections, a [4, 8192, 8192] pass and three [4, 8192, 2048] ones) and
     holds no copy of an activation around the passes; one row more, refused
     by 65 MB with the `jnp` delta rule (PR 32) and by 686 MB with its
-    kernel pair (PR 33), now compiles too, again with no `.remat`:
-    `batch_per_chip` is the benchmark's to change (PERF.md, section 7)."""
+    kernel pair (PR 33), compiles too — with no `.remat` from PR 35 to
+    PR 51, with six since PR 52 (below): `batch_per_chip` is the
+    benchmark's to change (PERF.md, section 7)."""
     one_chip = SingleDeviceSharding(v5e.devices[0])
     config = _qwen3_next_config()
     rows = config["batch_per_chip"]
     step, state, tokens = _qwen3_next_step(config, rows)
     assert tokens.shape == (rows, 8192)
-    compiled = step.lower(_on(one_chip, state),
-                          {"tokens": _on(one_chip, tokens)}).compile()
+    lowered = step.lower(_on(one_chip, state),
+                         {"tokens": _on(one_chip, tokens)})
+    # the held experts' walk takes trips of two sizes (PR 52: the chunk of
+    # 40,960 rows and 30,720, midway to the balance), forward and backward,
+    # in each of the period's four layers; the trips are jitted functions of
+    # their arrays, so the step lowers each once a size, not once a layer
+    private = collections.Counter(
+        re.sub(r"_\d+$", "", name) for name in re.findall(
+            r"func\.func private @(\w+)\(", lowered.as_text()))
+    assert private["_held_trip"] == 2 and private["_held_trip_bwd"] == 2
+    compiled = lowered.compile()
     # the flash kernels of the one full-attention layer: forward, the
     # forward recomputed under "full" remat, and the one backward kernel
     # (PR 38); the held experts' grouped matmuls are kernels too, inside
@@ -394,12 +405,13 @@ def test_qwen3_next_period_train_step_fills_one_chip(v5e):
     text = compiled.as_text()
     assert "while(" in text
     # the held experts' rows return to token order by `ops.segment_sum`'s
-    # kernel, a call a walk (PR 36): the forward walk and the backward walk
-    # of each of the period's four layers — the forward walk that "full"
-    # remat would make again is dead code, the backward rule makes a
-    # chunk's products itself. The parent's program had a row scatter-add
-    # into f32[32768,2048] in each of those eight places
-    assert _kernel_names(compiled, "moe_segsum") == ["moe_segsum"] * 8
+    # kernel, a call a size of trip a walk (PR 36; two sizes, each a loop
+    # of its own, since PR 52): the forward walk and the backward walk of
+    # each of the period's four layers — the forward walk that "full" remat
+    # would make again is dead code, the backward rule makes a trip's
+    # products itself. PR 36's parent had a row scatter-add into
+    # f32[32768,2048] in each of those eight places
+    assert _kernel_names(compiled, "moe_segsum") == ["moe_segsum"] * 16
     assert not re.search(r"= f32\[32768,2048\]\S* scatter\(", text)
     # the router's top-10 of 512 is `ops.router_topk`'s kernel (PR 41), a
     # call a layer in the forward pass and one in its recomputation, under
@@ -425,14 +437,26 @@ def test_qwen3_next_period_train_step_fills_one_chip(v5e):
     mem = compiled.memory_analysis()
     # the donated state is aliased to the new one: 12 bytes a parameter
     assert mem.alias_size_in_bytes > 7.4e9
-    # the step's temporaries, 7.26 GiB (8.87 in PR 33, 9.62 in PR 32): one
-    # more [rows, 8192, 4096] bf16 array kept across a layer is 0.25 GiB
+    # the step's temporaries, 7.10 GiB (7.08 before PR 52's conditional,
+    # 7.22 with its smaller trip at the balance's 20,480 rows, 8.87 in
+    # PR 33, 9.62 in PR 32): one more [rows, 8192, 4096] bf16 array kept
+    # across a layer is 0.25 GiB
     assert mem.temp_size_in_bytes < 7.4 * 2 ** 30
     step, state, tokens = _qwen3_next_step(config, rows + 1)
     compiled = step.lower(_on(one_chip, state),
                           {"tokens": _on(one_chip, tokens)}).compile()
-    # 8.44 GiB beside 6.99 of donated state, of 15.75
+    # 7.12 GiB beside 6.99 of donated state, of 15.75 — since PR 52 with
+    # six arrays recomputed by the compiler to get there (it was 8.44 GiB
+    # and none): three [5, 8192, 2048] products of the shared experts and
+    # three [5, 8192, 8192] projections of the Gated DeltaNet layers. The
+    # choice of a trip's size is a conditional, whose results (a trip's
+    # three weight gradients, 67 MB each in bf16) stay until it ends, and
+    # 5 rows had 0.3 GiB to spare
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
-    assert mem.temp_size_in_bytes < 8.6 * 2 ** 30
-    assert ".remat" not in compiled.as_text()
+    assert mem.temp_size_in_bytes < 7.7 * 2 ** 30
+    recomputed = dict(re.findall(r"%(\S+\.remat\d*) = (\w+\[[\d,]+\])",
+                                 compiled.as_text()))
+    assert set(recomputed.values()) <= {"bf16[5,8192,2048]",
+                                        "bf16[5,8192,8192]"}
+    assert len(recomputed) <= 6

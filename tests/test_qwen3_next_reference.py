@@ -170,6 +170,10 @@ def test_system_agrees_with_the_plain_reference(case):
                               np.asarray(metrics["moe_routed_here"]))
         assert np.array_equal(
             given, np.asarray(ref["counts"])[:, first:first + held])
+        # and the step reports the rows the walk took for them, a layer
+        walked = np.asarray(metrics["moe_rows_walked"])
+        assert walked.shape == (config.n_layers,)
+        assert (walked >= given.sum(-1)).all() and walked.sum() > 0
     # values
     assert float(jnp.max(jnp.abs(logits - ref["logits"]))) < 2e-4
     assert abs(float(metrics["ce_loss"]) - float(ref["ce"])) < 2e-5
@@ -250,8 +254,40 @@ def test_a_hot_share_is_walked_in_more_than_one_chunk(monkeypatch):
         aux["moe_routed_here"])
     # everything was routed to the share: it gives what all sixteen give
     assert int(aux["moe_routed_here"]) == 2 * 100 * 3
+    assert int(aux["moe_rows_walked"]) == 10 * 64 >= 600
     assert float(jnp.max(jnp.abs(out - whole))) < 1e-5
     assert float(jnp.max(jnp.abs(grad - grad_whole))) < 1e-4
+
+
+def test_a_period_s_walks_lower_to_one_function_a_size_of_trip(monkeypatch):
+    """What the walk costs a program's start: two periods of the Qwen3-Next
+    pattern under "full" remat — eight expert layers, each walked forward,
+    forward again where the checkpoint recomputes it and backward — lower to
+    one private function a size of trip for the forward trip and one for the
+    backward, not one a layer or a call site: the trips are jitted functions
+    of their arrays (`moe._held_trip`, `moe._held_trip_bwd`), so the period's
+    layers share a lowered function a size."""
+    import collections
+    import re
+    from ray_tpu.models import moe
+    monkeypatch.setattr(moe, "_held_trip_sizes",
+                        lambda pairs, share: (96, 48))
+    config = _config(moe_first_expert=4, moe_experts_held=4, remat=True)
+    assert config.n_layers == 2 * len(config.layer_pattern) == 8
+    model = GPT(config)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct((2, 96), jnp.int32)
+    text = jax.jit(jax.value_and_grad(
+        lambda p, t: model.loss(p, {"tokens": t}), has_aux=True)).lower(
+            params, tokens).as_text()
+    private = collections.Counter(
+        re.sub(r"_\d+$", "", name) for name in re.findall(
+            r"func\.func private @(\w+)\(", text))
+    assert private["_held_trip"] == 2 and private["_held_trip_bwd"] == 2
+    # each called from every layer's walk: a loop a size, the four layers of
+    # the period written out inside the scan over periods
+    assert len(re.findall(r"call @_held_trip(?:_\d+)?\(", text)) == 8
+    assert len(re.findall(r"call @_held_trip_bwd(?:_\d+)?\(", text)) == 8
 
 
 @pytest.mark.parametrize("seed", [1, 5])

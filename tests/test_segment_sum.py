@@ -128,19 +128,23 @@ def test_the_kernel_refuses_a_width_it_does_not_take():
     assert sorted_segment_sum(rows, ids, 4, impl="auto").shape == (4, 48)
 
 
-@pytest.mark.parametrize("chunk_rows", [None, 64],
-                         ids=["one_trip", "several_trips"])
+@pytest.mark.parametrize("trip_sizes, walked", [
+    (None, 600), ((64,), 640), ((64, 32), 608), ((256, 128, 64), 640),
+    ((600, 300), 600), ((599, 300), 899), ((601, 300), 601)],
+    ids=["one_trip", "several_trips", "a_smaller_last_trip",
+         "three_sizes", "exactly_a_chunk", "a_chunk_and_one",
+         "a_chunk_less_one"])
 @pytest.mark.parametrize("impl", ["reference", "pallas_interpret"])
 def test_a_share_that_is_routed_everything_gives_what_all_experts_give(
-        impl, chunk_rows, monkeypatch):
+        impl, trip_sizes, walked, monkeypatch):
     """`moe_ffn` with 4 of 16 experts held and a router that sends every
     choice to them: the held share's walk (rows sorted into token order and
     summed by segments, forward and for dx) against the all-experts path
     (gathers and a sum over k) on the same weights, result and the gradients of the input and of
     the experts' weights (the absent experts' are zero on both sides)."""
-    if chunk_rows:
-        monkeypatch.setattr(moe, "_held_chunk_rows",
-                            lambda pairs, share: chunk_rows)
+    if trip_sizes:
+        monkeypatch.setattr(moe, "_held_trip_sizes",
+                            lambda pairs, share: trip_sizes)
     keys = jax.random.split(jax.random.PRNGKey(3), 6)
     d, f, e, top_k = 128, 32, 16, 3
     router = jax.random.normal(keys[0], (d, e)).at[:, :3].add(50.0)
@@ -164,6 +168,8 @@ def test_a_share_that_is_routed_everything_gives_what_all_experts_give(
             part, argnums=(0, 1), has_aux=True)(h, weights, slice(0, e))
     assert int(aux["moe_routed_here"]) == 2 * 100 * top_k
     assert int(aux["moe_expert_tokens"][:4].sum()) == 2 * 100 * top_k
+    # whole trips of the largest size, then the smallest that holds the rest
+    assert int(aux["moe_rows_walked"]) == walked
     assert float(jnp.max(jnp.abs(whole))) > 1e-1
     assert float(jnp.max(jnp.abs(out - whole))) < 1e-5 * float(
         jnp.max(jnp.abs(whole)))
