@@ -2,31 +2,44 @@
 
 One implementation covers the GPT-2 family (learned positions, GELU MLP,
 LayerNorm), the Llama family (RoPE, SwiGLU, RMSNorm, GQA), OLMoE's
-sparse-expert block (QK-norm, dropless top-k experts, `models/moe.py`) and
+sparse-expert block (QK-norm, dropless top-k experts, `models/moe.py`),
 Qwen3-Next's hybrid (three Gated DeltaNet layers to one gated softmax
-attention layer, a shared expert beside a held share of the routed ones)
-and learned sparse attention (an indexer's choice of keys a query, forward
-only) through `GPTConfig` fields — the reference ships these as external
-torch models driven by Ray Train (`release/train_tests`, SURVEY §6
-north-star configs); here the model itself is framework-native.
+attention layer, a shared expert beside a held share of the routed ones),
+learned sparse attention (an indexer's choice of keys a query, forward
+only) and window and full attention mixed (Trinity-Mini's layers: three
+"window" layers with RoPE to one "full" layer without positions, gated
+attention under a norm on each branch's output, a leading dense layer
+before the periods of routed ones, sigmoid scores with a selection-only
+bias and a scale, an ungated shared expert; served only: the kernels'
+backward pass knows no window) through `GPTConfig` fields — the reference
+ships these as external torch models driven by Ray Train
+(`release/train_tests`, SURVEY §6 north-star configs); here the model itself
+is framework-native.
 
 The layers' kinds are data: `GPTConfig.layer_pattern` is one period of them
 ("full": softmax attention, "linear": the gated delta rule of
 `ops/delta_rule.py`, "sparse": softmax attention over the keys that the
-indexer of `ops/sparse_index.py` chooses), every layer is
-`x + mixer(norm(x))` then
-`x + mlp(norm(x))`, and the scan runs over periods. A model of one kind is
-the period ("full",): its weights are stacked [L, ...] under
+indexer of `ops/sparse_index.py` chooses, "window": softmax attention over
+a query's last `attn_window` keys), every layer is `x + mixer(norm(x))`
+then `x + mlp(norm(x))` — with `post_norm`, `x + norm(mixer(norm(x)))` —
+and the scan runs over periods. `lead_layers` names the kinds of the layers
+before the first period, written out one by one under their own scope; their
+FFN is dense (`lead_d_ff`) whatever the periods' is. Which kinds RoPE turns
+is `rope_layers` (`GPT._turned`, the one place that decides). A model of one
+kind is the period ("full",): its weights are stacked [L, ...] under
 `params["blocks"]`; with a longer period each kind's weights are stacked
 [periods, layers of that kind in a period, ...] under
-`params["blocks"][kind]`.
+`params["blocks"][kind]`; the leading layers' are a list, a layer each,
+under `params["lead"]`.
 
 A kind's weights are declared once, below `GPTConfig`: `_MIXERS` and
 `_FFNS` give each kind of mixer and of FFN the function of the config that
 lists its weights (shape, logical axes, how each starts) beside the method
-of `GPT` that uses them. `GPT.init`, `GPT.param_logical_axes` and
-`GPTConfig.n_params` are walks over those lists, `GPT._block` looks its two
-halves up in the same tables, and `LAYER_KINDS` is the mixers' keys.
+of `GPT` that uses them; `_layer_weights` adds the layer's norms and
+`_ffn_of` says which FFN a layer has by where it stands. `GPT.init`,
+`GPT.param_logical_axes` and `GPTConfig.n_params` are walks over those
+lists, `GPT._block` looks its two halves up in the same tables, and
+`LAYER_KINDS` is the mixers' keys.
 
 TPU-first choices:
   * scan-over-periods with stacked params — one compiled body a period,
@@ -55,7 +68,9 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..ops.attention import FLASH_RESIDUAL_NAMES, dot_product_attention
+from ..ops._impl import resolve_impl
+from ..ops.attention import (FLASH_RESIDUAL_NAMES, WINDOW_HAS_NO_BACKWARD,
+                             chunk_classes, dot_product_attention)
 from ..ops.delta_rule import gated_delta_rule
 from ..ops.gated_deltanet import gdn_conv, gdn_gated_norm
 from ..ops.ring_attention import ring_attention
@@ -123,6 +138,23 @@ class GPTConfig:
     sparse_topk: int = 0
     index_heads: int = 0
     index_head_dim: int = 0
+    # "window" layers: softmax attention over a causal query's last
+    # `attn_window` keys, its own included (0: the model has none)
+    attn_window: int = 0
+    # under positions="rope", the kinds of layer whose q and k RoPE turns
+    # (None: every kind that attends); a kind left out attends without
+    # positions
+    rope_layers: Optional[Tuple[str, ...]] = None
+    # a second learned norm of the model's kind on each branch's output,
+    # before it joins the stream: x + norm(f(norm(x)))
+    post_norm: bool = False
+    # the kinds of the layers before the scanned periods, in order (n_layers
+    # counts them). Their FFN is dense, `lead_d_ff` wide (None: d_ff),
+    # whatever the periods' is
+    lead_layers: Tuple[str, ...] = ()
+    lead_d_ff: Optional[int] = None
+    # what a token's embedding is multiplied by as it enters the stream
+    embed_scale: float = 1.0
     # pipeline parallelism: microbatches per global batch (0 -> = pp).
     # Stages come from the mesh's pp axis; GSPMD-style schedule (scan
     # over steps, stage-sharded rolling buffer -> collective-permute).
@@ -136,9 +168,19 @@ class GPTConfig:
     moe_norm_topk_prob: bool = True
     moe_aux_coeff: float = 0.01       # load-balancing loss, all k choices
     moe_router_z_coeff: float = 0.0   # mean squared logsumexp of the router
-    # width of a SwiGLU expert with a sigmoid gate of its own that every
-    # token passes through beside the routed ones (0: none)
+    # the router's scores: a "softmax" over the experts' logits or a
+    # "sigmoid" of each (which has neither of the two losses above)
+    moe_score: str = "softmax"
+    # a bias an expert, added to the scores for the choice of the top k
+    # alone (the weights are the chosen scores without it). No gradient's
+    # weight: what moves it between steps is not written here
+    moe_select_bias: bool = False
+    # what a token's routing weights are multiplied by, after the rescaling
+    moe_route_scale: float = 1.0
+    # width of a SwiGLU expert that every token passes through beside the
+    # routed ones (0: none), and whether it has a sigmoid gate of its own
     moe_shared_ff: int = 0
+    moe_shared_gate: bool = True
     # the share of each layer's experts that lives here: experts
     # moe_first_expert .. + moe_experts_held of the n_experts routed over
     # (None: all of them). The layer computes their part of the result
@@ -176,14 +218,24 @@ class GPTConfig:
 
     def __post_init__(self):
         pattern = tuple(self.layer_pattern)     # a JSON file gives a list
+        lead = tuple(self.lead_layers)
         object.__setattr__(self, "layer_pattern", pattern)
+        object.__setattr__(self, "lead_layers", lead)
+        if self.rope_layers is not None:
+            object.__setattr__(self, "rope_layers", tuple(self.rope_layers))
         if not pattern or set(pattern) - set(LAYER_KINDS):
             raise ValueError(f"layer_pattern {pattern!r}: a period of "
                              f"{LAYER_KINDS}")
-        if self.n_layers % len(pattern):
-            raise ValueError(f"n_layers={self.n_layers} is not whole periods "
-                             f"of {pattern!r}")
-        if "sparse" in pattern and not (self.sparse_topk > 0
+        if set(lead) - set(LAYER_KINDS):
+            raise ValueError(f"lead_layers {lead!r}: of {LAYER_KINDS}")
+        if self.n_layers < len(lead) or (
+                self.n_layers - len(lead)) % len(pattern):
+            raise ValueError(
+                f"n_layers={self.n_layers} is not {len(lead)} leading "
+                f"layers and whole periods of {pattern!r}")
+        if "window" in self.kinds and self.attn_window <= 0:
+            raise ValueError('a "window" layer needs attn_window')
+        if "sparse" in self.kinds and not (self.sparse_topk > 0
                                         and self.index_heads > 0
                                         and self.index_head_dim > 0):
             raise ValueError('a "sparse" layer needs sparse_topk, '
@@ -205,6 +257,17 @@ class GPTConfig:
             return self.d_head
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
+
+    @property
+    def kinds(self) -> frozenset:
+        """The kinds of layer the model has, leading or in a period."""
+        return frozenset(self.lead_layers + self.layer_pattern)
+
+    @property
+    def periods(self) -> int:
+        """Whole periods of `layer_pattern` after the leading layers."""
+        return (self.n_layers - len(self.lead_layers)) // len(
+            self.layer_pattern)
 
     @property
     def experts_held(self) -> int:
@@ -230,9 +293,11 @@ class GPTConfig:
             return sum(math.prod(w.shape) for name, w in weights.items()
                        if isinstance(w.start, float) and name != "pos_embed")
 
-        periods = self.n_layers // len(self.layer_pattern)
-        return drawn(_model_weights(self)) + periods * sum(
-            drawn(_layer_weights(self, kind)) for kind in self.layer_pattern)
+        return (drawn(_model_weights(self))
+                + sum(drawn(_layer_weights(self, kind, lead=True))
+                      for kind in self.lead_layers)
+                + self.periods * sum(drawn(_layer_weights(self, kind))
+                                     for kind in self.layer_pattern))
 
 
 # --- presets ---------------------------------------------------------------
@@ -263,6 +328,9 @@ def llama_tiny(**kw) -> GPTConfig:
 # `init` splits its key in twelve for a kind's layers (7..9 are the model's
 # own) and the twelfth in eight more: a weight's `key` counts through both
 _EXTRA = 12
+# what `init` folds into its key for the leading layers' draws, from here up
+# (a pattern's kinds fold in 1, 2, ...)
+_LEAD_FOLD = 1 << 16
 
 
 @dataclasses.dataclass(frozen=True)
@@ -352,8 +420,9 @@ def _linear_weights(c: GPTConfig) -> Dict[str, _Weight]:
                           _EXTRA + 3))
 
 
-def _dense_weights(c: GPTConfig) -> Dict[str, _Weight]:
-    d, f = c.d_model, c.ff_dim
+def _dense_weights(c: GPTConfig, lead: bool = False) -> Dict[str, _Weight]:
+    d = c.d_model
+    f = c.lead_d_ff if lead and c.lead_d_ff is not None else c.ff_dim
     weights = dict(
         w_up=_Weight((d, f), ("embed", "mlp"), _STD, 4),
         w_down=_Weight((f, d), ("mlp", "embed"), _resid_std(c), 5))
@@ -362,7 +431,7 @@ def _dense_weights(c: GPTConfig) -> Dict[str, _Weight]:
     return weights
 
 
-def _expert_weights(c: GPTConfig) -> Dict[str, _Weight]:
+def _expert_weights(c: GPTConfig, lead: bool = False) -> Dict[str, _Weight]:
     d, f, held = c.d_model, c.ff_dim, c.experts_held
     weights = dict(
         router=_Weight((d, c.n_experts), ("embed", None), _STD, 4),
@@ -370,21 +439,25 @@ def _expert_weights(c: GPTConfig) -> Dict[str, _Weight]:
         w_gate=_Weight((held, d, f), ("expert", "embed", "mlp"), _STD, 6),
         w_down=_Weight((held, f, d), ("expert", "mlp", "embed"),
                        _resid_std(c), 10))
+    if c.moe_select_bias:
+        weights["router_bias"] = _Weight((c.n_experts,), (None,), "zeros")
     if c.moe_shared_ff:
         fs = c.moe_shared_ff
         weights.update(
             ws_up=_Weight((d, fs), ("embed", "mlp"), _STD, _EXTRA + 0),
             ws_gate=_Weight((d, fs), ("embed", "mlp"), _STD, _EXTRA + 1),
             ws_down=_Weight((fs, d), ("mlp", "embed"), _resid_std(c),
-                            _EXTRA + 2),
-            ws_open=_Weight((d,), (None,), "zeros"))
+                            _EXTRA + 2))
+        if c.moe_shared_gate:
+            weights["ws_open"] = _Weight((d,), (None,), "zeros")
     return weights
 
 
 class _Half(NamedTuple):
-    """A kind of mixer or of FFN: what lists its weights, and what applies
-    them (of a `GPT`: the model, the layer's input, positions, weights),
-    giving its output and its facts."""
+    """A kind of mixer or of FFN: what lists its weights (of the config; an
+    FFN's also of whether the layer is a leading one), and what applies them
+    (of a `GPT`: the model, the layer's input, positions, weights), giving
+    its output and its facts."""
     weights: Callable[[GPTConfig], Dict[str, _Weight]]
     apply: Callable[..., Any]
 
@@ -396,7 +469,10 @@ _MIXERS = {
                     lambda m, x, positions, w: (m._linear_mixer(x, w), {})),
     "sparse": _Half(_sparse_weights,
                     lambda m, x, positions, w: m._full_mixer(
-                        x, positions, w, sparse=True)),
+                        x, positions, w, kind="sparse")),
+    "window": _Half(_full_weights,
+                    lambda m, x, positions, w: m._full_mixer(
+                        x, positions, w, kind="window")),
 }
 _FFNS = {
     "dense": _Half(_dense_weights, lambda m, h, w: m._dense_ffn(h, w)),
@@ -405,20 +481,25 @@ _FFNS = {
 LAYER_KINDS = tuple(_MIXERS)
 
 
-def _ffn_of(c: GPTConfig) -> _Half:
-    return _FFNS["experts" if c.n_experts > 0 else "dense"]
+def _ffn_of(c: GPTConfig, lead: bool = False) -> _Half:
+    """The FFN of a layer by where it stands: dense in a leading layer,
+    else the model's."""
+    return _FFNS["experts" if c.n_experts > 0 and not lead else "dense"]
 
 
-def _layer_weights(c: GPTConfig, kind: str) -> Dict[str, _Weight]:
-    """The weights of one layer of `kind`: its two norms', its mixer's and
-    its FFN's."""
+def _layer_weights(c: GPTConfig, kind: str, lead: bool = False
+                   ) -> Dict[str, _Weight]:
+    """The weights of one layer of `kind`, a leading one or one of a period:
+    its norms', its mixer's and its FFN's."""
     d = c.d_model
-    weights = dict(norm1=_Weight((d,), (None,), _unit(c)),
-                   norm2=_Weight((d,), (None,), _unit(c)),
-                   **_MIXERS[kind].weights(c), **_ffn_of(c).weights(c))
+    norms = ("1", "2") + (("1_post", "2_post") if c.post_norm else ())
+    weights = dict(**{"norm" + n: _Weight((d,), (None,), _unit(c))
+                      for n in norms},
+                   **_MIXERS[kind].weights(c),
+                   **_ffn_of(c, lead).weights(c, lead))
     if c.norm == "layernorm":
-        weights.update(bias1=_Weight((d,), (None,), "zeros"),
-                       bias2=_Weight((d,), (None,), "zeros"))
+        weights.update({"bias" + n: _Weight((d,), (None,), "zeros")
+                        for n in norms})
     return weights
 
 
@@ -462,12 +543,12 @@ class GPT:
                 raise NotImplementedError(
                     "EP+PP combined (MoE aux-loss masking across pipeline "
                     "bubbles) is not supported yet")
-            if len(config.layer_pattern) > 1:
+            if len(config.layer_pattern) > 1 or config.lead_layers:
                 raise NotImplementedError(
                     f"pipeline stages of a layer pattern "
-                    f"{config.layer_pattern!r} (stages of unequal layer "
-                    "kinds) are not supported yet: pp needs the period "
-                    '("full",)')
+                    f"{config.lead_layers + config.layer_pattern!r} (stages "
+                    "of unequal layer kinds) are not supported yet: pp "
+                    'needs the period ("full",) and no leading layers')
 
     def _mesh_size(self, logical: str) -> int:
         """Size of the one mesh axis `logical` maps to (1 with no mesh)."""
@@ -491,28 +572,29 @@ class GPT:
 
     # -- parameters --------------------------------------------------------
 
-    def _init_layers(self, kind: str, lead: Tuple[int, ...], keys) -> Params:
-        """The stacked weights of the layers of one kind, `lead` the
+    def _init_layers(self, kind: str, stack: Tuple[int, ...], keys,
+                     lead: bool = False) -> Params:
+        """The stacked weights of the layers of one kind, `stack` the
         stacking axes, `keys` the twelve."""
         extra = jax.random.split(keys[_EXTRA - 1], 8)
 
         def key_of(i):
             return keys[i] if i < _EXTRA else extra[i - _EXTRA]
 
-        return {name: w.make(key_of, lead, self.config.param_dtype)
-                for name, w in _layer_weights(self.config, kind).items()}
+        return {name: w.make(key_of, stack, self.config.param_dtype)
+                for name, w in _layer_weights(self.config, kind,
+                                              lead).items()}
 
     def init(self, rng: jax.Array) -> Params:
         c = self.config
-        L = c.n_layers
+        L = c.n_layers - len(c.lead_layers)
         keys = jax.random.split(rng, _EXTRA)
         if len(c.layer_pattern) == 1:
             blocks = self._init_layers(c.layer_pattern[0], (L,), keys)
         else:
-            periods = L // len(c.layer_pattern)
             blocks = {
                 kind: self._init_layers(
-                    kind, (periods, n),
+                    kind, (c.periods, n),
                     jax.random.split(jax.random.fold_in(rng, i + 1), _EXTRA))
                 for i, (kind, n) in enumerate(self._kinds.items())}
         P = self.pp_stages
@@ -521,17 +603,26 @@ class GPT:
             # sharded over pp so each stage holds only its layers
             blocks = jax.tree_util.tree_map(
                 lambda a: a.reshape((P, L // P) + a.shape[1:]), blocks)
-        return {"blocks": blocks,
-                **{name: w.make(lambda i: keys[i], (), c.param_dtype)
-                   for name, w in _model_weights(c).items()}}
+        params = {"blocks": blocks,
+                  **{name: w.make(lambda i: keys[i], (), c.param_dtype)
+                     for name, w in _model_weights(c).items()}}
+        if c.lead_layers:
+            # a list, a layer each, unstacked: they need not be of one kind
+            params["lead"] = [
+                self._init_layers(
+                    kind, (), jax.random.split(
+                        jax.random.fold_in(rng, _LEAD_FOLD + i), _EXTRA),
+                    lead=True)
+                for i, kind in enumerate(c.lead_layers)]
+        return params
 
     def param_logical_axes(self) -> Params:
         """Pytree matching `init` output: tuples of logical axis names."""
         c = self.config
 
-        def axes(kind, lead):
-            return {name: lead + w.axes
-                    for name, w in _layer_weights(c, kind).items()}
+        def axes(kind, stack, lead=False):
+            return {name: stack + w.axes
+                    for name, w in _layer_weights(c, kind, lead).items()}
 
         if len(c.layer_pattern) == 1:
             blocks: Params = axes(
@@ -540,8 +631,11 @@ class GPT:
         else:
             blocks = {kind: axes(kind, ("layers", None))
                       for kind in self._kinds}
+        lead = ({"lead": [axes(kind, (), lead=True)
+                          for kind in c.lead_layers]}
+                if c.lead_layers else {})
         return {**{name: w.axes for name, w in _model_weights(c).items()},
-                "blocks": blocks}
+                "blocks": blocks, **lead}
 
     # -- building blocks ---------------------------------------------------
 
@@ -595,10 +689,11 @@ class GPT:
         out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
         return out.astype(x.dtype)
 
-    def _attention(self, q, k, v, selection=None):
+    def _attention(self, q, k, v, selection=None, window=None):
         """q: [B, S, H, Dh], k/v: [B, S, Hk, Dh] → [B, S, H, Dh]; with
         `selection` (`ops.sparse_index.Selection`) over each query's chosen
-        keys alone, on one device.
+        keys alone, on one device; with `window` over each query's last
+        `window` keys, its own included.
 
         The flash kernels read q, k, v as the projections wrote them and
         write their gradients the same way (`ops/attention.py`,
@@ -622,8 +717,14 @@ class GPT:
             # shard_map can't nest there, so use the einsum attention and
             # let GSPMD partition it (pallas-in-pipeline: future work)
             return dot_product_attention(q, k, v, causal=True,
-                                         impl="reference", seq_major=True)
+                                         impl="reference", seq_major=True,
+                                         window=window)
         if self._mesh_size("act_seq") > 1:      # sequence parallelism
+            if window is not None:
+                raise NotImplementedError(
+                    "a \"window\" layer under sequence parallelism: ring "
+                    "attention passes every block of keys around the ring, "
+                    "and knows no window")
             # Specs derive from the rules table like every other sharding
             # decision; the ring axis is whatever act_seq maps to.
             spec_q = self.rules.spec("act_batch", "act_heads", "act_seq",
@@ -647,7 +748,7 @@ class GPT:
         def local(qb, kb, vb):
             return dot_product_attention(qb, kb, vb, causal=True,
                                          impl=c.attention_impl,
-                                         seq_major=True)
+                                         seq_major=True, window=window)
 
         if self.mesh is None:
             return local(q, k, v)
@@ -735,12 +836,31 @@ class GPT:
         return sparse_index(q, k, weight, c.sparse_topk,
                             impl=c.attention_impl)
 
-    def _full_mixer(self, x, positions, w, sparse=False):
+    def _turned(self, kind: str) -> bool:
+        """Whether RoPE turns the q and k of a layer of `kind`: the one place
+        that says which layers attend with positions."""
+        c = self.config
+        return c.positions == "rope" and (c.rope_layers is None
+                                          or kind in c.rope_layers)
+
+    def _join(self, x, branch, w, post: str):
+        """The stream plus a branch's output, through the branch's own norm
+        first where the model has one (`post_norm`; `post` names it)."""
+        if self.config.post_norm:
+            branch = self._norm(branch, w["norm" + post], w.get("bias" + post))
+        return x + self._constrain(branch, "act_batch", "act_seq",
+                                   "act_embed")
+
+    def _full_mixer(self, x, positions, w, kind="full"):
         """Softmax attention on the normed input, residual included, and the
-        layer's facts: with `sparse`, over the keys the layer's indexer
-        chooses, and how many (query, key) pairs that was."""
+        layer's facts: in a "sparse" layer over the keys the layer's indexer
+        chooses, and how many (query, key) pairs that was; in a "window"
+        layer over each query's last `attn_window` keys, and how many
+        rectangles of scores the forward kernel's walk of that band holds."""
         c = self.config
         dt = c.dtype
+        sparse = kind == "sparse"
+        window = c.attn_window if kind == "window" else None
         selection, facts = None, {}
         # the scopes are metadata on the ops (the profiler's trace and the
         # HLO carry them), the program is the same with or without
@@ -770,7 +890,7 @@ class GPT:
             elif c.qk_norm:
                 q = self._qk_norm(q, w["q_norm"])
                 k = self._qk_norm(k, w["k_norm"])
-            if c.positions == "rope":
+            if self._turned(kind):
                 q = self._rope(q, positions)
                 k = self._rope(k, positions)
             q = self._constrain(q, "act_batch", "act_seq", "act_heads",
@@ -781,10 +901,15 @@ class GPT:
                 with jax.named_scope("dsa_index"):
                     selection = self._index(h, positions, w)
                     facts["dsa_selected_pairs"] = selection.counts.sum()
+        if window is not None:
+            live = chunk_classes(x.shape[1], x.shape[1], True, window=window)
+            facts["attn_window_rects"] = jnp.int32(
+                x.shape[0] * c.n_heads * (live["interior"] + live["edge"]))
         with jax.named_scope("attn_kernel"), (
                 jax.named_scope("dsa_attend") if sparse
+                else jax.named_scope("attn_window") if window is not None
                 else contextlib.nullcontext()):
-            attn = self._attention(q, k, v, selection)
+            attn = self._attention(q, k, v, selection, window)
         with jax.named_scope("attn_out"):
             if c.attn_gate:
                 attn = attn * jax.nn.sigmoid(gate)
@@ -792,8 +917,7 @@ class GPT:
             attn = jnp.einsum("bse,ed->bsd",
                               attn.reshape(*attn.shape[:2], -1),
                               wo.reshape(-1, wo.shape[-1]))
-            return x + self._constrain(attn, "act_batch", "act_seq",
-                                       "act_embed"), facts
+            return self._join(x, attn, w, "1_post"), facts
 
     def _over_rows(self, fn, arrays, weights):
         """fn(*arrays, *weights) for [B, S, ...] arrays: on a mesh under
@@ -853,8 +977,7 @@ class GPT:
                                   impl=c.attention_impl),
                 (o.reshape(*o.shape[:2], -1), z), (w["lin_norm"],))
             out = jnp.einsum("bse,ed->bsd", o, w["w_lin_out"].astype(dt))
-            return x + self._constrain(out, "act_batch", "act_seq",
-                                       "act_embed")
+            return self._join(x, out, w, "1_post")
 
     def _dense_ffn(self, h, w):
         """The MLP on the normed input h: (its output, no facts)."""
@@ -881,28 +1004,33 @@ class GPT:
             h, w["router"], w["w_up"], w["w_gate"], w["w_down"],
             top_k=c.moe_top_k, norm_topk_prob=c.moe_norm_topk_prob,
             first_expert=c.moe_first_expert, dtype=c.dtype,
+            score=c.moe_score, select_bias=w.get("router_bias"),
+            route_scale=c.moe_route_scale,
             # a Mosaic call is not partitioned automatically: on a mesh the
             # router's top-k is `lax.top_k` and the held experts' rows are
             # summed in `jnp`
             impl=c.attention_impl if self.mesh is None else "reference")
         if c.moe_shared_ff:
             down = down + shared_expert_ffn(
-                h, w["ws_up"], w["ws_gate"], w["ws_down"], w["ws_open"],
+                h, w["ws_up"], w["ws_gate"], w["ws_down"], w.get("ws_open"),
                 dtype=c.dtype)
         return down, aux
 
-    def _block(self, x, positions, w, kind="full"):
-        """One block of the given kind. x: [B, S, D] bf16."""
+    def _block(self, x, positions, w, kind="full", lead=False):
+        """One block of the given kind, a leading one or one of a period.
+        x: [B, S, D] bf16."""
+        c = self.config
         x, facts = _MIXERS[kind].apply(self, x, positions, w)
-        if "sparse" in self.config.layer_pattern:
-            # a layer without an indexer chose none: the facts of every
-            # layer of a period are stacked
+        # a layer without an indexer chose none, one without a window walked
+        # none: the facts of every layer of a period are stacked
+        if "sparse" in c.kinds:
             facts.setdefault("dsa_selected_pairs", jnp.int32(0))
+        if "window" in c.kinds:
+            facts.setdefault("attn_window_rects", jnp.int32(0))
         with jax.named_scope("mlp"):
             h = self._norm(x, w["norm2"], w.get("bias2"))
-            down, aux = _ffn_of(self.config).apply(self, h, w)
-            x = x + self._constrain(down, "act_batch", "act_seq",
-                                    "act_embed")
+            down, aux = _ffn_of(c, lead).apply(self, h, w)
+            x = self._join(x, down, w, "2_post")
         return x, {**facts, **aux}
 
     # -- forward -----------------------------------------------------------
@@ -914,13 +1042,18 @@ class GPT:
 
     def forward_with_aux(self, params: Params, tokens: jax.Array,
                          positions: Optional[jax.Array] = None):
-        """Returns (logits, aux): with experts, the router's two losses as
-        means over the layers and, per layer, `moe_expert_tokens`
+        """Returns (logits, aux): with experts, a softmax router's two
+        losses as means over the layers and, per layer, `moe_expert_tokens`
         [n_layers, n_experts], `moe_expert_choice` [n_layers, tokens,
         top_k] and, where the layers hold a share of their experts,
         `moe_routed_here` and `moe_rows_walked` [n_layers]; with "sparse"
         layers, `dsa_selected_pairs` [n_layers], the (query, key) pairs
-        each layer's indexer chose; with neither, an empty dict."""
+        each layer's indexer chose; with "window" layers,
+        `attn_window_rects` [n_layers], the rectangles of scores the band
+        holds under the forward kernel's tiling (0 for a layer of another
+        kind); with none of these, an empty dict. A fact's layers are those
+        that have it, in order: the router's leave the leading layers
+        out."""
         c = self.config
         if positions is None:
             positions = jnp.broadcast_to(
@@ -943,10 +1076,16 @@ class GPT:
                 pos_tbl = self._constrain(
                     params["pos_embed"].astype(c.dtype), None, None)
                 x = x + pos_tbl[positions]
+            if c.embed_scale != 1.0:
+                x = x * jnp.asarray(c.embed_scale, c.dtype)
             x = self._constrain(x, "act_batch", "act_seq", "act_embed")
 
         block_fns = {kind: functools.partial(self._block, kind=kind)
                      for kind in self._kinds}
+        block_fns.update({
+            ("lead", kind): functools.partial(self._block, kind=kind,
+                                              lead=True)
+            for kind in c.lead_layers})
         if c.remat:
             cp = jax.checkpoint_policies
             policies = {
@@ -961,6 +1100,13 @@ class GPT:
             block_fns = {kind: jax.checkpoint(
                 fn, policy=policies[c.remat_policy])
                 for kind, fn in block_fns.items()}
+
+        lead_facts = []
+        for kind, layer_w in zip(c.lead_layers, params.get("lead", ())):
+            # a scope of their own: a trace tells them from the periods'
+            with jax.named_scope("lead"):
+                x, facts = block_fns["lead", kind](x, positions, layer_w)
+            lead_facts.append(facts)
 
         if self.pp_stages > 1:
             x = self._pipeline_blocks(block_fns["full"], params["blocks"], x,
@@ -991,6 +1137,11 @@ class GPT:
             # [periods, layers a period, ...] -> [L, ...]
             aux_per_layer = jax.tree_util.tree_map(
                 lambda a: a.reshape(-1, *a.shape[2:]), aux_per_period)
+        for facts in reversed(lead_facts):
+            for name, fact in facts.items():
+                aux_per_layer[name] = (
+                    jnp.concatenate([fact[None], aux_per_layer[name]])
+                    if name in aux_per_layer else fact[None])
         # one scope, `head_loss`, for the final norm and the logits here
         # and for the cross-entropy in `loss`
         with jax.named_scope("head_loss"):
@@ -1075,13 +1226,18 @@ class GPT:
         Targets are tokens shifted left; the final position is masked.
         """
         c = self.config
-        if "sparse" in c.layer_pattern:
+        if "sparse" in c.kinds:
             raise NotImplementedError(
                 'a model with a "sparse" layer is not trained: the objective '
                 "that trains the indexer (a divergence to the main "
                 "attention's distribution, its schedule and coefficient) is "
                 "no part of the configuration, and the layer has no backward "
                 "pass")
+        if "window" in c.kinds and resolve_impl(
+                c.attention_impl, "attention") != "reference":
+            raise NotImplementedError(
+                'a model with a "window" layer is not trained through the '
+                "kernels: " + WINDOW_HAS_NO_BACKWARD)
         tokens = batch["tokens"]
         logits, aux = self.forward_with_aux(params, tokens)  # [B,S,V] f32
         with jax.named_scope("head_loss"):
@@ -1107,13 +1263,14 @@ class GPT:
             "tokens": mask.sum(),
         }
         if c.n_experts > 0:
-            loss = (loss + c.moe_aux_coeff * aux["moe_aux_loss"]
-                    + c.moe_router_z_coeff * aux["moe_router_z"])
+            if "moe_aux_loss" in aux:   # a softmax router's: `moe.moe_ffn`
+                loss = (loss + c.moe_aux_coeff * aux["moe_aux_loss"]
+                        + c.moe_router_z_coeff * aux["moe_router_z"])
+                metrics.update(moe_aux_loss=aux["moe_aux_loss"],
+                               moe_router_z=aux["moe_router_z"])
             counts = aux["moe_expert_tokens"].astype(jnp.float32)   # [L, E]
             metrics.update(
                 loss=loss, ce_loss=metrics["ppl_log"],
-                moe_aux_loss=aux["moe_aux_loss"],
-                moe_router_z=aux["moe_router_z"],
                 # over the layers: each layer's sum is tokens x top-k
                 moe_expert_tokens=aux["moe_expert_tokens"].sum(0),
                 moe_load_max_over_mean=(counts.max(-1)

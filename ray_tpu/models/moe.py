@@ -1,10 +1,13 @@
 """Mixture-of-Experts FFN, dropless.
 
 The reference has NO native MoE/EP (SURVEY §2.4: "absent — only via
-external frameworks"); here it's first-class. Softmax top-k routing with no
-capacity and no dropped token. The router is float32 in truth: the logits
-are a `HIGHEST` product of the activations cast up and every bit of the
-router's weights (where the activations are bf16 the compiler leaves out
+external frameworks"); here it's first-class. Top-k routing with no
+capacity and no dropped token, on scores that are a softmax over the experts
+or a sigmoid of each (`score`), the choice made on the scores plus a bias
+that is no part of the weights (`select_bias`), the chosen scores rescaled
+to sum to 1 or not and multiplied by `route_scale`. The router is float32 in
+truth: the logits are a `HIGHEST` product of the activations cast up and
+every bit of the router's weights (where the activations are bf16 the compiler leaves out
 the passes that would multiply the cast's zero terms: three of six on a
 v5e, PERF.md, PR 41), the softmax is over all E, and the K largest
 probabilities of a token are `ops.router_topk`'s — K rounds of a maximum in
@@ -58,7 +61,7 @@ sort key and nothing more; what the absent experts would add is left out.
 from __future__ import annotations
 
 import functools
-from typing import Dict, NamedTuple, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -330,32 +333,77 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
 def shared_expert_ffn(x: jax.Array, w_up: jax.Array, w_gate: jax.Array,
-                      w_down: jax.Array, gate_w: jax.Array, *,
-                      dtype=jnp.bfloat16) -> jax.Array:
+                      w_down: jax.Array, gate_w: Optional[jax.Array] = None,
+                      *, dtype=jnp.bfloat16) -> jax.Array:
     """The expert every token passes through, beside the routed ones: a
-    SwiGLU MLP times a sigmoid gate of its own. x: [B, S, D]; w_up, w_gate:
-    [D, F]; w_down: [F, D]; gate_w: [D]."""
+    SwiGLU MLP, times a sigmoid gate of its own where `gate_w` is given.
+    x: [B, S, D]; w_up, w_gate: [D, F]; w_down: [F, D]; gate_w: [D]."""
     with jax.named_scope("moe_shared"):
         up = jnp.einsum("bsd,df->bsf", x, w_up.astype(dtype))
         gate = jnp.einsum("bsd,df->bsf", x, w_gate.astype(dtype))
         out = jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
                          w_down.astype(dtype))
+        if gate_w is None:
+            return out.astype(dtype)
         open_ = jax.nn.sigmoid(jnp.einsum(
             "bsd,d->bs", x.astype(jnp.float32), gate_w.astype(jnp.float32)))
         return (out * open_[..., None].astype(dtype)).astype(dtype)
+
+
+def _route(logits, top_k, norm_topk_prob, score, select_bias, route_scale,
+           impl):
+    """The router from its float32 logits [T, E] on (`moe_ffn` says what
+    each argument means): every expert's score [T, E], and the K chosen
+    experts' weights and indices [T, K]."""
+    if score not in ("softmax", "sigmoid"):
+        raise ValueError(f"score {score!r}: 'softmax' or 'sigmoid'")
+    probs = (jax.nn.sigmoid(logits) if score == "sigmoid"
+             else jax.nn.softmax(logits, axis=-1))
+    chosen_by = probs
+    if select_bias is not None:
+        chosen_by = probs + lax.stop_gradient(
+            select_bias.astype(jnp.float32))
+    # the selection's kernel takes a router of whole 128-lane tiles; a
+    # narrower one (OLMoE's 64) keeps `lax.top_k` whatever kernels the
+    # model runs
+    gate_vals, expert_idx = router_topk(                        # [T, K]
+        chosen_by, top_k,
+        impl=impl if logits.shape[-1] % 128 == 0 else "reference")
+    if select_bias is not None:
+        gate_vals = jnp.take_along_axis(probs, expert_idx, axis=-1)
+    if norm_topk_prob:
+        total = gate_vals.sum(-1, keepdims=True)
+        gate_vals = gate_vals / (total + 1e-20 if score == "sigmoid"
+                                 else total)
+    if route_scale != 1.0:
+        gate_vals = gate_vals * route_scale
+    return probs, gate_vals, expert_idx
 
 
 def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
             w_gate: jax.Array, w_down: jax.Array, *,
             top_k: int = 2, norm_topk_prob: bool = True,
             first_expert: int = 0, dtype=jnp.bfloat16,
-            impl: str = "auto") -> Tuple[jax.Array, Dict[str, jax.Array]]:
+            impl: str = "auto", score: str = "softmax",
+            select_bias: Optional[jax.Array] = None,
+            route_scale: float = 1.0
+            ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """x: [B, S, D]; router_w: [D, E]; w_up/w_gate: [E, D, F];
     w_down: [E, F, D] → ([B, S, D], aux), aux holding the load-balancing
     loss over all K choices (E * sum_e fraction_e * mean prob_e: K at uniform
     routing), the router z-loss (mean squared logsumexp of the router
     logits), the tokens each expert received ([E] int32, summing to
     T x K: nothing is dropped) and each token's chosen experts ([T, K]).
+
+    `score`: "softmax" over the E logits, or "sigmoid" of each. Both losses
+    are defined on a softmax's distribution over the experts; a sigmoid
+    router has none, and under it `aux` holds neither (what balances such a
+    router is `select_bias`'s update between steps, which is no loss).
+    `select_bias` ([E]): added to the scores for the choice of the K experts
+    alone — the weights are the chosen experts' scores without it — and no
+    gradient reaches it. `norm_topk_prob` divides a token's K weights by
+    their sum (plus 1e-20 under a sigmoid, whose scores may all be 0);
+    `route_scale` multiplies them after.
 
     Given the weights of H < E experts, the layer holds experts
     `first_expert` .. `first_expert + H` of the E it routes over (the
@@ -384,18 +432,22 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
         logits = jnp.dot(xf.astype(jnp.float32),
                          router_w.astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)       # [T, E]
-        probs = jax.nn.softmax(logits, axis=-1)
-        # the selection's kernel takes a router of whole 128-lane tiles; a
-        # narrower one (OLMoE's 64) keeps `lax.top_k` whatever kernels the
-        # model runs
-        gate_vals, expert_idx = router_topk(                    # [T, K]
-            probs, top_k, impl=impl if e % 128 == 0 else "reference")
-        if norm_topk_prob:
-            gate_vals = gate_vals / gate_vals.sum(-1, keepdims=True)
+        probs, gate_vals, expert_idx = _route(
+            logits, top_k, norm_topk_prob, score, select_bias, route_scale,
+            impl)
+
+    def losses(fraction):
+        """The softmax router's two, given the share of the tokens each
+        expert received (sums to K); none under a sigmoid."""
+        if score == "sigmoid":
+            return {}
+        return {"moe_aux_loss": e * jnp.sum(fraction * probs.mean(0)),
+                "moe_router_z": jnp.mean(
+                    jax.nn.logsumexp(logits, axis=-1) ** 2)}
 
     held = w_up.shape[0]
     if held < e:
-        return _moe_ffn_held(x, logits, probs, gate_vals, expert_idx, w_up,
+        return _moe_ffn_held(x, e, losses, gate_vals, expert_idx, w_up,
                              w_gate, w_down, first_expert, dtype, impl)
 
     with jax.named_scope("moe_dispatch"):
@@ -416,22 +468,21 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
         out = jnp.einsum("tkd,tk->td", back.reshape(n_tokens, top_k, d),
                          gate_vals.astype(dtype))
 
-    fraction = group_sizes.astype(jnp.float32) / n_tokens       # sums to K
     aux = {
-        "moe_aux_loss": e * jnp.sum(fraction * probs.mean(0)),
-        "moe_router_z": jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2),
+        **losses(group_sizes.astype(jnp.float32) / n_tokens),  # sums to K
         "moe_expert_tokens": group_sizes,
         "moe_expert_choice": expert_idx,
     }
     return out.reshape(b, s, d).astype(dtype), aux
 
 
-def _moe_ffn_held(x, logits, probs, gate_vals, expert_idx, w_up, w_gate,
-                  w_down, first_expert, dtype, impl):
+def _moe_ffn_held(x, e, losses, gate_vals, expert_idx, w_up, w_gate, w_down,
+                  first_expert, dtype, impl):
     """`moe_ffn` from the routing on, for a layer that holds experts
-    `first_expert` .. `first_expert + H` of the E routed over."""
+    `first_expert` .. `first_expert + H` of the `e` routed over; `losses`
+    gives the router's loss terms from the experts' shares of the tokens."""
     b, s, d = x.shape
-    n_tokens, e, held = b * s, logits.shape[-1], w_up.shape[0]
+    n_tokens, held = b * s, w_up.shape[0]
     top_k = expert_idx.shape[-1]
     here = slice(first_expert, first_expert + held)
 
@@ -458,12 +509,10 @@ def _moe_ffn_held(x, logits, probs, gate_vals, expert_idx, w_up, w_gate,
         w_up.astype(dtype), w_gate.astype(dtype), w_down.astype(dtype),
         order, start, routed[here]), trip_sizes, impl)
 
-    fraction = routed.astype(jnp.float32) / n_tokens
     in_share = (expert_idx >= first_expert) & (
         expert_idx < first_expert + held)
     aux = {
-        "moe_aux_loss": e * jnp.sum(fraction * probs.mean(0)),
-        "moe_router_z": jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2),
+        **losses(routed.astype(jnp.float32) / n_tokens),
         "moe_expert_tokens": routed.at[here].set(given),
         "moe_expert_choice": expert_idx,
         "moe_routed_here": in_share.sum().astype(jnp.int32),

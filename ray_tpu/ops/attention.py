@@ -112,10 +112,12 @@ _ACC_VREGS = 32   # registers (1024 float32) an accumulator may ride a loop in
 def attention_reference(q: jax.Array, k: jax.Array, v: jax.Array,
                         causal: bool = True,
                         scale: Optional[float] = None,
-                        mask: Optional[jax.Array] = None) -> jax.Array:
+                        mask: Optional[jax.Array] = None,
+                        window: Optional[int] = None) -> jax.Array:
     """Plain jnp attention with GQA. q: [B, H, S, D]; k/v: [B, Hk, S, D];
     `mask`, where given, [B, keys, queries]: the keys a query attends (a
-    `Selection.mask`), every head alike."""
+    `Selection.mask`), every head alike; `window`, where given, the keys a
+    causal query attends counted back from its own, itself included."""
     *_, num_q_heads, q_len, head_dim = q.shape
     num_kv_heads = k.shape[-3]
     k_len = k.shape[-2]
@@ -131,6 +133,8 @@ def attention_reference(q: jax.Array, k: jax.Array, v: jax.Array,
         qi = jax.lax.broadcasted_iota(jnp.int32, (q_len, k_len), 0)
         kj = jax.lax.broadcasted_iota(jnp.int32, (q_len, k_len), 1)
         s = jnp.where(kj <= qi + (k_len - q_len), s, NEG_INF)
+        if window is not None:
+            s = jnp.where(kj > qi + (k_len - q_len) - window, s, NEG_INF)
     if mask is not None:
         s = jnp.where(jnp.swapaxes(mask, -1, -2)[..., None, :, :] != 0, s,
                       NEG_INF)
@@ -175,6 +179,24 @@ def _k_chunk_bounds(r0, sub, rel, k_valid, *, chunk):
     return interior_end, live_end
 
 
+def _k_band_bounds(r0, sub, rel, k_valid, window, *, chunk):
+    """`_k_chunk_bounds` under a window of `window` keys a query, its own
+    included (None: no window): of the key chunks [live_start, live_end)
+    that are not dead — the earlier ones are older than the window of the
+    tile's first query — those before `interior_start` are edge (the
+    window's lower edge crosses them), those from `interior_end` on are edge
+    as they were, and what lies between, if anything, is interior."""
+    interior_end, live_end = _k_chunk_bounds(r0, sub, rel, k_valid,
+                                             chunk=chunk)
+    if window is None:
+        return 0, 0, interior_end, live_end
+    # query r keeps the keys above r + rel - window
+    live_start = _clip((rel + r0 - window + 1) // chunk, 0, live_end)
+    interior_start = _clip(-(-(rel + r0 + sub - window) // chunk),
+                           live_start, live_end)
+    return live_start, interior_start, interior_end, live_end
+
+
 def _q_chunk_bounds(r0, sub, rel, q_valid, k_valid, *, chunk):
     """For the tile's keys [r0, r0 + sub): query chunks [0, live_start) are
     dead, [live_start, interior_start) edge (the diagonal), [interior_start,
@@ -213,11 +235,12 @@ def _rect(block_sub, block_chunk, head_dim, rect):
 
 
 def chunk_classes(q_len, k_len, causal, tile=DEFAULT_BLOCK_K,
-                  sub=_FWD_RECT[0], chunk=_FWD_RECT[1]):
+                  sub=_FWD_RECT[0], chunk=_FWD_RECT[1], window=None):
     """Count the score square's [chunk keys, sub queries] rectangles by
     class, as the forward and dq kernels walk it under `tile`-sized grid
-    steps: {"dead", "interior", "edge", "computed_share"}. A pure function
-    of static shapes, on the bounds that the kernels' loops use."""
+    steps (the forward alone under a `window`, whose older rectangles are
+    dead too): {"dead", "interior", "edge", "computed_share"}. A pure
+    function of static shapes, on the bounds that the kernels' loops use."""
     block_q, block_k = min(tile, q_len), min(tile, k_len)
     sub, chunk = _divisor(block_q, sub), _divisor(block_k, chunk)
     n_chunks = -(-block_k // chunk)
@@ -226,25 +249,30 @@ def chunk_classes(q_len, k_len, causal, tile=DEFAULT_BLOCK_K,
         for k0 in range(0, k_len, block_k):
             rel = q0 + k_len - q_len - k0 if causal else None
             for r0 in range(0, block_q, sub):
-                interior_end, live_end = _k_chunk_bounds(
-                    r0, sub, rel, min(block_k, k_len - k0), chunk=chunk)
+                live_start, interior_start, interior_end, live_end = \
+                    _k_band_bounds(r0, sub, rel, min(block_k, k_len - k0),
+                                   window, chunk=chunk)
                 if q0 + r0 >= q_len:
-                    interior_end = live_end = 0
-                interior += interior_end
-                edge += live_end - interior_end
-                dead += n_chunks - live_end
+                    live_start = interior_start = interior_end = live_end = 0
+                inside = max(interior_end - interior_start, 0)
+                interior += inside
+                edge += live_end - live_start - inside
+                dead += n_chunks - (live_end - live_start)
     return {"dead": dead, "interior": interior, "edge": edge,
             "computed_share": (interior + edge) / (dead + interior + edge)}
 
 
-def _edge_mask(k0, q0, shape, rel, q_valid, k_valid):
+def _edge_mask(k0, q0, shape, rel, q_valid, k_valid, low=None):
     """Validity of an edge rectangle of scores laid out [keys, queries]
     whose first key and query are the tile's k0-th and q0-th, or None if
     nothing masks. The in-bounds halves exist only at a ragged end (a
-    `*_valid` that is not None, known at trace time)."""
+    `*_valid` that is not None, known at trace time); `low` is `rel` less a
+    window's keys where the window's lower edge may cross the rectangle."""
     kj = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
     qi = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     mask = None if rel is None else kj <= qi + rel
+    if low is not None:
+        mask = kj > qi + low if mask is None else mask & (kj > qi + low)
     for idx, valid in ((kj, k_valid), (qi, q_valid)):
         if valid is not None:
             mask = idx < valid if mask is None else mask & (idx < valid)
@@ -339,8 +367,13 @@ def _walk(bounds, body, carry):
 class _Tiling:
     """Static facts about one call's grid, and the tile's place in it."""
 
-    def __init__(self, q_len, k_len, block_q, block_k, causal):
+    def __init__(self, q_len, k_len, block_q, block_k, causal, window=None):
+        if window is not None and not (causal and window > 0):
+            raise ValueError(f"a window of {window} keys: a causal query's "
+                             "last keys, its own included, at least one")
         self.q_len, self.k_len, self.causal = q_len, k_len, causal
+        # keys a query attends, counted back from its own (None: all)
+        self.window = window
         self.block_q, self.block_k = min(block_q, q_len), min(block_k, k_len)
         self.nq = pl.cdiv(q_len, self.block_q)
         self.nk = pl.cdiv(k_len, self.block_k)
@@ -350,6 +383,11 @@ class _Tiling:
         # every live tile lies on the diagonal or wholly below it
         self.aligned = (self.block_q == self.block_k
                         and self.off % self.block_k == 0)
+        # grid steps along the keys of the forward pass: every k tile, or
+        # under a window the most that a q tile's band holds
+        self.steps_k = self.nk if window is None else max(
+            self.last_live_k(i) - self.first_k(i) + 1
+            for i in range(self.nq))
 
     def ids(self, q_axis, k_axis):
         """(qb, kb): Python 0 along an axis that one tile spans."""
@@ -371,30 +409,69 @@ class _Tiling:
                 inside(self.k_len, self.block_k, kb) if self.ragged_k
                 else None)
 
+    def live(self, rel):
+        """Whether the causal tile at `rel` computes anything: it is not
+        above the diagonal, nor older than the window of its first query."""
+        live = rel + self.block_q > 0
+        if self.window is None:
+            return live
+        inside = rel < self.block_k + self.window - 1
+        return live and inside if _static(rel) else live & inside
+
     def for_each_class(self, qb, kb, walk):
         """Call `walk(rel)` for the tile's class. A static place, or no
         mask at all, is one static walk. Aligned causal tiles are either
         on the diagonal or wholly interior, each a static walk under its
-        `pl.when`; dead tiles run nothing. Ragged or unaligned shapes walk
-        with the traced `rel`."""
+        `pl.when`, and under a window the one or two tiles that its lower
+        edge crosses are a static walk each; dead tiles run nothing. Ragged
+        or unaligned shapes walk with the traced `rel`."""
         if not self.causal:
             return walk(None)
         rel = qb * self.block_q + self.off - kb * self.block_k
         if _static(rel):
-            return walk(rel) if rel + self.block_q > 0 else None
-        if self.aligned and not (self.ragged_q or self.ragged_k):
-            pl.when(rel == 0)(lambda: walk(0))
-            pl.when(rel >= self.block_k)(lambda: walk(self.block_k))
-        else:
-            pl.when(rel + self.block_q > 0)(lambda: walk(rel))
+            return walk(rel) if self.live(rel) else None
+        if not self.aligned or self.ragged_q or self.ragged_k:
+            pl.when(self.live(rel))(lambda: walk(rel))
+            return
+        tile = self.block_k
+        pl.when(rel == 0)(lambda: walk(0))
+        if self.window is None:
+            pl.when(rel >= tile)(lambda: walk(tile))
+            return
+        # the last query of a tile at `rel` keeps the keys above rel + tile
+        # - 1 - window: whole tiles as far as `whole`, crossed ones beyond
+        whole = (self.window - tile) // tile * tile
+        if whole >= tile:
+            pl.when((rel >= tile) & (rel <= whole))(lambda: walk(tile))
+        for crossed in range(max(whole, 0) + tile, tile + self.window - 1,
+                             tile):
+            pl.when(rel == crossed)(functools.partial(walk, crossed))
 
     def last_live_k(self, i):
-        """The last k tile that q tile `i` computes: dead steps name its
-        block again, so that no copy is issued for them."""
+        """The last k tile that q tile `i` computes: dead steps after it
+        name its block again, so that no copy is issued for them."""
         if not self.causal:
             return self.nk - 1
-        return jnp.clip((i * self.block_q + self.block_q - 1 + self.off)
-                        // self.block_k, 0, self.nk - 1)
+        return _clip((i * self.block_q + self.block_q - 1 + self.off)
+                     // self.block_k, 0, self.nk - 1)
+
+    def first_k(self, i):
+        """The k tile of q tile `i`'s first forward grid step: the first, or
+        under a window the first that is not older than the window of the
+        tile's first query (the grid holds `steps_k` steps a q tile, not
+        `nk`: a step before the band would cost what a live one costs to
+        start, and most of a long row's would be such)."""
+        if self.window is None:
+            return 0
+        return _clip((i * self.block_q + self.off - self.window + 1)
+                     // self.block_k, 0, self.nk - 1)
+
+    def live_k(self, i, j):
+        """The k tile that forward step j of q tile `i` names: its own where
+        the tile is live, else the last live one again."""
+        if self.window is not None:
+            j = self.first_k(i) + j
+        return jnp.minimum(j, self.last_live_k(i))
 
     def first_live_q(self, j):
         """The first q tile that k tile `j` computes; dkv's dead steps come
@@ -527,15 +604,15 @@ def seq_major_fits(q_shape, k_shape):
 # ---------------------------------------------------------------------------
 
 def _scores(k_ref, cols, q, r0, c, chunk, rel, q_valid=None, k_valid=None,
-            edge=False):
+            edge=False, low=None):
     """(st, mask): the float32 scores [keys, queries] of the tile's `c`-th
     chunk of keys against the scaled queries `q`, the tile's from `r0` on,
     NEG_INF where an edge rectangle's mask drops them, and that mask (None
-    where nothing masks)."""
+    where nothing masks). `low`: `_edge_mask`'s."""
     st = _dot_nt(_rows(k_ref, cols, c * chunk, chunk,
                        k_valid if edge else None), q)
     mask = _edge_mask(c * chunk, r0, st.shape, rel, q_valid,
-                      k_valid) if edge else None
+                      k_valid, low) if edge else None
     if mask is not None:
         st = jnp.where(mask, st, NEG_INF)
     return st, mask
@@ -564,9 +641,9 @@ def _online_softmax(carry, st, v, mask=None, v_transposed=False):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "rel", "heads", "dim", "scale", "sub", "chunk", "keyless"))
+    "rel", "heads", "dim", "scale", "sub", "chunk", "keyless", "window"))
 def _fwd_unrolled(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, vt_refs, *,
-                  rel, heads, dim, scale, sub, chunk, keyless):
+                  rel, heads, dim, scale, sub, chunk, keyless, window=None):
     """The forward walk of a whole tile whose place is static (`rel`), for
     the `heads` of the block, each `dim` wide: straight-line code.
 
@@ -582,6 +659,14 @@ def _fwd_unrolled(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, vt_refs, *,
     there; without, p.v contracts v's rows and the matrix unit makes v^T a
     rectangle (`_fwd_pallas` says where which).
 
+    Under a `window` a block of queries starts its walk at the first chunk
+    the window leaves it, and a rectangle's mask holds the half that crosses
+    it: the diagonal's, the window's lower edge's, or both. A query that the
+    lower edge leaves no key of its first rectangle keeps a maximum of
+    NEG_INF there, and what exp(0) then adds to its sum and accumulator is
+    multiplied by exp(NEG_INF - m), exactly 0, at its first kept key: its
+    own key at the latest.
+
     Jitted, on the block's refs, for the trace's sake and not the
     program's: the walk is 36 rectangles a head at 1024 positions, a
     hundred a head of a long row's two tile classes, and every program that
@@ -590,25 +675,31 @@ def _fwd_unrolled(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, vt_refs, *,
     function by the refs' shapes and the static place, so a process traces
     a walk once a tile shape; the kernel's lowering inlines it."""
     block_q, block_k = q_ref.shape[0], k_ref.shape[0]
-    rects = []      # (scores' arguments, head, queries' slice, chunk, last)
+    # (scores' arguments, head, queries' slice, chunk, first, last)
+    rects = []
     for hh in heads:
         cols = slice(hh * dim, (hh + 1) * dim)
         if vt_refs:
             vt_refs[hh][...] = v_ref[:, cols].T
         for r0 in range(0, block_q, sub):
-            interior_end, live_end = _k_chunk_bounds(r0, sub, rel, block_k,
-                                                     chunk=chunk)
+            live_start, interior_start, interior_end, live_end = \
+                _k_band_bounds(r0, sub, rel, block_k, window, chunk=chunk)
             q = _scaled(q_ref[r0:r0 + sub, cols], scale)
-            rects += [((k_ref, cols, q, r0, c, chunk, rel, None, None,
-                        c >= interior_end), hh, slice(r0, r0 + sub), c,
-                       c == live_end - 1) for c in range(live_end)]
+            for c in range(live_start, live_end):
+                diagonal, lower = c >= interior_end, c < interior_start
+                rects.append((
+                    (k_ref, cols, q, r0, c, chunk, rel if diagonal else None,
+                     None, None, diagonal or lower,
+                     rel - window if lower else None),
+                    hh, slice(r0, r0 + sub), c, c == live_start,
+                    c == live_end - 1))
 
     made = [_scores(*args) for args, *_ in rects[:_FWD_AHEAD]]
-    for i, ((_, cols, *_), hh, rs, c, last) in enumerate(rects):
+    for i, ((_, cols, *_), hh, rs, c, first, last) in enumerate(rects):
         if i + _FWD_AHEAD < len(rects):
             made.append(_scores(*rects[i + _FWD_AHEAD][0]))
         row, keys = slice(hh, hh + 1), slice(c * chunk, (c + 1) * chunk)
-        if c == 0:
+        if first:
             carry = m_ref[row, rs], l_ref[row, rs], acc_ref[cols, rs]
         st, mask = made.pop(0)
         carry = _online_softmax(
@@ -631,16 +722,20 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     the select after the exp exists only where a query can have no key at
     all. q is scaled once per sub-block. A tile whose place is static walks
     unrolled, its scores made ahead (`_fwd_unrolled`); a ragged or unaligned
-    one by loops, a sub-block after the other, the products in place.
+    one by loops, a sub-block after the other, the products in place. Under
+    a window (`t.window`) the chunks older than it are dead too, its lower
+    edge is a second kind of edge, walked first, and a q tile's grid steps
+    start at its band's first k tile (`_Tiling.first_k`).
     Matmul operands are in the inputs' dtype (p cast to it); scores, exp,
     m, l, the accumulator and lse are float32. With `transposed_out` the
     output block is written as it was accumulated, [head_dim, queries].
     """
-    qb, kb = t.ids(2, 3)
+    qb, step = t.ids(2, 3)      # the q tile, and its step along the keys
+    kb = step if t.window is None else t.first_k(qb) + step
     q_valid, k_valid = t.valid(qb, kb)
     keyless = t.causal and t.off < 0      # queries before the first key
 
-    @_when(kb == 0)
+    @_when(step == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
@@ -651,20 +746,22 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
             return _fwd_unrolled(
                 q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, vt_refs,
                 rel=rel, heads=tuple(hhs), dim=heads.dim, scale=scale,
-                sub=sub, chunk=chunk, keyless=keyless)
+                sub=sub, chunk=chunk, keyless=keyless, window=t.window)
+        low = None if t.window is None else rel - t.window
         for hh in hhs:
             cols, row = heads.cols(hh), slice(hh, hh + 1)
             if vt_refs:
                 vt_refs[hh][...] = _rows(v_ref, cols, 0, t.block_k, k_valid).T
             for r0 in range(0, t.block_q, sub):
                 rs = slice(r0, r0 + sub)
-                interior_end, live_end = _k_chunk_bounds(
-                    r0, sub, rel, t.span(q_valid, k_valid)[1], chunk=chunk)
+                live_start, interior_start, interior_end, live_end = \
+                    _k_band_bounds(r0, sub, rel, t.span(q_valid, k_valid)[1],
+                                   t.window, chunk=chunk)
                 q = _scaled(q_ref[rs, cols], scale)
 
                 def step(c, carry, edge):
                     st, mask = _scores(k_ref, cols, q, r0, c, chunk, rel,
-                                       q_valid, k_valid, edge)
+                                       q_valid, k_valid, edge, low)
                     if vt_refs:
                         v = vt_refs[hh][:, _span(c * chunk, chunk)]
                     else:
@@ -674,9 +771,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
                         carry, st, v, mask if keyless else None,
                         v_transposed=bool(vt_refs))
 
+                carry = m_ref[row, rs], l_ref[row, rs], acc_ref[cols, rs]
+                if t.window is not None:
+                    # the window's lower edge comes first
+                    carry = _walk((live_start, live_start, interior_start),
+                                  step, carry)
+                    interior_end = _clip(interior_end, interior_start,
+                                         live_end)
                 m_ref[row, rs], l_ref[row, rs], acc_ref[cols, rs] = _walk(
-                    (0, interior_end, live_end), step,
-                    (m_ref[row, rs], l_ref[row, rs], acc_ref[cols, rs]))
+                    (interior_start, interior_end, live_end), step, carry)
 
     heads.together(lambda hhs: t.for_each_class(
         qb, kb, functools.partial(walk, hhs=hhs)))()
@@ -691,16 +794,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
             o_ref[:, cols] = o.T.astype(o_ref.dtype)
         lse_ref[hh] = m_ref[row] + jnp.log(l)
 
-    _when(kb == t.nk - 1)(heads.each(write))
+    _when(step == t.steps_k - 1)(heads.each(write))
 
 
 def _fwd_pallas(q, k, v, *, scale, causal, block_q, block_k, interpret,
-                transposed_out=False, seq_major=False):
+                transposed_out=False, seq_major=False, window=None):
     """q, k, v: [B, H, S, D] or, with `seq_major`, [B, S, H, D]. Returns
     (out in q's layout and dtype, lse [B, H, S] float32); with
-    `transposed_out`, out is [B, H, D, S] in both layouts."""
+    `transposed_out`, out is [B, H, D, S] in both layouts. With `window` a
+    query attends its last `window` keys, its own included, and the call is
+    named `flash_fwd_window`: the same body over the band's rectangles."""
     heads = _Heads(q.shape, k.shape, seq_major)
-    t = _Tiling(heads.q_len, heads.k_len, block_q, block_k, causal)
+    t = _Tiling(heads.q_len, heads.k_len, block_q, block_k, causal, window)
     sub, chunk = _rect(t.block_q, t.block_k, heads.dim, _FWD_RECT)
     # v^T scratches, one a head of the block: a v narrower than the lanes
     # streams its transpose out of the matrix unit at half rate, 8 rows an
@@ -709,8 +814,7 @@ def _fwd_pallas(q, k, v, *, scale, causal, block_q, block_k, interpret,
     staged = heads.per if heads.dim < 128 else 0
 
     q_spec = heads.spec(t.block_q, lambda i, j: i)
-    kv_spec = heads.spec(
-        t.block_k, lambda i, j: jnp.minimum(j, t.last_live_k(i)), kv=True)
+    kv_spec = heads.spec(t.block_k, t.live_k, kv=True)
     out_spec, out_shape = q_spec, heads.shape(t.q_len)
     if transposed_out:
         out_spec = heads.stat_spec(heads.dim, t.block_q, lambda i, j: (0, i))
@@ -719,7 +823,7 @@ def _fwd_pallas(q, k, v, *, scale, causal, block_q, block_k, interpret,
         functools.partial(_fwd_kernel, scale=scale, t=t, heads=heads,
                           sub=sub, chunk=chunk,
                           transposed_out=transposed_out),
-        grid=(heads.batch, heads.steps, t.nq, t.nk),
+        grid=(heads.batch, heads.steps, t.nq, t.steps_k),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[
             out_spec,
@@ -737,7 +841,7 @@ def _fwd_pallas(q, k, v, *, scale, causal, block_q, block_k, interpret,
             *[pltpu.VMEM((heads.dim, t.block_k), q.dtype)] * staged,
         ],
         interpret=interpret,
-        name="flash_fwd",
+        name="flash_fwd" if window is None else "flash_fwd_window",
     )(*heads.arrays(q, k, v))
     if not transposed_out:
         out = out.reshape(q.shape)
@@ -1303,18 +1407,21 @@ def flash_attention(q, k, v, causal: bool = True,
                     scale: Optional[float] = None,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_k: int = DEFAULT_BLOCK_K,
-                    interpret: bool = False, seq_major: bool = False):
+                    interpret: bool = False, seq_major: bool = False,
+                    window: Optional[int] = None):
     """FlashAttention-2 on TPU (Pallas). [B, H, S, D] or, with `seq_major`,
     [B, S, H, D] in and out; GQA via Hk | H. A sequence-major call whose
     heads the kernels do not read in place (`seq_major_fits`: a head width
     that fills the lanes, or does not divide them, or a GQA group) goes
-    through the head-major kernels and XLA's transposes."""
+    through the head-major kernels and XLA's transposes. With `window` a
+    causal query attends its last `window` keys, its own included: forward
+    only, the backward kernels know no window."""
     if seq_major and not seq_major_fits(q.shape, k.shape):
         return _via_head_major(
             lambda q, k, v: _flash(q, k, v, causal, scale, block_q, block_k,
-                                   interpret, False), q, k, v)
+                                   interpret, False, window), q, k, v)
     return _flash(q, k, v, causal, scale, block_q, block_k, interpret,
-                  seq_major)
+                  seq_major, window)
 
 
 def _via_head_major(fn, q, k, v):
@@ -1323,11 +1430,21 @@ def _via_head_major(fn, q, k, v):
     return jnp.swapaxes(out, 1, 2)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(q, k, v, causal, scale, block_q, block_k, interpret, seq_major):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, causal, scale, block_q, block_k, interpret, seq_major,
+           window=None):
     return _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-                      seq_major)[0]
+                      seq_major, window)[0]
 
+
+# Why nothing differentiates through the kernels under a window: said where
+# the gradient would be taken (`_flash_bwd`) and where a model would ask for
+# it (`models.gpt.GPT.loss`).
+WINDOW_HAS_NO_BACKWARD = (
+    "attention under a window has no backward kernel: `flash_bwd` and the "
+    "pair walk the whole causal triangle, and a full-attention gradient is "
+    "not a window's. impl='reference' differentiates through the masked jnp "
+    "form")
 
 # Names of the forward kernel's two results as `jax.checkpoint` sees them. A
 # policy that lists them (`GPT`'s "dots") keeps them for the backward pass,
@@ -1342,7 +1459,7 @@ def _narrow(q):
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-               seq_major):
+               seq_major, window=None):
     """The residual that carries the output is lane-dense. An array whose
     minor dimension is narrower than the 128 lanes of a tile is stored
     padded to them (head width 64: twice its size, as a saved activation and
@@ -1356,7 +1473,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
     out, lse = _fwd_pallas(q, k, v, scale=scale_val, causal=causal,
                            block_q=block_q, block_k=block_k,
                            interpret=interpret, transposed_out=transposed,
-                           seq_major=seq_major)
+                           seq_major=seq_major, window=window)
     out = checkpoint_name(out, FLASH_RESIDUAL_NAMES[0])
     lse = checkpoint_name(lse, FLASH_RESIDUAL_NAMES[1])
     primal = out
@@ -1366,8 +1483,10 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
     return primal, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, interpret, seq_major, res,
-               g):
+def _flash_bwd(causal, scale, block_q, block_k, interpret, seq_major, window,
+               res, g):
+    if window is not None:
+        raise NotImplementedError(WINDOW_HAS_NO_BACKWARD)
     q, k, v, out, lse = res
     scale_val = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     # delta = rowsum(dO * O), [B, H, S]: all the kernels want of the output
@@ -1398,26 +1517,33 @@ def dot_product_attention(q, k, v, causal: bool = True,
                           block_q: int = DEFAULT_BLOCK_Q,
                           block_k: int = DEFAULT_BLOCK_K,
                           seq_major: bool = False,
-                          selection=None) -> jax.Array:
+                          selection=None,
+                          window: Optional[int] = None) -> jax.Array:
     """Attention entry point used by models. [B, H, S, D] or, with
     `seq_major`, [B, S, H, D] (the projections' own layout) in and out.
     With `selection` (`ops.sparse_index.Selection`) each query attends its
     chosen keys alone, forward only: the kernel `dsa_attend_fwd`, which has
-    no backward pass.
+    no backward pass. With `window` a causal query attends its last `window`
+    keys, its own included (t - window < s <= t): the forward kernel walks
+    the band alone (`flash_fwd_window`), and only the `jnp` form
+    differentiates.
 
     impl: as `ops._impl.resolve_impl` takes it; the kernels take any width.
     """
     impl = resolve_impl(impl, "attention")
+    if selection is not None and window is not None:
+        raise ValueError("attention over a choice of keys takes no window")
     if impl == "reference":
         reference = functools.partial(
             attention_reference, causal=causal, scale=scale,
-            mask=None if selection is None else selection.mask)
+            mask=None if selection is None else selection.mask,
+            window=window)
         if seq_major:
             return _via_head_major(reference, q, k, v)
         return reference(q, k, v)
     if selection is None:
         return flash_attention(q, k, v, causal, scale, block_q, block_k,
-                               impl == "pallas_interpret", seq_major)
+                               impl == "pallas_interpret", seq_major, window)
 
     def attend(q, k, v):
         return _attend_pallas(

@@ -168,6 +168,21 @@ def test_oom_autopsy_names_victims_top_object(tmp_path, pressure_env):
         # to values); the dep pin names it through rec.deps either way
         ref = hold_and_sleep.options(max_retries=0).remote([big], marker)
         assert _wait_for_attempts(marker, 1)
+        # the marker says the task runs, not that the node has heard of
+        # the ref it holds: the worker registers a nested ref on its
+        # connection when it unpickles the list, and the autopsy reads
+        # that table. Wait until the plane counts the worker's reference
+        # beside the driver's before the pressure rises (on a loaded
+        # machine the kill came first and named no object)
+        deadline = time.monotonic() + 30.0
+        while time.monotonic() < deadline:
+            rows = [r for r in rstate.list_objects()
+                    if r["object_id"] == big.id.hex()]
+            if rows and sum((rows[0].get("ref_types") or {}).values()) >= 2:
+                break
+            time.sleep(0.1)
+        else:
+            pytest.fail("the worker's reference never reached the plane")
         os.environ["RTPU_TEST_MEMORY_USAGE_FRACTION"] = "0.99"
         with pytest.raises(OutOfMemoryError):
             ray_tpu.get(ref, timeout=30)

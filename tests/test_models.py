@@ -129,6 +129,8 @@ def test_layer_pattern_is_whole_periods_of_known_kinds():
         GPTConfig(n_layers=6, layer_pattern=("linear", "linear", "linear",
                                              "full"))
     with pytest.raises(ValueError, match="period of"):
+        GPTConfig(layer_pattern=("banded",))
+    with pytest.raises(ValueError, match="attn_window"):
         GPTConfig(layer_pattern=("window",))
     # a JSON file hands the period over as a list
     assert GPTConfig(n_layers=4, layer_pattern=["linear", "full"]
