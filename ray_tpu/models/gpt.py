@@ -453,6 +453,38 @@ def _expert_weights(c: GPTConfig, lead: bool = False) -> Dict[str, _Weight]:
     return weights
 
 
+# the experts' weights that the grouped matmuls take: a custom call's
+# operand, into which no slice of a stack is fused (`moe.moe_ffn`'s `layer`)
+_EXPERT_STACKS = ("w_up", "w_gate", "w_down")
+
+
+def _experts_apart(stacked: Params, lead: int) -> Tuple[Params, Params]:
+    """The stacked weights of a kind's layers ([*stack, ...], `lead` stacking
+    axes) in two: what a scan over the layers slices — every weight but the
+    experts' three, and each layer's place among the kind's, `experts_at` —
+    and the three as [L, E, ...], which is the same bytes."""
+    stack = stacked[_EXPERT_STACKS[0]].shape[:lead]
+    scanned = {name: w for name, w in stacked.items()
+               if name not in _EXPERT_STACKS}
+    scanned["experts_at"] = jnp.arange(
+        math.prod(stack), dtype=jnp.int32).reshape(stack)
+    return scanned, {name: stacked[name].reshape(
+        -1, *stacked[name].shape[lead:]) for name in _EXPERT_STACKS}
+
+
+def _unless_differentiated(fast, plain):
+    """`fast`, a function of arrays whose values are `plain`'s, with
+    `plain`'s derivatives: jax takes the rule below wherever the call is
+    differentiated, forward or backward, and `fast` as it stands elsewhere.
+    `fast` is jitted: traced under `custom_jvp` as it stands, a served
+    bucket's program took 0.7 s longer to trace on the chip's host, a sixth
+    of a replica's start over its six programs; jitted, what the parent
+    took (PERF.md, PR 54)."""
+    fn = jax.custom_jvp(jax.jit(fast))
+    fn.defjvp(lambda primals, tangents: jax.jvp(plain, primals, tangents))
+    return fn
+
+
 class _Half(NamedTuple):
     """A kind of mixer or of FFN: what lists its weights (of the config; an
     FFN's also of whether the layer is a leading one), and what applies them
@@ -1002,6 +1034,7 @@ class GPT:
         c = self.config
         down, aux = moe_ffn(
             h, w["router"], w["w_up"], w["w_gate"], w["w_down"],
+            layer=w.get("experts_at"),
             top_k=c.moe_top_k, norm_topk_prob=c.moe_norm_topk_prob,
             first_expert=c.moe_first_expert, dtype=c.dtype,
             score=c.moe_score, select_bias=w.get("router_bias"),
@@ -1112,31 +1145,16 @@ class GPT:
             x = self._pipeline_blocks(block_fns["full"], params["blocks"], x,
                                       positions)
             aux_per_layer = {}
-        elif len(c.layer_pattern) == 1:
-            block_fn = block_fns[c.layer_pattern[0]]
-
-            def scan_body(x, layer_w):
-                x, aux = block_fn(x, positions, layer_w)
-                return x, aux
-
-            x, aux_per_layer = lax.scan(scan_body, x, params["blocks"])
         else:
-            def period_body(x, period_w):
-                seen = dict.fromkeys(period_w, 0)
-                facts = []
-                for kind in c.layer_pattern:
-                    layer_w = jax.tree_util.tree_map(
-                        lambda a: a[seen[kind]], period_w[kind])
-                    seen[kind] += 1
-                    x, aux = block_fns[kind](x, positions, layer_w)
-                    facts.append(aux)
-                return x, jax.tree_util.tree_map(
-                    lambda *a: jnp.stack(a), *facts)
-
-            x, aux_per_period = lax.scan(period_body, x, params["blocks"])
-            # [periods, layers a period, ...] -> [L, ...]
-            aux_per_layer = jax.tree_util.tree_map(
-                lambda a: a.reshape(-1, *a.shape[2:]), aux_per_period)
+            through = functools.partial(self._through_blocks, block_fns)
+            if self._experts_lie_ready(params["blocks"]):
+                # read where they lie, unless the weights are differentiated:
+                # then the stack closed over would be handed a cotangent of
+                # its own size a layer, where a scanned slice's is the
+                # layer's, so a derivative is that of the sliced form
+                through = _unless_differentiated(
+                    functools.partial(through, in_place=True), through)
+            x, aux_per_layer = through(x, positions, params["blocks"])
         for facts in reversed(lead_facts):
             for name, fact in facts.items():
                 aux_per_layer[name] = (
@@ -1159,6 +1177,67 @@ class GPT:
         aux = {k: v.mean() if k in ("moe_aux_loss", "moe_router_z") else v
                for k, v in aux_per_layer.items()}
         return logits, aux
+
+    def _experts_lie_ready(self, blocks: Params) -> bool:
+        """Whether the layers' grouped matmuls can read the experts' weights
+        in the stacks `blocks` holds them in (`moe.moe_ffn`'s `layer`): the
+        FFN is the experts', the stacks are in the compute dtype already (a
+        served replica's; master weights in float32 are cast a layer, and
+        the cast writes the layer's copy whatever it reads), and no mesh
+        shards them (merging the layers' axis with the experts' would move a
+        sharded axis)."""
+        c = self.config
+        if c.n_experts <= 0 or self.mesh is not None:
+            return False
+        kinds = [blocks] if len(c.layer_pattern) == 1 else blocks.values()
+        return all(w[name].dtype == jnp.dtype(c.dtype)
+                   for w in kinds for name in _EXPERT_STACKS)
+
+    def _through_blocks(self, block_fns, x, positions, blocks: Params,
+                        in_place: bool = False):
+        """x through the layers after the leading ones: a scan over the
+        layers of the one kind, or over the periods with a period's layers
+        written out. Returns x and the layers' facts, [L, ...] each.
+
+        `in_place` (`_experts_lie_ready`): the experts' three stacks are not
+        scanned but closed over, as [L, E, ...], and a layer is handed them
+        with its place in them, `experts_at`, which is scanned with the
+        other weights' slices."""
+        c = self.config
+        if len(c.layer_pattern) == 1:
+            block_fn = block_fns[c.layer_pattern[0]]
+            stacks = {}
+            if in_place:
+                blocks, stacks = _experts_apart(blocks, lead=1)
+
+            def scan_body(x, layer_w):
+                return block_fn(x, positions, {**layer_w, **stacks})
+
+            return lax.scan(scan_body, x, blocks)
+        stacks = dict.fromkeys(blocks, {})
+        if in_place:
+            apart = {kind: _experts_apart(w, lead=2)
+                     for kind, w in blocks.items()}
+            blocks, stacks = ({kind: halves[i] for kind, halves
+                               in apart.items()} for i in (0, 1))
+
+        def period_body(x, period_w):
+            seen = dict.fromkeys(period_w, 0)
+            facts = []
+            for kind in c.layer_pattern:
+                layer_w = jax.tree_util.tree_map(
+                    lambda a: a[seen[kind]], period_w[kind])
+                seen[kind] += 1
+                x, aux = block_fns[kind](x, positions,
+                                         {**layer_w, **stacks[kind]})
+                facts.append(aux)
+            return x, jax.tree_util.tree_map(
+                lambda *a: jnp.stack(a), *facts)
+
+        x, aux_per_period = lax.scan(period_body, x, blocks)
+        # [periods, layers a period, ...] -> [L, ...]
+        return x, jax.tree_util.tree_map(
+            lambda a: a.reshape(-1, *a.shape[2:]), aux_per_period)
 
     def _pipeline_blocks(self, block_fn, blocks: Params, x: jax.Array,
                          positions: jax.Array) -> jax.Array:

@@ -19,9 +19,20 @@ matmuls over the E ragged groups (`jax.lax.ragged_dot`: FLOPs and memory
 grow with T x K, with no factor of E and no [T, E, C] tensor). The weighted
 results return to token order through the inverse permutation and are summed
 over K. An expert with no token is an empty group; an expert with many times
-the mean is a long one. Expert weights carry a leading "expert" logical axis
-that the rules map to the ``ep`` mesh axis: an annotation the partitioner is
-left to honour (no exchange of tokens between chips is written here).
+the mean is a long one. The weights are read where they lie: a layer may be
+handed the stack its experts are one slice of ([L, E, D, F], as a parameter
+tree holds the layers of a kind) and its place in it (`layer`), and the
+grouped matmuls then run over the stack's L x E experts with the layer's E
+group sizes set among zeros, the other layers' experts being (L - 1) x E more
+empty groups — because a grouped matmul is a call the compiler fuses no slice
+into, so that a layer's weights sliced from the stack were written out and
+read back before every one of them, 0.5 GB a weight in a served Trinity-Mini
+(PERF.md, PR 54). That holds where the stack is in the compute dtype already;
+where it is cast on the way in, the cast's output is the layer's copy, the
+slice is fused into it and the matmuls take that. Expert weights carry a
+leading "expert" logical axis that the rules map to the ``ep`` mesh axis: an
+annotation the partitioner is left to honour (no exchange of tokens between
+chips is written here).
 
 A layer may hold only a share of its experts (`first_expert` and as many as
 the weights it is given: one chip's part of a layer that several share). It
@@ -115,12 +126,27 @@ _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 HELD_CHUNK_SHARE = 2.0
 
 
-def _swiglu_groups(rows, w_up, w_gate, w_down, sizes):
-    """Rows sorted by expert through their experts: three grouped matmuls."""
+def _swiglu_groups(rows, w_up, w_gate, w_down, sizes, layer=None):
+    """Rows sorted by expert through their experts ([E, ...] weights, [E]
+    `sizes`): three grouped matmuls, each weight cast to the rows' dtype
+    where it is not in it. With `layer` the weights are stacks [L, E, ...]
+    in that dtype, read where they lie: as [L x E, ...], which is the same
+    bytes, under [L x E] sizes that are the layer's at `layer * E` and 0
+    elsewhere — the groups before a layer's hold no row, so its first still
+    starts at row 0."""
+    def of(w):
+        w = w.astype(rows.dtype)
+        return w if layer is None else w.reshape(-1, *w.shape[2:])
+
     with jax.named_scope("moe_experts"):
-        up = lax.ragged_dot(rows, w_up, sizes)
-        gate = lax.ragged_dot(rows, w_gate, sizes)
-        return lax.ragged_dot(jax.nn.silu(gate) * up, w_down, sizes)
+        if layer is not None:
+            n, e = w_up.shape[:2]
+            sizes = lax.dynamic_update_slice(
+                jnp.zeros(n * e, sizes.dtype), sizes,
+                (jnp.asarray(layer, jnp.int32) * e,))
+        up = lax.ragged_dot(rows, of(w_up), sizes)
+        gate = lax.ragged_dot(rows, of(w_gate), sizes)
+        return lax.ragged_dot(jax.nn.silu(gate) * up, of(w_down), sizes)
 
 
 class _Run(NamedTuple):
@@ -129,7 +155,9 @@ class _Run(NamedTuple):
     experts; `order`, the (token, choice) pairs sorted by expert and followed
     by a chunk's length of padding, so that a trip is a slice of it wherever
     it starts; `start`, where the held experts' run begins in it; `counts`
-    [H], how many pairs each held expert takes."""
+    [H], how many pairs each held expert takes; `layer`, where the weights
+    are stacks [L, H, ...], the layer whose experts these are
+    (`_swiglu_groups`), else None."""
     x: jax.Array
     gate_vals: jax.Array
     w_up: jax.Array
@@ -138,6 +166,7 @@ class _Run(NamedTuple):
     order: jax.Array
     start: jax.Array
     counts: jax.Array
+    layer: Optional[jax.Array] = None
 
 
 def _held_chunk(lo, rows, run: _Run):
@@ -258,7 +287,8 @@ def _held_trip(out, lo, by_token, run: _Run, *, rows: int, impl: str):
     walk's counts: the rows each held expert was given, and the rows it
     took, real or not."""
     *_, taken, sizes = _held_chunk(lo, rows, run)
-    y = _swiglu_groups(taken, run.w_up, run.w_gate, run.w_down, sizes)
+    y = _swiglu_groups(taken, run.w_up, run.w_gate, run.w_down, sizes,
+                       run.layer)
     with jax.named_scope("moe_combine"):
         ids, at, pair = (a[:rows] for a in by_token)
         out = _sum_into_tokens(out, lo, y, (ids, at),
@@ -316,17 +346,28 @@ def _held_experts_fwd(run, trip_sizes, impl):
 
 def _held_experts_bwd(trip_sizes, impl, run, cotangents):
     f32 = jnp.float32
+    stacks = (run.w_up, run.w_gate, run.w_down)
+    if run.layer is not None:
+        # the backward walk takes the layer's own weights, sliced once a
+        # pass: their gradients are the layer's size while they are summed
+        # over the trips, and are set into the stacks' once, after
+        run = run._replace(w_up=run.w_up[run.layer],
+                           w_gate=run.w_gate[run.layer],
+                           w_down=run.w_down[run.layer])
     weights = (run.w_up, run.w_gate, run.w_down)
     (dx, d_gate), d_weights = _walk(
         functools.partial(_held_trip_bwd, impl=impl),
         (jnp.zeros(run.x.shape, f32), jnp.zeros(run.gate_vals.size, f32)),
-        tuple(jnp.zeros(w.shape, f32) for w in weights), run, cotangents[0],
+        tuple(jnp.zeros(w.shape, f32) for w in weights),
+        run._replace(layer=None), cotangents[0],
         sizes=trip_sizes, pairs=False, scope="moe_dispatch")
-    return (_Run(dx.astype(run.x.dtype),
-                 d_gate.reshape(run.gate_vals.shape).astype(
-                     run.gate_vals.dtype),
-                 *(d.astype(w.dtype) for d, w in zip(d_weights, weights)),
-                 None, None, None),)
+    dx = dx.astype(run.x.dtype)
+    d_gate = d_gate.reshape(run.gate_vals.shape).astype(run.gate_vals.dtype)
+    d_weights = tuple(d.astype(w.dtype) for d, w in zip(d_weights, weights))
+    if run.layer is not None:
+        d_weights = tuple(jnp.zeros_like(w).at[run.layer].set(d)
+                          for d, w in zip(d_weights, stacks))
+    return (_Run(dx, d_gate, *d_weights, None, None, None, None),)
 
 
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
@@ -386,7 +427,7 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
             first_expert: int = 0, dtype=jnp.bfloat16,
             impl: str = "auto", score: str = "softmax",
             select_bias: Optional[jax.Array] = None,
-            route_scale: float = 1.0
+            route_scale: float = 1.0, layer=None
             ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """x: [B, S, D]; router_w: [D, E]; w_up/w_gate: [E, D, F];
     w_down: [E, F, D] → ([B, S, D], aux), aux holding the load-balancing
@@ -416,7 +457,18 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
     that is no multiple of 128 keeps `lax.top_k` under any `impl`) and of
     the sum that returns the held experts' rows to token order
     (`ops.segment_sum`), as it picks the other kernels.
+
+    With `layer` (an int, or a traced one under a scan over layers), the
+    three expert weights are stacks [L, ...] of which this layer's are
+    `w[layer]`, and the result is that of `moe_ffn` on those slices (on a
+    TPU bit for bit: PERF.md, PR 54). A stack in `dtype` is read where it
+    lies (`_swiglu_groups`); one in another is sliced on its way through the
+    cast, which writes the layer's copy whatever it is handed.
     """
+    if layer is not None and not (
+            w_up.dtype == w_gate.dtype == w_down.dtype == jnp.dtype(dtype)):
+        w_up, w_gate, w_down = w_up[layer], w_gate[layer], w_down[layer]
+        layer = None
     b, s, d = x.shape
     e = router_w.shape[-1]
     n_tokens = b * s
@@ -445,10 +497,10 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
                 "moe_router_z": jnp.mean(
                     jax.nn.logsumexp(logits, axis=-1) ** 2)}
 
-    held = w_up.shape[0]
+    held = w_up.shape[-3]
     if held < e:
         return _moe_ffn_held(x, e, losses, gate_vals, expert_idx, w_up,
-                             w_gate, w_down, first_expert, dtype, impl)
+                             w_gate, w_down, first_expert, dtype, impl, layer)
 
     with jax.named_scope("moe_dispatch"):
         flat_expert = expert_idx.reshape(-1)                    # [T*K]
@@ -457,11 +509,8 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
         group_sizes = jnp.bincount(flat_expert, length=e).astype(jnp.int32)
         rows = _take_rows(xf.astype(dtype), order, inverse, top_k)  # [T*K, D]
 
-    with jax.named_scope("moe_experts"):
-        up = lax.ragged_dot(rows, w_up.astype(dtype), group_sizes)
-        gate = lax.ragged_dot(rows, w_gate.astype(dtype), group_sizes)
-        act = jax.nn.silu(gate) * up
-        expert_out = lax.ragged_dot(act, w_down.astype(dtype), group_sizes)
+    expert_out = _swiglu_groups(rows, w_up, w_gate, w_down, group_sizes,
+                                layer)
 
     with jax.named_scope("moe_combine"):
         back = _take_rows(expert_out, inverse, order, 1)
@@ -477,12 +526,12 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
 
 
 def _moe_ffn_held(x, e, losses, gate_vals, expert_idx, w_up, w_gate, w_down,
-                  first_expert, dtype, impl):
+                  first_expert, dtype, impl, layer):
     """`moe_ffn` from the routing on, for a layer that holds experts
     `first_expert` .. `first_expert + H` of the `e` routed over; `losses`
     gives the router's loss terms from the experts' shares of the tokens."""
     b, s, d = x.shape
-    n_tokens, held = b * s, w_up.shape[0]
+    n_tokens, held = b * s, w_up.shape[-3]
     top_k = expert_idx.shape[-1]
     here = slice(first_expert, first_expert + held)
 
@@ -507,7 +556,9 @@ def _moe_ffn_held(x, e, losses, gate_vals, expert_idx, w_up, w_gate, w_down,
     out, given, walked = _held_experts(_Run(
         x.reshape(n_tokens, d).astype(dtype), gate_vals,
         w_up.astype(dtype), w_gate.astype(dtype), w_down.astype(dtype),
-        order, start, routed[here]), trip_sizes, impl)
+        order, start, routed[here],
+        None if layer is None else jnp.asarray(layer, jnp.int32)),
+        trip_sizes, impl)
 
     in_share = (expert_idx >= first_expert) & (
         expert_idx < first_expert + held)

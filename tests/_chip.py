@@ -97,12 +97,80 @@ def _kernel_names(compiled, name):
         for line in _kernel_calls(compiled, name))
 
 
+def _operations(compiled):
+    """The compiled program's operations by name: (result type, opcode,
+    the text from its operands on)."""
+    return {m.group(1): m.groups()[1:]
+            for line in compiled.as_text().splitlines()
+            if (m := re.match(
+                r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?)\s([\w\-]+)\((.*)", line))}
+
+
+def _grouped_matmul_weights(compiled):
+    """Where each of the compiled program's grouped matmuls
+    (`ragged-dot-none*`, the compiler's own custom calls) gets its weights
+    from: the opcode that writes its last operand, read through `bitcast`s
+    and `get-tuple-element`s. "parameter" (the program's, or the loop
+    body's whose tuple holds the program's) says the weights are read where
+    they lie; a "fusion" or a "copy" says they were written out first: a
+    custom call's operand cannot be a slice fused into it (PERF.md, PR
+    54)."""
+    made = _operations(compiled)
+    sources = []
+    for name, (_, op, args) in made.items():
+        if op != "custom-call" or not name.startswith("ragged-dot-none"):
+            continue
+        at = re.findall(r"%([\w.\-]+)", args.split("), ")[0])[-1]
+        while made[at][1] in ("bitcast", "get-tuple-element"):
+            at = re.search(r"%([\w.\-]+)", made[at][2]).group(1)
+        sources.append(made[at][1])
+    return sources
+
+
+def _writes_of(compiled, *shapes):
+    """The names of the compiled program's operations that write an array
+    of one of `shapes` ("bf16[128,2048,1024]"), alone or in a tuple: every
+    operation but the ones that only name bytes that are there."""
+    return [name for name, (made, op, _) in _operations(compiled).items()
+            if any(shape in made for shape in shapes)
+            and op not in ("parameter", "bitcast", "get-tuple-element")]
+
+
 def benchmark_config(name):
     """`benchmarks/configs/<name>.json`, read."""
     path = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), f"benchmarks/configs/{name}.json")
     with open(path) as f:
         return json.load(f)
+
+
+def served_bucket(v5e, name, rows, length):
+    """`benchmarks/configs/<name>.json` as `loops/serve.py::Scorer` builds
+    it (bfloat16 weights, the bucket program's own text), one bucket's
+    program compiled for the described chip: (the weights' shapes, the
+    compiled program)."""
+    from jax.sharding import SingleDeviceSharding
+    from ray_tpu.models.gpt import GPT, GPTConfig
+
+    kw = dict(benchmark_config(name)["model"], attention_impl="pallas")
+    kw["dtype"] = getattr(jnp, kw["dtype"])
+    kw["param_dtype"] = getattr(jnp, kw["param_dtype"])
+    model = GPT(GPTConfig(**kw))
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    params = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, jnp.bfloat16,
+                                       sharding=one_chip),
+        jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0))))
+
+    def score_bucket(params, tokens):
+        logits = model.apply(params, tokens)[:, :-1]
+        at_target = jnp.take_along_axis(
+            logits, tokens[:, 1:, None], axis=-1)[..., 0]
+        return at_target - jax.nn.logsumexp(logits, axis=-1)
+
+    return params, jax.jit(score_bucket).lower(
+        params, jax.ShapeDtypeStruct((rows, length), jnp.int32,
+                                     sharding=one_chip)).compile()
 
 
 def _qwen3_next_config():

@@ -20,13 +20,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax import lax
-from jax.sharding import SingleDeviceSharding
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
     __file__))))
 
-from _chip import (_kernel_calls, _kernel_names,          # noqa: E402,F401
-                   benchmark_config, v5e)
+from _chip import (_grouped_matmul_weights, _kernel_calls,  # noqa: E402,F401
+                   _kernel_names, _writes_of, benchmark_config,
+                   served_bucket, v5e)
 from benchmarks.reference import keye_vl2, keye_vl2_glue   # noqa: E402
 from ray_tpu.models.gpt import GPT, GPTConfig, llama_tiny  # noqa: E402
 from ray_tpu.ops.attention import (_Tiling, _live_tiles,   # noqa: E402
@@ -594,34 +594,15 @@ def test_the_largest_served_bucket_compiles_and_fits_a_v5e(v5e):
     kernels of the mechanism and the held experts' two are in it, and by
     the compiler's account it takes 5.71 GB, 36% of the chip (what the
     cell's runs report as `memory_peak_bytes`)."""
-    config = benchmark_config("keye_vl_2_30b_a3b")
     with open(os.path.join(
             os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
             "benchmarks/traffic/serve-score-16k-steady-over.json")) as f:
         batching = json.load(f)["batching"]
     rows, length = max(batching["rows"]), max(batching["lengths"])
     assert (rows, length) == (2, 16384)
-    kw = dict(config["model"], attention_impl="pallas")
-    kw["dtype"] = getattr(jnp, kw["dtype"])
-    kw["param_dtype"] = getattr(jnp, kw["param_dtype"])
-    model = GPT(GPTConfig(**kw))
-    one_chip = SingleDeviceSharding(v5e.devices[0])
-    params = jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, jnp.bfloat16,
-                                       sharding=one_chip),
-        jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0))))
-
-    def score_bucket(params, tokens):
-        logits = model.apply(params, tokens)[:, :-1]
-        at_target = jnp.take_along_axis(
-            logits, tokens[:, 1:, None], axis=-1)[..., 0]
-        return at_target - jax.nn.logsumexp(logits, axis=-1)
-
+    params, compiled = served_bucket(v5e, "keye_vl_2_30b_a3b", rows, length)
     weights = sum(x.size * 2 for x in jax.tree_util.tree_leaves(params))
     assert 1.70e9 < weights < 1.72e9        # 853 M parameters in bfloat16
-    compiled = jax.jit(score_bucket).lower(
-        params, jax.ShapeDtypeStruct((rows, length), jnp.int32,
-                                     sharding=one_chip)).compile()
     assert _kernel_names(compiled, "dsa_") == ["dsa_attend_fwd", "dsa_index"]
     # (the held walk's two sizes of trip, a body each in the one scanned
     # layer: PR 52)
@@ -638,3 +619,17 @@ def test_the_largest_served_bucket_compiles_and_fits_a_v5e(v5e):
     total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert 5.2e9 < total < 6.2e9, total
+
+
+def test_the_smallest_served_bucket_copies_no_expert_weight(v5e):
+    """The scan's form of what `test_trinity_mini.py` pins (PERF.md, PR 54):
+    the eight layers' held experts are [8, 16, ...] stacks; scanned, a layer's
+    slice was written out before its walk (three `dynamic-slice` fusions a
+    layer, 24 copies of 50 MB a call); closed over and read at the layer's
+    place, the walk's grouped matmuls take the loops' parameters and nothing
+    writes an array of a layer's experts."""
+    _, compiled = served_bucket(v5e, "keye_vl_2_30b_a3b", 1, 4096)
+    # the walk's two sizes of trip, three matmuls each
+    assert _grouped_matmul_weights(compiled) == ["parameter"] * 6
+    assert _writes_of(compiled, "bf16[16,2048,768]",
+                      "bf16[16,768,2048]") == []

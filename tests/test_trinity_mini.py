@@ -18,12 +18,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-from _chip import (_kernel_names, benchmark_config, v5e)   # noqa: E402,F401
+from _chip import (_grouped_matmul_weights, _kernel_names,  # noqa: E402,F401
+                   _writes_of, benchmark_config, served_bucket, v5e)
 from benchmarks.reference import (trinity_mini,            # noqa: E402
                                   trinity_mini_glue)
 from benchmarks.tests import trinity_faults                # noqa: E402
@@ -354,33 +354,14 @@ def test_the_largest_served_bucket_compiles_and_fits_a_v5e(v5e):
     kernel four times (the leading layer and the period's three), the full
     one once, the router's once, and by the compiler's account 12.2 GB with
     the 7.05 GB of weights, 76% of the chip."""
-    config = benchmark_config("trinity_mini")
     with open(os.path.join(ROOT, "benchmarks/traffic/"
                            "serve-score-16k-steady-over-swa.json")) as f:
         batching = json.load(f)["batching"]
     rows, length = max(batching["rows"]), max(batching["lengths"])
     assert (rows, length) == (2, 16384)
-    kw = dict(config["model"], attention_impl="pallas")
-    kw["dtype"] = getattr(jnp, kw["dtype"])
-    kw["param_dtype"] = getattr(jnp, kw["param_dtype"])
-    model = GPT(GPTConfig(**kw))
-    one_chip = SingleDeviceSharding(v5e.devices[0])
-    params = jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, jnp.bfloat16,
-                                       sharding=one_chip),
-        jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0))))
-
-    def score_bucket(params, tokens):
-        logits = model.apply(params, tokens)[:, :-1]
-        at_target = jnp.take_along_axis(
-            logits, tokens[:, 1:, None], axis=-1)[..., 0]
-        return at_target - jax.nn.logsumexp(logits, axis=-1)
-
+    params, compiled = served_bucket(v5e, "trinity_mini", rows, length)
     weights = sum(x.size * 2 for x in jax.tree_util.tree_leaves(params))
     assert 7.04e9 < weights < 7.06e9        # 3.52 B parameters in bfloat16
-    compiled = jax.jit(score_bucket).lower(
-        params, jax.ShapeDtypeStruct((rows, length), jnp.int32,
-                                     sharding=one_chip)).compile()
     # the leading layer's call and the scanned period's three and one
     assert _kernel_names(compiled, "flash_") == [
         "flash_fwd"] + ["flash_fwd_window"] * 4
@@ -389,3 +370,18 @@ def test_the_largest_served_bucket_compiles_and_fits_a_v5e(v5e):
     total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert 11.5e9 < total < 13.0e9, total
+
+
+def test_the_smallest_served_bucket_copies_no_expert_weight(v5e):
+    """The 1 x 4,096 bucket's program, whose 86 ms a call had 12 in copies
+    (PERF.md, PR 54): the three window layers' experts are a [1, 3, 128, ...]
+    stack, a grouped matmul is a custom call into which no slice is fused,
+    and nine of the twelve took a fusion that wrote a layer's 0.54 GB out
+    first. Read in place (`moe.moe_ffn`'s `layer`) each takes the
+    program's own parameter, nothing writes an array of a layer's experts,
+    and the temporaries are a quarter of the 2.48 GB they were."""
+    _, compiled = served_bucket(v5e, "trinity_mini", 1, 4096)
+    assert _grouped_matmul_weights(compiled) == ["parameter"] * 12
+    assert _writes_of(compiled, "bf16[128,2048,1024]",
+                      "bf16[128,1024,2048]") == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.8e9
