@@ -49,6 +49,11 @@ from .config import CONFIG
 DEFAULT_BUCKETS = (0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5,
                    5.0, 10.0)
 
+# for what takes a millisecond or less: a background thread's activation, a
+# phase of the serve batcher, a handle's routing
+SHORT_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
+                 0.5, 1.0, 2.5, 10.0)
+
 # for what takes seconds, not milliseconds: a checkpoint, a gang's start
 LONG_BUCKETS = (0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 20.0, 40.0,
                 80.0)
@@ -842,8 +847,7 @@ M_WORKER_BACKGROUND = define(
     "Seconds one activation of a process's periodic background thread "
     "ran (thread=sample_devices|telemetry_flush; chips=the accelerator "
     "slots the process's running task holds, 0 in a driver)",
-    buckets=(0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
-             0.5, 1.0, 2.5, 10.0))
+    buckets=SHORT_BUCKETS)
 M_DROPPED_SERIES = define(
     "counter", "rtpu_telemetry_dropped_series_total",
     "Metric series dropped by the control plane (cardinality cap or "

@@ -7,6 +7,13 @@ Implementation: a per-function collector thread gathers requests until
 callable with the list; callers block on their slot's future. Works with
 threaded actors (``max_concurrency > 1``) — concurrency is what creates
 batchable simultaneous requests.
+
+The collector times itself (ISSUE 55): its thread's life is tiled by four
+spans — ``serve::batch_wait`` (no request to take), ``serve::batch_fill``
+(first request taken -> batch closed), ``serve::batch_call`` (the wrapped
+function) and ``serve::batch_resolve`` (the futures) — each also an
+observation of ``rtpu_serve_batch_seconds{deployment, phase}``; a member's
+own waits, into the batch and back out of it, are two digests.
 """
 
 from __future__ import annotations
@@ -21,12 +28,31 @@ from .._private import telemetry
 from concurrent.futures import Future
 from typing import Any, Callable, List, Optional
 
+from ..util import tracing
 from . import request_context as _rc
 
 M_SERVE_BATCH_SIZE_DIGEST = telemetry.define(
     "digest", "rtpu_serve_batch_size_digest",
     "Streaming quantile digest of @serve.batch batch sizes per "
     "deployment (how well concurrent requests coalesce)")
+M_SERVE_BATCH_SECONDS = telemetry.define(
+    "histogram", "rtpu_serve_batch_seconds",
+    "Seconds one phase of a @serve.batch collector's loop took, one "
+    "observation a batch (phase=wait: blocked with no request to take; "
+    "fill: first request taken -> batch closed; call: the wrapped "
+    "function; resolve: the members' futures)",
+    buckets=telemetry.SHORT_BUCKETS)
+M_SERVE_BATCH_QUEUE = telemetry.define(
+    "digest", "rtpu_serve_batch_queue_seconds",
+    "Quantile digest of a request's wait in the @serve.batch queue: its "
+    "submit -> its batch closed, one record a member")
+M_SERVE_BATCH_WAKE = telemetry.define(
+    "digest", "rtpu_serve_batch_wake_seconds",
+    "Quantile digest of a batch member's way back: the collector's stamp "
+    "before it resolves the batch's futures -> the member's thread "
+    "returned from its future")
+
+_PHASES = ("wait", "fill", "call", "resolve")
 
 
 class _Batcher:
@@ -38,40 +64,70 @@ class _Batcher:
         self.q: "_queue.Queue" = _queue.Queue()
         self._thread: Optional[threading.Thread] = None
         self._lock = locksan.lock("serve.batcher")
+        self._bind_series("default")
 
-    def _ensure_thread(self):
+    def _bind_series(self, deployment: str) -> None:
+        """The tags and prebound digests of this batcher's own series. A
+        batcher serves one deployment for its life; which one it learns
+        from the request context its members carry."""
+        self._deployment = deployment
+        self._phase_tags = {
+            phase: (("deployment", deployment), ("phase", phase))
+            for phase in _PHASES}
+        self._queue_digest = telemetry.digest_series(
+            M_SERVE_BATCH_QUEUE, (("deployment", deployment),))
+        self._wake_digest = telemetry.digest_series(
+            M_SERVE_BATCH_WAKE, (("deployment", deployment),))
+
+    def _ensure_thread(self, meta: Optional[dict]):
         with self._lock:
             if self._thread is None or not self._thread.is_alive():
+                if meta:    # before the collector's first wait opens
+                    self._bind_series(meta.get("deployment", "default"))
                 self._thread = threading.Thread(target=self._loop,
                                                 daemon=True)
                 self._thread.start()
 
+    def _phase(self, phase: str):
+        return tracing.timed_span("serve::batch_" + phase,
+                                  M_SERVE_BATCH_SECONDS,
+                                  self._phase_tags[phase])
+
     def _loop(self):
         while True:
-            item = self.q.get()          # (arg, future, req_meta, trace)
-            t_first = _time.monotonic()
-            batch = [item]
-            # absolute deadline per batch: a fixed per-get timeout would
-            # reset on every arrival, making the first caller wait up to
-            # (max_batch_size-1)*timeout under a trickle of requests
-            deadline = t_first + self.timeout_s
-            while len(batch) < self.max_batch_size:
-                remaining = deadline - _time.monotonic()
-                if remaining <= 0:
-                    break
-                try:
-                    batch.append(self.q.get(timeout=remaining))
-                except _queue.Empty:
-                    break
+            with self._phase("wait"):
+                # (arg, future, req_meta, trace, submitted)
+                item = self.q.get()
+            with self._phase("fill"):
+                t_first = _time.monotonic()
+                batch = [item]
+                # absolute deadline per batch: a fixed per-get timeout
+                # would reset on every arrival, making the first caller
+                # wait up to (max_batch_size-1)*timeout under a trickle
+                # of requests
+                deadline = t_first + self.timeout_s
+                while len(batch) < self.max_batch_size:
+                    remaining = deadline - _time.monotonic()
+                    if remaining <= 0:
+                        break
+                    try:
+                        batch.append(self.q.get(timeout=remaining))
+                    except _queue.Empty:
+                        break
+            t_closed = _time.monotonic()
             args = [it[0] for it in batch]
             futures = [it[1] for it in batch]
+            lead = next((it[2] for it in batch if it[2]), None)
             try:
                 # accounting must never break the batch: an exception
                 # here (thread exhaustion in a lazy flusher start,
                 # interpreter teardown) would kill the collector with
                 # every member's future unresolved — callers block on
                 # fut.result() with no timeout
-                self._note_batch(batch, t_first)
+                deployment = (lead or {}).get("deployment", self._deployment)
+                if deployment != self._deployment:
+                    self._bind_series(deployment)
+                self._note_batch(batch, t_first, t_closed)
             except Exception:   # noqa: BLE001 — observability only
                 pass
             # bind the batch LEADER's request context around the user
@@ -79,32 +135,41 @@ class _Batcher:
             # id is inherently approximate, but get_request_id() inside
             # a batched body should name a member of THIS batch, not ""
             # (the per-member ids live in each access-log row)
-            lead = next((it[2] for it in batch
-                         if len(it) > 2 and it[2]), None)
             tok = _rc.bind(lead) if lead is not None else None
+            results, error = None, None
             try:
-                results = self.fn(args)
+                with self._phase("call"):
+                    results = self.fn(args)
                 if results is None or len(results) != len(args):
                     raise ValueError(
                         "@serve.batch function must return one result per "
                         f"input ({len(args)} inputs)")
-                for fut, res in zip(futures, results):
-                    fut.set_result(res)
             except Exception as e:
-                for fut in futures:
-                    fut.set_exception(e)
+                error = e
             finally:
                 if tok is not None:
                     _rc.unbind(tok)
+            with self._phase("resolve"):
+                # the members read the stamp back: their wake-up runs
+                # from here (`submit`)
+                stamp = _time.monotonic()
+                for i, fut in enumerate(futures):
+                    fut.resolving_at = stamp
+                    if error is None:
+                        fut.set_result(results[i])
+                    else:
+                        fut.set_exception(error)
 
-    @staticmethod
-    def _note_batch(batch, t_first: float) -> None:
-        """Request-plane accounting for one assembled batch: stamp each
-        member request's batch size (the replica's access-log row reads
-        it back), record the per-deployment batch-size digest, and emit
-        one ``request::batch_assemble`` span parented to the first
-        member's trace (span start = first arrival, end = invoke)."""
-        metas = [it[2] for it in batch if len(it) > 2 and it[2]]
+    def _note_batch(self, batch, t_first: float, t_closed: float) -> None:
+        """Accounting for one assembled batch: each member's wait in the
+        queue; then, for the request plane, stamp each member request's
+        batch size (the replica's access-log row reads it back), record
+        the per-deployment batch-size digest, and emit one
+        ``request::batch_assemble`` span parented to the first member's
+        trace (span start = first arrival, end = invoke)."""
+        for it in batch:
+            telemetry.digest_record(self._queue_digest, t_closed - it[4])
+        metas = [it[2] for it in batch if it[2]]
         if not metas:
             return                    # plane off / outside a request
         n = len(batch)
@@ -113,9 +178,7 @@ class _Batcher:
         deployment = metas[0].get("deployment", "default")
         telemetry.digest_observe(M_SERVE_BATCH_SIZE_DIGEST, float(n),
                                  (("deployment", deployment),))
-        from ..util import tracing
-        parent = next((it[3] for it in batch
-                       if len(it) > 3 and it[3]), None)
+        parent = next((it[3] for it in batch if it[3]), None)
         if parent is not None or tracing.enabled():
             span = tracing.begin_span(
                 "request::" + "batch_assemble", parent,
@@ -126,17 +189,20 @@ class _Batcher:
             tracing.end_span(span)
 
     def submit(self, arg: Any) -> Any:
-        self._ensure_thread()
         fut: Future = Future()
         # carry the caller's request context + trace ctx to the
         # collector thread (contextvars/thread-locals don't cross)
         meta = _rc.current() if _rc.enabled() else None
-        trace = None
-        if meta is not None:
-            from ..util import tracing
-            trace = tracing.get_current_context()
-        self.q.put((arg, fut, meta, trace))
-        return fut.result()
+        self._ensure_thread(meta)
+        trace = tracing.get_current_context() if meta is not None else None
+        self.q.put((arg, fut, meta, trace, _time.monotonic()))
+        try:
+            return fut.result()
+        finally:
+            stamp = getattr(fut, "resolving_at", None)
+            if stamp is not None:   # None: thrown out of the wait itself
+                telemetry.digest_record(self._wake_digest,
+                                        _time.monotonic() - stamp)
 
 
 def batch(_fn: Optional[Callable] = None, *, max_batch_size: int = 8,

@@ -9,6 +9,7 @@ the controller's replica list on a TTL.
 
 from __future__ import annotations
 
+import functools
 import random
 import time
 from typing import Any, Dict, List, Optional
@@ -16,9 +17,34 @@ from typing import Any, Dict, List, Optional
 from .. import get
 from .._private import context as _pctx
 from .._private import locksan
+from .._private import telemetry
+from ..util import tracing
 from . import request_context as _rc
 
 _REFRESH_S = 1.0
+
+M_SERVE_HANDLE_ROUTE = telemetry.define(
+    "histogram", "rtpu_serve_handle_route_seconds",
+    "Seconds one handle.remote() / handle.stream() held the caller's "
+    "thread: the replica list's refresh when due, the pick, the actor "
+    "call's submission", buckets=telemetry.SHORT_BUCKETS)
+M_SERVE_HANDLE_REFRESH = telemetry.define(
+    "histogram", "rtpu_serve_handle_refresh_seconds",
+    "Seconds one round trip to the controller for a deployment's replica "
+    "list took, on the thread of the caller whose request found the list "
+    "stale", buckets=telemetry.SHORT_BUCKETS)
+
+
+def _timed_route(route):
+    """The whole of a routing call on the caller's thread, `_refresh`
+    included, as the span ``serve::route`` and one observation of
+    ``rtpu_serve_handle_route_seconds``."""
+    @functools.wraps(route)
+    def timed(self, *args, **kwargs):
+        with tracing.timed_span("serve::route", M_SERVE_HANDLE_ROUTE,
+                                self._mtags):
+            return route(self, *args, **kwargs)
+    return timed
 
 
 class DeploymentHandle:
@@ -35,14 +61,17 @@ class DeploymentHandle:
         self._last_refresh = 0.0
         self._lock = locksan.lock("serve.handle")
         self._rng = random.Random()
+        self._mtags = (("deployment", deployment_name),)
 
     # -------------------------------------------------------------- routing
     def _refresh(self, force: bool = False) -> None:
         now = time.monotonic()
         if not force and now - self._last_refresh < _REFRESH_S:
             return
-        replicas = get(self._controller.get_replicas.remote(
-            self.deployment_name))
+        with tracing.timed_span("serve::refresh", M_SERVE_HANDLE_REFRESH,
+                                self._mtags):
+            replicas = get(self._controller.get_replicas.remote(
+                self.deployment_name))
         def ids(rs):
             return [getattr(r, "_actor_id", None) for r in rs]
 
@@ -132,6 +161,7 @@ class DeploymentHandle:
                     time.time(), model_id)
         return (_rc.new_request_id(), None, None, time.time(), model_id)
 
+    @_timed_route
     def _route(self, model_id, *args, **kwargs):
         self._refresh()
         meta = self._request_meta(model_id)
@@ -171,6 +201,7 @@ class DeploymentHandle:
         Returns an iterator of item VALUES."""
         return self._route_stream(None, *args, **kwargs)
 
+    @_timed_route
     def _route_stream(self, model_id, *args, **kwargs):
         self._refresh()
         meta = self._request_meta(model_id)

@@ -6,7 +6,8 @@ handle_request → user callable, queue metrics for autoscaling).
 Request observability (ISSUE 13): every request arrives with a compact
 context tuple (``spec.request_ctx`` baggage set by the handle, re-bound
 by the worker around the call — never an extra arg slot) — the replica
-measures queue wait (enqueued_at → execution start),
+measures queue wait (enqueued_at → execution start, and its
+replica-local part, frame arrival → execution start),
 re-binds the request context around the user callable (and streaming
 iteration) so ``serve.get_request_id()`` and ``@serve.batch`` see it,
 opens ``request::queue_wait`` / ``request::replica_execute`` spans when
@@ -33,9 +34,6 @@ from ..api import remote
 from ..util import tracing
 from . import request_context as _rc
 
-M_SERVE_LATENCY = telemetry.define(
-    "histogram", "rtpu_serve_request_latency_seconds",
-    "Replica-side request handling latency, tagged by deployment")
 M_SERVE_REQUESTS = telemetry.define(
     "counter", "rtpu_serve_requests_total",
     "Requests handled by serve replicas, tagged deployment and "
@@ -51,6 +49,12 @@ M_SERVE_QUEUE_WAIT_DIGEST = telemetry.define(
     "digest", "rtpu_serve_queue_wait_digest_seconds",
     "Streaming quantile digest of request queue wait (handle routing "
     "enqueue -> replica execution start) per deployment")
+M_SERVE_SLOT_WAIT_DIGEST = telemetry.define(
+    "digest", "rtpu_serve_replica_slot_wait_seconds",
+    "Quantile digest of the replica-local part of the queue wait: the "
+    "request's frame arrived in the replica's worker -> execution start "
+    "on a pool thread (the wait for one of max_concurrent_queries slots; "
+    "monotonic clock, one process)")
 
 # access-log ring rows are stored as compact tuples in this field order
 # and shaped into dicts lazily on access_log() reads / slow-error
@@ -92,6 +96,8 @@ class Replica:
             M_SERVE_LATENCY_DIGEST, (("deployment", self._deployment),))
         self._wait_digest = telemetry.digest_series(
             M_SERVE_QUEUE_WAIT_DIGEST, (("deployment", self._deployment),))
+        self._slot_digest = telemetry.digest_series(
+            M_SERVE_SLOT_WAIT_DIGEST, (("deployment", self._deployment),))
         # structured access log: fixed-capacity ring, GIL-atomic appends
         # (pool threads share it lock-free); capacity 0 disables the
         # whole request plane
@@ -108,13 +114,11 @@ class Replica:
             depth = self._depth
         telemetry.gauge_set(M_SERVE_QUEUE_DEPTH, float(depth), self._qtags)
 
-    def _exit(self, t0: float, ok: bool) -> None:
+    def _exit(self, ok: bool) -> None:
         with self._depth_lock:
             self._depth -= 1
             depth = self._depth
         telemetry.gauge_set(M_SERVE_QUEUE_DEPTH, float(depth), self._qtags)
-        telemetry.hist_observe(M_SERVE_LATENCY, time.monotonic() - t0,
-                               self._mtags)
         telemetry.counter_inc(
             M_SERVE_REQUESTS, 1.0,
             self._mtags + (("status", "ok" if ok else "error"),))
@@ -141,17 +145,21 @@ class Replica:
             proto = "python"
         now = time.time()
         queue_wait = now - enqueued_at
+        # the skew-free replica-local component of the wait: actor-call
+        # arrival at this process to execution start
+        recv = _pctx.request_recv_t.get()
+        slot_wait = 0.0
+        if recv is not None:
+            slot_wait = max(0.0, time.monotonic() - recv)
+            telemetry.digest_record(self._slot_digest, slot_wait)
         if queue_wait < 0.0:
             # cross-node clock skew hid the wait (enqueued_at is the
-            # HANDLE's wall clock): fall back to the skew-free
-            # replica-local component — actor-call arrival at this
-            # process to execution start. Positive skew inflating the
-            # wall measure is undetectable here; keep clocks synced
-            # (documented limitation, same tradeoff as the reference's
-            # cross-process wall-clock serve metrics).
-            recv = _pctx.request_recv_t.get()
-            queue_wait = (max(0.0, time.monotonic() - recv)
-                          if recv is not None else 0.0)
+            # HANDLE's wall clock): fall back to the local component.
+            # Positive skew inflating the wall measure is undetectable
+            # here; keep clocks synced (documented limitation, same
+            # tradeoff as the reference's cross-process wall-clock
+            # serve metrics).
+            queue_wait = slot_wait
         telemetry.digest_record(self._wait_digest, queue_wait)
         meta = {"request_id": rid, "deployment": self._deployment,
                 "route": route, "proto": proto,
@@ -264,7 +272,7 @@ class Replica:
                     result = self._instance(*args, **kwargs)
         except BaseException as e:
             self._finish_request(rctx, t0, ok=False, error=repr(e))
-            self._exit(t0, ok=False)
+            self._exit(ok=False)
             raise
         if inspect.isgenerator(result):
             # streaming: the request is live until the stream drains —
@@ -278,7 +286,7 @@ class Replica:
                     _rc.unbind(token)
             return self._track_stream(result, t0, rctx)
         self._finish_request(rctx, t0, ok=True)
-        self._exit(t0, ok=True)
+        self._exit(ok=True)
         return result
 
     def _track_stream(self, gen, t0: float, rctx=None):
@@ -312,7 +320,7 @@ class Replica:
                                               span["start_time"])
                 tracing.end_span(span, error=err)
             self._finish_request(rctx, t0, ok, error=err)
-            self._exit(t0, ok)
+            self._exit(ok)
 
     def handle_request_mux(self, model_id: str, *args, **kwargs):
         """handle_request with the request's multiplexed model id bound
@@ -345,7 +353,6 @@ class Replica:
 
     def call_method(self, method_name: str, *args, **kwargs):
         self._enter()
-        t0 = time.monotonic()
         ok = True
         try:
             return getattr(self._instance, method_name)(*args, **kwargs)
@@ -353,7 +360,7 @@ class Replica:
             ok = False
             raise
         finally:
-            self._exit(t0, ok)
+            self._exit(ok)
 
     def queue_depth(self) -> int:
         # executing + queued requests on this replica (approximation of

@@ -21,7 +21,7 @@ def test_scanner_sees_known_metrics():
     for name in ("rtpu_scheduler_tasks_submitted_total",
                  "rtpu_object_store_put_bytes_total",
                  "rtpu_collective_latency_seconds",
-                 "rtpu_serve_request_latency_seconds",
+                 "rtpu_serve_batch_seconds",
                  "rtpu_data_blocks_total",
                  "rtpu_device_hbm_bytes_in_use"):
         assert name in defined, name

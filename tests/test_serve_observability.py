@@ -440,3 +440,258 @@ def test_crashed_replica_gauge_retired(serve_session):
                 else None)
 
     assert _wait(replaced, timeout=20)
+
+
+# ------------------------------------------------------------------
+# The Serve layer times itself (ISSUE 55): the collector's phases, a
+# request's waits and the handle's stalls as spans and series.
+
+import glob              # noqa: E402
+import threading         # noqa: E402
+
+from ray_tpu._private import telemetry        # noqa: E402
+from ray_tpu.serve import request_context as _rc    # noqa: E402
+
+PHASES = ("wait", "fill", "call", "resolve")
+BATCH_SECONDS = "rtpu_serve_batch_seconds"
+BATCH_QUEUE = "rtpu_serve_batch_queue_seconds"
+BATCH_WAKE = "rtpu_serve_batch_wake_seconds"
+
+
+class _Batched:
+    """What a deployment's instance is to `@serve.batch`, in this process:
+    the decorator's own wrapper and collector, no replica around them."""
+
+    def __init__(self, fail=False):
+        self.batches, self.fail = [], fail
+
+    @serve.batch(max_batch_size=4, batch_wait_timeout_s=0.05)
+    def infer(self, xs):
+        self.batches.append(len(xs))
+        if self.fail:
+            raise KeyError("the batch function raised")
+        return [x * 2 for x in xs]
+
+    def request(self, deployment, x, out=None):
+        """One request's thread: the request context a replica binds,
+        then the batched call."""
+        token = _rc.bind({"deployment": deployment, "request_id": str(x)})
+        try:
+            result = self.infer(x)
+        except KeyError as e:
+            result = e
+        finally:
+            _rc.unbind(token)
+        if out is not None:
+            out[x] = result
+        return result
+
+    def volley(self, deployment, xs):
+        out = {}
+        threads = [threading.Thread(target=self.request,
+                                    args=(deployment, x, out)) for x in xs]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+        return out
+
+
+def _phase(snap, deployment, phase):
+    return snap["hists"].get((BATCH_SECONDS, (("deployment", deployment),
+                                              ("phase", phase))))
+
+
+def _digest_count(snap, name, deployment):
+    payload = snap["digests"].get((name, (("deployment", deployment),)))
+    return payload["count"] if payload else 0
+
+
+@pytest.fixture(scope="module")
+def phased():
+    """Nine requests through one collector: a lone one, a pause, a volley
+    of eight; the table after them and the wall time they took."""
+    model, t0 = _Batched(), time.monotonic()
+    assert model.request("phased", 1) == 2
+    time.sleep(0.3)
+    out = model.volley("phased", range(10, 18))
+    assert out == {x: 2 * x for x in range(10, 18)}
+    wall = time.monotonic() - t0
+    return {"snap": telemetry.snapshot_local(), "wall": wall,
+            "batches": list(model.batches), "requests": 9}
+
+
+@pytest.mark.parametrize("phase", PHASES)
+def test_each_phase_of_the_collector_is_observed_once_a_batch(phased, phase):
+    row = _phase(phased["snap"], "phased", phase)
+    assert sum(phased["batches"]) == 9 and len(phased["batches"]) >= 3
+    assert row and row["count"] == len(phased["batches"]), (phase, row)
+    assert 0.0 <= row["sum"] <= phased["wall"]
+
+
+def test_the_four_phases_tile_the_collectors_thread(phased):
+    """Their sums add up to no more than the wall time, and to most of
+    it: what is left is the Python between them and the thread's start."""
+    total = sum(_phase(phased["snap"], "phased", p)["sum"] for p in PHASES)
+    assert 0.6 * phased["wall"] <= total <= phased["wall"], (
+        total, phased["wall"])
+    # the pause between the lone request and the volley is in `wait`,
+    # and the lone request's batch closed at its deadline
+    assert _phase(phased["snap"], "phased", "wait")["sum"] >= 0.3
+    assert _phase(phased["snap"], "phased", "fill")["sum"] >= 0.05
+
+
+def test_a_lone_request_fills_for_the_timeout_and_a_pause_is_a_wait():
+    model = _Batched()
+    assert model.request("lone", 1) == 2
+    snap = telemetry.snapshot_local()
+    fill, wait = _phase(snap, "lone", "fill"), _phase(snap, "lone", "wait")
+    assert fill["count"] == 1 and 0.045 <= fill["sum"] < 0.5, fill
+    assert wait["count"] == 1 and wait["sum"] < 0.2, wait
+    time.sleep(0.4)
+    assert model.request("lone", 2) == 4
+    snap = telemetry.snapshot_local()
+    wait = _phase(snap, "lone", "wait")
+    assert wait["count"] == 2 and 0.4 <= wait["sum"] < 2.0, wait
+    assert _phase(snap, "lone", "fill")["count"] == 2
+
+
+@pytest.mark.parametrize("series", [BATCH_QUEUE, BATCH_WAKE])
+def test_a_members_waits_are_recorded_once_a_request(phased, series):
+    payload = phased["snap"]["digests"][
+        (series, (("deployment", "phased"),))]
+    assert payload["count"] == phased["requests"]
+    # (in the queue no longer than a window behind a running batch)
+    assert 0.0 <= payload["min"] and payload["max"] < 1.0, payload
+
+
+def test_outside_a_request_the_collectors_series_are_tagged_default():
+    before = _digest_count(telemetry.snapshot_local(), BATCH_QUEUE,
+                           "default")
+    model = _Batched()
+    assert model.infer(3) == 6
+    snap = telemetry.snapshot_local()
+    assert _digest_count(snap, BATCH_QUEUE, "default") == before + 1
+    assert _phase(snap, "default", "call")["count"] >= 1
+
+
+@pytest.mark.parametrize("series", [BATCH_SECONDS, BATCH_QUEUE, BATCH_WAKE])
+def test_with_telemetry_off_the_collector_records_nothing(monkeypatch,
+                                                          series):
+    from ray_tpu._private.config import CONFIG
+    monkeypatch.setitem(CONFIG._values, "telemetry_enabled", False)
+    model = _Batched()
+    assert model.volley("off-" + series, range(5)) == {
+        x: 2 * x for x in range(5)}
+    snap = telemetry.snapshot_local()
+    assert not [key for table in ("hists", "digests")
+                for key in snap[table]
+                if ("deployment", "off-" + series) in key[1]]
+
+
+def test_a_batch_function_that_raises_closes_its_span_and_every_future():
+    model = _Batched(fail=True)
+    out = model.volley("raises", range(6))
+    assert len(out) == 6 and all(isinstance(e, KeyError)
+                                 for e in out.values())
+    snap = telemetry.snapshot_local()
+    n = len(model.batches)
+    assert sum(model.batches) == 6
+    for phase in ("call", "resolve"):
+        assert _phase(snap, "raises", phase)["count"] == n, phase
+    assert _digest_count(snap, BATCH_WAKE, "raises") == 6
+    # the collector lives on: the next batch is taken and fails the same
+    assert isinstance(model.request("raises", 7), KeyError)
+
+
+def test_the_phases_are_annotations_on_the_collectors_thread(tmp_path):
+    """Inside an open profiler trace the four spans are `rtpu:serve::*` on
+    one host line, the collector thread's — tracing off, so no rows."""
+    import jax
+    from jax.profiler import ProfileData
+    from ray_tpu.util import tracing
+
+    assert not tracing.enabled()
+    tracing.drain()
+    model = _Batched()
+    with jax.profiler.trace(str(tmp_path)):
+        model.request("annotated", 1)
+        model.volley("annotated", range(2, 7))
+    assert tracing.drain() == []
+    found = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    assert found
+    lines = {}
+    for plane in ProfileData.from_file(found[0]).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("rtpu:serve::"):
+                    assert plane.name.startswith("/host:")
+                    lines.setdefault((plane.name, line.name),
+                                     []).append(ev.name)
+    assert len(lines) == 1, sorted(lines)
+    (names,) = lines.values()
+    assert set(names) == {"rtpu:serve::batch_" + p for p in PHASES}
+    n = len(model.batches)
+    # (the first wait opened before the trace did, the last is open)
+    assert names.count("rtpu:serve::batch_call") == n
+    assert names.count("rtpu:serve::batch_fill") == n
+    assert names.count("rtpu:serve::batch_resolve") == n
+
+
+def _rows(name, **tags):
+    return [r for r in rstate.list_metrics({"name": name})
+            if all(r["tags"].get(k) == v for k, v in tags.items())]
+
+
+def test_a_full_replica_records_the_next_requests_slot_wait(serve_session):
+    """Both of `max_concurrent_queries` threads are taken by slow
+    requests: the third waits in the worker for what is left of the
+    first, and the replica's own clock says so."""
+
+    @serve.deployment(max_concurrent_queries=2)
+    class Slow:
+        def __call__(self, seconds):
+            time.sleep(seconds)
+            return seconds
+
+    handle = serve.run(Slow.bind())
+    assert handle.remote(0.0).result(timeout=15) == 0.0    # warm
+    slow = [handle.remote(1.0) for _ in range(2)]
+    time.sleep(0.2)
+    third = handle.remote(0.0)
+    assert third.result(timeout=15) == 0.0
+    assert [r.result(timeout=15) for r in slow] == [1.0, 1.0]
+
+    def waited():
+        rows = _rows("rtpu_serve_replica_slot_wait_seconds",
+                     deployment="Slow")
+        return rows if rows and rows[0]["count"] >= 4 else None
+
+    rows = _wait(waited, timeout=20)
+    assert rows, rstate.list_metrics(
+        {"name": "rtpu_serve_replica_slot_wait_seconds"})
+    # what was left of the first one's second when the third arrived
+    assert 0.5 <= rows[0]["max"] < 1.5, rows[0]
+    # the digest the handle's stamp feeds holds the same wait, and more
+    whole = _rows("rtpu_serve_queue_wait_digest_seconds",
+                  deployment="Slow")
+    assert whole and whole[0]["max"] >= rows[0]["max"] - 0.05
+
+
+def test_the_handle_times_each_route_and_its_refresh(serve_session):
+    @serve.deployment
+    def echo(x):
+        return x
+
+    handle = serve.run(echo.bind())
+    route = ("rtpu_serve_handle_route_seconds", (("deployment", "echo"),))
+    refresh = ("rtpu_serve_handle_refresh_seconds",
+               (("deployment", "echo"),))
+    before = telemetry.snapshot_local()["hists"].get(route, {"count": 0})
+    for i in range(12):
+        assert handle.remote(i).result(timeout=15) == i
+    snap = telemetry.snapshot_local()["hists"]
+    assert snap[route]["count"] == before["count"] + 12
+    assert snap[refresh]["count"] >= 1
+    # a refresh is inside the route that made it
+    assert snap[refresh]["sum"] <= snap[route]["sum"]
