@@ -13,13 +13,49 @@ v5e, PERF.md, PR 41), the softmax is over all E, and the K largest
 probabilities of a token are `ops.router_topk`'s — K rounds of a maximum in
 one Pallas kernel at an E of whole 128-lane tiles on a TPU, `lax.top_k`
 elsewhere, the same values, indices and order among equals either way. The
-T x K (token, expert) assignments are sorted by expert (stable, so token order is kept inside a group), the rows
-gathered into that order, and the three SwiGLU matmuls run as grouped
-matmuls over the E ragged groups (`jax.lax.ragged_dot`: FLOPs and memory
-grow with T x K, with no factor of E and no [T, E, C] tensor). The weighted
-results return to token order through the inverse permutation and are summed
-over K. An expert with no token is an empty group; an expert with many times
-the mean is a long one. The weights are read where they lie: a layer may be
+T x K (token, expert) assignments are sorted by expert (stable, so token
+order is kept inside a group), the rows gathered into that order, and the
+SwiGLU matmuls run as grouped matmuls over the E ragged groups
+(`jax.lax.ragged_dot`: FLOPs and memory grow with T x K, with no factor of E
+and no [T, E, C] tensor). The results return to token order through the
+inverse permutation and are summed over K. An expert with no token is an
+empty group; an expert with many times the mean is a long one.
+
+Where a layer holds all its experts, everything that has to happen to a
+(token, expert) pair happens while the pair's row is in expert order, so
+that the T x K rows — [163,840, 2048] bf16, 0.67 GB a pass in OLMoE's step —
+cross HBM as few times as the mathematics asks (PERF.md, PR 56). One sort
+hands back the experts as sorted and the order; the counts are where each
+expert's run begins in the sorted experts, not a scatter-add of T x K ones;
+the pairs' routing weights come into that order as a sort's payload
+(`_permuted`), not by a gather of single elements. **The routing weight is
+applied inside the SwiGLU product**, in float32 ahead of the product's one
+rounding: weight x ((silu(gate) x up) @ w_down) is (weight x silu(gate) x
+up) @ w_down. **So the combine is the dispatch's transpose** and nothing
+else: the dispatch makes K copies of a token's row in expert order
+(`_take_rows`), the combine sums a token's K rows back (`_sum_rows`), and
+each is the other's derivative — the combine's backward pass is one K-fold
+gather of the [T, D] cotangent, no [T, K, D] product of cotangent and weight
+is written, the rows gathered back are no residual, and the weights'
+gradient is a sum over F inside the SwiGLU product's backward pass, which
+holds up, gate and the cotangent already. **Up and gate are one grouped
+matmul where the weights are cast on the way in** (float32 master weights
+under a bf16 layer: training): the cast writes a copy of each weight
+whatever it is handed, so it writes them side by side, [E, D, 2F]; the rows are read once for both, the transposes give the
+rows' gradient in one matmul — not two and an addition of their [T x K, D]
+results — and the joined weight's in one, and the product's backward pass
+writes d up | d gate as one array (`ops.swiglu`: a kernel on a TPU, because
+the compiler writes the halves out and joins them in a pass of its own) and
+the product again beside them, for `w_down`'s gradient, so that it is
+neither kept from the forward pass nor made in a pass of its own
+(`_weighted_down`).
+Weights that are in the compute dtype already (a served replica) are never
+joined, which would be a copy: they are read where they lie, by three
+matmuls. Which of the two it is the code reads off its input's dtype; there
+is no argument for it. No pass of the block is a scatter-add, and nothing is
+differentiated through a sort.
+
+The weights are read where they lie: a layer may be
 handed the stack its experts are one slice of ([L, E, D, F], as a parameter
 tree holds the layers of a kind) and its place in it (`layer`), and the
 grouped matmuls then run over the stack's L x E experts with the layer's E
@@ -32,7 +68,9 @@ where it is cast on the way in, the cast's output is the layer's copy, the
 slice is fused into it and the matmuls take that. Expert weights carry a
 leading "expert" logical axis that the rules map to the ``ep`` mesh axis: an
 annotation the partitioner is left to honour (no exchange of tokens between
-chips is written here).
+chips is written here; nor is the joined weight written for it: the rules
+map its last axis to ``tp``, so on such a mesh a chip may hold up's columns
+and another gate's, and the partitioner moves what the product needs).
 
 A layer may hold only a share of its experts (`first_expert` and as many as
 the weights it is given: one chip's part of a layer that several share). It
@@ -80,6 +118,7 @@ from jax import lax
 
 from ..ops.router_topk import router_topk
 from ..ops.segment_sum import sorted_segment_sum
+from ..ops.swiglu import weighted_swiglu, weighted_swiglu_bwd
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -89,24 +128,48 @@ def _take_rows(x: jax.Array, order: jax.Array, inverse: jax.Array,
     is a permutation of range(rows x copies) and `inverse` its inverse: with
     one copy a permutation of the rows, with K each token's row once per
     choice, in the sorted order. The cotangent comes back through `inverse`
-    and is summed over the copies: a gather and a reduction where autodiff
-    would scatter-add."""
+    and is summed over the copies (`_sum_rows`): a gather and a reduction
+    where autodiff would scatter-add."""
     return x[order // copies]
 
 
-def _take_rows_fwd(x, order, inverse, copies):
-    return x[order // copies], inverse
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _sum_rows(y: jax.Array, order: jax.Array, inverse: jax.Array,
+              copies: int):
+    """`_take_rows`' transpose: row t of the result is the sum, in float32,
+    of the `copies` rows of y that `_take_rows` made of row t — rows
+    `inverse[t * copies : (t + 1) * copies]`. Its cotangent is `_take_rows`
+    of the result's, one gather of the [T, D] array: autodiff of the gather
+    and the sum would first write the cotangent `copies` times over."""
+    return y[inverse].reshape(-1, copies, y.shape[-1]).sum(
+        1, dtype=jnp.float32).astype(y.dtype)
 
 
-def _take_rows_bwd(copies, inverse, g):
-    back = g[inverse]
-    if copies > 1:
-        back = back.reshape(-1, copies, g.shape[-1]).sum(
-            1, dtype=jnp.float32).astype(g.dtype)
-    return back, None, None
+_take_rows.defvjp(
+    lambda x, order, inverse, copies: (
+        _take_rows(x, order, inverse, copies), (order, inverse)),
+    lambda copies, at, g: (_sum_rows(g, *at, copies), None, None))
+_sum_rows.defvjp(
+    lambda y, order, inverse, copies: (
+        _sum_rows(y, order, inverse, copies), (order, inverse)),
+    lambda copies, at, g: (_take_rows(g, *at, copies), None, None))
 
 
-_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+@jax.custom_vjp
+def _permuted(values: jax.Array, order: jax.Array, inverse: jax.Array):
+    """values[order], a number an element, for a permutation `order` and
+    its `inverse` — as the payload of a sort: sorted by `inverse`, element i
+    lands at place inverse[i], which is where `order` reads it from. The
+    cotangent comes back as the payload of `order`'s sort. On a TPU a
+    gather of 163,840 single elements costs 1.17 ms and such a sort 0.16
+    (PERF.md, PR 56)."""
+    return lax.sort((inverse, values), num_keys=1)[1]
+
+
+_permuted.defvjp(
+    lambda values, order, inverse: (
+        _permuted(values, order, inverse), (order, inverse)),
+    lambda at, g: (_permuted(g, at[1], at[0]), None, None))
 
 
 # Rows of the walk's largest trip, the chunk, as a multiple of the share's
@@ -126,14 +189,53 @@ _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 HELD_CHUNK_SHARE = 2.0
 
 
-def _swiglu_groups(rows, w_up, w_gate, w_down, sizes, layer=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _weighted_down(up_gate, weight, w_down, sizes, impl):
+    """(weight x silu(gate) x up) @ w_down over the groups, from the joined
+    up | gate rows ([R, 2F]; `ops.swiglu`). Its backward pass keeps the
+    product from nothing: the pass that differentiates the product holds
+    up, gate and the weight, and writes the product again beside their
+    gradients, for the gradient of `w_down`."""
+    return lax.ragged_dot(weighted_swiglu(up_gate, weight), w_down, sizes)
+
+
+def _weighted_down_fwd(up_gate, weight, w_down, sizes, impl):
+    return (_weighted_down(up_gate, weight, w_down, sizes, impl),
+            (up_gate, weight, w_down, sizes))
+
+
+def _weighted_down_bwd(impl, res, g):
+    up_gate, weight, w_down, sizes = res
+    d_act = lax.ragged_dot(g, w_down.swapaxes(1, 2), sizes)
+    d_up_gate, d_weight, act = weighted_swiglu_bwd(up_gate, weight, d_act,
+                                                   impl=impl)
+    d_w_down, = jax.vjp(lambda w: lax.ragged_dot(act, w, sizes), w_down)[1](g)
+    return d_up_gate, d_weight, d_w_down, None
+
+
+_weighted_down.defvjp(_weighted_down_fwd, _weighted_down_bwd)
+
+
+def _swiglu_groups(rows, w_up, w_gate, w_down, sizes, layer=None,
+                   weight=None, impl="reference"):
     """Rows sorted by expert through their experts ([E, ...] weights, [E]
-    `sizes`): three grouped matmuls, each weight cast to the rows' dtype
-    where it is not in it. With `layer` the weights are stacks [L, E, ...]
-    in that dtype, read where they lie: as [L x E, ...], which is the same
-    bytes, under [L x E] sizes that are the layer's at `layer * E` and 0
-    elsewhere — the groups before a layer's hold no row, so its first still
-    starts at row 0."""
+    `sizes`): grouped matmuls, each weight cast to the rows' dtype where it
+    is not in it. With `layer` the weights are stacks [L, E, ...] in that
+    dtype, read where they lie: as [L x E, ...], which is the same bytes,
+    under [L x E] sizes that are the layer's at `layer * E` and 0 elsewhere
+    — the groups before a layer's hold no row, so its first still starts at
+    row 0.
+
+    With `weight` ([rows] float32: each row's routing weight) the result is
+    that of the weighted rows' — weight x (silu(gate) x up) @ w_down is
+    (weight x silu(gate) x up) @ w_down — the weight multiplied into the
+    SwiGLU product in float32, ahead of the product's one rounding. Up and
+    gate are then one grouped matmul where they are cast on the way in: the
+    cast writes a copy of each weight whatever it is handed, so it writes
+    the two side by side, [E, D, 2F]; the rows are read once for both, and
+    the transposes give the rows' gradient in one matmul and the joined
+    weight's in one (`ops.swiglu`, which `impl` is for). Weights that are in
+    the rows' dtype already are never joined: that would be a copy."""
     def of(w):
         w = w.astype(rows.dtype)
         return w if layer is None else w.reshape(-1, *w.shape[2:])
@@ -144,9 +246,24 @@ def _swiglu_groups(rows, w_up, w_gate, w_down, sizes, layer=None):
             sizes = lax.dynamic_update_slice(
                 jnp.zeros(n * e, sizes.dtype), sizes,
                 (jnp.asarray(layer, jnp.int32) * e,))
+        if weight is not None and w_up.dtype != rows.dtype:
+            # each cast and then joined, not the join cast: a gradient
+            # then comes back cut in the rows' dtype and cast half by half,
+            # as two weights' are, where it would be cast whole first — a
+            # pass of its own that writes what no one wrote before
+            up_gate = lax.ragged_dot(
+                rows, jnp.concatenate([of(w_up), of(w_gate)], -1), sizes)
+            # (the product's backward kernel takes an F of whole lane tiles)
+            return _weighted_down(
+                up_gate, weight, of(w_down), sizes,
+                impl if w_up.shape[-1] % 128 == 0 else "reference")
         up = lax.ragged_dot(rows, of(w_up), sizes)
         gate = lax.ragged_dot(rows, of(w_gate), sizes)
-        return lax.ragged_dot(jax.nn.silu(gate) * up, of(w_down), sizes)
+        if weight is None:
+            return lax.ragged_dot(jax.nn.silu(gate) * up, of(w_down), sizes)
+        f32 = jnp.float32
+        act = jax.nn.silu(gate.astype(f32)) * up.astype(f32) * weight[:, None]
+        return lax.ragged_dot(act.astype(rows.dtype), of(w_down), sizes)
 
 
 class _Run(NamedTuple):
@@ -421,6 +538,22 @@ def _route(logits, top_k, norm_topk_prob, score, select_bias, route_scale,
     return probs, gate_vals, expert_idx
 
 
+def _by_expert(expert_idx, e, method="scan"):
+    """The T x K (token, choice) pairs sorted by expert, stable: their
+    order ([T x K] int32) and where each of the `e` experts' runs begins in
+    it ([e + 1] int32). The sort hands back its keys with the order — the
+    experts as sorted, without a gather of T x K elements through the order
+    — and the runs' starts are found in them (`jnp.searchsorted`'s
+    `method`): the counts without a scatter-add of T x K ones into E
+    bins."""
+    by_expert, order = lax.sort(
+        (expert_idx.reshape(-1), jnp.arange(expert_idx.size, dtype=jnp.int32)),
+        num_keys=1, is_stable=True)
+    bounds = jnp.searchsorted(
+        by_expert, jnp.arange(e + 1, dtype=by_expert.dtype), method=method)
+    return order, bounds.astype(jnp.int32)
+
+
 def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
             w_gate: jax.Array, w_down: jax.Array, *,
             top_k: int = 2, norm_topk_prob: bool = True,
@@ -454,9 +587,20 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
     router's count — and `moe_routed_here` is the router's own count of the
     choices that fell on held experts, which the held entries must sum to.
     `impl` picks the form of the router's top-k (`ops.router_topk`; an E
-    that is no multiple of 128 keeps `lax.top_k` under any `impl`) and of
-    the sum that returns the held experts' rows to token order
-    (`ops.segment_sum`), as it picks the other kernels.
+    that is no multiple of 128 keeps `lax.top_k` under any `impl`), of the
+    sum that returns the held experts' rows to token order
+    (`ops.segment_sum`) and of the joined SwiGLU product's backward pass
+    (`ops.swiglu`; an F that is no multiple of 128 keeps the `jnp` form), as
+    it picks the other kernels.
+
+    Given all E experts (the module's docstring has the why): a pair's
+    routing weight multiplies inside the SwiGLU product, in float32, while
+    its row is in expert order, so the combine is the dispatch's transpose
+    — a permutation and a sum over K, whose derivative is the dispatch's
+    K-fold gather of the cotangent. Up and gate are one grouped matmul over
+    [E, D, 2F] exactly where the weights' dtype is not `dtype`, which is
+    where a cast writes a copy of them anyway; weights in `dtype` are three
+    matmuls on what lies there.
 
     With `layer` (an int, or a traced one under a scan over layers), the
     three expert weights are stacks [L, ...] of which this layer's are
@@ -503,19 +647,20 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
                              w_gate, w_down, first_expert, dtype, impl, layer)
 
     with jax.named_scope("moe_dispatch"):
-        flat_expert = expert_idx.reshape(-1)                    # [T*K]
-        order = jnp.argsort(flat_expert, stable=True)
+        # (the runs' starts by one pass of compares, not a loop of E-wide
+        # steps: the pairs are few enough here)
+        order, bounds = _by_expert(expert_idx, e, method="compare_all")
         inverse = jnp.argsort(order)
-        group_sizes = jnp.bincount(flat_expert, length=e).astype(jnp.int32)
+        group_sizes = bounds[1:] - bounds[:-1]
         rows = _take_rows(xf.astype(dtype), order, inverse, top_k)  # [T*K, D]
+        weight = _permuted(gate_vals.astype(jnp.float32).reshape(-1),
+                           order, inverse)
 
     expert_out = _swiglu_groups(rows, w_up, w_gate, w_down, group_sizes,
-                                layer)
+                                layer, weight, impl)
 
     with jax.named_scope("moe_combine"):
-        back = _take_rows(expert_out, inverse, order, 1)
-        out = jnp.einsum("tkd,tk->td", back.reshape(n_tokens, top_k, d),
-                         gate_vals.astype(dtype))
+        out = _sum_rows(expert_out, order, inverse, top_k)
 
     aux = {
         **losses(group_sizes.astype(jnp.float32) / n_tokens),  # sums to K
@@ -536,17 +681,7 @@ def _moe_ffn_held(x, e, losses, gate_vals, expert_idx, w_up, w_gate, w_down,
     here = slice(first_expert, first_expert + held)
 
     with jax.named_scope("moe_dispatch"):
-        # the sort hands back its keys with the order: the experts as
-        # sorted, without a gather of T x K elements through the order
-        by_expert, order = lax.sort(
-            (expert_idx.reshape(-1),
-             jnp.arange(n_tokens * top_k, dtype=jnp.int32)),
-            num_keys=1, is_stable=True)
-        # where each expert's run begins in the sorted order: the counts
-        # without a scatter-add of T x K ones into E bins
-        bounds = jnp.searchsorted(by_expert,
-                                  jnp.arange(e + 1, dtype=by_expert.dtype)
-                                  ).astype(jnp.int32)
+        order, bounds = _by_expert(expert_idx, e)
         routed = bounds[1:] - bounds[:-1]
         start = bounds[first_expert]
         trip_sizes = _held_trip_sizes(n_tokens * top_k, held / e)

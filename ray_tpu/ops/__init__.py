@@ -9,10 +9,13 @@ it (`gated_deltanet`: convolution + SiLU + q/k normalisation, and the gated
 RMSNorm), each pair forward and backward; and the sum of rows sorted by
 segment into their segments (`segment_sum`), which returns the held
 experts' rows to token order in `models.moe`, the K largest of each row
-of a router's probabilities (`router_topk`), and a learned choice of keys
-for sparse attention (`sparse_index`: index scores and each query's best
-causal keys, which `attention`'s forward kernel then walks under, forward
-only).
+of a router's probabilities (`router_topk`), the SwiGLU product of rows
+whose up and gate halves come out of one matmul, times a weight a row
+(`swiglu`: its backward pass writes both halves' gradients as one array,
+and the product again),
+and a learned choice of keys for sparse attention (`sparse_index`: index
+scores and each query's best causal keys, which `attention`'s forward kernel
+then walks under, forward only).
 """
 
 from .attention import dot_product_attention, flash_attention  # noqa: F401
@@ -24,5 +27,7 @@ from .ring_attention import ring_attention  # noqa: F401
 from .router_topk import router_topk  # noqa: F401
 from .segment_sum import (sorted_segment_sum,  # noqa: F401
                           sorted_segment_sum_reference)
+from .swiglu import (weighted_swiglu, weighted_swiglu_bwd,  # noqa: F401
+                     weighted_swiglu_bwd_reference)
 from .sparse_index import (Selection, sparse_index,  # noqa: F401
                            sparse_index_reference)

@@ -30,6 +30,7 @@ back without a chip.
 """
 
 import json
+import math
 import os
 import re
 
@@ -97,13 +98,43 @@ def _kernel_names(compiled, name):
         for line in _kernel_calls(compiled, name))
 
 
-def _operations(compiled):
+def _operations(compiled, entry=False):
     """The compiled program's operations by name: (result type, opcode,
-    the text from its operands on)."""
+    the text from its operands on). With `entry`, those of the program's
+    entry computation alone: what runs as an operation of its own and
+    writes its result out, where the whole text also lists what a fusion
+    holds inside (a scan over one layer is part of the entry computation:
+    the compiler writes its one trip out)."""
+    text = compiled.as_text()
+    if entry:
+        text = text[text.index("\nENTRY "):]
+        text = text[:text.index("\n}")]
     return {m.group(1): m.groups()[1:]
-            for line in compiled.as_text().splitlines()
+            for line in text.splitlines()
             if (m := re.match(
                 r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?)\s([\w\-]+)\((.*)", line))}
+
+
+_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s32": 4,
+          "u32": 4, "f32": 4}
+
+
+def _bytes_written_to_rows(compiled, rows):
+    """The bytes each of the program's own operations writes to arrays
+    whose leading dimension is `rows` (the members of a tuple each; a
+    recomputation, `.remat`, is an operation of its own), by name."""
+    sizes = {}
+    for name, (made, op, _) in _operations(compiled, entry=True).items():
+        if op in ("parameter", "bitcast", "get-tuple-element", "tuple"):
+            continue
+        size = sum(
+            _BYTES[kind] * rows * math.prod(map(int, filter(None,
+                                                            dims.split(","))))
+            for kind, dims in re.findall(
+                rf"\b({'|'.join(_BYTES)})\[{rows},?([\d,]*)\]", made))
+        if size:
+            sizes[name] = size
+    return sizes
 
 
 def _grouped_matmul_weights(compiled):

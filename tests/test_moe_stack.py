@@ -226,18 +226,22 @@ def test_a_replica_reads_its_experts_in_place_and_a_gradient_slices_them(
 
 @pytest.mark.parametrize("kw,digest", [
     (dict(n_layers=2, n_experts=16, remat=True, remat_policy="dots"),
-     "45566802e5f0a3ae"),
+     "72d34371eb86637c"),
     (dict(n_layers=2, n_experts=32, moe_experts_held=8, moe_first_expert=8,
           remat=True, remat_policy="full"), "1da176f742ac743d"),
     (dict(n_layers=5, layer_pattern=("window", "window", "window", "full"),
           lead_layers=("window",), lead_d_ff=96, attn_window=32,
-          n_experts=16, remat=False), "4bfd0a2454313a9b")],
+          n_experts=16, remat=False), "86712a8d54f0d652")],
     ids=["all_held_scanned", "a_share_held_scanned", "a_period_written_out"])
 def test_master_weights_in_float32_trace_the_program_they_did(kw, digest):
     """The training cells' side of the choice: with float32 weights under a
     bfloat16 layer the jaxpr of the logits and of the loss's gradients is
-    the parent commit's (PR 53), operation for operation — read off the
-    parent with the same jax — so their compiled steps are what they were."""
+    the one read off the tree named here, operation for operation, with the
+    same jax — so a compiled step is what it was there. A layer that holds a
+    share of its experts traces what it did at PR 53: PR 56 rewrote the
+    block that holds all its experts (the two other cases, read again off
+    that PR's tree) and this digest is the proof that the held share's walk,
+    which shares `_swiglu_groups` with it, was left as it was."""
     if jax.__version__ != "0.9.0":
         pytest.skip("the digests were read off jax 0.9.0's printer")
     model = GPT(GPTConfig(**WIDTHS, **kw))

@@ -28,10 +28,11 @@ from jax.sharding import SingleDeviceSharding
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from _chip import (HBM_BYTES, _asks_no_vmem, _kernel_calls,  # noqa: E402,F401
-                   _kernel_names, _on, benchmark_config, v5e)
+from _chip import (HBM_BYTES, _asks_no_vmem,  # noqa: E402,F401
+                   _bytes_written_to_rows, _kernel_names, _on, _operations,
+                   benchmark_config, v5e)
 from benchmarks.reference import olmoe, olmoe_glue          # noqa: E402
-from ray_tpu.models import GPT                               # noqa: E402
+from ray_tpu.models import GPT, moe                          # noqa: E402
 from ray_tpu.models.gpt import GPTConfig                     # noqa: E402
 
 AUX, ROUTER_Z = 0.01, 0.001
@@ -170,6 +171,115 @@ def test_one_expert_takes_most_tokens_and_one_takes_none():
     assert float(metrics["moe_load_max_over_mean"]) > 3.0
 
 
+# ---------------------------------- the block that holds all its experts
+
+BLOCK = dict(tokens=96, d=64, f=128, experts=8, top_k=2)
+ROUTINGS = {
+    "softmax": dict(norm_topk_prob=False),
+    "sigmoid_with_select_bias": dict(norm_topk_prob=False, score="sigmoid"),
+    "norm_topk_prob": dict(norm_topk_prob=True),
+    "route_scale": dict(norm_topk_prob=True, route_scale=2.5),
+    "an_expert_without_a_token": dict(norm_topk_prob=False),
+}
+# float32 weights under a float32 layer are multiplied where they lie, three
+# grouped matmuls; bfloat16 ones are cast on the way in, which joins up and
+# gate (exactly: every bfloat16 is a float32), the SwiGLU product's backward
+# rule in `jnp` or as its kernel under the interpreter
+FORMS = {"three_matmuls": (jnp.float32, "reference"),
+         "joined": (jnp.bfloat16, "reference"),
+         "joined_kernel": (jnp.bfloat16, "pallas_interpret")}
+
+
+def _plain_block(x, router_w, w_up, w_gate, w_down, *, top_k, select_bias,
+                 norm_topk_prob, score="softmax", route_scale=1.0):
+    """The block as it was before PR 56, in float32: rows gathered by two
+    argsorts, the counts binned, three grouped matmuls, the rounded rows
+    weighed in an einsum after the gather back; autodiff throughout."""
+    f32 = jnp.float32
+    t, d = x.shape[1:]
+    xf = x.reshape(t, d)
+    logits = jnp.dot(xf, router_w, precision=jax.lax.Precision.HIGHEST)
+    _, gate_vals, expert_idx = moe._route(
+        logits, top_k, norm_topk_prob, score, select_bias, route_scale,
+        "reference")
+    flat = expert_idx.reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    inverse = jnp.argsort(order)
+    sizes = jnp.bincount(flat, length=router_w.shape[-1]).astype(jnp.int32)
+    rows = xf[order // top_k]
+    up = jax.lax.ragged_dot(rows, w_up.astype(f32), sizes)
+    gate = jax.lax.ragged_dot(rows, w_gate.astype(f32), sizes)
+    y = jax.lax.ragged_dot(jax.nn.silu(gate) * up, w_down.astype(f32), sizes)
+    return jnp.einsum("tkd,tk->td", y[inverse].reshape(t, top_k, d),
+                      gate_vals).reshape(x.shape), sizes
+
+
+@pytest.mark.parametrize("form", FORMS)
+@pytest.mark.parametrize("routing", ROUTINGS)
+def test_the_block_and_every_gradient_agree_with_the_plain_form(routing,
+                                                                form):
+    """`moe_ffn` with all its experts — one sort, the routing weight inside
+    the SwiGLU product, the combine as the dispatch's transpose, up and gate
+    joined where they are cast — against the plain form: the output, the
+    counts, and the gradients of x, the router (through the routing weights'
+    way back, `_permuted` and the product's backward rule) and the three
+    expert weights. Float32 sums in another order: 1e-5 of the largest entry
+    of the output and 2e-4 of a gradient's; a bfloat16 weight's gradient is
+    the same float32 number rounded, a step of 2 ** -7."""
+    b = BLOCK
+    weight_dtype, impl = FORMS[form]
+    keys = jax.random.split(jax.random.PRNGKey(11), 7)
+    x = jax.random.normal(keys[0], (1, b["tokens"], b["d"]))
+    router_w = jax.random.normal(keys[1], (b["d"], b["experts"]))
+    w_up, w_gate = ((0.2 * jax.random.normal(k, (
+        b["experts"], b["d"], b["f"]))).astype(weight_dtype)
+        for k in keys[2:4])
+    w_down = (0.2 * jax.random.normal(keys[4], (
+        b["experts"], b["f"], b["d"]))).astype(weight_dtype)
+    mix = jax.random.normal(keys[5], x.shape)
+    kw = dict(ROUTINGS[routing], top_k=b["top_k"], select_bias=None)
+    if routing == "sigmoid_with_select_bias":
+        kw["select_bias"] = 0.2 * jax.random.normal(keys[6], (b["experts"],))
+    if routing == "an_expert_without_a_token":
+        router_w = router_w.at[:, 1].set(0.0)
+        x = x.at[..., 0].set(1.0)
+        router_w = router_w.at[0, 1].set(-1e4)
+
+    def ours(*args):
+        out, aux = moe.moe_ffn(*args, dtype=jnp.float32, impl=impl, **kw)
+        return (out * mix).sum(), (out, aux["moe_expert_tokens"])
+
+    def plain(*args):
+        out, sizes = _plain_block(*args, **kw)
+        return (out * mix).sum(), (out, sizes)
+
+    args = (x, router_w, w_up, w_gate, w_down)
+    with jax.default_matmul_precision("highest"):
+        (_, (out, counts)), grads = jax.jit(jax.value_and_grad(
+            ours, argnums=range(5), has_aux=True))(*args)
+        (_, (want, sizes)), wants = jax.jit(jax.value_and_grad(
+            plain, argnums=range(5), has_aux=True))(*args)
+    assert np.array_equal(counts, sizes) and int(sizes.sum()) == (
+        b["tokens"] * b["top_k"])
+    assert (int(sizes[1]) == 0) == (routing == "an_expert_without_a_token")
+    # up and gate are one matmul exactly where they are cast
+    text = str(jax.make_jaxpr(lambda *a: ours(*a)[0])(*args))
+    assert (f"[{b['experts']},{b['d']},{2 * b['f']}]" in text) == (
+        weight_dtype == jnp.bfloat16)
+    scale = float(jnp.abs(want).max())
+    assert scale > 0.1
+    assert float(jnp.abs(out - want).max()) <= 1e-5 * scale
+    for name, got, want in zip(("x", "router", "w_up", "w_gate", "w_down"),
+                               grads, wants):
+        assert got.dtype == want.dtype, name
+        got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+        scale = float(jnp.abs(want).max())
+        assert scale > 0, name
+        step = 2.0 ** -7 if name.startswith("w_") and (
+            weight_dtype == jnp.bfloat16) else 2e-4
+        assert float(jnp.abs(got - want).max()) <= step * scale, name
+
+
 def _olmoe_config():
     return benchmark_config("olmoe_1b_7b")
 
@@ -196,8 +306,19 @@ def test_olmoe_one_layer_train_step_fills_one_chip(v5e):
     """`olmoe-steady`'s step: one OLMoE layer at published widths with all
     64 experts (dropless, by sort and grouped matmul), embedding and untied
     head, float32 AdamW state, at the configuration's `batch_per_chip` rows
-    of 4,096 tokens. It fits, and one row more does not: this is what fixes
-    `batch_per_chip`."""
+    of 4,096 tokens. It fits; and since PR 56 so does one row more (15.26
+    GiB), the first that does not being two more: until then the row after
+    `batch_per_chip` was refused (16.02 GiB), which is what fixed it.
+
+    And the expert block's shape in it (PR 56): up and gate are one grouped
+    matmul, forward and in both transposes, so six where there were nine;
+    the SwiGLU product's backward pass is the repo's kernel, which writes
+    both halves' gradients as one array; nothing adds two [T x K, D] arrays
+    in a pass of its own, nothing under `moe_dispatch` scatters, and the
+    T x K rows are written 7.1 GB a step where they were 9.08 — the
+    compiler's recomputation of the dispatch gather (`.remat`) counted, as
+    it was; the SwiGLU product, which it also made twice, the kernel now
+    writes the second time."""
     one_chip = SingleDeviceSharding(v5e.devices[0])
     config = _olmoe_config()
     rows = config["batch_per_chip"]
@@ -210,12 +331,27 @@ def test_olmoe_one_layer_train_step_fills_one_chip(v5e):
     # the one kernel), and the grouped matmuls are kernels too
     assert _kernel_names(compiled, "flash_") == ["flash_bwd", "flash_fwd"]
     assert _asks_no_vmem(compiled, "flash_fwd")
-    assert len(_kernel_calls(compiled)) > 2
+    assert _kernel_names(compiled, "moe_") == ["moe_swiglu_bwd"]
     mem = compiled.memory_analysis()
     # the donated state is aliased to the new one: 12 bytes a parameter
     assert mem.alias_size_in_bytes > 7.4e9
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
+    pairs = rows * 4096 * config["model"]["moe_top_k"]
+    made = _operations(compiled, entry=True)
+    assert sum(op == "custom-call" and name.startswith("ragged-dot-none")
+               for name, (_, op, _) in made.items()) == 6
+    assert not [name for name, (result, op, _) in made.items()
+                if op == "add" and f"[{pairs}," in result]
+    assert not [name for name, (_, _, rest) in made.items()
+                if "/moe_dispatch/" in rest and "scatter" in rest]
+    written = _bytes_written_to_rows(compiled, pairs)
+    assert 6.0e9 < sum(written.values()) <= 7.2e9, written
     step, state, tokens = _olmoe_step(config, rows + 1)
+    mem = step.lower(_on(one_chip, state),
+                     {"tokens": _on(one_chip, tokens)}
+                     ).compile().memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
+    step, state, tokens = _olmoe_step(config, rows + 2)
     with pytest.raises(Exception, match="(?i)ran out of memory|exhausted"):
         step.lower(_on(one_chip, state),
                    {"tokens": _on(one_chip, tokens)}).compile()

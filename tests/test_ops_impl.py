@@ -11,7 +11,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ray_tpu.ops import (dot_product_attention, gated_delta_rule, gdn_conv,
                          gdn_gated_norm, ring_attention, router_topk,
-                         sorted_segment_sum)
+                         sorted_segment_sum, weighted_swiglu_bwd)
 from ray_tpu.ops._impl import IMPLS, resolve_impl
 
 
@@ -60,6 +60,11 @@ def _router_topk(impl):
     return router_topk(jax.nn.softmax(_normal(10, 24, 48)), 3, impl=impl)
 
 
+def _weighted_swiglu_bwd(impl):
+    return weighted_swiglu_bwd(_normal(11, 24, 96), _normal(12, 24),
+                               _normal(13, 24, 48), impl=impl)
+
+
 OPERATORS = {
     # the operator at a width of 48 or 64, and whether its kernels take
     # only whole 128-lane tiles
@@ -70,6 +75,7 @@ OPERATORS = {
     "gdn_gated_norm": (_gdn_gated_norm, True),
     "segment_sum": (_segment_sum, True),
     "router_topk": (_router_topk, True),
+    "weighted_swiglu_bwd": (_weighted_swiglu_bwd, True),
 }
 
 
