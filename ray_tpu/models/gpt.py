@@ -75,6 +75,7 @@ from ..ops.delta_rule import gated_delta_rule
 from ..ops.gated_deltanet import gdn_conv, gdn_gated_norm
 from ..ops.ring_attention import ring_attention
 from ..ops.sparse_index import sparse_index
+from ..ops.swiglu import gate_activation
 from ..parallel.sharding import (DEFAULT_RULES, ShardingRules,
                                  with_logical_constraint)
 
@@ -186,6 +187,13 @@ class GPTConfig:
     # (None: all of them). The layer computes their part of the result
     moe_first_expert: int = 0
     moe_experts_held: Optional[int] = None
+    # what the router reads: "ffn", the FFN's own normed input (norm2 of the
+    # stream the mixer hands on), or "mixer", the mixer's (norm1 of the
+    # layer's input: the choice of experts is known before attention runs)
+    moe_router_input: str = "ffn"
+    # the activation on an expert's gate projection, routed or shared:
+    # "silu" (SwiGLU) or "relu"
+    moe_activation: str = "silu"
     # the seeded start of the token embedding: the std of its normal draw
     # (every other draw is `_STD`). At 0.02 a token's row is a fortieth of
     # what a pre-normed branch writes back, so a few layers in the stream is
@@ -240,6 +248,16 @@ class GPTConfig:
                                         and self.index_head_dim > 0):
             raise ValueError('a "sparse" layer needs sparse_topk, '
                              "index_heads and index_head_dim")
+        if self.moe_router_input not in ("ffn", "mixer"):
+            raise ValueError(f"moe_router_input {self.moe_router_input!r}: "
+                             "'ffn' or 'mixer'")
+        if self.moe_router_input == "mixer" and (
+                self.n_experts <= 0 or "linear" in pattern):
+            raise ValueError(
+                'moe_router_input="mixer" needs experts (n_experts) to route '
+                "and, in every layer of a period, a mixer that hands its "
+                'normed input on: not "linear"')
+        gate_activation(self.moe_activation)    # an unknown one: by name
 
     @property
     def kv_heads(self) -> int:
@@ -489,7 +507,9 @@ class _Half(NamedTuple):
     """A kind of mixer or of FFN: what lists its weights (of the config; an
     FFN's also of whether the layer is a leading one), and what applies them
     (of a `GPT`: the model, the layer's input, positions, weights), giving
-    its output and its facts."""
+    its output and its facts — a mixer also the normed input it worked on
+    (None from one that does not hand it on), which an FFN is given after
+    its own where the model's router reads it (`moe_router_input`)."""
     weights: Callable[[GPTConfig], Dict[str, _Weight]]
     apply: Callable[..., Any]
 
@@ -498,7 +518,8 @@ _MIXERS = {
     "full": _Half(_full_weights,
                   lambda m, x, positions, w: m._full_mixer(x, positions, w)),
     "linear": _Half(_linear_weights,
-                    lambda m, x, positions, w: (m._linear_mixer(x, w), {})),
+                    lambda m, x, positions, w: (m._linear_mixer(x, w), {},
+                                                None)),
     "sparse": _Half(_sparse_weights,
                     lambda m, x, positions, w: m._full_mixer(
                         x, positions, w, kind="sparse")),
@@ -507,8 +528,9 @@ _MIXERS = {
                         x, positions, w, kind="window")),
 }
 _FFNS = {
-    "dense": _Half(_dense_weights, lambda m, h, w: m._dense_ffn(h, w)),
-    "experts": _Half(_expert_weights, lambda m, h, w: m._expert_ffn(h, w)),
+    "dense": _Half(_dense_weights, lambda m, h, w, tap: m._dense_ffn(h, w)),
+    "experts": _Half(_expert_weights,
+                     lambda m, h, w, tap: m._expert_ffn(h, w, tap)),
 }
 LAYER_KINDS = tuple(_MIXERS)
 
@@ -884,8 +906,9 @@ class GPT:
                                    "act_embed")
 
     def _full_mixer(self, x, positions, w, kind="full"):
-        """Softmax attention on the normed input, residual included, and the
-        layer's facts: in a "sparse" layer over the keys the layer's indexer
+        """Softmax attention on the normed input, residual included, the
+        layer's facts and that normed input: in a "sparse" layer over the
+        keys the layer's indexer
         chooses, and how many (query, key) pairs that was; in a "window"
         layer over each query's last `attn_window` keys, and how many
         rectangles of scores the forward kernel's walk of that band holds."""
@@ -949,7 +972,7 @@ class GPT:
             attn = jnp.einsum("bse,ed->bsd",
                               attn.reshape(*attn.shape[:2], -1),
                               wo.reshape(-1, wo.shape[-1]))
-            return self._join(x, attn, w, "1_post"), facts
+            return self._join(x, attn, w, "1_post"), facts, h
 
     def _over_rows(self, fn, arrays, weights):
         """fn(*arrays, *weights) for [B, S, ...] arrays: on a mesh under
@@ -1026,15 +1049,16 @@ class GPT:
         act = self._constrain(act, "act_batch", "act_seq", "act_mlp")
         return jnp.einsum("bsf,fd->bsd", act, w["w_down"].astype(dt)), {}
 
-    def _expert_ffn(self, h, w):
+    def _expert_ffn(self, h, w, tap=None):
         """The routed experts held here, and the shared one where the model
-        has it, on the normed input h: (their output, the router's
+        has it, on the normed input h, routed by `tap` where given (the
+        mixer's normed input) and by h else: (their output, the router's
         facts)."""
         from .moe import moe_ffn, shared_expert_ffn
         c = self.config
         down, aux = moe_ffn(
             h, w["router"], w["w_up"], w["w_gate"], w["w_down"],
-            layer=w.get("experts_at"),
+            layer=w.get("experts_at"), router_x=tap, act=c.moe_activation,
             top_k=c.moe_top_k, norm_topk_prob=c.moe_norm_topk_prob,
             first_expert=c.moe_first_expert, dtype=c.dtype,
             score=c.moe_score, select_bias=w.get("router_bias"),
@@ -1046,14 +1070,14 @@ class GPT:
         if c.moe_shared_ff:
             down = down + shared_expert_ffn(
                 h, w["ws_up"], w["ws_gate"], w["ws_down"], w.get("ws_open"),
-                dtype=c.dtype)
+                dtype=c.dtype, act=c.moe_activation)
         return down, aux
 
     def _block(self, x, positions, w, kind="full", lead=False):
         """One block of the given kind, a leading one or one of a period.
         x: [B, S, D] bf16."""
         c = self.config
-        x, facts = _MIXERS[kind].apply(self, x, positions, w)
+        x, facts, tap = _MIXERS[kind].apply(self, x, positions, w)
         # a layer without an indexer chose none, one without a window walked
         # none: the facts of every layer of a period are stacked
         if "sparse" in c.kinds:
@@ -1062,7 +1086,8 @@ class GPT:
             facts.setdefault("attn_window_rects", jnp.int32(0))
         with jax.named_scope("mlp"):
             h = self._norm(x, w["norm2"], w.get("bias2"))
-            down, aux = _ffn_of(c, lead).apply(self, h, w)
+            down, aux = _ffn_of(c, lead).apply(
+                self, h, w, tap if c.moe_router_input == "mixer" else None)
             x = self._join(x, down, w, "2_post")
         return x, {**facts, **aux}
 

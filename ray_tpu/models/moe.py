@@ -118,7 +118,8 @@ from jax import lax
 
 from ..ops.router_topk import router_topk
 from ..ops.segment_sum import sorted_segment_sum
-from ..ops.swiglu import weighted_swiglu, weighted_swiglu_bwd
+from ..ops.swiglu import (gate_activation, weighted_swiglu,
+                          weighted_swiglu_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -189,27 +190,29 @@ _permuted.defvjp(
 HELD_CHUNK_SHARE = 2.0
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _weighted_down(up_gate, weight, w_down, sizes, impl):
-    """(weight x silu(gate) x up) @ w_down over the groups, from the joined
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _weighted_down(up_gate, weight, w_down, sizes, impl, act="silu"):
+    """(weight x act(gate) x up) @ w_down over the groups, from the joined
     up | gate rows ([R, 2F]; `ops.swiglu`). Its backward pass keeps the
     product from nothing: the pass that differentiates the product holds
     up, gate and the weight, and writes the product again beside their
     gradients, for the gradient of `w_down`."""
-    return lax.ragged_dot(weighted_swiglu(up_gate, weight), w_down, sizes)
+    return lax.ragged_dot(weighted_swiglu(up_gate, weight, act), w_down,
+                          sizes)
 
 
-def _weighted_down_fwd(up_gate, weight, w_down, sizes, impl):
-    return (_weighted_down(up_gate, weight, w_down, sizes, impl),
+def _weighted_down_fwd(up_gate, weight, w_down, sizes, impl, act):
+    return (_weighted_down(up_gate, weight, w_down, sizes, impl, act),
             (up_gate, weight, w_down, sizes))
 
 
-def _weighted_down_bwd(impl, res, g):
+def _weighted_down_bwd(impl, act, res, g):
     up_gate, weight, w_down, sizes = res
     d_act = lax.ragged_dot(g, w_down.swapaxes(1, 2), sizes)
-    d_up_gate, d_weight, act = weighted_swiglu_bwd(up_gate, weight, d_act,
-                                                   impl=impl)
-    d_w_down, = jax.vjp(lambda w: lax.ragged_dot(act, w, sizes), w_down)[1](g)
+    d_up_gate, d_weight, product = weighted_swiglu_bwd(
+        up_gate, weight, d_act, impl=impl, act=act)
+    d_w_down, = jax.vjp(lambda w: lax.ragged_dot(product, w, sizes),
+                        w_down)[1](g)
     return d_up_gate, d_weight, d_w_down, None
 
 
@@ -217,7 +220,7 @@ _weighted_down.defvjp(_weighted_down_fwd, _weighted_down_bwd)
 
 
 def _swiglu_groups(rows, w_up, w_gate, w_down, sizes, layer=None,
-                   weight=None, impl="reference"):
+                   weight=None, impl="reference", act="silu"):
     """Rows sorted by expert through their experts ([E, ...] weights, [E]
     `sizes`): grouped matmuls, each weight cast to the rows' dtype where it
     is not in it. With `layer` the weights are stacks [L, E, ...] in that
@@ -235,7 +238,13 @@ def _swiglu_groups(rows, w_up, w_gate, w_down, sizes, layer=None,
     the two side by side, [E, D, 2F]; the rows are read once for both, and
     the transposes give the rows' gradient in one matmul and the joined
     weight's in one (`ops.swiglu`, which `impl` is for). Weights that are in
-    the rows' dtype already are never joined: that would be a copy."""
+    the rows' dtype already are never joined: that would be a copy.
+
+    `act` names the gate's activation (`ops.swiglu.gate_activation`: "silu"
+    in everything written here, or the model's "relu"), in all three
+    branches."""
+    opened = gate_activation(act)
+
     def of(w):
         w = w.astype(rows.dtype)
         return w if layer is None else w.reshape(-1, *w.shape[2:])
@@ -256,14 +265,14 @@ def _swiglu_groups(rows, w_up, w_gate, w_down, sizes, layer=None,
             # (the product's backward kernel takes an F of whole lane tiles)
             return _weighted_down(
                 up_gate, weight, of(w_down), sizes,
-                impl if w_up.shape[-1] % 128 == 0 else "reference")
+                impl if w_up.shape[-1] % 128 == 0 else "reference", act)
         up = lax.ragged_dot(rows, of(w_up), sizes)
         gate = lax.ragged_dot(rows, of(w_gate), sizes)
         if weight is None:
-            return lax.ragged_dot(jax.nn.silu(gate) * up, of(w_down), sizes)
+            return lax.ragged_dot(opened(gate) * up, of(w_down), sizes)
         f32 = jnp.float32
-        act = jax.nn.silu(gate.astype(f32)) * up.astype(f32) * weight[:, None]
-        return lax.ragged_dot(act.astype(rows.dtype), of(w_down), sizes)
+        product = opened(gate.astype(f32)) * up.astype(f32) * weight[:, None]
+        return lax.ragged_dot(product.astype(rows.dtype), of(w_down), sizes)
 
 
 class _Run(NamedTuple):
@@ -396,8 +405,9 @@ def _walk(trip, carry, sums, run: _Run, *more, sizes, pairs, scope):
 # size wherever jax's cache of traces reaches, which is the trace around it
 # (a kind of block under `jax.checkpoint`, the backward pass).
 
-@functools.partial(jax.jit, static_argnames=("rows", "impl"))
-def _held_trip(out, lo, by_token, run: _Run, *, rows: int, impl: str):
+@functools.partial(jax.jit, static_argnames=("rows", "impl", "act"))
+def _held_trip(out, lo, by_token, run: _Run, *, rows: int, impl: str,
+               act: str = "silu"):
     """`rows` pairs of the held experts' run from its `lo`-th on, forward:
     gathered, multiplied and added to `out`, the result so far in token
     order ([T, D] float32). Returns it with what the trip adds to the
@@ -405,7 +415,7 @@ def _held_trip(out, lo, by_token, run: _Run, *, rows: int, impl: str):
     took, real or not."""
     *_, taken, sizes = _held_chunk(lo, rows, run)
     y = _swiglu_groups(taken, run.w_up, run.w_gate, run.w_down, sizes,
-                       run.layer)
+                       run.layer, act=act)
     with jax.named_scope("moe_combine"):
         ids, at, pair = (a[:rows] for a in by_token)
         out = _sum_into_tokens(out, lo, y, (ids, at),
@@ -413,9 +423,9 @@ def _held_trip(out, lo, by_token, run: _Run, *, rows: int, impl: str):
     return out, (sizes, jnp.int32(rows))
 
 
-@functools.partial(jax.jit, static_argnames=("rows", "impl"))
+@functools.partial(jax.jit, static_argnames=("rows", "impl", "act"))
 def _held_trip_bwd(carry, lo, by_token, run: _Run, d_out, *, rows: int,
-                   impl: str):
+                   impl: str, act: str = "silu"):
     """The same trip backward: its products made again and differentiated.
     `carry`: the gradients so far of x ([T, D] float32) and of the routing
     weights ([T x K]). Returns them with what the trip adds to the
@@ -424,7 +434,7 @@ def _held_trip_bwd(carry, lo, by_token, run: _Run, d_out, *, rows: int,
     dx, d_gate = carry
     pair, token, valid, taken, sizes = _held_chunk(lo, rows, run)
     y, pull = jax.vjp(
-        lambda r, *w: _swiglu_groups(r, *w, sizes), taken, run.w_up,
+        lambda r, *w: _swiglu_groups(r, *w, sizes, act=act), taken, run.w_up,
         run.w_gate, run.w_down)
     with jax.named_scope("moe_combine"):
         dy = d_out[token]
@@ -440,8 +450,9 @@ def _held_trip_bwd(carry, lo, by_token, run: _Run, d_out, *, rows: int,
     return (dx, d_gate), tuple(dw)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
-def _held_experts(run: _Run, trip_sizes: Tuple[int, ...], impl: str):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
+def _held_experts(run: _Run, trip_sizes: Tuple[int, ...], impl: str,
+                  act: str = "silu"):
     """The held experts' part of the block's result: the result in token
     order ([T, D] float32), the rows each held expert was given ([H] int32,
     the grouped matmuls' own group sizes summed over the trips) and the
@@ -450,18 +461,18 @@ def _held_experts(run: _Run, trip_sizes: Tuple[int, ...], impl: str):
     autodiff cannot pass it: the backward pass is the same walk, each
     trip's products made again and differentiated."""
     out, (given, walked) = _walk(
-        functools.partial(_held_trip, impl=impl),
+        functools.partial(_held_trip, impl=impl, act=act),
         jnp.zeros(run.x.shape, jnp.float32),
         (jnp.zeros_like(run.counts), jnp.int32(0)), run,
         sizes=trip_sizes, pairs=True, scope="moe_combine")
     return out, given, walked
 
 
-def _held_experts_fwd(run, trip_sizes, impl):
-    return _held_experts(run, trip_sizes, impl), run
+def _held_experts_fwd(run, trip_sizes, impl, act):
+    return _held_experts(run, trip_sizes, impl, act), run
 
 
-def _held_experts_bwd(trip_sizes, impl, run, cotangents):
+def _held_experts_bwd(trip_sizes, impl, act, run, cotangents):
     f32 = jnp.float32
     stacks = (run.w_up, run.w_gate, run.w_down)
     if run.layer is not None:
@@ -473,7 +484,7 @@ def _held_experts_bwd(trip_sizes, impl, run, cotangents):
                            w_down=run.w_down[run.layer])
     weights = (run.w_up, run.w_gate, run.w_down)
     (dx, d_gate), d_weights = _walk(
-        functools.partial(_held_trip_bwd, impl=impl),
+        functools.partial(_held_trip_bwd, impl=impl, act=act),
         (jnp.zeros(run.x.shape, f32), jnp.zeros(run.gate_vals.size, f32)),
         tuple(jnp.zeros(w.shape, f32) for w in weights),
         run._replace(layer=None), cotangents[0],
@@ -492,14 +503,15 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 def shared_expert_ffn(x: jax.Array, w_up: jax.Array, w_gate: jax.Array,
                       w_down: jax.Array, gate_w: Optional[jax.Array] = None,
-                      *, dtype=jnp.bfloat16) -> jax.Array:
+                      *, dtype=jnp.bfloat16, act: str = "silu") -> jax.Array:
     """The expert every token passes through, beside the routed ones: a
-    SwiGLU MLP, times a sigmoid gate of its own where `gate_w` is given.
+    SwiGLU MLP (`act` on its gate projection, as the routed experts'),
+    times a sigmoid gate of its own where `gate_w` is given.
     x: [B, S, D]; w_up, w_gate: [D, F]; w_down: [F, D]; gate_w: [D]."""
     with jax.named_scope("moe_shared"):
         up = jnp.einsum("bsd,df->bsf", x, w_up.astype(dtype))
         gate = jnp.einsum("bsd,df->bsf", x, w_gate.astype(dtype))
-        out = jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
+        out = jnp.einsum("bsf,fd->bsd", gate_activation(act)(gate) * up,
                          w_down.astype(dtype))
         if gate_w is None:
             return out.astype(dtype)
@@ -560,7 +572,8 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
             first_expert: int = 0, dtype=jnp.bfloat16,
             impl: str = "auto", score: str = "softmax",
             select_bias: Optional[jax.Array] = None,
-            route_scale: float = 1.0, layer=None
+            route_scale: float = 1.0, layer=None,
+            router_x: Optional[jax.Array] = None, act: str = "silu"
             ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """x: [B, S, D]; router_w: [D, E]; w_up/w_gate: [E, D, F];
     w_down: [E, F, D] → ([B, S, D], aux), aux holding the load-balancing
@@ -568,6 +581,12 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
     routing), the router z-loss (mean squared logsumexp of the router
     logits), the tokens each expert received ([E] int32, summing to
     T x K: nothing is dropped) and each token's chosen experts ([T, K]).
+
+    `router_x` ([B, S, D]), where given, is what the router reads in x's
+    place — the logits, the choice and the weights are its, the rows the
+    experts multiply are x's (a model whose router stands ahead of its
+    attention: `GPTConfig.moe_router_input`). `act` is the activation on
+    the experts' gate projection, "silu" or "relu", in every path below.
 
     `score`: "softmax" over the E logits, or "sigmoid" of each. Both losses
     are defined on a softmax's distribution over the experts; a sigmoid
@@ -625,7 +644,8 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
         # `_norm`'s output is, the compiler leaves out the passes that
         # would multiply its zero terms (PERF.md, PR 41: this product and
         # its dW run at three passes' time, dx at six)
-        logits = jnp.dot(xf.astype(jnp.float32),
+        routed_by = xf if router_x is None else router_x.reshape(n_tokens, d)
+        logits = jnp.dot(routed_by.astype(jnp.float32),
                          router_w.astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)       # [T, E]
         probs, gate_vals, expert_idx = _route(
@@ -644,7 +664,8 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
     held = w_up.shape[-3]
     if held < e:
         return _moe_ffn_held(x, e, losses, gate_vals, expert_idx, w_up,
-                             w_gate, w_down, first_expert, dtype, impl, layer)
+                             w_gate, w_down, first_expert, dtype, impl, layer,
+                             act)
 
     with jax.named_scope("moe_dispatch"):
         # (the runs' starts by one pass of compares, not a loop of E-wide
@@ -657,7 +678,7 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
                            order, inverse)
 
     expert_out = _swiglu_groups(rows, w_up, w_gate, w_down, group_sizes,
-                                layer, weight, impl)
+                                layer, weight, impl, act)
 
     with jax.named_scope("moe_combine"):
         out = _sum_rows(expert_out, order, inverse, top_k)
@@ -671,7 +692,7 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
 
 
 def _moe_ffn_held(x, e, losses, gate_vals, expert_idx, w_up, w_gate, w_down,
-                  first_expert, dtype, impl, layer):
+                  first_expert, dtype, impl, layer, act="silu"):
     """`moe_ffn` from the routing on, for a layer that holds experts
     `first_expert` .. `first_expert + H` of the `e` routed over; `losses`
     gives the router's loss terms from the experts' shares of the tokens."""
@@ -693,7 +714,7 @@ def _moe_ffn_held(x, e, losses, gate_vals, expert_idx, w_up, w_gate, w_down,
         w_up.astype(dtype), w_gate.astype(dtype), w_down.astype(dtype),
         order, start, routed[here],
         None if layer is None else jnp.asarray(layer, jnp.int32)),
-        trip_sizes, impl)
+        trip_sizes, impl, act)
 
     in_share = (expert_idx >= first_expert) & (
         expert_idx < first_expert + held)

@@ -3,7 +3,11 @@ times a weight a row, and its backward pass:
 
     out[r, :] = weights[r] * silu(up_gate[r, F:]) * up_gate[r, :F]
 
-in float32, rounded once to the rows' dtype. One matmul over weights joined
+in float32, rounded once to the rows' dtype — or, for a model whose experts
+gate by ReLU (`act="relu"`), relu in silu's place, in every form below: the
+forward product, the `jnp` backward pass and the kernel, whose derivative of
+the gate is then a step (0 at a gate of 0, as `jax.nn.relu`'s). One matmul
+over weights joined
 along their output width gives `up_gate` ([R, 2F]: up | gate), and its
 transposes then want the product's cotangent as one [R, 2F] array too. The
 forward product (`weighted_swiglu`) is `jnp`: the compiler fuses the two
@@ -56,34 +60,51 @@ _ROWS = 256
 _LANES = 256
 
 
+_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def gate_activation(act: str):
+    """The function on an expert's gate projection, by the model's name for
+    it (`GPTConfig.moe_activation`); any other name is refused."""
+    if act not in _ACTIVATIONS:
+        raise ValueError(f"moe_activation {act!r}: one of "
+                         f"{sorted(_ACTIVATIONS)}")
+    return _ACTIVATIONS[act]
+
+
 def _halves(up_gate):
     f = up_gate.shape[-1] // 2
     return (up_gate[:, :f].astype(jnp.float32),
             up_gate[:, f:].astype(jnp.float32))
 
 
-def _pulled(up, gate, g, w):
+def _pulled(up, gate, g, w, act="silu"):
     """d up, d gate, the terms whose sum over F is d weight, and the
     product itself, from float32 slices of up, gate and the cotangent g and
-    the weights w ([rows, 1])."""
-    s = jax.nn.sigmoid(gate)
-    act = gate * s * up
+    the weights w ([rows, 1]). One body for both activations: act(gate) is
+    gate x s with s the sigmoid (silu) or the step (relu), whose derivative
+    is s (1 + gate (1 - s)) or the step itself."""
+    relu = gate_activation(act) is jax.nn.relu
+    s = (gate > 0).astype(gate.dtype) if relu else jax.nn.sigmoid(gate)
+    product = gate * s * up
     gw = g * w
-    return (gw * gate * s, gw * up * (s * (1.0 + gate * (1.0 - s))),
-            g * act, act * w)
+    return (gw * gate * s,
+            gw * up * (s if relu else s * (1.0 + gate * (1.0 - s))),
+            g * product, product * w)
 
 
-def weighted_swiglu_bwd_reference(up_gate, weights, g):
+def weighted_swiglu_bwd_reference(up_gate, weights, g, act="silu"):
     """The backward pass in `jnp`: (d up_gate [R, 2F], d weights [R], the
     product itself [R, F], made again)."""
     d_up, d_gate, d_w, out = _pulled(
         *_halves(up_gate), g.astype(jnp.float32),
-        weights.astype(jnp.float32)[:, None])
+        weights.astype(jnp.float32)[:, None], act)
     return (jnp.concatenate([d_up, d_gate], -1).astype(up_gate.dtype),
             d_w.sum(-1).astype(weights.dtype), out.astype(up_gate.dtype))
 
 
-def _bwd_kernel(ug_ref, g_ref, w_ref, d_ref, dw_ref, out_ref, *, lanes):
+def _bwd_kernel(ug_ref, g_ref, w_ref, d_ref, dw_ref, out_ref, *, lanes,
+                act="silu"):
     """ug_ref, d_ref: [rows, 2F]; g_ref, out_ref: [rows, F]; w_ref, dw_ref:
     [rows, 1] float32."""
     f = g_ref.shape[1]
@@ -94,7 +115,7 @@ def _bwd_kernel(ug_ref, g_ref, w_ref, d_ref, dw_ref, out_ref, *, lanes):
         d_up, d_gate, terms, out = _pulled(
             ug_ref[:, here].astype(jnp.float32),
             ug_ref[:, there].astype(jnp.float32),
-            g_ref[:, here].astype(jnp.float32), w)
+            g_ref[:, here].astype(jnp.float32), w, act)
         d_ref[:, here] = d_up.astype(d_ref.dtype)
         d_ref[:, there] = d_gate.astype(d_ref.dtype)
         out_ref[:, here] = out.astype(out_ref.dtype)
@@ -102,8 +123,8 @@ def _bwd_kernel(ug_ref, g_ref, w_ref, d_ref, dw_ref, out_ref, *, lanes):
     dw_ref[...] = d_w.sum(-1, keepdims=True)
 
 
-@functools.partial(jax.jit, static_argnums=(3,))
-def _bwd_pallas(up_gate, weights, g, interpret):
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _bwd_pallas(up_gate, weights, g, interpret, act="silu"):
     r, f = g.shape
     rows = min(_ROWS, -(-r // 8) * 8)
     lanes = _LANES if f % _LANES == 0 else 128
@@ -111,7 +132,7 @@ def _bwd_pallas(up_gate, weights, g, interpret):
     whole = pl.BlockSpec((rows, 2 * f), lambda i: (i, 0))
     half = pl.BlockSpec((rows, f), lambda i: (i, 0))
     d, d_w, out = pl.pallas_call(
-        functools.partial(_bwd_kernel, lanes=lanes),
+        functools.partial(_bwd_kernel, lanes=lanes, act=act),
         grid=(-(-r // rows),),
         in_specs=[whole, half, column],
         out_specs=[whole, column, half],
@@ -125,18 +146,20 @@ def _bwd_pallas(up_gate, weights, g, interpret):
     return d, d_w[:, 0].astype(weights.dtype), out
 
 
-def weighted_swiglu(up_gate: jax.Array, weights: jax.Array) -> jax.Array:
+def weighted_swiglu(up_gate: jax.Array, weights: jax.Array,
+                    act: str = "silu") -> jax.Array:
     """up_gate: [R, 2F], up | gate; weights: [R]. Returns [R, F] in
-    `up_gate`'s dtype: weights x silu(gate) x up, computed in float32
+    `up_gate`'s dtype: weights x act(gate) x up, computed in float32
     (module docstring). Differentiated by `weighted_swiglu_bwd`, which its
     caller's own rule calls."""
     up, gate = _halves(up_gate)
-    return (jax.nn.silu(gate) * up * weights.astype(jnp.float32)[:, None]
-            ).astype(up_gate.dtype)
+    return (gate_activation(act)(gate) * up
+            * weights.astype(jnp.float32)[:, None]).astype(up_gate.dtype)
 
 
 def weighted_swiglu_bwd(up_gate: jax.Array, weights: jax.Array,
-                        g: jax.Array, *, impl: str = "auto"):
+                        g: jax.Array, *, impl: str = "auto",
+                        act: str = "silu"):
     """`weighted_swiglu`'s backward pass for the cotangent g [R, F]:
     d up_gate [R, 2F], d weights [R], and the product itself, made again —
     so that a caller who needs it in its own backward pass (the gradient of
@@ -146,5 +169,5 @@ def weighted_swiglu_bwd(up_gate: jax.Array, weights: jax.Array,
     whole 128-lane tiles."""
     impl = resolve_impl(impl, "weighted SwiGLU", g.shape[-1])
     if impl == "reference":
-        return weighted_swiglu_bwd_reference(up_gate, weights, g)
-    return _bwd_pallas(up_gate, weights, g, impl == "pallas_interpret")
+        return weighted_swiglu_bwd_reference(up_gate, weights, g, act)
+    return _bwd_pallas(up_gate, weights, g, impl == "pallas_interpret", act)

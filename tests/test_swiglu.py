@@ -13,6 +13,8 @@ rows' dtype, so float32 results agree to the order of a row's sum over F
 (1e-6 of the largest entry) and bf16 ones to a rounding step.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -22,11 +24,13 @@ from ray_tpu.ops import (weighted_swiglu, weighted_swiglu_bwd,
                          weighted_swiglu_bwd_reference)
 
 
-def _plain(up_gate, weights):
+def _plain(up_gate, weights, act="silu"):
     f = up_gate.shape[-1] // 2
     up, gate = (a.astype(jnp.float32) for a in (up_gate[:, :f],
                                                 up_gate[:, f:]))
-    return gate * jax.nn.sigmoid(gate) * up * weights[:, None]
+    opened = (jnp.maximum(gate, 0.0) if act == "relu"
+              else gate * jax.nn.sigmoid(gate))
+    return opened * up * weights[:, None]
 
 
 def _inputs(rows, f, dtype, seed=0):
@@ -51,15 +55,20 @@ DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
                          ids=["one_block", "a_ragged_last_block",
                               "under_a_tile"])
 @pytest.mark.parametrize("impl", ["pallas_interpret", "reference"])
-def test_the_product_and_its_backward_pass_are_the_formulas(impl, rows, f,
-                                                            dtype):
+@pytest.mark.parametrize("act", ["silu", "relu"])
+def test_the_product_and_its_backward_pass_are_the_formulas(act, impl, rows,
+                                                            f, dtype):
+    """Under either activation of the gate (a seeded normal draw has no
+    gate of exactly 0, where ReLU's derivative is a convention)."""
     dtype = DTYPES[dtype]
     up_gate, weights, g = _inputs(rows, f, dtype)
-    out = weighted_swiglu(up_gate, weights)
-    want, pull = jax.vjp(_plain, up_gate, weights)
+    out = weighted_swiglu(up_gate, weights, act)
+    want, pull = jax.vjp(functools.partial(_plain, act=act), up_gate,
+                         weights)
     assert float(jnp.abs(want).max()) > 1.0
     assert _close(out, want.astype(dtype), dtype)
-    d, d_w, again = weighted_swiglu_bwd(up_gate, weights, g, impl=impl)
+    d, d_w, again = weighted_swiglu_bwd(up_gate, weights, g, impl=impl,
+                                        act=act)
     want_d, want_w = pull(g.astype(jnp.float32))
     assert _close(d, want_d, dtype)
     # a row's weight gradient is a float32 sum over F on both sides
@@ -69,8 +78,18 @@ def test_the_product_and_its_backward_pass_are_the_formulas(impl, rows, f,
                                           and _close(again, out, dtype))
     # and the two forms of the pass are one pass
     for got, ref in zip((d, d_w, again),
-                        weighted_swiglu_bwd_reference(up_gate, weights, g)):
+                        weighted_swiglu_bwd_reference(up_gate, weights, g,
+                                                      act)):
         assert _close(got, ref, jnp.float32 if got is d_w else dtype)
+
+
+def test_an_unknown_activation_is_refused_by_name():
+    up_gate, weights, g = _inputs(8, 128, jnp.float32)
+    with pytest.raises(ValueError, match="moe_activation 'gelu'"):
+        weighted_swiglu(up_gate, weights, "gelu")
+    with pytest.raises(ValueError, match="moe_activation 'gelu'"):
+        weighted_swiglu_bwd(up_gate, weights, g, impl="reference",
+                            act="gelu")
 
 
 def test_the_kernel_is_the_backward_pass_alone():
